@@ -94,7 +94,6 @@ define_flag("check_nan_inf", False, "Scan op outputs for NaN/Inf after each eage
 define_flag("benchmark", False, "Synchronize after each op for timing")
 define_flag("use_bf16_default", True, "Prefer bf16 in AMP autocast on TPU")
 define_flag("eager_delete_tensor_gb", 0.0, "Kept for API parity; PJRT owns memory")
-define_flag("tpu_allow_cpu_fallback", True, "Allow 'tpu' place to map to CPU XLA when no TPU")
 define_flag("jit_cache_size", 4096, "Max cached compiled executables per op signature")
 define_flag("log_level", 0, "VLOG-style verbosity tier")
 define_flag("eager_async_depth", 2,

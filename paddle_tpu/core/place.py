@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import threading
 
-from . import flags
 
 
 class Place:
@@ -114,7 +113,8 @@ def current_place() -> Place:
 
 
 def jax_device(place: Place | None = None):
-    """Resolve a Place to a concrete jax.Device (with CPU fallback for 'tpu')."""
+    """Resolve a Place to a concrete jax.Device. A 'tpu' place with no TPU
+    attached raises: it never resolves to the CPU."""
     import jax
 
     place = place or current_place()
@@ -122,9 +122,9 @@ def jax_device(place: Place | None = None):
         return jax.local_devices(backend="cpu")[0]
     devs = [d for d in jax.devices() if d.platform != "cpu"]
     if not devs:
-        if flags.flag_value("tpu_allow_cpu_fallback"):
-            return jax.local_devices(backend="cpu")[0]
-        raise RuntimeError("No TPU device available and cpu fallback disabled")
+        raise RuntimeError(
+            f"{place!r} needs a TPU and JAX reports none "
+            f"({jax.devices()[0].platform}); name a cpu place to run there")
     return devs[min(place.device_id, len(devs) - 1)]
 
 

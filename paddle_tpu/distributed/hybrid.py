@@ -151,7 +151,14 @@ class AdamWConfig:
 
 def init_opt_state(params):
     zeros = lambda p: jax.tree.map(lambda x: jnp.zeros_like(x, jnp.float32), p)
-    return {"m": zeros(params), "v": zeros(params), "step": jnp.zeros((), jnp.int32)}
+    step = jnp.zeros((), jnp.int32)
+    sharding = getattr(jax.tree.leaves(params)[0], "sharding", None)
+    if isinstance(sharding, NamedSharding):
+        # the train step returns the counter replicated over the params'
+        # mesh; an uncommitted counter on the first call is a different
+        # input type, and the second call would compile the step again
+        step = jax.device_put(step, NamedSharding(sharding.mesh, P()))
+    return {"m": zeros(params), "v": zeros(params), "step": step}
 
 
 def _adamw_update(params, grads, opt, hp: AdamWConfig, global_sq_sum,

@@ -154,14 +154,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto") -> j
 
         return _fa.flash_attention(q, k, v, causal=True)
     if impl == "auto":
-        try:
-            from ..ops.pallas import flash_attention as _fa
+        from ..ops.pallas import flash_attention as _fa
 
-            if (_fa.available() and q.shape[1] == k.shape[1]
-                    and _fa.supported(q.shape, k.shape)):
-                return _fa.flash_attention(q, k, v, causal=True)
-        except ImportError:
-            pass
+        if (_fa.available() and q.shape[1] == k.shape[1]
+                and _fa.supported(q.shape, k.shape)):
+            return _fa.flash_attention(q, k, v, causal=True)
     B, T, H, hd = q.shape
     KV = k.shape[2]
     if KV != H:
@@ -200,17 +197,14 @@ def ffn(h: jax.Array, lp: Dict[str, jax.Array], impl: str = "stock") -> jax.Arra
     and falls back to stock otherwise, mirroring attention's 'auto'.
     """
     if impl == "pallas":
-        try:
-            from ..ops.pallas import fused_ffn as _ff
+        from ..ops.pallas import fused_ffn as _ff
 
-            rows = math.prod(h.shape[:-1])
-            d, f = lp["w1"].shape
-            if _ff.supported(rows, d, f):
-                return _ff.fused_ffn(h, lp["w1"].astype(h.dtype),
-                                     lp["w3"].astype(h.dtype),
-                                     lp["w2"].astype(h.dtype))
-        except ImportError:
-            pass
+        rows = math.prod(h.shape[:-1])
+        d, f = lp["w1"].shape
+        if _ff.supported(rows, d, f):
+            return _ff.fused_ffn(h, lp["w1"].astype(h.dtype),
+                                 lp["w3"].astype(h.dtype),
+                                 lp["w2"].astype(h.dtype))
     gate = jax.nn.silu(h @ lp["w1"].astype(h.dtype)) * (h @ lp["w3"].astype(h.dtype))
     return gate @ lp["w2"].astype(h.dtype)
 

@@ -30,11 +30,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 # All scalar constants entering kernel bodies must be concrete np.float32:
 # under jax_enable_x64 a bare python float is a weak f64, and the resulting
@@ -84,12 +80,7 @@ def _assert_mosaic_tileable(block_shape, array_shape, what: str) -> None:
 
 def available() -> bool:
     """True when the Pallas TPU kernel path can run on the default backend."""
-    if pltpu is None:
-        return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # Trace-time launch accounting, shared by every kernel wrapper in this
@@ -126,8 +117,6 @@ def _pick_block(n: int) -> Optional[int]:
 
 def supported(q_shape, k_shape) -> bool:
     """Static-shape gate: fall back to the XLA path when tiling doesn't fit."""
-    if pltpu is None:
-        return False
     B, T, H, hd = q_shape
     S, KV = k_shape[1], k_shape[2]
     if H % KV != 0:

@@ -56,11 +56,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core import flags
 from .flash_attention import (_BLOCK_CANDIDATES, _assert_mosaic_tileable,
@@ -93,35 +89,53 @@ _QMAX = np.float32(127.0)   # transform.py QMAX; s/127 dequant must match
 # d_ff tiles: the block is the last dim of the w1/w3 blocks, so Mosaic
 # needs it 128-divisible (or the whole dim, always legal)
 _F_TILES = (512, 256, 128)
-# conservative per-launch VMEM budget for the f32 working set
-_VMEM_BUDGET = 14 * 1024 * 1024
+# Mosaic's default scoped-VMEM limit (16 MiB) is below what the backward
+# needs at any tiling once d reaches 4096, so every FFN launch states its
+# own limit (a v5e core has 128 MiB of VMEM) and `_plan` budgets the
+# heaviest launch's working set under it
+_VMEM_LIMIT = 100 * 1024 * 1024
+_VMEM_BUDGET = 96 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+# whole-k GEMM epilogue launches stay under Mosaic's default limit
+_EPILOGUE_BUDGET = 14 * 1024 * 1024
+
+
+def _working_set(br: int, bf: int, d: int) -> int:
+    """Upper bound, in bytes, on the VMEM of the heaviest FFN launch (the
+    backward's dw kernel; the forward and dx launches hold a subset):
+    double-buffered blocks counted at 4 bytes — in x/do [br, d] and
+    w1/w3/w2, out dw1/dw3/dw2 [d, bf] — the three f32 [d, bf]
+    accumulators, and the f32 temporaries (casts of every input block,
+    the three outer products, and u/v/sg/dg/du/dv/g [br, bf])."""
+    blocks = 2 * 4 * (2 * br * d + 6 * d * bf)
+    scratch = 4 * 3 * d * bf
+    temps = 4 * (2 * br * d + 6 * d * bf + 8 * br * bf)
+    return blocks + scratch + temps
 
 
 def _plan(rows: int, d: int, d_ff: int) -> Optional[Tuple[int, int]]:
-    """(row_block, f_block) or None when no Mosaic-legal tiling fits."""
+    """(row_block, f_block) shared by the forward and both backward
+    launches, or None when no Mosaic-legal tiling fits the VMEM budget:
+    the largest tile area that fits, ties to the taller row block (the
+    weights are re-streamed once per row block)."""
     if rows < 1 or d < 8 or d_ff < 8:
         return None
     f_opts = [d_ff] if d_ff <= 512 else [b for b in _F_TILES
                                          if d_ff % b == 0]
     r_opts = [rows] if rows <= 512 else [b for b in _BLOCK_CANDIDATES
                                          if rows % b == 0]
-    if not f_opts or not r_opts:
+    fits = [(br * bf, br, bf) for bf in f_opts for br in r_opts
+            if _working_set(br, bf, d) <= _VMEM_BUDGET]
+    if not fits:
         return None
-    for bf in f_opts:
-        for br in r_opts:
-            # f32 working set: x/acc/out [br, d], w1/w3 [d, bf], w2
-            # [bf, d], u/v/g [br, bf]
-            if 4 * (3 * br * d + 3 * d * bf + 3 * br * bf) <= _VMEM_BUDGET:
-                return br, bf
-    return None
+    _, br, bf = max(fits)
+    return br, bf
 
 
 def supported(rows: int, d: int, d_ff: int) -> bool:
     """Static gate: can this FFN geometry run through the kernel?
     (availability — is there TPU hardware — is `available()`; interpret
     mode ignores it and is how CPU CI exercises the kernel bit-for-bit)."""
-    if pltpu is None:
-        return False
     return _plan(int(rows), int(d), int(d_ff)) is not None
 
 
@@ -227,6 +241,7 @@ def _fwd(x, w1, w3, w2, interpret: bool):
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((br, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
 
@@ -337,6 +352,7 @@ def _bwd(interpret, res, do):
         out_specs=pl.BlockSpec((br, d), lambda i, j: (i, _i32(0)), **mem),
         out_shape=jax.ShapeDtypeStruct((R, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((br, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
 
@@ -376,6 +392,7 @@ def _bwd(interpret, res, do):
             pltpu.VMEM((d, bf), jnp.float32),
             pltpu.VMEM((bf, d), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
     return dx, dw1, dw3, dw2
@@ -411,9 +428,6 @@ def fused_ffn(x, w1, w3, w2, interpret: Optional[bool] = None):
     forces the Pallas interpreter (CPU testing); default: interpret on
     non-TPU backends.
     """
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable; gate calls "
-                           "with fused_ffn.supported()")
     x2, lead, d = _flatten_rows(x)
     f = w1.shape[1]
     if w1.shape != (d, f) or w3.shape != (d, f) or w2.shape != (f, d):
@@ -438,9 +452,6 @@ def fused_ffn_w8(x, w1_q, w1_s, w3_q, w3_s, w2_q, w2_s,
     stock `matmul_param` dequant, so interpret-mode outputs are
     bit-identical to the stock w8 path).
     """
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable; gate calls "
-                           "with fused_ffn.supported()")
     x2, lead, d = _flatten_rows(x)
     f = w1_q.shape[1]
     R = x2.shape[0]
@@ -474,6 +485,7 @@ def fused_ffn_w8(x, w1_q, w1_s, w3_q, w3_s, w2_q, w2_s,
         out_specs=pl.BlockSpec((br, d), lambda i, j: (i, _i32(0)), **mem),
         out_shape=jax.ShapeDtypeStruct((R, d), x2.dtype),
         scratch_shapes=[pltpu.VMEM((br, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
     return o.reshape(*lead, d)
@@ -513,7 +525,7 @@ _EPI_ACTS = {
 def epilogue_supported(m: int, k: int, n: int, activation: str) -> bool:
     """Static gate for `fused_gemm_epilogue`: activation in the fused
     set and an (m, n) tiling that keeps the whole k dim in VMEM."""
-    if pltpu is None or activation not in _EPI_ACTS:
+    if activation not in _EPI_ACTS:
         return False
     if m < 1 or k < 8 or n < 8:
         return False
@@ -523,7 +535,7 @@ def epilogue_supported(m: int, k: int, n: int, activation: str) -> bool:
         (b for b in _F_TILES if n % b == 0), None)
     if bm is None or bn is None:
         return False
-    return 4 * (bm * k + k * bn + 2 * bm * bn) <= _VMEM_BUDGET
+    return 4 * (bm * k + k * bn + 2 * bm * bn) <= _EPILOGUE_BUDGET
 
 
 def _epilogue_kernel(x_ref, y_ref, b_ref, o_ref, *, act: str,
@@ -540,9 +552,6 @@ def fused_gemm_epilogue(x, y, bias=None, activation: str = "none",
                         interpret: Optional[bool] = None):
     """`act(x @ y + bias)` in one launch — the cublasLt-epilogue analog.
     x [m, k], y [k, n], bias [n] or None."""
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable; gate calls "
-                           "with fused_ffn.epilogue_supported()")
     m, k = x.shape
     n = y.shape[1]
     if not epilogue_supported(m, k, n, activation):
@@ -592,8 +601,6 @@ def fused_glu(u, v, act: str = "silu",
               interpret: Optional[bool] = None):
     """Gated-activation epilogue `act(u) * v` in one launch (the
     swiglu/geglu half of fused_bias_act). u, v [rows, f]."""
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable")
     u2, lead, f = _flatten_rows(u)
     v2 = v.reshape(u2.shape)
     R = u2.shape[0]
@@ -620,6 +627,6 @@ def fused_glu(u, v, act: str = "silu",
 
 
 def glu_supported(rows: int, f: int, act: str) -> bool:
-    if pltpu is None or act not in _EPI_ACTS or f < 8 or rows < 1:
+    if act not in _EPI_ACTS or f < 8 or rows < 1:
         return False
     return rows <= 512 or any(rows % b == 0 for b in _BLOCK_CANDIDATES)

@@ -29,9 +29,9 @@ Design:
   prefetched lengths), so a 4-page sequence in a 64-page table costs 4
   iterations, not 64;
 - int8 pages dequantize IN-REGISTER: the per-page scale planes
-  `[num_blocks, KV]` ride the same prefetched table through (1, 1) SMEM
-  blocks; the k scale is constant over hd so it factors out of the q·k
-  dot and lands on the scores, the v scale lands on the probabilities —
+  `[num_blocks, KV]` ride the same prefetched table through (8, KV)
+  SMEM blocks; the k scale is constant over hd so it factors out of the
+  q·k dot and lands on the scores, the v scale lands on the probabilities —
   bit-identical placement to the stock path's folding, and no fp copy
   of the cache ever exists;
 - `max_q=1` is the decode-specialized launch: rows collapse to the GQA
@@ -52,11 +52,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend (absent on some CPU-only builds)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import (NEG_INF, _assert_mosaic_tileable, _i32,
                               available, count_launch)
@@ -67,6 +63,8 @@ __all__ = ["paged_attention", "available", "supported"]
 # flash_attention.py (a [rows, 1] scratch column is not a legal vreg shape
 # on all Mosaic versions; 128 lanes is the native tile)
 _STAT_LANES = 128
+# rows of the per-page scale planes brought into SMEM with each page
+_SCALE_ROWS = 8
 
 
 def supported(num_heads: int, num_kv_heads: int, head_dim: int,
@@ -74,8 +72,6 @@ def supported(num_heads: int, num_kv_heads: int, head_dim: int,
     """Static gate: can this head/page geometry run through the kernel?
     (availability — is there TPU hardware — is `available()`; interpret
     mode ignores it and is how CPU CI exercises the kernel bit-for-bit)."""
-    if pltpu is None:
-        return False
     if num_kv_heads <= 0 or num_heads % num_kv_heads != 0:
         return False
     # blocks equal the array dims on the last two axes, so any
@@ -102,6 +98,10 @@ def _kernel(tables_ref, past_ref, this_ref, *refs, sm_scale: float,
     b = pl.program_id(0)
     p = pl.program_id(2)
     n_pages = pl.num_programs(2)
+    if has_quant:
+        # this page's row inside the [_SCALE_ROWS, KV] SMEM scale block
+        scale_row = jax.lax.rem(tables_ref[b, p], _i32(_SCALE_ROWS))
+        kv_head = pl.program_id(1)
 
     @pl.when(p == 0)
     def _():
@@ -125,7 +125,7 @@ def _kernel(tables_ref, past_ref, this_ref, *refs, sm_scale: float,
         if has_quant:
             # per-page k scale is constant over hd: it factors out of the
             # dot, so one scalar multiply dequantizes the whole score tile
-            s = s * (sm_scale * kdq_ref[0, 0])
+            s = s * (sm_scale * kdq_ref[scale_row, kv_head])
         else:
             s = s * sm_scale
         rows_i = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -142,7 +142,7 @@ def _kernel(tables_ref, past_ref, this_ref, *refs, sm_scale: float,
         l_sc[:] = l_sc[:] * alpha + jnp.sum(prob, axis=-1, keepdims=True)
         if has_quant:
             # v scale likewise factors out: fold into the probabilities
-            prob = prob * vdq_ref[0, 0]
+            prob = prob * vdq_ref[scale_row, kv_head]
         v = v_ref[0, 0].astype(jnp.float32)           # [bs, hd]
         acc[:] = acc[:] * alpha + jax.lax.dot_general(
             prob, v, (((1,), (0,)), ((), ())),
@@ -178,9 +178,6 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     mode (pass both or neither). Returns [B, KV, max_q * G, hd] in
     q_rows.dtype; pad rows come back 0.
     """
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable; gate calls "
-                           "with paged_attention.supported()")
     if (k_dequant is None) != (v_dequant is None):
         raise ValueError("pass both k_dequant and v_dequant or neither")
     has_quant = k_dequant is not None
@@ -214,19 +211,23 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     ]
     inputs = [q_rows, key_cache, value_cache]
     if has_quant:
-        smem = {"memory_space": pltpu.SMEM}
-        in_specs += [
-            pl.BlockSpec((1, 1),
-                         lambda b, kv, p, tr, pr, th: (tr[b, p], kv), **smem),
-            pl.BlockSpec((1, 1),
-                         lambda b, kv, p, tr, pr, th: (tr[b, p], kv), **smem),
-        ]
-        inputs += [k_dequant.astype(jnp.float32),
-                   v_dequant.astype(jnp.float32)]
+        # a (1, 1) block of the [num_blocks, KV] plane breaks Mosaic's
+        # (8, 128) rule; (_SCALE_ROWS, KV) is legal (8 rows, whole last
+        # dim) and the kernel picks its scalar out of the block
+        scale_spec = pl.BlockSpec(
+            (_SCALE_ROWS, KV),
+            lambda b, kv, p, tr, pr, th: (
+                jax.lax.div(tr[b, p], _i32(_SCALE_ROWS)), _i32(0)),
+            memory_space=pltpu.SMEM)
+        in_specs += [scale_spec, scale_spec]
+        # whole SMEM blocks only: pad a pool that is no multiple of 8
+        pad = (0, -num_blocks % _SCALE_ROWS), (0, 0)
+        inputs += [jnp.pad(k_dequant.astype(jnp.float32), pad),
+                   jnp.pad(v_dequant.astype(jnp.float32), pad)]
     out_spec = pl.BlockSpec(
         (1, 1, rows, hd),
         lambda b, kv, p, tr, pr, th: (b, kv, _i32(0), _i32(0)), **mem)
-    for spec, arr in zip(in_specs[:3], inputs[:3]):
+    for spec, arr in zip(in_specs, inputs):
         _assert_mosaic_tileable(spec.block_shape, arr.shape, "paged input")
     _assert_mosaic_tileable(out_spec.block_shape, q_rows.shape,
                             "paged output")

@@ -96,6 +96,10 @@ class OpCosts:
                 data = json.load(f)
         except (OSError, ValueError):
             data = {}
+        # a machine class without pins has no timings at all: a lookup
+        # that refresh() has not filled then fails, where a default would
+        # price the candidate from nothing (or from another machine)
+        self.pinned = self.key in data
         for name, entry in (data.get(self.key) or {}).items():
             t = entry_time(entry)
             if t is not None:
@@ -104,6 +108,12 @@ class OpCosts:
 
     def time(self, name: str, default: Optional[float] = None
              ) -> Optional[float]:
+        if name not in self.times and not self.pinned:
+            raise KeyError(
+                f"no timing of {name!r} for machine {self.key!r}: "
+                f"{self.path} holds no pins for it (run "
+                f"tools/ci_op_benchmark.py --update there) and refresh() "
+                f"has not measured it")
         return self.times.get(name, default)
 
     def noise(self, name: str) -> float:

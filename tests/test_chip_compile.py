@@ -1,0 +1,193 @@
+"""Real compiles of the main-path Pallas kernels for a described TPU v5e.
+
+Every other kernel test runs the Pallas interpreter, which accepts what
+the chip's compiler refuses: a scale-plane BlockSpec that breaks the
+(8, 128) rule, a backward launch over the scoped-VMEM limit, an int64
+argmax index, a sort inside a kernel. libtpu compiles for a `v5e:2x2`
+topology that is described, not attached, so these tests hand each kernel
+its shapes at the widths `chip_smoke.py` runs (llama-7b: d 4096, d_ff
+11008, 32 heads of 128, vocab 32000) and compile it in this process.
+Nothing runs: a pass says the compiler takes the kernel, not that its
+results are right — the interpret-mode parity tests say that.
+
+The topology is described inside a fixture, never at import: libtpu
+belongs to one process, and under xdist every worker imports this file.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import fused_ffn as ff
+from paddle_tpu.ops.pallas import fused_sample as fs
+from paddle_tpu.ops.pallas import paged_attention as pa
+
+# llama-7b widths (models/llama.py CONFIGS["llama-7b"])
+D, D_FF, HEADS, HEAD_DIM, VOCAB, SEQ = 4096, 11008, 32, 128, 32000, 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep it off around these
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """Compile `fn` for the described chip; shapes are (shape, dtype)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _bf16(*shape):
+    return shape, jnp.bfloat16
+
+
+def _flash_loss(q, k, v):
+    o = fa._flash_bhtd(q, k, v, np.float32(HEAD_DIM ** -0.5), True, False)
+    return jnp.sum(o.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("q_shape, kv_shape, grad", [
+    # the smoke's train step: one sequence of 2048 over 32 heads of 128
+    ((1, HEADS, SEQ, HEAD_DIM), (1, HEADS, SEQ, HEAD_DIM), True),
+    ((4, 12, SEQ, HEAD_DIM), (4, 12, SEQ, HEAD_DIM), True),
+    # the shapes test_mosaic_lowering.py exported: small fwd, fwd+bwd,
+    # GQA index maps (h // group on int32), the old bench shape
+    ((2, 4, 256, 64), (2, 4, 256, 64), False),
+    ((2, 4, 256, 64), (2, 4, 256, 64), True),
+    ((2, 8, 256, 64), (2, 2, 256, 64), True),
+    ((1, 12, SEQ, HEAD_DIM), (1, 12, SEQ, HEAD_DIM), True),
+])
+def test_flash_attention_compiles(one_chip, q_shape, kv_shape, grad):
+    fn = (jax.value_and_grad(_flash_loss, argnums=(0, 1, 2)) if grad
+          else _flash_loss)
+    compiled = _compile(fn, one_chip, _bf16(*q_shape), _bf16(*kv_shape),
+                        _bf16(*kv_shape))
+    # fwd, dq and dkv launches
+    assert compiled.as_text().count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("rows, d, d_ff", [
+    (SEQ, D, D_FF),          # the smoke's train step, batch 1
+    (2 * SEQ, D, D_FF),
+    (8192, 1536, 4096),      # the backward Mosaic refused at 18.15M VMEM
+])
+def test_fused_ffn_fwd_bwd_compiles(one_chip, rows, d, d_ff):
+    assert ff.supported(rows, d, d_ff)
+
+    def loss(x, w1, w3, w2):
+        o = ff.fused_ffn(x, w1, w3, w2, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+                        one_chip, _bf16(rows, d), _bf16(d, d_ff),
+                        _bf16(d, d_ff), _bf16(d_ff, d))
+    assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dx, dw
+
+
+@pytest.mark.parametrize("rows", [8, 128, 1024])   # decode tick / chunks
+@pytest.mark.parametrize("w8", [False, True])
+def test_fused_ffn_serving_compiles(one_chip, rows, w8):
+    assert ff.supported(rows, D, D_FF)
+    if w8:
+        fn = lambda x, q1, s1, q3, s3, q2, s2: ff.fused_ffn_w8(
+            x, q1, s1, q3, s3, q2, s2, interpret=False)
+        shapes = [_bf16(rows, D),
+                  ((D, D_FF), jnp.int8), ((1, D_FF), jnp.float32),
+                  ((D, D_FF), jnp.int8), ((1, D_FF), jnp.float32),
+                  ((D_FF, D), jnp.int8), ((1, D), jnp.float32)]
+    else:
+        fn = lambda x, w1, w3, w2: ff.fused_ffn(x, w1, w3, w2,
+                                                interpret=False)
+        shapes = [_bf16(rows, D), _bf16(D, D_FF), _bf16(D, D_FF),
+                  _bf16(D_FF, D)]
+    _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("max_q", [1, 128])        # decode / chunked prefill
+@pytest.mark.parametrize("int8_pages", [False, True])
+def test_paged_attention_compiles(one_chip, max_q, int8_pages):
+    batch, block_size, num_blocks, max_blocks = 8, 16, 576, 72
+    assert pa.supported(HEADS, HEADS, HEAD_DIM, block_size)
+    page_dtype = jnp.int8 if int8_pages else jnp.bfloat16
+    page = ((num_blocks, HEADS, block_size, HEAD_DIM), page_dtype)
+    shapes = [_bf16(batch, HEADS, max_q, HEAD_DIM), page, page,
+              ((batch, max_blocks), jnp.int32), ((batch,), jnp.int32),
+              ((batch,), jnp.int32)]
+    if int8_pages:
+        shapes += [((num_blocks, HEADS), jnp.float32)] * 2
+
+    def fn(q, k, v, tables, past, this, *dequant):
+        return pa.paged_attention(q, k, v, tables, past, this, 1,
+                                  HEAD_DIM ** -0.5, *dequant,
+                                  interpret=False)
+
+    _compile(fn, one_chip, *shapes)
+
+
+@pytest.mark.parametrize("top_k", [0, 50])
+def test_fused_sample_prep_compiles(one_chip, top_k):
+    batch = 8
+    assert fs.supported(batch, VOCAB)
+    _compile(lambda l, t, p: fs.fused_sample_prep(l, t, p, top_k,
+                                                  interpret=False),
+             one_chip, ((batch, VOCAB), jnp.float32),
+             ((batch,), jnp.float32), ((batch,), jnp.float32))
+
+
+def test_r02_lse_blockspec_fails_tpu_lowering():
+    """Deliberately rebuild the r02 bug — a rank-3 lse output whose block
+    (1, 1, bq) puts a size-1 second-minor dim against H — and prove the
+    TPU lowering catches it WITHOUT hardware. This guards the guard: if
+    lowering for the TPU platform ever stops running Mosaic's
+    block-mapping check, this test fails and the compiles above are known
+    to be toothless."""
+    B, H, T, bq = 2, 4, 512, 256
+
+    def kernel(x_ref, o_ref):
+        o_ref[0, 0] = jnp.max(x_ref[0, 0], axis=-1)
+
+    def bad(x):
+        return pl.pallas_call(
+            kernel,
+            grid=(B, H, T // bq),
+            in_specs=[pl.BlockSpec((1, 1, bq, 128),
+                                   lambda b, h, i: (b, h, i, np.int32(0)))],
+            out_specs=pl.BlockSpec((1, 1, bq),
+                                   lambda b, h, i: (b, h, i)),
+            out_shape=jax.ShapeDtypeStruct((B, H, T), jnp.float32),
+        )(x)
+
+    x = jax.ShapeDtypeStruct((B, H, T, 128), jnp.float32)
+    with pytest.raises(Exception, match="divisible|block shape"):
+        jax.export.export(jax.jit(bad), platforms=["tpu"])(x)
+
+
+def test_static_mirror_agrees_with_mosaic():
+    """The CPU-side `_assert_mosaic_tileable` mirror rejects exactly the
+    r02 spec too, so interpret-mode tests fail fast as well."""
+    with pytest.raises(ValueError, match="tiling rule"):
+        fa._assert_mosaic_tileable((1, 1, 256), (2, 4, 512), "lse output")
+    # legal: trailing dim equals array dim
+    fa._assert_mosaic_tileable((1, 1, 256, fa.LANES), (2, 4, 512, fa.LANES),
+                               "lse output")

@@ -26,7 +26,7 @@ from paddle_tpu.tuner import (Candidate, CostModel, OpCosts, Ranked,
 def _toy_costs(**times):
     """OpCosts detached from the pinned baseline file."""
     oc = OpCosts.__new__(OpCosts)
-    oc.path, oc.key = "<toy>", "test/toy"
+    oc.path, oc.key, oc.pinned = "<toy>", "test/toy", True
     oc.times = dict(times)
     oc.noises = {k: 0.0 for k in times}
     return oc
@@ -209,6 +209,15 @@ class TestCostModel:
         oc = OpCosts(key="cpu/1cpu")
         assert oc.time("decode_tick_stock") is not None
         assert oc.noise("decode_tick_stock") >= 0.0
+
+    def test_opcosts_fails_on_a_machine_without_pins(self):
+        """No pins for the machine class: a lookup raises instead of
+        handing the model a default; a pinned machine keeps defaults for
+        entries it lacks."""
+        oc = OpCosts(key="tpu/13cpu")
+        with pytest.raises(KeyError, match="tpu/13cpu"):
+            oc.time("decode_tick_stock", 0.0)
+        assert OpCosts(key="cpu/1cpu").time("no_such_op", 0.0) == 0.0
 
 
 # ---------------------------------------------------------------------------

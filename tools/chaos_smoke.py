@@ -10,8 +10,8 @@ recovery invariants:
   registry (recovery is *observed*, not assumed)
 - the final checkpoint publishes and loads back with CRC verification
 
-Prints ONE json line and exits non-zero on any violation, so CI (and
-tools/bench_watch.py, which logs a RED line on failure) can gate on it::
+Prints ONE json line and exits non-zero on any violation, so CI can gate
+on it::
 
     python tools/chaos_smoke.py
 """

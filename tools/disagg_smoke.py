@@ -1,7 +1,7 @@
 """Disaggregated-serving smoke: prefill/decode pools under a mid-handoff
 sender kill. Prints ONE JSON line; exit 0 iff ok.
 
-The drill behind bench_watch's RED line for the disagg subsystem:
+The drill for the disagg subsystem:
 - a prefill replica is chaos-killed mid-handoff (``migration:rank_dead``
   riding the page offer, driven through ``FLAGS_chaos_spec``): the
   lease-derived epoch fence must reject its pages at ingest and the

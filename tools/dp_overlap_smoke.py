@@ -1,7 +1,7 @@
 """DP overlap/sharding smoke: barrier vs overlap vs sharded step time on the
 8-virtual-device CPU mesh. Prints ONE JSON line; exit 0 iff ok.
 
-The drill behind bench_watch's RED line for the data-parallel hot path:
+The drill for the data-parallel hot path:
 - parity: overlapped and sharded updates must match the barrier baseline
 - overlap: grad collectives issue from backward hooks (Task handles
   outstanding before the drain) and the overlap-efficiency gauge holds

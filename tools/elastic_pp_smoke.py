@@ -20,8 +20,7 @@ training. Gates:
   stages, later steps add no stage executables
   (paddle_pp_stage_builds_total is constant)
 
-Prints ONE json line; exit 0 iff ok. Wired as a RED line in
-tools/bench_watch.py::
+Prints ONE json line; exit 0 iff ok::
 
     python tools/elastic_pp_smoke.py
 """

@@ -15,8 +15,8 @@ mid-collective — and checks the elastic invariants:
   compiles for the new mesh, later steps add no fused-update
   executables
 
-Prints ONE json line and exits non-zero on any violation, so CI (and
-tools/bench_watch.py, which logs a RED line on failure) can gate on it::
+Prints ONE json line and exits non-zero on any violation, so CI can gate
+on it::
 
     python tools/elastic_smoke.py
 """

@@ -1,9 +1,9 @@
 """One-off flagship perf probe: try batch-size x remat variants on the real
 chip to find a higher-MFU operating point for bench.py's flagship config.
 
-Run under the advisory chip lock (tools/tpu_lock.py). Each variant compiles
-once and times a few steps; OOM/compile failures are caught and reported as
-such so an over-HBM variant costs nothing but its compile attempt.
+Each variant compiles once and times a few steps; OOM/compile failures are
+caught and reported as such so an over-HBM variant costs nothing but its
+compile attempt.
 
 Usage: python tools/perf_probe.py [--steps 3] [--warmup 2]
 """
@@ -17,7 +17,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def probe(B, remat, steps, warmup, M=1):
@@ -45,7 +44,7 @@ def probe(B, remat, steps, warmup, M=1):
     for _ in range(warmup):
         sp, opt, loss = step(sp, opt, tokens, targets)
     if loss is not None:
-        float(loss)
+        jax.block_until_ready(loss)
     from paddle_tpu import observability
     from paddle_tpu.core import async_engine
     from paddle_tpu.ops import dispatch
@@ -58,7 +57,7 @@ def probe(B, remat, steps, warmup, M=1):
         c_s = dispatch.dispatch_cache_stats()
         print(f"  step {i}: in_flight={a_s['in_flight']}/{a_s['depth']} "
               f"cache_hit_rate={c_s['hit_rate']}", flush=True)
-    float(loss)
+    jax.block_until_ready(loss)
     dt = time.perf_counter() - t0
     tps = B * T * steps / dt
     mfu = cfg.flops_per_token() * tps / bench.chip_peak_flops(jax.devices()[0])
@@ -88,31 +87,29 @@ def main():
                     default="4:dots,4:none,8:dots,8:none,16:dots")
     args = ap.parse_args()
 
-    import tpu_lock
-    with tpu_lock.held(wait_s=1800):
-        import jax
-        d = jax.devices()[0]
-        print(f"device: {d.platform} {getattr(d, 'device_kind', '')}",
-              flush=True)
-        if d.platform == "cpu":
-            print("cpu backend; aborting probe", flush=True)
-            return 1
-        results = {}
-        for spec in args.variants.split(","):
-            parts = spec.split(":")
-            bs, rs = parts[0], parts[1]
-            M = int(parts[2]) if len(parts) > 2 else 1
-            remat = {"dots": "dots", "none": False, "full": True}[rs]
-            key = f"B{bs}_{rs}" + (f"_M{M}" if M > 1 else "")
-            t0 = time.perf_counter()
-            try:
-                results[key] = probe(int(bs), remat, args.steps, args.warmup,
-                                     M=M)
-                results[key]["wall_s"] = round(time.perf_counter() - t0, 1)
-            except Exception as e:  # noqa: BLE001 — OOM variants report+continue
-                results[key] = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
-            print(json.dumps({key: results[key]}), flush=True)
-        print("FINAL " + json.dumps(results), flush=True)
+    import jax
+    d = jax.devices()[0]
+    print(f"device: {d.platform} {getattr(d, 'device_kind', '')}",
+          flush=True)
+    if d.platform == "cpu":
+        print("cpu backend; aborting probe", flush=True)
+        return 1
+    results = {}
+    for spec in args.variants.split(","):
+        parts = spec.split(":")
+        bs, rs = parts[0], parts[1]
+        M = int(parts[2]) if len(parts) > 2 else 1
+        remat = {"dots": "dots", "none": False, "full": True}[rs]
+        key = f"B{bs}_{rs}" + (f"_M{M}" if M > 1 else "")
+        t0 = time.perf_counter()
+        try:
+            results[key] = probe(int(bs), remat, args.steps, args.warmup,
+                                 M=M)
+            results[key]["wall_s"] = round(time.perf_counter() - t0, 1)
+        except Exception as e:  # noqa: BLE001 — OOM variants report+continue
+            results[key] = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
+        print(json.dumps({key: results[key]}), flush=True)
+    print("FINAL " + json.dumps(results), flush=True)
     return 0
 
 

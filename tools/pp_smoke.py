@@ -1,6 +1,6 @@
 """Pipeline-parallel smoke: 1F1B on 4 virtual CPU devices vs pp=1.
 
-The drill behind bench_watch's RED line for the MPMD pipeline subsystem
+The drill for the MPMD pipeline subsystem
 (distributed.pipeline). Prints ONE JSON line; exit 0 iff ok. Gates:
 
 - parity: pp=2 1F1B with 8 microbatches trains within float32-ulp

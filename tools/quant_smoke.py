@@ -2,7 +2,7 @@
 fp paged engine on the same request trace. Prints ONE JSON line; exit 0
 iff ok.
 
-The drill behind bench_watch's RED line for the quant subsystem:
+The drill for the quant subsystem:
 - logit parity: quantized LLMPredictor logits stay within tolerance of
   the fp predictor on the same prompt (weight-only int8 tracks fp32 to
   well under 5% relative error on this model)

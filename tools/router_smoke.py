@@ -1,7 +1,7 @@
 """Resilient-serving smoke: the multi-replica router under a chaos
 replica kill. Prints ONE JSON line; exit 0 iff ok.
 
-The drill behind bench_watch's RED line for the router subsystem:
+The drill for the router subsystem:
 - zero dropped streams: every admitted stream completes even though one
   of the two replicas is chaos-killed mid-trace
 - failover parity: the merged outputs (streamed prefix on the dead

@@ -2,7 +2,7 @@
 against the dense slot engine on the same request trace. Prints ONE JSON
 line; exit 0 iff ok.
 
-The drill behind bench_watch's RED line for the serving subsystem:
+The drill for the serving subsystem:
 - parity: paged greedy outputs must match the dense-slot engine
   token-for-token across the whole trace
 - throughput: paged tokens/s >= dense tokens/s on a production-shaped

@@ -2,7 +2,7 @@
 for the multi-tenant serving tentpole. Prints ONE JSON line; exit 0
 iff ok.
 
-The drill behind bench_watch's RED line for the spec/adapter
+The drill for the spec/adapter
 subsystem:
 
 - spec parity: greedy outputs with a (different, smaller) draft model

@@ -10,7 +10,7 @@ therefore without importing jax — and keeps a per-file findings cache
 
 Usage:
   python tools/tpu_lint.py                  # human output, exit 0/1
-  python tools/tpu_lint.py --json           # machine output (bench_watch)
+  python tools/tpu_lint.py --json           # machine output
   python tools/tpu_lint.py --changed        # findings in git-changed files only
   python tools/tpu_lint.py --changed=main   # ... changed relative to a ref
   python tools/tpu_lint.py --explain TPL003

@@ -1,7 +1,7 @@
 """Distributed-tracing smoke: the span/fleet plane end to end. Prints
 ONE JSON line; exit 0 iff ok.
 
-The drill behind bench_watch's RED line for the tracing subsystem:
+The drill for the tracing subsystem:
 
 - TTFT decomposition: one traced request through the serving router;
   the queue.wait + prefill.chunk spans must sum to the observed

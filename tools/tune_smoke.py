@@ -1,7 +1,7 @@
 """Autotuner smoke: the cost model's ranking claim on a 3-candidate toy
 space, end to end. Prints ONE JSON line; exit 0 iff ok.
 
-The drill behind bench_watch's RED line for the tuner subsystem:
+The drill for the tuner subsystem:
 - FRESH op measurements (not the pinned baseline — a stale pin would
   let the model agree with itself) feed the analytic cost model, three
   serving candidates are predicted, and every one is measured with
