@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device."""
+from benchmark.lib.xplane import idle_share_percent
+
+
+def read(record):
+    return None if record.trace is None else idle_share_percent(record.trace)

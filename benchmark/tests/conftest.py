@@ -1,0 +1,13 @@
+"""The benchmark's own tests: CPU only, tiny fixtures, no chip numbers.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
+
+They are not part of the repo's tier-1 suite (which runs tests/)."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
