@@ -1,0 +1,8 @@
+"""Share of device busy time that is self time of the one-hot page write of the
+serve tick (scope `cache_write`), and nothing else. The scope of an
+operation is read from the trace (benchmark/lib/program_trace.py)."""
+from benchmark.lib import program_trace
+
+
+def read(record):
+    return program_trace.scope_share(record, "cache_write")

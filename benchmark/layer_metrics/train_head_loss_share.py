@@ -1,0 +1,10 @@
+"""Share of device busy time that is self time of the final norm, the
+vocabulary head and the cross-entropy of the train step, forward and
+backward (scope `head_loss`), on every pipeline stage in every slot, kept or
+masked. The scope of an operation is read from the trace
+(benchmark/lib/program_trace.py)."""
+from benchmark.lib import program_trace
+
+
+def read(record):
+    return program_trace.scope_share(record, "head_loss")

@@ -299,44 +299,48 @@ def _block_sp(x, lp, cfg: L.LlamaConfig, cos, sin, ep_size: int,
     """
     Bm, Tloc, D = x.shape
     hd = cfg.head_dim
-    h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    h_full = lax.all_gather(h, "tp", axis=1, tiled=True)          # SP gather [B, T, D]
-    T = h_full.shape[1]
-    nh_loc = lp["wq"].shape[-1] // hd
-    nkv_loc = lp["wk"].shape[-1] // hd
-    q = (h_full @ lp["wq"].astype(h_full.dtype)).reshape(Bm, T, nh_loc, hd)
-    kk = (h_full @ lp["wk"].astype(h_full.dtype)).reshape(Bm, T, nkv_loc, hd)
-    vv = (h_full @ lp["wv"].astype(h_full.dtype)).reshape(Bm, T, nkv_loc, hd)
-    q = L.apply_rope(q, cos, sin)
-    kk = L.apply_rope(kk, cos, sin)
-    if cp > 1:
-        # context parallelism: T here is the cp-LOCAL sequence; blockwise
-        # ring attention rotates k/v shards over the 'cp' axis (ICI ring)
-        from ..ops.ring_attention import ring_attention_shard
+    # named scopes: forward, jvp and transpose operations of a region carry
+    # its name inside JAX's wrappers, so a trace reader counts them to it
+    with jax.named_scope("attention"):
+        h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+        h_full = lax.all_gather(h, "tp", axis=1, tiled=True)          # SP gather [B, T, D]
+        T = h_full.shape[1]
+        nh_loc = lp["wq"].shape[-1] // hd
+        nkv_loc = lp["wk"].shape[-1] // hd
+        q = (h_full @ lp["wq"].astype(h_full.dtype)).reshape(Bm, T, nh_loc, hd)
+        kk = (h_full @ lp["wk"].astype(h_full.dtype)).reshape(Bm, T, nkv_loc, hd)
+        vv = (h_full @ lp["wv"].astype(h_full.dtype)).reshape(Bm, T, nkv_loc, hd)
+        q = L.apply_rope(q, cos, sin)
+        kk = L.apply_rope(kk, cos, sin)
+        if cp > 1:
+            # context parallelism: T here is the cp-LOCAL sequence; blockwise
+            # ring attention rotates k/v shards over the 'cp' axis (ICI ring)
+            from ..ops.ring_attention import ring_attention_shard
 
-        if attn_impl == "flash":
-            raise ValueError(
-                "attn_impl='flash' cannot be forced on a cp>1 mesh: context "
-                "parallelism uses ring attention over the cp axis (fusing "
-                "Pallas flash inside the ring blocks is a future "
-                "optimization); use attn_impl='auto'")
-        o = ring_attention_shard(q, kk, vv, "cp", causal=True)
-        o = o.astype(h_full.dtype).reshape(Bm, T, nh_loc * hd)
-    else:
-        o = L.attention(q, kk, vv, impl=attn_impl).reshape(Bm, T, nh_loc * hd)
-    partial = o @ lp["wo"].astype(o.dtype)                         # row-parallel partial
-    x = x + lax.psum_scatter(partial, "tp", scatter_dimension=1, tiled=True)
-    h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    h_full = lax.all_gather(h, "tp", axis=1, tiled=True)
-    if cfg.num_experts:
-        y_partial = _moe_ffn(h_full, lp, cfg, ep_size)  # partial over tp
-        x = x + lax.psum_scatter(y_partial, "tp", scatter_dimension=1, tiled=True)
-    else:
-        # column-parallel w1/w3 + row-parallel w2 → the shard's FFN body is
-        # exactly the dense SwiGLU over local f/tp, so the fused Pallas
-        # kernel drops in per-shard, before the tp reduce-scatter
-        partial = L.ffn(h_full, lp, impl=ffn_impl)
+            if attn_impl == "flash":
+                raise ValueError(
+                    "attn_impl='flash' cannot be forced on a cp>1 mesh: context "
+                    "parallelism uses ring attention over the cp axis (fusing "
+                    "Pallas flash inside the ring blocks is a future "
+                    "optimization); use attn_impl='auto'")
+            o = ring_attention_shard(q, kk, vv, "cp", causal=True)
+            o = o.astype(h_full.dtype).reshape(Bm, T, nh_loc * hd)
+        else:
+            o = L.attention(q, kk, vv, impl=attn_impl).reshape(Bm, T, nh_loc * hd)
+        partial = o @ lp["wo"].astype(o.dtype)                         # row-parallel partial
         x = x + lax.psum_scatter(partial, "tp", scatter_dimension=1, tiled=True)
+    with jax.named_scope("ffn"):
+        h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        h_full = lax.all_gather(h, "tp", axis=1, tiled=True)
+        if cfg.num_experts:
+            y_partial = _moe_ffn(h_full, lp, cfg, ep_size)  # partial over tp
+            x = x + lax.psum_scatter(y_partial, "tp", scatter_dimension=1, tiled=True)
+        else:
+            # column-parallel w1/w3 + row-parallel w2 → the shard's FFN body is
+            # exactly the dense SwiGLU over local f/tp, so the fused Pallas
+            # kernel drops in per-shard, before the tp reduce-scatter
+            partial = L.ffn(h_full, lp, impl=ffn_impl)
+            x = x + lax.psum_scatter(partial, "tp", scatter_dimension=1, tiled=True)
     return x
 
 
@@ -364,7 +368,8 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
                 policy=jax.checkpoint_policies.dots_saveable)
         elif remat:
             body = jax.checkpoint(body, prevent_cse=False)
-        x, _ = lax.scan(body, x, blocks_local)
+        with jax.named_scope("layers"):
+            x, _ = lax.scan(body, x, blocks_local)
         return x
 
     def shard_loss(params, tokens, targets):
@@ -385,18 +390,21 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
         vloc = params["lm_head"].shape[1]
 
         def embed_mb(m):
-            x = _vp_embed_lookup(params["embed"], tok_mb[m], cfg)  # [Bm, T/tp, D]
-            return x.astype(cfg.dtype)
+            with jax.named_scope("embed"):
+                x = _vp_embed_lookup(params["embed"], tok_mb[m], cfg)
+                return x.astype(cfg.dtype)            # [Bm, T/tp, D]
 
         def mb_loss(y, m):
             # y [Bm, T/tp, D]: exit the SP region (all_gather seq), then
             # vocab-parallel head + CE over the full sequence. per_tok is
             # replicated over tp; SUM over the microbatch's tokens.
-            h = L.rms_norm(y, params["final_norm"], cfg.rms_eps)
-            h_full = lax.all_gather(h, "tp", axis=1, tiled=True)   # [Bm, T, D]
-            logits = (h_full @ params["lm_head"].astype(h_full.dtype)).astype(jnp.float32)
-            per_tok = _vp_cross_entropy(logits, tgt_mb[m], vloc)
-            return jnp.sum(per_tok)
+            with jax.named_scope("head_loss"):
+                h = L.rms_norm(y, params["final_norm"], cfg.rms_eps)
+                h_full = lax.all_gather(h, "tp", axis=1, tiled=True)  # [Bm, T, D]
+                logits = (h_full @ params["lm_head"].astype(h_full.dtype)
+                          ).astype(jnp.float32)
+                per_tok = _vp_cross_entropy(logits, tgt_mb[m], vloc)
+                return jnp.sum(per_tok)
 
         def pipe_step(carry, t):
             x_in, loss_acc = carry
@@ -408,12 +416,16 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
             lmb = mb_loss(y, m)
             take = active & (stage == pp - 1)
             loss_acc = loss_acc + jnp.where(take, lmb, 0.0)
-            y_send = lax.ppermute(y, "pp", [(i, (i + 1) % pp) for i in range(pp)])
+            with jax.named_scope("pp_send"):
+                y_send = lax.ppermute(
+                    y, "pp", [(i, (i + 1) % pp) for i in range(pp)])
             return (y_send, loss_acc), None
 
         x_init = jnp.zeros((Bm, Tloc, D), cfg.dtype)
-        (_, loss_sum), _ = lax.scan(
-            pipe_step, (x_init, jnp.zeros((), jnp.float32)), jnp.arange(M + pp - 1))
+        with jax.named_scope("pipeline"):
+            (_, loss_sum), _ = lax.scan(
+                pipe_step, (x_init, jnp.zeros((), jnp.float32)),
+                jnp.arange(M + pp - 1))
         # collect from the last stage (pp); already replicated over tp.
         # Normalize to the GLOBAL batch mean: local token count is M*Bm*T, and
         # the extra 1/dp makes the implicit sum over dp ranks a global mean.
@@ -517,17 +529,22 @@ def make_train_step(cfg, mesh: Mesh, num_microbatches: Optional[int] = None,
 
     def per_shard_step(params, opt, tokens, targets):
         loss, grads = jax.value_and_grad(shard_loss)(params, tokens, targets)
-        grads = sync_grads(grads, specs)
-        loss = lax.psum(loss, "dp")  # replicate the global mean for reporting
+        with jax.named_scope("grad_sync"):
+            grads = sync_grads(grads, specs)
+            loss = lax.psum(loss, "dp")  # replicate the global mean for reporting
         # global grad-norm² for clipping: local shards' sq-sums + psum over the
         # axes each leaf is sharded on (replicated leaves are already synced).
-        sq = 0.0
-        for g, s in zip(jax.tree.leaves(grads),
-                        jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))):
-            loc = jnp.sum(g.astype(jnp.float32) ** 2)
-            shard_axes = tuple(a for a in MESH_AXES if a not in _sync_axes(s))
-            sq = sq + (lax.psum(loc, shard_axes) if shard_axes else loc)
-        new_params, new_opt = _adamw_update(params, grads, opt, hp, sq)
+        with jax.named_scope("grad_norm"):
+            sq = 0.0
+            for g, s in zip(jax.tree.leaves(grads),
+                            jax.tree.leaves(
+                                specs, is_leaf=lambda x: isinstance(x, P))):
+                loc = jnp.sum(g.astype(jnp.float32) ** 2)
+                shard_axes = tuple(a for a in MESH_AXES
+                                   if a not in _sync_axes(s))
+                sq = sq + (lax.psum(loc, shard_axes) if shard_axes else loc)
+        with jax.named_scope("adamw"):
+            new_params, new_opt = _adamw_update(params, grads, opt, hp, sq)
         return new_params, new_opt, loss
 
     step = jax.shard_map(

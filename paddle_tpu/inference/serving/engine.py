@@ -341,57 +341,59 @@ class PagedServingEngine:
         router's per-request trace) — rides the Sequence as two host
         ints so every queue-wait/prefill/decode span of this request
         lands in the same trace tree; never touches the jitted step."""
-        tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
-        total = len(tokens) + max(int(max_new_tokens), 0)
-        if total > self.max_len:
-            raise ValueError(f"prompt {len(tokens)} + new {max_new_tokens} "
-                             f"exceeds max_len {self.max_len}")
-        if self.blocks.blocks_needed(total) > self.num_blocks:
-            raise ValueError(
-                f"request needs {self.blocks.blocks_needed(total)} KV "
-                f"blocks but the pool has {self.num_blocks}; raise "
-                f"num_blocks or lower max_new_tokens")
-        if top_k is not None and int(top_k) != self.top_k:
-            raise ValueError(
-                f"per-request top_k={top_k} != engine top_k={self.top_k}: "
-                "top_k is static in the fused step (one executable); build "
-                "the engine with the top_k you serve")
-        rid = self._next_rid
-        self._next_rid += 1
-        self._events_by_rid[rid] = []
-        if max_new_tokens <= 0:   # parity with generate(max_new_tokens=0)
-            self._finish_event(Sequence(rid, tokens, 0), "length")
-            return rid
-        if temperature is None and (self.top_k or top_p is not None):
-            temperature = 1.0      # top-k/top-p imply sampling
-        sample = temperature is not None and float(temperature) > 0.0
-        seq = Sequence(
-            rid, tokens, int(max_new_tokens),
-            eos=-1 if eos_token_id is None else int(eos_token_id),
-            priority=int(priority),
-            deadline=(time.monotonic() + float(deadline_s)
-                      if deadline_s is not None else None),
-            temperature=float(temperature) if sample else 0.0,
-            top_p=float(top_p) if top_p is not None else 1.0,
-            seed=int(seed))
-        if trace is not None:
-            seq.trace_id, seq.parent_span = int(trace[0]), int(trace[1])
-        seq._key = jax.random.PRNGKey(int(seed)) if sample else None
-        if adapter is not None:
-            # pin BEFORE enqueue (AdapterMissingError moves no counts);
-            # unpinned on every completion path via _record_completion
-            self.adapters.pin(adapter)
-            seq.adapter = adapter
-            seq._adapter_pinned = True
-        try:
-            self.scheduler.add_request(seq)   # RejectedError on overflow
-        except BaseException:
+        with _tracing.phase("serve.submit"):
+            tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
+            total = len(tokens) + max(int(max_new_tokens), 0)
+            if total > self.max_len:
+                raise ValueError(
+                    f"prompt {len(tokens)} + new {max_new_tokens} "
+                    f"exceeds max_len {self.max_len}")
+            if self.blocks.blocks_needed(total) > self.num_blocks:
+                raise ValueError(
+                    f"request needs {self.blocks.blocks_needed(total)} KV "
+                    f"blocks but the pool has {self.num_blocks}; raise "
+                    f"num_blocks or lower max_new_tokens")
+            if top_k is not None and int(top_k) != self.top_k:
+                raise ValueError(
+                    f"per-request top_k={top_k} != engine top_k="
+                    f"{self.top_k}: top_k is static in the fused step (one "
+                    "executable); build the engine with the top_k you serve")
+            rid = self._next_rid
+            self._next_rid += 1
+            self._events_by_rid[rid] = []
+            if max_new_tokens <= 0:   # parity with generate(max_new_tokens=0)
+                self._finish_event(Sequence(rid, tokens, 0), "length")
+                return rid
+            if temperature is None and (self.top_k or top_p is not None):
+                temperature = 1.0      # top-k/top-p imply sampling
+            sample = temperature is not None and float(temperature) > 0.0
+            seq = Sequence(
+                rid, tokens, int(max_new_tokens),
+                eos=-1 if eos_token_id is None else int(eos_token_id),
+                priority=int(priority),
+                deadline=(time.monotonic() + float(deadline_s)
+                          if deadline_s is not None else None),
+                temperature=float(temperature) if sample else 0.0,
+                top_p=float(top_p) if top_p is not None else 1.0,
+                seed=int(seed))
+            if trace is not None:
+                seq.trace_id, seq.parent_span = int(trace[0]), int(trace[1])
+            seq._key = jax.random.PRNGKey(int(seed)) if sample else None
             if adapter is not None:
-                seq._adapter_pinned = False
-                self.adapters.unpin(adapter)
-            raise
-        self._update_gauges()
-        return rid
+                # pin BEFORE enqueue (AdapterMissingError moves no counts);
+                # unpinned on every completion path via _record_completion
+                self.adapters.pin(adapter)
+                seq.adapter = adapter
+                seq._adapter_pinned = True
+            try:
+                self.scheduler.add_request(seq)   # RejectedError on overflow
+            except BaseException:
+                if adapter is not None:
+                    seq._adapter_pinned = False
+                    self.adapters.unpin(adapter)
+                raise
+            self._update_gauges()
+            return rid
 
     def cancel(self, rid: int) -> bool:
         seq = self.scheduler.get(rid)
@@ -599,7 +601,13 @@ class PagedServingEngine:
                     block_tables, cu_seqlens_q, seq_lens_decoder,
                     seq_lens_this_time, rope_emb, temps, top_ps, keys,
                     greedy, ad_args):
-            x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+            # named scopes: every device operation of the tick belongs to
+            # a region named here (embed; layers > qkv, cache_write,
+            # paged_attention, attn_out, ffn; head; sample), whatever
+            # number the compiler gives it. Metadata only.
+            with jax.named_scope("embed"):
+                x = jnp.take(params["embed"], tokens,
+                             axis=0).astype(cfg.dtype)
             zeros_b = jnp.zeros((B,), jnp.int32)
             # per-class token->slot scaling selectors (closed over by the
             # scan body — they carry no layer axis)
@@ -632,11 +640,14 @@ class PagedServingEngine:
                                            sel).astype(y.dtype)
                     return y
 
-                h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-                q = lora(h, "wq", Q.matmul_param(h, lp, "wq"))
-                k = lora(h, "wk", Q.matmul_param(h, lp, "wk"))
-                v = lora(h, "wv", Q.matmul_param(h, lp, "wv"))
-                qkv = jnp.concatenate([q, k, v], axis=-1)
+                with jax.named_scope("qkv"):
+                    h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+                    q = lora(h, "wq", Q.matmul_param(h, lp, "wq"))
+                    k = lora(h, "wk", Q.matmul_param(h, lp, "wk"))
+                    v = lora(h, "wv", Q.matmul_param(h, lp, "wv"))
+                    qkv = jnp.concatenate([q, k, v], axis=-1)
+                # scopes itself: qkv (split, rope), cache_write,
+                # paged_attention
                 o, _, kc, vc = block_multihead_attention_.__wrapped__(
                     qkv, kc, vc, zeros_b, seq_lens_decoder,
                     seq_lens_this_time, cu_seqlens_q=cu_seqlens_q,
@@ -646,16 +657,18 @@ class PagedServingEngine:
                     cache_v_dequant_scales=vdq,
                     use_neox_style=True, block_size=bs,
                     rope_theta=cfg.rope_theta, use_pallas=pallas_mode)
-                x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
-                h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-                if ffn_mode:
-                    # one launch: gate+up matmuls, silu·mul, down matmul —
-                    # the d_ff intermediate never leaves VMEM
-                    x = x + FF.apply_ffn(h, lp)
-                else:
-                    gate = (jax.nn.silu(Q.matmul_param(h, lp, "w1"))
-                            * Q.matmul_param(h, lp, "w3"))
-                    x = x + Q.matmul_param(gate, lp, "w2")
+                with jax.named_scope("attn_out"):
+                    x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
+                with jax.named_scope("ffn"):
+                    h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+                    if ffn_mode:
+                        # one launch: gate+up matmuls, silu·mul, down
+                        # matmul — the d_ff intermediate never leaves VMEM
+                        x = x + FF.apply_ffn(h, lp)
+                    else:
+                        gate = (jax.nn.silu(Q.matmul_param(h, lp, "w1"))
+                                * Q.matmul_param(h, lp, "w3"))
+                        x = x + Q.matmul_param(gate, lp, "w2")
                 return x, (kc, vc)
 
             xs = (params["blocks"], key_cache, value_cache)
@@ -663,37 +676,45 @@ class PagedServingEngine:
                 xs = xs + tuple(kv_scales)   # kq, vq [L,KV]; kdq,vdq [L,nb,KV]
             # stacked adapter packs ride the layer scan like param leaves
             xs = xs + tuple(a["packs"] for a in ad_args)
-            x, (kcs, vcs) = lax.scan(body, x, xs)
-            # last-token hidden state per slot (cu[1:]-1; idle slots gather
-            # garbage the host never reads)
-            last_idx = jnp.clip(cu_seqlens_q[1:] - 1, 0, tok_pad - 1)
-            hlast = x[last_idx]                                # [B, d]
-            hlast = L.rms_norm(hlast, params["final_norm"], cfg.rms_eps)
-            logits = Q.matmul_param(hlast, params, "lm_head"
-                                    ).astype(jnp.float32)      # [B, V]
-            if fused_tick and FS.supported(B, logits.shape[-1]):
-                # fused decode tick "+1": argmax + temperature/top-k/top-p
-                # masking in ONE launch; the categorical draw stays outside
-                # on bit-identical masked logits (token parity vs stock)
-                masked, nxt_greedy = FS.fused_sample_prep(
-                    logits, temps, top_ps, top_k)
-                nxt_sampled = jax.vmap(
-                    lambda k_, row: jax.random.categorical(
-                        jax.random.wrap_key_data(k_), row)
-                )(keys, masked).astype(jnp.int32)
-            else:
-                nxt_greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                nxt_sampled = _sample_rows(logits, keys, temps, top_ps,
-                                           top_k)
-            nxt = jnp.where(greedy, nxt_greedy, nxt_sampled)
+            with jax.named_scope("layers"):
+                x, (kcs, vcs) = lax.scan(body, x, xs)
+            with jax.named_scope("head"):
+                # last-token hidden state per slot (cu[1:]-1; idle slots
+                # gather garbage the host never reads)
+                last_idx = jnp.clip(cu_seqlens_q[1:] - 1, 0, tok_pad - 1)
+                hlast = x[last_idx]                                # [B, d]
+                hlast = L.rms_norm(hlast, params["final_norm"], cfg.rms_eps)
+                logits = Q.matmul_param(hlast, params, "lm_head"
+                                        ).astype(jnp.float32)      # [B, V]
+            with jax.named_scope("sample"):
+                if fused_tick and FS.supported(B, logits.shape[-1]):
+                    # fused decode tick "+1": argmax + temperature/top-k/
+                    # top-p masking in ONE launch; the categorical draw
+                    # stays outside on bit-identical masked logits (token
+                    # parity vs stock)
+                    masked, nxt_greedy = FS.fused_sample_prep(
+                        logits, temps, top_ps, top_k)
+                    nxt_sampled = jax.vmap(
+                        lambda k_, row: jax.random.categorical(
+                            jax.random.wrap_key_data(k_), row)
+                    )(keys, masked).astype(jnp.int32)
+                else:
+                    nxt_greedy = jnp.argmax(logits,
+                                            axis=-1).astype(jnp.int32)
+                    nxt_sampled = _sample_rows(logits, keys, temps, top_ps,
+                                               top_k)
+                nxt = jnp.where(greedy, nxt_greedy, nxt_sampled)
             if spec_mode:
                 # the verify read: greedy argmax at EVERY packed row, so
                 # a k+1-wide speculative chunk's per-position targets
                 # come out of this same single launch
-                hall = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
-                all_logits = Q.matmul_param(hall, params, "lm_head"
-                                            ).astype(jnp.float32)
-                all_arg = jnp.argmax(all_logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("head"):
+                    hall = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
+                    all_logits = Q.matmul_param(hall, params, "lm_head"
+                                                ).astype(jnp.float32)
+                with jax.named_scope("sample"):
+                    all_arg = jnp.argmax(all_logits,
+                                         axis=-1).astype(jnp.int32)
                 return nxt, all_arg, kcs, vcs
             return nxt, kcs, vcs
 
@@ -728,20 +749,21 @@ class PagedServingEngine:
                 # When quantized, a page's dequant-scale rows move WITH the
                 # page (per-page layout contract; numerically a no-op while
                 # scales are calibration-static).
-                for i in range(PAD):
-                    s = jnp.maximum(src[i], 0)
-                    sel = (jnp.arange(nb) == dst[i])[None, :, None, None,
-                                                     None]
-                    blk_k = lax.dynamic_slice_in_dim(kc, s, 1, axis=1)
-                    blk_v = lax.dynamic_slice_in_dim(vc, s, 1, axis=1)
-                    kc = jnp.where(sel, blk_k, kc)
-                    vc = jnp.where(sel, blk_v, vc)
-                    if quant_kv:
-                        sel3 = (jnp.arange(nb) == dst[i])[None, :, None]
-                        kdq = jnp.where(sel3, lax.dynamic_slice_in_dim(
-                            kdq, s, 1, axis=1), kdq)
-                        vdq = jnp.where(sel3, lax.dynamic_slice_in_dim(
-                            vdq, s, 1, axis=1), vdq)
+                with jax.named_scope("cow_copy"):
+                    for i in range(PAD):
+                        s = jnp.maximum(src[i], 0)
+                        sel = (jnp.arange(nb) == dst[i])[None, :, None,
+                                                         None, None]
+                        blk_k = lax.dynamic_slice_in_dim(kc, s, 1, axis=1)
+                        blk_v = lax.dynamic_slice_in_dim(vc, s, 1, axis=1)
+                        kc = jnp.where(sel, blk_k, kc)
+                        vc = jnp.where(sel, blk_v, vc)
+                        if quant_kv:
+                            sel3 = (jnp.arange(nb) == dst[i])[None, :, None]
+                            kdq = jnp.where(sel3, lax.dynamic_slice_in_dim(
+                                kdq, s, 1, axis=1), kdq)
+                            vdq = jnp.where(sel3, lax.dynamic_slice_in_dim(
+                                vdq, s, 1, axis=1), vdq)
                 return kc, vc, kdq, vdq
 
             self._copy_fn = copy_fn
@@ -769,243 +791,268 @@ class PagedServingEngine:
     # -- scheduler tick ---------------------------------------------------
     def step(self) -> List[TokenEvent]:
         """One tick: schedule a mixed batch, run the fused step, harvest
-        tokens. Returns this tick's streamed events."""
-        hook = _CHAOS_HOOK[0]
-        if hook is not None:
-            hook("step")
-        batch, expired = self.scheduler.schedule()
-        events: List[TokenEvent] = []
-        for seq in expired:
-            events.append(self._finish_event(seq, "deadline",
-                                             already_finished=True))
-        if not batch:
+        tokens. Returns this tick's streamed events.
+
+        In a ``jax.profiler`` trace the tick is ``ptpu.serve.step`` and
+        its phases (schedule, prepare, dispatch, wait, harvest) are
+        contiguous children, so a step's self time is what no phase
+        covers. The clock readings at the phase boundaries are the ones
+        the ring's cow.copy / prefill.chunk / decode.tick spans get."""
+        with _tracing.phase("serve.step", tick=self.stats["steps"]) as tick:
+            return self._step(tick)
+
+    def _step(self, tick) -> List[TokenEvent]:
+        with _tracing.phase("serve.schedule"):
+            hook = _CHAOS_HOOK[0]
+            if hook is not None:
+                hook("step")
+            batch, expired = self.scheduler.schedule()
+            events: List[TokenEvent] = []
+            for seq in expired:
+                events.append(self._finish_event(seq, "deadline",
+                                                 already_finished=True))
+            if not batch:
+                self._update_gauges()
+                return events
+            pairs = self.blocks.take_copies()
+
+        with _tracing.phase("serve.prepare"):
+            if pairs:
+                t0c = time.perf_counter_ns()
+                self._copy_blocks(pairs)
+                # attribute the COW interval to the first traced request
+                # in the batch (its page appends forced the copies)
+                tseq = next((s for s, _ in batch.items if s.trace_id), None)
+                if tseq is not None:
+                    _tracing.record_span(
+                        "cow.copy", tseq.trace_id, tseq.parent_span, t0c,
+                        (time.perf_counter_ns() - t0c) * 1e-9,
+                        copies=len(pairs), replica=self._trace_replica)
+
+            pallas_mode, pallas_fb = self._resolve_pallas()
+            if pallas_fb is not None:
+                _emit("serving.pallas_fallback", reason=pallas_fb)
+            ffn_mode, ffn_fb = self._resolve_ffn()
+            if ffn_fb is not None:
+                _emit("pallas_ffn.fallback", reason=ffn_fb)
+
+            # adapter residency for this tick: every adapter referenced by the
+            # batch gets a device slot (loading/LRU-swapping as needed). The
+            # chaos "adapter" site drills mid-stream eviction here — a forced
+            # evict simply reloads below, counted as a swap.
+            ad_hook = AD._CHAOS_HOOK[0]
+            active: Dict[str, Tuple[int, int]] = {}
+            for seq, _n in batch.items:
+                name = seq.adapter
+                if name is None or name in active:
+                    continue
+                if (ad_hook is not None
+                        and ad_hook("use", name=name) == "evict"):
+                    self.adapters.evict_device(name, why="chaos")
+                active[name] = self.adapters.ensure_loaded(name)
+            ad_sig = tuple(sorted({cls for cls, _ in active.values()}))
+
+            # speculative plan: widen each greedy decode-ready chunk by k
+            # draft tokens (inside the token budget and the block pool), so
+            # the ONE fused step below verifies the whole proposal
+            spec_plan: Dict[int, List[int]] = {}
+            if self.spec is not None and self.spec_k > 0:
+                budget_left = self.token_budget - batch.total_tokens
+                for i, (seq, n) in enumerate(batch.items):
+                    if budget_left < 1:
+                        break
+                    if (n != 1 or seq.temperature > 0.0
+                            or seq.num_computed + 1 != len(seq.tokens)):
+                        continue
+                    k_eff = min(self.spec_k, budget_left,
+                                seq.max_new_tokens - len(seq.generated) - 1)
+                    if k_eff < 1:
+                        continue
+                    try:
+                        self.blocks.ensure_capacity(
+                            seq.rid, len(seq.tokens) + k_eff)
+                    except NoFreeBlocksError:
+                        continue   # pool exhausted: this tick unspeculated
+                    spec_plan[i] = self.spec.propose(seq, k_eff)
+                    budget_left -= k_eff
+            spec_mode = bool(spec_plan)
+
+            tok_pad, B = self.token_budget, self.max_batch
+            if (pallas_mode and not spec_plan
+                    and all(n == 1 for _, n in batch.items)):
+                # decode fast path: every scheduled chunk is one token, so the
+                # step packs [max_batch] tokens instead of [token_budget] and
+                # the kernel runs its max_q=1 specialized launch — the
+                # steady-state executable (built once; the MPK-style single
+                # launch per decode step)
+                pallas_mode = "decode"
+                tok_pad = B
+            tokens = np.zeros((tok_pad,), np.int32)
+            cu = np.zeros((B + 1,), np.int32)
+            dec_lens = np.zeros((B,), np.int32)
+            this_lens = np.zeros((B,), np.int32)
+            tables = np.full((B, self.max_blocks_per_seq), -1, np.int32)
+            temps = np.ones((B,), np.float32)
+            top_ps = np.ones((B,), np.float32)
+            keys = np.zeros((B, 2), np.uint32)
+            greedy = np.ones((B,), bool)
+            pos = 0
+            for i, (seq, n) in enumerate(batch.items):
+                chunk = seq.tokens[seq.num_computed:seq.num_computed + n]
+                props = spec_plan.get(i)
+                if props is not None:
+                    chunk = list(chunk) + props   # [t_c, d1..dk]: verify rows
+                    n = len(chunk)
+                tokens[pos:pos + n] = chunk
+                pos += n
+                cu[i + 1] = pos
+                dec_lens[i] = seq.num_computed
+                this_lens[i] = n
+                row = self.blocks.block_table(seq.rid)
+                tables[i, :len(row)] = row
+                if seq.temperature > 0.0:
+                    greedy[i] = False
+                    temps[i] = seq.temperature
+                    top_ps[i] = seq.top_p
+                    seq._key, sub = jax.random.split(seq._key)
+                    keys[i] = _key_bits(sub)
+            cu[len(batch.items) + 1:] = pos
+
+            # per-class [tok_pad, slots] selectors: each adapter-bound chunk's
+            # rows carry its slot's alpha/rank scaling; everything else is 0.0
+            ad_args: Tuple[Any, ...] = ()
+            if ad_sig:
+                sels = {cls: np.zeros((tok_pad, self.adapters.slots),
+                                      np.float32) for cls in ad_sig}
+                for i, (seq, _n) in enumerate(batch.items):
+                    name = seq.adapter
+                    if name is None:
+                        continue
+                    cls, slot = active[name]
+                    sels[cls][cu[i]:cu[i + 1], slot] = \
+                        self.adapters.get(name).scaling
+                ad_args = tuple({"sel": jnp.asarray(sels[cls]),
+                                 "packs": self.adapters.device_packs(cls)}
+                                for cls in ad_sig)
+
+            # tick classification per request, snapshotted BEFORE the device
+            # step mutates generated: a request mid-prompt is in a prefill
+            # chunk; one with tokens out is in a decode tick
+            was_decode = [bool(s.generated) for s, _ in batch.items]
+
+        with _tracing.phase("serve.dispatch"):
+            t0 = time.perf_counter_ns()
+            builds0 = self.stats["step_builds"]
+            fn = self._get_step_fn(tok_pad, B, pallas_mode, ffn_mode,
+                                   ad_sig, spec_mode)
+            fused_tick = bool(ffn_mode) and pallas_mode == "decode"
+            launches0 = FA.trace_launches()
+            out = fn(
+                self.params, self._key_cache, self._value_cache,
+                self._kv_scales, jnp.asarray(tokens), jnp.asarray(tables),
+                jnp.asarray(cu), jnp.asarray(dec_lens), jnp.asarray(this_lens),
+                self._rope_emb, jnp.asarray(temps), jnp.asarray(top_ps),
+                jnp.asarray(keys), jnp.asarray(greedy), ad_args)
+
+        with _tracing.phase("serve.wait"):
+            all_arg = None
+            if spec_mode:
+                nxt, all_arg, self._key_cache, self._value_cache = out
+                all_arg = np.asarray(all_arg)
+            else:
+                nxt, self._key_cache, self._value_cache = out
+            nxt = np.asarray(nxt)     # the step's one sync point
+            dur = (time.perf_counter_ns() - t0) * 1e-9
+
+        with _tracing.phase("serve.harvest"):
+            if fused_tick and self.stats["step_builds"] > builds0:
+                # fresh trace: the launch-counter delta counts the DISTINCT
+                # Pallas launches traced into this tick's executable (the
+                # layer scan body is traced once, so per-layer kernels count
+                # once — paged attention + fused FFN + the sampler prep).
+                # Steady-state ticks re-run the same executable, so the count
+                # holds for every subsequent tick.
+                self.stats["tick_pallas_launches"] = (FA.trace_launches()
+                                                      - launches0)
+            n_prefill = sum(n for s, n in batch.items
+                            if s.num_computed + n < len(s.tokens))
+            spec_extra = sum(len(p) for p in spec_plan.values())
+            _emit("serving.step", dur_s=dur,
+                  tokens=batch.total_tokens + spec_extra,
+                  batch=len(batch.items), prefill_tokens=n_prefill)
+            tick.set_metadata(
+                batch=len(batch.items),
+                tokens=batch.total_tokens + spec_extra,
+                prefill_tokens=n_prefill,
+                kind="decode" if pallas_mode == "decode" else "mixed")
+            if _tracing.trace_enabled():
+                # per-request tick attribution: each traced request in the
+                # batch gets a span over this tick's device interval, so a
+                # request's TTFT decomposes into queue.wait + its prefill
+                # chunks (+ cow copies) and TPOT into decode ticks
+                for (seq, n), dec in zip(batch.items, was_decode):
+                    if seq.trace_id:
+                        _tracing.record_span(
+                            "decode.tick" if dec else "prefill.chunk",
+                            seq.trace_id, seq.parent_span, t0, dur,
+                            rid=seq.rid, tokens=n,
+                            replica=self._trace_replica)
+            if pallas_mode:
+                kind = "decode" if pallas_mode == "decode" else "mixed"
+                self.stats["pallas_steps"] += 1
+                if kind == "decode":
+                    self.stats["decode_fast_steps"] += 1
+                _emit("serving.pallas_step", launch=kind)
+            if ffn_mode:
+                self.stats["ffn_steps"] += 1
+                if fused_tick:
+                    self.stats["fused_ticks"] += 1
+                _emit("pallas_ffn.step",
+                      launch="fused_tick" if fused_tick else "serving")
+            if self.quant_kv:
+                _emit("quant.kv_step",
+                      tokens=batch.total_tokens * self.cfg.num_layers,
+                      pages=int((tables >= 0).sum()) * self.cfg.num_layers)
+            self.stats["steps"] += 1
+            self.stats["tokens_computed"] += batch.total_tokens + spec_extra
+
+            # harvest: a slot yields a token iff its chunk reached the end of
+            # the sequence's current tokens (final prefill chunk or decode row)
+            for i, (seq, n) in enumerate(batch.items):
+                props = spec_plan.get(i)
+                if props is not None:
+                    events.extend(self._harvest_spec(seq, props, int(cu[i]),
+                                                     all_arg))
+                    continue
+                self.scheduler.on_computed(seq, n)
+                if seq.num_computed < len(seq.tokens):
+                    continue   # mid-prefill: logits row is not a next token
+                tok = int(nxt[i])
+                # token stamps stay on the scheduler's clock (arrival and
+                # deadlines are time.monotonic()), not on the span clock
+                now = time.monotonic()
+                first = seq.first_token_at is None
+                if seq.eos >= 0 and tok == seq.eos:
+                    self.scheduler.append_token(seq, tok)  # timestamps
+                    seq.generated.pop()                    # eos not surfaced
+                    seq.tokens.pop()
+                    events.append(self._finish_event(seq, "stop"))
+                    continue
+                self.scheduler.append_token(seq, tok)
+                _emit("serving.token", rid=seq.rid, first=first,
+                      ttft_s=(now - seq.arrival) if first else None,
+                      tpot_s=None if first else now - seq._prev_token_at)
+                seq._prev_token_at = now
+                if len(seq.generated) >= seq.max_new_tokens:
+                    ev = TokenEvent(seq.rid, tok, True, "length")
+                    self._record_completion(seq, "length")
+                    self.scheduler.finish(seq, "length")
+                else:
+                    ev = TokenEvent(seq.rid, tok, False)
+                events.append(ev)
+                self._events_by_rid[seq.rid].append(ev)
             self._update_gauges()
             return events
-
-        pairs = self.blocks.take_copies()
-        if pairs:
-            t0c = time.perf_counter()
-            self._copy_blocks(pairs)
-            # attribute the COW interval to the first traced request in
-            # the batch (its page appends are what forced the copies)
-            tseq = next((s for s, _ in batch.items if s.trace_id), None)
-            if tseq is not None:
-                _tracing.record_span(
-                    "cow.copy", tseq.trace_id, tseq.parent_span,
-                    int(t0c * 1e9), time.perf_counter() - t0c,
-                    copies=len(pairs), replica=self._trace_replica)
-
-        pallas_mode, pallas_fb = self._resolve_pallas()
-        if pallas_fb is not None:
-            _emit("serving.pallas_fallback", reason=pallas_fb)
-        ffn_mode, ffn_fb = self._resolve_ffn()
-        if ffn_fb is not None:
-            _emit("pallas_ffn.fallback", reason=ffn_fb)
-
-        # adapter residency for this tick: every adapter referenced by the
-        # batch gets a device slot (loading/LRU-swapping as needed). The
-        # chaos "adapter" site drills mid-stream eviction here — a forced
-        # evict simply reloads below, counted as a swap.
-        ad_hook = AD._CHAOS_HOOK[0]
-        active: Dict[str, Tuple[int, int]] = {}
-        for seq, _n in batch.items:
-            name = seq.adapter
-            if name is None or name in active:
-                continue
-            if ad_hook is not None and ad_hook("use", name=name) == "evict":
-                self.adapters.evict_device(name, why="chaos")
-            active[name] = self.adapters.ensure_loaded(name)
-        ad_sig = tuple(sorted({cls for cls, _ in active.values()}))
-
-        # speculative plan: widen each greedy decode-ready chunk by k
-        # draft tokens (inside the token budget and the block pool), so
-        # the ONE fused step below verifies the whole proposal
-        spec_plan: Dict[int, List[int]] = {}
-        if self.spec is not None and self.spec_k > 0:
-            budget_left = self.token_budget - batch.total_tokens
-            for i, (seq, n) in enumerate(batch.items):
-                if budget_left < 1:
-                    break
-                if (n != 1 or seq.temperature > 0.0
-                        or seq.num_computed + 1 != len(seq.tokens)):
-                    continue
-                k_eff = min(self.spec_k, budget_left,
-                            seq.max_new_tokens - len(seq.generated) - 1)
-                if k_eff < 1:
-                    continue
-                try:
-                    self.blocks.ensure_capacity(
-                        seq.rid, len(seq.tokens) + k_eff)
-                except NoFreeBlocksError:
-                    continue   # pool exhausted: this tick unspeculated
-                spec_plan[i] = self.spec.propose(seq, k_eff)
-                budget_left -= k_eff
-        spec_mode = bool(spec_plan)
-
-        tok_pad, B = self.token_budget, self.max_batch
-        if (pallas_mode and not spec_plan
-                and all(n == 1 for _, n in batch.items)):
-            # decode fast path: every scheduled chunk is one token, so the
-            # step packs [max_batch] tokens instead of [token_budget] and
-            # the kernel runs its max_q=1 specialized launch — the
-            # steady-state executable (built once; the MPK-style single
-            # launch per decode step)
-            pallas_mode = "decode"
-            tok_pad = B
-        tokens = np.zeros((tok_pad,), np.int32)
-        cu = np.zeros((B + 1,), np.int32)
-        dec_lens = np.zeros((B,), np.int32)
-        this_lens = np.zeros((B,), np.int32)
-        tables = np.full((B, self.max_blocks_per_seq), -1, np.int32)
-        temps = np.ones((B,), np.float32)
-        top_ps = np.ones((B,), np.float32)
-        keys = np.zeros((B, 2), np.uint32)
-        greedy = np.ones((B,), bool)
-        pos = 0
-        for i, (seq, n) in enumerate(batch.items):
-            chunk = seq.tokens[seq.num_computed:seq.num_computed + n]
-            props = spec_plan.get(i)
-            if props is not None:
-                chunk = list(chunk) + props   # [t_c, d1..dk]: verify rows
-                n = len(chunk)
-            tokens[pos:pos + n] = chunk
-            pos += n
-            cu[i + 1] = pos
-            dec_lens[i] = seq.num_computed
-            this_lens[i] = n
-            row = self.blocks.block_table(seq.rid)
-            tables[i, :len(row)] = row
-            if seq.temperature > 0.0:
-                greedy[i] = False
-                temps[i] = seq.temperature
-                top_ps[i] = seq.top_p
-                seq._key, sub = jax.random.split(seq._key)
-                keys[i] = _key_bits(sub)
-        cu[len(batch.items) + 1:] = pos
-
-        # per-class [tok_pad, slots] selectors: each adapter-bound chunk's
-        # rows carry its slot's alpha/rank scaling; everything else is 0.0
-        ad_args: Tuple[Any, ...] = ()
-        if ad_sig:
-            sels = {cls: np.zeros((tok_pad, self.adapters.slots),
-                                  np.float32) for cls in ad_sig}
-            for i, (seq, _n) in enumerate(batch.items):
-                name = seq.adapter
-                if name is None:
-                    continue
-                cls, slot = active[name]
-                sels[cls][cu[i]:cu[i + 1], slot] = \
-                    self.adapters.get(name).scaling
-            ad_args = tuple({"sel": jnp.asarray(sels[cls]),
-                             "packs": self.adapters.device_packs(cls)}
-                            for cls in ad_sig)
-
-        # tick classification per request, snapshotted BEFORE the device
-        # step mutates generated: a request mid-prompt is in a prefill
-        # chunk; one with tokens out is in a decode tick
-        was_decode = [bool(s.generated) for s, _ in batch.items]
-        builds0 = self.stats["step_builds"]
-        fn = self._get_step_fn(tok_pad, B, pallas_mode, ffn_mode,
-                               ad_sig, spec_mode)
-        fused_tick = bool(ffn_mode) and pallas_mode == "decode"
-        launches0 = FA.trace_launches()
-        t0 = time.perf_counter()
-        out = fn(
-            self.params, self._key_cache, self._value_cache,
-            self._kv_scales, jnp.asarray(tokens), jnp.asarray(tables),
-            jnp.asarray(cu), jnp.asarray(dec_lens), jnp.asarray(this_lens),
-            self._rope_emb, jnp.asarray(temps), jnp.asarray(top_ps),
-            jnp.asarray(keys), jnp.asarray(greedy), ad_args)
-        all_arg = None
-        if spec_mode:
-            nxt, all_arg, self._key_cache, self._value_cache = out
-            all_arg = np.asarray(all_arg)
-        else:
-            nxt, self._key_cache, self._value_cache = out
-        nxt = np.asarray(nxt)     # the step's one sync point
-        dur = time.perf_counter() - t0
-        if fused_tick and self.stats["step_builds"] > builds0:
-            # fresh trace: the launch-counter delta counts the DISTINCT
-            # Pallas launches traced into this tick's executable (the
-            # layer scan body is traced once, so per-layer kernels count
-            # once — paged attention + fused FFN + the sampler prep).
-            # Steady-state ticks re-run the same executable, so the count
-            # holds for every subsequent tick.
-            self.stats["tick_pallas_launches"] = (FA.trace_launches()
-                                                  - launches0)
-        n_prefill = sum(n for s, n in batch.items
-                        if s.num_computed + n < len(s.tokens))
-        spec_extra = sum(len(p) for p in spec_plan.values())
-        _emit("serving.step", dur_s=dur,
-              tokens=batch.total_tokens + spec_extra,
-              batch=len(batch.items), prefill_tokens=n_prefill)
-        if _tracing.trace_enabled():
-            # per-request tick attribution: each traced request in the
-            # batch gets a span over this tick's device interval, so a
-            # request's TTFT decomposes into queue.wait + its prefill
-            # chunks (+ cow copies) and TPOT into decode ticks
-            step_t0_ns = int(t0 * 1e9)
-            for (seq, n), dec in zip(batch.items, was_decode):
-                if seq.trace_id:
-                    _tracing.record_span(
-                        "decode.tick" if dec else "prefill.chunk",
-                        seq.trace_id, seq.parent_span, step_t0_ns, dur,
-                        rid=seq.rid, tokens=n,
-                        replica=self._trace_replica)
-        if pallas_mode:
-            kind = "decode" if pallas_mode == "decode" else "mixed"
-            self.stats["pallas_steps"] += 1
-            if kind == "decode":
-                self.stats["decode_fast_steps"] += 1
-            _emit("serving.pallas_step", launch=kind)
-        if ffn_mode:
-            self.stats["ffn_steps"] += 1
-            if fused_tick:
-                self.stats["fused_ticks"] += 1
-            _emit("pallas_ffn.step",
-                  launch="fused_tick" if fused_tick else "serving")
-        if self.quant_kv:
-            _emit("quant.kv_step",
-                  tokens=batch.total_tokens * self.cfg.num_layers,
-                  pages=int((tables >= 0).sum()) * self.cfg.num_layers)
-        self.stats["steps"] += 1
-        self.stats["tokens_computed"] += batch.total_tokens + spec_extra
-
-        # harvest: a slot yields a token iff its chunk reached the end of
-        # the sequence's current tokens (final prefill chunk or decode row)
-        for i, (seq, n) in enumerate(batch.items):
-            props = spec_plan.get(i)
-            if props is not None:
-                events.extend(self._harvest_spec(seq, props, int(cu[i]),
-                                                 all_arg))
-                continue
-            self.scheduler.on_computed(seq, n)
-            if seq.num_computed < len(seq.tokens):
-                continue   # mid-prefill: logits row is not a next token
-            tok = int(nxt[i])
-            now = time.monotonic()
-            first = seq.first_token_at is None
-            if seq.eos >= 0 and tok == seq.eos:
-                self.scheduler.append_token(seq, tok)  # timestamps
-                seq.generated.pop()                    # eos not surfaced
-                seq.tokens.pop()
-                events.append(self._finish_event(seq, "stop"))
-                continue
-            self.scheduler.append_token(seq, tok)
-            _emit("serving.token", rid=seq.rid, first=first,
-                  ttft_s=(now - seq.arrival) if first else None,
-                  tpot_s=None if first else now - seq._prev_token_at)
-            seq._prev_token_at = now
-            if len(seq.generated) >= seq.max_new_tokens:
-                ev = TokenEvent(seq.rid, tok, True, "length")
-                self._record_completion(seq, "length")
-                self.scheduler.finish(seq, "length")
-            else:
-                ev = TokenEvent(seq.rid, tok, False)
-            events.append(ev)
-            self._events_by_rid[seq.rid].append(ev)
-        self._update_gauges()
-        return events
 
     def _harvest_spec(self, seq: Sequence, props: List[int], base: int,
                       all_arg: np.ndarray) -> List[TokenEvent]:

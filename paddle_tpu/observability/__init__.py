@@ -384,8 +384,6 @@ _h_tr_span = _H("paddle_trace_span_seconds",
 _g_tr_active = _G("paddle_trace_active_spans",
                   "Spans currently open on this process (in-flight "
                   "requests/steps land in distress dumps from here)")
-_c_tr_clock = _C("paddle_trace_clock_handshakes_total",
-                 "Store-based clock-offset handshakes completed")
 _c_fl_pub = _C("paddle_fleet_publishes_total",
                "Registry snapshots published to the fleet metrics plane")
 _h_fl_pub = _H("paddle_fleet_publish_seconds",
@@ -815,7 +813,6 @@ _HANDLERS = {
     "quant.manifest_load": lambda d, f: _c_q_manifest.inc(
         labels={"result": f.get("result", "")}),
     "trace.span": _h_trace_span,
-    "trace.clock": lambda d, f: _c_tr_clock.inc(),
     "fleet.publish": lambda d, f: (_c_fl_pub.inc(),
                                    _h_fl_pub.observe(d)
                                    if d is not None else None),

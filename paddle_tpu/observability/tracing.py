@@ -19,13 +19,13 @@ Design constraints, in order:
   ring); the hot-path budget gated by ci_op_benchmark is unchanged
   because span starts/ends happen at request/tick/action frequency,
   never per dispatched eager op.
-- **Merge-able across ranks.**  Span timestamps are
-  ``time.perf_counter_ns()`` (monotonic, process-local).
-  :func:`clock_handshake` publishes each rank's wall-vs-perf anchor
-  over the TCPStore and returns the per-rank offset that maps local
-  perf timestamps onto the fleet-shared wall axis;
-  :func:`merge_chrome_traces` then folds per-rank exports into one
-  ``chrome://tracing`` document.
+- **One timeline with the device in it.**  Ring spans are stamped with
+  ``time.perf_counter_ns()`` (monotonic, process-local).  :func:`phase`
+  names the host's work as a ``jax.profiler.TraceAnnotation`` (prefix
+  ``ptpu.``), so a ``jax.profiler`` trace holds the host phases and the
+  device's operations on one axis; :func:`clock_anchor` writes one
+  ``ptpu.clock`` annotation carrying ``perf_counter_ns()``, from which
+  a reader gets the offset that lays the ring's spans over that axis.
 
 Spans form a tree per trace: the serving root span ("request") parents
 queue.wait / prefill.chunk / decode.tick / cow.copy / failover.replay;
@@ -38,15 +38,17 @@ import itertools
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
+
+import jax
 
 from ..core import flags
 
 __all__ = [
     "Span", "trace_enabled", "new_trace", "start_span", "end_span",
     "record_span", "span", "active_spans", "active_tree", "finished_spans",
-    "to_chrome_trace", "merge_chrome_traces", "clock_handshake",
-    "clock_offset_ns", "measured_schedule_stats", "reset",
+    "to_chrome_trace", "phase", "clock_anchor", "PHASE_PREFIX",
+    "measured_schedule_stats", "reset",
 ]
 
 flags.define_flag("trace_spans", True,
@@ -64,8 +66,6 @@ _lock = threading.Lock()
 _ids = itertools.count(1)
 _active: Dict[int, "Span"] = {}
 _finished: deque = deque(maxlen=max(1, int(flags.flag_value("trace_buffer_size"))))
-# wall-axis mapping installed by clock_handshake: perf_ns + offset -> wall ns
-_clock = {"offset_ns": 0, "rank": 0, "rtt_ns": 0, "handshaken": False}
 
 
 def _on_flag_change(name, value):
@@ -235,15 +235,12 @@ def finished_spans(trace_id: Optional[int] = None,
             and (name is None or sp.name == name)]
 
 
-def to_chrome_trace(pid=None, offset_ns: Optional[int] = None,
+def to_chrome_trace(pid="paddle_tpu", offset_ns: int = 0,
                     include_active: bool = False) -> dict:
-    """Finished spans as a chrome://tracing document. ``offset_ns``
-    defaults to this process's handshaken clock offset so per-rank
-    exports land on the shared wall axis; tid groups spans by trace."""
-    if offset_ns is None:
-        offset_ns = _clock["offset_ns"]
-    if pid is None:
-        pid = f"rank{_clock['rank']}" if _clock["handshaken"] else "paddle_tpu"
+    """Finished spans as a chrome://tracing document (distress dumps
+    read it). ``offset_ns`` shifts the ring's ``perf_counter_ns`` stamps,
+    e.g. by the offset a ``ptpu.clock`` anchor gives onto a profile's
+    axis; tid groups spans by trace."""
     with _lock:
         spans = list(_finished)
         if include_active:
@@ -261,70 +258,34 @@ def to_chrome_trace(pid=None, offset_ns: Optional[int] = None,
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
-def merge_chrome_traces(parts) -> dict:
-    """Fold per-rank chrome-trace documents into one.
-
-    ``parts``: iterable of either a document dict (already on the shared
-    axis) or a ``(doc, offset_ns)`` / ``(doc, offset_ns, pid)`` tuple —
-    the offset from that rank's :func:`clock_handshake`, applied here
-    when the exporting process could not apply it itself."""
-    merged: List[dict] = []
-    for part in parts:
-        pid = None
-        off = 0
-        if isinstance(part, tuple):
-            doc = part[0]
-            off = part[1] if len(part) > 1 else 0
-            pid = part[2] if len(part) > 2 else None
-        else:
-            doc = part
-        for ev in doc.get("traceEvents", []):
-            ev = dict(ev)
-            if off:
-                ev["ts"] = ev.get("ts", 0.0) + off / 1e3
-            if pid is not None:
-                ev["pid"] = pid
-            merged.append(ev)
-    merged.sort(key=lambda e: e.get("ts", 0.0))
-    return {"traceEvents": merged, "displayTimeUnit": "ms"}
-
-
 # ---------------------------------------------------------------------------
-# Store-based clock-offset handshake
+# Phases on the profiler's clock
 # ---------------------------------------------------------------------------
 
-def clock_offset_ns() -> int:
-    return _clock["offset_ns"]
+PHASE_PREFIX = "ptpu."
 
 
-def clock_handshake(store, rank: int,
-                    key_prefix: str = "paddle_trace/clock") -> int:
-    """Agree on a shared trace time axis across ranks via the TCPStore.
+def phase(name: str, **fields):
+    """``with tracing.phase("serve.schedule", tick=n): ...`` names a
+    stretch of host work as the ``jax.profiler.TraceAnnotation``
+    ``ptpu.<name>``, ``fields`` as its metadata, and nothing else: no
+    lock, no ``emit``, no ring write. Outside a profiler session a
+    TraceAnnotation is a flag test, so "off" is the default state."""
+    return jax.profiler.TraceAnnotation(PHASE_PREFIX + name, **fields)
 
-    Every rank publishes its wall-vs-monotonic anchor
-    ``time.time_ns() - perf_counter_ns()`` under ``{key_prefix}/{rank}``
-    and reads rank 0's (blocking until rank 0 has published).  The
-    returned offset maps this rank's ``perf_counter_ns`` span stamps
-    onto rank 0's wall axis; a store round trip is timed and half the
-    RTT recorded as the residual uncertainty of the merge.  Wall-clock
-    skew between hosts beyond NTP is accepted as-is — the handshake
-    removes the (unbounded) monotonic-epoch difference, which is what
-    actually breaks naive merges."""
-    local_anchor = time.time_ns() - time.perf_counter_ns()
-    t0 = time.perf_counter_ns()
-    store.set(f"{key_prefix}/{rank}", str(local_anchor))
-    rtt_ns = time.perf_counter_ns() - t0
-    anchor0 = int(store.get(f"{key_prefix}/0"))
-    # perf_ns + local_anchor = local wall ~= shared wall; the anchor gap
-    # vs rank 0 is the monotonic-epoch difference (boot-time offset) the
-    # handshake exists to remove from merged timelines.
-    offset_ns = local_anchor
-    _clock.update(offset_ns=offset_ns, rank=rank, rtt_ns=rtt_ns,
-                  handshaken=True)
-    from . import emit as _emit
-    _emit("trace.clock", rank=rank, rtt_ns=rtt_ns,
-          anchor_gap_ns=local_anchor - anchor0)
-    return offset_ns
+
+def clock_anchor() -> int:
+    """Emit one ``ptpu.clock`` annotation carrying
+    ``perf_ns=time.perf_counter_ns()``. A reader of the profile subtracts
+    that from the event's start on the profile's axis and has the offset
+    that maps every ring span (``queue.wait``, ``prefill.chunk``,
+    ``decode.tick``, ``cow.copy``, ``failover.replay``, ``pp.*``) onto
+    it, whichever clock the profiler uses. Returns the stamp."""
+    perf_ns = time.perf_counter_ns()
+    with jax.profiler.TraceAnnotation(PHASE_PREFIX + "clock",
+                                      perf_ns=perf_ns):
+        pass
+    return perf_ns
 
 
 # ---------------------------------------------------------------------------
@@ -365,13 +326,12 @@ def measured_schedule_stats(timeline, stages: int, groups: int = 0) -> dict:
 
 
 def reset():
-    """Drop all span state and the clock handshake (test isolation)."""
+    """Drop all span state (test isolation)."""
     global _ids
     with _lock:
         _active.clear()
         _finished.clear()
     _ids = itertools.count(1)
-    _clock.update(offset_ns=0, rank=0, rtt_ns=0, handshaken=False)
 
 
 def install() -> None:

@@ -486,141 +486,148 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
             f"hd={hd} block_size={bs} is not supported() by the pallas "
             f"paged-attention kernel")
 
-    qkv3 = qkv.reshape(token_num, H + 2 * KV, hd)
-    if qkv_bias is not None:
-        qkv3 = qkv3 + qkv_bias.reshape(1, H + 2 * KV, hd).astype(qkv3.dtype)
-    q_tok, k_tok, v_tok = (qkv3[:, :H], qkv3[:, H:H + KV],
-                           qkv3[:, H + KV:])          # [tok, H/KV, hd]
+    # named scopes (jax.named_scope): the device operations of this op
+    # belong to `qkv` (split, bias, rope, token indices), `cache_write`
+    # (the one-hot page write only) or `paged_attention` (the read:
+    # the Pallas launch or the stock gather path)
+    with jax.named_scope("qkv"):
+        qkv3 = qkv.reshape(token_num, H + 2 * KV, hd)
+        if qkv_bias is not None:
+            qkv3 = qkv3 + qkv_bias.reshape(1, H + 2 * KV, hd).astype(qkv3.dtype)
+        q_tok, k_tok, v_tok = (qkv3[:, :H], qkv3[:, H:H + KV],
+                               qkv3[:, H + KV:])          # [tok, H/KV, hd]
 
-    cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
-    tok_idx = jnp.arange(token_num, dtype=jnp.int32)
-    tok_b = jnp.clip(jnp.searchsorted(cu, tok_idx, side="right") - 1, 0, B - 1)
-    tok_local = tok_idx - cu[tok_b]
-    past = seq_lens_decoder.reshape(-1).astype(jnp.int32)    # [B]
-    this = seq_lens_this_time.reshape(-1).astype(jnp.int32)  # [B]
-    tok_pos = past[tok_b] + tok_local                        # absolute pos
-    tok_valid = tok_local < this[tok_b]
+        cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
+        tok_idx = jnp.arange(token_num, dtype=jnp.int32)
+        tok_b = jnp.clip(jnp.searchsorted(cu, tok_idx, side="right") - 1, 0, B - 1)
+        tok_local = tok_idx - cu[tok_b]
+        past = seq_lens_decoder.reshape(-1).astype(jnp.int32)    # [B]
+        this = seq_lens_this_time.reshape(-1).astype(jnp.int32)  # [B]
+        tok_pos = past[tok_b] + tok_local                        # absolute pos
+        tok_valid = tok_local < this[tok_b]
 
-    if rope_emb is not None:
-        cos_t, sin_t = _rotary_table(rope_emb, hd)           # [Br, S, hd//2]
-        tb = jnp.zeros_like(tok_b) if cos_t.shape[0] == 1 else tok_b
-        cos = cos_t[tb, tok_pos]                             # [tok, hd//2]
-        sin = sin_t[tb, tok_pos]
-        q_tok = _rope_pairwise(q_tok, cos[:, None], sin[:, None], use_neox_style)
-        k_tok = _rope_pairwise(k_tok, cos[:, None], sin[:, None], use_neox_style)
+        if rope_emb is not None:
+            cos_t, sin_t = _rotary_table(rope_emb, hd)           # [Br, S, hd//2]
+            tb = jnp.zeros_like(tok_b) if cos_t.shape[0] == 1 else tok_b
+            cos = cos_t[tb, tok_pos]                             # [tok, hd//2]
+            sin = sin_t[tb, tok_pos]
+            q_tok = _rope_pairwise(q_tok, cos[:, None], sin[:, None], use_neox_style)
+            k_tok = _rope_pairwise(k_tok, cos[:, None], sin[:, None], use_neox_style)
 
-    # ---- quantize-on-append: per-head static multipliers, round+clip to
-    # the int8 page dtype. Quantization is per-token VALUE-based (no
-    # dependence on which chunk wrote the token), so a preemption resume
-    # that re-prefills with different chunk boundaries reproduces the
-    # int8 pages bit-for-bit.
-    if kv_quant:
-        kqs = cache_k_quant_scales.astype(jnp.float32).reshape(1, KV, 1)
-        vqs = cache_v_quant_scales.astype(jnp.float32).reshape(1, KV, 1)
-        k_store = jnp.clip(jnp.round(k_tok.astype(jnp.float32) * kqs),
-                           quant_min_bound, quant_max_bound).astype(jnp.int8)
-        v_store = jnp.clip(jnp.round(v_tok.astype(jnp.float32) * vqs),
-                           quant_min_bound, quant_max_bound).astype(jnp.int8)
-    else:
-        k_store, v_store = k_tok, v_tok
+        # ---- quantize-on-append: per-head static multipliers, round+clip to
+        # the int8 page dtype. Quantization is per-token VALUE-based (no
+        # dependence on which chunk wrote the token), so a preemption resume
+        # that re-prefills with different chunk boundaries reproduces the
+        # int8 pages bit-for-bit.
+        if kv_quant:
+            kqs = cache_k_quant_scales.astype(jnp.float32).reshape(1, KV, 1)
+            vqs = cache_v_quant_scales.astype(jnp.float32).reshape(1, KV, 1)
+            k_store = jnp.clip(jnp.round(k_tok.astype(jnp.float32) * kqs),
+                               quant_min_bound, quant_max_bound).astype(jnp.int8)
+            v_store = jnp.clip(jnp.round(v_tok.astype(jnp.float32) * vqs),
+                               quant_min_bound, quant_max_bound).astype(jnp.int8)
+        else:
+            k_store, v_store = k_tok, v_tok
 
-    # ---- paged cache write: token t -> page block_tables[b, pos//bs],
-    # slot pos%bs. One-hot over the flat page table (pages are dense rows).
-    tok_page = jnp.take_along_axis(
-        block_tables[tok_b], (tok_pos // bs)[:, None], axis=1)[:, 0]
-    tok_slot = tok_pos % bs
-    flat_idx = tok_page * bs + tok_slot                      # [tok]
-    flat_idx = jnp.where(tok_valid, flat_idx, -1)
-    # slot-major view [nb*bs, KV, hd] (cache layout is [nb, KV, bs, hd])
-    kc = key_cache.transpose(0, 2, 1, 3).reshape(num_blocks * bs, KV, hd)
-    vc = value_cache.transpose(0, 2, 1, 3).reshape(num_blocks * bs, KV, hd)
-    onehot = (flat_idx[None, :] == jnp.arange(num_blocks * bs)[:, None])
-    written = onehot.any(axis=1, keepdims=True)[..., None]
-    if kv_quant:
-        # int8 one-hot select with int32 accumulation (each slot sums at
-        # most one non-zero term, so the astype back to int8 is exact)
-        wsel = onehot.astype(jnp.int8)                       # [slots, tok]
-        k_new = jnp.einsum("st,tkd->skd", wsel, k_store,
-                           preferred_element_type=jnp.int32).astype(jnp.int8)
-        v_new = jnp.einsum("st,tkd->skd", wsel, v_store,
-                           preferred_element_type=jnp.int32).astype(jnp.int8)
-    else:
-        wsel = onehot.astype(kc.dtype)                       # [slots, tok]
-        k_new = jnp.einsum("st,tkd->skd", wsel, k_store.astype(kc.dtype))
-        v_new = jnp.einsum("st,tkd->skd", wsel, v_store.astype(vc.dtype))
-    kc = jnp.where(written, k_new, kc)
-    vc = jnp.where(written, v_new, vc)
-    key_cache_out = kc.reshape(num_blocks, bs, KV, hd).transpose(0, 2, 1, 3)
-    value_cache_out = vc.reshape(num_blocks, bs, KV, hd).transpose(0, 2, 1, 3)
+    with jax.named_scope("cache_write"):
+        # ---- paged cache write: token t -> page block_tables[b, pos//bs],
+        # slot pos%bs. One-hot over the flat page table (pages are dense rows).
+        tok_page = jnp.take_along_axis(
+            block_tables[tok_b], (tok_pos // bs)[:, None], axis=1)[:, 0]
+        tok_slot = tok_pos % bs
+        flat_idx = tok_page * bs + tok_slot                      # [tok]
+        flat_idx = jnp.where(tok_valid, flat_idx, -1)
+        # slot-major view [nb*bs, KV, hd] (cache layout is [nb, KV, bs, hd])
+        kc = key_cache.transpose(0, 2, 1, 3).reshape(num_blocks * bs, KV, hd)
+        vc = value_cache.transpose(0, 2, 1, 3).reshape(num_blocks * bs, KV, hd)
+        onehot = (flat_idx[None, :] == jnp.arange(num_blocks * bs)[:, None])
+        written = onehot.any(axis=1, keepdims=True)[..., None]
+        if kv_quant:
+            # int8 one-hot select with int32 accumulation (each slot sums at
+            # most one non-zero term, so the astype back to int8 is exact)
+            wsel = onehot.astype(jnp.int8)                       # [slots, tok]
+            k_new = jnp.einsum("st,tkd->skd", wsel, k_store,
+                               preferred_element_type=jnp.int32).astype(jnp.int8)
+            v_new = jnp.einsum("st,tkd->skd", wsel, v_store,
+                               preferred_element_type=jnp.int32).astype(jnp.int8)
+        else:
+            wsel = onehot.astype(kc.dtype)                       # [slots, tok]
+            k_new = jnp.einsum("st,tkd->skd", wsel, k_store.astype(kc.dtype))
+            v_new = jnp.einsum("st,tkd->skd", wsel, v_store.astype(vc.dtype))
+        kc = jnp.where(written, k_new, kc)
+        vc = jnp.where(written, v_new, vc)
+        key_cache_out = kc.reshape(num_blocks, bs, KV, hd).transpose(0, 2, 1, 3)
+        value_cache_out = vc.reshape(num_blocks, bs, KV, hd).transpose(0, 2, 1, 3)
 
-    if use_pallas:
-        # ---- pallas read: pack q per sequence into [B, KV, max_q*G, hd]
-        # rows (row r = t*G + g) and let the kernel walk the block table —
-        # no dense gather ever exists. The freshly written caches go in
-        # untouched pool layout; int8 pages ride with their scale planes.
+    with jax.named_scope("paged_attention"):
+        if use_pallas:
+            # ---- pallas read: pack q per sequence into [B, KV, max_q*G, hd]
+            # rows (row r = t*G + g) and let the kernel walk the block table —
+            # no dense gather ever exists. The freshly written caches go in
+            # untouched pool layout; int8 pages ride with their scale planes.
+            G = H // KV
+            maxq = 1 if use_pallas == "decode" else token_num
+            q_g = q_tok.reshape(token_num, KV, G, hd)            # head h = kv*G+g
+            t_off = jnp.arange(maxq, dtype=jnp.int32)
+            row_tok = jnp.clip(cu[:B, None] + t_off[None, :], 0, token_num - 1)
+            q_pack = q_g[row_tok]                                # [B, maxq, KV, G, hd]
+            q_pack = q_pack.transpose(0, 2, 1, 3, 4).reshape(B, KV, maxq * G, hd)
+            o_pack = PA.paged_attention(
+                q_pack, key_cache_out, value_cache_out, block_tables,
+                past, this, G, float(1.0 / np.sqrt(hd)),
+                k_dequant=cache_k_dequant_scales if kv_quant else None,
+                v_dequant=cache_v_dequant_scales if kv_quant else None)
+            o_pack = o_pack.reshape(B, KV, maxq, G, hd).transpose(0, 2, 1, 3, 4)
+            o = o_pack[tok_b, jnp.minimum(tok_local, maxq - 1)]  # [tok, KV, G, hd]
+            o = jnp.where(tok_valid[:, None, None, None],
+                          o.astype(jnp.float32), 0.0)
+            fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
+            return (fmha_out, qkv3.reshape(token_num, -1),
+                    key_cache_out, value_cache_out)
+
+        # ---- attention: gather each row's pages into a dense [B, max_kv] view
+        rows_k = kc.reshape(num_blocks, bs, KV, hd)[block_tables]  # [B, mb, bs, KV, hd]
+        rows_v = vc.reshape(num_blocks, bs, KV, hd)[block_tables]
+        rows_k = rows_k.reshape(B, max_kv, KV, hd)
+        rows_v = rows_v.reshape(B, max_kv, KV, hd)
+        page_valid = (block_tables >= 0)[:, :, None]             # [B, mb, 1]
+        page_valid = jnp.broadcast_to(page_valid, (B, max_blocks, bs)
+                                      ).reshape(B, max_kv)
+
+        # grouped-head attention WITHOUT materializing the GQA-expanded cache
+        # (q head h reads kv head h // G — the same mapping the Pallas kernel
+        # uses via index maps); rows stay [tok, max_kv, KV, hd]
         G = H // KV
-        maxq = 1 if use_pallas == "decode" else token_num
-        q_g = q_tok.reshape(token_num, KV, G, hd)            # head h = kv*G+g
-        t_off = jnp.arange(maxq, dtype=jnp.int32)
-        row_tok = jnp.clip(cu[:B, None] + t_off[None, :], 0, token_num - 1)
-        q_pack = q_g[row_tok]                                # [B, maxq, KV, G, hd]
-        q_pack = q_pack.transpose(0, 2, 1, 3, 4).reshape(B, KV, maxq * G, hd)
-        o_pack = PA.paged_attention(
-            q_pack, key_cache_out, value_cache_out, block_tables,
-            past, this, G, float(1.0 / np.sqrt(hd)),
-            k_dequant=cache_k_dequant_scales if kv_quant else None,
-            v_dequant=cache_v_dequant_scales if kv_quant else None)
-        o_pack = o_pack.reshape(B, KV, maxq, G, hd).transpose(0, 2, 1, 3, 4)
-        o = o_pack[tok_b, jnp.minimum(tok_local, maxq - 1)]  # [tok, KV, G, hd]
-        o = jnp.where(tok_valid[:, None, None, None],
-                      o.astype(jnp.float32), 0.0)
+        q_g = q_tok.reshape(token_num, KV, G, hd)                # head h = kv*G+g
+        k_tok_rows = rows_k[tok_b]                               # [tok, max_kv, KV, hd]
+        v_tok_rows = rows_v[tok_b]
+        s = jnp.einsum("tkgd,tskd->tkgs", q_g.astype(jnp.float32),
+                       k_tok_rows.astype(jnp.float32)) / np.sqrt(hd)
+        if kv_quant:
+            # per-page dequant: gather each row's page scales like the pages
+            # themselves, expand to slots, apply on the SCORES — the scale is
+            # constant over hd so it factors out of the q·k dot, and the int8
+            # rows are consumed directly by the einsum (convert fused into
+            # the dot read; no dequantized cache copy exists)
+            def _page_scales(dq):                                # [nb, KV]
+                rows = dq.astype(jnp.float32)[block_tables]      # [B, mb, KV]
+                rows = jnp.broadcast_to(rows[:, :, None, :],
+                                        (B, max_blocks, bs, KV))
+                return rows.reshape(B, max_kv, KV)[tok_b]        # [tok, max_kv, KV]
+            kdq = jnp.swapaxes(_page_scales(cache_k_dequant_scales), 1, 2)
+            vdq = jnp.swapaxes(_page_scales(cache_v_dequant_scales), 1, 2)
+            s = s * kdq[:, :, None, :]                           # [tok, KV, 1, mkv]
+        kv_pos = jnp.arange(max_kv)[None, :]
+        ok = (kv_pos <= tok_pos[:, None]) & page_valid[tok_b]    # [tok, max_kv]
+        s = jnp.where(ok[:, None, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        if kv_quant:
+            # value dequant likewise factors out: fold into the probabilities
+            p = p * vdq[:, :, None, :]
+        o = jnp.einsum("tkgs,tskd->tkgd", p, v_tok_rows.astype(jnp.float32))
+        o = jnp.where(tok_valid[:, None, None, None], o, 0.0)
         fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
-        return (fmha_out, qkv3.reshape(token_num, -1),
-                key_cache_out, value_cache_out)
-
-    # ---- attention: gather each row's pages into a dense [B, max_kv] view
-    rows_k = kc.reshape(num_blocks, bs, KV, hd)[block_tables]  # [B, mb, bs, KV, hd]
-    rows_v = vc.reshape(num_blocks, bs, KV, hd)[block_tables]
-    rows_k = rows_k.reshape(B, max_kv, KV, hd)
-    rows_v = rows_v.reshape(B, max_kv, KV, hd)
-    page_valid = (block_tables >= 0)[:, :, None]             # [B, mb, 1]
-    page_valid = jnp.broadcast_to(page_valid, (B, max_blocks, bs)
-                                  ).reshape(B, max_kv)
-
-    # grouped-head attention WITHOUT materializing the GQA-expanded cache
-    # (q head h reads kv head h // G — the same mapping the Pallas kernel
-    # uses via index maps); rows stay [tok, max_kv, KV, hd]
-    G = H // KV
-    q_g = q_tok.reshape(token_num, KV, G, hd)                # head h = kv*G+g
-    k_tok_rows = rows_k[tok_b]                               # [tok, max_kv, KV, hd]
-    v_tok_rows = rows_v[tok_b]
-    s = jnp.einsum("tkgd,tskd->tkgs", q_g.astype(jnp.float32),
-                   k_tok_rows.astype(jnp.float32)) / np.sqrt(hd)
-    if kv_quant:
-        # per-page dequant: gather each row's page scales like the pages
-        # themselves, expand to slots, apply on the SCORES — the scale is
-        # constant over hd so it factors out of the q·k dot, and the int8
-        # rows are consumed directly by the einsum (convert fused into
-        # the dot read; no dequantized cache copy exists)
-        def _page_scales(dq):                                # [nb, KV]
-            rows = dq.astype(jnp.float32)[block_tables]      # [B, mb, KV]
-            rows = jnp.broadcast_to(rows[:, :, None, :],
-                                    (B, max_blocks, bs, KV))
-            return rows.reshape(B, max_kv, KV)[tok_b]        # [tok, max_kv, KV]
-        kdq = jnp.swapaxes(_page_scales(cache_k_dequant_scales), 1, 2)
-        vdq = jnp.swapaxes(_page_scales(cache_v_dequant_scales), 1, 2)
-        s = s * kdq[:, :, None, :]                           # [tok, KV, 1, mkv]
-    kv_pos = jnp.arange(max_kv)[None, :]
-    ok = (kv_pos <= tok_pos[:, None]) & page_valid[tok_b]    # [tok, max_kv]
-    s = jnp.where(ok[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    if kv_quant:
-        # value dequant likewise factors out: fold into the probabilities
-        p = p * vdq[:, :, None, :]
-    o = jnp.einsum("tkgs,tskd->tkgd", p, v_tok_rows.astype(jnp.float32))
-    o = jnp.where(tok_valid[:, None, None, None], o, 0.0)
-    fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
-    return fmha_out, qkv3.reshape(token_num, -1), key_cache_out, value_cache_out
+        return fmha_out, qkv3.reshape(token_num, -1), key_cache_out, value_cache_out
 
 
 # ---------------------------------------------------------------------------

@@ -252,6 +252,7 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, interpret: bool,
     count_launch()
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -403,6 +404,7 @@ def _bwd(sm_scale, causal, interpret, res, do):
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=np.float32(sm_scale), causal=causal,
                           block_q=bq, block_k=bk, has_seg=has_seg),
+        name="flash_attention_dq",
         grid=(B, H, T // bq, S // bk),
         in_specs=dq_in_specs,
         out_specs=dq_out_spec,
@@ -448,6 +450,7 @@ def _bwd(sm_scale, causal, interpret, res, do):
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=np.float32(sm_scale), causal=causal,
                           block_q=bq, block_k=bk, group=G, has_seg=has_seg),
+        name="flash_attention_dkv",
         grid=(B, KV, S // bk, G, T // bq),
         in_specs=dkv_in_specs,
         out_specs=dkv_out_specs,

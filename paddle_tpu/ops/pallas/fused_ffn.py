@@ -236,6 +236,7 @@ def _fwd(x, w1, w3, w2, interpret: bool):
     count_launch()
     return pl.pallas_call(
         _fwd_kernel,
+        name="fused_ffn_fwd",
         grid=(R // br, f // bf),
         in_specs=in_specs,
         out_specs=out_spec,
@@ -347,6 +348,7 @@ def _bwd(interpret, res, do):
     count_launch()
     dx = pl.pallas_call(
         _dx_kernel,
+        name="fused_ffn_dx",
         grid=(R // br, f // bf),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, d), lambda i, j: (i, _i32(0)), **mem),
@@ -383,6 +385,7 @@ def _bwd(interpret, res, do):
     count_launch()
     dw1, dw3, dw2 = pl.pallas_call(
         _dw_kernel,
+        name="fused_ffn_dw",
         grid=(f // bf, R // br),
         in_specs=dw_in_specs,
         out_specs=dw_out_specs,
@@ -480,6 +483,7 @@ def fused_ffn_w8(x, w1_q, w1_s, w3_q, w3_s, w2_q, w2_s,
     count_launch()
     o = pl.pallas_call(
         _fwd_w8_kernel,
+        name="fused_ffn_w8_fwd",
         grid=(R // br, f // bf),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, d), lambda i, j: (i, _i32(0)), **mem),
@@ -583,6 +587,7 @@ def fused_gemm_epilogue(x, y, bias=None, activation: str = "none",
     return pl.pallas_call(
         functools.partial(_epilogue_kernel, act=activation,
                           has_bias=has_bias),
+        name="fused_gemm_epilogue",
         grid=(m // bm, n // bn),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j), **mem),
@@ -617,6 +622,7 @@ def fused_glu(u, v, act: str = "silu",
     count_launch()
     o = pl.pallas_call(
         functools.partial(_glu_kernel, act=act),
+        name="fused_glu",
         grid=(R // br,),
         in_specs=[spec, spec],
         out_specs=spec,

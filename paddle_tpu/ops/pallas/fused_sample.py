@@ -151,6 +151,7 @@ def fused_sample_prep(logits, temps, top_ps, top_k: int = 0,
     count_launch()
     masked, amax = pl.pallas_call(
         functools.partial(_sample_kernel, top_k=int(top_k)),
+        name="fused_sample_prep",
         grid=(),
         in_specs=in_specs,
         out_specs=out_specs,

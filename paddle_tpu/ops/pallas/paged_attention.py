@@ -249,6 +249,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     count_launch()
     return pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), q_rows.dtype),
         interpret=interpret,

@@ -4,7 +4,13 @@ observability/fleet.py).
 The contracts under test, in dependency order:
 
 - span plane basics: trace trees, the active-tree view, chrome-trace
-  export/merge, and the flag kill switch;
+  export, and the flag kill switch;
+- phases on the profiler's clock: inside a jax.profiler session every
+  tick is one ptpu.serve.step with its five phases as contiguous
+  children, ptpu.clock lays the ring's spans over the profile's axis,
+  and outside a session phase() records and builds nothing;
+- stable device names: the lowered serve and train steps carry every
+  named scope, and every pallas_call has a name;
 - serving propagation: one router submission = one trace whose child
   spans (queue.wait / prefill.chunk / decode.tick) decompose TTFT/TPOT,
   riding the request objects as plain host ints;
@@ -104,7 +110,7 @@ class TestSpanPlane:
             flags.set_flags({"trace_spans": True})
         assert tracing.new_trace("request") is not None
 
-    def test_chrome_trace_export_and_multi_rank_merge(self):
+    def test_chrome_trace_export(self):
         root = tracing.new_trace("pipeline.batch", epoch=0)
         tracing.record_span("pp.F", root.trace_id, root.span_id,
                             root.start_ns, 1e-3, stage=0)
@@ -116,31 +122,13 @@ class TestSpanPlane:
             {"pipeline.batch", "pp.F"}
         assert all(e["ph"] == "X" and e["dur"] >= 0
                    for e in doc["traceEvents"])
-        # merging a second "rank" with a +1s clock offset shifts its
-        # events onto the shared axis and interleaves by timestamp
-        merged = tracing.merge_chrome_traces(
-            [doc, (doc, int(1e9), "rank1")])
-        assert len(merged["traceEvents"]) == 2 * len(doc["traceEvents"])
-        ts = [e["ts"] for e in merged["traceEvents"]]
-        assert ts == sorted(ts)
-        shifted = [e for e in merged["traceEvents"] if e["pid"] == "rank1"]
+        # an offset (e.g. the one a ptpu.clock anchor gives) shifts every
+        # stamp onto the other axis
         base = {e["name"]: e["ts"] for e in doc["traceEvents"]}
-        assert all(abs(e["ts"] - base[e["name"]] - 1e6) < 1e-6
-                   for e in shifted)
-
-    def test_clock_handshake_maps_perf_onto_wall_axis(self, store):
-        off0 = tracing.clock_handshake(store, 0)
-        off1 = tracing.clock_handshake(store, 1)
-        import time as _time
-        # both offsets map perf_counter_ns onto the wall axis: applying
-        # them to "now" must land within a second of wall-clock now
-        now_perf = _time.perf_counter_ns()
-        for off in (off0, off1):
-            assert abs((now_perf + off) - _time.time_ns()) < 1e9
-        assert tracing.clock_offset_ns() == off1
-        assert store.check("paddle_trace/clock/0")
-        assert obs.registry().value(
-            "paddle_trace_clock_handshakes_total") == 2
+        moved = tracing.to_chrome_trace(pid="rank1", offset_ns=int(1e9))
+        assert all(e["pid"] == "rank1"
+                   and abs(e["ts"] - base[e["name"]] - 1e6) < 1e-6
+                   for e in moved["traceEvents"])
 
     def test_distress_dump_carries_active_span_tree(self, tmp_path):
         root = tracing.new_trace("request", rid=42)
@@ -283,6 +271,313 @@ class TestServingPropagation:
             flags.set_flags({"trace_spans": True})
         assert builds_on == builds_off
         assert tracing.finished_spans() == []   # off = zero spans
+
+    def test_zero_retrace_pin_profiler_on_vs_off(self, tiny, tmp_path):
+        """phase() is metadata for the profiler, never a cache key: the
+        same workload builds the same executables inside a jax.profiler
+        session and outside one, and outside one it records nothing."""
+
+        def run():
+            eng = _factory(tiny)()
+            for i in range(3):
+                eng.submit(_prompt(tiny[0], 4 + i, seed=50 + i),
+                           max_new_tokens=6)
+            while eng.has_work():
+                eng.step()
+            return eng.stats["step_builds"]
+
+        assert not jax.profiler.TraceAnnotation.is_enabled()
+        builds_outside = run()
+        with tracing.phase("serve.step", tick=0) as ph:
+            ph.set_metadata(batch=1)      # a no-op outside a session
+        tracing.clock_anchor()
+        spans, builds_inside = _profiled(str(tmp_path), run)
+        assert builds_inside == builds_outside
+        # only what ran inside the session is in the profile
+        steps = [s for s in spans if s[0] == "ptpu.serve.step"]
+        assert steps and all(s[3]["tick"] >= 0 and "batch" in s[3]
+                             for s in steps if "kind" in s[3])
+        assert not any(s[0] == "ptpu.clock" for s in spans)
+
+
+# ---------------------------------------------------------------------------
+# Phases on the profiler's clock
+# ---------------------------------------------------------------------------
+
+def _profiled(out_dir, fn):
+    """Run `fn` inside a jax.profiler session (python tracer off); return
+    the session's ptpu.* events [(name, start_ns, dur_ns, stats)], in
+    order, and fn's result."""
+    import glob
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(out_dir, profiler_options=options)
+    try:
+        result = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(out_dir + "/**/*.xplane.pb", recursive=True))[-1]
+    spans = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            spans += [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
+                      for e in line.events
+                      if e.name.startswith(tracing.PHASE_PREFIX)]
+    return sorted(spans, key=lambda e: (e[1], -e[2])), result
+
+
+PHASES = ["ptpu.serve.schedule", "ptpu.serve.prepare",
+          "ptpu.serve.dispatch", "ptpu.serve.wait", "ptpu.serve.harvest"]
+
+
+@pytest.fixture(scope="module")
+def profiled_engine(tiny, tmp_path_factory):
+    """A tiny engine stepped inside a profiler session: two traced
+    requests (a prompt of two chunks, then decode ticks). Returns the
+    profile's ptpu.* events, the ring's spans and the tick count."""
+    obs.reset()
+    eng = _factory(tiny, prefill_chunk=8)()
+    eng.submit(_prompt(tiny[0], 12, seed=9), max_new_tokens=3)
+    eng.run()                              # both executables built
+
+    def drive():
+        anchor = tracing.clock_anchor()
+        roots = [tracing.new_trace("request", rid=i) for i in range(2)]
+        for i, root in enumerate(roots):
+            eng.submit(_prompt(tiny[0], 12 + i, seed=70 + i),
+                       max_new_tokens=4,
+                       trace=(root.trace_id, root.span_id))
+        steps0 = eng.stats["steps"]
+        while eng.has_work():
+            eng.step()
+        return anchor, steps0, eng.stats["steps"] - steps0
+
+    out = str(tmp_path_factory.mktemp("profile"))
+    spans, (anchor, steps0, ticks) = _profiled(out, drive)
+    ring = tracing.finished_spans()
+    obs.reset()
+    return {"spans": spans, "ring": ring, "anchor": anchor,
+            "steps0": steps0, "ticks": ticks}
+
+
+def _children(spans, step):
+    _, start, dur, _ = step
+    return [s for s in spans if s[0] != "ptpu.serve.step"
+            and start <= s[1] and s[1] + s[2] <= start + dur]
+
+
+class TestPhasesOnTheProfilersClock:
+    def test_one_step_per_tick_with_five_contiguous_phases(
+            self, profiled_engine):
+        spans = profiled_engine["spans"]
+        steps = [s for s in spans if s[0] == "ptpu.serve.step"]
+        assert len(steps) == profiled_engine["ticks"] > 3
+        for step in steps:
+            kids = _children(spans, step)
+            assert [k[0] for k in kids] == PHASES
+            for a, b in zip(kids, kids[1:]):
+                assert a[1] + a[2] <= b[1]          # non-overlapping
+            # contiguous: the phases cover the step, its self time is
+            # what no phase covers
+            assert sum(k[2] for k in kids) >= 0.95 * step[2]
+        # steps do not overlap each other either
+        for a, b in zip(steps, steps[1:]):
+            assert a[1] + a[2] <= b[1]
+
+    def test_step_fields_name_the_tick(self, profiled_engine):
+        steps = [s[3] for s in profiled_engine["spans"]
+                 if s[0] == "ptpu.serve.step"]
+        first = profiled_engine["steps0"]
+        assert [f["tick"] for f in steps] == list(
+            range(first, first + len(steps)))
+        for f in steps:
+            assert set(f) == {"tick", "batch", "tokens", "prefill_tokens",
+                              "kind"}
+            assert 1 <= f["batch"] <= 2 and f["tokens"] >= f["batch"]
+            assert f["kind"] in ("decode", "mixed")
+        # two prompts of 12 and 13 tokens in chunks of 8: the first
+        # chunks are prefill tokens, the last decode ticks carry none
+        assert steps[0]["prefill_tokens"] > 0
+        assert steps[-1]["prefill_tokens"] == 0
+        assert steps[-1]["tokens"] == steps[-1]["batch"]
+
+    def test_submit_is_a_span_outside_every_step(self, profiled_engine):
+        spans = profiled_engine["spans"]
+        submits = [s for s in spans if s[0] == "ptpu.serve.submit"]
+        steps = [s for s in spans if s[0] == "ptpu.serve.step"]
+        assert len(submits) == 2
+        for sub in submits:
+            assert not any(st[1] <= sub[1] < st[1] + st[2] for st in steps)
+
+    def test_clock_anchor_lays_ring_spans_over_the_profile(
+            self, profiled_engine):
+        spans, ring = profiled_engine["spans"], profiled_engine["ring"]
+        (clock,) = [s for s in spans if s[0] == "ptpu.clock"]
+        assert clock[3]["perf_ns"] == profiled_engine["anchor"]
+        # the one event gives the offset from the ring's clock
+        # (perf_counter_ns) to the profile's axis
+        offset = clock[1] - clock[3]["perf_ns"]
+        steps = [s for s in spans if s[0] == "ptpu.serve.step"]
+        ticks = [d for d in ring
+                 if d["name"] in ("decode.tick", "prefill.chunk")]
+        assert any(d["name"] == "decode.tick" for d in ticks)
+        slack = 2_000_000    # ns between a clock read and the annotation
+        for d in ticks:
+            lo, hi = d["start_ns"] + offset, d["end_ns"] + offset
+            # the span lies over one step's dispatch + wait interval: the
+            # same clock readings bound both
+            hits = [st for st in steps if st[1] <= lo <= st[1] + st[2]]
+            assert len(hits) == 1, d
+            kids = {k[0]: k for k in _children(spans, hits[0])}
+            disp, wait = kids["ptpu.serve.dispatch"], kids["ptpu.serve.wait"]
+            assert abs(lo - disp[1]) < slack
+            assert abs(hi - (wait[1] + wait[2])) < slack
+
+    def test_outside_a_session_nothing_is_recorded(self, tmp_path):
+        with tracing.phase("serve.step", tick=1):
+            with tracing.phase("serve.wait"):
+                pass
+        tracing.clock_anchor()
+        spans, _ = _profiled(str(tmp_path), lambda: None)
+        assert spans == []
+
+
+# ---------------------------------------------------------------------------
+# Stable names on the device side
+# ---------------------------------------------------------------------------
+
+SERVE_SCOPES = ["embed", "layers", "qkv", "cache_write", "paged_attention",
+                "attn_out", "ffn", "head", "sample"]
+TRAIN_SCOPES = ["embed", "attention", "ffn", "head_loss", "pp_send",
+                "grad_sync", "grad_norm", "adamw", "layers", "pipeline"]
+
+
+def _scopes_in(text):
+    """Scope words found in the name stacks of a lowered module's
+    locations (`loc("jit(f)/layers/while/body/ffn/dot_general")`)."""
+    import re
+
+    words = set()
+    for loc in re.findall(r'loc\("([^"]+)"', text):
+        for comp in loc.split("/"):
+            words.update(re.findall(r"[A-Za-z_][A-Za-z0-9_]*", comp))
+    return words
+
+
+class TestStableDeviceNames:
+    @pytest.mark.parametrize("pallas", [False, True],
+                             ids=["stock", "pallas"])
+    def test_serve_step_carries_every_scope(self, tiny, pallas):
+        eng = _factory(tiny, pallas=pallas)()
+        lowered = []
+        build = eng._build_step
+
+        def spy(*a, **k):
+            fn = build(*a, **k)
+
+            def call(*args):
+                shapes = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+                lowered.append(fn.lower(*shapes).as_text(debug_info=True))
+                return fn(*args)
+            return call
+
+        eng._build_step = spy
+        eng.submit(_prompt(tiny[0], 6), max_new_tokens=2)
+        eng.run()
+        assert lowered
+        for text in lowered:
+            assert set(SERVE_SCOPES) <= _scopes_in(text)
+
+    def test_cow_copy_carries_its_scope(self, tiny):
+        eng = _factory(tiny)()
+        eng._copy_blocks([(0, 1)])
+        kc = jax.ShapeDtypeStruct(eng._key_cache.shape,
+                                  eng._key_cache.dtype)
+        idx = jax.ShapeDtypeStruct((8,), jnp.int32)
+        text = eng._copy_fn.lower(kc, kc, None, None, idx, idx).as_text(
+            debug_info=True)
+        assert "cow_copy" in _scopes_in(text)
+
+    def test_train_step_carries_every_scope(self):
+        from paddle_tpu.distributed import hybrid as H
+        from paddle_tpu.models import llama as L
+
+        cfg = L.LlamaConfig(vocab_size=64, hidden_size=32,
+                            intermediate_size=64, num_layers=2, num_heads=4,
+                            num_kv_heads=2, max_seq_len=16,
+                            dtype=jnp.float32)
+        mesh = H.build_mesh(1, 1, 1)
+        params = H.shard_params(L.init_params(cfg, jax.random.PRNGKey(0)),
+                                mesh, cfg)
+        opt = H.init_opt_state(params)
+        step = H.make_train_step(cfg, mesh, num_microbatches=2)
+        tokens = jnp.zeros((2, 16), jnp.int32)
+        lowered = step.lower(params, opt, tokens, tokens)
+        found = _scopes_in(lowered.as_text(debug_info=True))
+        assert set(TRAIN_SCOPES) <= found, set(TRAIN_SCOPES) - found
+        # in the executable, forward, jvp and transpose operations carry
+        # the scope's name, the outermost inside JAX's wrappers
+        import re
+
+        names = set(re.findall(r'op_name="([^"]+)"',
+                               lowered.compile().as_text()))
+        assert any(re.search(r"/jvp\(pipeline\)/.*/head_loss/", n)
+                   for n in names)
+        assert any(re.search(r"/transpose\(jvp\(pipeline\)\)/.*/ffn/", n)
+                   for n in names)
+        assert any(n.endswith(("/adamw/sqrt", "/adamw/sqrt:"))
+                   for n in names)
+
+    @pytest.mark.parametrize("module, count", [
+        ("flash_attention", 3), ("fused_ffn", 6), ("fused_sample", 1),
+        ("paged_attention", 1)])
+    def test_every_pallas_call_has_a_name(self, module, count):
+        import ast
+        import os
+
+        import paddle_tpu.ops.pallas as pkg
+
+        path = os.path.join(os.path.dirname(pkg.__file__), module + ".py")
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        calls = [n for n in ast.walk(tree) if isinstance(n, ast.Call)
+                 and isinstance(n.func, ast.Attribute)
+                 and n.func.attr == "pallas_call"]
+        assert len(calls) == count
+        names = []
+        for call in calls:
+            kw = {k.arg: k.value for k in call.keywords}
+            assert isinstance(kw.get("name"), ast.Constant), (
+                f"{module}.py:{call.lineno}: pallas_call without name=")
+            names.append(kw["name"].value)
+        assert len(set(names)) == count
+        assert all(n.startswith(module.replace("fused_sample",
+                                               "fused_sample_prep")[:5])
+                   for n in names)
+
+    def test_pallas_call_sites_are_all_in_ops_pallas(self):
+        """The 11 named sites above are all there are in the package."""
+        import os
+        import re
+
+        import paddle_tpu
+
+        root = os.path.dirname(paddle_tpu.__file__)
+        sites = {}
+        for base, _, files in os.walk(root):
+            for f in files:
+                if f.endswith(".py"):
+                    with open(os.path.join(base, f)) as fh:
+                        n = len(re.findall(r"\bpl\.pallas_call\(", fh.read()))
+                    if n:
+                        sites[os.path.relpath(os.path.join(base, f),
+                                              root)] = n
+        assert sites == {"ops/pallas/flash_attention.py": 3,
+                         "ops/pallas/fused_ffn.py": 6,
+                         "ops/pallas/fused_sample.py": 1,
+                         "ops/pallas/paged_attention.py": 1}
 
 
 # ---------------------------------------------------------------------------
