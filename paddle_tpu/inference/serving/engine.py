@@ -47,7 +47,7 @@ from ...core import flags
 from ...models import llama as L
 from ...observability import emit as _emit
 from ...observability import tracing as _tracing
-from ...ops.kernels.serving_attention import block_multihead_attention_
+from ...ops.kernels.serving_attention import paged_layer_attention
 from ...ops.pallas import flash_attention as FA
 from ...ops.pallas import fused_ffn as FF
 from ...ops.pallas import fused_sample as FS
@@ -279,8 +279,9 @@ class PagedServingEngine:
                     f"pallas_ffn=True forced but FFN geometry d={d} f={f} "
                     f"rows<={rows} is not supported() by the fused kernel")
 
-        # device state: stacked per-layer paged caches (scanned with the
-        # layer axis, like llm.py's init_cache)
+        # device state: the page pool, stacked over layers. The tick
+        # donates it, carries it through its layer loop and returns it:
+        # one buffer, updated in place
         shape = (cfg.num_layers, self.num_blocks, kvh, self.block_size, hd)
         self._key_cache = jnp.zeros(shape, self.cache_dtype)
         self._value_cache = jnp.zeros(shape, self.cache_dtype)
@@ -592,7 +593,6 @@ class PagedServingEngine:
         all-position argmax — the speculative-decoding verify read."""
         cfg = self.cfg
         top_k = self.top_k
-        bs = self.block_size
         quant_kv = self.quant_kv   # static: selects the int8-cache trace
         fused_tick = bool(ffn_mode) and pallas_mode == "decode"
 
@@ -608,20 +608,20 @@ class PagedServingEngine:
             with jax.named_scope("embed"):
                 x = jnp.take(params["embed"], tokens,
                              axis=0).astype(cfg.dtype)
-            zeros_b = jnp.zeros((B,), jnp.int32)
             # per-class token->slot scaling selectors (closed over by the
             # scan body — they carry no layer axis)
             ad_sels = tuple(a["sel"] for a in ad_args)
 
             def body(carry, layer):
-                x = carry
+                # the stacked page pool rides the carry, so the step's
+                # input, the loop's state and the step's output are one
+                # buffer; a layer finds its pages by its index
+                x, kcs, vcs = carry
+                li, lp = layer[:2]
                 if quant_kv:
-                    lp, kc, vc, kq, vq, kdq, vdq = layer[:7]
-                    ad_layers = layer[7:]
+                    kv_layer, ad_layers = layer[2:6], layer[6:]
                 else:
-                    lp, kc, vc = layer[:3]
-                    ad_layers = layer[3:]
-                    kq = vq = kdq = vdq = None
+                    kv_layer, ad_layers = None, layer[2:]
 
                 def lora(h, t, y):
                     # segmented/gathered LoRA: every slot of every active
@@ -648,15 +648,11 @@ class PagedServingEngine:
                     qkv = jnp.concatenate([q, k, v], axis=-1)
                 # scopes itself: qkv (split, rope), cache_write,
                 # paged_attention
-                o, _, kc, vc = block_multihead_attention_.__wrapped__(
-                    qkv, kc, vc, zeros_b, seq_lens_decoder,
-                    seq_lens_this_time, cu_seqlens_q=cu_seqlens_q,
-                    block_tables=block_tables, rope_emb=rope_emb,
-                    cache_k_quant_scales=kq, cache_v_quant_scales=vq,
-                    cache_k_dequant_scales=kdq,
-                    cache_v_dequant_scales=vdq,
-                    use_neox_style=True, block_size=bs,
-                    rope_theta=cfg.rope_theta, use_pallas=pallas_mode)
+                o, _, kcs, vcs = paged_layer_attention(
+                    qkv, kcs, vcs, li, seq_lens_decoder,
+                    seq_lens_this_time, cu_seqlens_q, block_tables,
+                    rope_emb=rope_emb, quant_scales=kv_layer,
+                    use_neox_style=True, use_pallas=pallas_mode)
                 with jax.named_scope("attn_out"):
                     x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
                 with jax.named_scope("ffn"):
@@ -669,15 +665,18 @@ class PagedServingEngine:
                         gate = (jax.nn.silu(Q.matmul_param(h, lp, "w1"))
                                 * Q.matmul_param(h, lp, "w3"))
                         x = x + Q.matmul_param(gate, lp, "w2")
-                return x, (kc, vc)
+                return (x, kcs, vcs), None
 
-            xs = (params["blocks"], key_cache, value_cache)
+            # scanned over: the layer index and what a layer only reads
+            xs = (jnp.arange(cfg.num_layers, dtype=jnp.int32),
+                  params["blocks"])
             if quant_kv:
                 xs = xs + tuple(kv_scales)   # kq, vq [L,KV]; kdq,vdq [L,nb,KV]
             # stacked adapter packs ride the layer scan like param leaves
             xs = xs + tuple(a["packs"] for a in ad_args)
             with jax.named_scope("layers"):
-                x, (kcs, vcs) = lax.scan(body, x, xs)
+                (x, kcs, vcs), _ = lax.scan(
+                    body, (x, key_cache, value_cache), xs)
             with jax.named_scope("head"):
                 # last-token hidden state per slot (cu[1:]-1; idle slots
                 # gather garbage the host never reads)
@@ -730,8 +729,14 @@ class PagedServingEngine:
                                   ad_sig, spec_mode)
             self._step_fns[key] = fn
             self.stats["step_builds"] += 1
+            # cache_write: how this executable's layers put their new rows
+            # into the carried pool (paged_layer_attention chooses by the
+            # read path: the page-write kernel beside the Pallas read, an
+            # XLA row scatter on the stock path)
             _emit("serving.step_build", tok_pad=tok_pad, batch=B,
-                  ad_sig=list(ad_sig), spec=bool(spec_mode))
+                  ad_sig=list(ad_sig), spec=bool(spec_mode),
+                  cache_write="pallas_pages" if pallas_mode
+                  else "scatter_rows")
         return fn
 
     def _copy_blocks(self, pairs: List[Tuple[int, int]]):

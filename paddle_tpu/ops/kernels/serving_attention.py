@@ -398,8 +398,10 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
     (past length, 0 for prefill rows) + local offset.
 
     Returns (fmha_out [token_num, H·hd], qkv_out, key_cache_out,
-    value_cache_out). Paged pages are written with a one-hot select over
-    the row's pages (TPU-friendly scatter).
+    value_cache_out). Only the new tokens' rows are written, at
+    [page, :, slot, :] of a cache (`write_rows`); pad rows and rows whose
+    table entry is −1 write nothing. The serving engine runs the same
+    code on its whole stacked pool (`paged_layer_attention`).
 
     Int8 cache path: pass int8 key/value caches plus all four scale
     tensors — `cache_{k,v}_quant_scales` [KV] per-head quant multipliers
@@ -461,11 +463,8 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
             "per-slot cache without block tables use the dense fallbacks: "
             "masked_multihead_attention_ (one decode step) or "
             "fused_multi_transformer_ (whole stack)")
-    num_blocks, KV, bs, hd = key_cache.shape
-    B, max_blocks = block_tables.shape
-    token_num = qkv.shape[0]
+    _, KV, bs, hd = key_cache.shape
     H = qkv.shape[1] // hd - 2 * KV
-    max_kv = max_blocks * bs
 
     # ---- pallas dispatch (static, resolved at trace time):
     #   None     -> FLAGS_serving_pallas_attention, gated on available()
@@ -480,6 +479,95 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
         use_pallas = (bool(flags.flag_value("serving_pallas_attention"))
                       and PA.available()
                       and PA.supported(H, KV, hd, bs))
+    # one layer's caches are a pool of one layer (a leading axis of 1 is a
+    # bitcast): the op and the serving engine's tick share one write and
+    # one read
+    fmha_out, qkv_out, kcs, vcs = paged_layer_attention(
+        qkv, key_cache[None], value_cache[None], 0, seq_lens_decoder,
+        seq_lens_this_time, cu_seqlens_q, block_tables, rope_emb=rope_emb,
+        quant_scales=(cache_k_quant_scales, cache_v_quant_scales,
+                      cache_k_dequant_scales, cache_v_dequant_scales)
+        if kv_quant else None,
+        qkv_bias=qkv_bias, use_neox_style=use_neox_style,
+        quant_max_bound=quant_max_bound, quant_min_bound=quant_min_bound,
+        use_pallas=use_pallas)
+    return fmha_out, qkv_out, kcs[0], vcs[0]
+
+
+def write_rows(pool, layer, page, slot, rows):
+    """Put `rows[t]` ([tok, KV, hd]) at `pool[layer, page[t], :, slot[t], :]`
+    of the stacked pool [L, num_blocks, KV, block_size, hd] and touch
+    nothing else: one XLA scatter with a (KV, hd) update window — the
+    stock path's write. A row whose page lies outside [0, num_blocks)
+    writes nothing (the caller sends a row there to drop it); the rows
+    that land name distinct slots."""
+    tok = rows.shape[0]
+    idx = jnp.stack([jnp.full((tok,), layer, jnp.int32),
+                     page.astype(jnp.int32), slot.astype(jnp.int32)], axis=1)
+    dnums = lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2), inserted_window_dims=(0, 1, 3),
+        scatter_dims_to_operand_dims=(0, 1, 3))
+    return lax.scatter(pool, idx, rows.astype(pool.dtype), dnums,
+                       unique_indices=True,
+                       mode=lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def page_plan(past, this, cu, block_tables, num_blocks, bs, token_num):
+    """The pages a batch writes, for `paged_attention.write_pages`: a
+    sequence's new positions past[b] … past[b]+this[b]−1 are contiguous, so
+    it writes a run of its table's pages, the first and last of them in
+    part. Returns (pages [n], lo [n], hi [n], src [n, bs]): entry j writes
+    slots [lo, hi) of physical page `pages[j]`, and slot s takes packed
+    token row `src[j, s]` (clamped; outside [lo, hi) it is not read). n is
+    static, min(tokens, ceil(tokens / bs) + 2·B), which no batch exceeds.
+    An entry with nothing to write (past the batch's last page, or on a
+    table entry of −1) repeats the last one before it that has (the first
+    that has, when none came before; an empty write when there is none)."""
+    B, max_blocks = block_tables.shape
+    n = min(token_num, -(-token_num // bs) + 2 * B)
+    first = past // bs
+    count = jnp.where(this > 0, (past + this - 1) // bs - first + 1, 0)
+    ends = jnp.cumsum(count)                                    # [B]
+    j = jnp.arange(n, dtype=jnp.int32)
+    b = jnp.clip(jnp.searchsorted(ends, j, side="right"), 0, B - 1)
+    logical = first[b] + j - (ends[b] - count[b])
+    page = block_tables[b, jnp.clip(logical, 0, max_blocks - 1)]
+    live = ((j < ends[-1]) & (logical < max_blocks)
+            & (page >= 0) & (page < num_blocks))
+    start = logical * bs - past[b]          # page's slot 0, in the chunk
+    lo = jnp.where(live, jnp.clip(-start, 0, bs), 0)
+    hi = jnp.where(live, jnp.clip(this[b] - start, 0, bs), 0)
+    src = jnp.clip((cu[b] + start)[:, None]
+                   + jnp.arange(bs, dtype=jnp.int32)[None, :],
+                   0, token_num - 1)
+    last = lax.cummax(jnp.where(live, j, -1))
+    rep = jnp.where(last >= 0, last, jnp.argmax(live)).astype(jnp.int32)
+    return (jnp.clip(page, 0, num_blocks - 1)[rep], lo[rep], hi[rep],
+            src[rep])
+
+
+def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
+                          seq_lens_this_time, cu_seqlens_q, block_tables,
+                          rope_emb=None, quant_scales=None, qkv_bias=None,
+                          use_neox_style=False, quant_max_bound=127.0,
+                          quant_min_bound=-127.0, use_pallas=False):
+    """One layer of `block_multihead_attention_` on the stacked page pool
+    [L, num_blocks, KV, block_size, hd]: split and rotate `qkv`, write the
+    new tokens' rows into `layer`'s pages where they lie, then attend over
+    them. The pools come back updated in place (a donated or loop-carried
+    pool is aliased through the write: the page-write kernel beside the
+    Pallas read, an XLA row scatter on the stock path); no other layer
+    and no page the batch does not own is touched. `quant_scales` is None or
+    (k_quant [KV], v_quant [KV], k_dequant [num_blocks, KV], v_dequant)
+    of this layer; `use_pallas` is False, True or "decode", already
+    resolved. Returns (fmha_out, qkv_out, key_pool, value_pool)."""
+    from ..pallas import paged_attention as PA
+    _, num_blocks, KV, bs, hd = key_pool.shape
+    B, max_blocks = block_tables.shape
+    token_num = qkv.shape[0]
+    H = qkv.shape[1] // hd - 2 * KV
+    max_kv = max_blocks * bs
+    kv_quant = quant_scales is not None
     if use_pallas and not PA.supported(H, KV, hd, bs):
         raise ValueError(
             f"use_pallas={use_pallas!r} forced but geometry H={H} KV={KV} "
@@ -488,8 +576,8 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
 
     # named scopes (jax.named_scope): the device operations of this op
     # belong to `qkv` (split, bias, rope, token indices), `cache_write`
-    # (the one-hot page write only) or `paged_attention` (the read:
-    # the Pallas launch or the stock gather path)
+    # (the row write only) or `paged_attention` (the read: the Pallas
+    # launch or the stock gather path)
     with jax.named_scope("qkv"):
         qkv3 = qkv.reshape(token_num, H + 2 * KV, hd)
         if qkv_bias is not None:
@@ -520,76 +608,77 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
         # that re-prefills with different chunk boundaries reproduces the
         # int8 pages bit-for-bit.
         if kv_quant:
-            kqs = cache_k_quant_scales.astype(jnp.float32).reshape(1, KV, 1)
-            vqs = cache_v_quant_scales.astype(jnp.float32).reshape(1, KV, 1)
+            k_quant, v_quant, k_dequant, v_dequant = quant_scales
+            kqs = k_quant.astype(jnp.float32).reshape(1, KV, 1)
+            vqs = v_quant.astype(jnp.float32).reshape(1, KV, 1)
             k_store = jnp.clip(jnp.round(k_tok.astype(jnp.float32) * kqs),
                                quant_min_bound, quant_max_bound).astype(jnp.int8)
             v_store = jnp.clip(jnp.round(v_tok.astype(jnp.float32) * vqs),
                                quant_min_bound, quant_max_bound).astype(jnp.int8)
         else:
             k_store, v_store = k_tok, v_tok
+            k_dequant = v_dequant = None
 
     with jax.named_scope("cache_write"):
         # ---- paged cache write: token t -> page block_tables[b, pos//bs],
-        # slot pos%bs. One-hot over the flat page table (pages are dense rows).
-        tok_page = jnp.take_along_axis(
-            block_tables[tok_b], (tok_pos // bs)[:, None], axis=1)[:, 0]
-        tok_slot = tok_pos % bs
-        flat_idx = tok_page * bs + tok_slot                      # [tok]
-        flat_idx = jnp.where(tok_valid, flat_idx, -1)
-        # slot-major view [nb*bs, KV, hd] (cache layout is [nb, KV, bs, hd])
-        kc = key_cache.transpose(0, 2, 1, 3).reshape(num_blocks * bs, KV, hd)
-        vc = value_cache.transpose(0, 2, 1, 3).reshape(num_blocks * bs, KV, hd)
-        onehot = (flat_idx[None, :] == jnp.arange(num_blocks * bs)[:, None])
-        written = onehot.any(axis=1, keepdims=True)[..., None]
-        if kv_quant:
-            # int8 one-hot select with int32 accumulation (each slot sums at
-            # most one non-zero term, so the astype back to int8 is exact)
-            wsel = onehot.astype(jnp.int8)                       # [slots, tok]
-            k_new = jnp.einsum("st,tkd->skd", wsel, k_store,
-                               preferred_element_type=jnp.int32).astype(jnp.int8)
-            v_new = jnp.einsum("st,tkd->skd", wsel, v_store,
-                               preferred_element_type=jnp.int32).astype(jnp.int8)
+        # slot pos%bs; pad rows and rows whose table entry is unassigned
+        # (−1) write nothing. Only the new rows move, in either form.
+        if use_pallas:
+            # a page at a time through the kernel, which keeps the pool in
+            # the layout the read wants (an XLA scatter beside the Pallas
+            # read makes the compiler hold the pool slot-major and re-lay
+            # it out whole for every launch)
+            pages, lo, hi, src = page_plan(past, this, cu, block_tables,
+                                           num_blocks, bs, token_num)
+
+            def staged(rows):                                    # [n, KV, bs, hd]
+                return rows[src].transpose(0, 2, 1, 3).astype(key_pool.dtype)
+            key_pool, value_pool = PA.write_pages(
+                key_pool, value_pool, layer, pages, lo, hi,
+                staged(k_store), staged(v_store))
         else:
-            wsel = onehot.astype(kc.dtype)                       # [slots, tok]
-            k_new = jnp.einsum("st,tkd->skd", wsel, k_store.astype(kc.dtype))
-            v_new = jnp.einsum("st,tkd->skd", wsel, v_store.astype(vc.dtype))
-        kc = jnp.where(written, k_new, kc)
-        vc = jnp.where(written, v_new, vc)
-        key_cache_out = kc.reshape(num_blocks, bs, KV, hd).transpose(0, 2, 1, 3)
-        value_cache_out = vc.reshape(num_blocks, bs, KV, hd).transpose(0, 2, 1, 3)
+            # dropped rows go to pages past the pool, one each
+            tok_page = jnp.take_along_axis(
+                block_tables[tok_b], (tok_pos // bs)[:, None], axis=1)[:, 0]
+            tok_page = jnp.where(tok_valid & (tok_page >= 0), tok_page,
+                                 num_blocks + tok_idx)
+            tok_slot = tok_pos % bs
+            key_pool = write_rows(key_pool, layer, tok_page, tok_slot, k_store)
+            value_pool = write_rows(value_pool, layer, tok_page, tok_slot,
+                                    v_store)
 
     with jax.named_scope("paged_attention"):
+        G = H // KV
+        q_g = q_tok.reshape(token_num, KV, G, hd)                # head h = kv*G+g
         if use_pallas:
             # ---- pallas read: pack q per sequence into [B, KV, max_q*G, hd]
             # rows (row r = t*G + g) and let the kernel walk the block table —
-            # no dense gather ever exists. The freshly written caches go in
-            # untouched pool layout; int8 pages ride with their scale planes.
-            G = H // KV
+            # no dense gather ever exists, and no slice of the layer either:
+            # the freshly written pool goes in whole, with the layer index;
+            # int8 pages ride with their scale planes.
             maxq = 1 if use_pallas == "decode" else token_num
-            q_g = q_tok.reshape(token_num, KV, G, hd)            # head h = kv*G+g
             t_off = jnp.arange(maxq, dtype=jnp.int32)
             row_tok = jnp.clip(cu[:B, None] + t_off[None, :], 0, token_num - 1)
             q_pack = q_g[row_tok]                                # [B, maxq, KV, G, hd]
             q_pack = q_pack.transpose(0, 2, 1, 3, 4).reshape(B, KV, maxq * G, hd)
             o_pack = PA.paged_attention(
-                q_pack, key_cache_out, value_cache_out, block_tables,
-                past, this, G, float(1.0 / np.sqrt(hd)),
-                k_dequant=cache_k_dequant_scales if kv_quant else None,
-                v_dequant=cache_v_dequant_scales if kv_quant else None)
+                q_pack, key_pool, value_pool, block_tables, past, this, G,
+                float(1.0 / np.sqrt(hd)), k_dequant=k_dequant,
+                v_dequant=v_dequant, layer=layer)
             o_pack = o_pack.reshape(B, KV, maxq, G, hd).transpose(0, 2, 1, 3, 4)
             o = o_pack[tok_b, jnp.minimum(tok_local, maxq - 1)]  # [tok, KV, G, hd]
             o = jnp.where(tok_valid[:, None, None, None],
                           o.astype(jnp.float32), 0.0)
             fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
-            return (fmha_out, qkv3.reshape(token_num, -1),
-                    key_cache_out, value_cache_out)
+            return fmha_out, qkv3.reshape(token_num, -1), key_pool, value_pool
 
-        # ---- attention: gather each row's pages into a dense [B, max_kv] view
-        rows_k = kc.reshape(num_blocks, bs, KV, hd)[block_tables]  # [B, mb, bs, KV, hd]
-        rows_v = vc.reshape(num_blocks, bs, KV, hd)[block_tables]
-        rows_k = rows_k.reshape(B, max_kv, KV, hd)
-        rows_v = rows_v.reshape(B, max_kv, KV, hd)
+        # ---- stock read (CPU tests; it runs in no benchmark cell): slice the
+        # layer out and gather each row's pages into a dense [B, max_kv] view
+        def _dense_rows(pool):
+            pages = lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+            rows = pages[block_tables]                           # [B, mb, KV, bs, hd]
+            return rows.transpose(0, 1, 3, 2, 4).reshape(B, max_kv, KV, hd)
+        rows_k, rows_v = _dense_rows(key_pool), _dense_rows(value_pool)
         page_valid = (block_tables >= 0)[:, :, None]             # [B, mb, 1]
         page_valid = jnp.broadcast_to(page_valid, (B, max_blocks, bs)
                                       ).reshape(B, max_kv)
@@ -597,8 +686,6 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
         # grouped-head attention WITHOUT materializing the GQA-expanded cache
         # (q head h reads kv head h // G — the same mapping the Pallas kernel
         # uses via index maps); rows stay [tok, max_kv, KV, hd]
-        G = H // KV
-        q_g = q_tok.reshape(token_num, KV, G, hd)                # head h = kv*G+g
         k_tok_rows = rows_k[tok_b]                               # [tok, max_kv, KV, hd]
         v_tok_rows = rows_v[tok_b]
         s = jnp.einsum("tkgd,tskd->tkgs", q_g.astype(jnp.float32),
@@ -614,8 +701,8 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
                 rows = jnp.broadcast_to(rows[:, :, None, :],
                                         (B, max_blocks, bs, KV))
                 return rows.reshape(B, max_kv, KV)[tok_b]        # [tok, max_kv, KV]
-            kdq = jnp.swapaxes(_page_scales(cache_k_dequant_scales), 1, 2)
-            vdq = jnp.swapaxes(_page_scales(cache_v_dequant_scales), 1, 2)
+            kdq = jnp.swapaxes(_page_scales(k_dequant), 1, 2)
+            vdq = jnp.swapaxes(_page_scales(v_dequant), 1, 2)
             s = s * kdq[:, :, None, :]                           # [tok, KV, 1, mkv]
         kv_pos = jnp.arange(max_kv)[None, :]
         ok = (kv_pos <= tok_pos[:, None]) & page_valid[tok_b]    # [tok, max_kv]
@@ -627,7 +714,7 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
         o = jnp.einsum("tkgs,tskd->tkgd", p, v_tok_rows.astype(jnp.float32))
         o = jnp.where(tok_valid[:, None, None, None], o, 0.0)
         fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
-        return fmha_out, qkv3.reshape(token_num, -1), key_cache_out, value_cache_out
+        return fmha_out, qkv3.reshape(token_num, -1), key_pool, value_pool
 
 
 # ---------------------------------------------------------------------------

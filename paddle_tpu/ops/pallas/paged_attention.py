@@ -36,11 +36,19 @@ Design:
   of the cache ever exists;
 - `max_q=1` is the decode-specialized launch: rows collapse to the GQA
   group (`[B, KV, G, hd]`), zero padding waste on the steady-state hot
-  path.
+  path;
+- the append that comes before the read is `write_pages`, a second small
+  kernel over the pages a batch touches, with the pools aliased input to
+  output: beside this kernel an XLA scatter of rows makes the compiler
+  hold the pool in another layout and convert all of it for every launch.
 
 Layout contract: q rows are packed/unpacked by the caller
-(block_multihead_attention_); caches stay in their pool layout
-`[num_blocks, KV, block_size, hd]` — no transpose, no reshape, no copy.
+(block_multihead_attention_); caches stay in their pool layout — one
+layer's `[num_blocks, KV, block_size, hd]`, or the serving engine's whole
+stacked pool `[L, num_blocks, KV, block_size, hd]` with the layer as one
+more prefetched scalar, which the K/V index maps put in front of the
+page: `(layer, tables[b, p], kv, 0, 0)`. No transpose, no reshape, no
+copy, and no slice of a layer out of the stack.
 """
 from __future__ import annotations
 
@@ -57,7 +65,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import (NEG_INF, _assert_mosaic_tileable, _i32,
                               available, count_launch)
 
-__all__ = ["paged_attention", "available", "supported"]
+__all__ = ["paged_attention", "write_pages", "available", "supported"]
 
 # m/l carriers use the same [rows, LANES] lane-broadcast trick as
 # flash_attention.py (a [rows, 1] scratch column is not a legal vreg shape
@@ -81,9 +89,10 @@ def supported(num_heads: int, num_kv_heads: int, head_dim: int,
     return head_dim >= 8 and block_size >= 1
 
 
-def _kernel(tables_ref, past_ref, this_ref, *refs, sm_scale: float,
-            block_size: int, group: int, has_quant: bool):
-    """One (sequence b, kv head, page p) grid step.
+def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
+            sm_scale: float, block_size: int, group: int, has_quant: bool):
+    """One (sequence b, kv head, page p) grid step. `layer_ref` is read
+    by the K/V index maps only.
 
     refs: q, k_page, v_page, [k_scale, v_scale,] o, acc, m, l.
     q rows pack chunk offset t and GQA head g as r = t*G + g; absolute
@@ -162,16 +171,18 @@ def _kernel(tables_ref, past_ref, this_ref, *refs, sm_scale: float,
 def paged_attention(q_rows, key_cache, value_cache, block_tables,
                     seq_lens_decoder, seq_lens_this_time, group: int,
                     sm_scale: float, k_dequant=None, v_dequant=None,
-                    interpret: Optional[bool] = None):
+                    interpret: Optional[bool] = None, layer=None):
     """Attention over paged caches, block table walked in-kernel.
 
     q_rows [B, KV, max_q * G, hd] — per-sequence packed rows (row
     r = t * G + g: chunk offset t, GQA head g; the caller packs/unpacks
     against cu_seqlens); `group` is G = H // KV (static); key_cache /
     value_cache [num_blocks, KV, block_size, hd] ALREADY containing this
-    step's appended tokens; block_tables [B, max_blocks] int32 (−1 =
-    unassigned; never dereferenced thanks to the length skip, but
-    clamped defensively); seq_lens_decoder / seq_lens_this_time [B]
+    step's appended tokens — or, with `layer` (an int32 scalar, traced or
+    not), the stacked pool [L, num_blocks, KV, block_size, hd], of which
+    the kernel reads that layer's pages where they lie; block_tables
+    [B, max_blocks] int32 (−1 = unassigned; never dereferenced thanks to
+    the length skip, but clamped defensively); seq_lens_decoder / seq_lens_this_time [B]
     int32 past/this lengths (the scheduler's chunked-prefill metadata).
 
     k_dequant / v_dequant [num_blocks, KV] f32 enable the int8-page
@@ -185,9 +196,16 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     if rows <= 0 or group <= 0 or rows % group != 0:
         raise ValueError(f"q_rows rows={rows} must be a positive multiple "
                          f"of group={group}")
-    num_blocks, KVc, bs, hdc = key_cache.shape
+    if key_cache.ndim != (4 if layer is None else 5):
+        raise ValueError(
+            f"cache {key_cache.shape}: pass one layer's [nb, KV, bs, hd], "
+            f"or the stacked [L, nb, KV, bs, hd] together with `layer`")
+    if layer is None:
+        # one layer is a stack of one: a leading axis of 1 is a bitcast
+        key_cache, value_cache, layer = key_cache[None], value_cache[None], 0
+    _, num_blocks, KVc, bs, hdc = key_cache.shape
     if (KVc, hdc) != (KV, hd):
-        raise ValueError(f"cache [nb, KV, bs, hd]={key_cache.shape} does "
+        raise ValueError(f"cache [nb, KV, bs, hd]={key_cache.shape[1:]} does "
                          f"not match q rows [B, KV, rows, hd]={q_rows.shape}")
     max_blocks = block_tables.shape[1]
     if interpret is None:
@@ -196,18 +214,19 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     tables = jnp.maximum(block_tables.astype(jnp.int32), 0)   # [B, mb]
     past = seq_lens_decoder.reshape(-1).astype(jnp.int32)     # [B]
     this = seq_lens_this_time.reshape(-1).astype(jnp.int32)   # [B]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     mem = {"memory_space": pltpu.VMEM}
+    # the layer axis is squeezed: the body sees [1, 1, bs, hd] pages
+    page_spec = pl.BlockSpec(
+        (None, 1, 1, bs, hd),
+        lambda b, kv, p, tr, pr, th, ly: (ly[0], tr[b, p], kv, _i32(0),
+                                          _i32(0)), **mem)
     in_specs = [
         pl.BlockSpec((1, 1, rows, hd),
-                     lambda b, kv, p, tr, pr, th: (b, kv, _i32(0), _i32(0)),
-                     **mem),
-        pl.BlockSpec((1, 1, bs, hd),
-                     lambda b, kv, p, tr, pr, th: (tr[b, p], kv, _i32(0),
-                                                   _i32(0)), **mem),
-        pl.BlockSpec((1, 1, bs, hd),
-                     lambda b, kv, p, tr, pr, th: (tr[b, p], kv, _i32(0),
-                                                   _i32(0)), **mem),
+                     lambda b, kv, p, tr, pr, th, ly: (b, kv, _i32(0),
+                                                       _i32(0)), **mem),
+        page_spec, page_spec,
     ]
     inputs = [q_rows, key_cache, value_cache]
     if has_quant:
@@ -216,7 +235,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
         # dim) and the kernel picks its scalar out of the block
         scale_spec = pl.BlockSpec(
             (_SCALE_ROWS, KV),
-            lambda b, kv, p, tr, pr, th: (
+            lambda b, kv, p, tr, pr, th, ly: (
                 jax.lax.div(tr[b, p], _i32(_SCALE_ROWS)), _i32(0)),
             memory_space=pltpu.SMEM)
         in_specs += [scale_spec, scale_spec]
@@ -226,14 +245,14 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
                    jnp.pad(v_dequant.astype(jnp.float32), pad)]
     out_spec = pl.BlockSpec(
         (1, 1, rows, hd),
-        lambda b, kv, p, tr, pr, th: (b, kv, _i32(0), _i32(0)), **mem)
+        lambda b, kv, p, tr, pr, th, ly: (b, kv, _i32(0), _i32(0)), **mem)
     for spec, arr in zip(in_specs, inputs):
         _assert_mosaic_tileable(spec.block_shape, arr.shape, "paged input")
     _assert_mosaic_tileable(out_spec.block_shape, q_rows.shape,
                             "paged output")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, KV, max_blocks),
         in_specs=in_specs,
         out_specs=out_spec,
@@ -253,4 +272,71 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), q_rows.dtype),
         interpret=interpret,
-    )(tables, past, this, *inputs)
+    )(tables, past, this, layer, *inputs)
+
+
+def _write_kernel(layer_ref, page_ref, lo_ref, hi_ref, k_new_ref, v_new_ref,
+                  k_in_ref, v_in_ref, k_out_ref, v_out_ref):
+    """One touched page: slots [lo, hi) take the staged rows, the others
+    keep what the page held. The select runs on 32-bit lanes (exact for
+    bf16 and int8 pages alike); `layer_ref` and `page_ref` are read by
+    the index maps only."""
+    del layer_ref, page_ref
+    j = pl.program_id(0)
+    slot = jax.lax.broadcasted_iota(jnp.int32, k_in_ref.shape, 2)
+    fresh = (slot >= lo_ref[j]) & (slot < hi_ref[j])
+    for new_ref, in_ref, out_ref in ((k_new_ref, k_in_ref, k_out_ref),
+                                     (v_new_ref, v_in_ref, v_out_ref)):
+        wide = jnp.int32 if out_ref.dtype == jnp.int8 else jnp.float32
+        out_ref[...] = jnp.where(fresh, new_ref[...].astype(wide),
+                                 in_ref[...].astype(wide)
+                                 ).astype(out_ref.dtype)
+
+
+def write_pages(key_pool, value_pool, layer, pages, lo, hi, k_new, v_new,
+                interpret: Optional[bool] = None):
+    """Write the new tokens' rows into the stacked pools in place, a page
+    at a time: for each plan entry j, slots [lo[j], hi[j]) of
+    `pool[layer, pages[j]]` take `new[j]`'s rows and the other slots keep
+    theirs. The pools [L, num_blocks, KV, block_size, hd] are aliased
+    input to output (`input_output_aliases`), so pages outside the plan
+    are never read or written.
+
+    pages / lo / hi [n] int32, k_new / v_new [n, KV, block_size, hd] in the
+    pools' dtype. An entry may repeat the one before it (same page, same
+    slots, same rows: the block stays put and the result is the same),
+    which is how the caller pads a plan; apart from that the plan's pages
+    are distinct, as the pages that a batch's sequences write are.
+    Returns (key_pool, value_pool)."""
+    _, _, KV, bs, hd = key_pool.shape
+    n = pages.shape[0]
+    if interpret is None:
+        interpret = not available()
+    mem = {"memory_space": pltpu.VMEM}
+    new_spec = pl.BlockSpec(
+        (1, KV, bs, hd),
+        lambda j, ly, pg, lo_, hi_: (j, _i32(0), _i32(0), _i32(0)), **mem)
+    page_spec = pl.BlockSpec(
+        (None, 1, KV, bs, hd),
+        lambda j, ly, pg, lo_, hi_: (ly[0], pg[j], _i32(0), _i32(0),
+                                     _i32(0)), **mem)
+    _assert_mosaic_tileable(new_spec.block_shape, k_new.shape, "page write")
+    _assert_mosaic_tileable(page_spec.block_shape, key_pool.shape,
+                            "page write")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(n,),
+        in_specs=[new_spec, new_spec, page_spec, page_spec],
+        out_specs=[page_spec, page_spec])
+    count_launch()
+    return pl.pallas_call(
+        _write_kernel,
+        name="paged_cache_write",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(key_pool.shape, key_pool.dtype),
+                   jax.ShapeDtypeStruct(value_pool.shape, value_pool.dtype)],
+        # operands count the four prefetched scalars: pools are 6 and 7
+        input_output_aliases={6: 0, 7: 1},
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), pages.astype(jnp.int32),
+      lo.astype(jnp.int32), hi.astype(jnp.int32), k_new, v_new,
+      key_pool, value_pool)

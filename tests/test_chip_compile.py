@@ -145,6 +145,52 @@ def test_paged_attention_compiles(one_chip, max_q, int8_pages):
     _compile(fn, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("rows", [16, 512])        # decode / mixed tick
+@pytest.mark.parametrize("int8_pages", [False, True])
+def test_paged_layer_in_stacked_pool_compiles(one_chip, rows, int8_pages):
+    """The serve tick's layer on the stacked pool (Mistral-7B's 8 KV heads
+    of 128, 16-token pages): the page-write kernel with the pools aliased
+    in to out, then the read through the layer index. No operation of
+    the pool's shape but the write kernel's own may come out of it: a
+    pool-shaped copy is a layout change of 2.4 GB a layer."""
+    import re
+
+    from paddle_tpu.ops.kernels import serving_attention as sa
+    # the benchmark's pool: too large for the compiler to stage it anywhere
+    layers, num_blocks, kv, block_size = 16, 2304, 8, 16
+    batch, max_blocks = 16, 128
+    page_dtype = jnp.int8 if int8_pages else jnp.bfloat16
+    pool = ((layers, num_blocks, kv, block_size, HEAD_DIM), page_dtype)
+    shapes = [_bf16(rows, (HEADS + 2 * kv) * HEAD_DIM), pool, pool,
+              ((), jnp.int32), ((batch,), jnp.int32), ((batch,), jnp.int32),
+              ((batch + 1,), jnp.int32), ((batch, max_blocks), jnp.int32)]
+    if int8_pages:
+        shapes += [((kv,), jnp.float32)] * 2
+        shapes += [((num_blocks, kv), jnp.float32)] * 2
+
+    def fn(qkv, kp, vp, layer, past, this, cu, tables, *scales):
+        return sa.paged_layer_attention(
+            qkv, kp, vp, layer, past, this, cu, tables,
+            quant_scales=scales or None,
+            use_pallas="decode" if rows == batch else True)
+
+    # available() is False here, which would trace the kernels in interpret
+    # mode: steer it for this compile (the program has no option for it)
+    available = pa.available
+    pa.available = lambda: True
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    try:    # the pools are donated, as the tick donates them
+        text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile(
+        ).as_text()
+    finally:
+        pa.available = available
+    assert "paged_cache_write" in text and "paged_attention" in text
+    shape = re.escape("[%d,%d,%d,%d,%d]" % (layers, num_blocks, kv,
+                                            block_size, HEAD_DIM))
+    made = re.findall(r"%(\S+) = [^ ]*" + shape + r"\S* (\w[\w-]*)\(", text)
+    assert {op for _, op in made} <= {"parameter", "get-tuple-element"}, made
+
+
 @pytest.mark.parametrize("top_k", [0, 50])
 def test_fused_sample_prep_compiles(one_chip, top_k):
     batch = 8

@@ -14,6 +14,8 @@ streaming delivery, sampling determinism, zero-retrace steady state, the
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -553,7 +555,37 @@ def _mha_both(args, pallas_mode=True):
     stock = block_multihead_attention_.__wrapped__(use_pallas=False, **args)
     pal = block_multihead_attention_.__wrapped__(use_pallas=pallas_mode,
                                                  **args)
+    _assert_stacked_pool_matches(args, pallas_mode, pal)
     return stock, pal
+
+
+def _assert_stacked_pool_matches(args, pallas_mode, pal):
+    """The engine's call: the same caches as layer 1 of a three-layer
+    pool, read and written where they lie through the layer index. Same
+    bits as the one-layer op, and the other layers are not touched."""
+    from paddle_tpu.ops.kernels.serving_attention import (
+        paged_layer_attention)
+    rs = np.random.RandomState(99)
+
+    def pool(cache):
+        c = np.asarray(cache)
+        other = rs.randint(-100, 100, (2,) + c.shape).astype(c.dtype)
+        return jnp.asarray(np.stack([other[0], c, other[1]]))
+    kp, vp = pool(args["key_cache"]), pool(args["value_cache"])
+    scales = tuple(args[k] for k in (
+        "cache_k_quant_scales", "cache_v_quant_scales",
+        "cache_k_dequant_scales", "cache_v_dequant_scales") if k in args)
+    out, _, kp2, vp2 = jax.jit(
+        lambda layer: paged_layer_attention(
+            args["qkv"], kp, vp, layer, args["seq_lens_decoder"],
+            args["seq_lens_this_time"], args["cu_seqlens_q"],
+            args["block_tables"], quant_scales=scales or None,
+            use_pallas=pallas_mode))(jnp.int32(1))
+    assert np.array_equal(np.asarray(out), np.asarray(pal[0]))
+    for got, before, want in ((kp2, kp, pal[2]), (vp2, vp, pal[3])):
+        got = np.asarray(got)
+        assert np.array_equal(got[1], np.asarray(want))
+        assert np.array_equal(got[[0, 2]], np.asarray(before)[[0, 2]])
 
 
 class TestPallasPagedAttention:
@@ -641,6 +673,17 @@ class TestPallasPagedAttention:
         assert np.all(o[0, :, 2:] == 0.0)       # t >= this[0]
         assert np.all(o[1, :, 4:] == 0.0)       # t >= this[1]
         assert np.all(o[0, :, :2] != 0.0)
+
+    def test_stacked_pool_needs_its_layer(self):
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        q = jnp.zeros((1, 1, 2, 8), jnp.float32)
+        pool = jnp.zeros((3, 2, 1, 8, 8), jnp.float32)
+        bt = jnp.zeros((1, 2), jnp.int32)
+        z = jnp.zeros((1,), jnp.int32)
+        with pytest.raises(ValueError, match="layer"):
+            PA.paged_attention(q, pool, pool, bt, z, z, 2, 1.0)
+        with pytest.raises(ValueError, match="layer"):
+            PA.paged_attention(q, pool[0], pool[0], bt, z, z, 2, 1.0, layer=1)
 
 
 class TestEnginePallas:
@@ -740,3 +783,334 @@ class TestEnginePallas:
         eng.run()
         s = obs.summary()["serving"]
         assert s["pallas_steps"] == eng.stats["pallas_steps"] > 0
+
+
+# ---------------------------------------------------------------------------
+# The page pool is updated in place: structure of the tick, and its contents
+# ---------------------------------------------------------------------------
+
+ENGINE_KW = dict(num_blocks=48, block_size=4, max_batch=4, token_budget=16)
+
+
+def _step_args(eng, tok_pad, tokens=None, tables=None, cu=None, past=None,
+               this=None):
+    """The fifteen arguments `_step` hands the tick's executable (greedy,
+    no adapters), zero-filled where the caller gives nothing."""
+    B = eng.max_batch
+
+    def arr(v, shape, dtype, fill=0):
+        return jnp.asarray(np.full(shape, fill, dtype) if v is None
+                           else np.asarray(v, dtype))
+    return (eng.params, eng._key_cache, eng._value_cache, eng._kv_scales,
+            arr(tokens, (tok_pad,), np.int32),
+            arr(tables, (B, eng.max_blocks_per_seq), np.int32, -1),
+            arr(cu, (B + 1,), np.int32), arr(past, (B,), np.int32),
+            arr(this, (B,), np.int32), eng._rope_emb,
+            jnp.ones((B,), jnp.float32), jnp.ones((B,), jnp.float32),
+            jnp.zeros((B, 2), jnp.uint32), jnp.ones((B,), bool), ())
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+class TestPoolUpdatedInPlace:
+    """Counts, not times: the guard that keeps a later refactor from
+    bringing the whole-pool passes back into the tick."""
+
+    @pytest.mark.parametrize("pallas", [True, False])
+    @pytest.mark.parametrize("tick", ["decode", "mixed"])
+    def test_tick_carries_the_pool_and_writes_rows(self, tiny, pallas, tick):
+        cfg, params = tiny
+        eng = PagedServingEngine(cfg, params, pallas=pallas, **ENGINE_KW)
+        # a decode tick packs max_batch rows (and with the kernel takes the
+        # max_q=1 launch), a mixed tick token_budget rows
+        tok_pad = eng.max_batch if tick == "decode" else eng.token_budget
+        mode = pallas and ("decode" if tick == "decode" else True)
+        fn = eng._build_step(tok_pad, eng.max_batch, mode)
+        args = _step_args(eng, tok_pad)
+        pool = eng._key_cache.shape
+        layer = pool[1:]
+        slots = eng.num_blocks * eng.block_size
+
+        eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+        scans = [e for e in eqns if e.primitive.name == "scan"
+                 and e.params["length"] == cfg.num_layers]
+        assert len(scans) == 1
+        scan = scans[0]
+        nc, ncar = scan.params["num_consts"], scan.params["num_carry"]
+        carry = [v.aval.shape for v in scan.invars[nc:nc + ncar]]
+        xs = [v.aval.shape for v in scan.invars[nc + ncar:]]
+        ys = [v.aval.shape for v in scan.outvars[ncar:]]
+        # both caches are loop state; nothing of the pool's shape, whole or
+        # one layer of it, is scanned over or stacked up
+        assert carry.count(pool) == 2
+        assert pool not in xs + ys and layer not in xs + ys
+        # the write is a row write: no one-hot matmul and no select over
+        # every slot of a layer
+        for e in eqns:
+            if e.primitive.name in ("dot_general", "select_n"):
+                shapes = [v.aval.shape for v in e.invars]
+                assert not any(slots in sh for sh in shapes), (e, shapes)
+        if pallas:
+            # ...and the kernel reads the stack through the layer index: no
+            # equation of the loop body yields one layer of the pool
+            body = list(_eqns(scan.params["jaxpr"].jaxpr))
+            made = [v.aval.shape for e in body for v in e.outvars]
+            assert layer not in made and (1,) + layer not in made
+
+        # input, loop state and output are one buffer: the donated caches
+        # alias the step's outputs
+        text = fn.lower(*args).as_text()
+        aliased = re.findall(r"tensor<%s[^>]*> \{[^}]*tf\.aliasing_output"
+                             % "x".join(map(str, pool)), text)
+        assert len(aliased) == 2, text[:2000]
+
+    def test_step_build_names_the_write_form(self, tiny):
+        cfg, params = tiny
+        for pallas, want in ((True, "pallas_pages"), (False, "scatter_rows")):
+            eng = PagedServingEngine(cfg, params, pallas=pallas, **ENGINE_KW)
+            eng._get_step_fn(16, 4, pallas)
+            builds = [f for _, _, kind, _, f in obs.recorder().events()
+                      if kind == "serving.step_build"]
+            assert builds[-1]["cache_write"] == want
+
+
+def _onehot_layer(qkv, key_pool, value_pool, layer, seq_lens_decoder,
+                  seq_lens_this_time, cu_seqlens_q, block_tables, **kw):
+    """The reference for the pool's contents: the write this repo had
+    before the pool became loop state. One layer's pages are sliced out
+    of the stack, every slot of the layer is rewritten through a one-hot
+    product over the tick's rows, and the layer is put back. The rows
+    (split, rope, int8 rounding) and the attention output are the
+    program's own: only the write differs."""
+    from paddle_tpu.ops.kernels import serving_attention as SA
+    out, qkv_out, _, _ = SA.paged_layer_attention(
+        qkv, key_pool, value_pool, layer, seq_lens_decoder,
+        seq_lens_this_time, cu_seqlens_q, block_tables, **kw)
+    _, nb, KV, bs, hd = key_pool.shape
+    tok, B = qkv.shape[0], block_tables.shape[0]
+    H = qkv.shape[1] // hd - 2 * KV
+    qkv3 = qkv.reshape(tok, H + 2 * KV, hd)
+    k_tok, v_tok = qkv3[:, H:H + KV], qkv3[:, H + KV:]
+    cu = cu_seqlens_q.astype(jnp.int32)
+    idx = jnp.arange(tok, dtype=jnp.int32)
+    tok_b = jnp.clip(jnp.searchsorted(cu, idx, side="right") - 1, 0, B - 1)
+    local = idx - cu[tok_b]
+    pos = seq_lens_decoder[tok_b] + local
+    valid = local < seq_lens_this_time[tok_b]
+    if kw.get("rope_emb") is not None:
+        cos_t, sin_t = SA._rotary_table(kw["rope_emb"], hd)
+        k_tok = SA._rope_pairwise(k_tok, cos_t[0, pos][:, None],
+                                  sin_t[0, pos][:, None],
+                                  kw["use_neox_style"])
+    if kw.get("quant_scales") is not None:
+        kq, vq = kw["quant_scales"][:2]
+        k_tok = jnp.clip(jnp.round(k_tok * kq.reshape(1, KV, 1)), -127, 127)
+        v_tok = jnp.clip(jnp.round(v_tok * vq.reshape(1, KV, 1)), -127, 127)
+    page = jnp.take_along_axis(block_tables[tok_b], (pos // bs)[:, None],
+                               axis=1)[:, 0]
+    flat = jnp.where(valid, page * bs + pos % bs, -1)
+    onehot = flat[None, :] == jnp.arange(nb * bs)[:, None]     # [slots, tok]
+    written = onehot.any(axis=1)[:, None, None]
+
+    def write(pool, rows):
+        pages = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+        slots = pages.transpose(0, 2, 1, 3).reshape(nb * bs, KV, hd)
+        new = jnp.einsum("st,tkd->skd", onehot.astype(jnp.float32),
+                         rows.astype(jnp.float32),
+                         precision="highest").astype(pool.dtype)
+        slots = jnp.where(written, new, slots)
+        pages = slots.reshape(nb, bs, KV, hd).transpose(0, 2, 1, 3)
+        return jax.lax.dynamic_update_index_in_dim(pool, pages, layer, 0)
+    return out, qkv_out, write(key_pool, k_tok), write(value_pool, v_tok)
+
+
+def _sentinel_pools(eng, seed=5):
+    """Fill the engine's pools with a pattern, so that a page nobody
+    writes can be told from one that was rewritten with what it held."""
+    rs = np.random.RandomState(seed)
+    shape, dtype = eng._key_cache.shape, eng._key_cache.dtype
+    mk = ((lambda: rs.randint(-127, 128, shape).astype(np.int8))
+          if dtype == jnp.int8 else
+          (lambda: rs.randn(*shape).astype(np.float32)))
+    k, v = mk(), mk()
+    eng._key_cache, eng._value_cache = jnp.asarray(k), jnp.asarray(v)
+    return k, v
+
+
+@pytest.fixture(scope="module")
+def kv_manifest(tiny):
+    from paddle_tpu.inference import quant as Q
+    cfg, params = tiny
+    rs = np.random.RandomState(7)
+    return Q.calibrate(cfg, params,
+                       [rs.randint(1, cfg.vocab_size, (2, 12))
+                        for _ in range(2)])
+
+
+class TestPoolContentParity:
+    """After N ticks the carried pool holds, bit for bit, what the
+    one-hot write puts there from the same inputs, and pages that no
+    sequence owns keep what they held."""
+
+    # name -> (prompt lengths, new tokens, engine options)
+    CASES = {
+        "decode_ticks": ([3, 5, 2, 6], 7, {}),
+        "chunk_ends_mid_page": ([37, 3], 3, {}),    # 16 + 16 + 5 rows, 4 a page
+        "idle_slots": ([6], 5, {}),
+        "int8_pages": ([9, 4, 18], 5, {"quant_kv": True}),
+        "spec_mode": ([5, 7], 8, {"spec": True}),
+        "lora_class": ([6, 11, 4], 5, {"lora": True}),
+    }
+
+    def _run(self, tiny, kv_manifest, pallas, lens, new, opts):
+        from paddle_tpu.inference.serving import DraftModel, make_adapter
+        cfg, params = tiny
+        kw = dict(ENGINE_KW)
+        if opts.get("quant_kv"):
+            kw.update(quant_kv=True, quant_manifest=kv_manifest)
+        if opts.get("spec"):
+            dcfg = L.LlamaConfig(vocab_size=97, hidden_size=32,
+                                 intermediate_size=64, num_layers=1,
+                                 num_heads=4, num_kv_heads=2, max_seq_len=96,
+                                 dtype=jnp.float32)
+            dparams = dict(params, blocks=jax.tree.map(lambda a: a[:1],
+                                                       params["blocks"]))
+            kw.update(draft=DraftModel(dcfg, dparams), spec_k=3)
+        if opts.get("lora"):
+            kw.update(adapter_slots=2)
+        eng = PagedServingEngine(cfg, params, pallas=pallas, max_len=96, **kw)
+        if opts.get("lora"):
+            eng.adapters.register(make_adapter(cfg, "a", rank=4, seed=3))
+        before = _sentinel_pools(eng)
+        owned, table_of = set(), eng.blocks.block_table
+
+        def spy(rid):
+            row = table_of(rid)
+            owned.update(int(b) for b in row)
+            return row
+        eng.blocks.block_table = spy
+        rids = [eng.submit(p, max_new_tokens=new,
+                           **({"adapter": "a"} if opts.get("lora") and i == 0
+                              else {}))
+                for i, p in enumerate(_prompts(cfg, len(lens), lens, seed=31))]
+        done = {c.rid: c.output_tokens for c in eng.run()}
+        return ([done[r] for r in rids], np.asarray(eng._key_cache),
+                np.asarray(eng._value_cache), before, owned, eng.stats)
+
+    @pytest.mark.parametrize("pallas", [True, False])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_engine_pool_equals_onehot_write(self, tiny, kv_manifest,
+                                             monkeypatch, case, pallas):
+        from paddle_tpu.inference.serving import engine as E
+        lens, new, opts = self.CASES[case]
+        toks, k, v, (k0, v0), owned, stats = self._run(
+            tiny, kv_manifest, pallas, lens, new, opts)
+        monkeypatch.setattr(E, "paged_layer_attention", _onehot_layer)
+        ref_toks, rk, rv, _, ref_owned, _ = self._run(
+            tiny, kv_manifest, pallas, lens, new, opts)
+        assert toks == ref_toks and owned == ref_owned
+        assert np.array_equal(k, rk) and np.array_equal(v, rv)
+        free = sorted(set(range(ENGINE_KW["num_blocks"])) - owned)
+        assert free and len(owned) >= len(lens)
+        assert np.array_equal(k[:, free], k0[:, free])
+        assert np.array_equal(v[:, free], v0[:, free])
+        assert not np.array_equal(k, k0)
+        if case == "decode_ticks" and pallas:
+            assert stats["decode_fast_steps"] > 0
+        if case == "spec_mode":
+            assert stats["spec_ticks"] > 0
+
+    @pytest.mark.parametrize("pallas", [True, "decode", False])
+    def test_unassigned_table_entry_writes_nothing(self, tiny, monkeypatch,
+                                                   pallas):
+        """A row whose table entry is −1 is dropped, the rows beside it
+        land, pad rows and idle slots write nothing."""
+        from paddle_tpu.inference.serving import engine as E
+        cfg, params = tiny
+        B, bs = ENGINE_KW["max_batch"], ENGINE_KW["block_size"]
+        decode = pallas == "decode"
+        tok_pad = B if decode else ENGINE_KW["token_budget"]
+        if decode:      # slot 1's only page is unassigned, slot 3 is idle
+            past, this = [5, 2, 9, 0], [1, 1, 1, 0]
+            tables = {0: [7, 3], 1: [-1], 2: [11, 12, 13]}
+        else:           # slot 0's chunk crosses an unassigned page
+            past, this = [2, 0, 9, 0], [9, 0, 1, 0]
+            tables = {0: [7, -1, 21], 2: [11, 12, 13]}
+        cu = np.concatenate([[0], np.cumsum(this)])
+        tab = np.full((B, 96 // bs), -1, np.int32)
+        for b, row in tables.items():
+            tab[b, :len(row)] = row
+        tokens = np.arange(1, tok_pad + 1) % cfg.vocab_size
+
+        def tick():
+            eng = PagedServingEngine(cfg, params, pallas=bool(pallas),
+                                     max_len=96, **ENGINE_KW)
+            before = _sentinel_pools(eng)
+            fn = eng._get_step_fn(tok_pad, B, pallas)
+            _, kc, vc = fn(*_step_args(eng, tok_pad, tokens, tab, cu, past,
+                                       this))
+            return np.asarray(kc), np.asarray(vc), before
+        k, v, (k0, v0) = tick()
+        monkeypatch.setattr(E, "paged_layer_attention", _onehot_layer)
+        rk, rv, _ = tick()
+        assert np.array_equal(k, rk) and np.array_equal(v, rv)
+        changed = {int(p) for p in
+                   np.nonzero((k != k0).any(axis=(0, 2, 3, 4)))[0]}
+        assert changed == ({3, 13} if decode else {7, 21, 13})
+
+
+class TestPagePlan:
+    """`page_plan` against a walk over the tokens: every valid token has
+    its page, slot and source row in the plan exactly once, nothing else
+    is in it, and an entry is repeated only right after itself."""
+
+    @pytest.mark.parametrize("B, bs, tokens, max_blocks, num_blocks", [
+        (16, 16, 512, 128, 2304),      # the benchmark's mixed tick
+        (16, 16, 16, 128, 2304),       # ... and its decode tick
+        (4, 4, 16, 24, 96), (3, 8, 7, 6, 40)])
+    def test_plan_is_the_tokens_pages(self, B, bs, tokens, max_blocks,
+                                      num_blocks):
+        from paddle_tpu.ops.kernels.serving_attention import page_plan
+        rs = np.random.RandomState(B * tokens)
+        plan = jax.jit(lambda *a: page_plan(*a, num_blocks, bs, tokens))
+        for _ in range(40):
+            this, left = np.zeros(B, np.int64), tokens
+            for b in rs.permutation(B):   # idle, decode row or a chunk
+                kind = rs.randint(4)
+                n = (0, 1)[kind] if kind < 2 else rs.randint(1, left + 1) \
+                    if left else 0
+                this[b] = min(n, left)
+                left -= this[b]
+            past = np.array([rs.randint(0, max_blocks * bs - n + 1)
+                             for n in this])
+            cu = np.concatenate([[0], np.cumsum(this)])
+            tab = np.full((B, max_blocks), -1, np.int64)
+            free = iter(rs.permutation(num_blocks))
+            for b in range(B):
+                for p in range(-(-(past[b] + this[b]) // bs)):
+                    tab[b, p] = next(free)
+            if rs.rand() < 0.3:           # an unassigned entry somewhere
+                tab[rs.randint(B), rs.randint(max_blocks)] = -1
+            pages, lo, hi, src = (np.asarray(a) for a in plan(
+                *(jnp.asarray(a, jnp.int32) for a in (past, this, cu, tab))))
+            want = {(tab[b, (past[b] + i) // bs], (past[b] + i) % bs):
+                    cu[b] + i for b in range(B) for i in range(this[b])
+                    if tab[b, (past[b] + i) // bs] >= 0}
+            got = {}
+            for j in range(len(pages)):
+                if j and pages[j] in pages[:j] and hi[j] > lo[j]:
+                    assert (pages[j], lo[j], hi[j]) == (
+                        pages[j - 1], lo[j - 1], hi[j - 1])
+                    assert np.array_equal(src[j], src[j - 1])
+                for s in range(lo[j], hi[j]):
+                    got[(pages[j], s)] = src[j, s]
+            assert got == want
