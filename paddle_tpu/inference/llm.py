@@ -79,12 +79,12 @@ def _block_cached(x, lp, cfg: L.LlamaConfig, cache_k, cache_v, pos,
     B, T, d = x.shape
     hd, nh, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
     h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = Q.matmul_param(h, lp, "wq").reshape(B, T, nh, hd)
-    k = Q.matmul_param(h, lp, "wk").reshape(B, T, nkv, hd)
+    q, k = L.qk_normed(Q.matmul_param(h, lp, "wq"),
+                       Q.matmul_param(h, lp, "wk"), lp, cfg)
     v = Q.matmul_param(h, lp, "wv").reshape(B, T, nkv, hd)
     cos, sin = L.rope_cos_sin(pos + jnp.arange(T), hd, cfg.rope_theta)
-    q = L.apply_rope(q, cos, sin)
-    k = L.apply_rope(k, cos, sin)
+    q = L.apply_rope(q.reshape(B, T, nh, hd), cos, sin)
+    k = L.apply_rope(k.reshape(B, T, nkv, hd), cos, sin)
     cache_k = lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype),
                                               pos, axis=1)
     cache_v = lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype),
@@ -98,7 +98,7 @@ def _block_cached(x, lp, cfg: L.LlamaConfig, cache_k, cache_v, pos,
     x = x + Q.matmul_param(o.reshape(B, T, nh * hd), lp, "wo")
     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if cfg.num_experts:
-        x = x + L.moe_mlp(h, lp, cfg)
+        x = x + L.routed_ffn(h, lp, cfg)
     elif ffn_impl == "pallas" and _ffn_fusable(h, lp):
         x = x + FF.apply_ffn(h, lp)
     else:

@@ -146,10 +146,14 @@ class PagedServingEngine:
                  adapter_slots: Optional[int] = None,
                  draft: Optional[Any] = None,
                  spec_k: Optional[int] = None):
-        if cfg.num_experts:
+        if cfg.num_experts and (draft is not None or pallas_ffn
+                                or Q.resolve_quant_mode(quant_mode)):
             raise NotImplementedError(
-                "PagedServingEngine serves dense LLaMA; route MoE decode "
-                "through LLMPredictor until the paged MoE step lands")
+                "PagedServingEngine serves routed experts in fp weights "
+                "through models.llama.routed_ffn; a draft model, the fused "
+                "Pallas FFN (pallas_ffn=True) and weight quantisation "
+                "(quant_mode) cover dense FFNs only: drop them for a "
+                "config with num_experts (int8 pages, quant_kv, are fine)")
         # apply any FLAGS_tuned_profile before geometry is resolved and
         # executables are keyed, so a pinned profile is zero-retrace
         from ... import tuner as _tuner
@@ -224,6 +228,12 @@ class PagedServingEngine:
                       "fused_ticks": 0, "tick_pallas_launches": 0,
                       "spec_ticks": 0, "spec_proposed": 0,
                       "spec_accepted": 0}
+        if cfg.num_experts:
+            # routed-expert work, summed over ticks (max_load: the largest
+            # seen): (row, expert) pairs, (layer, expert) groups with at
+            # least one row, most rows on one expert in one layer
+            self.stats.update(moe_pairs=0, moe_experts_hit=0,
+                              moe_max_load=0)
         # multi-tenant LoRA adapters: paged ref-counted device slots.
         # Always constructed (device packs allocate lazily on the first
         # registered adapter), so submit(adapter=...) works out of the box
@@ -561,6 +571,8 @@ class PagedServingEngine:
             return True, None
         if not flags.flag_value("pallas_ffn"):
             return False, None
+        if self.cfg.num_experts:
+            return False, "moe"
         blocks0 = self.params["blocks"]
         kind = FF.params_kind(blocks0)
         if kind is None:
@@ -603,14 +615,18 @@ class PagedServingEngine:
                     greedy, ad_args):
             # named scopes: every device operation of the tick belongs to
             # a region named here (embed; layers > qkv, cache_write,
-            # paged_attention, attn_out, ffn; head; sample), whatever
-            # number the compiler gives it. Metadata only.
+            # paged_attention, attn_out, ffn or moe > router, dispatch,
+            # experts, combine; head; sample), whatever number the
+            # compiler gives it. Metadata only.
             with jax.named_scope("embed"):
                 x = jnp.take(params["embed"], tokens,
                              axis=0).astype(cfg.dtype)
             # per-class token->slot scaling selectors (closed over by the
             # scan body — they carry no layer axis)
             ad_sels = tuple(a["sel"] for a in ad_args)
+            # rows are packed from 0: what lies behind the last chunk is
+            # padding, which no expert may see
+            valid = jnp.arange(tok_pad) < cu_seqlens_q[B]
 
             def body(carry, layer):
                 # the stacked page pool rides the carry, so the step's
@@ -645,6 +661,7 @@ class PagedServingEngine:
                     q = lora(h, "wq", Q.matmul_param(h, lp, "wq"))
                     k = lora(h, "wk", Q.matmul_param(h, lp, "wk"))
                     v = lora(h, "wv", Q.matmul_param(h, lp, "wv"))
+                    q, k = L.qk_normed(q, k, lp, cfg)
                     qkv = jnp.concatenate([q, k, v], axis=-1)
                 # scopes itself: qkv (split, rope), cache_write,
                 # paged_attention
@@ -655,6 +672,13 @@ class PagedServingEngine:
                     use_neox_style=True, use_pallas=pallas_mode)
                 with jax.named_scope("attn_out"):
                     x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
+                if cfg.num_experts:
+                    with jax.named_scope("moe"):
+                        h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+                        y, load = L.routed_ffn_load(
+                            h, {**lp, **experts}, cfg, valid, layer=li)
+                        x = x + y
+                    return (x, kcs, vcs), (jnp.sum(load > 0), jnp.max(load))
                 with jax.named_scope("ffn"):
                     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
                     if ffn_mode:
@@ -667,15 +691,20 @@ class PagedServingEngine:
                         x = x + Q.matmul_param(gate, lp, "w2")
                 return (x, kcs, vcs), None
 
-            # scanned over: the layer index and what a layer only reads
+            # scanned over: the layer index and what a layer only reads.
+            # The expert matrices stay whole: the expert kernel finds a
+            # layer's by its index, like the page pool's readers
+            experts = {n: params["blocks"][n] for n in
+                       (("w1", "w3", "w2") if cfg.num_experts else ())}
             xs = (jnp.arange(cfg.num_layers, dtype=jnp.int32),
-                  params["blocks"])
+                  {n: v for n, v in params["blocks"].items()
+                   if n not in experts})
             if quant_kv:
                 xs = xs + tuple(kv_scales)   # kq, vq [L,KV]; kdq,vdq [L,nb,KV]
             # stacked adapter packs ride the layer scan like param leaves
             xs = xs + tuple(a["packs"] for a in ad_args)
             with jax.named_scope("layers"):
-                (x, kcs, vcs), _ = lax.scan(
+                (x, kcs, vcs), loads = lax.scan(
                     body, (x, key_cache, value_cache), xs)
             with jax.named_scope("head"):
                 # last-token hidden state per slot (cu[1:]-1; idle slots
@@ -703,6 +732,14 @@ class PagedServingEngine:
                     nxt_sampled = _sample_rows(logits, keys, temps, top_ps,
                                                top_k)
                 nxt = jnp.where(greedy, nxt_greedy, nxt_sampled)
+            if cfg.num_experts:
+                # the tick's expert counters ride behind the B tokens, so
+                # the host's one fetch brings both
+                with jax.named_scope("moe"):
+                    hit, max_load = loads
+                    nxt = jnp.concatenate([nxt, jnp.stack([
+                        cu_seqlens_q[B] * cfg.top_k, jnp.sum(hit),
+                        jnp.max(max_load)]).astype(jnp.int32)])
             if spec_mode:
                 # the verify read: greedy argmax at EVERY packed row, so
                 # a k+1-wide speculative chunk's per-position targets
@@ -733,10 +770,12 @@ class PagedServingEngine:
             # into the carried pool (paged_layer_attention chooses by the
             # read path: the page-write kernel beside the Pallas read, an
             # XLA row scatter on the stock path)
+            # experts: the form `routed_ffn` computes the experts in
             _emit("serving.step_build", tok_pad=tok_pad, batch=B,
                   ad_sig=list(ad_sig), spec=bool(spec_mode),
                   cache_write="pallas_pages" if pallas_mode
-                  else "scatter_rows")
+                  else "scatter_rows",
+                  experts=L.expert_form(self.cfg))
         return fn
 
     def _copy_blocks(self, pairs: List[Tuple[int, int]]):
@@ -968,6 +1007,9 @@ class PagedServingEngine:
                 nxt, self._key_cache, self._value_cache = out
             nxt = np.asarray(nxt)     # the step's one sync point
             dur = (time.perf_counter_ns() - t0) * 1e-9
+            moe = None
+            if self.cfg.num_experts:
+                nxt, moe = nxt[:B], [int(c) for c in nxt[B:]]
 
         with _tracing.phase("serve.harvest"):
             if fused_tick and self.stats["step_builds"] > builds0:
@@ -985,11 +1027,20 @@ class PagedServingEngine:
             _emit("serving.step", dur_s=dur,
                   tokens=batch.total_tokens + spec_extra,
                   batch=len(batch.items), prefill_tokens=n_prefill)
+            fields = {}
+            if moe is not None:
+                fields = dict(zip(("moe_pairs", "moe_experts_hit",
+                                   "moe_max_load"), moe))
+                self.stats["moe_pairs"] += moe[0]
+                self.stats["moe_experts_hit"] += moe[1]
+                self.stats["moe_max_load"] = max(
+                    self.stats["moe_max_load"], moe[2])
             tick.set_metadata(
                 batch=len(batch.items),
                 tokens=batch.total_tokens + spec_extra,
                 prefill_tokens=n_prefill,
-                kind="decode" if pallas_mode == "decode" else "mixed")
+                kind="decode" if pallas_mode == "decode" else "mixed",
+                **fields)
             if _tracing.trace_enabled():
                 # per-request tick attribution: each traced request in the
                 # batch gets a span over this tick's device interval, so a

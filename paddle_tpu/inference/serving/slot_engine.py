@@ -91,12 +91,12 @@ def _block_decode_rows(x, lp, cfg: L.LlamaConfig, ck, cv, pos):
     hd, nh, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
     S = ck.shape[1]
     h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = (h @ lp["wq"].astype(h.dtype)).reshape(B, 1, nh, hd)
-    k = (h @ lp["wk"].astype(h.dtype)).reshape(B, 1, nkv, hd)
+    q, k = L.qk_normed(h @ lp["wq"].astype(h.dtype),
+                       h @ lp["wk"].astype(h.dtype), lp, cfg)
     v = (h @ lp["wv"].astype(h.dtype)).reshape(B, 1, nkv, hd)
     cos, sin = L.rope_cos_sin(pos, hd, cfg.rope_theta)   # [B, hd/2]
-    q = _apply_rope_rows(q, cos, sin)
-    k = _apply_rope_rows(k, cos, sin)
+    q = _apply_rope_rows(q.reshape(B, 1, nh, hd), cos, sin)
+    k = _apply_rope_rows(k.reshape(B, 1, nkv, hd), cos, sin)
     # per-row masked-select write at column pos[b] (scatter-free)
     write = (jnp.arange(S)[None, :] == pos[:, None])[:, :, None, None]
     ck = jnp.where(write, k.astype(ck.dtype), ck)
@@ -115,7 +115,7 @@ def _block_decode_rows(x, lp, cfg: L.LlamaConfig, ck, cv, pos):
     x = x + o.reshape(B, 1, nh * hd) @ lp["wo"].astype(o.dtype)
     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if cfg.num_experts:
-        x = x + L.moe_mlp(h, lp, cfg)
+        x = x + L.routed_ffn(h, lp, cfg)
     else:
         gate = jax.nn.silu(h @ lp["w1"].astype(h.dtype)) * (h @ lp["w3"].astype(h.dtype))
         x = x + gate @ lp["w2"].astype(h.dtype)

@@ -13,8 +13,9 @@ of its output/input dim is valid.
 
 TPU-first choices: bf16 compute / f32 master params, static shapes, scan over
 stacked layer params (one compiled block body, not L unrolled layers), GQA,
-RoPE computed in f32, optional MoE (top-k routing; the hybrid engine dispatches
-tokens with all_to_all over the ep axis).
+RoPE computed in f32, optional MoE (top-k routing through `route` and
+`routed_ffn`; the hybrid engine dispatches tokens with all_to_all over the
+ep axis) and optional QK-norm (`qk_normed`), as OLMoE has them.
 """
 from __future__ import annotations
 
@@ -41,6 +42,12 @@ class LlamaConfig:
     # MoE: 0 = dense MLP. When >0, every layer's MLP is a top-k gated MoE.
     num_experts: int = 0
     top_k: int = 2
+    # two shape keys of a model's own config.json (defaults: what every
+    # model before OLMoE had): RMSNorm over the whole projected q and k
+    # vectors before the split into heads, and whether the top-k router
+    # weights are renormalised to sum to one
+    qk_norm: bool = False
+    norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
 
@@ -52,6 +59,8 @@ class LlamaConfig:
         d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd = self.head_dim
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
+        if self.qk_norm:
+            attn += (self.num_heads + self.num_kv_heads) * hd
         if self.num_experts:
             mlp = self.num_experts * 3 * d * f + d * self.num_experts
         else:
@@ -60,11 +69,16 @@ class LlamaConfig:
         return v * d + self.num_layers * per_layer + d + d * v
 
     def flops_per_token(self) -> int:
-        """Approximate training FLOPs/token (fwd+bwd ≈ 6·N_active)."""
+        """Approximate training FLOPs/token (fwd+bwd ≈ 6·N_active): a
+        token passes through `top_k` of the experts and the router."""
         d, f = self.hidden_size, self.intermediate_size
         hd = self.head_dim
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
-        mlp = 3 * d * f * (min(self.top_k, self.num_experts) if self.num_experts else 1)
+        if self.num_experts:
+            mlp = (3 * d * f * min(self.top_k, self.num_experts)
+                   + d * self.num_experts)
+        else:
+            mlp = 3 * d * f
         dense = self.num_layers * (attn + mlp) + 2 * self.hidden_size * self.vocab_size
         return 6 * dense
 
@@ -101,6 +115,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "attn_norm": jnp.ones((L, d), pt),
         "mlp_norm": jnp.ones((L, d), pt),
     }
+    if cfg.qk_norm:
+        blocks["q_norm"] = jnp.ones((L, nh * hd), pt)
+        blocks["k_norm"] = jnp.ones((L, nkv * hd), pt)
     if cfg.num_experts:
         e = cfg.num_experts
         blocks["router"] = normal(keys[4], (L, d, e))
@@ -172,21 +189,182 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto") -> j
     return jnp.einsum("bhts,bshd->bthd", probs, v)
 
 
-def moe_mlp(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig) -> jax.Array:
-    """Dense (compute-all-experts) MoE for the single-device path. The hybrid
-    engine replaces this with an all_to_all token dispatch over the ep axis."""
+def qk_normed(q: jax.Array, k: jax.Array, lp: Dict[str, jax.Array],
+              cfg: LlamaConfig):
+    """OLMoE's QK-norm: RMSNorm over the WHOLE projected vector (q [..., H*hd],
+    k [..., KV*hd]), before the split into heads and before rope. Identity
+    for a model without it."""
+    if not cfg.qk_norm:
+        return q, k
+    return (rms_norm(q, lp["q_norm"], cfg.rms_eps),
+            rms_norm(k, lp["k_norm"], cfg.rms_eps))
+
+
+def route(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig):
+    """Top-k routing of rows h [T, d]: (w [T, k] f32, e [T, k] i32). The
+    softmax runs in float32 over ALL experts; the k weights are renormalised
+    to sum to one only where the model's config says so (`norm_topk_prob`)."""
     gate = jax.nn.softmax(
-        (x.astype(jnp.float32) @ lp["router"].astype(jnp.float32)), axis=-1)
-    topw, topi = lax.top_k(gate, cfg.top_k)
-    topw = topw / jnp.sum(topw, axis=-1, keepdims=True)
-    # combine weights [B, T, E]
-    comb = jnp.sum(jax.nn.one_hot(topi, cfg.num_experts, dtype=gate.dtype)
-                   * topw[..., None], axis=-2)
-    h = jnp.einsum("btd,edf->btef", x, lp["w1"].astype(x.dtype))
-    g = jnp.einsum("btd,edf->btef", x, lp["w3"].astype(x.dtype))
-    h = jax.nn.silu(h) * g
-    out = jnp.einsum("btef,efd->bted", h, lp["w2"].astype(x.dtype))
-    return jnp.einsum("bted,bte->btd", out, comb.astype(x.dtype))
+        h.astype(jnp.float32) @ lp["router"].astype(jnp.float32), axis=-1)
+    w, e = lax.top_k(gate, cfg.top_k)
+    if cfg.norm_topk_prob:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, e.astype(jnp.int32)
+
+
+def expert_form(cfg: LlamaConfig) -> Optional[str]:
+    """The form `routed_ffn` computes the experts in (None for a dense
+    model): rows sorted by expert through the grouped-matmul Pallas kernel
+    where that kernel can run (the TPU), else every expert over every row
+    in XLA, as `attention`'s 'auto' chooses its kernel. On the TPU v5e at
+    OLMoE's widths the sorted form won at a decode tick's 16 rows (1.05
+    against 1.20 ms a layer: it reads only the experts that are hit) and
+    at a mixed tick's 512 (1.54-1.76 against 2.46 ms: an eighth of the
+    FLOPs); `jax.lax.ragged_dot` lost to both (PERF.md section 6, PR 27)."""
+    if not cfg.num_experts:
+        return None
+    from ..ops.pallas import flash_attention as _fa
+
+    return "sorted_gmm" if _fa.available() else "dense_einsum"
+
+
+def _layer_of(w: jax.Array, layer) -> jax.Array:
+    return w if layer is None else w[layer]
+
+
+def _experts_dense(h, w, e, valid, lp, cfg: LlamaConfig, layer):
+    """Every expert over every row; a row's output keeps its k experts by a
+    [T, E] combine weight that is zero elsewhere (and on a padding row)."""
+    with jax.named_scope("dispatch"):
+        comb = jnp.sum(jax.nn.one_hot(e, cfg.num_experts, dtype=w.dtype)
+                       * w[..., None], axis=-2)                   # [T, E]
+        comb = jnp.where(valid[:, None], comb, 0.0)
+    with jax.named_scope("experts"):
+        w1, w3, w2 = (_layer_of(lp[n], layer).astype(h.dtype)
+                      for n in ("w1", "w3", "w2"))
+        g = jnp.einsum("td,edf->tef", h, w1)
+        u = jnp.einsum("td,edf->tef", h, w3)
+        a = jax.nn.silu(g) * u
+        out = jnp.einsum("tef,efd->ted", a, w2)
+    with jax.named_scope("combine"):
+        return jnp.einsum("ted,te->td", out, comb.astype(h.dtype))
+
+
+GMM_ROWS = 128      # the grouped-matmul kernel's row tile
+
+
+def _gmm(xs, w, group_sizes, group_offset):
+    from jax.experimental.pallas.ops.tpu import megablox
+    from ..ops.pallas import flash_attention as _fa
+
+    tile = lambda n, t: t if n % t == 0 else n
+    return megablox.gmm(
+        xs, w, group_sizes, preferred_element_type=xs.dtype,
+        tiling=(GMM_ROWS, tile(xs.shape[1], 1024), tile(w.shape[-1], 1024)),
+        group_offset=group_offset, interpret=not _fa.available())
+
+
+@jax.custom_vjp
+def _grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
+                    group_offset: jax.Array):
+    """xs [M, K] rows sorted by group (M a multiple of GMM_ROWS), w
+    [G, K, N], group_sizes [E] i32: row r of group g times
+    w[g - group_offset]; rows behind the last group are undefined. The
+    grouped-matmul Pallas kernel JAX ships (megablox `gmm`, tiles of
+    128 x 1024 x 1024: the fastest of five tilings on the v5e). It is
+    traced with x64 off, forward and backward: under this package's
+    jax_enable_x64 it hands the kernel a 64-bit scalar, which the TPU
+    compiler refuses."""
+    with jax.enable_x64(False):
+        return _gmm(xs, w, group_sizes, group_offset)
+
+
+def _grouped_matmul_fwd(xs, w, group_sizes, group_offset):
+    with jax.enable_x64(False):
+        return jax.vjp(lambda a, b: _gmm(a, b, group_sizes, group_offset),
+                       xs, w)
+
+
+def _grouped_matmul_bwd(pull, g):
+    with jax.enable_x64(False):
+        return (*pull(g), None, None)
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
+    """The T*k (row, expert) pairs sorted by expert, three grouped matmuls
+    over `load` rows a group, then un-sorted and summed over a row's k. A
+    padding row's pairs sort behind every group and belong to none.
+
+    With `layer`, the weights are the stacked [L, E, ...] leaves and the
+    kernel finds the layer's experts by its index map (group g reads
+    w[layer*E + g], i.e. a group offset of -layer*E): a slice of the
+    stack would be copied whole, 268 MB a matrix at OLMoE's widths, before
+    each launch."""
+    T, k = e.shape
+    pad = -(T * k) % GMM_ROWS
+    offset = jnp.asarray(0 if layer is None else -layer * cfg.num_experts,
+                         jnp.int32)
+
+    def dot(x, name):
+        wn = lp[name].astype(h.dtype)
+        return _grouped_matmul(x, wn.reshape(-1, *wn.shape[-2:]), load,
+                               offset)
+
+    # rows behind the last group are whatever the kernel left there, in
+    # either direction: select them away, do not multiply by zero
+    keep = (jnp.arange(T * k + pad) < jnp.sum(load))[:, None]
+    with jax.named_scope("dispatch"):
+        flat_e = jnp.where(valid[:, None], e, cfg.num_experts).reshape(-1)
+        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)  # [T*k]
+        xs = jnp.where(keep, jnp.take(h, jnp.pad(order // k, (0, pad)),
+                                      axis=0), 0)
+    with jax.named_scope("experts"):
+        a = jnp.where(keep, jax.nn.silu(dot(xs, "w1")) * dot(xs, "w3"), 0)
+        ys = dot(a, "w2")
+    with jax.named_scope("combine"):
+        ys = jnp.where(keep, ys, 0)[:T * k].astype(jnp.float32)
+        y = jnp.take(ys, jnp.argsort(order), axis=0).reshape(T, k, -1)
+        return jnp.sum(y * w[..., None], axis=1).astype(h.dtype)
+
+
+def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+                    valid: Optional[jax.Array] = None, layer=None):
+    """The routed SwiGLU experts over normed activations h [..., d]:
+    sum_j w_j * (silu(h W1[e_j]) * (h W3[e_j])) W2[e_j] over a row's top-k
+    experts. `valid` [...] bool marks the rows that exist (a serving tick
+    pads its rows): a padding row joins no expert's group, yields zeros and
+    counts in no load. With `layer` (an int32 scalar) `lp`'s w1, w3 and w2
+    are the stacked [L, E, ...] leaves, as a layer loop that must not
+    slice them hands them over; the router is the layer's own. Returns
+    (y [..., d], load [E] i32: valid rows on each expert). Scopes: router,
+    dispatch, experts, combine."""
+    shape = h.shape
+    h = h.reshape(-1, shape[-1])
+    T = h.shape[0]
+    valid = (jnp.ones((T,), bool) if valid is None
+             else valid.reshape(-1))
+    with jax.named_scope("router"):
+        w, e = route(h, lp, cfg)
+    with jax.named_scope("dispatch"):
+        load = jnp.sum(jax.nn.one_hot(e, cfg.num_experts, dtype=jnp.int32)
+                       * valid[:, None, None], axis=(0, 1),
+                       dtype=jnp.int32)                           # [E]
+    if expert_form(cfg) == "sorted_gmm":
+        y = _experts_sorted(h, w, e, valid, load, lp, cfg, layer)
+    else:
+        y = _experts_dense(h, w, e, valid, lp, cfg, layer)
+    return y.reshape(shape), load
+
+
+def routed_ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+               valid: Optional[jax.Array] = None) -> jax.Array:
+    """`routed_ffn_load` without the load (the one routed FFN of the tree:
+    `block`, `inference/llm.py` and the paged engine's tick call it; the
+    hybrid trainer dispatches over the ep axis with its own all_to_all)."""
+    return routed_ffn_load(h, lp, cfg, valid)[0]
 
 
 def ffn(h: jax.Array, lp: Dict[str, jax.Array], impl: str = "stock") -> jax.Array:
@@ -216,16 +394,16 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     B, T, d = x.shape
     hd, nh, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q = (h @ lp["wq"].astype(h.dtype)).reshape(B, T, nh, hd)
-    k = (h @ lp["wk"].astype(h.dtype)).reshape(B, T, nkv, hd)
+    q, k = qk_normed(h @ lp["wq"].astype(h.dtype),
+                     h @ lp["wk"].astype(h.dtype), lp, cfg)
     v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, nkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q = apply_rope(q.reshape(B, T, nh, hd), cos, sin)
+    k = apply_rope(k.reshape(B, T, nkv, hd), cos, sin)
     o = attention(q, k, v, impl=attn_impl).reshape(B, T, nh * hd)
     x = x + o @ lp["wo"].astype(o.dtype)
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if cfg.num_experts:
-        x = x + moe_mlp(h, lp, cfg)
+        x = x + routed_ffn(h, lp, cfg)
     else:
         x = x + ffn(h, lp, impl=ffn_impl)
     return x

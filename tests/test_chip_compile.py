@@ -191,6 +191,75 @@ def test_paged_layer_in_stacked_pool_compiles(one_chip, rows, int8_pages):
     assert {op for _, op in made} <= {"parameter", "get-tuple-element"}, made
 
 
+@pytest.mark.parametrize("rows", [16, 512])        # decode / mixed tick
+def test_paged_layer_compiles_at_gqa_group_one(one_chip, rows):
+    """OLMoE-1B-7B's attention (16 query and 16 KV heads of 128: a GQA
+    group of one, so a decode launch's q block is a single row) on its
+    benchmark pool."""
+    from paddle_tpu.ops.kernels import serving_attention as sa
+    layers, num_blocks, heads, block_size = 16, 768, 16, 16
+    batch, max_blocks = 16, 32
+    assert pa.supported(heads, heads, HEAD_DIM, block_size)
+    pool = ((layers, num_blocks, heads, block_size, HEAD_DIM), jnp.bfloat16)
+    shapes = [_bf16(rows, 3 * heads * HEAD_DIM), pool, pool,
+              ((), jnp.int32), ((batch,), jnp.int32), ((batch,), jnp.int32),
+              ((batch + 1,), jnp.int32), ((batch, max_blocks), jnp.int32)]
+
+    def fn(qkv, kp, vp, layer, past, this, cu, tables):
+        return sa.paged_layer_attention(
+            qkv, kp, vp, layer, past, this, cu, tables,
+            use_pallas="decode" if rows == batch else True)
+
+    available = pa.available
+    pa.available = lambda: True
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    try:
+        text = jax.jit(fn, donate_argnums=(1, 2)).lower(*args).compile(
+        ).as_text()
+    finally:
+        pa.available = available
+    assert "paged_cache_write" in text and "paged_attention" in text
+
+
+@pytest.mark.parametrize("rows", [16, 512])        # decode / mixed tick
+def test_routed_ffn_on_stacked_experts_compiles_without_a_copy(one_chip,
+                                                               rows):
+    """OLMoE-1B-7B's routed FFN as the serve tick calls it: the stacked
+    expert leaves and a layer index, under this package's jax_enable_x64
+    (which the grouped-matmul kernel cannot be traced with, so
+    `_grouped_matmul` turns it off around the call). No operation may
+    make anything of one layer's expert matrix's size: a slice of the
+    stack handed to the kernel is a copy of 268 MB, three a layer."""
+    import re
+
+    from paddle_tpu.models import llama as L
+    cfg = L.LlamaConfig(vocab_size=50304, hidden_size=2048,
+                        intermediate_size=1024, num_layers=16, num_heads=16,
+                        num_kv_heads=16, num_experts=64, top_k=8,
+                        qk_norm=True, norm_topk_prob=False,
+                        param_dtype=jnp.bfloat16)
+    lp = {"router": _bf16(2048, 64), "w1": _bf16(16, 64, 2048, 1024),
+          "w3": _bf16(16, 64, 2048, 1024), "w2": _bf16(16, 64, 1024, 2048)}
+
+    def fn(h, valid, layer, router, w1, w3, w2):
+        return L.routed_ffn_load(
+            h, {"router": router, "w1": w1, "w3": w3, "w2": w2}, cfg,
+            valid, layer=layer)
+
+    available = fa.available
+    fa.available = lambda: True         # expert_form and interpret read it
+    try:
+        assert L.expert_form(cfg) == "sorted_gmm"
+        text = _compile(fn, one_chip, _bf16(rows, 2048),
+                        ((rows,), jnp.bool_), ((), jnp.int32),
+                        lp["router"], lp["w1"], lp["w3"], lp["w2"]).as_text()
+    finally:
+        fa.available = available
+    made = re.findall(r"%(\S+) = bf16\[(?:64|1024),(?:2048|1024),"
+                      r"(?:1024|2048)\]\S* (\w[\w-]*)\(", text)
+    assert {op for _, op in made} <= {"parameter", "bitcast"}, made
+
+
 @pytest.mark.parametrize("top_k", [0, 50])
 def test_fused_sample_prep_compiles(one_chip, top_k):
     batch = 8

@@ -157,6 +157,18 @@ def tiny():
     return cfg, params
 
 
+@pytest.fixture(scope="module")
+def tiny_moe():
+    from paddle_tpu.models import llama as L
+
+    cfg = L.LlamaConfig(vocab_size=97, hidden_size=32,
+                        intermediate_size=16, num_layers=2, num_heads=4,
+                        num_kv_heads=4, max_seq_len=96, num_experts=8,
+                        top_k=2, qk_norm=True, norm_topk_prob=False,
+                        dtype=jnp.float32)
+    return cfg, L.init_params(cfg, jax.random.PRNGKey(0))
+
+
 def _factory(tiny, **kw):
     from paddle_tpu.inference.serving import PagedServingEngine
 
@@ -402,6 +414,44 @@ class TestPhasesOnTheProfilersClock:
         assert steps[-1]["prefill_tokens"] == 0
         assert steps[-1]["tokens"] == steps[-1]["batch"]
 
+    def test_moe_tick_adds_its_three_counters_to_the_step(
+            self, tiny_moe, tmp_path):
+        """A routed-expert tick's step span carries `moe_pairs`,
+        `moe_experts_hit` and `moe_max_load` beside the five fields of a
+        dense tick (which the test above pins as they were)."""
+        eng = _factory(tiny_moe)()
+        eng.submit(_prompt(tiny_moe[0], 6), max_new_tokens=2)
+        eng.run()                          # both executables built
+
+        def drive():
+            eng.submit(_prompt(tiny_moe[0], 9, seed=5), max_new_tokens=3)
+            steps0 = eng.stats["steps"]
+            while eng.has_work():
+                eng.step()
+            return eng.stats["steps"] - steps0
+
+        stats0 = dict(eng.stats)
+        spans, ticks = _profiled(str(tmp_path), drive)
+        steps = [s[3] for s in spans if s[0] == "ptpu.serve.step"]
+        assert len(steps) == ticks == 3
+        cfg = tiny_moe[0]
+        for f in steps:
+            assert set(f) == {"tick", "batch", "tokens", "prefill_tokens",
+                              "kind", "moe_pairs", "moe_experts_hit",
+                              "moe_max_load"}
+            assert f["moe_pairs"] == f["tokens"] * cfg.top_k
+            assert 1 <= f["moe_max_load"] <= f["tokens"] * cfg.num_layers
+            assert (cfg.top_k * cfg.num_layers <= f["moe_experts_hit"]
+                    <= min(f["moe_pairs"], cfg.num_experts)
+                    * cfg.num_layers)
+        # and the engine's stats are the sums (max_load: the maximum)
+        assert (eng.stats["moe_pairs"] - stats0["moe_pairs"]
+                == sum(f["moe_pairs"] for f in steps))
+        assert (eng.stats["moe_experts_hit"] - stats0["moe_experts_hit"]
+                == sum(f["moe_experts_hit"] for f in steps))
+        assert eng.stats["moe_max_load"] >= max(
+            f["moe_max_load"] for f in steps)
+
     def test_submit_is_a_span_outside_every_step(self, profiled_engine):
         spans = profiled_engine["spans"]
         submits = [s for s in spans if s[0] == "ptpu.serve.submit"]
@@ -489,6 +539,39 @@ class TestStableDeviceNames:
         assert lowered
         for text in lowered:
             assert set(SERVE_SCOPES) <= _scopes_in(text)
+
+    @pytest.mark.parametrize("form", ["dense_einsum", "sorted_gmm"])
+    def test_moe_serve_step_carries_the_moe_scopes(self, tiny_moe, form,
+                                                   monkeypatch):
+        """A routed-expert tick names `moe` and inside it `router`,
+        `dispatch`, `experts`, `combine` in place of `ffn`, in either
+        expert form."""
+        from paddle_tpu.models import llama as L
+
+        monkeypatch.setattr(L, "expert_form", lambda cfg: form)
+        eng = _factory(tiny_moe, pallas=True)()
+        lowered = []
+        build = eng._build_step
+
+        def spy(*a, **k):
+            fn = build(*a, **k)
+
+            def call(*args):
+                shapes = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+                lowered.append(fn.lower(*shapes).as_text(debug_info=True))
+                return fn(*args)
+            return call
+
+        eng._build_step = spy
+        eng.submit(_prompt(tiny_moe[0], 6), max_new_tokens=2)
+        eng.run()
+        assert len(lowered) == 2               # mixed, then decode
+        want = (set(SERVE_SCOPES) - {"ffn"}) | {
+            "moe", "router", "dispatch", "experts", "combine"}
+        for text in lowered:
+            words = _scopes_in(text)
+            assert want <= words and "ffn" not in words
 
     def test_cow_copy_carries_its_scope(self, tiny):
         eng = _factory(tiny)()
