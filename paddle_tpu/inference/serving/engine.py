@@ -227,7 +227,8 @@ class PagedServingEngine:
                       "decode_fast_steps": 0, "ffn_steps": 0,
                       "fused_ticks": 0, "tick_pallas_launches": 0,
                       "spec_ticks": 0, "spec_proposed": 0,
-                      "spec_accepted": 0}
+                      "spec_accepted": 0, "attn_pages_live": 0,
+                      "attn_pages_fetched": 0}
         if cfg.num_experts:
             # routed-expert work, summed over ticks (max_load: the largest
             # seen): (row, expert) pairs, (layer, expert) groups with at
@@ -1035,6 +1036,19 @@ class PagedServingEngine:
                 self.stats["moe_experts_hit"] += moe[1]
                 self.stats["moe_max_load"] = max(
                     self.stats["moe_max_load"], moe[2])
+            if pallas_mode == "decode":
+                # how well the decode launch's walk fits the traffic, from
+                # the host's own lengths: pages that hold a live key, and
+                # pages the walk fetches (whole key blocks)
+                live, fetched = PA.decode_pages_walked(
+                    (dec_lens + this_lens)[this_lens > 0], self.block_size,
+                    self.cfg.num_kv_heads, self.cfg.head_dim,
+                    np.dtype(self.cache_dtype).itemsize,
+                    self.max_blocks_per_seq)
+                fields["attn_pages_live"] = live
+                fields["attn_pages_fetched"] = fetched
+                self.stats["attn_pages_live"] += live
+                self.stats["attn_pages_fetched"] += fetched
             tick.set_metadata(
                 batch=len(batch.items),
                 tokens=batch.total_tokens + spec_extra,

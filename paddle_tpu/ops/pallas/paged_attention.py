@@ -5,50 +5,76 @@ Reference parity target: the paged attention read inside
 XLA path in ops/kernels/serving_attention.py materializes every
 sequence's pages into a dense `[B, max_kv, KV, hd]` gather before the
 score dot — on a paged pool that is the single biggest avoidable HBM
-round-trip in the decode loop. This kernel never materializes the
+round-trip in the decode loop. These kernels never materialize the
 gather: the per-sequence block table is *scalar-prefetched* into SMEM
-(`pltpu.PrefetchScalarGridSpec`) and the K/V page BlockSpec index maps
-read it directly, so each grid step DMAs exactly one `[block_size, hd]`
-page from wherever it lives in the pool.
+(`pltpu.PrefetchScalarGridSpec`) and the pages are read through it from
+wherever they lie in the pool.
 
-Design:
+Design — two walks over one pool, chosen by the static shape of the q
+rows (`rows == group` is the decode launch), because their needs
+conflict: a decode launch has 1–4 query rows a head and is bound by the
+count and size of its fetches; a mixed launch has up to token_budget rows
+a head and is bound by its products.
 
-- grid `(B, KV, P)` with the page axis innermost; online-softmax
+The mixed walk (`_kernel`, ragged prefill + decode in ONE launch):
+
+- grid `(B, KV, P)` with the page axis innermost, one `[block_size, hd]`
+  page of one KV head a step through a BlockSpec index map; online-softmax
   running statistics (m, l, acc) live in VMEM scratch across the page
   walk (the flash_attention.py formulation over pages instead of dense
   kv blocks);
-- ragged mixed prefill+decode in ONE launch: the packed q tokens are
-  regrouped per sequence into `[B, KV, max_q * G, hd]` rows (GQA group
-  g and chunk offset t fold into one MXU axis, row r = t*G + g) and the
-  chunked-prefill metadata the scheduler already produces
-  (`seq_lens_decoder` past + `seq_lens_this_time`) is prefetched so the
-  kernel masks `kv_pos <= past + t` per row — in-chunk causality holds
-  because the pages already contain this step's tokens (the append
-  happens before the read, same as the stock path);
+- the packed q tokens are regrouped per sequence into
+  `[B, KV, max_q * G, hd]` rows (GQA group g and chunk offset t fold into
+  one MXU axis, row r = t*G + g) and the chunked-prefill metadata the
+  scheduler already produces (`seq_lens_decoder` past +
+  `seq_lens_this_time`) is prefetched so the kernel masks
+  `kv_pos <= past + t` per row — in-chunk causality holds because the
+  pages already contain this step's tokens (the append happens before the
+  read, same as the stock path);
 - pages past a sequence's live length are *skipped* (`pl.when` on the
-  prefetched lengths), so a 4-page sequence in a 64-page table costs 4
-  iterations, not 64;
+  prefetched lengths): no fetch, no product, but still a grid step each;
 - int8 pages dequantize IN-REGISTER: the per-page scale planes
   `[num_blocks, KV]` ride the same prefetched table through (8, KV)
   SMEM blocks; the k scale is constant over hd so it factors out of the
   q·k dot and lands on the scores, the v scale lands on the probabilities —
   bit-identical placement to the stock path's folding, and no fp copy
-  of the cache ever exists;
-- `max_q=1` is the decode-specialized launch: rows collapse to the GQA
-  group (`[B, KV, G, hd]`), zero padding waste on the steady-state hot
-  path;
-- the append that comes before the read is `write_pages`, a second small
-  kernel over the pages a batch touches, with the pools aliased input to
-  output: beside this kernel an XLA scatter of rows makes the compiler
-  hold the pool in another layout and convert all of it for every launch.
+  of the cache ever exists.
+
+The decode walk (`_decode_kernel`, `max_q = 1`, rows `[B, KV, G, hd]`):
+
+- grid `(B,)`, the pools left in HBM (`memory_space=pl.ANY`); a sequence
+  walks only its own live key blocks in a `fori_loop` whose trip count is
+  ceil((past + 1) / (P * block_size)), an idle slot none: a 17-page
+  sequence in a 128-page table costs 3 iterations, not 128 grid steps a
+  head;
+- a fetch is a WHOLE page, `pool[layer, tables[b, p]]` = `[KV, block_size,
+  hd]`, contiguous in the page-major pool, so one copy serves every KV
+  head; a key block is P pages (`decode_pages_per_block`: about 128 key
+  positions, from shapes and a VMEM budget alone) gathered by
+  `pltpu.make_async_copy` into a double-buffered scratch, the next
+  block's copies started before this block's products;
+- per head the same online softmax, mask (`kv_pos <= past`), zero for an
+  idle slot and in-register int8 dequantisation as the mixed walk, over a
+  block of P * block_size keys; the dots keep their operand types (blocks
+  cast to f32). An int8 page's `[KV]` scale row rides with the page: one
+  more copy beside it, out of the plane padded to whole lanes (Mosaic
+  takes no copy of an 8-wide row out of `[num_blocks, KV]`) into SMEM,
+  so what a launch holds on chip does not grow with the table;
+- a table no multiple of P wide is padded by the wrapper; entries of −1
+  are clamped, lie behind every live length and are masked if fetched.
+
+The append that comes before the read is `write_pages`, a third small
+kernel over the pages a batch touches, with the pools aliased input to
+output: beside these kernels an XLA scatter of rows makes the compiler
+hold the pool in another layout and convert all of it for every launch.
 
 Layout contract: q rows are packed/unpacked by the caller
 (block_multihead_attention_); caches stay in their pool layout — one
 layer's `[num_blocks, KV, block_size, hd]`, or the serving engine's whole
 stacked pool `[L, num_blocks, KV, block_size, hd]` with the layer as one
-more prefetched scalar, which the K/V index maps put in front of the
-page: `(layer, tables[b, p], kv, 0, 0)`. No transpose, no reshape, no
-copy, and no slice of a layer out of the stack.
+more prefetched scalar, which the mixed walk's index maps and the decode
+walk's copies put in front of the page: `(layer, tables[b, p], …)`. No
+transpose, no reshape, no copy, and no slice of a layer out of the stack.
 """
 from __future__ import annotations
 
@@ -168,6 +194,215 @@ def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
                        ).astype(o_ref.dtype)
 
 
+# the decode walk's key block: about one MXU tile of key positions a step,
+# inside a VMEM budget for the double-buffered K and V page scratch
+_DECODE_KEYS = 128
+_DECODE_VMEM_BYTES = 4 << 20
+
+
+def decode_pages_per_block(block_size: int, num_kv_heads: int, head_dim: int,
+                           itemsize: int, max_blocks: int) -> int:
+    """Pages the decode walk fetches and works on at a time, from shapes
+    alone: as many whole pages `[KV, block_size, hd]` as make a key block
+    of about `_DECODE_KEYS` positions, no more than the scratch budget
+    holds twice over for K and for V, and no more than a table is wide."""
+    page_bytes = num_kv_heads * block_size * head_dim * itemsize
+    return max(1, min(_DECODE_KEYS // block_size,
+                      _DECODE_VMEM_BYTES // (4 * page_bytes), max_blocks))
+
+
+def decode_pages_walked(ends, block_size: int, num_kv_heads: int,
+                        head_dim: int, itemsize: int, max_blocks: int):
+    """(live, fetched) pages of one decode launch, reckoned on the host:
+    `ends` [n] are the live lengths `past + 1` of the sequences that take
+    part (idle slots left out). Live pages hold a key the query may see;
+    fetched pages are the walk's trip count (`n_blocks` of
+    `_decode_kernel`) times its P whole pages a key block."""
+    pages = decode_pages_per_block(block_size, num_kv_heads, head_dim,
+                                   itemsize, max_blocks)
+    ends = np.asarray(ends, np.int64)
+    blocks = np.minimum(-(-ends // (pages * block_size)),
+                        -(-max_blocks // pages))
+    return int((-(-ends // block_size)).sum()), int(blocks.sum()) * pages
+
+
+def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
+                   sm_scale: float, block_size: int, pages: int,
+                   has_quant: bool):
+    """One sequence b of a decode launch (one query token, rows = the GQA
+    group): walk its live key blocks of `pages` whole pages each.
+
+    refs: q [1, KV, G, hd], k_pool, v_pool, [k_scale, v_scale
+    [num_blocks, LANES],] (all left in HBM), o, then scratch: kbuf, vbuf
+    [2, pages, KV, bs, hd], [kdq, vdq [2, pages, LANES] in SMEM,] sems
+    [2, 2], acc [KV, G, hd], m, l [KV, G, LANES]. A page comes in one copy
+    that serves every KV head, an int8 page's scale row in one more beside
+    it; block i+1's copies start before block i's products. The online
+    softmax runs per head over the block's pages * bs keys."""
+    if has_quant:
+        (q_ref, k_hbm, v_hbm, kdq_hbm, vdq_hbm, o_ref, kbuf, vbuf, kdq_ref,
+         vdq_ref, sems, acc, m_sc, l_sc) = refs
+    else:
+        q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, acc, m_sc, l_sc = refs
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    KV, G, hd = acc.shape
+    span = pages * block_size
+    width = tables_ref.shape[1]
+    past = past_ref[b]
+    # the one query sits at position `past`: keys 0..past are live, in
+    # ceil((past + 1) / span) blocks (never past the table's end); an idle
+    # slot walks none
+    n_blocks = jnp.where(
+        this_ref[b] > 0,
+        jnp.minimum(jax.lax.div(past + _i32(span), _i32(span)),
+                    _i32(width // pages)), _i32(0))
+
+    def copies(i, slot):
+        out = []
+        for j in range(pages):
+            page = tables_ref[b, i * _i32(pages) + _i32(j)]
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[layer, page], kbuf.at[slot, _i32(j)],
+                sems.at[_i32(0), slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[layer, page], vbuf.at[slot, _i32(j)],
+                sems.at[_i32(1), slot]))
+            if has_quant:
+                out.append(pltpu.make_async_copy(
+                    kdq_hbm.at[page], kdq_ref.at[slot, _i32(j)],
+                    sems.at[_i32(0), slot]))
+                out.append(pltpu.make_async_copy(
+                    vdq_hbm.at[page], vdq_ref.at[slot, _i32(j)],
+                    sems.at[_i32(1), slot]))
+        return out
+
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for c in copies(_i32(0), _i32(0)):
+            c.start()
+
+    def block(i, _):
+        slot = jax.lax.rem(i, _i32(2))
+
+        @pl.when(i + _i32(1) < n_blocks)
+        def _():
+            for c in copies(i + _i32(1), _i32(1) - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (G, span), 1)
+                  + i * _i32(span))
+        ok = kv_abs <= past                           # causal = live keys
+        if has_quant:
+            key_page = jax.lax.div(
+                jax.lax.broadcasted_iota(jnp.int32, (1, span), 1),
+                _i32(block_size))
+
+            def page_scales(scale_ref, kv):           # [1, span], per key
+                vec = jnp.zeros((1, span), jnp.float32)
+                for j in range(pages):
+                    vec = jnp.where(key_page == j,
+                                    scale_ref[slot, _i32(j), _i32(kv)], vec)
+                return vec
+        for kv in range(KV):
+            q = q_ref[0, kv].astype(jnp.float32)      # [G, hd]
+            k = kbuf[slot, :, kv].astype(jnp.float32).reshape(span, hd)
+            s = jax.lax.dot_general(                  # int8 pages dequant
+                q, k, (((1,), (1,)), ((), ())),       # in-register
+                preferred_element_type=jnp.float32)   # [G, span]
+            if has_quant:
+                # a page's k scale is constant over hd: it factors out of
+                # the dot and lands on that page's scores
+                s = s * (sm_scale * page_scales(kdq_ref, kv))
+            else:
+                s = s * sm_scale
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_sc[kv][:, :1]                  # [G, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            prob = jnp.exp(s - m_new)                 # [G, span]
+            prob = jnp.where(ok, prob, 0.0)
+            alpha = jnp.exp(m_prev - m_new)           # [G, 1]
+            l_sc[kv] = l_sc[kv] * alpha + jnp.sum(prob, axis=-1,
+                                                  keepdims=True)
+            if has_quant:
+                # the v scale likewise: fold into the probabilities
+                prob = prob * page_scales(vdq_ref, kv)
+            v = vbuf[slot, :, kv].astype(jnp.float32).reshape(span, hd)
+            acc[kv] = acc[kv] * alpha + jax.lax.dot_general(
+                prob, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_sc[kv] = jnp.broadcast_to(m_new, m_sc.shape[1:])
+
+    jax.lax.fori_loop(_i32(0), n_blocks, block, None)
+    # an idle slot walked nothing and has l == 0: divide by 1, emit 0
+    l = l_sc[...][:, :, :1]
+    o_ref[0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
+                 sm_scale, k_dequant, v_dequant, interpret):
+    """The decode launch (`rows == group`): grid over sequences, pools
+    left in HBM, whole pages gathered by the kernel."""
+    B, KV, G, hd = q_rows.shape
+    _, _, _, bs, _ = key_cache.shape
+    has_quant = k_dequant is not None
+    max_blocks = tables.shape[1]
+    pages = decode_pages_per_block(bs, KV, hd, key_cache.dtype.itemsize,
+                                   max_blocks)
+    # whole key blocks only: a table that is no multiple wide is padded
+    # (page 0, behind every live length, fetched at most and masked)
+    tables = jnp.pad(tables, ((0, 0), (0, -max_blocks % pages)))
+    inputs = [q_rows, key_cache, value_cache]
+    scale_scratch = []
+    if has_quant:
+        # a page's scale row rides with the page, copied into SMEM: Mosaic
+        # takes no copy of an 8- or 16-wide row out of the [num_blocks, KV]
+        # plane, so the planes are padded to whole lanes
+        pad = (0, 0), (0, -KV % _STAT_LANES)
+        inputs += [jnp.pad(k_dequant.astype(jnp.float32), pad),
+                   jnp.pad(v_dequant.astype(jnp.float32), pad)]
+        scale_scratch = [pltpu.SMEM((2, pages, inputs[-1].shape[1]),
+                                    jnp.float32)] * 2
+
+    row_spec = pl.BlockSpec((1, KV, G, hd),
+                            lambda b, *_: (b, _i32(0), _i32(0), _i32(0)),
+                            memory_space=pltpu.VMEM)
+    _assert_mosaic_tileable(row_spec.block_shape, q_rows.shape, "decode rows")
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B,),
+        in_specs=[row_spec] + [hbm] * (len(inputs) - 1),
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, KV, bs, hd), key_cache.dtype),
+            pltpu.VMEM((2, pages, KV, bs, hd), value_cache.dtype),
+            *scale_scratch,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((KV, G, hd), jnp.float32),
+            pltpu.VMEM((KV, G, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((KV, G, _STAT_LANES), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _decode_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
+        pages=int(pages), has_quant=has_quant)
+    count_launch()
+    return pl.pallas_call(
+        kernel,
+        name="paged_attention_decode",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q_rows.dtype),
+        interpret=interpret,
+    )(tables, past, this, layer, *inputs)
+
+
 def paged_attention(q_rows, key_cache, value_cache, block_tables,
                     seq_lens_decoder, seq_lens_this_time, group: int,
                     sm_scale: float, k_dequant=None, v_dequant=None,
@@ -188,6 +423,10 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     k_dequant / v_dequant [num_blocks, KV] f32 enable the int8-page
     mode (pass both or neither). Returns [B, KV, max_q * G, hd] in
     q_rows.dtype; pad rows come back 0.
+
+    Rows equal to `group` (max_q = 1: the CALLER guarantees every
+    seq_lens_this_time <= 1) take the decode walk, any other shape the
+    mixed walk.
     """
     if (k_dequant is None) != (v_dequant is None):
         raise ValueError("pass both k_dequant and v_dequant or neither")
@@ -215,6 +454,11 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     past = seq_lens_decoder.reshape(-1).astype(jnp.int32)     # [B]
     this = seq_lens_this_time.reshape(-1).astype(jnp.int32)   # [B]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    if rows == group:
+        return _decode_call(q_rows, key_cache, value_cache, tables, past,
+                            this, layer, sm_scale, k_dequant, v_dequant,
+                            interpret)
 
     mem = {"memory_space": pltpu.VMEM}
     # the layer axis is squeezed: the body sees [1, 1, bs, hd] pages

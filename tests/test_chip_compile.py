@@ -145,6 +145,43 @@ def test_paged_attention_compiles(one_chip, max_q, int8_pages):
     _compile(fn, one_chip, *shapes)
 
 
+@pytest.mark.parametrize("kv, group, num_blocks, max_blocks, rows, int8", [
+    # the decode walk (rows == group) at the two serve configurations'
+    # shapes: Mistral-7B (8 KV heads, group 4, table 128) and OLMoE-1B-7B
+    # (16 KV heads, group 1, table 32), bf16 and int8 pages
+    (8, 4, 2304, 128, 4, False), (16, 1, 768, 32, 1, False),
+    (8, 4, 2304, 128, 4, True), (16, 1, 768, 32, 1, True),
+    # int8 at an 8192-position table: the scale rows come with the pages,
+    # so what the launch holds in SMEM is the table alone
+    (8, 4, 9216, 512, 4, True),
+    # and the mixed launch beside them (token_budget rows a head)
+    (8, 4, 2304, 128, 2048, False), (16, 1, 768, 32, 512, False),
+])
+def test_paged_attention_on_the_serve_pools_compiles(
+        one_chip, kv, group, num_blocks, max_blocks, rows, int8):
+    """The kernel alone on the stacked pool with a traced layer: a launch
+    whose rows are the GQA group takes the decode walk (whole pages from
+    the pool left in HBM, several a key block), any other the mixed walk."""
+    layers, batch, block_size = 16, 16, 16
+    page_dtype = jnp.int8 if int8 else jnp.bfloat16
+    pool = ((layers, num_blocks, kv, block_size, HEAD_DIM), page_dtype)
+    shapes = [_bf16(batch, kv, rows, HEAD_DIM), pool, pool,
+              ((batch, max_blocks), jnp.int32), ((batch,), jnp.int32),
+              ((batch,), jnp.int32), ((), jnp.int32)]
+    if int8:
+        shapes += [((num_blocks, kv), jnp.float32)] * 2
+
+    def fn(q, k, v, tables, past, this, layer, *dequant):
+        return pa.paged_attention(q, k, v, tables, past, this, group,
+                                  HEAD_DIM ** -0.5, *dequant,
+                                  interpret=False, layer=layer)
+
+    text = _compile(fn, one_chip, *shapes).as_text()
+    assert ("paged_attention_decode" in text) == (rows == group)
+    assert pa.decode_pages_per_block(block_size, kv, HEAD_DIM,
+                                     1 if int8 else 2, max_blocks) == 8
+
+
 @pytest.mark.parametrize("rows", [16, 512])        # decode / mixed tick
 @pytest.mark.parametrize("int8_pages", [False, True])
 def test_paged_layer_in_stacked_pool_compiles(one_chip, rows, int8_pages):

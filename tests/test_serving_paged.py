@@ -588,6 +588,33 @@ def _assert_stacked_pool_matches(args, pallas_mode, pal):
         assert np.array_equal(got[[0, 2]], np.asarray(before)[[0, 2]])
 
 
+# the decode walk's cases: G, page size, pages a key block (P), table width
+# (a multiple of P or not); lengths default to [idle, 1, P*bs - 1, P*bs,
+# P*bs + 1, the full table]
+_DECODE_WALK = [
+    dict(id="g1-bs4-p2-even", G=1, bs=4, P=2, mb=6),
+    dict(id="g1-bs4-p2-odd", G=1, bs=4, P=2, mb=7),
+    dict(id="g4-bs4-p4-even", G=4, bs=4, P=4, mb=8),
+    dict(id="g4-bs4-p4-odd", G=4, bs=4, P=4, mb=9),
+    dict(id="g1-bs16-p2-even", G=1, bs=16, P=2, mb=4),
+    dict(id="g1-bs16-p2-odd", G=1, bs=16, P=2, mb=5),
+    dict(id="g4-bs16-p2-even", G=4, bs=16, P=2, mb=4),
+    dict(id="g4-bs16-p2-odd", G=4, bs=16, P=2, mb=5),
+    dict(id="g4-bs16-p1-single-pages", G=4, bs=16, P=1, mb=3),
+    # the block the shapes give: 128 keys, or the whole table if narrower
+    dict(id="g4-bs16-reckoned-p8", G=4, bs=16, mb=10,
+         lengths=[0, 1, 127, 128, 129, 160]),
+    dict(id="g1-bs4-reckoned-table", G=1, bs=4, mb=5,
+         lengths=[0, 1, 19, 20, 7, 12]),
+    dict(id="g4-bs4-cow-first-page", G=4, bs=4, P=2, mb=5, cow=True,
+         lengths=[9, 8, 7, 17, 20]),
+    dict(id="g4-bs16-int8-partial-last-page", G=4, bs=16, P=2, mb=5,
+         quant=True, lengths=[0, 1, 31, 33, 40, 71]),
+    dict(id="g1-bs16-int8-partial-last-page", G=1, bs=16, P=2, mb=4,
+         quant=True, lengths=[11, 64, 0, 45]),
+]
+
+
 class TestPallasPagedAttention:
     def test_supported_gates(self):
         from paddle_tpu.ops.pallas import paged_attention as PA
@@ -643,6 +670,74 @@ class TestPallasPagedAttention:
         np.testing.assert_allclose(np.asarray(pal[0]), np.asarray(stock[0]),
                                    atol=5e-5, rtol=1e-5)
         assert np.asarray(pal[2]).dtype == np.int8
+
+    @pytest.mark.parametrize("case", _DECODE_WALK, ids=lambda c: c["id"])
+    def test_decode_walk_parity(self, case, monkeypatch):
+        """The decode launch's own walk (whole pages, P a key block, live
+        blocks only) against the stock path: an idle slot, lengths 1,
+        P*bs - 1, P*bs, P*bs + 1 and the full table in one batch, -1
+        entries behind every live length, the stacked pool with a traced
+        layer (inside `_mha_both`)."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        G, bs, mb, KV, hd = case["G"], case["bs"], case["mb"], 2, 8
+        quant = case.get("quant", False)
+        if "P" in case:       # steer the key block; the program has no knob
+            monkeypatch.setattr(PA, "_DECODE_KEYS", case["P"] * bs)
+        P = PA.decode_pages_per_block(bs, KV, hd, 1 if quant else 4, mb)
+        assert P == case.get("P", min(128 // bs, mb))
+        span = P * bs
+        lengths = case.get("lengths",
+                           [0, 1, span - 1, span, span + 1, mb * bs])
+        past = [5 if n == 0 else n - 1 for n in lengths]
+        this = [0 if n == 0 else 1 for n in lengths]
+        nb = sum(-(-(a + b) // bs) for a, b in zip(past, this)) + 2
+        args = _mha_args(past, this, KV=KV, G=G, hd=hd, bs=bs, mb=mb, nb=nb,
+                         quant=quant, seed=case.get("seed", 11),
+                         shared_first_page=case.get("cow", False))
+        tables = np.asarray(args["block_tables"])
+        for row, a, b in zip(tables, past, this):  # -1 behind the live
+            assert (row[-(-(a + b) // bs):] == -1).all()
+        assert (tables == -1).any()
+        if quant:
+            # a scale of its own for every page and head: the walk must
+            # pair each page's keys with that page's row
+            rs = np.random.RandomState(12)
+            for name in ("cache_k_dequant_scales", "cache_v_dequant_scales"):
+                args[name] = jnp.asarray(
+                    rs.uniform(0.01, 0.05, (nb, KV)).astype(np.float32))
+        stock, pal = _mha_both(args, pallas_mode="decode")
+        np.testing.assert_allclose(np.asarray(pal[0]), np.asarray(stock[0]),
+                                   atol=5e-5, rtol=1e-5)
+        assert np.array_equal(np.asarray(pal[2]), np.asarray(stock[2]))
+        assert np.array_equal(np.asarray(pal[3]), np.asarray(stock[3]))
+
+    def test_decode_walk_reads_no_page_behind_the_live_length(
+            self, monkeypatch):
+        """NaN in every page the tables do not name, and in the named
+        pages' slots past the live length: the walk's answer stays what
+        it was (a fetched page behind the length is masked, never used)."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        monkeypatch.setattr(PA, "_DECODE_KEYS", 8)   # P = 2 pages of 4
+        rs = np.random.RandomState(13)
+        KV, G, hd, bs, nb = 2, 4, 8, 4, 12
+        q = jnp.asarray(rs.randn(2, KV, G, hd).astype(np.float32))
+        kc = rs.randn(nb, KV, bs, hd).astype(np.float32)
+        vc = rs.randn(nb, KV, bs, hd).astype(np.float32)
+        bt = np.array([[3, 7, -1, -1, -1], [5, 1, 9, -1, -1]], np.int32)
+        past = np.array([5, 8], np.int32)            # lengths 6 and 9
+        this = jnp.ones((2,), jnp.int32)
+        clean = PA.paged_attention(q, jnp.asarray(kc), jnp.asarray(vc),
+                                   jnp.asarray(bt), jnp.asarray(past), this,
+                                   G, 0.3, interpret=True)
+        # the zero behind a masked key is 0 * v: keep v finite, poison k
+        dirty = kc.copy()
+        named = {3: 4, 7: 2, 5: 4, 1: 4, 9: 1}       # page: live slots
+        for page in range(nb):
+            dirty[page, :, named.get(page, 0):] = np.nan
+        out = PA.paged_attention(q, jnp.asarray(dirty), jnp.asarray(vc),
+                                 jnp.asarray(bt), jnp.asarray(past), this,
+                                 G, 0.3, interpret=True)
+        assert np.array_equal(np.asarray(out), np.asarray(clean))
 
     def test_forced_bad_geometry_raises(self):
         args = _mha_args(past=[0], this=[2], KV=1, G=2, hd=4, seed=6)
@@ -741,6 +836,34 @@ class TestEnginePallas:
         assert eng.stats["step_builds"] == builds  # steady state: zero
         assert eng.stats["decode_fast_steps"] > 0
         assert eng.stats["pallas_steps"] == eng.stats["steps"]
+
+    def test_attn_page_counters_hand_counted(self, tiny, monkeypatch):
+        """`attn_pages_live` / `attn_pages_fetched`: what the decode
+        launch's walk must read and what it fetches, from the host's own
+        lengths. Only ticks that take the decode launch add to either."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        monkeypatch.setattr(PA, "_DECODE_KEYS", 8)   # P = 2 pages of 4
+        eng = self._engine(tiny, True)
+        assert PA.decode_pages_per_block(4, 2, 8, 4,
+                                         eng.max_blocks_per_seq) == 2
+        for p in _prompts(tiny[0], 2, [7, 3], seed=24):
+            eng.submit(p, max_new_tokens=4)
+        eng.step()                      # the mixed tick: both prompts whole
+        assert eng.stats["decode_fast_steps"] == 0
+        assert eng.stats["attn_pages_live"] == 0
+        assert eng.stats["attn_pages_fetched"] == 0
+        eng.run()
+        assert eng.stats["decode_fast_steps"] == 3 == eng.stats["steps"] - 1
+        # decode tick k reads positions 0 .. prompt + k - 1:
+        #   prompt 7: 8, 9, 10 keys -> 2 + 3 + 3 pages, 1 + 2 + 2 blocks
+        #   prompt 3: 4, 5, 6 keys  -> 1 + 2 + 2 pages, 1 + 1 + 1 blocks
+        assert eng.stats["attn_pages_live"] == 8 + 5
+        # (the two idle slots of the four walk and count nothing)
+        assert eng.stats["attn_pages_fetched"] == (5 + 3) * 2
+        # the helper alone: a full table of 5 pages is 3 blocks of 2 (the
+        # wrapper pads the table to 6), and no sequence is no page
+        assert PA.decode_pages_walked([20, 1], 4, 2, 8, 4, 5) == (6, 8)
+        assert PA.decode_pages_walked([], 4, 2, 8, 4, 5) == (0, 0)
 
     def test_flag_driven_falls_back_off_tpu(self, tiny):
         """FLAGS_serving_pallas_attention on a host without the TPU kernel

@@ -452,6 +452,38 @@ class TestPhasesOnTheProfilersClock:
         assert eng.stats["moe_max_load"] >= max(
             f["moe_max_load"] for f in steps)
 
+    def test_decode_launch_tick_adds_its_page_counts_to_the_step(
+            self, tiny, tmp_path):
+        """A tick through the decode launch of paged attention carries
+        `attn_pages_live` and `attn_pages_fetched`; a mixed tick of the
+        same engine carries the five fields alone."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        eng = _factory(tiny, pallas=True)()
+        eng.submit(_prompt(tiny[0], 6), max_new_tokens=2)
+        eng.run()                          # both executables built
+
+        def drive():
+            eng.submit(_prompt(tiny[0], 9, seed=5), max_new_tokens=3)
+            while eng.has_work():
+                eng.step()
+
+        stats0 = dict(eng.stats)
+        spans, _ = _profiled(str(tmp_path), drive)
+        steps = [s[3] for s in spans if s[0] == "ptpu.serve.step"]
+        assert [f["kind"] for f in steps] == ["mixed", "decode", "decode"]
+        five = {"tick", "batch", "tokens", "prefill_tokens", "kind"}
+        assert set(steps[0]) == five
+        P = PA.decode_pages_per_block(4, tiny[0].num_kv_heads,
+                                      tiny[0].head_dim, 4,
+                                      eng.max_blocks_per_seq)
+        for k, f in enumerate(steps[1:], start=1):
+            assert set(f) == five | {"attn_pages_live", "attn_pages_fetched"}
+            assert f["attn_pages_live"] == -(-(9 + k) // 4)
+            assert f["attn_pages_fetched"] == -(-(9 + k) // (4 * P)) * P
+        for name in ("attn_pages_live", "attn_pages_fetched"):
+            assert (eng.stats[name] - stats0[name]
+                    == sum(f[name] for f in steps[1:]))
+
     def test_submit_is_a_span_outside_every_step(self, profiled_engine):
         spans = profiled_engine["spans"]
         submits = [s for s in spans if s[0] == "ptpu.serve.submit"]
@@ -615,7 +647,7 @@ class TestStableDeviceNames:
 
     @pytest.mark.parametrize("module, count", [
         ("flash_attention", 3), ("fused_ffn", 6), ("fused_sample", 1),
-        ("paged_attention", 2)])
+        ("paged_attention", 3)])
     def test_every_pallas_call_has_a_name(self, module, count):
         import ast
         import os
@@ -641,7 +673,7 @@ class TestStableDeviceNames:
                    for n in names)
 
     def test_pallas_call_sites_are_all_in_ops_pallas(self):
-        """The 12 named sites above are all there are in the package."""
+        """The 13 named sites above are all there are in the package."""
         import os
         import re
 
@@ -660,7 +692,7 @@ class TestStableDeviceNames:
         assert sites == {"ops/pallas/flash_attention.py": 3,
                          "ops/pallas/fused_ffn.py": 6,
                          "ops/pallas/fused_sample.py": 1,
-                         "ops/pallas/paged_attention.py": 2}
+                         "ops/pallas/paged_attention.py": 3}
 
 
 # ---------------------------------------------------------------------------
