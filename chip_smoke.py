@@ -306,11 +306,11 @@ def run_server(seed: int, log: CompileLog) -> None:
         check(stats["pallas_steps"] == stats["steps"]
               and stats["ffn_steps"] == stats["steps"],
               "paged attention and the fused FFN ran in every tick")
-        # the fused decode tick traces paged attention + fused FFN (once
-        # each, in the layer scan) + the sampler prep
+        # the fused decode tick traces the page write, paged attention and
+        # the fused FFN (once each, in the layer scan) + the sampler prep
         check(stats["fused_ticks"] > 0
-              and stats["tick_pallas_launches"] == 3,
-              "the fused decode tick holds the three kernels")
+              and stats["tick_pallas_launches"] == 4,
+              "the fused decode tick holds the four kernels")
         stock, stats = serve(cfg, params, prompts, new_tokens, seed, log,
                              pallas=False, pallas_ffn=False,
                              token_budget=STOCK_TOKEN_BUDGET, **kw)

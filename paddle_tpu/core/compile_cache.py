@@ -1,8 +1,8 @@
 """Where JAX's persistent compilation cache lives.
 
-Entry scripts (`chip_smoke.py`, `bench.py`, `__graft_entry__.py`) call
-`configure()` before their first compile; the package never does at
-import, so tests compile uncached. The directory is part of every cache
+Entry scripts (`chip_smoke.py`, `__graft_entry__.py`) call `configure()`
+before their first compile; the package never does at import, so tests
+compile uncached. The directory is part of every cache
 key's lookup path, so it is fixed: the one `JAX_COMPILATION_CACHE_DIR`
 names, or one inside the checkout — never a temp name, pid or timestamp.
 """

@@ -22,16 +22,16 @@ import numpy as np
 from ..core.tensor import Tensor
 
 __all__ = ["Config", "Predictor", "create_predictor", "PrecisionType",
-           "PlaceType", "LLMPredictor", "init_cache", "ServingEngine",
-           "Request", "Completion", "PagedServingEngine", "TokenEvent",
+           "PlaceType", "LLMPredictor", "init_cache", "Completion",
+           "PagedServingEngine", "TokenEvent",
            "BlockManager", "RejectedError", "DeadlineExceededError",
            "ServingRouter", "FailoverMismatchError"]
 
 from .llm import LLMPredictor, init_cache  # noqa: E402,F401
 from .serving import (BlockManager, Completion,  # noqa: E402,F401
                       DeadlineExceededError, FailoverMismatchError,
-                      PagedServingEngine, RejectedError, Request,
-                      ServingEngine, ServingRouter, TokenEvent)
+                      PagedServingEngine, RejectedError, ServingRouter,
+                      TokenEvent)
 
 
 class PrecisionType:

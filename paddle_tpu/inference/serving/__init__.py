@@ -1,6 +1,6 @@
 """LLM serving subsystem.
 
-Two engines and a fleet router share this package:
+One engine and a fleet router share this package:
 
 - :class:`PagedServingEngine` (``engine.py``) — the production path: a
   paged KV block pool with prefix caching (``block_manager.py``), a
@@ -8,8 +8,6 @@ Two engines and a fleet router share this package:
   deadlines and load shedding (``scheduler.py``), and one jitted
   fixed-shape mixed prefill+decode step over
   ``block_multihead_attention_`` with streaming token delivery;
-- :class:`ServingEngine` (``slot_engine.py``) — the dense per-slot
-  baseline the smoke gate compares against;
 - :class:`ServingRouter` (``router.py``) + :class:`ReplicaHandle`
   (``replica.py``) — resilient multi-replica serving: health-checked
   circuit breakers over N identical engines, mid-stream failover with
@@ -46,9 +44,8 @@ from .disagg import (DisaggRouter, FleetPrefixIndex, MigrationError,
 from .engine import PagedServingEngine, TokenEvent
 from .replica import ReplicaDeadError, ReplicaHandle, ReplicaKilledError
 from .router import FailoverMismatchError, RouterRequest, ServingRouter
-from .scheduler import (DeadlineExceededError, RejectedError,
+from .scheduler import (Completion, DeadlineExceededError, RejectedError,
                         ScheduledBatch, Scheduler, Sequence)
-from .slot_engine import Completion, Request, ServingEngine
 from .speculative import DraftModel
 
 __all__ = [
@@ -61,7 +58,7 @@ __all__ = [
     "PagedServingEngine", "TokenEvent",
     "RejectedError", "DeadlineExceededError",
     "ScheduledBatch", "Scheduler", "Sequence",
-    "Completion", "Request", "ServingEngine",
+    "Completion",
     "ServingRouter", "RouterRequest", "FailoverMismatchError",
     "ReplicaHandle", "ReplicaKilledError", "ReplicaDeadError",
     "DisaggRouter", "PoolAutoscaler", "PageTransport", "FleetPrefixIndex",

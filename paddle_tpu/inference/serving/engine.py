@@ -22,9 +22,8 @@ Client surface:
 - ``step()`` — one scheduler tick + one fused device step, returning
   :class:`TokenEvent` records (the streaming unit);
 - ``stream(rid)`` — iterator of tokens as they are produced;
-- ``run()`` — drain everything, return :class:`Completion` list (API
-  parity with the dense-slot :class:`~.slot_engine.ServingEngine` and
-  greedy/sampling parity with ``LLMPredictor``).
+- ``run()`` — drain everything, return :class:`Completion` list
+  (greedy/sampling parity with ``LLMPredictor``).
 
 SLO metrics (TTFT/TPOT histograms, queue-depth and KV-block-utilization
 gauges, admit/preempt/shed counters + flight-recorder events) flow
@@ -56,9 +55,8 @@ from .. import quant as Q
 from . import adapters as AD
 from . import speculative as SP
 from .block_manager import BlockManager, NoFreeBlocksError
-from .scheduler import (DeadlineExceededError, RejectedError, ScheduledBatch,
-                        Scheduler, Sequence)
-from .slot_engine import Completion
+from .scheduler import (Completion, DeadlineExceededError, RejectedError,
+                        ScheduledBatch, Scheduler, Sequence)
 
 # step-geometry flags: the executable signature is keyed on
 # (token_budget, batch_slots), so these are exactly the knobs a tuned
@@ -258,18 +256,24 @@ class PagedServingEngine:
         register_distress_section("adapters", self.adapters.snapshot)
         if self.spec is not None:
             register_distress_section("spec", self.spec.snapshot)
-        # pallas attention read: None = FLAGS_serving_pallas_attention
-        # (re-read each tick, so flips retrace via the executable key);
-        # True = force (interpret mode off-TPU — how CPU CI drives it);
-        # False = stock. Forced mode fails loudly on bad geometry now.
-        self.pallas = pallas
-        if pallas and not PA.supported(cfg.num_heads, cfg.num_kv_heads,
-                                       cfg.head_dim, self.block_size):
+        # the attention read, decided here and for good: None = the kernel
+        # where it runs and takes this geometry (PA.selected), the stock
+        # path elsewhere; True = force (interpret mode off-TPU — how CPU CI
+        # drives it; a bad geometry fails here); False = the stock
+        # reference
+        geometry = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                    self.block_size)
+        if pallas and not PA.supported(*geometry):
             raise ValueError(
                 f"pallas=True forced but geometry H={cfg.num_heads} "
                 f"KV={cfg.num_kv_heads} hd={cfg.head_dim} "
                 f"block_size={self.block_size} is not supported() by the "
                 f"paged-attention kernel")
+        self.pallas = bool(PA.selected(*geometry) if pallas is None
+                           else pallas)
+        # whether an all-decode tick's read is the decode walk (its page
+        # counters are reckoned only then) or the mixed walk with max_q = 1
+        self._decode_walk = self.pallas and PA.decode_walk(cfg.head_dim)
         # fused-FFN routing mirrors the attention tri-state: None =
         # FLAGS_pallas_ffn per tick; True = force (interpret off-TPU);
         # False = off. Forced mode validates params + geometry eagerly.
@@ -326,11 +330,10 @@ class PagedServingEngine:
         self._rope_emb = jnp.stack([
             jnp.concatenate([cos, cos], -1)[None],
             jnp.concatenate([sin, sin], -1)[None]])
-        # executables keyed by (token-budget, batch-slots, pallas-mode)
-        # signature; pallas-mode is False | True | "decode" (the max_q=1
-        # specialized launch), so a flag flip lands on a different key and
-        # retraces cleanly instead of serving a stale trace
-        self._step_fns: Dict[Tuple[int, int, Any], Any] = {}
+        # executables keyed by what differs between two ticks of this
+        # engine: (token-budget, batch-slots, decode, ffn-mode, adapter
+        # rank classes, spec-mode); `decode` = every chunk is one token
+        self._step_fns: Dict[Tuple[Any, ...], Any] = {}
         self._copy_fn = None
         # set by ReplicaHandle so this engine's tick spans say which
         # replica served them (the merged-trace failover story)
@@ -543,28 +546,9 @@ class PagedServingEngine:
             self.step()
 
     # -- the fused step ---------------------------------------------------
-    def _resolve_pallas(self) -> Tuple[Any, Optional[str]]:
-        """Host-side dispatch decision for this tick: (use_pallas value
-        for the op, fallback reason). Flag-driven mode re-reads the flag
-        every tick; the executable cache key carries the result, so flips
-        retrace instead of reusing a stale trace."""
-        if self.pallas is False:
-            return False, None
-        if self.pallas:          # forced (geometry validated at __init__)
-            return True, None
-        if not flags.flag_value("serving_pallas_attention"):
-            return False, None
-        cfg = self.cfg
-        if not PA.available():
-            return False, "unavailable"
-        if not PA.supported(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                            self.block_size):
-            return False, "unsupported"
-        return True, None
-
     def _resolve_ffn(self) -> Tuple[bool, Optional[str]]:
         """Host-side fused-FFN dispatch for this tick: (on, fallback
-        reason). Same tri-state contract as `_resolve_pallas`; the result
+        reason). None re-reads FLAGS_pallas_ffn every tick; the result
         rides the executable cache key so flag flips retrace exactly once."""
         if self.pallas_ffn is False:
             return False, None
@@ -586,16 +570,18 @@ class PagedServingEngine:
             return False, "unsupported"
         return True, None
 
-    def _build_step(self, tok_pad: int, B: int, pallas_mode=False,
+    def _build_step(self, tok_pad: int, B: int, decode: bool = False,
                     ffn_mode=False, ad_sig: Tuple[int, ...] = (),
                     spec_mode: bool = False):
         """Trace+compile the fixed-shape mixed prefill+decode executable
-        for the (token-budget, batch-slots, pallas-mode, ffn-mode,
-        adapter-signature, spec-mode) signature. `ffn_mode` swaps the
-        per-layer SwiGLU for the fused Pallas kernel; combined with
-        `pallas_mode == "decode"` it also swaps the sampling tail for
-        the one-launch sampler prep — the fused decode tick
-        (~2 launches/layer + 1 sampler).
+        for the (token-budget, batch-slots, decode, ffn-mode,
+        adapter-signature, spec-mode) signature. `decode` is the caller's
+        promise that every scheduled chunk is one token: beside the
+        kernel (`self.pallas`) the read then takes its max_q=1 launch.
+        `ffn_mode` swaps the per-layer SwiGLU for the fused Pallas
+        kernel; combined with the decode launch it also swaps the
+        sampling tail for the one-launch sampler prep — the fused decode
+        tick (~2 launches/layer + 1 sampler).
 
         `ad_sig` is the sorted tuple of active LoRA rank classes
         (() = adapter-off): per class the step takes the WHOLE stacked
@@ -607,7 +593,9 @@ class PagedServingEngine:
         cfg = self.cfg
         top_k = self.top_k
         quant_kv = self.quant_kv   # static: selects the int8-cache trace
-        fused_tick = bool(ffn_mode) and pallas_mode == "decode"
+        # the op's vocabulary for the engine's constant and the tick's shape
+        use_pallas = self.pallas and ("decode" if decode else True)
+        fused_tick = bool(ffn_mode) and use_pallas == "decode"
 
         @functools.partial(jax.jit, donate_argnums=(1, 2))
         def step_fn(params, key_cache, value_cache, kv_scales, tokens,
@@ -670,7 +658,7 @@ class PagedServingEngine:
                     qkv, kcs, vcs, li, seq_lens_decoder,
                     seq_lens_this_time, cu_seqlens_q, block_tables,
                     rope_emb=rope_emb, quant_scales=kv_layer,
-                    use_neox_style=True, use_pallas=pallas_mode)
+                    use_neox_style=True, use_pallas=use_pallas)
                 with jax.named_scope("attn_out"):
                     x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
                 if cfg.num_experts:
@@ -757,13 +745,13 @@ class PagedServingEngine:
 
         return step_fn
 
-    def _get_step_fn(self, tok_pad: int, B: int, pallas_mode=False,
+    def _get_step_fn(self, tok_pad: int, B: int, decode: bool = False,
                      ffn_mode=False, ad_sig: Tuple[int, ...] = (),
                      spec_mode: bool = False):
-        key = (tok_pad, B, pallas_mode, ffn_mode, ad_sig, spec_mode)
+        key = (tok_pad, B, decode, ffn_mode, ad_sig, spec_mode)
         fn = self._step_fns.get(key)
         if fn is None:
-            fn = self._build_step(tok_pad, B, pallas_mode, ffn_mode,
+            fn = self._build_step(tok_pad, B, decode, ffn_mode,
                                   ad_sig, spec_mode)
             self._step_fns[key] = fn
             self.stats["step_builds"] += 1
@@ -774,7 +762,7 @@ class PagedServingEngine:
             # experts: the form `routed_ffn` computes the experts in
             _emit("serving.step_build", tok_pad=tok_pad, batch=B,
                   ad_sig=list(ad_sig), spec=bool(spec_mode),
-                  cache_write="pallas_pages" if pallas_mode
+                  cache_write="pallas_pages" if self.pallas
                   else "scatter_rows",
                   experts=L.expert_form(self.cfg))
         return fn
@@ -874,9 +862,6 @@ class PagedServingEngine:
                         (time.perf_counter_ns() - t0c) * 1e-9,
                         copies=len(pairs), replica=self._trace_replica)
 
-            pallas_mode, pallas_fb = self._resolve_pallas()
-            if pallas_fb is not None:
-                _emit("serving.pallas_fallback", reason=pallas_fb)
             ffn_mode, ffn_fb = self._resolve_ffn()
             if ffn_fb is not None:
                 _emit("pallas_ffn.fallback", reason=ffn_fb)
@@ -923,14 +908,14 @@ class PagedServingEngine:
             spec_mode = bool(spec_plan)
 
             tok_pad, B = self.token_budget, self.max_batch
-            if (pallas_mode and not spec_plan
-                    and all(n == 1 for _, n in batch.items)):
+            decode = (self.pallas and not spec_plan
+                      and all(n == 1 for _, n in batch.items))
+            if decode:
                 # decode fast path: every scheduled chunk is one token, so the
                 # step packs [max_batch] tokens instead of [token_budget] and
                 # the kernel runs its max_q=1 specialized launch — the
                 # steady-state executable (built once; the MPK-style single
                 # launch per decode step)
-                pallas_mode = "decode"
                 tok_pad = B
             tokens = np.zeros((tok_pad,), np.int32)
             cu = np.zeros((B + 1,), np.int32)
@@ -988,9 +973,9 @@ class PagedServingEngine:
         with _tracing.phase("serve.dispatch"):
             t0 = time.perf_counter_ns()
             builds0 = self.stats["step_builds"]
-            fn = self._get_step_fn(tok_pad, B, pallas_mode, ffn_mode,
+            fn = self._get_step_fn(tok_pad, B, decode, ffn_mode,
                                    ad_sig, spec_mode)
-            fused_tick = bool(ffn_mode) and pallas_mode == "decode"
+            fused_tick = bool(ffn_mode) and decode
             launches0 = FA.trace_launches()
             out = fn(
                 self.params, self._key_cache, self._value_cache,
@@ -1036,7 +1021,7 @@ class PagedServingEngine:
                 self.stats["moe_experts_hit"] += moe[1]
                 self.stats["moe_max_load"] = max(
                     self.stats["moe_max_load"], moe[2])
-            if pallas_mode == "decode":
+            if decode and self._decode_walk:
                 # how well the decode launch's walk fits the traffic, from
                 # the host's own lengths: pages that hold a live key, and
                 # pages the walk fetches (whole key blocks)
@@ -1053,8 +1038,7 @@ class PagedServingEngine:
                 batch=len(batch.items),
                 tokens=batch.total_tokens + spec_extra,
                 prefill_tokens=n_prefill,
-                kind="decode" if pallas_mode == "decode" else "mixed",
-                **fields)
+                kind="decode" if decode else "mixed", **fields)
             if _tracing.trace_enabled():
                 # per-request tick attribution: each traced request in the
                 # batch gets a span over this tick's device interval, so a
@@ -1067,12 +1051,12 @@ class PagedServingEngine:
                             seq.trace_id, seq.parent_span, t0, dur,
                             rid=seq.rid, tokens=n,
                             replica=self._trace_replica)
-            if pallas_mode:
-                kind = "decode" if pallas_mode == "decode" else "mixed"
+            if self.pallas:
                 self.stats["pallas_steps"] += 1
-                if kind == "decode":
+                if decode:
                     self.stats["decode_fast_steps"] += 1
-                _emit("serving.pallas_step", launch=kind)
+                _emit("serving.pallas_step",
+                      launch="decode" if decode else "mixed")
             if ffn_mode:
                 self.stats["ffn_steps"] += 1
                 if fused_tick:

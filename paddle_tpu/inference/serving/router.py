@@ -50,8 +50,7 @@ from .adapters import AdapterMissingError
 from .engine import PagedServingEngine, TokenEvent
 from .replica import (DEAD, DEGRADED, DRAINED, DRAINING, HEALTHY,
                       ReplicaHandle, ReplicaKilledError)
-from .scheduler import DeadlineExceededError, RejectedError
-from .slot_engine import Completion
+from .scheduler import Completion, DeadlineExceededError, RejectedError
 
 __all__ = ["ServingRouter", "RouterRequest", "FailoverMismatchError"]
 

@@ -37,7 +37,7 @@ from ...observability import tracing as _tracing
 from .block_manager import BlockManager, NoFreeBlocksError
 
 __all__ = ["RejectedError", "DeadlineExceededError", "Sequence",
-           "ScheduledBatch", "Scheduler"]
+           "Completion", "ScheduledBatch", "Scheduler"]
 
 flags.define_flag("serving_max_queue", 128,
                   "Serving admission control: submissions beyond this many "
@@ -95,6 +95,15 @@ class Sequence:
 
     def remaining(self) -> int:
         return len(self.tokens) - self.num_computed
+
+
+@dataclass
+class Completion:
+    """What a finished request leaves behind (`engine.run()`, the router)."""
+    rid: int
+    prompt_tokens: List[int]
+    output_tokens: List[int]
+    finish_reason: str  # stop | length | deadline | cancelled | shed | ...
 
 
 @dataclass

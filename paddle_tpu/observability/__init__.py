@@ -7,8 +7,8 @@ One choke point, ``emit(kind, dur_s=None, **fields)``, feeds BOTH:
   (watchdog timeout / fatal enforce / SIGUSR1) for post-mortem debugging;
 - the **metrics registry** (metrics.py): counters/gauges/histograms with
   Prometheus text exposition and a JSON snapshot — the numbers behind
-  ``profiler.dispatch_cache_stats()`` / ``async_stats()``, perf_probe,
-  bench.py artifacts and the ci_op_benchmark overhead gate.
+  ``profiler.dispatch_cache_stats()`` / ``async_stats()`` and the
+  ci_op_benchmark overhead gate.
 
 Fast path: ``FLAGS_metrics_sampling=0`` turns ``emit`` into a single
 cached-int check and return (no tuple, no dict, no timestamps) — the
@@ -233,10 +233,6 @@ _c_srv_pallas = _C("paddle_serving_pallas_steps_total",
                    "Serving steps served through the Pallas paged-attention "
                    "kernel, by kind (decode = max_q=1 specialized launch, "
                    "mixed = generic ragged launch)")
-_c_srv_pallas_fb = _C("paddle_serving_pallas_fallback_total",
-                      "Steps that wanted FLAGS_serving_pallas_attention but "
-                      "served stock XLA instead, by reason (unavailable = "
-                      "no TPU, unsupported = head/page geometry)")
 _c_ffn = _C("paddle_pallas_ffn_steps_total",
             "Steps served through the fused Pallas SwiGLU FFN kernel, by "
             "kind (serving = engine tick with fused FFN, fused_tick = the "
@@ -702,8 +698,6 @@ _HANDLERS = {
     "serving.cow": lambda d, f: _c_srv_cow.inc(f.get("copies", 1)),
     "serving.pallas_step": lambda d, f: _c_srv_pallas.inc(
         labels={"kind": f.get("launch", "mixed")}),
-    "serving.pallas_fallback": lambda d, f: _c_srv_pallas_fb.inc(
-        labels={"reason": f.get("reason", "")}),
     "pallas_ffn.step": lambda d, f: _c_ffn.inc(
         labels={"kind": f.get("launch", "serving")}),
     "pallas_ffn.fallback": lambda d, f: _c_ffn_fb.inc(
@@ -872,7 +866,7 @@ def _ratio(ref, actual) -> float:
 
 
 def summary() -> dict:
-    """The perf-triage digest printed by tools and embedded in BENCH_*.json:
+    """The perf-triage digest printed by tools:
     dispatch hit-rate, retrace count, fetch-stall p50/p99."""
     hits = _c_hits.value()
     misses = _c_misses.value()
@@ -932,9 +926,6 @@ def summary() -> dict:
             "pallas_steps": int(_c_srv_pallas.value(
                 {"kind": "decode"}) + _c_srv_pallas.value(
                 {"kind": "mixed"})),
-            "pallas_fallbacks": int(_c_srv_pallas_fb.value(
-                {"reason": "unavailable"}) + _c_srv_pallas_fb.value(
-                {"reason": "unsupported"})),
             "ffn_steps": int(_c_ffn.value(
                 {"kind": "serving"}) + _c_ffn.value(
                 {"kind": "fused_tick"})),

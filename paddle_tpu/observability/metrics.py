@@ -5,8 +5,8 @@ statics (kernel-factory hit counts, GC meta, allocator stats exposed one
 pybind getter at a time); production XLA-stack services converge on a
 single registry with Prometheus text exposition. Here every runtime
 subsystem (dispatch cache, async engine, autograd, collectives, optimizer,
-serving) publishes through ONE registry, so `perf_probe`, `bench.py`, the
-distress dumps and any scrape endpoint all read the same numbers.
+serving) publishes through ONE registry, so `observability.summary()`,
+the distress dumps and any scrape endpoint all read the same numbers.
 
 Concurrency note: updates are plain Python int/float ops under the GIL —
 no locks on the hot path. A racing `+=` can in principle drop a tick

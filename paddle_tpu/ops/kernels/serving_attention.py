@@ -39,20 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ...core import flags
 from ..dispatch import register_op
-
-flags.define_flag(
-    "serving_pallas_attention", False,
-    help="Serve block_multihead_attention_ reads through the Pallas "
-         "paged-attention kernel (ops/pallas/paged_attention.py): the "
-         "block table is walked inside the kernel (no materialized KV "
-         "gather) and int8 pages dequantize in-register. Takes effect "
-         "when the kernel is available() and the head/page geometry is "
-         "supported(); otherwise the stock XLA path serves the step "
-         "(paddle_serving_pallas_fallback_total counts why). Read at "
-         "trace time — PagedServingEngine keys its step executables on "
-         "the value so flips retrace cleanly.")
 
 __all__ = [
     "masked_multihead_attention_", "block_multihead_attention_",
@@ -467,8 +454,8 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
     H = qkv.shape[1] // hd - 2 * KV
 
     # ---- pallas dispatch (static, resolved at trace time):
-    #   None     -> FLAGS_serving_pallas_attention, gated on available()
-    #               (real TPU) and supported() (geometry)
+    #   None     -> paged_attention.selected(): the kernel on a TPU at a
+    #               supported() geometry, the stock path elsewhere
     #   True     -> force the kernel (interpret mode off-TPU; how CPU CI
     #               exercises it bit-for-bit)
     #   "decode" -> force, with the decode-specialized max_q=1 launch; the
@@ -476,9 +463,7 @@ def block_multihead_attention_(qkv, key_cache, value_cache, seq_lens_encoder,
     #   False    -> stock XLA path
     from ..pallas import paged_attention as PA
     if use_pallas is None:
-        use_pallas = (bool(flags.flag_value("serving_pallas_attention"))
-                      and PA.available()
-                      and PA.supported(H, KV, hd, bs))
+        use_pallas = PA.selected(H, KV, hd, bs)
     # one layer's caches are a pool of one layer (a leading axis of 1 is a
     # bitcast): the op and the serving engine's tick share one write and
     # one read

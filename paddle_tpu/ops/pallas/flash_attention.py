@@ -48,7 +48,7 @@ def _i32(x):
 # small trailing lane dim. Mosaic requires the last two dims of every block to
 # be divisible by the (8, 128) native tile or EQUAL the array dims; a rank-3
 # [B, H, T] block (1, 1, bq) puts a size-1 second-minor dim against H and
-# fails lowering on real TPU (this killed BENCH_r02). With the trailing dim,
+# fails lowering on real TPU (round 2's failure). With the trailing dim,
 # the block's last dim equals the array dim (legal for any LANES) and the
 # second-minor bq is 8-divisible. LANES=8 keeps the residual small (vs the
 # 128-lane variant of jax's reference kernel, 16x the HBM for the same math).
@@ -88,7 +88,7 @@ def available() -> bool:
 # The wrappers only run while an executable is being TRACED, so the delta
 # across a fresh jit trace equals the number of Pallas launches that
 # executable performs per call — which is how the serving engine pins its
-# per-tick launch budget (serving_smoke asserts fused decode <= 3*layers+1).
+# per-tick launch budget (chip_smoke.py asserts the fused decode tick's 4).
 _TRACE_LAUNCHES = [0]
 
 

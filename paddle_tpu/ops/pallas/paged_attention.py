@@ -10,11 +10,12 @@ gather: the per-sequence block table is *scalar-prefetched* into SMEM
 (`pltpu.PrefetchScalarGridSpec`) and the pages are read through it from
 wherever they lie in the pool.
 
-Design — two walks over one pool, chosen by the static shape of the q
-rows (`rows == group` is the decode launch), because their needs
-conflict: a decode launch has 1–4 query rows a head and is bound by the
-count and size of its fetches; a mixed launch has up to token_budget rows
-a head and is bound by its products.
+Design — two walks over one pool, chosen by the static shapes of the
+launch (one query token a sequence, `rows == group`, at a head_dim whose
+whole pages Mosaic copies: `decode_walk`), because their needs conflict:
+a decode launch has 1–4 query rows a head and is bound by the count and
+size of its fetches; a mixed launch has up to token_budget rows a head
+and is bound by its products.
 
 The mixed walk (`_kernel`, ragged prefill + decode in ONE launch):
 
@@ -91,7 +92,8 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import (NEG_INF, _assert_mosaic_tileable, _i32,
                               available, count_launch)
 
-__all__ = ["paged_attention", "write_pages", "available", "supported"]
+__all__ = ["paged_attention", "write_pages", "available", "supported",
+           "selected", "decode_walk"]
 
 # m/l carriers use the same [rows, LANES] lane-broadcast trick as
 # flash_attention.py (a [rows, 1] scratch column is not a legal vreg shape
@@ -108,11 +110,38 @@ def supported(num_heads: int, num_kv_heads: int, head_dim: int,
     mode ignores it and is how CPU CI exercises the kernel bit-for-bit)."""
     if num_kv_heads <= 0 or num_heads % num_kv_heads != 0:
         return False
-    # blocks equal the array dims on the last two axes, so any
-    # (block_size, head_dim) is Mosaic-legal; keep the same floor as the
-    # flash kernel so degenerate head dims fall back loudly instead of
-    # wasting the MXU
+    # the mixed walk's blocks equal the array dims on the last two axes,
+    # so any (block_size, head_dim) is Mosaic-legal (the decode walk asks
+    # more: `decode_walk`); keep the same floor as the flash kernel so
+    # degenerate head dims fall back loudly instead of wasting the MXU
     return head_dim >= 8 and block_size >= 1
+
+
+def selected(num_heads: int, num_kv_heads: int, head_dim: int,
+             block_size: int) -> bool:
+    """The rule a caller that was told nothing follows: the kernel where
+    it runs (`available()`: a TPU) and the geometry is `supported()`, the
+    stock XLA path elsewhere. `block_multihead_attention_(use_pallas=None)`
+    and `PagedServingEngine(pallas=None)` both ask here, once, when they
+    trace or are built."""
+    return available() and supported(num_heads, num_kv_heads, head_dim,
+                                     block_size)
+
+
+def decode_walk(head_dim: int, interpret: Optional[bool] = None) -> bool:
+    """Can a launch of one query token a sequence (`rows == group`) take
+    the decode walk? It copies whole pages `[KV, block_size, hd]` out of
+    the pool left in HBM, and Mosaic slices HBM in whole lanes: compiled
+    for a v5e, every head_dim of 8 to 64 (page sizes 4 to 32, bf16 and
+    int8 pages) is refused with "Slice shape along dimension 4 must be
+    aligned to tiling (128)", 128 and 256 are taken, and the mixed walk's
+    BlockSpec pages and `write_pages` are taken at all of them
+    (tests/test_chip_compile.py). Such a launch then takes the mixed walk
+    with max_q = 1. The interpreter takes any geometry, which is how CPU
+    CI runs the decode walk at small widths."""
+    if interpret is None:
+        interpret = not available()
+    return interpret or head_dim % _STAT_LANES == 0
 
 
 def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
@@ -425,8 +454,8 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     q_rows.dtype; pad rows come back 0.
 
     Rows equal to `group` (max_q = 1: the CALLER guarantees every
-    seq_lens_this_time <= 1) take the decode walk, any other shape the
-    mixed walk.
+    seq_lens_this_time <= 1) take the decode walk where `decode_walk`
+    says Mosaic lowers it, any other launch the mixed walk.
     """
     if (k_dequant is None) != (v_dequant is None):
         raise ValueError("pass both k_dequant and v_dequant or neither")
@@ -455,7 +484,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     this = seq_lens_this_time.reshape(-1).astype(jnp.int32)   # [B]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    if rows == group:
+    if rows == group and decode_walk(hd, interpret):
         return _decode_call(q_rows, key_cache, value_cache, tables, past,
                             this, layer, sm_scale, k_dequant, v_dequant,
                             interpret)

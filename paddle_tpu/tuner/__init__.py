@@ -1,7 +1,7 @@
 """Offline autotuner over the tunable-flag space.
 
 The repo's config surface (dp bucket sizes + grad-comm dtype/block, pp
-schedule x microbatches x virtual degree, ZeRO-1, Pallas attention/FFN,
+schedule x microbatches x virtual degree, ZeRO-1, the fused Pallas FFN,
 serving token budget x max batch) grew hand-picked; this package turns
 the three measurement sources that already exist — ``ci_op_benchmark``
 op timings, ``schedule.simulate()`` bubbles, wire-byte accounting over
@@ -13,8 +13,8 @@ a measured link estimate — into a search loop:
 3. :mod:`.profile` validates the top-k finalists with short real runs,
    pins the measured winner into a versioned CRC'd manifest per
    (model, topology), and applies it at startup via
-   ``FLAGS_tuned_profile`` (bench.py, the train-step factory and
-   ``PagedServingEngine`` all call :func:`maybe_apply_flagged`).
+   ``FLAGS_tuned_profile`` (the train-step factory and
+   ``PagedServingEngine`` call :func:`maybe_apply_flagged`).
 
 CI: ``tools/tune_smoke.py`` proves analytic top-1 = measured top-1 on a
 toy space with zero steady-state retraces under the applied profile;
@@ -28,7 +28,7 @@ from ..core import flags
 flags.define_flag(
     "tuned_profile", "",
     "Path to a tuned-profile manifest (tuner/profile.py). When set, "
-    "bench.py, make_train_step and PagedServingEngine apply its flag "
+    "make_train_step and PagedServingEngine apply its flag "
     "assignment at startup — before any executable is built, so the "
     "steady state under a profile performs zero retraces. Load, CRC "
     "and topology-mismatch failures raise (fail-loud).")

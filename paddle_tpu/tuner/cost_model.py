@@ -270,11 +270,13 @@ class CostModel:
 
     def _serving_terms(self, w: Workload, c) -> dict:
         """One decode tick composed from the tick/attention/FFN
-        micro-entries. Preference order: a measured whole-tick entry for
-        the exact lever combination (stock / fused), else the stock tick
-        plus per-op deltas for each lever flipped — the fusion-paper
-        discipline of predicting from the most aggregate measurement
-        available."""
+        micro-entries: the measured stock tick plus the per-op delta of
+        the one kernel lever a candidate holds (the fused FFN) — the
+        fusion-paper discipline of predicting from the most aggregate
+        measurement available. The attention read is no candidate's to
+        choose (the engine follows ops/pallas/paged_attention.selected),
+        so it is priced as the anchor tick ran it and is the same for
+        every candidate of one machine."""
         t = self.costs.time
         base = t("decode_tick_stock")
         if base is None:
@@ -282,21 +284,11 @@ class CostModel:
                 f"cost model needs a 'decode_tick_stock' entry under "
                 f"{self.costs.key!r} in {self.costs.path} — run "
                 f"tools/ci_op_benchmark.py --update (or .refresh())")
-        attn_stock = t("block_mha_decode_stock", 0.0)
-        attn_pallas = t("block_mha_decode_pallas", attn_stock)
+        attn_e = t("block_mha_decode_stock", 0.0)
         ffn_stock = t("ffn_fwd_stock", 0.0)
-        ffn_pallas = t("ffn_fwd_pallas", ffn_stock)
+        ffn_e = t("ffn_fwd_pallas", ffn_stock) if c.pallas_ffn else ffn_stock
         L = w.tick_layers
-        fused_tick = t("decode_tick_fused")
-        if c.pallas_attention and c.pallas_ffn and fused_tick is not None:
-            anchor, anchor_name = fused_tick, "decode_tick_fused"
-            attn_e, ffn_e = attn_pallas, ffn_pallas
-        else:
-            anchor_name = "decode_tick_stock"
-            attn_e = attn_pallas if c.pallas_attention else attn_stock
-            ffn_e = ffn_pallas if c.pallas_ffn else ffn_stock
-            anchor = (base + L * (attn_e - attn_stock)
-                      + L * (ffn_e - ffn_stock))
+        anchor = base + L * (ffn_e - ffn_stock)
         # scale the variable portion to the candidate geometry: the
         # attention launch walks batch-slot rows, the FFN walks the
         # padded token_budget rows (executables are keyed on both)
@@ -339,7 +331,7 @@ class CostModel:
         return {"cost": tick_total / (max(1, c.max_batch)
                                       * tokens_per_tick),
                 "tick_s": tick_total, "tokens_per_s": tok_s,
-                "anchor": anchor_name,
+                "anchor": "decode_tick_stock",
                 "terms": {"host_s": host_s, "attn_s": attn_s,
                           "ffn_s": ffn_s, "adapter_s": adapter_s,
                           "swap_s": swap_s, "spec_s": spec_s,

@@ -2,7 +2,7 @@
 
 The search is deliberately dumb-but-exhaustive: the config space the
 repo actually exposes (dp bucket size, grad-comm dtype + block size, pp
-schedule x microbatches x virtual degree, ZeRO-1, Pallas attention/FFN,
+schedule x microbatches x virtual degree, ZeRO-1, the fused Pallas FFN,
 serving token budget x max batch) is small enough — hundreds, not
 millions — that full enumeration under the ANALYTIC model is cheap,
 and only the survivors pay for real validation runs. Pruning is a
@@ -39,7 +39,6 @@ class Candidate:
     pp_microbatches: int = 1         # FLAGS_pp_accumulate_steps
     pp_virtual_degree: int = 1       # FLAGS_pp_virtual_degree
     # kernels
-    pallas_attention: bool = False   # FLAGS_serving_pallas_attention
     pallas_ffn: bool = False         # FLAGS_pallas_ffn
     # serving step geometry
     token_budget: int = 64           # FLAGS_serving_token_budget
@@ -59,7 +58,6 @@ class Candidate:
             "pp_schedule": self.pp_schedule,
             "pp_accumulate_steps": int(self.pp_microbatches),
             "pp_virtual_degree": int(self.pp_virtual_degree),
-            "serving_pallas_attention": bool(self.pallas_attention),
             "pallas_ffn": bool(self.pallas_ffn),
             "serving_token_budget": int(self.token_budget),
             "serving_max_batch": int(self.max_batch),
@@ -76,7 +74,6 @@ class Candidate:
              "pp_schedule": "pp_schedule",
              "pp_accumulate_steps": "pp_microbatches",
              "pp_virtual_degree": "pp_virtual_degree",
-             "serving_pallas_attention": "pallas_attention",
              "pallas_ffn": "pallas_ffn",
              "serving_token_budget": "token_budget",
              "serving_max_batch": "max_batch",

@@ -182,6 +182,102 @@ def test_paged_attention_on_the_serve_pools_compiles(
                                      1 if int8 else 2, max_blocks) == 8
 
 
+@pytest.mark.parametrize("head_dim", [16, 64])
+@pytest.mark.parametrize("launch", ["decode", "mixed"])
+@pytest.mark.parametrize("int8", [False, True])
+def test_paged_attention_off_whole_lanes_compiles(one_chip, head_dim, launch,
+                                                  int8):
+    """Head dims that are no multiple of 128 (`llama-test`'s 16, any
+    64-wide model): both launches compile, and the launch of one token a
+    sequence takes the mixed walk with max_q = 1 (`pa.decode_walk`)."""
+    layers, num_blocks, kv, group, block_size = 2, 64, 2, 2, 8
+    batch, max_blocks = 4, 8
+    assert pa.supported(kv * group, kv, head_dim, block_size)
+    assert not pa.decode_walk(head_dim, interpret=False)
+    rows = group if launch == "decode" else 16 * group
+    page_dtype = jnp.int8 if int8 else jnp.bfloat16
+    pool = ((layers, num_blocks, kv, block_size, head_dim), page_dtype)
+    shapes = [_bf16(batch, kv, rows, head_dim), pool, pool,
+              ((batch, max_blocks), jnp.int32), ((batch,), jnp.int32),
+              ((batch,), jnp.int32), ((), jnp.int32)]
+    if int8:
+        shapes += [((num_blocks, kv), jnp.float32)] * 2
+
+    def fn(q, k, v, tables, past, this, layer, *dequant):
+        return pa.paged_attention(q, k, v, tables, past, this, group,
+                                  head_dim ** -0.5, *dequant,
+                                  interpret=False, layer=layer)
+
+    text = _compile(fn, one_chip, *shapes).as_text()
+    assert "paged_attention" in text
+    assert "paged_attention_decode" not in text
+
+
+def test_decode_walk_is_refused_off_whole_lanes(one_chip):
+    """Guards `pa.decode_walk`: the decode walk forced at head_dim 64 is
+    what Mosaic refuses. When this stops failing the gate can go."""
+    layers, num_blocks, kv, group, block_size, head_dim = 2, 64, 2, 2, 8, 64
+    pool = ((layers, num_blocks, kv, block_size, head_dim), jnp.bfloat16)
+
+    def fn(q, k, v, tables, past, this, layer):
+        return pa._decode_call(q, k, v, tables, past, this,
+                               layer.reshape(1), np.float32(0.125), None,
+                               None, False)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(fn, one_chip, _bf16(4, kv, group, head_dim), pool, pool,
+                 ((4, 8), jnp.int32), ((4,), jnp.int32), ((4,), jnp.int32),
+                 ((), jnp.int32))
+
+
+@pytest.mark.parametrize("hidden, decode_walk", [
+    (64, False),      # llama-test as it stands: 4 heads of 16
+    (256, False),     # 4 heads of 64
+    (512, True),      # 4 heads of 128: the decode tick takes the decode walk
+])
+def test_default_engine_ticks_compile(one_chip, monkeypatch, hidden,
+                                      decode_walk):
+    """`PagedServingEngine(cfg, params)`, told nothing, as a TPU builds it
+    (`available` steered true, the program has no option for it): it takes
+    the kernel, and its mixed and its decode executable, as the engine
+    itself calls them, are compiled for the described chip instead of
+    run."""
+    import dataclasses
+
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import llama as L
+    cfg = dataclasses.replace(L.CONFIGS["llama-test"], hidden_size=hidden)
+    assert cfg.head_dim == hidden // 4
+    params = L.init_params(cfg, jax.random.PRNGKey(0))
+    monkeypatch.setattr(fa, "available", lambda: True)
+    monkeypatch.setattr(pa, "available", lambda: True)
+    eng = PagedServingEngine(cfg, params, block_size=8, max_batch=4,
+                             token_budget=32)
+    assert eng.pallas is True
+    build, texts = eng._build_step, {}
+
+    def compiled_not_run(tok_pad, B, decode=False, *rest):
+        fn = build(tok_pad, B, decode, *rest)
+
+        def tick(*args):
+            abstract = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            texts[decode] = fn.lower(*abstract).compile().as_text()
+            return jnp.zeros((B,), jnp.int32), args[1], args[2]
+        return tick
+
+    monkeypatch.setattr(eng, "_build_step", compiled_not_run)
+    eng.submit([5, 6, 7, 8, 9], max_new_tokens=3)
+    eng.run()
+    assert set(texts) == {False, True}          # one mixed, one decode
+    for text in texts.values():
+        assert "paged_cache_write" in text and "paged_attention" in text
+    assert "paged_attention_decode" not in texts[False]
+    assert ("paged_attention_decode" in texts[True]) == decode_walk
+    assert (eng.stats["attn_pages_fetched"] > 0) == decode_walk
+
+
 @pytest.mark.parametrize("rows", [16, 512])        # decode / mixed tick
 @pytest.mark.parametrize("int8_pages", [False, True])
 def test_paged_layer_in_stacked_pool_compiles(one_chip, rows, int8_pages):

@@ -6,7 +6,8 @@ of SURVEY.md §4). Interpret mode skips Mosaic's block-mapping validation
 kernel mirrors that rule statically (`fa._assert_mosaic_tileable`, exercised
 at every trace) and `test_mosaic_tiling_rule*` below pins the regression.
 The kernel was verified end-to-end (lower+compile+run, fwd+bwd, GQA) on a
-real TPU v5e chip on 2026-07-29; bench.py re-checks lowering every run.
+real TPU v5e chip on 2026-07-29; tests/test_chip_compile.py re-checks the
+lowering for a described v5e.
 """
 import functools
 
@@ -156,7 +157,7 @@ def test_inside_jit_and_scan():
 
 
 def test_mosaic_tiling_rule_rejects_rank3_lse():
-    # The exact BENCH_r02 failure: lse [B, H, T] with block (1, 1, bq) puts a
+    # The exact round-2 failure: lse [B, H, T] with block (1, 1, bq) puts a
     # size-1 second-minor dim against H != 1. Must be rejected statically.
     with pytest.raises(ValueError, match="8, 128"):
         fa._assert_mosaic_tileable((1, 1, 256), (4, 12, 2048), "lse")

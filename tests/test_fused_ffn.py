@@ -347,12 +347,12 @@ def test_ffn_mode_is_in_executable_cache_key():
     cfg = _tiny_cfg()
     params = L.init_params(cfg, jax.random.PRNGKey(0))
     eng = _engine(cfg, params)
-    eng._get_step_fn(32, 4, pallas_mode=False, ffn_mode=False)
+    eng._get_step_fn(32, 4, ffn_mode=False)
     b0 = eng.stats["step_builds"]
-    eng._get_step_fn(32, 4, pallas_mode=False, ffn_mode=True)
+    eng._get_step_fn(32, 4, ffn_mode=True)
     assert eng.stats["step_builds"] == b0 + 1
-    eng._get_step_fn(32, 4, pallas_mode=False, ffn_mode=False)
-    eng._get_step_fn(32, 4, pallas_mode=False, ffn_mode=True)
+    eng._get_step_fn(32, 4, ffn_mode=False)
+    eng._get_step_fn(32, 4, ffn_mode=True)
     assert eng.stats["step_builds"] == b0 + 1
 
 

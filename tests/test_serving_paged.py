@@ -249,6 +249,36 @@ class TestPagedEngineParity:
         assert eos not in done.output_tokens
         assert done.output_tokens == hostloop_ref(prompt, 40, eos)
 
+    def test_admitted_mid_flight_yields_what_it_yields_alone(
+            self, tiny, hostloop_ref):
+        cfg, params = tiny
+        eng = PagedServingEngine(cfg, params, num_blocks=48, block_size=4,
+                                 max_batch=3, token_budget=16)
+        p1, p2 = _prompts(cfg, 2, [9, 6], seed=27)
+        r1 = eng.submit(p1, max_new_tokens=12)
+        for _ in range(4):
+            eng.step()                            # r1 is decoding by now
+        r2 = eng.submit(p2, max_new_tokens=7)
+        done = {c.rid: c.output_tokens for c in eng.run()}
+        assert done[r2] == hostloop_ref(p2, 7)
+        assert done[r1] == hostloop_ref(p1, 12)
+
+    def test_eos_returns_the_requests_pages(self, tiny, hostloop_ref):
+        cfg, params = tiny
+        prompt = _prompts(cfg, 1, [6], seed=28)[0]
+        ref = hostloop_ref(prompt, 5)
+        eos = ref[4]
+        assert eos not in ref[:4]                 # it ends the fifth tick
+        eng = PagedServingEngine(cfg, params, num_blocks=32, block_size=4,
+                                 max_batch=2, token_budget=16)
+        eng.submit(prompt, max_new_tokens=40, eos_token_id=eos)
+        eng.step()
+        assert eng.blocks.num_allocated() > 0
+        (done,) = eng.run()
+        assert done.finish_reason == "stop" and done.output_tokens == ref[:4]
+        assert eng.blocks.num_allocated() == 0
+        assert eng.blocks.num_free() == 32
+
     def test_prefix_cache_reuses_blocks_across_requests(self, tiny):
         cfg, params = tiny
         eng = PagedServingEngine(cfg, params, num_blocks=32, block_size=4,
@@ -447,18 +477,6 @@ class TestServingObservability:
         assert 0.0 <= s["kv_block_utilization"] <= 1.0
         assert s["steps_total"] == eng.stats["steps"]
 
-    def test_legacy_slot_engine_reports_through_summary(self, tiny):
-        from paddle_tpu.inference.serving import ServingEngine
-        cfg, params = tiny
-        obs.reset()
-        eng = ServingEngine(cfg, params, num_slots=2, max_len=96, chunk=4)
-        for p in _prompts(cfg, 2, [4], seed=14):
-            eng.submit(p, max_new_tokens=4)
-        eng.run()
-        s = obs.summary()["serving"]
-        assert s["admitted"] == 2 and s["completed"] == 2
-        assert obs.registry().value("paddle_serving_tokens_total") > 0
-
     def test_chaos_stall_trips_deadline_path(self, tiny):
         """A chaos-injected decode stall pushes an in-flight request past
         its deadline; the expiry shows up in metrics and the completion."""
@@ -625,6 +643,44 @@ class TestPallasPagedAttention:
         assert not PA.supported(4, 2, 4, 16)     # head_dim floor
         assert not PA.supported(4, 2, 64, 0)     # degenerate page
 
+    @pytest.mark.parametrize("available", [False, True])
+    @pytest.mark.parametrize("supported", [False, True])
+    def test_rule_is_available_and_supported(self, monkeypatch, available,
+                                             supported):
+        """`selected`: the kernel where it runs and takes the geometry.
+        No launch here (`paged_attention` reads `available()` for its
+        interpret mode, so a test that runs the kernel patches the rule,
+        never `available`)."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        seen = []
+        monkeypatch.setattr(PA, "available", lambda: available)
+        monkeypatch.setattr(
+            PA, "supported",
+            lambda *geometry: seen.append(geometry) or supported)
+        assert PA.selected(32, 8, 128, 16) is (available and supported)
+        assert seen in ([], [(32, 8, 128, 16)])
+
+    @pytest.mark.parametrize("rule", [False, True])
+    def test_op_told_nothing_follows_the_rule(self, monkeypatch, rule):
+        """`use_pallas=None` is the forced call the rule names, with the
+        geometry the op reads off its arguments."""
+        from paddle_tpu.ops.kernels.serving_attention import (
+            block_multihead_attention_)
+        from paddle_tpu.ops.pallas import flash_attention as FA
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        args = _mha_args(past=[8, 0, 15], this=[5, 9, 1], seed=9)
+        asked = []
+        monkeypatch.setattr(PA, "selected",
+                            lambda *geometry: asked.append(geometry) or rule)
+        launches0 = FA.trace_launches()
+        got = block_multihead_attention_.__wrapped__(**args)
+        assert asked == [(4, 2, 8, 8)]
+        assert (FA.trace_launches() > launches0) is rule
+        want = block_multihead_attention_.__wrapped__(use_pallas=rule,
+                                                      **args)
+        for g, w in zip(got, want):
+            assert np.array_equal(np.asarray(g), np.asarray(w))
+
     @pytest.mark.parametrize("bs", [8, 16])
     def test_parity_across_page_sizes(self, bs):
         """Interpret-mode kernel vs stock XLA on a ragged mixed batch:
@@ -781,6 +837,48 @@ class TestPallasPagedAttention:
             PA.paged_attention(q, pool[0], pool[0], bt, z, z, 2, 1.0, layer=1)
 
 
+class TestNoFlagNoDimension:
+    """What went with the rule's second and third homes: a flag nobody
+    needs to set cannot be set, searched or pinned."""
+
+    def test_flag_is_unknown(self):
+        from paddle_tpu.core import flags
+        with pytest.raises(KeyError):
+            flags.flag_value("serving_pallas_attention")
+        with pytest.raises(KeyError):
+            flags.flag_value("no_such_flag_at_all")
+
+    def test_tuner_has_no_attention_dimension(self):
+        import dataclasses
+
+        from paddle_tpu.tuner.search import Candidate
+        names = {f.name for f in dataclasses.fields(Candidate)}
+        assert "pallas_attention" not in names and "pallas_ffn" in names
+        assert "serving_pallas_attention" not in Candidate().to_flags()
+        with pytest.raises(TypeError):
+            Candidate(pallas_attention=True)
+
+    def test_pinned_profile_applies_strictly(self, monkeypatch):
+        import os
+
+        from paddle_tpu import tuner
+        from paddle_tpu.core import flags
+        from paddle_tpu.tuner import profile as P
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tuned_profiles",
+            "serving_llama_tiny_cpu.json")
+        prof = tuner.load_profile(path)              # the CRC holds
+        # pinned on one CPU device; the suite runs on eight virtual ones
+        one_cpu = tuner.topology_signature(n_devices=1)
+        monkeypatch.setattr(P, "topology_signature", lambda: one_cpu)
+        keep = {k: flags.flag_value(k) for k in prof.flags}
+        try:
+            tuner.apply_profile(prof, strict=True)   # every key is a flag
+            assert flags.flag_value("serving_max_batch") == 16
+        finally:
+            flags.set_flags(keep)
+
+
 class TestEnginePallas:
     def _engine(self, tiny, pallas, **kw):
         cfg, params = tiny
@@ -865,27 +963,59 @@ class TestEnginePallas:
         assert PA.decode_pages_walked([20, 1], 4, 2, 8, 4, 5) == (6, 8)
         assert PA.decode_pages_walked([], 4, 2, 8, 4, 5) == (0, 0)
 
-    def test_flag_driven_falls_back_off_tpu(self, tiny):
-        """FLAGS_serving_pallas_attention on a host without the TPU kernel
-        path serves stock and counts the fallback reason."""
-        from paddle_tpu.core import flags
+    @pytest.mark.parametrize("rule", [False, True], ids=["off_tpu", "on"])
+    def test_engine_told_nothing_follows_the_rule(self, tiny, monkeypatch,
+                                                  rule):
+        """`pallas=None` asks `paged_attention.selected` once, when the
+        engine is built. Where the rule says no (this host, unpatched) the
+        engine serves the stock path, which is a choice and no fallback;
+        where it says yes (the rule patched, never `available()`: the
+        kernel then runs in interpret mode) the engine is the `pallas=True`
+        engine."""
         from paddle_tpu.ops.pallas import paged_attention as PA
-        if PA.available():
-            pytest.skip("real TPU: flag-driven mode would engage")
+        if rule:
+            monkeypatch.setattr(PA, "selected", lambda *geometry: True)
+        elif PA.available():
+            pytest.skip("a TPU: the unpatched rule takes the kernel")
         obs.reset()
-        flags.set_flags({"serving_pallas_attention": True})
-        try:
-            eng = self._engine(tiny, None)
-            eng.submit(_prompts(tiny[0], 1, [5], seed=24)[0],
-                       max_new_tokens=3)
-            eng.run()
+        prompts = _prompts(tiny[0], 2, [5, 9], seed=24)
+
+        def run(pallas):
+            eng = self._engine(tiny, pallas)
+            rids = [eng.submit(p, max_new_tokens=5) for p in prompts]
+            done = {c.rid: c.output_tokens for c in eng.run()}
+            return eng, [done[r] for r in rids]
+
+        eng, tokens = run(None)
+        assert eng.pallas is rule
+        events = [(kind, f) for _, _, kind, _, f in obs.recorder().events()]
+        assert "serving.pallas_fallback" not in [kind for kind, _ in events]
+        assert "pallas_fallbacks" not in obs.summary()["serving"]
+        writes = {f["cache_write"] for kind, f in events
+                  if kind == "serving.step_build"}
+        if rule:
+            assert writes == {"pallas_pages"}
+            assert eng.stats["pallas_steps"] == eng.stats["steps"] > 0
+            assert eng.stats["decode_fast_steps"] > 0
+        else:
+            assert writes == {"scatter_rows"}
             assert eng.stats["pallas_steps"] == 0
-            assert obs.registry().value(
-                "paddle_serving_pallas_fallback_total",
-                {"reason": "unavailable"}) > 0
-            assert obs.summary()["serving"]["pallas_fallbacks"] > 0
-        finally:
-            flags.set_flags({"serving_pallas_attention": False})
+            assert eng.stats["step_builds"] == 1
+        assert tokens == run(rule)[1]
+
+    def test_step_key_holds_what_differs_between_ticks(self, tiny):
+        """The attention read is the engine's constant and no part of the
+        executable key: a run with a mixed tick and decode ticks builds two
+        executables, told apart by the tick's shape alone."""
+        eng = self._engine(tiny, True)
+        for p in _prompts(tiny[0], 3, [5, 3, 8], seed=26):
+            eng.submit(p, max_new_tokens=6)
+        eng.run()
+        assert eng.stats["decode_fast_steps"] > 0
+        assert eng.stats["step_builds"] == 2 == len(eng._step_fns)
+        B, budget = eng.max_batch, eng.token_budget
+        assert set(eng._step_fns) == {(budget, B, False, False, (), False),
+                                      (B, B, True, False, (), False)}
 
     def test_forced_bad_geometry_fails_at_init(self):
         # head_dim 16/4 = 4 is under the kernel's floor: forced pallas
@@ -956,8 +1086,8 @@ class TestPoolUpdatedInPlace:
         # a decode tick packs max_batch rows (and with the kernel takes the
         # max_q=1 launch), a mixed tick token_budget rows
         tok_pad = eng.max_batch if tick == "decode" else eng.token_budget
-        mode = pallas and ("decode" if tick == "decode" else True)
-        fn = eng._build_step(tok_pad, eng.max_batch, mode)
+        fn = eng._build_step(tok_pad, eng.max_batch,
+                             pallas and tick == "decode")
         args = _step_args(eng, tok_pad)
         pool = eng._key_cache.shape
         layer = pool[1:]
@@ -1000,7 +1130,7 @@ class TestPoolUpdatedInPlace:
         cfg, params = tiny
         for pallas, want in ((True, "pallas_pages"), (False, "scatter_rows")):
             eng = PagedServingEngine(cfg, params, pallas=pallas, **ENGINE_KW)
-            eng._get_step_fn(16, 4, pallas)
+            eng._get_step_fn(16, 4)
             builds = [f for _, _, kind, _, f in obs.recorder().events()
                       if kind == "serving.step_build"]
             assert builds[-1]["cache_write"] == want
@@ -1178,7 +1308,7 @@ class TestPoolContentParity:
             eng = PagedServingEngine(cfg, params, pallas=bool(pallas),
                                      max_len=96, **ENGINE_KW)
             before = _sentinel_pools(eng)
-            fn = eng._get_step_fn(tok_pad, B, pallas)
+            fn = eng._get_step_fn(tok_pad, B, decode)
             _, kc, vc = fn(*_step_args(eng, tok_pad, tokens, tab, cu, past,
                                        this))
             return np.asarray(kc), np.asarray(vc), before

@@ -23,10 +23,10 @@ from paddle_tpu.tuner import (Candidate, CostModel, OpCosts, Ranked,
                               TunedProfile, Workload)
 
 
-def _toy_costs(**times):
+def _toy_costs(key="test/toy", **times):
     """OpCosts detached from the pinned baseline file."""
     oc = OpCosts.__new__(OpCosts)
-    oc.path, oc.key, oc.pinned = "<toy>", "test/toy", True
+    oc.path, oc.key, oc.pinned = "<toy>", key, True
     oc.times = dict(times)
     oc.noises = {k: 0.0 for k in times}
     return oc
@@ -120,17 +120,26 @@ class TestCostModel:
 
     def test_serving_cost_is_seconds_per_token(self):
         """Bigger max_batch amortizes the fixed host slice of the tick:
-        sec/token must fall, and the fused-tick anchor must be used when
-        both pallas levers are on."""
+        sec/token must fall. The attention read is no candidate's and no
+        entry of the kernel's prices it: whatever the machine class, the
+        anchor is the stock tick and the one kernel lever (the fused FFN)
+        moves it by its per-op delta."""
         m = _model()
         w = Workload("s", kind="serving")
         small = m.predict(w, Candidate(max_batch=4))
         big = m.predict(w, Candidate(max_batch=16))
         assert big["cost"] < small["cost"]
-        fused = m.predict(w, Candidate(pallas_attention=True,
-                                       pallas_ffn=True))
-        assert fused["anchor"] == "decode_tick_fused"
-        assert m.predict(w, Candidate())["anchor"] == "decode_tick_stock"
+        times = dict(SERVING_TIMES, ffn_fwd_pallas=3.3e-6,
+                     block_mha_decode_pallas=1.0, decode_tick_fused=1.0)
+        for key in ("test/toy", "tpu/13cpu"):
+            m = CostModel(costs=_toy_costs(key=key, **times),
+                          link_bytes_per_s=1e9)
+            stock = m.predict(w, Candidate())
+            fused = m.predict(w, Candidate(pallas_ffn=True))
+            assert stock["anchor"] == fused["anchor"] == "decode_tick_stock"
+            assert stock["terms"]["attn_s"] == fused["terms"]["attn_s"]
+            assert fused["tick_s"] == pytest.approx(
+                stock["tick_s"] - w.tick_layers * 3.3e-6)
 
     def test_spec_k_term_rides_acceptance_and_draft_cost(self):
         """Speculation pays k draft steps (draft_cost_ratio of a tick
@@ -380,9 +389,9 @@ def tiny_llama():
 def reset_tuner_flags():
     keep = {k: flags.flag_value(k) for k in
             ("tuned_profile", "serving_max_batch", "serving_token_budget",
-             "pp_accumulate_steps", "serving_pallas_attention",
-             "pallas_ffn", "dp_grad_comm_dtype", "dp_comm_block_size",
-             "dp_shard_update", "pp_schedule", "pp_virtual_degree")}
+             "pp_accumulate_steps", "pallas_ffn", "dp_grad_comm_dtype",
+             "dp_comm_block_size", "dp_shard_update", "pp_schedule",
+             "pp_virtual_degree")}
     yield
     flags.set_flags(keep)
     from paddle_tpu.tuner import profile as _p

@@ -13,7 +13,7 @@ The drill for the MPMD pipeline subsystem
 
 Step times (naive-sequential GPipe vs 1F1B) are reported for trend
 logging only — virtual CPU devices share one threadpool, so wall-clock
-overlap is not gated here (bench.py reports the same trio).
+overlap is not gated here.
 """
 from __future__ import annotations
 

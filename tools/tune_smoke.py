@@ -20,7 +20,7 @@ The drill for the tuner subsystem:
   assignment made before tracing, so the steady state never retraces.
 
 The candidates differ along the axes the cost model actually ranks on
-CPU: step geometry (max_batch) and the pallas-vs-stock kernel choice.
+CPU: step geometry (max_batch) and the fused-vs-stock FFN choice.
 """
 from __future__ import annotations
 
@@ -50,8 +50,7 @@ def _candidates():
     return [
         Candidate(),                                   # stock, hand-picked
         Candidate(max_batch=16),                       # bigger step
-        Candidate(pallas_attention=True,
-                  pallas_ffn=True),                    # fused kernels
+        Candidate(pallas_ffn=True),                    # the fused FFN
     ]
 
 
@@ -72,8 +71,7 @@ def run() -> dict:
     # model composes — the smoke must hold on today's machine state, not
     # on whatever the pinned baseline remembers
     costs = tuner.OpCosts()
-    costs.refresh(["decode_tick_stock", "decode_tick_fused",
-                   "block_mha_decode_stock", "block_mha_decode_pallas",
+    costs.refresh(["decode_tick_stock", "block_mha_decode_stock",
                    "ffn_fwd_stock", "ffn_fwd_pallas"], reps=MEASURE_REPS)
     model = tuner.CostModel(costs=costs)
     workload = tuner.Workload("tune_smoke_serving", kind="serving",
@@ -85,7 +83,7 @@ def run() -> dict:
         eng = PagedServingEngine(
             cfg, params, block_size=8, max_batch=c.max_batch,
             token_budget=c.token_budget, max_len=cfg.max_seq_len,
-            pallas=c.pallas_attention, pallas_ffn=c.pallas_ffn)
+            pallas_ffn=c.pallas_ffn)
         rs = np.random.RandomState(7)
         for _ in range(c.max_batch):
             eng.submit(rs.randint(1, cfg.vocab_size, 12).tolist(),
