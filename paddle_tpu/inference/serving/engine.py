@@ -226,7 +226,8 @@ class PagedServingEngine:
                       "fused_ticks": 0, "tick_pallas_launches": 0,
                       "spec_ticks": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "attn_pages_live": 0,
-                      "attn_pages_fetched": 0}
+                      "attn_pages_fetched": 0, "attn_q_tiles": 0,
+                      "attn_rows_live": 0, "attn_rows_packed": 0}
         if cfg.num_experts:
             # routed-expert work, summed over ticks (max_load: the largest
             # seen): (row, expert) pairs, (layer, expert) groups with at
@@ -271,9 +272,9 @@ class PagedServingEngine:
                 f"paged-attention kernel")
         self.pallas = bool(PA.selected(*geometry) if pallas is None
                            else pallas)
-        # whether an all-decode tick's read is the decode walk (its page
-        # counters are reckoned only then) or the mixed walk with max_q = 1
-        self._decode_walk = self.pallas and PA.decode_walk(cfg.head_dim)
+        # whether a tick's read is one of the whole-page walks (their
+        # counters are reckoned only then) or the BlockSpec walk
+        self._whole_pages = self.pallas and PA.whole_pages(cfg.head_dim)
         # fused-FFN routing mirrors the attention tri-state: None =
         # FLAGS_pallas_ffn per tick; True = force (interpret off-TPU);
         # False = off. Forced mode validates params + geometry eagerly.
@@ -977,12 +978,14 @@ class PagedServingEngine:
                                    ad_sig, spec_mode)
             fused_tick = bool(ffn_mode) and decode
             launches0 = FA.trace_launches()
+            # the host arrays go in as they are: the call moves them with
+            # its own argument handling, which costs the tick half of what
+            # ten `jnp.asarray` did (1.3 against 2.7 ms of dispatch on the
+            # chip, PERF.md PR 30), and nothing writes them afterwards
             out = fn(
                 self.params, self._key_cache, self._value_cache,
-                self._kv_scales, jnp.asarray(tokens), jnp.asarray(tables),
-                jnp.asarray(cu), jnp.asarray(dec_lens), jnp.asarray(this_lens),
-                self._rope_emb, jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(keys), jnp.asarray(greedy), ad_args)
+                self._kv_scales, tokens, tables, cu, dec_lens, this_lens,
+                self._rope_emb, temps, top_ps, keys, greedy, ad_args)
 
         with _tracing.phase("serve.wait"):
             all_arg = None
@@ -1021,19 +1024,28 @@ class PagedServingEngine:
                 self.stats["moe_experts_hit"] += moe[1]
                 self.stats["moe_max_load"] = max(
                     self.stats["moe_max_load"], moe[2])
-            if decode and self._decode_walk:
-                # how well the decode launch's walk fits the traffic, from
-                # the host's own lengths: pages that hold a live key, and
-                # pages the walk fetches (whole key blocks)
-                live, fetched = PA.decode_pages_walked(
-                    (dec_lens + this_lens)[this_lens > 0], self.block_size,
-                    self.cfg.num_kv_heads, self.cfg.head_dim,
-                    np.dtype(self.cache_dtype).itemsize,
-                    self.max_blocks_per_seq)
-                fields["attn_pages_live"] = live
-                fields["attn_pages_fetched"] = fetched
-                self.stats["attn_pages_live"] += live
-                self.stats["attn_pages_fetched"] += fetched
+            if self._whole_pages:
+                # how well the launch's walk fits the traffic, from the
+                # host's own lengths: pages that hold a live key against
+                # pages fetched (whole key blocks), and on a mixed tick the
+                # work items with their live and packed query rows
+                cfg = self.cfg
+                pool = (cfg.head_dim, np.dtype(self.cache_dtype).itemsize,
+                        self.max_blocks_per_seq)
+                if decode:
+                    walked = dict(zip(
+                        ("attn_pages_live", "attn_pages_fetched"),
+                        PA.decode_pages_walked(
+                            (dec_lens + this_lens)[this_lens > 0],
+                            self.block_size, cfg.num_kv_heads, *pool)))
+                else:
+                    walked = PA.mixed_work(
+                        dec_lens, this_lens, tok_pad, self.block_size,
+                        cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads,
+                        *pool)
+                fields.update(walked)
+                for name, n in walked.items():
+                    self.stats[name] += n
             tick.set_metadata(
                 batch=len(batch.items),
                 tokens=batch.total_tokens + spec_extra,

@@ -636,24 +636,26 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
         G = H // KV
         q_g = q_tok.reshape(token_num, KV, G, hd)                # head h = kv*G+g
         if use_pallas:
-            # ---- pallas read: pack q per sequence into [B, KV, max_q*G, hd]
-            # rows (row r = t*G + g) and let the kernel walk the block table —
-            # no dense gather ever exists, and no slice of the layer either:
-            # the freshly written pool goes in whole, with the layer index;
-            # int8 pages ride with their scale planes.
-            maxq = 1 if use_pallas == "decode" else token_num
-            t_off = jnp.arange(maxq, dtype=jnp.int32)
-            row_tok = jnp.clip(cu[:B, None] + t_off[None, :], 0, token_num - 1)
-            q_pack = q_g[row_tok]                                # [B, maxq, KV, G, hd]
-            q_pack = q_pack.transpose(0, 2, 1, 3, 4).reshape(B, KV, maxq * G, hd)
-            o_pack = PA.paged_attention(
-                q_pack, key_pool, value_pool, block_tables, past, this, G,
-                float(1.0 / np.sqrt(hd)), k_dequant=k_dequant,
-                v_dequant=v_dequant, layer=layer)
-            o_pack = o_pack.reshape(B, KV, maxq, G, hd).transpose(0, 2, 1, 3, 4)
-            o = o_pack[tok_b, jnp.minimum(tok_local, maxq - 1)]  # [tok, KV, G, hd]
-            o = jnp.where(tok_valid[:, None, None, None],
-                          o.astype(jnp.float32), 0.0)
+            # ---- pallas read: the kernel walks the block table — no dense
+            # gather ever exists, and no slice of the layer either: the
+            # freshly written pool goes in whole, with the layer index; int8
+            # pages ride with their scale planes.
+            sm_scale = float(1.0 / np.sqrt(hd))
+            if use_pallas == "decode":
+                # one token a sequence: rows [B, KV, G, hd], row b = token
+                # cu[b] (an idle slot's is masked by its length)
+                row_tok = jnp.clip(cu[:B], 0, token_num - 1)
+                o = PA.paged_attention(
+                    q_g[row_tok], key_pool, value_pool, block_tables, past,
+                    this, G, sm_scale, k_dequant=k_dequant,
+                    v_dequant=v_dequant, layer=layer)[tok_b]
+                o = jnp.where(tok_valid[:, None, None, None], o, 0)
+            else:
+                # ragged chunks: the packed stream goes in as it is
+                o = PA.paged_attention_packed(
+                    q_g, key_pool, value_pool, block_tables, past, this, cu,
+                    sm_scale, k_dequant=k_dequant, v_dequant=v_dequant,
+                    layer=layer)
             fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
             return fmha_out, qkv3.reshape(token_num, -1), key_pool, value_pool
 

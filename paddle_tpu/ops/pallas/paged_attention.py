@@ -10,36 +10,13 @@ gather: the per-sequence block table is *scalar-prefetched* into SMEM
 (`pltpu.PrefetchScalarGridSpec`) and the pages are read through it from
 wherever they lie in the pool.
 
-Design — two walks over one pool, chosen by the static shapes of the
-launch (one query token a sequence, `rows == group`, at a head_dim whose
-whole pages Mosaic copies: `decode_walk`), because their needs conflict:
-a decode launch has 1–4 query rows a head and is bound by the count and
-size of its fetches; a mixed launch has up to token_budget rows a head
-and is bound by its products.
-
-The mixed walk (`_kernel`, ragged prefill + decode in ONE launch):
-
-- grid `(B, KV, P)` with the page axis innermost, one `[block_size, hd]`
-  page of one KV head a step through a BlockSpec index map; online-softmax
-  running statistics (m, l, acc) live in VMEM scratch across the page
-  walk (the flash_attention.py formulation over pages instead of dense
-  kv blocks);
-- the packed q tokens are regrouped per sequence into
-  `[B, KV, max_q * G, hd]` rows (GQA group g and chunk offset t fold into
-  one MXU axis, row r = t*G + g) and the chunked-prefill metadata the
-  scheduler already produces (`seq_lens_decoder` past +
-  `seq_lens_this_time`) is prefetched so the kernel masks
-  `kv_pos <= past + t` per row — in-chunk causality holds because the
-  pages already contain this step's tokens (the append happens before the
-  read, same as the stock path);
-- pages past a sequence's live length are *skipped* (`pl.when` on the
-  prefetched lengths): no fetch, no product, but still a grid step each;
-- int8 pages dequantize IN-REGISTER: the per-page scale planes
-  `[num_blocks, KV]` ride the same prefetched table through (8, KV)
-  SMEM blocks; the k scale is constant over hd so it factors out of the
-  q·k dot and lands on the scores, the v scale lands on the probabilities —
-  bit-identical placement to the stock path's folding, and no fp copy
-  of the cache ever exists.
+Design — three walks over one pool, chosen by the static shapes of the
+launch. Two copy whole pages out of the pool left in HBM and run wherever
+Mosaic takes such a copy (`whole_pages`: head dims that are whole lanes,
+every benchmark cell): the decode walk for a launch of one query token a
+sequence (`rows == group`), bound by the count and size of its fetches,
+and the mixed walk for a ragged launch, bound by its products. The third,
+the BlockSpec walk, takes both kinds of launch at every other head dim.
 
 The decode walk (`_decode_kernel`, `max_q = 1`, rows `[B, KV, G, hd]`):
 
@@ -53,33 +30,82 @@ The decode walk (`_decode_kernel`, `max_q = 1`, rows `[B, KV, G, hd]`):
   head; a key block is P pages (`decode_pages_per_block`: about 128 key
   positions, from shapes and a VMEM budget alone) gathered by
   `pltpu.make_async_copy` into a double-buffered scratch, the next
-  block's copies started before this block's products;
-- per head the same online softmax, mask (`kv_pos <= past`), zero for an
-  idle slot and in-register int8 dequantisation as the mixed walk, over a
-  block of P * block_size keys; the dots keep their operand types (blocks
-  cast to f32). An int8 page's `[KV]` scale row rides with the page: one
-  more copy beside it, out of the plane padded to whole lanes (Mosaic
-  takes no copy of an 8-wide row out of `[num_blocks, KV]`) into SMEM,
-  so what a launch holds on chip does not grow with the table;
+  block's copies started before this block's products (`_page_copies`,
+  shared with the mixed walk);
+- per head an online softmax over the block's P * block_size keys with
+  running statistics (m, l, acc) in VMEM scratch, the mask `kv_pos <=
+  past`, zero for an idle slot; the dots run on blocks cast to f32;
+- int8 pages dequantize IN-REGISTER: the k scale is constant over hd so
+  it factors out of the q·k dot and lands on the scores, the v scale on
+  the probabilities — bit-identical placement to the stock path's
+  folding, and no fp copy of the cache ever exists. A page's `[KV]` scale
+  row rides with the page: one more copy beside it, out of the plane
+  padded to whole lanes (Mosaic takes no copy of an 8-wide row out of
+  `[num_blocks, KV]`) into SMEM, so what a launch holds on chip does not
+  grow with the table;
 - a table no multiple of P wide is padded by the wrapper; entries of −1
   are clamped, lie behind every live length and are masked if fetched.
+
+The mixed walk (`_mixed_kernel` under `paged_attention_packed`: ragged
+prefill chunks, decode rows and idle slots in ONE launch, on the packed
+token stream):
+
+- the launch runs over *work items* (sequence b, row tile starting at
+  chunk offset t0), reckoned inside the jitted step from `cu_seqlens_q` /
+  `seq_lens_this_time` (`_work_items`): a sequence with `this` tokens has
+  ceil(this / TQ) tiles, an idle slot none. Their count is static
+  (`mixed_items`: token_num // TQ + B at most), the table rides as two
+  more prefetched scalars, an unused item walks nothing. TQ
+  (`mixed_tiles`) comes from shapes and a VMEM budget alone;
+- a tile's rows `[KV, TQ * G, hd]` (GQA group g and chunk offset t fold
+  into one MXU axis, row r = t*G + g) are gathered from the stream into
+  `[items, KV, TQ * G, hd]` and the output is scattered back by the same
+  table: what is packed is the tick's tokens in tiles, not token_num rows
+  for every slot;
+- per item the decode walk's `fori_loop` over key blocks of P whole pages
+  (`mixed_pages_per_block`), up to the tile's OWN causal limit
+  ceil((past + t0 + live) / (P * block_size)): the first tile of a chunk
+  does not visit the keys of the last. The chunked-prefill metadata the
+  scheduler already produces masks `kv_pos <= past + t0 + t` per row —
+  in-chunk causality holds because the pages already contain this step's
+  tokens (the append happens before the read, same as the stock path);
+- q·k runs on the operands' own type (bf16 x bf16 products are exact in
+  the f32 accumulator; int8 pages convert exactly), p·v in f32; the same
+  online softmax, int8 scales and zero for rows without a query as the
+  decode walk;
+- an item with few live tokens (`_MIXED_SMALL_TOKENS`: a decode row or a
+  speculative verify run beside a chunk) computes on the tile's first
+  rows only — static slices of the same blocks under `pl.when` — so a
+  one-token sequence in a mixed tick costs about what it costs in a
+  decode tick.
+
+The BlockSpec walk (`_kernel`; rows `[B, KV, max_q * G, hd]` packed per
+sequence, max_q = 1 for a decode launch): grid `(B, KV, table width)`
+with the page axis innermost, one `[block_size, hd]` page of one KV head
+a step through an index map over the prefetched table, pages past a
+sequence's live length skipped (`pl.when`: no fetch, no product, but
+still a grid step each), the scale planes through (8, KV) SMEM blocks.
+Its blocks equal the array dims on the last two axes, so any geometry
+lowers; it costs a grid step a page and head, and token_num rows a slot.
 
 The append that comes before the read is `write_pages`, a third small
 kernel over the pages a batch touches, with the pools aliased input to
 output: beside these kernels an XLA scatter of rows makes the compiler
 hold the pool in another layout and convert all of it for every launch.
 
-Layout contract: q rows are packed/unpacked by the caller
-(block_multihead_attention_); caches stay in their pool layout — one
-layer's `[num_blocks, KV, block_size, hd]`, or the serving engine's whole
-stacked pool `[L, num_blocks, KV, block_size, hd]` with the layer as one
-more prefetched scalar, which the mixed walk's index maps and the decode
-walk's copies put in front of the page: `(layer, tables[b, p], …)`. No
+Layout contract: `paged_attention` takes q rows packed per sequence by
+the caller (block_multihead_attention_), `paged_attention_packed` the
+token stream as it is; caches stay in their pool layout — one layer's
+`[num_blocks, KV, block_size, hd]`, or the serving engine's whole stacked
+pool `[L, num_blocks, KV, block_size, hd]` with the layer as one more
+prefetched scalar, which the BlockSpec walk's index maps and the other
+walks' copies put in front of the page: `(layer, tables[b, p], …)`. No
 transpose, no reshape, no copy, and no slice of a layer out of the stack.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import numpy as np
@@ -93,7 +119,8 @@ from .flash_attention import (NEG_INF, _assert_mosaic_tileable, _i32,
                               available, count_launch)
 
 __all__ = ["paged_attention", "write_pages", "available", "supported",
-           "selected", "decode_walk"]
+           "selected", "whole_pages", "paged_attention_packed",
+           "mixed_work", "decode_pages_walked"]
 
 # m/l carriers use the same [rows, LANES] lane-broadcast trick as
 # flash_attention.py (a [rows, 1] scratch column is not a legal vreg shape
@@ -110,9 +137,9 @@ def supported(num_heads: int, num_kv_heads: int, head_dim: int,
     mode ignores it and is how CPU CI exercises the kernel bit-for-bit)."""
     if num_kv_heads <= 0 or num_heads % num_kv_heads != 0:
         return False
-    # the mixed walk's blocks equal the array dims on the last two axes,
+    # the BlockSpec walk's blocks equal the array dims on the last two axes,
     # so any (block_size, head_dim) is Mosaic-legal (the decode walk asks
-    # more: `decode_walk`); keep the same floor as the flash kernel so
+    # more: `whole_pages`); keep the same floor as the flash kernel so
     # degenerate head dims fall back loudly instead of wasting the MXU
     return head_dim >= 8 and block_size >= 1
 
@@ -128,17 +155,19 @@ def selected(num_heads: int, num_kv_heads: int, head_dim: int,
                                      block_size)
 
 
-def decode_walk(head_dim: int, interpret: Optional[bool] = None) -> bool:
-    """Can a launch of one query token a sequence (`rows == group`) take
-    the decode walk? It copies whole pages `[KV, block_size, hd]` out of
+def whole_pages(head_dim: int, interpret: Optional[bool] = None) -> bool:
+    """Can a launch take the walks that copy whole pages (the decode walk
+    at one query token a sequence, `rows == group`; the mixed walk of
+    `paged_attention_packed`)? They copy `[KV, block_size, hd]` out of
     the pool left in HBM, and Mosaic slices HBM in whole lanes: compiled
     for a v5e, every head_dim of 8 to 64 (page sizes 4 to 32, bf16 and
     int8 pages) is refused with "Slice shape along dimension 4 must be
-    aligned to tiling (128)", 128 and 256 are taken, and the mixed walk's
-    BlockSpec pages and `write_pages` are taken at all of them
-    (tests/test_chip_compile.py). Such a launch then takes the mixed walk
-    with max_q = 1. The interpreter takes any geometry, which is how CPU
-    CI runs the decode walk at small widths."""
+    aligned to tiling (128)", 128 and 256 are taken, and the BlockSpec
+    walk's pages and `write_pages` are taken at all of them
+    (tests/test_chip_compile.py). Elsewhere every launch takes the
+    BlockSpec walk (`_kernel`; a decode launch with max_q = 1). The
+    interpreter takes any geometry, which is how CPU CI runs the
+    whole-page walks at small widths."""
     if interpret is None:
         interpret = not available()
     return interpret or head_dim % _STAT_LANES == 0
@@ -223,10 +252,11 @@ def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
                        ).astype(o_ref.dtype)
 
 
-# the decode walk's key block: about one MXU tile of key positions a step,
-# inside a VMEM budget for the double-buffered K and V page scratch
+# the decode walk's key block: about one MXU tile of key positions a step;
+# either whole-page walk's, inside a VMEM budget for the double-buffered K
+# and V page scratch
 _DECODE_KEYS = 128
-_DECODE_VMEM_BYTES = 4 << 20
+_PAGE_SCRATCH_BYTES = 4 << 20
 
 
 def decode_pages_per_block(block_size: int, num_kv_heads: int, head_dim: int,
@@ -235,9 +265,15 @@ def decode_pages_per_block(block_size: int, num_kv_heads: int, head_dim: int,
     alone: as many whole pages `[KV, block_size, hd]` as make a key block
     of about `_DECODE_KEYS` positions, no more than the scratch budget
     holds twice over for K and for V, and no more than a table is wide."""
+    return _pages_per_block(_DECODE_KEYS, block_size, num_kv_heads, head_dim,
+                            itemsize, max_blocks)
+
+
+def _pages_per_block(keys: int, block_size: int, num_kv_heads: int,
+                     head_dim: int, itemsize: int, max_blocks: int) -> int:
     page_bytes = num_kv_heads * block_size * head_dim * itemsize
-    return max(1, min(_DECODE_KEYS // block_size,
-                      _DECODE_VMEM_BYTES // (4 * page_bytes), max_blocks))
+    return max(1, min(keys // block_size,
+                      _PAGE_SCRATCH_BYTES // (4 * page_bytes), max_blocks))
 
 
 def decode_pages_walked(ends, block_size: int, num_kv_heads: int,
@@ -255,6 +291,69 @@ def decode_pages_walked(ends, block_size: int, num_kv_heads: int,
     return int((-(-ends // block_size)).sum()), int(blocks.sum()) * pages
 
 
+def _stacked(key_cache, value_cache, layer):
+    """(key pool, value pool, layer [1] int32): the stacked pool
+    [L, nb, KV, bs, hd] with its layer, or one layer's caches as a stack of
+    one (a leading axis of 1 is a bitcast)."""
+    if key_cache.ndim != (4 if layer is None else 5):
+        raise ValueError(
+            f"cache {key_cache.shape}: pass one layer's [nb, KV, bs, hd], "
+            f"or the stacked [L, nb, KV, bs, hd] together with `layer`")
+    if layer is None:
+        key_cache, value_cache, layer = key_cache[None], value_cache[None], 0
+    return key_cache, value_cache, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _walk_refs(refs, has_quant: bool):
+    """Split a whole-page walk's refs: q, the pools left in HBM (k, v[,
+    k_scale, v_scale]), o, their scratch buffers in the same order, then
+    sems, acc, m, l."""
+    n = 4 if has_quant else 2
+    return (refs[0], refs[1:1 + n], refs[1 + n], refs[2 + n:2 + 2 * n],
+            *refs[2 + 2 * n:])
+
+
+def _page_copies(tables_ref, b, i, j, slot, pages: int, layer, pools, bufs,
+                 sems):
+    """The copies that bring page j (static or traced) of sequence b's key
+    block i into buffer `slot`, shared by the decode walk and the mixed
+    walk: one whole page `pool[layer, page]` = `[KV, block_size, hd]` of K
+    and one of V (a copy serves every KV head), and with int8 pages that
+    page's scale rows beside them. `pools` are the refs left in HBM (k,
+    v[, k_scale, v_scale [num_blocks, LANES]]), `bufs` their
+    double-buffered scratch ([2, pages, KV, bs, hd], scale rows [2, pages,
+    LANES] in SMEM), `sems` [2, 2]: K and its scales signal `sems[0,
+    slot]`, V and its scales `sems[1, slot]`."""
+    page = tables_ref[b, i * _i32(pages) + j]
+    return [pltpu.make_async_copy(
+        pool.at[layer, page] if n < 2 else pool.at[page], buf.at[slot, j],
+        sems.at[_i32(n % 2), slot])
+        for n, (pool, buf) in enumerate(zip(pools, bufs))]
+
+
+def _block_copies(tables_ref, b, i, slot, pages: int, layer, pools, bufs,
+                 sems):
+    """Every copy of key block i, page by page (`_page_copies`)."""
+    return [c for j in range(pages)
+            for c in _page_copies(tables_ref, b, i, _i32(j), slot, pages,
+                                  layer, pools, bufs, sems)]
+
+
+def _page_scales(scale_ref, slot, kv, pages: int, block_size: int):
+    """[1, pages * block_size] f32: for each key of the block in buffer
+    `slot`, its page's scale for head kv (static or traced; the rows
+    `_block_copies` brought into SMEM)."""
+    if isinstance(kv, int):
+        kv = _i32(kv)
+    span = pages * block_size
+    key_page = jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (1, span), 1), _i32(block_size))
+    vec = jnp.zeros((1, span), jnp.float32)
+    for j in range(pages):
+        vec = jnp.where(key_page == j, scale_ref[slot, _i32(j), kv], vec)
+    return vec
+
+
 def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
                    sm_scale: float, block_size: int, pages: int,
                    has_quant: bool):
@@ -268,11 +367,9 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
     that serves every KV head, an int8 page's scale row in one more beside
     it; block i+1's copies start before block i's products. The online
     softmax runs per head over the block's pages * bs keys."""
-    if has_quant:
-        (q_ref, k_hbm, v_hbm, kdq_hbm, vdq_hbm, o_ref, kbuf, vbuf, kdq_ref,
-         vdq_ref, sems, acc, m_sc, l_sc) = refs
-    else:
-        q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, acc, m_sc, l_sc = refs
+    q_ref, pools, o_ref, bufs, sems, acc, m_sc, l_sc = _walk_refs(
+        refs, has_quant)
+    kbuf, vbuf = bufs[:2]
     b = pl.program_id(0)
     layer = layer_ref[0]
     KV, G, hd = acc.shape
@@ -288,23 +385,8 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
                     _i32(width // pages)), _i32(0))
 
     def copies(i, slot):
-        out = []
-        for j in range(pages):
-            page = tables_ref[b, i * _i32(pages) + _i32(j)]
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[layer, page], kbuf.at[slot, _i32(j)],
-                sems.at[_i32(0), slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[layer, page], vbuf.at[slot, _i32(j)],
-                sems.at[_i32(1), slot]))
-            if has_quant:
-                out.append(pltpu.make_async_copy(
-                    kdq_hbm.at[page], kdq_ref.at[slot, _i32(j)],
-                    sems.at[_i32(0), slot]))
-                out.append(pltpu.make_async_copy(
-                    vdq_hbm.at[page], vdq_ref.at[slot, _i32(j)],
-                    sems.at[_i32(1), slot]))
-        return out
+        return _block_copies(tables_ref, b, i, slot, pages, layer, pools,
+                             bufs, sems)
 
     m_sc[...] = jnp.full_like(m_sc, NEG_INF)
     l_sc[...] = jnp.zeros_like(l_sc)
@@ -328,17 +410,6 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
         kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (G, span), 1)
                   + i * _i32(span))
         ok = kv_abs <= past                           # causal = live keys
-        if has_quant:
-            key_page = jax.lax.div(
-                jax.lax.broadcasted_iota(jnp.int32, (1, span), 1),
-                _i32(block_size))
-
-            def page_scales(scale_ref, kv):           # [1, span], per key
-                vec = jnp.zeros((1, span), jnp.float32)
-                for j in range(pages):
-                    vec = jnp.where(key_page == j,
-                                    scale_ref[slot, _i32(j), _i32(kv)], vec)
-                return vec
         for kv in range(KV):
             q = q_ref[0, kv].astype(jnp.float32)      # [G, hd]
             k = kbuf[slot, :, kv].astype(jnp.float32).reshape(span, hd)
@@ -348,7 +419,8 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
             if has_quant:
                 # a page's k scale is constant over hd: it factors out of
                 # the dot and lands on that page's scores
-                s = s * (sm_scale * page_scales(kdq_ref, kv))
+                s = s * (sm_scale * _page_scales(bufs[2], slot, kv, pages,
+                                                   block_size))
             else:
                 s = s * sm_scale
             s = jnp.where(ok, s, NEG_INF)
@@ -361,7 +433,8 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
                                                   keepdims=True)
             if has_quant:
                 # the v scale likewise: fold into the probabilities
-                prob = prob * page_scales(vdq_ref, kv)
+                prob = prob * _page_scales(bufs[3], slot, kv, pages,
+                                          block_size)
             v = vbuf[slot, :, kv].astype(jnp.float32).reshape(span, hd)
             acc[kv] = acc[kv] * alpha + jax.lax.dot_general(
                 prob, v, (((1,), (0,)), ((), ())),
@@ -374,6 +447,30 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
     o_ref[0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+def _walk_operands(key_cache, value_cache, tables, k_dequant, v_dequant,
+                   pages: int):
+    """What a whole-page walk is launched with beside its q rows: the
+    table padded to whole key blocks of `pages` (page 0, behind every live
+    length, fetched at most and masked), the pools it leaves in HBM, and
+    the scratch `_block_copies` fills (the double-buffered pages, an int8
+    pool's scale rows, the semaphores)."""
+    _, _, KV, bs, hd = key_cache.shape
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % pages)))
+    pools = [key_cache, value_cache]
+    scratch = [pltpu.VMEM((2, pages, KV, bs, hd), key_cache.dtype),
+               pltpu.VMEM((2, pages, KV, bs, hd), value_cache.dtype)]
+    if k_dequant is not None:
+        # a page's scale row rides with the page, copied into SMEM: Mosaic
+        # takes no copy of an 8- or 16-wide row out of the [num_blocks, KV]
+        # plane, so the planes are padded to whole lanes
+        pad = (0, 0), (0, -KV % _STAT_LANES)
+        pools += [jnp.pad(k_dequant.astype(jnp.float32), pad),
+                  jnp.pad(v_dequant.astype(jnp.float32), pad)]
+        scratch += [pltpu.SMEM((2, pages, pools[-1].shape[1]),
+                               jnp.float32)] * 2
+    return tables, pools, scratch + [pltpu.SemaphoreType.DMA((2, 2))]
+
+
 def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
                  sm_scale, k_dequant, v_dequant, interpret):
     """The decode launch (`rows == group`): grid over sequences, pools
@@ -384,20 +481,8 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
     max_blocks = tables.shape[1]
     pages = decode_pages_per_block(bs, KV, hd, key_cache.dtype.itemsize,
                                    max_blocks)
-    # whole key blocks only: a table that is no multiple wide is padded
-    # (page 0, behind every live length, fetched at most and masked)
-    tables = jnp.pad(tables, ((0, 0), (0, -max_blocks % pages)))
-    inputs = [q_rows, key_cache, value_cache]
-    scale_scratch = []
-    if has_quant:
-        # a page's scale row rides with the page, copied into SMEM: Mosaic
-        # takes no copy of an 8- or 16-wide row out of the [num_blocks, KV]
-        # plane, so the planes are padded to whole lanes
-        pad = (0, 0), (0, -KV % _STAT_LANES)
-        inputs += [jnp.pad(k_dequant.astype(jnp.float32), pad),
-                   jnp.pad(v_dequant.astype(jnp.float32), pad)]
-        scale_scratch = [pltpu.SMEM((2, pages, inputs[-1].shape[1]),
-                                    jnp.float32)] * 2
+    tables, pools, page_scratch = _walk_operands(
+        key_cache, value_cache, tables, k_dequant, v_dequant, pages)
 
     row_spec = pl.BlockSpec((1, KV, G, hd),
                             lambda b, *_: (b, _i32(0), _i32(0), _i32(0)),
@@ -407,13 +492,10 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
-        in_specs=[row_spec] + [hbm] * (len(inputs) - 1),
+        in_specs=[row_spec] + [hbm] * len(pools),
         out_specs=row_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, pages, KV, bs, hd), key_cache.dtype),
-            pltpu.VMEM((2, pages, KV, bs, hd), value_cache.dtype),
-            *scale_scratch,
-            pltpu.SemaphoreType.DMA((2, 2)),
+            *page_scratch,
             pltpu.VMEM((KV, G, hd), jnp.float32),
             pltpu.VMEM((KV, G, _STAT_LANES), jnp.float32),
             pltpu.VMEM((KV, G, _STAT_LANES), jnp.float32),
@@ -429,7 +511,367 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_rows.shape, q_rows.dtype),
         interpret=interpret,
-    )(tables, past, this, layer, *inputs)
+    )(tables, past, this, layer, q_rows, *pools)
+
+
+# the mixed walk's work item: the row tile of this many tokens of one
+# sequence (x group rows a KV head) against key blocks of about this many
+# positions; a sequence with at most this many tokens in a tick (a decode
+# row, a speculative verify) computes on a small row tile. Measured on a
+# v5e over the benchmark's mixes (PERF.md, PR 30): tiles of 64 tokens beat
+# 32 and 128 everywhere, blocks of 512 keys beat 256 by 10-17 % on chunks
+# and lose 4 % on a tick of one-row items
+_MIXED_TOKENS = 64
+_MIXED_KEYS = 512
+_MIXED_SMALL_TOKENS = 8
+# acc, m, l and the double-buffered q and o blocks of an item stay inside
+# this; the launch states its own scoped-VMEM limit (a v5e core has 128 MiB)
+_MIXED_VMEM_BYTES = 40 << 20
+_MIXED_VMEM_LIMIT = 96 << 20
+
+
+def mixed_pages_per_block(block_size: int, num_kv_heads: int, head_dim: int,
+                          itemsize: int, max_blocks: int) -> int:
+    """Pages the mixed walk fetches and works on at a time: the decode
+    walk's rule at the mixed walk's key block."""
+    return _pages_per_block(_MIXED_KEYS, block_size, num_kv_heads, head_dim,
+                            itemsize, max_blocks)
+
+
+def mixed_tiles(token_num: int, group: int, num_kv_heads: int,
+                head_dim: int):
+    """(TQ, TS) of a mixed launch, from shapes alone. TQ: the tokens of a
+    work item's row tile, `_MIXED_TOKENS`, no more than the launch packs
+    and than `_MIXED_VMEM_BYTES` holds (per token and head: acc, m, l in
+    f32 and four q / o block rows), in whole sublane tiles of rows (16, the
+    bf16 tile). TS: the tokens of the small row tile an item with few live
+    tokens computes on (TS == TQ: there is none)."""
+    step = 16 // math.gcd(group, 16)
+    per_token = group * num_kv_heads * 4 * (5 * head_dim + 2 * _STAT_LANES)
+    cap = min(_MIXED_TOKENS, _MIXED_VMEM_BYTES // per_token,
+              -(-token_num // step) * step)
+    tq = max(step, cap // step * step)
+    ts = min(tq, -(-_MIXED_SMALL_TOKENS // step) * step)
+    return tq, ts
+
+
+def mixed_items(token_num: int, batch: int, tq: int) -> int:
+    """The static count of work items of a mixed launch: a sequence with
+    `this` tokens has ceil(this / TQ), so no tick has more than
+    token_num // TQ + B, nor more than it has tokens."""
+    return max(1, min(token_num, token_num // tq + batch))
+
+
+def mixed_work(past, this, token_num: int, block_size: int,
+               num_kv_heads: int, group: int, head_dim: int, itemsize: int,
+               max_blocks: int):
+    """What one mixed launch walks, reckoned on the host from the
+    scheduler's own lengths (`past`, `this` [B], idle slots 0): the trip
+    counts of `_mixed_kernel`, as `decode_pages_walked` mirrors
+    `_decode_kernel`. Returns a dict: `attn_q_tiles` work items with a
+    query row; `attn_rows_live` query tokens and `attn_rows_packed` the
+    token rows of the tiles they are computed on (TS or TQ an item: their
+    ratio is the tile occupancy); `attn_pages_live` pages that hold a key
+    some query may see; `attn_pages_fetched` key blocks walked x P, every
+    item's own (a chunk's later tiles walk its earlier keys again)."""
+    tq, ts = mixed_tiles(token_num, group, num_kv_heads, head_dim)
+    pages = mixed_pages_per_block(block_size, num_kv_heads, head_dim,
+                                  itemsize, max_blocks)
+    past = np.asarray(past, np.int64)
+    this = np.asarray(this, np.int64)
+    past, this = past[this > 0], this[this > 0]
+    tiles = -(-this // tq)
+    seq = np.repeat(np.arange(len(this)), tiles)
+    t0 = (np.arange(int(tiles.sum())) - np.repeat(np.cumsum(tiles) - tiles,
+                                                  tiles)) * tq
+    live = np.minimum(this[seq] - t0, tq)
+    blocks = np.minimum(-(-(past[seq] + t0 + live) // (pages * block_size)),
+                        -(-max_blocks // pages))
+    return {"attn_q_tiles": int(tiles.sum()),
+            "attn_rows_live": int(this.sum()),
+            "attn_rows_packed": int(np.where(live <= ts, ts, tq).sum()),
+            "attn_pages_live": int((-(-(past + this) // block_size)).sum()),
+            "attn_pages_fetched": int(blocks.sum()) * pages}
+
+
+def _work_items(cu, this, tq: int, items: int, token_num: int):
+    """The mixed launch's work items, inside the jitted step: (seq [items],
+    t0 [items], first [B]). Item j is the row tile of sequence seq[j] that
+    starts at chunk offset t0[j]; a sequence's ceil(this / tq) tiles are
+    consecutive from first[b]; an item past the last one sits behind every
+    chunk (t0 = token_num), so it has no rows and walks nothing."""
+    B = this.shape[0]
+    tiles = -(-this // tq)                                      # [B]
+    ends = jnp.cumsum(tiles)
+    first = ends - tiles
+    j = jnp.arange(items, dtype=jnp.int32)
+    # (compare_all: a few dozen comparisons that fuse, where the default
+    # binary search is a loop of small gathers in every layer)
+    seq = jnp.clip(jnp.searchsorted(ends, j, side="right",
+                                    method="compare_all"), 0,
+                   B - 1).astype(jnp.int32)
+    t0 = jnp.where(j < ends[-1], (j - first[seq]) * tq, token_num)
+    return seq, t0.astype(jnp.int32), first.astype(jnp.int32)
+
+
+def _loop_i32(n: int, body) -> None:
+    """`body(j)` for j = 0 .. n - 1 on an int32 counter. (`fori_loop` with
+    static bounds becomes a scan whose counter is int64 under
+    jax_enable_x64, and Mosaic lowers no arithmetic on one.)"""
+    jax.lax.while_loop(lambda j: j < _i32(n),
+                       lambda j: (body(j), j + _i32(1))[1], _i32(0))
+
+
+def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
+                  *refs, sm_scale: float, block_size: int, pages: int,
+                  group: int, small: int, has_quant: bool):
+    """One work item j of a mixed launch: the query rows of sequence
+    seq[j] from chunk offset t0[j] on (row r = t * G + g of the tile, its
+    query at position past + t0 + t), against that sequence's key blocks
+    of `pages` whole pages up to the tile's own causal limit.
+
+    refs: q [1, KV, R, hd], the pools (left in HBM), o, then scratch as
+    the decode walk's: kbuf, vbuf [2, pages, KV, bs, hd], [scale rows,]
+    sems, acc [KV, R, hd], m, l [KV, R, LANES]. The pages come as the
+    decode walk's do (`_page_copies`); q.k runs on the operands' own
+    type with f32 accumulation, p.v in f32. An item with at most `small`
+    live tokens (a decode row or a verify run beside a chunk) computes on
+    the tile's first small * G rows only."""
+    q_ref, pools, o_ref, bufs, sems, acc, m_sc, l_sc = _walk_refs(
+        refs, has_quant)
+    kbuf, vbuf = bufs[:2]
+    j = pl.program_id(0)
+    b = seq_ref[j]
+    t0 = t0_ref[j]
+    layer = layer_ref[0]
+    KV, R, hd = acc.shape
+    bs = block_size
+    span = pages * bs
+    width = tables_ref.shape[1]
+    past = past_ref[b]
+    # tokens of this tile that hold a query (none: an unused item)
+    live = jnp.clip(this_ref[b] - t0, _i32(0), _i32(R // group))
+    # keys 0 .. past + t0 + live - 1 can be seen from the tile, the first
+    # tile of a chunk does not visit the keys of the last
+    n_blocks = jnp.where(
+        live > 0,
+        jnp.minimum(jax.lax.div(past + t0 + live + _i32(span - 1),
+                                _i32(span)), _i32(width // pages)), _i32(0))
+    # the products' operand type: q's and the pages' own (int8 pages
+    # convert exactly), never wider than what either holds
+    ct = (q_ref.dtype if kbuf.dtype == jnp.int8
+          else jnp.promote_types(q_ref.dtype, kbuf.dtype))
+    # 16-bit products are exact in the f32 accumulator at any precision, and
+    # Mosaic takes no other for them: a caller's default_matmul_precision
+    # ("highest" around a reference check) is left to the f32 dots
+    qk_precision = (jax.lax.Precision.DEFAULT if jnp.dtype(ct).itemsize < 4
+                    else None)
+
+    def fetch(i, slot, wait=False):
+        # a loop over the block's pages, not P copies of the descriptors:
+        # the kernel is traced anew in every process that builds a tick,
+        # before it can ask the compile cache
+        def page(j):
+            for c in _page_copies(tables_ref, b, i, j, slot, pages, layer,
+                                  pools, bufs, sems):
+                if wait:
+                    c.wait()
+                else:
+                    c.start()
+        _loop_i32(pages, page)
+
+    def head_block(buf, slot, kv, dtype):             # [span, hd] of a head
+        x = buf[slot, :, kv]                          # [pages, bs, hd]
+        if x.dtype != dtype or bs % (32 // x.dtype.itemsize) != 0:
+            # whole f32 sublane tiles merge without a relayout
+            x = x.astype(jnp.float32)
+        return x.reshape(span, hd).astype(dtype)
+
+    def walk(rows):
+        """The item's walk on its first `rows` rows (static)."""
+        t = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0),
+                        _i32(group))
+        # a row's query position; rows without a query (pad, t >= this)
+        # sit before every key
+        pos = jnp.where(t < live, past + t0 + t, _i32(-1))    # [rows, 1]
+        m_sc[:, :rows] = jnp.full((KV, rows, _STAT_LANES), NEG_INF,
+                                  jnp.float32)
+        l_sc[:, :rows] = jnp.zeros((KV, rows, _STAT_LANES), jnp.float32)
+        acc[:, :rows] = jnp.zeros((KV, rows, hd), jnp.float32)
+
+        pl.when(n_blocks > 0)(lambda: fetch(_i32(0), _i32(0)))
+
+        def block(i, _):
+            slot = jax.lax.rem(i, _i32(2))
+            pl.when(i + _i32(1) < n_blocks)(
+                lambda: fetch(i + _i32(1), _i32(1) - slot))
+            fetch(i, slot, wait=True)
+            kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+                      + i * _i32(span))
+            ok = kv_abs <= pos                        # [rows, span]
+
+            def head(kv):
+                s = jax.lax.dot_general(
+                    q_ref[0, kv, :rows].astype(ct),
+                    head_block(kbuf, slot, kv, ct),
+                    (((1,), (1,)), ((), ())), precision=qk_precision,
+                    preferred_element_type=jnp.float32)       # [rows, span]
+                if has_quant:
+                    # a page's k scale is constant over hd: it factors out
+                    # of the dot and lands on that page's scores
+                    s = s * (sm_scale * _page_scales(bufs[2], slot, kv,
+                                                     pages, bs))
+                else:
+                    s = s * sm_scale
+                s = jnp.where(ok, s, NEG_INF)
+                m_prev = m_sc[kv, :rows, :1]                  # [rows, 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                # a masked key of a row with a live one gives exp(-1e30 -
+                # m) = 0 exactly; a row with none yet (only rows without
+                # a query: key 0 is in every first block) is zeroed below
+                prob = jnp.exp(s - m_new)                     # [rows, span]
+                alpha = jnp.exp(m_prev - m_new)               # [rows, 1]
+                l_sc[kv, :rows] = (l_sc[kv, :rows] * alpha
+                                   + jnp.sum(prob, axis=-1, keepdims=True))
+                if has_quant:
+                    # the v scale likewise: fold into the probabilities
+                    prob = prob * _page_scales(bufs[3], slot, kv, pages, bs)
+                acc[kv, :rows] = acc[kv, :rows] * alpha + jax.lax.dot_general(
+                    prob, head_block(vbuf, slot, kv, jnp.float32),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_sc[kv, :rows] = jnp.broadcast_to(m_new,
+                                                   (rows, _STAT_LANES))
+
+            # a loop over the heads, not KV copies of the body: unrolled,
+            # the launch is 4 % faster at 8 heads and 20 % at 16 heads of
+            # one-row items (0.1-0.2 % of a cell's rate), and every process
+            # that builds a tick spends 0.3 s more tracing it (PERF.md,
+            # PR 30)
+            _loop_i32(KV, head)
+
+        jax.lax.fori_loop(_i32(0), n_blocks, block, None)
+        l = l_sc[:, :rows, :1]
+        out = acc[:, :rows] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, :, :rows] = jnp.where(pos >= 0, out, 0.0).astype(o_ref.dtype)
+        if rows < R:
+            o_ref[0, :, rows:] = jnp.zeros((KV, R - rows, hd), o_ref.dtype)
+
+    if 0 < small * group < R:
+        pl.when(live <= small)(lambda: walk(small * group))
+        pl.when(live > small)(lambda: walk(R))
+    else:
+        walk(R)
+
+
+def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
+                seq, t0, group, small, sm_scale, k_dequant, v_dequant,
+                interpret):
+    """The mixed launch: grid over work items, pools left in HBM, whole
+    pages gathered by the kernel."""
+    items, KV, R, hd = q_items.shape
+    _, _, _, bs, _ = key_cache.shape
+    has_quant = k_dequant is not None
+    pages = mixed_pages_per_block(bs, KV, hd, key_cache.dtype.itemsize,
+                                  tables.shape[1])
+    tables, pools, page_scratch = _walk_operands(
+        key_cache, value_cache, tables, k_dequant, v_dequant, pages)
+    row_spec = pl.BlockSpec((1, KV, R, hd),
+                            lambda j, *_: (j, _i32(0), _i32(0), _i32(0)),
+                            memory_space=pltpu.VMEM)
+    _assert_mosaic_tileable(row_spec.block_shape, q_items.shape, "mixed rows")
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(items,),
+        in_specs=[row_spec] + [hbm] * len(pools),
+        out_specs=row_spec,
+        scratch_shapes=[
+            *page_scratch,
+            pltpu.VMEM((KV, R, hd), jnp.float32),
+            pltpu.VMEM((KV, R, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((KV, R, _STAT_LANES), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _mixed_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
+        pages=int(pages), group=int(group), small=int(small),
+        has_quant=has_quant)
+    count_launch()
+    return pl.pallas_call(
+        kernel,
+        name="paged_attention_mixed",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q_items.shape, q_items.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_MIXED_VMEM_LIMIT),
+        interpret=interpret,
+    )(tables, past, this, layer, seq, t0, q_items, *pools)
+
+
+def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
+                           seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
+                           sm_scale: float, k_dequant=None, v_dequant=None,
+                           interpret: Optional[bool] = None, layer=None):
+    """Attention of a ragged mixed batch (prefill chunks, decode rows and
+    idle slots in one launch) over paged caches, on the packed token
+    stream itself.
+
+    q_tok [token_num, KV, G, hd]: sequence b's `seq_lens_this_time[b]`
+    tokens lie at rows cu_seqlens_q[b] … of the stream, its token t at
+    position `seq_lens_decoder[b] + t`; the caches, tables, scales and
+    `layer` are `paged_attention`'s. Returns [token_num, KV, G, hd] in
+    q_tok.dtype, rows that are no sequence's token 0.
+
+    Where whole pages can be copied (`whole_pages`) this is the mixed
+    walk: the launch runs over work items reckoned here from the lengths
+    (`_work_items`), gathers their rows into [items, KV, TQ * G, hd] and
+    scatters the output back by the same table. Elsewhere the rows are
+    packed per sequence, [B, KV, token_num * G, hd], for the BlockSpec
+    walk of `paged_attention`."""
+    if (k_dequant is None) != (v_dequant is None):
+        raise ValueError("pass both k_dequant and v_dequant or neither")
+    token_num, KV, G, hd = q_tok.shape
+    B = block_tables.shape[0]
+    if interpret is None:
+        interpret = not available()
+    cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
+    past = seq_lens_decoder.reshape(-1).astype(jnp.int32)
+    this = seq_lens_this_time.reshape(-1).astype(jnp.int32)
+    tok_idx = jnp.arange(token_num, dtype=jnp.int32)
+    tok_b = jnp.clip(jnp.searchsorted(cu, tok_idx, side="right",
+                                      method="compare_all") - 1, 0, B - 1)
+    tok_local = tok_idx - cu[tok_b]
+    tok_valid = (tok_local < this[tok_b])[:, None, None, None]
+
+    if not whole_pages(hd, interpret):
+        row_tok = jnp.clip(cu[:B, None] + tok_idx[None, :], 0, token_num - 1)
+        q_pack = q_tok[row_tok].transpose(0, 2, 1, 3, 4)  # [B, KV, tok, G, hd]
+        o_pack = paged_attention(
+            q_pack.reshape(B, KV, token_num * G, hd), key_cache, value_cache,
+            block_tables, past, this, G, sm_scale, k_dequant=k_dequant,
+            v_dequant=v_dequant, interpret=interpret, layer=layer)
+        o_pack = o_pack.reshape(B, KV, token_num, G, hd)
+        return jnp.where(tok_valid, o_pack[tok_b, :, tok_local], 0
+                         ).astype(q_tok.dtype)
+
+    key_cache, value_cache, layer = _stacked(key_cache, value_cache, layer)
+    tq, ts = mixed_tiles(token_num, G, KV, hd)
+    items = mixed_items(token_num, B, tq)
+    seq, t0, first = _work_items(cu, this, tq, items, token_num)
+    row_tok = jnp.clip((cu[seq] + t0)[:, None]
+                       + jnp.arange(tq, dtype=jnp.int32)[None, :],
+                       0, token_num - 1)                  # [items, tq]
+    q_items = q_tok[row_tok].transpose(0, 2, 1, 3, 4)     # [items, KV, tq, G, hd]
+    o_items = _mixed_call(
+        q_items.reshape(items, KV, tq * G, hd), key_cache, value_cache,
+        jnp.maximum(block_tables.astype(jnp.int32), 0), past, this, layer,
+        seq, t0, G, ts, sm_scale, k_dequant, v_dequant, interpret)
+    o_items = o_items.reshape(items, KV, tq, G, hd)
+    item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
+    return jnp.where(tok_valid, o_items[item, :, tok_local % tq], 0
+                     ).astype(q_tok.dtype)
 
 
 def paged_attention(q_rows, key_cache, value_cache, block_tables,
@@ -454,8 +896,8 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     q_rows.dtype; pad rows come back 0.
 
     Rows equal to `group` (max_q = 1: the CALLER guarantees every
-    seq_lens_this_time <= 1) take the decode walk where `decode_walk`
-    says Mosaic lowers it, any other launch the mixed walk.
+    seq_lens_this_time <= 1) take the decode walk where `whole_pages`
+    says Mosaic lowers it, any other launch the BlockSpec walk (`_kernel`).
     """
     if (k_dequant is None) != (v_dequant is None):
         raise ValueError("pass both k_dequant and v_dequant or neither")
@@ -464,13 +906,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     if rows <= 0 or group <= 0 or rows % group != 0:
         raise ValueError(f"q_rows rows={rows} must be a positive multiple "
                          f"of group={group}")
-    if key_cache.ndim != (4 if layer is None else 5):
-        raise ValueError(
-            f"cache {key_cache.shape}: pass one layer's [nb, KV, bs, hd], "
-            f"or the stacked [L, nb, KV, bs, hd] together with `layer`")
-    if layer is None:
-        # one layer is a stack of one: a leading axis of 1 is a bitcast
-        key_cache, value_cache, layer = key_cache[None], value_cache[None], 0
+    key_cache, value_cache, layer = _stacked(key_cache, value_cache, layer)
     _, num_blocks, KVc, bs, hdc = key_cache.shape
     if (KVc, hdc) != (KV, hd):
         raise ValueError(f"cache [nb, KV, bs, hd]={key_cache.shape[1:]} does "
@@ -482,9 +918,8 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     tables = jnp.maximum(block_tables.astype(jnp.int32), 0)   # [B, mb]
     past = seq_lens_decoder.reshape(-1).astype(jnp.int32)     # [B]
     this = seq_lens_this_time.reshape(-1).astype(jnp.int32)   # [B]
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
-    if rows == group and decode_walk(hd, interpret):
+    if rows == group and whole_pages(hd, interpret):
         return _decode_call(q_rows, key_cache, value_cache, tables, past,
                             this, layer, sm_scale, k_dequant, v_dequant,
                             interpret)

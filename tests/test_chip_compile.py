@@ -154,14 +154,16 @@ def test_paged_attention_compiles(one_chip, max_q, int8_pages):
     # int8 at an 8192-position table: the scale rows come with the pages,
     # so what the launch holds in SMEM is the table alone
     (8, 4, 9216, 512, 4, True),
-    # and the mixed launch beside them (token_budget rows a head)
+    # and the BlockSpec walk at those pools (token_budget rows a head: what
+    # a mixed launch ran until the mixed walk, below, took head_dim 128)
     (8, 4, 2304, 128, 2048, False), (16, 1, 768, 32, 512, False),
 ])
 def test_paged_attention_on_the_serve_pools_compiles(
         one_chip, kv, group, num_blocks, max_blocks, rows, int8):
     """The kernel alone on the stacked pool with a traced layer: a launch
     whose rows are the GQA group takes the decode walk (whole pages from
-    the pool left in HBM, several a key block), any other the mixed walk."""
+    the pool left in HBM, several a key block), any other the BlockSpec
+    walk."""
     layers, batch, block_size = 16, 16, 16
     page_dtype = jnp.int8 if int8 else jnp.bfloat16
     pool = ((layers, num_blocks, kv, block_size, HEAD_DIM), page_dtype)
@@ -182,6 +184,66 @@ def test_paged_attention_on_the_serve_pools_compiles(
                                      1 if int8 else 2, max_blocks) == 8
 
 
+@pytest.mark.parametrize(
+    "kv, group, num_blocks, max_blocks, small, pages, int8", [
+        # the two serve configurations' mixed ticks: Mistral-7B (8 KV heads,
+        # group 4, table 128) and OLMoE-1B-7B (16 KV heads, group 1, table
+        # 32), token_budget 512, bf16 and int8 pages
+        (8, 4, 2304, 128, 8, 32, False), (16, 1, 768, 32, 16, 16, False),
+        (8, 4, 2304, 128, 8, 32, True), (16, 1, 768, 32, 16, 32, True),
+    ])
+def test_mixed_walk_on_the_serve_pools_compiles(
+        one_chip, kv, group, num_blocks, max_blocks, small, pages, int8):
+    """The mixed walk on the packed stream, inside the stacked pool with a
+    traced layer: work items of 64 tokens (x group rows a head) and a
+    small tile, key blocks of up to 32 whole pages (512 keys, inside the
+    page scratch's budget) from the pool left in HBM."""
+    layers, batch, block_size, tokens = 16, 16, 16, 512
+    page_dtype = jnp.int8 if int8 else jnp.bfloat16
+    pool = ((layers, num_blocks, kv, block_size, HEAD_DIM), page_dtype)
+    shapes = [_bf16(tokens, kv, group, HEAD_DIM), pool, pool,
+              ((batch, max_blocks), jnp.int32), ((batch,), jnp.int32),
+              ((batch,), jnp.int32), ((batch + 1,), jnp.int32),
+              ((), jnp.int32)]
+    if int8:
+        shapes += [((num_blocks, kv), jnp.float32)] * 2
+
+    def fn(q, k, v, tables, past, this, cu, layer, *dequant):
+        return pa.paged_attention_packed(q, k, v, tables, past, this, cu,
+                                         HEAD_DIM ** -0.5, *dequant,
+                                         interpret=False, layer=layer)
+
+    assert pa.whole_pages(HEAD_DIM, interpret=False)
+    text = _compile(fn, one_chip, *shapes).as_text()
+    assert "paged_attention_mixed" in text
+    assert pa.mixed_tiles(tokens, group, kv, HEAD_DIM) == (64, small)
+    assert pa.mixed_items(tokens, batch, 64) == 8 + batch
+    assert pa.mixed_pages_per_block(block_size, kv, HEAD_DIM,
+                                    1 if int8 else 2, max_blocks) == pages
+
+
+def test_mixed_walk_compiles_under_highest_matmul_precision(one_chip):
+    """A reference check runs the engine under
+    `jax.default_matmul_precision("highest")` (benchmark/drivers/
+    closed_loop_sessions.py), which retraces the tick: Mosaic takes no
+    fp32 contract precision on bf16 operands ("Bad lhs type"), so the
+    q.k dot names its own."""
+    kv, group, num_blocks, max_blocks = 8, 4, 2304, 128
+    layers, batch, block_size, tokens = 16, 16, 16, 512
+    pool = ((layers, num_blocks, kv, block_size, HEAD_DIM), jnp.bfloat16)
+
+    def fn(q, k, v, tables, past, this, cu, layer):
+        return pa.paged_attention_packed(q, k, v, tables, past, this, cu,
+                                         HEAD_DIM ** -0.5, interpret=False,
+                                         layer=layer)
+
+    with jax.default_matmul_precision("highest"):
+        _compile(fn, one_chip, _bf16(tokens, kv, group, HEAD_DIM), pool, pool,
+                 ((batch, max_blocks), jnp.int32), ((batch,), jnp.int32),
+                 ((batch,), jnp.int32), ((batch + 1,), jnp.int32),
+                 ((), jnp.int32))
+
+
 @pytest.mark.parametrize("head_dim", [16, 64])
 @pytest.mark.parametrize("launch", ["decode", "mixed"])
 @pytest.mark.parametrize("int8", [False, True])
@@ -189,11 +251,11 @@ def test_paged_attention_off_whole_lanes_compiles(one_chip, head_dim, launch,
                                                   int8):
     """Head dims that are no multiple of 128 (`llama-test`'s 16, any
     64-wide model): both launches compile, and the launch of one token a
-    sequence takes the mixed walk with max_q = 1 (`pa.decode_walk`)."""
+    sequence takes the BlockSpec walk with max_q = 1 (`pa.whole_pages`)."""
     layers, num_blocks, kv, group, block_size = 2, 64, 2, 2, 8
     batch, max_blocks = 4, 8
     assert pa.supported(kv * group, kv, head_dim, block_size)
-    assert not pa.decode_walk(head_dim, interpret=False)
+    assert not pa.whole_pages(head_dim, interpret=False)
     rows = group if launch == "decode" else 16 * group
     page_dtype = jnp.int8 if int8 else jnp.bfloat16
     pool = ((layers, num_blocks, kv, block_size, head_dim), page_dtype)
@@ -214,7 +276,7 @@ def test_paged_attention_off_whole_lanes_compiles(one_chip, head_dim, launch,
 
 
 def test_decode_walk_is_refused_off_whole_lanes(one_chip):
-    """Guards `pa.decode_walk`: the decode walk forced at head_dim 64 is
+    """Guards `pa.whole_pages`: the decode walk forced at head_dim 64 is
     what Mosaic refuses. When this stops failing the gate can go."""
     layers, num_blocks, kv, group, block_size, head_dim = 2, 64, 2, 2, 8, 64
     pool = ((layers, num_blocks, kv, block_size, head_dim), jnp.bfloat16)
@@ -230,13 +292,13 @@ def test_decode_walk_is_refused_off_whole_lanes(one_chip):
                  ((), jnp.int32))
 
 
-@pytest.mark.parametrize("hidden, decode_walk", [
+@pytest.mark.parametrize("hidden, whole_pages", [
     (64, False),      # llama-test as it stands: 4 heads of 16
     (256, False),     # 4 heads of 64
     (512, True),      # 4 heads of 128: the decode tick takes the decode walk
 ])
 def test_default_engine_ticks_compile(one_chip, monkeypatch, hidden,
-                                      decode_walk):
+                                      whole_pages):
     """`PagedServingEngine(cfg, params)`, told nothing, as a TPU builds it
     (`available` steered true, the program has no option for it): it takes
     the kernel, and its mixed and its decode executable, as the engine
@@ -274,8 +336,11 @@ def test_default_engine_ticks_compile(one_chip, monkeypatch, hidden,
     for text in texts.values():
         assert "paged_cache_write" in text and "paged_attention" in text
     assert "paged_attention_decode" not in texts[False]
-    assert ("paged_attention_decode" in texts[True]) == decode_walk
-    assert (eng.stats["attn_pages_fetched"] > 0) == decode_walk
+    # whole lanes: the whole-page walks, and their counters; else neither
+    assert ("paged_attention_mixed" in texts[False]) == whole_pages
+    assert ("paged_attention_decode" in texts[True]) == whole_pages
+    assert (eng.stats["attn_pages_fetched"] > 0) == whole_pages
+    assert (eng.stats["attn_q_tiles"] > 0) == whole_pages
 
 
 @pytest.mark.parametrize("rows", [16, 512])        # decode / mixed tick
@@ -317,7 +382,9 @@ def test_paged_layer_in_stacked_pool_compiles(one_chip, rows, int8_pages):
         ).as_text()
     finally:
         pa.available = available
-    assert "paged_cache_write" in text and "paged_attention" in text
+    assert "paged_cache_write" in text
+    assert ("paged_attention_decode" if rows == batch
+            else "paged_attention_mixed") in text
     shape = re.escape("[%d,%d,%d,%d,%d]" % (layers, num_blocks, kv,
                                             block_size, HEAD_DIM))
     made = re.findall(r"%(\S+) = [^ ]*" + shape + r"\S* (\w[\w-]*)\(", text)

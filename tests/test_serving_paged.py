@@ -519,7 +519,7 @@ class TestServingObservability:
 # ---------------------------------------------------------------------------
 
 def _mha_args(past, this, KV=2, G=2, hd=8, bs=8, mb=4, nb=24, quant=False,
-              seed=0, shared_first_page=False):
+              seed=0, shared_first_page=False, dtype=np.float32):
     """Build block_multihead_attention_ inputs for a ragged batch. With
     shared_first_page, every sequence's table entry 0 points at the SAME
     physical page (the COW/prefix-cache layout after a shared-prefix
@@ -558,7 +558,9 @@ def _mha_args(past, this, KV=2, G=2, hd=8, bs=8, mb=4, nb=24, quant=False,
         kc = rs.randn(nb, KV, bs, hd).astype(np.float32)
         vc = rs.randn(nb, KV, bs, hd).astype(np.float32)
         scales = {}
-    return dict(qkv=jnp.asarray(qkv), key_cache=jnp.asarray(kc),
+    if not quant:
+        kc, vc = jnp.asarray(kc, dtype), jnp.asarray(vc, dtype)
+    return dict(qkv=jnp.asarray(qkv, dtype), key_cache=jnp.asarray(kc),
                 value_cache=jnp.asarray(vc),
                 seq_lens_encoder=jnp.zeros(B, jnp.int32),
                 seq_lens_decoder=jnp.asarray(past, np.int32),
@@ -631,6 +633,56 @@ _DECODE_WALK = [
     dict(id="g1-bs16-int8-partial-last-page", G=1, bs=16, P=2, mb=4,
          quant=True, lengths=[11, 64, 0, 45]),
 ]
+
+
+# the mixed walk's cases: G, page size, the row tile TQ and the small tile
+# TS in tokens, pages a key block (P); `this` walks 0 / 1 / TQ - 1 / TQ /
+# TQ + 1 and a chunk of several tiles, `past` 0, mid-page and page boundaries
+_MIXED_WALK = [
+    dict(id="g4-tq8-ts4-p2-odd-table", G=4, bs=4, TQ=8, TS=4, P=2, mb=7,
+         past=[3, 5, 8, 0, 16, 7], this=[0, 1, 7, 8, 9, 19]),
+    dict(id="g4-tq8-whole-tiles-only", G=4, bs=4, TQ=8, TS=8, P=2, mb=8,
+         past=[0, 11, 4, 9, 0], this=[1, 1, 3, 0, 17]),
+    dict(id="g1-tq16-p2", G=1, bs=4, TQ=16, TS=16, P=2, mb=13,
+         past=[0, 1, 16, 5, 9, 0], this=[0, 1, 15, 16, 17, 33]),
+    dict(id="g1-tq32-ts16-p4", G=1, bs=4, TQ=32, TS=16, P=4, mb=17,
+         past=[7, 0, 16, 0, 32, 2], this=[1, 16, 17, 31, 33, 0]),
+    dict(id="g4-bs16-tq8-ts4-p1", G=4, bs=16, TQ=8, TS=4, P=1, mb=3,
+         past=[0, 16, 30, 5], this=[9, 1, 12, 0]),
+    dict(id="g4-reckoned-from-shapes", G=4, bs=4, mb=9,
+         past=[2, 0, 8, 13], this=[1, 20, 0, 5]),
+    dict(id="g1-reckoned-from-shapes", G=1, bs=16, mb=4,
+         past=[0, 16, 40], this=[23, 1, 9]),
+    dict(id="g4-tq8-cow-first-page", G=4, bs=4, TQ=8, TS=4, P=2, mb=5,
+         cow=True, past=[4, 8, 4], this=[9, 1, 4]),
+    dict(id="g4-tq8-int8", G=4, bs=16, TQ=8, TS=4, P=2, mb=5, quant=True,
+         past=[0, 16, 30, 5, 41], this=[9, 1, 12, 0, 17]),
+    dict(id="g1-tq16-int8", G=1, bs=16, TQ=16, TS=16, P=2, mb=4, quant=True,
+         past=[11, 0, 32], this=[1, 33, 17]),
+    dict(id="g4-tq8-bf16", G=4, bs=16, TQ=8, TS=4, P=2, mb=5,
+         dtype=jnp.bfloat16, past=[0, 16, 30, 5, 41],
+         this=[9, 1, 12, 0, 17]),
+    dict(id="g1-tq16-bf16", G=1, bs=16, TQ=16, TS=16, P=2, mb=4,
+         dtype=jnp.bfloat16, past=[11, 0, 32], this=[1, 33, 17]),
+]
+
+
+def _mixed_calls(monkeypatch):
+    """A list that grows by one for every launch of the mixed walk."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    calls, real = [], PA._mixed_call
+    monkeypatch.setattr(
+        PA, "_mixed_call", lambda *a, **k: calls.append(1) or real(*a, **k))
+    return calls
+
+
+def _steer_mixed_walk(monkeypatch, case):
+    """Steer the tiles the shapes would give; the program has no knob."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+    if "TQ" in case:
+        monkeypatch.setattr(PA, "_MIXED_TOKENS", case["TQ"])
+        monkeypatch.setattr(PA, "_MIXED_SMALL_TOKENS", case["TS"])
+        monkeypatch.setattr(PA, "_MIXED_KEYS", case["P"] * case["bs"])
 
 
 class TestPallasPagedAttention:
@@ -726,6 +778,123 @@ class TestPallasPagedAttention:
         np.testing.assert_allclose(np.asarray(pal[0]), np.asarray(stock[0]),
                                    atol=5e-5, rtol=1e-5)
         assert np.asarray(pal[2]).dtype == np.int8
+
+    @pytest.mark.parametrize("case", _MIXED_WALK, ids=lambda c: c["id"])
+    def test_mixed_walk_parity(self, case, monkeypatch):
+        """The mixed launch's walk (work items by `cu_seqlens_q`, live key
+        blocks of P whole pages up to a tile's causal limit) against the
+        stock path: chunks that cross tile and key-block boundaries beside
+        one-token sequences and idle slots, -1 entries behind every live
+        length, the stacked pool with a traced layer (`_mha_both`)."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        G, bs, mb, KV, hd = case["G"], case["bs"], case["mb"], 2, 8
+        past, this = case["past"], case["this"]
+        quant, dtype = case.get("quant", False), case.get("dtype", np.float32)
+        _steer_mixed_walk(monkeypatch, case)
+        tq, ts = PA.mixed_tiles(sum(this), G, KV, hd)
+        if "TQ" in case:
+            assert (tq, ts) == (case["TQ"], case["TS"])
+            assert PA.mixed_pages_per_block(bs, KV, hd, 4, mb) == case["P"]
+            assert max(this) > tq                 # a chunk of several tiles
+        nb = sum(-(-(a + b) // bs) for a, b in zip(past, this)) + 2
+        args = _mha_args(past, this, KV=KV, G=G, hd=hd, bs=bs, mb=mb, nb=nb,
+                         quant=quant, seed=31, dtype=dtype,
+                         shared_first_page=case.get("cow", False))
+        tables = np.asarray(args["block_tables"])
+        for row, a, b in zip(tables, past, this):  # -1 behind the live
+            assert (row[-(-(a + b) // bs):] == -1).all()
+        assert (tables == -1).any()
+        if quant:       # a scale of its own for every page and head
+            rs = np.random.RandomState(32)
+            for name in ("cache_k_dequant_scales", "cache_v_dequant_scales"):
+                args[name] = jnp.asarray(
+                    rs.uniform(0.01, 0.05, (nb, KV)).astype(np.float32))
+        calls = _mixed_calls(monkeypatch)
+        stock, pal = _mha_both(args)
+        assert len(calls) == 2            # the op, and the stacked pool
+        # bf16: the stock path rounds its f32 answer once, the kernel too
+        tol = (dict(atol=2e-2, rtol=2e-2) if dtype == jnp.bfloat16
+               else dict(atol=5e-5, rtol=1e-5))
+        np.testing.assert_allclose(
+            np.asarray(pal[0], np.float32), np.asarray(stock[0], np.float32),
+            **tol)
+        assert np.array_equal(np.asarray(pal[2]), np.asarray(stock[2]))
+        assert np.array_equal(np.asarray(pal[3]), np.asarray(stock[3]))
+
+    @pytest.mark.parametrize("past, this, quant", [
+        ([8, 0, 15], [5, 9, 1], False), ([3, 0, 7, 0], [2, 0, 1, 4], False),
+        ([10, 0, 33], [1, 13, 1], True)], ids=["ragged", "idle", "int8"])
+    def test_blockspec_walk_through_the_layer(self, past, this, quant,
+                                              monkeypatch):
+        """Where whole pages cannot be copied (head dims off whole lanes
+        on the chip) a mixed launch packs its rows per sequence for the
+        BlockSpec walk (`_kernel`): the same answers."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        monkeypatch.setattr(PA, "whole_pages", lambda *a, **k: False)
+        args = _mha_args(past, this, KV=2, G=3 if quant else 2,
+                         hd=16 if quant else 8, bs=16 if quant else 8,
+                         quant=quant, seed=33)
+        calls = _mixed_calls(monkeypatch)
+        stock, pal = _mha_both(args)
+        assert not calls
+        np.testing.assert_allclose(np.asarray(pal[0]), np.asarray(stock[0]),
+                                   atol=5e-5, rtol=1e-5)
+
+    def test_mixed_walk_reads_no_page_behind_a_tiles_limit(self, monkeypatch):
+        """NaN in every page the tables do not name and in the named
+        pages' slots past the live length: the answer stays what it was,
+        and rows that are no sequence's token come back 0."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        _steer_mixed_walk(monkeypatch, dict(TQ=8, TS=4, P=2, bs=4))
+        rs = np.random.RandomState(34)
+        KV, G, hd, bs, nb = 2, 4, 8, 4, 16
+        past = np.array([5, 0, 8], np.int32)
+        this = np.array([1, 11, 0], np.int32)        # lengths 6, 11, idle
+        cu = np.array([0, 1, 12, 12], np.int32)
+        q = jnp.asarray(rs.randn(14, KV, G, hd).astype(np.float32))
+        kc = rs.randn(nb, KV, bs, hd).astype(np.float32)
+        vc = rs.randn(nb, KV, bs, hd).astype(np.float32)
+        bt = np.array([[3, 7, -1, -1, -1], [5, 1, 9, -1, -1],
+                       [11, 12, -1, -1, -1]], np.int32)
+
+        def run(k):
+            return np.asarray(PA.paged_attention_packed(
+                q, jnp.asarray(k), jnp.asarray(vc), jnp.asarray(bt),
+                jnp.asarray(past), jnp.asarray(this), jnp.asarray(cu), 0.3,
+                interpret=True))
+        clean = run(kc)
+        dirty = kc.copy()
+        named = {3: 4, 7: 2, 5: 4, 1: 4, 9: 3}       # page: live slots
+        for page in range(nb):
+            dirty[page, :, named.get(page, 0):] = np.nan
+        out = run(dirty)
+        assert np.array_equal(out, clean)
+        assert np.abs(clean[:12]).min(axis=(1, 2, 3)).all()
+        assert not clean[12:].any()                  # no sequence's rows
+
+    def test_mixed_work_hand_counted(self, monkeypatch):
+        """`mixed_work` mirrors the kernel's trip counts: work items,
+        live and packed rows, live and fetched pages."""
+        from paddle_tpu.ops.pallas import paged_attention as PA
+        _steer_mixed_walk(monkeypatch, dict(TQ=8, TS=4, P=2, bs=4))
+        # group 4, pages of 4, TQ 8, TS 4, key blocks of 2 pages = 8 keys
+        work = PA.mixed_work([3, 5, 0, 16], [0, 1, 8, 19], 32, 4, 2, 4, 8,
+                             4, 9)
+        # items: (1: 1 token), (2: 8), (3: 8, 8, 3): five, the idle slot none
+        assert work["attn_q_tiles"] == 5
+        assert work["attn_rows_live"] == 1 + 8 + 19
+        assert work["attn_rows_packed"] == 4 + 8 + 8 + 8 + 4
+        # pages: 6 keys -> 2, 8 -> 2, 35 -> 9
+        assert work["attn_pages_live"] == 2 + 2 + 9
+        # key blocks a tile: ceil(6/8), ceil(8/8), ceil(24/8), ceil(32/8),
+        # ceil(35/8) capped at the padded table's 5 blocks
+        assert work["attn_pages_fetched"] == (1 + 1 + 3 + 4 + 5) * 2
+        # the static count of items holds every schedule of the shape
+        tq, _ = PA.mixed_tiles(32, 4, 2, 8)
+        assert PA.mixed_items(32, 4, tq) == 32 // 8 + 4
+        assert PA.mixed_items(4, 4, tq) == 4     # never more than tokens
+        assert PA.mixed_work([], [], 32, 4, 2, 4, 8, 4, 9) == dict.fromkeys(
+            work, 0)
 
     @pytest.mark.parametrize("case", _DECODE_WALK, ids=lambda c: c["id"])
     def test_decode_walk_parity(self, case, monkeypatch):
@@ -902,6 +1071,30 @@ class TestEnginePallas:
         assert s_on["pallas_steps"] == s_on["steps"] > 0
         assert s_off["pallas_steps"] == 0
 
+    def test_chunked_prefill_token_parity_through_the_mixed_walk(
+            self, tiny, monkeypatch):
+        """Prompts longer than the token budget are prefilled in chunks
+        beside decoding sequences: the same tokens through the mixed walk
+        (tiles of 8 tokens, key blocks of 2 pages, so chunks cross both)
+        and through the stock path."""
+        _steer_mixed_walk(monkeypatch, dict(TQ=8, TS=8, P=2, bs=4))
+        prompts = _prompts(tiny[0], 3, [37, 5, 21], seed=25)
+
+        def run(pallas):
+            eng = self._engine(tiny, pallas, num_blocks=64)
+            rids = [eng.submit(p, max_new_tokens=6) for p in prompts]
+            done = {c.rid: c.output_tokens for c in eng.run()}
+            return [done[r] for r in rids], eng.stats
+
+        off, _ = run(False)
+        on, stats = run(True)
+        assert on == off
+        mixed = stats["steps"] - stats["decode_fast_steps"]
+        assert mixed >= 4                         # 63 tokens, 16 a tick
+        assert stats["attn_q_tiles"] > mixed      # ticks of several items
+        assert stats["attn_rows_live"] >= 37 + 5 + 21   # + decode rows
+        assert stats["attn_rows_packed"] >= stats["attn_rows_live"]
+
     def test_preemption_recompute_bit_exact_flag_on(self, tiny):
         """Starved pool forces eviction; the pallas path's recompute on
         resume must reproduce the ample-pool pallas outputs exactly."""
@@ -936,28 +1129,36 @@ class TestEnginePallas:
         assert eng.stats["pallas_steps"] == eng.stats["steps"]
 
     def test_attn_page_counters_hand_counted(self, tiny, monkeypatch):
-        """`attn_pages_live` / `attn_pages_fetched`: what the decode
-        launch's walk must read and what it fetches, from the host's own
-        lengths. Only ticks that take the decode launch add to either."""
+        """`attn_pages_live` / `attn_pages_fetched`: what a launch's walk
+        must read and what it fetches, from the host's own lengths; a
+        mixed tick adds its work items and their rows too."""
         from paddle_tpu.ops.pallas import paged_attention as PA
         monkeypatch.setattr(PA, "_DECODE_KEYS", 8)   # P = 2 pages of 4
+        monkeypatch.setattr(PA, "_MIXED_KEYS", 8)
         eng = self._engine(tiny, True)
         assert PA.decode_pages_per_block(4, 2, 8, 4,
                                          eng.max_blocks_per_seq) == 2
+        # group 2: a row tile of 16 tokens (the budget), a small one of 8
+        assert PA.mixed_tiles(16, 2, 2, tiny[0].head_dim) == (16, 8)
         for p in _prompts(tiny[0], 2, [7, 3], seed=24):
             eng.submit(p, max_new_tokens=4)
         eng.step()                      # the mixed tick: both prompts whole
         assert eng.stats["decode_fast_steps"] == 0
-        assert eng.stats["attn_pages_live"] == 0
-        assert eng.stats["attn_pages_fetched"] == 0
+        # two items, 7 + 3 tokens on two small tiles of 8; keys 0..6 and
+        # 0..2: 2 + 1 pages, one block of 2 pages each
+        mixed = {"attn_q_tiles": 2, "attn_rows_live": 10,
+                 "attn_rows_packed": 16, "attn_pages_live": 3,
+                 "attn_pages_fetched": 4}
+        assert {k: eng.stats[k] for k in mixed} == mixed
         eng.run()
         assert eng.stats["decode_fast_steps"] == 3 == eng.stats["steps"] - 1
         # decode tick k reads positions 0 .. prompt + k - 1:
         #   prompt 7: 8, 9, 10 keys -> 2 + 3 + 3 pages, 1 + 2 + 2 blocks
         #   prompt 3: 4, 5, 6 keys  -> 1 + 2 + 2 pages, 1 + 1 + 1 blocks
-        assert eng.stats["attn_pages_live"] == 8 + 5
+        assert eng.stats["attn_pages_live"] == 3 + 8 + 5
         # (the two idle slots of the four walk and count nothing)
-        assert eng.stats["attn_pages_fetched"] == (5 + 3) * 2
+        assert eng.stats["attn_pages_fetched"] == 4 + (5 + 3) * 2
+        assert eng.stats["attn_q_tiles"] == 2     # decode ticks add none
         # the helper alone: a full table of 5 pages is 3 blocks of 2 (the
         # wrapper pads the table to 6), and no sequence is no page
         assert PA.decode_pages_walked([20, 1], 4, 2, 8, 4, 5) == (6, 8)
@@ -1016,6 +1217,30 @@ class TestEnginePallas:
         B, budget = eng.max_batch, eng.token_budget
         assert set(eng._step_fns) == {(budget, B, False, False, (), False),
                                       (B, B, True, False, (), False)}
+
+    def test_tick_hands_its_host_arrays_to_the_executable_as_they_are(
+            self, tiny, monkeypatch):
+        """A tick's ten small host arrays go into the jitted call as numpy
+        arrays, where the call's own argument handling moves them; wrapped
+        one by one in `jnp.asarray` they were half of the dispatch phase on
+        the chip (PERF.md, PR 30). The tokens are those of the stock engine
+        either way."""
+        from paddle_tpu.inference.serving import engine as E
+        made = []
+        real = E.jnp.asarray
+        monkeypatch.setattr(E.jnp, "asarray",
+                            lambda *a, **k: made.append(1) or real(*a, **k))
+        outs = []
+        for pallas in (True, False):
+            eng = self._engine(tiny, pallas)
+            for seed in (26, 27):     # the first pass traces the executables
+                rids = [eng.submit(p, max_new_tokens=5)
+                        for p in _prompts(tiny[0], 3, [5, 3, 8], seed=seed)]
+                made.clear()
+                done = {d.rid: d.output_tokens for d in eng.run()}
+            assert eng.stats["steps"] > 8 and not made
+            outs.append([done[r] for r in rids])
+        assert outs[0] == outs[1]
 
     def test_forced_bad_geometry_fails_at_init(self):
         # head_dim 16/4 = 4 is under the kernel's floor: forced pallas

@@ -452,11 +452,11 @@ class TestPhasesOnTheProfilersClock:
         assert eng.stats["moe_max_load"] >= max(
             f["moe_max_load"] for f in steps)
 
-    def test_decode_launch_tick_adds_its_page_counts_to_the_step(
-            self, tiny, tmp_path):
+    def test_attention_walk_counts_ride_on_the_step(self, tiny, tmp_path):
         """A tick through the decode launch of paged attention carries
-        `attn_pages_live` and `attn_pages_fetched`; a mixed tick of the
-        same engine carries the five fields alone."""
+        `attn_pages_live` and `attn_pages_fetched`; a tick through the
+        mixed launch those and its work items with their live and packed
+        rows. All from the host's lengths, summed into `engine.stats`."""
         from paddle_tpu.ops.pallas import paged_attention as PA
         eng = _factory(tiny, pallas=True)()
         eng.submit(_prompt(tiny[0], 6), max_new_tokens=2)
@@ -472,17 +472,27 @@ class TestPhasesOnTheProfilersClock:
         steps = [s[3] for s in spans if s[0] == "ptpu.serve.step"]
         assert [f["kind"] for f in steps] == ["mixed", "decode", "decode"]
         five = {"tick", "batch", "tokens", "prefill_tokens", "kind"}
-        assert set(steps[0]) == five
-        P = PA.decode_pages_per_block(4, tiny[0].num_kv_heads,
-                                      tiny[0].head_dim, 4,
-                                      eng.max_blocks_per_seq)
+        pair = {"attn_pages_live", "attn_pages_fetched"}
+        rows = {"attn_q_tiles", "attn_rows_live", "attn_rows_packed"}
+        geometry = (4, tiny[0].num_kv_heads, tiny[0].head_dim, 4,
+                    eng.max_blocks_per_seq)
+        # the mixed tick: 9 tokens at past 0 are one work item on the
+        # whole tile of 16 tokens (the budget; the small one holds 8),
+        # 3 live pages, one key block fetched
+        assert PA.mixed_tiles(16, 2, *geometry[1:3]) == (16, 8)
+        assert set(steps[0]) == five | pair | rows
+        assert {k: steps[0][k] for k in pair | rows} == {
+            "attn_q_tiles": 1, "attn_rows_live": 9, "attn_rows_packed": 16,
+            "attn_pages_live": 3,
+            "attn_pages_fetched": PA.mixed_pages_per_block(*geometry)}
+        P = PA.decode_pages_per_block(*geometry)
         for k, f in enumerate(steps[1:], start=1):
-            assert set(f) == five | {"attn_pages_live", "attn_pages_fetched"}
+            assert set(f) == five | pair
             assert f["attn_pages_live"] == -(-(9 + k) // 4)
             assert f["attn_pages_fetched"] == -(-(9 + k) // (4 * P)) * P
-        for name in ("attn_pages_live", "attn_pages_fetched"):
+        for name in pair | rows:
             assert (eng.stats[name] - stats0[name]
-                    == sum(f[name] for f in steps[1:]))
+                    == sum(f.get(name, 0) for f in steps))
 
     def test_submit_is_a_span_outside_every_step(self, profiled_engine):
         spans = profiled_engine["spans"]
@@ -647,7 +657,7 @@ class TestStableDeviceNames:
 
     @pytest.mark.parametrize("module, count", [
         ("flash_attention", 3), ("fused_ffn", 6), ("fused_sample", 1),
-        ("paged_attention", 3)])
+        ("paged_attention", 4)])
     def test_every_pallas_call_has_a_name(self, module, count):
         import ast
         import os
@@ -673,7 +683,7 @@ class TestStableDeviceNames:
                    for n in names)
 
     def test_pallas_call_sites_are_all_in_ops_pallas(self):
-        """The 13 named sites above are all there are in the package."""
+        """The 14 named sites above are all there are in the package."""
         import os
         import re
 
@@ -692,7 +702,7 @@ class TestStableDeviceNames:
         assert sites == {"ops/pallas/flash_attention.py": 3,
                          "ops/pallas/fused_ffn.py": 6,
                          "ops/pallas/fused_sample.py": 1,
-                         "ops/pallas/paged_attention.py": 3}
+                         "ops/pallas/paged_attention.py": 4}
 
 
 # ---------------------------------------------------------------------------
