@@ -181,6 +181,12 @@ class LLMPredictor:
                  cache_dtype=None, weight_dtype=None,
                  quant_mode: Optional[str] = None, quant_manifest=None,
                  pallas_ffn: Optional[bool] = None):
+        if cfg.block_length:
+            raise NotImplementedError(
+                f"LLMPredictor decodes one token a step under the causal "
+                f"mask; a block-diffusion config (block_length="
+                f"{cfg.block_length}) is served by "
+                f"inference.serving.PagedServingEngine")
         self.cfg = cfg
         if weight_dtype is not None:
             params = jax.tree.map(

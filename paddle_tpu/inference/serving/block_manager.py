@@ -46,12 +46,21 @@ def _chain_hash(prev_hash: int, tokens: Tuple[int, ...]) -> int:
 
 class BlockManager:
     def __init__(self, num_blocks: int, block_size: int,
-                 page_bytes: int = 0):
+                 page_bytes: int = 0, hit_multiple: int = 1):
         if num_blocks < 1 or block_size < 1:
             raise ValueError(f"need num_blocks>=1 and block_size>=1, got "
                              f"{num_blocks}/{block_size}")
+        if hit_multiple < 1 or block_size % hit_multiple:
+            raise ValueError(
+                f"block_size={block_size} must be a multiple of "
+                f"hit_multiple={hit_multiple} (the model's block_length)")
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
+        # a prefix hit is cut down to a multiple of this: under generation
+        # by diffusion over blocks (the engine passes the model's
+        # block_length) the keys and values inside a block depend on the
+        # whole block, so a block matched in part is not matched
+        self.hit_multiple = int(hit_multiple)
         # dtype-aware device footprint of one page across all layers, both
         # cache sides (+ per-page scales when quantized) — supplied by the
         # engine so byte gauges and router placement stay truthful when
@@ -225,6 +234,7 @@ class BlockManager:
                 self._decref(b)
             raise
         cached = min(cached, len(tokens) - 1)
+        cached -= cached % self.hit_multiple
         self.stats["prefix_hit_tokens"] += cached
         self._pending_copies.extend(new_copies)
         self._tables[seq_id] = table

@@ -20,10 +20,35 @@ Client surface:
 - ``submit(...) -> rid`` with admission control (:class:`RejectedError`
   on queue overflow), per-request priority/deadline/sampling knobs;
 - ``step()`` — one scheduler tick + one fused device step, returning
-  :class:`TokenEvent` records (the streaming unit);
+  :class:`TokenEvent` records (the streaming unit): one token a decoding
+  sequence for an autoregressive model, none or a whole block's for a
+  block-diffusion one (below);
 - ``stream(rid)`` — iterator of tokens as they are produced;
 - ``run()`` — drain everything, return :class:`Completion` list
   (greedy/sampling parity with ``LLMPredictor``).
+
+Generation by diffusion over blocks (``cfg.block_length`` Bd > 0, SDAR):
+a step does not yield one token a sequence. Attention is block-causal
+(position i sees j iff j // Bd <= i // Bd). A prompt is prefilled up to its
+last whole block; its tail opens the first generated block, whose other
+rows are masked (input id ``cfg.mask_token_id``). A running sequence brings
+its open block's Bd rows to every tick. While a row is masked the tick is a
+*denoise forward* of the block: all Bd rows go through the head, and on the
+device (scope ``unmask``) each masked row proposes x0 = argmax(logits) with
+confidence softmax(logits)[x0], and the k_s = min(masks left, ceil(Bd / T))
+most confident masked rows (ties to the lower position) become tokens —
+remasking ``low_confidence_static``, T the request's ``denoising_steps``.
+The host fetches [max_batch, Bd] ids, confidences and flags, never logits.
+Once no row is masked the next tick is the block's *commit forward*: the
+same rows, whose keys and values now stand in the pages (every forward
+writes them; the commit's overwrite is the one that is read later), after
+which the block is committed: ``num_computed += Bd`` and its new tokens
+come out as TokenEvents of one tick. What the last block holds beyond
+``max_new_tokens`` is computed and dropped. A tick that holds only block
+rows takes the ``tok_pad = max_batch * Bd`` executable, one with a prefill
+chunk the ``token_budget`` one; both run the mixed attention launch.
+Sampling (temperature > 0), a draft model, LoRA adapters and int8 pages
+are refused with a block-diffusion config.
 
 SLO metrics (TTFT/TPOT histograms, queue-depth and KV-block-utilization
 gauges, admit/preempt/shed counters + flight-recorder events) flow
@@ -112,6 +137,32 @@ def _sample_rows(logits, keys, temps, top_ps, top_k: int):
         jax.random.wrap_key_data(k), row))(keys, l).astype(jnp.int32)
 
 
+def _unmask_rows(logits, masked, quota):
+    """One denoise forward's transfer, on the device (low_confidence_static
+    at temperature 0). logits [B, Bd, V] f32 of every slot's block; masked
+    [B, Bd] bool, the rows still masked; quota [B] i32, how many of them
+    become tokens now (0 on a commit forward, a prefill chunk or an idle
+    slot). Each row proposes x0 = argmax(logits) with confidence
+    softmax_f32(logits)[x0] = 1 / sum(exp(logits - max)); the `quota`
+    masked rows of highest confidence are taken, ties to the lower
+    position. Returns int32 [B * Bd * 3]: per row x0, the confidence's
+    float32 bits, and whether it was taken — what the host fetches instead
+    of logits."""
+    Bd = logits.shape[1]
+    top = jnp.max(logits, axis=-1)
+    x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)           # [B, Bd]
+    conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+    c = jnp.where(masked, conf, -1.0)
+    lower = jnp.arange(Bd)[None, :] < jnp.arange(Bd)[:, None]    # [i, j]: j<i
+    beats = masked[:, None, :] & (
+        (c[:, None, :] > c[:, :, None])
+        | ((c[:, None, :] == c[:, :, None]) & lower[None]))      # [B, i, j]
+    rank = jnp.sum(beats, axis=-1, dtype=jnp.int32)
+    take = masked & (rank < quota[:, None])
+    return jnp.stack([x0, lax.bitcast_convert_type(conf, jnp.int32),
+                      take.astype(jnp.int32)], axis=-1).reshape(-1)
+
+
 def _key_bits(key) -> np.ndarray:
     """Raw uint32[2] view of a PRNG key (typed or legacy)."""
     if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
@@ -152,6 +203,18 @@ class PagedServingEngine:
                 "Pallas FFN (pallas_ffn=True) and weight quantisation "
                 "(quant_mode) cover dense FFNs only: drop them for a "
                 "config with num_experts (int8 pages, quant_kv, are fine)")
+        Bd = int(cfg.block_length)
+        if Bd and (draft is not None or top_k):
+            raise NotImplementedError(
+                f"a block-diffusion config (block_length={Bd}) is served "
+                "greedily, a block at a time: a draft model (speculative "
+                "decoding proposes one token a row) and top_k sampling do "
+                "not apply; drop them")
+        if Bd and block_size % Bd:
+            raise ValueError(
+                f"block_size={block_size} is no multiple of the model's "
+                f"block_length={Bd}: a block's keys and values must lie in "
+                "one page, and a prefix hit ends on a block boundary")
         # apply any FLAGS_tuned_profile before geometry is resolved and
         # executables are keyed, so a pinned profile is zero-retrace
         from ... import tuner as _tuner
@@ -172,6 +235,12 @@ class PagedServingEngine:
         if quant_kv is None:
             quant_kv = bool(flags.flag_value("quant_kv_cache"))
         self.quant_kv = bool(quant_kv)
+        if Bd and self.quant_kv:
+            raise NotImplementedError(
+                f"int8 pages (quant_kv) with block_length={Bd}: a denoise "
+                "forward's keys are rewritten by the commit forward, and "
+                "their rounding was never judged against the reference; "
+                "serve a block-diffusion config with fp pages")
         manifest = Q.resolve_manifest(quant_manifest)
         if self.quant_kv and manifest is None:
             raise ValueError(
@@ -212,11 +281,12 @@ class PagedServingEngine:
         if self.quant_kv:
             self.kv_page_bytes += 2 * cfg.num_layers * kvh * 4
         self.blocks = BlockManager(self.num_blocks, self.block_size,
-                                   page_bytes=self.kv_page_bytes)
+                                   page_bytes=self.kv_page_bytes,
+                                   hit_multiple=max(Bd, 1))
         self.scheduler = Scheduler(self.blocks, self.token_budget,
                                    self.max_batch,
                                    prefill_chunk=prefill_chunk,
-                                   max_queue=max_queue)
+                                   max_queue=max_queue, block_length=Bd)
         self._next_rid = 0
         self._completions: List[Completion] = []
         self._events_by_rid: Dict[int, List[TokenEvent]] = {}
@@ -234,6 +304,13 @@ class PagedServingEngine:
             # least one row, most rows on one expert in one layer
             self.stats.update(moe_pairs=0, moe_experts_hit=0,
                               moe_max_load=0)
+        if Bd:
+            # block diffusion, summed over ticks: sequence-forwards of each
+            # kind (one sequence's block through one tick), blocks
+            # committed, masked rows that became tokens, block rows computed
+            self.stats.update(diff_denoise_forwards=0, diff_commit_forwards=0,
+                              diff_blocks_committed=0, diff_tokens_unmasked=0,
+                              diff_rows=0)
         # multi-tenant LoRA adapters: paged ref-counted device slots.
         # Always constructed (device packs allocate lazily on the first
         # registered adapter), so submit(adapter=...) works out of the box
@@ -347,7 +424,8 @@ class PagedServingEngine:
                temperature: Optional[float] = None,
                top_k: Optional[int] = None, top_p: Optional[float] = None,
                seed: int = 0, trace: Optional[Tuple[int, int]] = None,
-               adapter: Optional[str] = None) -> int:
+               adapter: Optional[str] = None,
+               denoising_steps: Optional[int] = None) -> int:
         """Enqueue a request. Raises ValueError when it cannot ever fit,
         RejectedError (load shed) when the wait queue is full,
         :class:`~.adapters.AdapterMissingError` when ``adapter`` names an
@@ -356,10 +434,26 @@ class PagedServingEngine:
         ``trace``: optional ``(trace_id, parent_span_id)`` context (the
         router's per-request trace) — rides the Sequence as two host
         ints so every queue-wait/prefill/decode span of this request
-        lands in the same trace tree; never touches the jitted step."""
+        lands in the same trace tree; never touches the jitted step.
+
+        ``denoising_steps``: for a block-diffusion config alone, the
+        denoise forwards a block gets at most (1..block_length, the
+        default); the rows to unmask are picked by the one rule served,
+        ``low_confidence_static``."""
         with _tracing.phase("serve.submit"):
             tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
             total = len(tokens) + max(int(max_new_tokens), 0)
+            Bd = self.cfg.block_length
+            steps = 0
+            if Bd:
+                steps = self._check_block_request(
+                    temperature, top_k, top_p, adapter, denoising_steps)
+                total = -(-total // Bd) * Bd    # the last block is whole
+            elif denoising_steps is not None:
+                raise ValueError(
+                    "denoising_steps belongs to a block-diffusion config "
+                    "(block_length > 0); this engine's model is "
+                    "autoregressive")
             if total > self.max_len:
                 raise ValueError(
                     f"prompt {len(tokens)} + new {max_new_tokens} "
@@ -391,7 +485,7 @@ class PagedServingEngine:
                           if deadline_s is not None else None),
                 temperature=float(temperature) if sample else 0.0,
                 top_p=float(top_p) if top_p is not None else 1.0,
-                seed=int(seed))
+                seed=int(seed), denoising_steps=steps)
             if trace is not None:
                 seq.trace_id, seq.parent_span = int(trace[0]), int(trace[1])
             seq._key = jax.random.PRNGKey(int(seed)) if sample else None
@@ -410,6 +504,27 @@ class PagedServingEngine:
                 raise
             self._update_gauges()
             return rid
+
+    def _check_block_request(self, temperature, top_k, top_p, adapter,
+                             denoising_steps) -> int:
+        """What a block-diffusion config refuses at submit, with a message
+        (no silent autoregressive decoding under this model's name);
+        returns the request's denoising steps T."""
+        Bd = self.cfg.block_length
+        if ((temperature is not None and float(temperature) > 0.0)
+                or top_k or top_p is not None):
+            raise NotImplementedError(
+                f"block_length={Bd}: only greedy unmasking (temperature 0) "
+                "is served; sampling of x0 is not implemented")
+        if adapter is not None:
+            raise NotImplementedError(
+                f"block_length={Bd}: LoRA adapters were never judged "
+                "against the block-diffusion reference; submit without one")
+        steps = Bd if denoising_steps is None else int(denoising_steps)
+        if not 1 <= steps <= Bd:
+            raise ValueError(
+                f"denoising_steps={steps} must lie in 1..block_length={Bd}")
+        return steps
 
     def cancel(self, rid: int) -> bool:
         seq = self.scheduler.get(rid)
@@ -593,6 +708,7 @@ class PagedServingEngine:
         all-position argmax — the speculative-decoding verify read."""
         cfg = self.cfg
         top_k = self.top_k
+        Bd = cfg.block_length      # static: 0 = one token a slot comes back
         quant_kv = self.quant_kv   # static: selects the int8-cache trace
         # the op's vocabulary for the engine's constant and the tick's shape
         use_pallas = self.pallas and ("decode" if decode else True)
@@ -602,12 +718,14 @@ class PagedServingEngine:
         def step_fn(params, key_cache, value_cache, kv_scales, tokens,
                     block_tables, cu_seqlens_q, seq_lens_decoder,
                     seq_lens_this_time, rope_emb, temps, top_ps, keys,
-                    greedy, ad_args):
+                    greedy, ad_args, quota=None, masked=None):
             # named scopes: every device operation of the tick belongs to
             # a region named here (embed; layers > qkv, cache_write,
             # paged_attention, attn_out, ffn or moe > router, dispatch,
-            # experts, combine; head; sample), whatever number the
-            # compiler gives it. Metadata only.
+            # experts, combine; head; sample > unmask), whatever number
+            # the compiler gives it. Metadata only. `quota` [B] and
+            # `masked` [B, Bd] come with a block-diffusion tick alone
+            # (`_unmask_rows` says what they are).
             with jax.named_scope("embed"):
                 x = jnp.take(params["embed"], tokens,
                              axis=0).astype(cfg.dtype)
@@ -659,7 +777,8 @@ class PagedServingEngine:
                     qkv, kcs, vcs, li, seq_lens_decoder,
                     seq_lens_this_time, cu_seqlens_q, block_tables,
                     rope_emb=rope_emb, quant_scales=kv_layer,
-                    use_neox_style=True, use_pallas=use_pallas)
+                    use_neox_style=True, use_pallas=use_pallas,
+                    block_length=Bd)
                 with jax.named_scope("attn_out"):
                     x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
                 if cfg.num_experts:
@@ -698,14 +817,23 @@ class PagedServingEngine:
                     body, (x, key_cache, value_cache), xs)
             with jax.named_scope("head"):
                 # last-token hidden state per slot (cu[1:]-1; idle slots
-                # gather garbage the host never reads)
+                # gather garbage the host never reads); under block
+                # diffusion the slot's last Bd rows, its whole block
                 last_idx = jnp.clip(cu_seqlens_q[1:] - 1, 0, tok_pad - 1)
-                hlast = x[last_idx]                                # [B, d]
+                if Bd:
+                    last_idx = jnp.clip(
+                        last_idx[:, None] - (Bd - 1 - jnp.arange(Bd))[None],
+                        0, tok_pad - 1).reshape(-1)
+                hlast = x[last_idx]                          # [B (* Bd), d]
                 hlast = L.rms_norm(hlast, params["final_norm"], cfg.rms_eps)
                 logits = Q.matmul_param(hlast, params, "lm_head"
-                                        ).astype(jnp.float32)      # [B, V]
+                                        ).astype(jnp.float32)  # [B (* Bd), V]
             with jax.named_scope("sample"):
-                if fused_tick and FS.supported(B, logits.shape[-1]):
+                if Bd:
+                    with jax.named_scope("unmask"):
+                        nxt = _unmask_rows(logits.reshape(B, Bd, -1),
+                                           masked, quota)
+                elif fused_tick and FS.supported(B, logits.shape[-1]):
                     # fused decode tick "+1": argmax + temperature/top-k/
                     # top-p masking in ONE launch; the categorical draw
                     # stays outside on bit-identical masked logits (token
@@ -721,7 +849,8 @@ class PagedServingEngine:
                                             axis=-1).astype(jnp.int32)
                     nxt_sampled = _sample_rows(logits, keys, temps, top_ps,
                                                top_k)
-                nxt = jnp.where(greedy, nxt_greedy, nxt_sampled)
+                if not Bd:
+                    nxt = jnp.where(greedy, nxt_greedy, nxt_sampled)
             if cfg.num_experts:
                 # the tick's expert counters ride behind the B tokens, so
                 # the host's one fetch brings both
@@ -765,7 +894,8 @@ class PagedServingEngine:
                   ad_sig=list(ad_sig), spec=bool(spec_mode),
                   cache_write="pallas_pages" if self.pallas
                   else "scatter_rows",
-                  experts=L.expert_form(self.cfg))
+                  experts=L.expert_form(self.cfg),
+                  block_length=self.cfg.block_length)
         return fn
 
     def _copy_blocks(self, pairs: List[Tuple[int, int]]):
@@ -909,7 +1039,18 @@ class PagedServingEngine:
             spec_mode = bool(spec_plan)
 
             tok_pad, B = self.token_budget, self.max_batch
-            decode = (self.pallas and not spec_plan
+            Bd = self.cfg.block_length
+            # block diffusion: which items bring their open block (the
+            # others are prefill chunks), opened here where it is not yet
+            in_block = [bool(Bd) and self.scheduler.prefill_left(seq) <= 0
+                        for seq, _ in batch.items]
+            for (seq, _n), blk in zip(batch.items, in_block):
+                if blk and seq.block_ids is None:
+                    self._open_block(seq)
+            if Bd and all(in_block):
+                # every chunk is one block: the steady-state executable
+                tok_pad = min(B * Bd, tok_pad)
+            decode = (self.pallas and not spec_plan and not Bd
                       and all(n == 1 for _, n in batch.items))
             if decode:
                 # decode fast path: every scheduled chunk is one token, so the
@@ -927,9 +1068,18 @@ class PagedServingEngine:
             top_ps = np.ones((B,), np.float32)
             keys = np.zeros((B, 2), np.uint32)
             greedy = np.ones((B,), bool)
+            # block diffusion: the rows to unmask now and the rows still
+            # masked; zero on a commit forward and on a prefill chunk
+            quota = np.zeros((B,), np.int32)
+            masked = np.zeros((B, Bd), bool)
             pos = 0
             for i, (seq, n) in enumerate(batch.items):
                 chunk = seq.tokens[seq.num_computed:seq.num_computed + n]
+                if in_block[i]:
+                    chunk = seq.block_ids
+                    quota[i] = min(sum(seq.block_masked),
+                                   -(-Bd // seq.denoising_steps))
+                    masked[i] = seq.block_masked
                 props = spec_plan.get(i)
                 if props is not None:
                     chunk = list(chunk) + props   # [t_c, d1..dk]: verify rows
@@ -982,10 +1132,10 @@ class PagedServingEngine:
             # its own argument handling, which costs the tick half of what
             # ten `jnp.asarray` did (1.3 against 2.7 ms of dispatch on the
             # chip, PERF.md PR 30), and nothing writes them afterwards
-            out = fn(
-                self.params, self._key_cache, self._value_cache,
-                self._kv_scales, tokens, tables, cu, dec_lens, this_lens,
-                self._rope_emb, temps, top_ps, keys, greedy, ad_args)
+            out = fn(self.params, self._key_cache, self._value_cache,
+                     self._kv_scales, tokens, tables, cu, dec_lens,
+                     this_lens, self._rope_emb, temps, top_ps, keys,
+                     greedy, ad_args, *((quota, masked) if Bd else ()))
 
         with _tracing.phase("serve.wait"):
             all_arg = None
@@ -998,7 +1148,7 @@ class PagedServingEngine:
             dur = (time.perf_counter_ns() - t0) * 1e-9
             moe = None
             if self.cfg.num_experts:
-                nxt, moe = nxt[:B], [int(c) for c in nxt[B:]]
+                nxt, moe = nxt[:-3], [int(c) for c in nxt[-3:]]
 
         with _tracing.phase("serve.harvest"):
             if fused_tick and self.stats["step_builds"] > builds0:
@@ -1010,8 +1160,9 @@ class PagedServingEngine:
                 # holds for every subsequent tick.
                 self.stats["tick_pallas_launches"] = (FA.trace_launches()
                                                       - launches0)
-            n_prefill = sum(n for s, n in batch.items
-                            if s.num_computed + n < len(s.tokens))
+            n_prefill = sum(n for (s, n), blk in zip(batch.items, in_block)
+                            if not blk and (Bd or s.num_computed + n
+                                            < len(s.tokens)))
             spec_extra = sum(len(p) for p in spec_plan.values())
             _emit("serving.step", dur_s=dur,
                   tokens=batch.total_tokens + spec_extra,
@@ -1042,7 +1193,7 @@ class PagedServingEngine:
                     walked = PA.mixed_work(
                         dec_lens, this_lens, tok_pad, self.block_size,
                         cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads,
-                        *pool)
+                        *pool, block_len=Bd)
                 fields.update(walked)
                 for name, n in walked.items():
                     self.stats[name] += n
@@ -1050,7 +1201,9 @@ class PagedServingEngine:
                 batch=len(batch.items),
                 tokens=batch.total_tokens + spec_extra,
                 prefill_tokens=n_prefill,
-                kind="decode" if decode else "mixed", **fields)
+                kind=("decode" if decode else
+                      "block" if Bd and all(in_block) else "mixed"),
+                **fields)
             if _tracing.trace_enabled():
                 # per-request tick attribution: each traced request in the
                 # batch gets a span over this tick's device interval, so a
@@ -1081,6 +1234,11 @@ class PagedServingEngine:
                       pages=int((tables >= 0).sum()) * self.cfg.num_layers)
             self.stats["steps"] += 1
             self.stats["tokens_computed"] += batch.total_tokens + spec_extra
+            if Bd:
+                events.extend(self._harvest_blocks(
+                    batch, in_block, nxt.reshape(B, Bd, 3)))
+                self._update_gauges()
+                return events
 
             # harvest: a slot yields a token iff its chunk reached the end of
             # the sequence's current tokens (final prefill chunk or decode row)
@@ -1093,32 +1251,87 @@ class PagedServingEngine:
                 self.scheduler.on_computed(seq, n)
                 if seq.num_computed < len(seq.tokens):
                     continue   # mid-prefill: logits row is not a next token
-                tok = int(nxt[i])
-                # token stamps stay on the scheduler's clock (arrival and
-                # deadlines are time.monotonic()), not on the span clock
-                now = time.monotonic()
-                first = seq.first_token_at is None
-                if seq.eos >= 0 and tok == seq.eos:
-                    self.scheduler.append_token(seq, tok)  # timestamps
-                    seq.generated.pop()                    # eos not surfaced
-                    seq.tokens.pop()
-                    events.append(self._finish_event(seq, "stop"))
-                    continue
-                self.scheduler.append_token(seq, tok)
-                _emit("serving.token", rid=seq.rid, first=first,
-                      ttft_s=(now - seq.arrival) if first else None,
-                      tpot_s=None if first else now - seq._prev_token_at)
-                seq._prev_token_at = now
-                if len(seq.generated) >= seq.max_new_tokens:
-                    ev = TokenEvent(seq.rid, tok, True, "length")
-                    self._record_completion(seq, "length")
-                    self.scheduler.finish(seq, "length")
-                else:
-                    ev = TokenEvent(seq.rid, tok, False)
-                events.append(ev)
-                self._events_by_rid[seq.rid].append(ev)
+                events.append(self._emit_token(seq, int(nxt[i])))
             self._update_gauges()
             return events
+
+    def _open_block(self, seq: Sequence):
+        """Open the next block of a block-diffusion sequence: what its
+        tokens hold behind the last whole block (the prompt's tail, for the
+        first block alone) as known rows, the rest masked."""
+        Bd = self.cfg.block_length
+        tail = seq.tokens[seq.num_computed:]
+        seq.block_ids = tail + [self.cfg.mask_token_id] * (Bd - len(tail))
+        seq.block_masked = [False] * len(tail) + [True] * (Bd - len(tail))
+        seq.block_forwards = 0
+
+    def _harvest_blocks(self, batch: ScheduledBatch, in_block: List[bool],
+                        out: np.ndarray) -> List[TokenEvent]:
+        """A block-diffusion tick's harvest. `out` [B, Bd, 3] int32 is what
+        `_unmask_rows` returned: per row the proposed token, its
+        confidence's bits, whether it was taken. A prefill chunk advances
+        `num_computed`. A block with masked rows had a denoise forward:
+        the rows taken become tokens, nothing is committed. A block with
+        none had its commit forward: its keys and values stand, it is
+        committed, and its new tokens come out together."""
+        Bd = self.cfg.block_length
+        events: List[TokenEvent] = []
+        for i, (seq, n) in enumerate(batch.items):
+            if not in_block[i]:
+                self.scheduler.on_computed(seq, n)
+                continue
+            self.stats["diff_rows"] += Bd
+            if any(seq.block_masked):
+                for r in np.flatnonzero(out[i, :, 2]):
+                    seq.block_ids[r] = int(out[i, r, 0])
+                    seq.block_masked[r] = False
+                    self.stats["diff_tokens_unmasked"] += 1
+                seq.block_forwards += 1
+                self.stats["diff_denoise_forwards"] += 1
+                continue
+            self.stats["diff_commit_forwards"] += 1
+            self.stats["diff_blocks_committed"] += 1
+            new = seq.block_ids[len(seq.tokens) - seq.num_computed:]
+            _emit("serving.block_commit", rid=seq.rid, start=seq.num_computed,
+                  tokens=len(new), denoise_forwards=seq.block_forwards)
+            seq.block_ids = seq.block_masked = None
+            for tok in new:
+                # what the last block holds beyond max_new_tokens (or
+                # behind an end-of-sequence token) is computed and dropped
+                ev = self._emit_token(seq, tok)
+                events.append(ev)
+                if ev.finished:
+                    break
+            else:
+                self.scheduler.on_computed(seq, Bd)
+        return events
+
+    def _emit_token(self, seq: Sequence, tok: int) -> TokenEvent:
+        """Append one harvested token to a sequence and make its event: the
+        end-of-sequence token finishes it unsurfaced ("stop"), the
+        max_new_tokens-th finishes it ("length"). Token stamps stay on the
+        scheduler's clock (arrival and deadlines are time.monotonic()),
+        not on the span clock."""
+        now = time.monotonic()
+        first = seq.first_token_at is None
+        if seq.eos >= 0 and tok == seq.eos:
+            self.scheduler.append_token(seq, tok)  # timestamps
+            seq.generated.pop()                    # eos not surfaced
+            seq.tokens.pop()
+            return self._finish_event(seq, "stop")
+        self.scheduler.append_token(seq, tok)
+        _emit("serving.token", rid=seq.rid, first=first,
+              ttft_s=(now - seq.arrival) if first else None,
+              tpot_s=None if first else now - seq._prev_token_at)
+        seq._prev_token_at = now
+        if len(seq.generated) >= seq.max_new_tokens:
+            ev = TokenEvent(seq.rid, tok, True, "length")
+            self._record_completion(seq, "length")
+            self.scheduler.finish(seq, "length")
+        else:
+            ev = TokenEvent(seq.rid, tok, False)
+        self._events_by_rid[seq.rid].append(ev)
+        return ev
 
     def _harvest_spec(self, seq: Sequence, props: List[int], base: int,
                       all_arg: np.ndarray) -> List[TokenEvent]:
@@ -1147,29 +1360,9 @@ class PagedServingEngine:
         events: List[TokenEvent] = []
         for tok in emitted:
             self.scheduler.on_computed(seq, 1)
-            now = time.monotonic()
-            first = seq.first_token_at is None
-            if seq.eos >= 0 and tok == seq.eos:
-                self.scheduler.append_token(seq, tok)  # timestamps
-                seq.generated.pop()                    # eos not surfaced
-                seq.tokens.pop()
-                events.append(self._finish_event(seq, "stop"))
-                return events
-            self.scheduler.append_token(seq, tok)
-            _emit("serving.token", rid=seq.rid, first=first,
-                  ttft_s=(now - seq.arrival) if first else None,
-                  tpot_s=None if first else now - seq._prev_token_at)
-            seq._prev_token_at = now
-            if len(seq.generated) >= seq.max_new_tokens:
-                ev = TokenEvent(seq.rid, tok, True, "length")
-                self._record_completion(seq, "length")
-                self.scheduler.finish(seq, "length")
-                events.append(ev)
-                self._events_by_rid[seq.rid].append(ev)
-                return events
-            ev = TokenEvent(seq.rid, tok, False)
-            events.append(ev)
-            self._events_by_rid[seq.rid].append(ev)
+            events.append(self._emit_token(seq, tok))
+            if events[-1].finished:
+                break
         return events
 
     # -- bookkeeping ------------------------------------------------------

@@ -18,7 +18,13 @@ request mix into one fixed-shape compiled program):
   generated tokens re-prefill when capacity returns, numerically exact);
 - **deadlines & cancellation**: per-request absolute deadlines checked at
   every schedule point; expired or cancelled requests free their pages
-  immediately and finish with reason ``"deadline"`` / ``"cancelled"``.
+  immediately and finish with reason ``"deadline"`` / ``"cancelled"``;
+- **generation by diffusion over blocks** (``block_length`` Bd > 0, SDAR):
+  a step no longer yields one token a sequence. A prompt is prefilled in
+  chunks that end on multiples of Bd, up to its last whole block; its tail
+  opens the first generated block. From then on a running sequence is
+  scheduled Bd rows a tick, its open block, and charged Bd in the token
+  budget, whether the engine runs a denoise or the commit forward on them.
 
 The scheduler owns sequence state and the
 :class:`~.block_manager.BlockManager`; the engine owns device state and
@@ -89,6 +95,17 @@ class Sequence:
     trace_id: int = 0
     parent_span: int = 0
     _qw_span: Optional[object] = None   # open queue.wait span, if any
+    # generation by diffusion over blocks (the engine's, for a config with
+    # block_length > 0): the request's denoise forwards a block at most,
+    # and the open block — its ids (a masked row carries mask_token_id),
+    # whether each row is still masked (the sequence's own state, never
+    # `id == mask_token_id`: a prompt may hold that id), and the denoise
+    # forwards it has had. They outlive a preemption: the block's rows
+    # depend only on what is committed before it
+    denoising_steps: int = 0
+    block_ids: Optional[List[int]] = None
+    block_masked: Optional[List[bool]] = None
+    block_forwards: int = 0
 
     def __post_init__(self):
         self.tokens = list(self.prompt)
@@ -124,13 +141,22 @@ class ScheduledBatch:
 class Scheduler:
     def __init__(self, block_manager: BlockManager, token_budget: int,
                  max_batch: int, prefill_chunk: Optional[int] = None,
-                 max_queue: Optional[int] = None):
+                 max_queue: Optional[int] = None, block_length: int = 0):
         if token_budget < 1 or max_batch < 1:
             raise ValueError("token_budget and max_batch must be >= 1")
         self.blocks = block_manager
         self.token_budget = int(token_budget)
         self.max_batch = int(max_batch)
         self.prefill_chunk = int(prefill_chunk or token_budget)
+        self.block_length = int(block_length)
+        if self.block_length:
+            # chunks are whole blocks: the keys and values inside a block
+            # depend on the whole block
+            self.prefill_chunk -= self.prefill_chunk % self.block_length
+            if min(self.prefill_chunk, self.token_budget) < self.block_length:
+                raise ValueError(
+                    f"block_length={block_length} needs a token_budget and "
+                    f"a prefill_chunk of at least one block")
         self._max_queue = max_queue
         self.waiting: Deque[Sequence] = deque()
         self.running: List[Sequence] = []
@@ -240,6 +266,27 @@ class Scheduler:
         return expired
 
     # -- the step builder -------------------------------------------------
+    def prefill_left(self, seq: Sequence) -> int:
+        """Positions of `seq` still to prefill. Autoregressive: all that
+        is not computed (a decode row is a prefill of one). Block
+        diffusion: up to the last whole block of its tokens; at 0 the
+        sequence is in its open block."""
+        if not self.block_length:
+            return seq.remaining()
+        bd = self.block_length
+        return len(seq.tokens) // bd * bd - seq.num_computed
+
+    def _chunk(self, seq: Sequence, budget: int) -> int:
+        """Rows to run for `seq` in this step inside `budget`: a prefill
+        chunk (whole blocks under block diffusion), else the open block."""
+        left = self.prefill_left(seq)
+        n = min(left, self.prefill_chunk, budget)
+        if not self.block_length:
+            return n
+        if left > 0:
+            return n - n % self.block_length
+        return self.block_length if budget >= self.block_length else 0
+
     def schedule(self) -> Tuple[ScheduledBatch, List[Sequence]]:
         """Build the next mixed prefill+decode batch. Returns (batch,
         expired) where expired sequences hit their deadline and finished
@@ -256,7 +303,7 @@ class Scheduler:
                 break
             if seq.status != RUNNING:   # preempted by an earlier iteration
                 continue
-            n = min(seq.remaining(), self.prefill_chunk, budget)
+            n = self._chunk(seq, budget)
             if n <= 0:
                 continue
             while True:
@@ -279,7 +326,8 @@ class Scheduler:
             budget -= n
 
         # 2) admit waiting sequences into leftover budget (chunked prefill)
-        while self.waiting and budget > 0 and len(items) < self.max_batch:
+        while (self.waiting and budget > 0 and len(items) < self.max_batch
+               and budget >= self.block_length):
             seq = self.waiting[0]
             try:
                 cached = self.blocks.allocate_sequence(seq.rid, seq.tokens)
@@ -288,7 +336,7 @@ class Scheduler:
             if cached:
                 seq.num_computed = cached
                 _emit("serving.prefix_hit", rid=seq.rid, tokens=cached)
-            n = min(seq.remaining(), self.prefill_chunk, budget)
+            n = self._chunk(seq, budget)
             self.waiting.popleft()
             seq.status = RUNNING
             if seq._qw_span is not None:   # queue wait ends here

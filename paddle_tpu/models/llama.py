@@ -15,7 +15,10 @@ TPU-first choices: bf16 compute / f32 master params, static shapes, scan over
 stacked layer params (one compiled block body, not L unrolled layers), GQA,
 RoPE computed in f32, optional MoE (top-k routing through `route` and
 `routed_ffn`; the hybrid engine dispatches tokens with all_to_all over the
-ep axis) and optional QK-norm (`qk_normed`), as OLMoE has them.
+ep axis) and optional QK-norm (`qk_normed`), as OLMoE has them or per head
+as Qwen3-MoE and SDAR have it; `block_length` > 0 puts the full-sequence
+forward under SDAR's block-causal mask (generation by diffusion over
+blocks itself is the serving engine's, inference/serving/engine.py).
 """
 from __future__ import annotations
 
@@ -50,17 +53,35 @@ class LlamaConfig:
     norm_topk_prob: bool = True
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    # the width of one head; 0 = hidden_size // num_heads, what every model
+    # before SDAR had (its 32 heads of 128 are wider than its hidden 2048).
+    # `dataclasses.replace(cfg, hidden_size=..)` keeps the resolved value:
+    # pass head_dim=0 with it to derive it anew
+    head_dim: int = 0
+    # with `qk_norm`: the second form of QK-norm (Qwen3-MoE's, which SDAR
+    # keeps), RMSNorm over each head's vector (weight [head_dim]) after the
+    # split into heads, instead of OLMoE's over the whole projected vector
+    qk_norm_per_head: bool = False
+    # generation by diffusion over blocks (SDAR): 0 = autoregressive. With
+    # block_length Bd > 0 attention is block-causal (position i sees j iff
+    # j // Bd <= i // Bd), a block of Bd positions is generated together
+    # from rows that carry `mask_token_id`, in the request's denoise
+    # forwards and one commit forward (inference/serving/engine.py)
+    block_length: int = 0
+    mask_token_id: int = 0
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.hidden_size // self.num_heads)
 
     def num_params(self) -> int:
         d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
         hd = self.head_dim
         attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
         if self.qk_norm:
-            attn += (self.num_heads + self.num_kv_heads) * hd
+            attn += (2 * hd if self.qk_norm_per_head
+                     else (self.num_heads + self.num_kv_heads) * hd)
         if self.num_experts:
             mlp = self.num_experts * 3 * d * f + d * self.num_experts
         else:
@@ -115,7 +136,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "attn_norm": jnp.ones((L, d), pt),
         "mlp_norm": jnp.ones((L, d), pt),
     }
-    if cfg.qk_norm:
+    if cfg.qk_norm and cfg.qk_norm_per_head:
+        blocks["q_norm"] = jnp.ones((L, hd), pt)
+        blocks["k_norm"] = jnp.ones((L, hd), pt)
+    elif cfg.qk_norm:
         blocks["q_norm"] = jnp.ones((L, nh * hd), pt)
         blocks["k_norm"] = jnp.ones((L, nkv * hd), pt)
     if cfg.num_experts:
@@ -159,12 +183,23 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto") -> jax.Array:
+def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto",
+              block_length: int = 0) -> jax.Array:
     """Causal MHA/GQA. q [B,T,H,hd], k/v [B,T,KV,hd] → [B,T,H,hd].
 
     impl: 'auto' uses the Pallas flash kernel on TPU when available, else the
     XLA einsum path (which XLA fuses well on its own).
+
+    block_length Bd > 0: the block-causal mask of generation by diffusion
+    over blocks, position i sees j iff j // Bd <= i // Bd (full inside a
+    block, causal across blocks), on the XLA path alone: the flash kernel
+    knows the causal mask only, and impl='flash' with it raises.
     """
+    if block_length and impl == "flash":
+        raise ValueError("the flash kernel has no block-causal mask: "
+                         "block_length > 0 takes impl='auto' or 'xla'")
+    if block_length:
+        impl = "xla"
     if impl == "flash":
         # explicit request: no silent fallback — unsupported shapes raise
         from ..ops.pallas import flash_attention as _fa
@@ -183,7 +218,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto") -> j
         v = jnp.repeat(v, H // KV, axis=2)
     scale = 1.0 / (hd ** 0.5)
     scores = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32) * scale
-    mask = jnp.tril(jnp.ones((T, T), bool))
+    if block_length:
+        blk = jnp.arange(T) // block_length
+        mask = blk[None, :] <= blk[:, None]
+    else:
+        mask = jnp.tril(jnp.ones((T, T), bool))
     scores = jnp.where(mask[None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bshd->bthd", probs, v)
@@ -191,11 +230,19 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto") -> j
 
 def qk_normed(q: jax.Array, k: jax.Array, lp: Dict[str, jax.Array],
               cfg: LlamaConfig):
-    """OLMoE's QK-norm: RMSNorm over the WHOLE projected vector (q [..., H*hd],
-    k [..., KV*hd]), before the split into heads and before rope. Identity
-    for a model without it."""
+    """QK-norm on the projected q [..., H*hd] and k [..., KV*hd], before
+    rope, in the model's form. OLMoE's: RMSNorm over the WHOLE projected
+    vector, before the split into heads. Qwen3-MoE's and SDAR's
+    (`qk_norm_per_head`): RMSNorm over each head's hd values with one
+    weight [hd] for all heads, after the split. Identity for a model
+    without it."""
     if not cfg.qk_norm:
         return q, k
+    if cfg.qk_norm_per_head:
+        def per_head(x, w):
+            heads = x.reshape(*x.shape[:-1], -1, cfg.head_dim)
+            return rms_norm(heads, w, cfg.rms_eps).reshape(x.shape)
+        return per_head(q, lp["q_norm"]), per_head(k, lp["k_norm"])
     return (rms_norm(q, lp["q_norm"], cfg.rms_eps),
             rms_norm(k, lp["k_norm"], cfg.rms_eps))
 
@@ -399,7 +446,8 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, nkv, hd)
     q = apply_rope(q.reshape(B, T, nh, hd), cos, sin)
     k = apply_rope(k.reshape(B, T, nkv, hd), cos, sin)
-    o = attention(q, k, v, impl=attn_impl).reshape(B, T, nh * hd)
+    o = attention(q, k, v, impl=attn_impl,
+                  block_length=cfg.block_length).reshape(B, T, nh * hd)
     x = x + o @ lp["wo"].astype(o.dtype)
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if cfg.num_experts:

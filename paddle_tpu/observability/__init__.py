@@ -229,6 +229,9 @@ _c_srv_prefix = _C("paddle_serving_prefix_cached_tokens_total",
                    "instead of recompute")
 _c_srv_cow = _C("paddle_serving_cow_copies_total",
                 "Copy-on-write KV page copies executed on device")
+_c_srv_blocks = _C("paddle_serving_blocks_committed_total",
+                   "Blocks of a block-diffusion model committed (their "
+                   "commit forward done, their tokens streamed)")
 _c_srv_pallas = _C("paddle_serving_pallas_steps_total",
                    "Serving steps served through the Pallas paged-attention "
                    "kernel, by kind (decode = max_q=1 specialized launch, "
@@ -696,6 +699,7 @@ _HANDLERS = {
     "serving.prefix_hit": lambda d, f: _c_srv_prefix.inc(
         f.get("tokens", 0)),
     "serving.cow": lambda d, f: _c_srv_cow.inc(f.get("copies", 1)),
+    "serving.block_commit": lambda d, f: _c_srv_blocks.inc(),
     "serving.pallas_step": lambda d, f: _c_srv_pallas.inc(
         labels={"kind": f.get("launch", "mixed")}),
     "pallas_ffn.step": lambda d, f: _c_ffn.inc(

@@ -535,7 +535,8 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
                           seq_lens_this_time, cu_seqlens_q, block_tables,
                           rope_emb=None, quant_scales=None, qkv_bias=None,
                           use_neox_style=False, quant_max_bound=127.0,
-                          quant_min_bound=-127.0, use_pallas=False):
+                          quant_min_bound=-127.0, use_pallas=False,
+                          block_length=0):
     """One layer of `block_multihead_attention_` on the stacked page pool
     [L, num_blocks, KV, block_size, hd]: split and rotate `qkv`, write the
     new tokens' rows into `layer`'s pages where they lie, then attend over
@@ -545,7 +546,12 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
     and no page the batch does not own is touched. `quant_scales` is None or
     (k_quant [KV], v_quant [KV], k_dequant [num_blocks, KV], v_dequant)
     of this layer; `use_pallas` is False, True or "decode", already
-    resolved. Returns (fmha_out, qkv_out, key_pool, value_pool)."""
+    resolved. `block_length` Bd > 0 (static) takes the block-causal mask
+    of generation by diffusion over blocks in place of the causal one: the
+    row at absolute position p sees key j iff j < (p // Bd + 1) * Bd and
+    j < past + this, on either read path (not the decode launch: one row
+    a sequence is no block). Returns (fmha_out, qkv_out, key_pool,
+    value_pool)."""
     from ..pallas import paged_attention as PA
     _, num_blocks, KV, bs, hd = key_pool.shape
     B, max_blocks = block_tables.shape
@@ -558,6 +564,10 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
             f"use_pallas={use_pallas!r} forced but geometry H={H} KV={KV} "
             f"hd={hd} block_size={bs} is not supported() by the pallas "
             f"paged-attention kernel")
+    if block_length > 1 and use_pallas == "decode":
+        raise ValueError(
+            f"block_length={block_length}: the rows of a block go through "
+            "the mixed launch (use_pallas=True), not the decode launch")
 
     # named scopes (jax.named_scope): the device operations of this op
     # belong to `qkv` (split, bias, rope, token indices), `cache_write`
@@ -655,7 +665,7 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
                 o = PA.paged_attention_packed(
                     q_g, key_pool, value_pool, block_tables, past, this, cu,
                     sm_scale, k_dequant=k_dequant, v_dequant=v_dequant,
-                    layer=layer)
+                    layer=layer, block_len=block_length)
             fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
             return fmha_out, qkv3.reshape(token_num, -1), key_pool, value_pool
 
@@ -692,7 +702,14 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
             vdq = jnp.swapaxes(_page_scales(v_dequant), 1, 2)
             s = s * kdq[:, :, None, :]                           # [tok, KV, 1, mkv]
         kv_pos = jnp.arange(max_kv)[None, :]
-        ok = (kv_pos <= tok_pos[:, None]) & page_valid[tok_b]    # [tok, max_kv]
+        if block_length:
+            # block-causal: to the end of the row's own block, and no key
+            # behind the sequence's last written position
+            see = jnp.minimum((tok_pos // block_length + 1) * block_length,
+                              (past + this)[tok_b]) - 1
+        else:
+            see = tok_pos
+        ok = (kv_pos <= see[:, None]) & page_valid[tok_b]        # [tok, max_kv]
         s = jnp.where(ok[:, None, None, :], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
         if kv_quant:

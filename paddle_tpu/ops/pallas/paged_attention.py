@@ -79,6 +79,12 @@ token stream):
   one-token sequence in a mixed tick costs about what it costs in a
   decode tick.
 
+Both ragged walks take a static `block_len` (0 = causal): the block-causal
+mask of generation by diffusion over blocks, under which the query at
+position p sees key j iff j < (p // block_len + 1) * block_len and
+j < past + this (`_see_limit`); a tile's key blocks then end with its last
+row's block. With 0 the kernels are what they were.
+
 The BlockSpec walk (`_kernel`; rows `[B, KV, max_q * G, hd]` packed per
 sequence, max_q = 1 for a decode launch): grid `(B, KV, table width)`
 with the page axis innermost, one `[block_size, hd]` page of one KV head
@@ -130,6 +136,20 @@ _STAT_LANES = 128
 _SCALE_ROWS = 8
 
 
+def _see_limit(pos, end, block_len: int):
+    """The last key position a query at absolute position `pos` may see
+    (int32 arrays or scalars, in a kernel or outside one): `pos` itself
+    under the causal mask (block_len 0); under the block-causal mask of
+    generation by diffusion over blocks the end of the query's own block
+    of `block_len` positions, (pos // Bd + 1) * Bd - 1, and never past
+    `end - 1`, the sequence's last position that holds a key (`end` is
+    not looked at under the causal mask)."""
+    if not block_len:
+        return pos
+    bd = _i32(block_len)
+    return jnp.minimum((jax.lax.div(pos, bd) + _i32(1)) * bd, end) - _i32(1)
+
+
 def supported(num_heads: int, num_kv_heads: int, head_dim: int,
               block_size: int) -> bool:
     """Static gate: can this head/page geometry run through the kernel?
@@ -174,7 +194,8 @@ def whole_pages(head_dim: int, interpret: Optional[bool] = None) -> bool:
 
 
 def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
-            sm_scale: float, block_size: int, group: int, has_quant: bool):
+            sm_scale: float, block_size: int, group: int, has_quant: bool,
+            block_len: int = 0):
     """One (sequence b, kv head, page p) grid step. `layer_ref` is read
     by the K/V index maps only.
 
@@ -225,7 +246,9 @@ def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
         t = jax.lax.div(rows_i, _i32(group))          # chunk offset of row
         kv_abs = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
                   + p * _i32(block_size))
-        ok = (kv_abs <= past + t) & (t < this)        # causal + live rows
+        # causal (or block-causal: `_see_limit`) + live rows
+        ok = (kv_abs <= _see_limit(past + t, past + this if block_len
+                                   else None, block_len)) & (t < this)
         s = jnp.where(ok, s, NEG_INF)
         m_prev = m_sc[:, :1]                          # [rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -564,7 +587,7 @@ def mixed_items(token_num: int, batch: int, tq: int) -> int:
 
 def mixed_work(past, this, token_num: int, block_size: int,
                num_kv_heads: int, group: int, head_dim: int, itemsize: int,
-               max_blocks: int):
+               max_blocks: int, block_len: int = 0):
     """What one mixed launch walks, reckoned on the host from the
     scheduler's own lengths (`past`, `this` [B], idle slots 0): the trip
     counts of `_mixed_kernel`, as `decode_pages_walked` mirrors
@@ -585,7 +608,11 @@ def mixed_work(past, this, token_num: int, block_size: int,
     t0 = (np.arange(int(tiles.sum())) - np.repeat(np.cumsum(tiles) - tiles,
                                                   tiles)) * tq
     live = np.minimum(this[seq] - t0, tq)
-    blocks = np.minimum(-(-(past[seq] + t0 + live) // (pages * block_size)),
+    seen = past[seq] + t0 + live
+    if block_len:       # to the end of the tile's last row's block
+        seen = np.minimum(-(-seen // block_len) * block_len,
+                          past[seq] + this[seq])
+    blocks = np.minimum(-(-seen // (pages * block_size)),
                         -(-max_blocks // pages))
     return {"attn_q_tiles": int(tiles.sum()),
             "attn_rows_live": int(this.sum()),
@@ -624,7 +651,8 @@ def _loop_i32(n: int, body) -> None:
 
 def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
                   *refs, sm_scale: float, block_size: int, pages: int,
-                  group: int, small: int, has_quant: bool):
+                  group: int, small: int, has_quant: bool,
+                  block_len: int = 0):
     """One work item j of a mixed launch: the query rows of sequence
     seq[j] from chunk offset t0[j] on (row r = t * G + g of the tile, its
     query at position past + t0 + t), against that sequence's key blocks
@@ -651,12 +679,23 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
     past = past_ref[b]
     # tokens of this tile that hold a query (none: an unused item)
     live = jnp.clip(this_ref[b] - t0, _i32(0), _i32(R // group))
-    # keys 0 .. past + t0 + live - 1 can be seen from the tile, the first
+    # keys 0 .. past + t0 + live - 1 can be seen from the tile (under the
+    # block-causal mask: up to the end of its last row's block), the first
     # tile of a chunk does not visit the keys of the last
+    # (with block_len 0 nothing below traces an operation it did not
+    # trace before the mask was there: the causal executables are the same)
+    end = past + this_ref[b] if block_len else None
+
+    def seen():
+        s = past + t0 + live
+        if block_len:
+            s = _see_limit(s - _i32(1), end, block_len) + _i32(1)
+        return s
+
     n_blocks = jnp.where(
         live > 0,
-        jnp.minimum(jax.lax.div(past + t0 + live + _i32(span - 1),
-                                _i32(span)), _i32(width // pages)), _i32(0))
+        jnp.minimum(jax.lax.div(seen() + _i32(span - 1), _i32(span)),
+                    _i32(width // pages)), _i32(0))
     # the products' operand type: q's and the pages' own (int8 pages
     # convert exactly), never wider than what either holds
     ct = (q_ref.dtype if kbuf.dtype == jnp.int8
@@ -691,9 +730,12 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
         """The item's walk on its first `rows` rows (static)."""
         t = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0),
                         _i32(group))
-        # a row's query position; rows without a query (pad, t >= this)
-        # sit before every key
-        pos = jnp.where(t < live, past + t0 + t, _i32(-1))    # [rows, 1]
+        # the last key a row's query sees (its own position; the end of
+        # its block under the block-causal mask); rows without a query
+        # (pad, t >= this) sit before every key
+        pos = jnp.where(t < live,
+                        _see_limit(past + t0 + t, end, block_len),
+                        _i32(-1))                             # [rows, 1]
         m_sc[:, :rows] = jnp.full((KV, rows, _STAT_LANES), NEG_INF,
                                   jnp.float32)
         l_sc[:, :rows] = jnp.zeros((KV, rows, _STAT_LANES), jnp.float32)
@@ -767,7 +809,7 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
 
 def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
                 seq, t0, group, small, sm_scale, k_dequant, v_dequant,
-                interpret):
+                interpret, block_len: int = 0):
     """The mixed launch: grid over work items, pools left in HBM, whole
     pages gathered by the kernel."""
     items, KV, R, hd = q_items.shape
@@ -797,7 +839,7 @@ def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
     kernel = functools.partial(
         _mixed_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
         pages=int(pages), group=int(group), small=int(small),
-        has_quant=has_quant)
+        has_quant=has_quant, block_len=int(block_len))
     count_launch()
     return pl.pallas_call(
         kernel,
@@ -813,7 +855,8 @@ def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
 def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
                            seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
                            sm_scale: float, k_dequant=None, v_dequant=None,
-                           interpret: Optional[bool] = None, layer=None):
+                           interpret: Optional[bool] = None, layer=None,
+                           block_len: int = 0):
     """Attention of a ragged mixed batch (prefill chunks, decode rows and
     idle slots in one launch) over paged caches, on the packed token
     stream itself.
@@ -823,6 +866,11 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
     position `seq_lens_decoder[b] + t`; the caches, tables, scales and
     `layer` are `paged_attention`'s. Returns [token_num, KV, G, hd] in
     q_tok.dtype, rows that are no sequence's token 0.
+
+    `block_len` (static; 0 = causal) is the block length Bd of generation
+    by diffusion over blocks: the query at position p sees key j iff
+    j < (p // Bd + 1) * Bd and j < past + this (`_see_limit`), so the rows
+    of one block see one another whichever tile they fall in.
 
     Where whole pages can be copied (`whole_pages`) this is the mixed
     walk: the launch runs over work items reckoned here from the lengths
@@ -851,7 +899,8 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
         o_pack = paged_attention(
             q_pack.reshape(B, KV, token_num * G, hd), key_cache, value_cache,
             block_tables, past, this, G, sm_scale, k_dequant=k_dequant,
-            v_dequant=v_dequant, interpret=interpret, layer=layer)
+            v_dequant=v_dequant, interpret=interpret, layer=layer,
+            block_len=block_len)
         o_pack = o_pack.reshape(B, KV, token_num, G, hd)
         return jnp.where(tok_valid, o_pack[tok_b, :, tok_local], 0
                          ).astype(q_tok.dtype)
@@ -867,7 +916,8 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
     o_items = _mixed_call(
         q_items.reshape(items, KV, tq * G, hd), key_cache, value_cache,
         jnp.maximum(block_tables.astype(jnp.int32), 0), past, this, layer,
-        seq, t0, G, ts, sm_scale, k_dequant, v_dequant, interpret)
+        seq, t0, G, ts, sm_scale, k_dequant, v_dequant, interpret,
+        block_len)
     o_items = o_items.reshape(items, KV, tq, G, hd)
     item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
     return jnp.where(tok_valid, o_items[item, :, tok_local % tq], 0
@@ -877,7 +927,8 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
 def paged_attention(q_rows, key_cache, value_cache, block_tables,
                     seq_lens_decoder, seq_lens_this_time, group: int,
                     sm_scale: float, k_dequant=None, v_dequant=None,
-                    interpret: Optional[bool] = None, layer=None):
+                    interpret: Optional[bool] = None, layer=None,
+                    block_len: int = 0):
     """Attention over paged caches, block table walked in-kernel.
 
     q_rows [B, KV, max_q * G, hd] — per-sequence packed rows (row
@@ -898,6 +949,9 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     Rows equal to `group` (max_q = 1: the CALLER guarantees every
     seq_lens_this_time <= 1) take the decode walk where `whole_pages`
     says Mosaic lowers it, any other launch the BlockSpec walk (`_kernel`).
+    `block_len` > 0 (static) asks for the block-causal mask
+    (`paged_attention_packed`), which the BlockSpec walk has and the
+    decode walk has not: one row a sequence is no block.
     """
     if (k_dequant is None) != (v_dequant is None):
         raise ValueError("pass both k_dequant and v_dequant or neither")
@@ -920,6 +974,11 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     this = seq_lens_this_time.reshape(-1).astype(jnp.int32)   # [B]
 
     if rows == group and whole_pages(hd, interpret):
+        if block_len > 1:
+            raise ValueError(
+                "the decode walk (one query row a sequence) has no "
+                f"block-causal mask: block_len={block_len} rows of a block "
+                "go through paged_attention_packed")
         return _decode_call(q_rows, key_cache, value_cache, tables, past,
                             this, layer, sm_scale, k_dequant, v_dequant,
                             interpret)
@@ -972,7 +1031,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     )
     kernel = functools.partial(
         _kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
-        group=int(group), has_quant=has_quant)
+        group=int(group), has_quant=has_quant, block_len=int(block_len))
     count_launch()
     return pl.pallas_call(
         kernel,

@@ -157,6 +157,9 @@ def test_paged_attention_compiles(one_chip, max_q, int8_pages):
     # and the BlockSpec walk at those pools (token_budget rows a head: what
     # a mixed launch ran until the mixed walk, below, took head_dim 128)
     (8, 4, 2304, 128, 2048, False), (16, 1, 768, 32, 512, False),
+    # SDAR-30B-A3B (4 KV heads, group 8, table 32): the decode walk, which
+    # its block rows never take, and the BlockSpec walk
+    (4, 8, 768, 32, 8, False), (4, 8, 768, 32, 512, False),
 ])
 def test_paged_attention_on_the_serve_pools_compiles(
         one_chip, kv, group, num_blocks, max_blocks, rows, int8):
@@ -308,7 +311,8 @@ def test_default_engine_ticks_compile(one_chip, monkeypatch, hidden,
 
     from paddle_tpu.inference.serving import PagedServingEngine
     from paddle_tpu.models import llama as L
-    cfg = dataclasses.replace(L.CONFIGS["llama-test"], hidden_size=hidden)
+    cfg = dataclasses.replace(L.CONFIGS["llama-test"], hidden_size=hidden,
+                              head_dim=0)       # 0: derived anew
     assert cfg.head_dim == hidden // 4
     params = L.init_params(cfg, jax.random.PRNGKey(0))
     monkeypatch.setattr(fa, "available", lambda: True)
@@ -458,6 +462,110 @@ def test_routed_ffn_on_stacked_experts_compiles_without_a_copy(one_chip,
     made = re.findall(r"%(\S+) = bf16\[(?:64|1024),(?:2048|1024),"
                       r"(?:1024|2048)\]\S* (\w[\w-]*)\(", text)
     assert {op for _, op in made} <= {"parameter", "bitcast"}, made
+
+
+# SDAR-30B-A3B-Chat (benchmark/configs/sdar30b-a3b-serve.json): hidden 2048,
+# 32 q / 4 kv heads of 128, 128 experts of 768, vocabulary 151,936
+SDAR = dict(vocab_size=151936, hidden_size=2048, intermediate_size=768,
+            num_heads=32, num_kv_heads=4, head_dim=128, max_seq_len=512,
+            rope_theta=1e6, rms_eps=1e-6, num_experts=128, top_k=8,
+            qk_norm=True, qk_norm_per_head=True, norm_topk_prob=True,
+            block_length=4, mask_token_id=151669,
+            param_dtype=jnp.bfloat16)
+
+
+@pytest.mark.parametrize("tokens, block_len", [
+    (64, 4), (512, 4),      # a tick of 16 blocks, a tick with a prefill chunk
+    (64, 0),                # the same shapes under the causal mask
+])
+def test_block_causal_mixed_walk_compiles(one_chip, tokens, block_len):
+    """The mixed walk at SDAR's shapes (4 KV heads, GQA group 8, table of
+    32 pages) under the block-causal mask, inside the depth-7 pool."""
+    kv, group, layers, batch, block_size = 4, 8, 7, 16, 16
+    pool = ((layers, 768, kv, block_size, HEAD_DIM), jnp.bfloat16)
+
+    def fn(q, k, v, tables, past, this, cu, layer):
+        return pa.paged_attention_packed(q, k, v, tables, past, this, cu,
+                                         HEAD_DIM ** -0.5, interpret=False,
+                                         layer=layer, block_len=block_len)
+
+    text = _compile(fn, one_chip, _bf16(tokens, kv, group, HEAD_DIM), pool,
+                    pool, ((batch, 32), jnp.int32), ((batch,), jnp.int32),
+                    ((batch,), jnp.int32), ((batch + 1,), jnp.int32),
+                    ((), jnp.int32)).as_text()
+    assert "paged_attention_mixed" in text
+    # 64 tokens a work item, 8 (64 rows a KV head) on the small tile that a
+    # block of 4 takes; a key block is the whole table of 32 pages
+    assert pa.mixed_tiles(tokens, group, kv, HEAD_DIM) == (64, 8)
+    assert pa.mixed_pages_per_block(block_size, kv, HEAD_DIM, 2, 32) == 32
+
+
+@pytest.mark.parametrize("rows", [64, 512])     # a block tick / a mixed tick
+def test_routed_ffn_at_sdar_widths_compiles(one_chip, rows):
+    """SDAR's routed FFN as the serve tick calls it: 128 experts of 768,
+    8 a row renormalised, the stacked leaves of depth 7 and a layer
+    index."""
+    from paddle_tpu.models import llama as L
+    cfg = L.LlamaConfig(num_layers=7, **SDAR)
+
+    def fn(h, valid, layer, router, w1, w3, w2):
+        return L.routed_ffn_load(
+            h, {"router": router, "w1": w1, "w3": w3, "w2": w2}, cfg,
+            valid, layer=layer)
+
+    available = fa.available
+    fa.available = lambda: True         # expert_form and interpret read it
+    try:
+        assert L.expert_form(cfg) == "sorted_gmm"
+        _compile(fn, one_chip, _bf16(rows, 2048), ((rows,), jnp.bool_),
+                 ((), jnp.int32), _bf16(2048, 128), _bf16(7, 128, 2048, 768),
+                 _bf16(7, 128, 2048, 768), _bf16(7, 128, 768, 2048))
+    finally:
+        fa.available = available
+
+
+def test_sdar_depth7_ticks_fit_the_chip(one_chip, monkeypatch):
+    """The cell `serve_blockdiff_decode` as the engine builds it on a TPU
+    (`available` steered true), at the published widths and depth 7: both
+    executables (a tick with a prefill chunk, token_budget 512 rows; a
+    tick of blocks alone, 16 x 4 rows) compile for the described v5e and
+    the compiler counts each under the chip's 15.75 GiB."""
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import llama as L
+    cfg = L.LlamaConfig(num_layers=7, **SDAR)
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    monkeypatch.setattr(fa, "available", lambda: True)
+    monkeypatch.setattr(pa, "available", lambda: True)
+    eng = PagedServingEngine(cfg, params, num_blocks=768, block_size=16,
+                             max_batch=16, token_budget=512, max_len=512,
+                             pallas=True, pallas_ffn=False)
+    build, gib = eng._build_step, {}
+
+    def compiled_not_run(tok_pad, B, *rest):
+        fn = build(tok_pad, B, *rest)
+
+        def tick(*args):
+            abstract = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            compiled = fn.lower(*abstract).compile()
+            assert "paged_attention_mixed" in compiled.as_text()
+            m = compiled.memory_analysis()
+            gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                            + m.temp_size_in_bytes
+                            - m.alias_size_in_bytes) / 2 ** 30
+            return (jnp.zeros((B * cfg.block_length * 3 + 3,), jnp.int32),
+                    args[1], args[2])
+        return tick
+
+    monkeypatch.setattr(eng, "_build_step", compiled_not_run)
+    eng.submit(list(range(1, 70)), max_new_tokens=8)
+    eng.step()                  # 68 positions of prefill
+    eng.step()                  # the open block alone
+    assert set(gib) == {512, 64}
+    assert all(g < 15.75 for g in gib.values()), gib
+    print("SDAR depth-7 GiB by tok_pad:", gib)
 
 
 @pytest.mark.parametrize("top_k", [0, 50])
