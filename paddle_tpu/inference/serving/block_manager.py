@@ -76,8 +76,11 @@ class BlockManager:
         self._hash_info: Dict[int, Tuple[int, Tuple[int, ...]]] = {}
         # refcount-0 blocks still holding cached KV, oldest first (LRU)
         self._cached_free: "OrderedDict[int, None]" = OrderedDict()
-        # per-sequence block tables
+        # per-sequence block tables, and how far each is content-addressed:
+        # (leading pages hashed, chain hash through them), where
+        # register_computed goes on
         self._tables: Dict[int, List[int]] = {}
+        self._hashed: Dict[int, Tuple[int, int]] = {}
         # pending device copies (src, dst) the engine must execute before
         # the next step touches dst. src pages are ref-pinned while a copy
         # is pending so allocation pressure cannot reclaim (and another
@@ -181,7 +184,7 @@ class BlockManager:
         table: List[int] = []
         new_copies: List[Tuple[int, int]] = []
         cached = 0
-        prev_h = 0
+        prev_h = before_last = 0
         try:
             # full-block prefix hits: share pages by refcount
             i, full_run = 0, True
@@ -195,7 +198,7 @@ class BlockManager:
                 table.append(blk)
                 self.stats["prefix_hit_blocks"] += 1
                 cached += bs
-                prev_h = h
+                before_last, prev_h = prev_h, h
                 i += bs
             # partial hit on the next block (whether the chain ran out of
             # full-sized chunks or broke on content): copy-on-write. The
@@ -226,6 +229,9 @@ class BlockManager:
                 dst = self._alloc_block()
                 new_copies.append((src, dst))   # table drop keeps src's ref
                 table[-1] = dst
+                # the private copy is not addressed yet: register_computed
+                # goes on with it
+                i, prev_h = i - bs, before_last
                 self.stats["cow_copies"] += 1
         except NoFreeBlocksError:
             for src, _ in new_copies:
@@ -238,6 +244,7 @@ class BlockManager:
         self.stats["prefix_hit_tokens"] += cached
         self._pending_copies.extend(new_copies)
         self._tables[seq_id] = table
+        self._hashed[seq_id] = (i // bs, prev_h)   # the pages hit
         return cached
 
     def _partial_match(self, prev_h: int,
@@ -286,13 +293,19 @@ class BlockManager:
                           num_computed: int):
         """Content-address every full block covered by the first
         `num_computed` computed tokens of `tokens`, making them
-        prefix-cache hits for future sequences."""
+        prefix-cache hits for future sequences. Goes on from the last page
+        hashed for this sequence (a sequence's tokens only grow), so a call
+        costs the pages that filled since the last one; every id below
+        `num_computed` has to be known."""
         bs = self.block_size
         table = self._tables.get(seq_id)
         if table is None:
             return
-        prev_h = 0
-        for bi in range(min(num_computed, len(tokens)) // bs):
+        bi, prev_h = self._hashed[seq_id]
+        full = min(num_computed, len(tokens)) // bs
+        if bi >= full:
+            return
+        for bi in range(bi, full):
             chunk = tuple(int(t) for t in tokens[bi * bs:(bi + 1) * bs])
             h = _chain_hash(prev_h, chunk)
             blk = table[bi]
@@ -301,9 +314,11 @@ class BlockManager:
                 self._block_hash[blk] = h
                 self._hash_info[h] = (prev_h, chunk)
             prev_h = h
+        self._hashed[seq_id] = (full, prev_h)
 
     def free_sequence(self, seq_id: int):
         table = self._tables.pop(seq_id, None)
+        self._hashed.pop(seq_id, None)
         if not table:
             return
         if self._pending_copies:
@@ -327,6 +342,9 @@ class BlockManager:
 
     def block_table(self, seq_id: int) -> List[int]:
         return list(self._tables[seq_id])
+
+    def num_blocks_of(self, seq_id: int) -> int:
+        return len(self._tables[seq_id])
 
     def has_sequence(self, seq_id: int) -> bool:
         return seq_id in self._tables

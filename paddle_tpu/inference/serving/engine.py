@@ -19,10 +19,13 @@ Client surface:
 
 - ``submit(...) -> rid`` with admission control (:class:`RejectedError`
   on queue overflow), per-request priority/deadline/sampling knobs;
-- ``step()`` — one scheduler tick + one fused device step, returning
-  :class:`TokenEvent` records (the streaming unit): one token a decoding
-  sequence for an autoregressive model, none or a whole block's for a
-  block-diffusion one (below);
+- ``step()`` — one tick's :class:`TokenEvent` records (the streaming
+  unit): one token a decoding sequence for an autoregressive model, none
+  or a whole block's for a block-diffusion one (below). A tick is one
+  scheduler pass + one fused device step; the next one is launched before
+  this one's ids are read wherever counts determine it, its decode rows
+  fed from this one's output on the device, so the chip does not wait for
+  the host between two ticks (``step`` says when);
 - ``stream(rid)`` — iterator of tokens as they are produced;
 - ``run()`` — drain everything, return :class:`Completion` list
   (greedy/sampling parity with ``LLMPredictor``).
@@ -59,7 +62,7 @@ from __future__ import annotations
 
 import functools
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -80,8 +83,9 @@ from .. import quant as Q
 from . import adapters as AD
 from . import speculative as SP
 from .block_manager import BlockManager, NoFreeBlocksError
-from .scheduler import (Completion, DeadlineExceededError, RejectedError,
-                        ScheduledBatch, Scheduler, Sequence)
+from .scheduler import (FINISHED, UNKNOWN, Completion,
+                        DeadlineExceededError, RejectedError, ScheduledBatch,
+                        Scheduler, Sequence)
 
 # step-geometry flags: the executable signature is keyed on
 # (token_budget, batch_slots), so these are exactly the knobs a tuned
@@ -115,6 +119,33 @@ class TokenEvent:
     token: int                 # -1 for compute-free terminal events
     finished: bool
     reason: Optional[str] = None   # stop | length | deadline | cancelled
+
+
+@dataclass(eq=False)
+class _Tick:
+    """One tick between its launch and its harvest: what `_launch` planned
+    and called, for `_harvest` to read a call of `step()` later."""
+    events: List[TokenEvent]        # deadlines that fell while scheduling
+    ahead: bool                     # launched behind a tick not yet read
+    batch: Optional[ScheduledBatch] = None     # None: nothing was scheduled
+    out: Any = None                 # the executable's `nxt`, on the device
+    all_arg: Any = None             # a speculative tick's verify read
+    t0: int = 0                     # perf_counter_ns at the call
+    # item index by rid of the sequences this tick yields a token: the
+    # entry of `out` that a row launched behind it feeds from
+    slots: Dict[int, int] = field(default_factory=dict)
+    # per item, `num_computed` once this tick's rows are in (None: a
+    # speculative chunk or a block-diffusion tick, counted at harvest)
+    ends: List[Optional[int]] = field(default_factory=list)
+    n_prefill: int = 0
+    spec_plan: Dict[int, List[int]] = field(default_factory=dict)
+    in_block: List[bool] = field(default_factory=list)
+    was_decode: List[bool] = field(default_factory=list)
+    tok_pad: int = 0
+    decode: bool = False            # the one-row launch
+    ffn_mode: bool = False
+    lens: Tuple[Any, ...] = ()      # cu, dec_lens, this_lens
+    pages: int = 0                  # table entries assigned (int8 pages)
 
 
 def _sample_rows(logits, keys, temps, top_ps, top_k: int):
@@ -297,7 +328,8 @@ class PagedServingEngine:
                       "spec_ticks": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "attn_pages_live": 0,
                       "attn_pages_fetched": 0, "attn_q_tiles": 0,
-                      "attn_rows_live": 0, "attn_rows_packed": 0}
+                      "attn_rows_live": 0, "attn_rows_packed": 0,
+                      "ticks_ahead": 0, "ahead_void_rows": 0}
         if cfg.num_experts:
             # routed-expert work, summed over ticks (max_load: the largest
             # seen): (row, expert) pairs, (layer, expert) groups with at
@@ -413,6 +445,16 @@ class PagedServingEngine:
         # rank classes, spec-mode); `decode` = every chunk is one token
         self._step_fns: Dict[Tuple[Any, ...], Any] = {}
         self._copy_fn = None
+        # the tick launched and not yet harvested (`step`), the events of
+        # one harvested outside `step()` (`_settle`), the newest tick's
+        # output (the `prev` argument of the next; zeros of its shape
+        # before the first) and when the device last came free
+        self._in_flight: Optional[_Tick] = None
+        self._held: List[TokenEvent] = []
+        self._last_out: Any = np.zeros(
+            (self.max_batch * (3 * Bd if Bd else 1)
+             + (3 if cfg.num_experts else 0),), np.int32)
+        self._device_free_ns = 0
         # set by ReplicaHandle so this engine's tick spans say which
         # replica served them (the merged-trace failover story)
         self._trace_replica: Optional[int] = None
@@ -527,6 +569,7 @@ class PagedServingEngine:
         return steps
 
     def cancel(self, rid: int) -> bool:
+        self._settle()
         seq = self.scheduler.get(rid)
         if seq is None or seq.status == "finished":
             return False
@@ -535,7 +578,10 @@ class PagedServingEngine:
         return True
 
     def has_work(self) -> bool:
-        return self.scheduler.has_work()
+        """True while a request waits or runs, a tick is in flight, or a
+        settled tick's events wait for the next `step()`."""
+        return (self.scheduler.has_work() or self._in_flight is not None
+                or bool(self._held))
 
     # -- cross-replica page migration (serving/disagg.py) ------------------
     def extract_pages(self, tokens) -> Optional[Dict[str, Any]]:
@@ -545,6 +591,7 @@ class PagedServingEngine:
         when this pool cannot serve the complete chain (never a partial
         payload — the receiver recomputes instead). Quantized engines
         export int8 pages plus their per-page dequant scale rows."""
+        self._settle()
         chain = self.blocks.prefix_chain(tokens)
         if not chain:
             return None
@@ -575,6 +622,7 @@ class PagedServingEngine:
         holds). Returns pages adopted (0 = all already present). Raises
         ValueError on cache-geometry/dtype mismatch (heterogeneous
         pools must recompute, not adopt)."""
+        self._settle()
         if payload["dtype"] != np.dtype(self.cache_dtype).name:
             raise ValueError(
                 f"migrated pages are {payload['dtype']} but this engine "
@@ -718,7 +766,16 @@ class PagedServingEngine:
         def step_fn(params, key_cache, value_cache, kv_scales, tokens,
                     block_tables, cu_seqlens_q, seq_lens_decoder,
                     seq_lens_this_time, rope_emb, temps, top_ps, keys,
-                    greedy, ad_args, quota=None, masked=None):
+                    greedy, ad_args, quota=None, masked=None, prev=None,
+                    feed=None):
+            # a tick launched ahead of the last one's harvest takes the ids
+            # the host does not hold yet from the last tick's output, on
+            # the device: `prev` is that tick's `nxt` (any earlier one when
+            # no row needs it) and `feed` [tok_pad] the entry of it a row
+            # embeds, -1 for a row whose id the host wrote into `tokens`
+            if feed is not None:
+                tokens = jnp.where(feed >= 0, prev[jnp.maximum(feed, 0)],
+                                   tokens)
             # named scopes: every device operation of the tick belongs to
             # a region named here (embed; layers > qkv, cache_write,
             # paged_attention, attn_out, ffn or moe > router, dispatch,
@@ -954,30 +1011,101 @@ class PagedServingEngine:
 
     # -- scheduler tick ---------------------------------------------------
     def step(self) -> List[TokenEvent]:
-        """One tick: schedule a mixed batch, run the fused step, harvest
-        tokens. Returns this tick's streamed events.
+        """One tick's events: the streamed tokens of exactly one fused
+        device step, in the order the device runs them.
 
-        In a ``jax.profiler`` trace the tick is ``ptpu.serve.step`` and
-        its phases (schedule, prepare, dispatch, wait, harvest) are
-        contiguous children, so a step's self time is what no phase
-        covers. The clock readings at the phase boundaries are the ones
-        the ring's cow.copy / prefill.chunk / decode.tick spans get."""
-        with _tracing.phase("serve.step", tick=self.stats["steps"]) as tick:
-            return self._step(tick)
+        A tick has two halves. Its *launch* (schedule, prepare, dispatch)
+        plans a mixed batch from counts the host holds, calls the
+        executable and advances every scheduled sequence by count:
+        ``num_computed`` by its rows, and one position of unknown id where
+        the tick yields it a token. Its *harvest* (wait, then harvest)
+        reads the tick's ids — the one sync —, writes them into the
+        sequences, runs `_emit_token` / `TokenEvent` / completions, hashes
+        the pages that filled into the prefix cache, and keeps the books.
 
-    def _step(self, tick) -> List[TokenEvent]:
+        The next tick is launched before this one is harvested wherever it
+        is determined without this one's ids (`_next_is_determined`): its
+        decode rows take their ids from this tick's output on the device,
+        so the chip runs it while the host reads, harvests and plans.
+        `step()` then returns this tick's events with the next one in
+        flight. What the host learns a tick late — an end-of-sequence id,
+        a deadline — leaves the sequence a row in the tick in flight: it is
+        computed and dropped (``stats["ahead_void_rows"]``).
+
+        In a ``jax.profiler`` trace the call is ``ptpu.serve.step`` and
+        its phases (schedule, prepare, dispatch of the tick launched here;
+        wait, harvest of the tick whose events it returns; a call with no
+        tick in flight launches that one first, so it has schedule,
+        prepare, dispatch twice) are contiguous children, so a step's
+        self time is what no phase covers. The span's fields describe the
+        tick harvested, with ``ahead`` = 1 if it had been launched ahead
+        and ``void_rows``. The clock readings at the phase boundaries are
+        the ones the ring's cow.copy / prefill.chunk / decode.tick spans
+        get."""
+        with _tracing.phase("serve.step", tick=self.stats["steps"]) as span:
+            if self._held:      # a tick settled outside step(): its events
+                events, self._held = self._held, []
+                return events
+            cur = self._in_flight
+            if cur is None:
+                cur = self._launch(None)
+                if cur.batch is None:
+                    self._update_gauges()
+                    return cur.events
+                self._in_flight = cur
+            nxt = None
+            if self._next_is_determined(cur):
+                nxt = self._launch(cur)
+                if nxt.batch is None and not nxt.events:
+                    nxt = None
+            self._in_flight = nxt
+            return self._harvest(cur, span)
+
+    def _settle(self):
+        """Harvest the tick in flight, if any, and hold its events for the
+        next `step()`: what reads or edits sequences and pools from outside
+        a tick (`cancel`, `extract_pages`, `ingest_pages`, `engine_stats`)
+        sees every dispatched row accounted for."""
+        cur, self._in_flight = self._in_flight, None
+        if cur is not None:
+            self._held.extend(self._harvest(cur, None))
+
+    def _next_is_determined(self, cur: "_Tick") -> bool:
+        """Whether the tick after `cur` can be planned before `cur`'s ids
+        are read: everything the scheduler decides for it must be a count
+        the host holds now. Not after a block-diffusion tick (the next
+        `block_ids`, `masked` and `quota` are a host function of this
+        tick's result) and not with a draft model (a speculative tick
+        advances by the accepted length, and the next proposal starts from
+        this tick's id): those engines keep the synchronous order, in this
+        same loop. Not when a sequence of `cur` reaches `max_new_tokens` in
+        it: a tick that frees a slot is followed by one planned with full
+        knowledge, so whoever waits for the slot, or submits when the last
+        token is seen, is admitted as in the synchronous order. And not
+        when planning would have to preempt: a preempted sequence
+        re-prefills from its ids."""
+        if (cur.batch is None or self.cfg.block_length
+                or (self.spec is not None and self.spec_k > 0)):
+            return False
+        for i in cur.slots.values():
+            seq = cur.batch.items[i][0]
+            if len(seq.generated) + 1 >= seq.max_new_tokens:
+                return False
+        return self.scheduler.next_fits()
+
+    def _launch(self, prev: Optional["_Tick"]) -> "_Tick":
+        """Schedule, prepare and dispatch one tick. `prev` is the tick in
+        flight this one is launched behind (None: every id is known)."""
         with _tracing.phase("serve.schedule"):
             hook = _CHAOS_HOOK[0]
             if hook is not None:
                 hook("step")
             batch, expired = self.scheduler.schedule()
-            events: List[TokenEvent] = []
-            for seq in expired:
-                events.append(self._finish_event(seq, "deadline",
-                                                 already_finished=True))
+            tick = _Tick(events=[self._finish_event(seq, "deadline",
+                                                    already_finished=True)
+                                 for seq in expired], ahead=prev is not None)
             if not batch:
-                self._update_gauges()
-                return events
+                return tick
             pairs = self.blocks.take_copies()
 
         with _tracing.phase("serve.prepare"):
@@ -1060,6 +1188,9 @@ class PagedServingEngine:
                 # launch per decode step)
                 tok_pad = B
             tokens = np.zeros((tok_pad,), np.int32)
+            # the entry of `prev`'s output a row embeds, where the host
+            # does not hold its id yet (-1: `tokens` has it)
+            feed = np.full((tok_pad,), -1, np.int32)
             cu = np.zeros((B + 1,), np.int32)
             dec_lens = np.zeros((B,), np.int32)
             this_lens = np.zeros((B,), np.int32)
@@ -1085,6 +1216,11 @@ class PagedServingEngine:
                     chunk = list(chunk) + props   # [t_c, d1..dk]: verify rows
                     n = len(chunk)
                 tokens[pos:pos + n] = chunk
+                if chunk[-1] == UNKNOWN:
+                    # the token `prev` yields this sequence: a row's id is
+                    # unknown only behind everything the host holds
+                    tokens[pos + n - 1] = 0
+                    feed[pos + n - 1] = prev.slots[seq.rid]
                 pos += n
                 cu[i + 1] = pos
                 dec_lens[i] = seq.num_computed
@@ -1116,13 +1252,15 @@ class PagedServingEngine:
                                  "packs": self.adapters.device_packs(cls)}
                                 for cls in ad_sig)
 
-            # tick classification per request, snapshotted BEFORE the device
-            # step mutates generated: a request mid-prompt is in a prefill
-            # chunk; one with tokens out is in a decode tick
-            was_decode = [bool(s.generated) for s, _ in batch.items]
+            # tick classification per request, snapshotted BEFORE the tick
+            # extends the sequence: a request mid-prompt is in a prefill
+            # chunk; one with tokens out (or one on its way) is in a decode
+            # tick
+            was_decode = [len(s.tokens) > len(s.prompt)
+                          for s, _ in batch.items]
 
         with _tracing.phase("serve.dispatch"):
-            t0 = time.perf_counter_ns()
+            tick.t0 = time.perf_counter_ns()
             builds0 = self.stats["step_builds"]
             fn = self._get_step_fn(tok_pad, B, decode, ffn_mode,
                                    ad_sig, spec_mode)
@@ -1131,26 +1269,21 @@ class PagedServingEngine:
             # the host arrays go in as they are: the call moves them with
             # its own argument handling, which costs the tick half of what
             # ten `jnp.asarray` did (1.3 against 2.7 ms of dispatch on the
-            # chip, PERF.md PR 30), and nothing writes them afterwards
+            # chip, PERF.md PR 30), and nothing writes them afterwards.
+            # `prev`/`feed` are always there, so a tick launched ahead runs
+            # the executable every other tick of its shape runs
             out = fn(self.params, self._key_cache, self._value_cache,
                      self._kv_scales, tokens, tables, cu, dec_lens,
                      this_lens, self._rope_emb, temps, top_ps, keys,
-                     greedy, ad_args, *((quota, masked) if Bd else ()))
-
-        with _tracing.phase("serve.wait"):
-            all_arg = None
+                     greedy, ad_args, *((quota, masked) if Bd
+                                        else (None, None)),
+                     self._last_out, feed)
             if spec_mode:
-                nxt, all_arg, self._key_cache, self._value_cache = out
-                all_arg = np.asarray(all_arg)
+                tick.out, tick.all_arg = out[:2]
             else:
-                nxt, self._key_cache, self._value_cache = out
-            nxt = np.asarray(nxt)     # the step's one sync point
-            dur = (time.perf_counter_ns() - t0) * 1e-9
-            moe = None
-            if self.cfg.num_experts:
-                nxt, moe = nxt[:-3], [int(c) for c in nxt[-3:]]
-
-        with _tracing.phase("serve.harvest"):
+                tick.out = out[0]
+            self._last_out = tick.out
+            self._key_cache, self._value_cache = out[-2:]
             if fused_tick and self.stats["step_builds"] > builds0:
                 # fresh trace: the launch-counter delta counts the DISTINCT
                 # Pallas launches traced into this tick's executable (the
@@ -1160,13 +1293,62 @@ class PagedServingEngine:
                 # holds for every subsequent tick.
                 self.stats["tick_pallas_launches"] = (FA.trace_launches()
                                                       - launches0)
-            n_prefill = sum(n for (s, n), blk in zip(batch.items, in_block)
-                            if not blk and (Bd or s.num_computed + n
-                                            < len(s.tokens)))
-            spec_extra = sum(len(p) for p in spec_plan.values())
+            # progress by count: every plain row advances its sequence now,
+            # so the next tick can be planned before this one is read. A
+            # speculative chunk advances by what is accepted and a
+            # block-diffusion tick by what the block's state says: their
+            # harvest does both halves, as `ends[i] is None` tells it
+            tick.ends = [None] * len(batch.items)
+            for i, (seq, n) in enumerate(batch.items):
+                if Bd:
+                    if not in_block[i]:
+                        tick.n_prefill += n
+                elif i not in spec_plan:
+                    if self.scheduler.on_dispatched(seq, n):
+                        tick.slots[seq.rid] = i
+                    else:
+                        tick.n_prefill += n
+                    tick.ends[i] = seq.num_computed
+            tick.batch, tick.spec_plan, tick.in_block = (batch, spec_plan,
+                                                         in_block)
+            tick.tok_pad, tick.decode, tick.ffn_mode = (tok_pad, decode,
+                                                        bool(ffn_mode))
+            tick.lens = (cu, dec_lens, this_lens)
+            tick.was_decode = was_decode
+            if self.quant_kv:
+                tick.pages = int((tables >= 0).sum())
+        return tick
+
+    def _harvest(self, cur: "_Tick", span) -> List[TokenEvent]:
+        """Wait for a dispatched tick and harvest it: the ids into the
+        sequences, events, prefix-cache hashes, books. `span` is the step
+        span that takes the tick's fields (None outside `step()`)."""
+        events = cur.events
+        if cur.batch is None:     # launched ahead, and only deadlines fell
+            self._update_gauges()
+            return events
+        batch, in_block = cur.batch, cur.in_block
+        decode, ffn_mode = cur.decode, cur.ffn_mode
+        cu, dec_lens, this_lens = cur.lens
+        B, Bd = self.max_batch, self.cfg.block_length
+        with _tracing.phase("serve.wait"):
+            all_arg = None if cur.all_arg is None else np.asarray(cur.all_arg)
+            nxt = np.asarray(cur.out)     # the step's one sync point
+            now = time.perf_counter_ns()
+            # the tick's device interval: a tick launched ahead starts
+            # when the one before it ends, not when it was called
+            t0 = max(cur.t0, self._device_free_ns)
+            self._device_free_ns = now
+            dur = (now - t0) * 1e-9
+            moe = None
+            if self.cfg.num_experts:
+                nxt, moe = nxt[:-3], [int(c) for c in nxt[-3:]]
+
+        with _tracing.phase("serve.harvest"):
+            spec_extra = sum(len(p) for p in cur.spec_plan.values())
             _emit("serving.step", dur_s=dur,
                   tokens=batch.total_tokens + spec_extra,
-                  batch=len(batch.items), prefill_tokens=n_prefill)
+                  batch=len(batch.items), prefill_tokens=cur.n_prefill)
             fields = {}
             if moe is not None:
                 fields = dict(zip(("moe_pairs", "moe_experts_hit",
@@ -1191,25 +1373,18 @@ class PagedServingEngine:
                             self.block_size, cfg.num_kv_heads, *pool)))
                 else:
                     walked = PA.mixed_work(
-                        dec_lens, this_lens, tok_pad, self.block_size,
+                        dec_lens, this_lens, cur.tok_pad, self.block_size,
                         cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads,
                         *pool, block_len=Bd)
                 fields.update(walked)
                 for name, n in walked.items():
                     self.stats[name] += n
-            tick.set_metadata(
-                batch=len(batch.items),
-                tokens=batch.total_tokens + spec_extra,
-                prefill_tokens=n_prefill,
-                kind=("decode" if decode else
-                      "block" if Bd and all(in_block) else "mixed"),
-                **fields)
             if _tracing.trace_enabled():
                 # per-request tick attribution: each traced request in the
                 # batch gets a span over this tick's device interval, so a
                 # request's TTFT decomposes into queue.wait + its prefill
                 # chunks (+ cow copies) and TPOT into decode ticks
-                for (seq, n), dec in zip(batch.items, was_decode):
+                for (seq, n), dec in zip(batch.items, cur.was_decode):
                     if seq.trace_id:
                         _tracing.record_span(
                             "decode.tick" if dec else "prefill.chunk",
@@ -1224,36 +1399,60 @@ class PagedServingEngine:
                       launch="decode" if decode else "mixed")
             if ffn_mode:
                 self.stats["ffn_steps"] += 1
-                if fused_tick:
+                if decode:
                     self.stats["fused_ticks"] += 1
                 _emit("pallas_ffn.step",
-                      launch="fused_tick" if fused_tick else "serving")
+                      launch="fused_tick" if decode else "serving")
             if self.quant_kv:
                 _emit("quant.kv_step",
                       tokens=batch.total_tokens * self.cfg.num_layers,
-                      pages=int((tables >= 0).sum()) * self.cfg.num_layers)
+                      pages=cur.pages * self.cfg.num_layers)
             self.stats["steps"] += 1
+            self.stats["ticks_ahead"] += cur.ahead
             self.stats["tokens_computed"] += batch.total_tokens + spec_extra
+            void = 0
             if Bd:
                 events.extend(self._harvest_blocks(
                     batch, in_block, nxt.reshape(B, Bd, 3)))
-                self._update_gauges()
-                return events
-
-            # harvest: a slot yields a token iff its chunk reached the end of
-            # the sequence's current tokens (final prefill chunk or decode row)
-            for i, (seq, n) in enumerate(batch.items):
-                props = spec_plan.get(i)
-                if props is not None:
-                    events.extend(self._harvest_spec(seq, props, int(cu[i]),
-                                                     all_arg))
-                    continue
-                self.scheduler.on_computed(seq, n)
-                if seq.num_computed < len(seq.tokens):
-                    continue   # mid-prefill: logits row is not a next token
-                events.append(self._emit_token(seq, int(nxt[i])))
+            else:
+                void = self._harvest_rows(cur, nxt, all_arg, events)
+            self.stats["ahead_void_rows"] += void
+            if span is not None:
+                span.set_metadata(
+                    batch=len(batch.items),
+                    tokens=batch.total_tokens + spec_extra,
+                    prefill_tokens=cur.n_prefill,
+                    kind=("decode" if decode else
+                          "block" if Bd and all(in_block) else "mixed"),
+                    ahead=int(cur.ahead), void_rows=void, **fields)
             self._update_gauges()
             return events
+
+    def _harvest_rows(self, cur: _Tick, nxt: np.ndarray, all_arg,
+                      events: List[TokenEvent]) -> int:
+        """An autoregressive tick's harvest: a slot yields a token iff its
+        chunk reached the end of the sequence's tokens (final prefill
+        chunk or decode row; `cur.slots`). Appends the events; returns the
+        rows computed and dropped."""
+        void = 0
+        for i, (seq, _n) in enumerate(cur.batch.items):
+            props = cur.spec_plan.get(i)
+            if props is not None:
+                events.extend(self._harvest_spec(
+                    seq, props, int(cur.lens[0][i]), all_arg))
+            elif seq.status == FINISHED:
+                # finished while this row was in flight (an end-of-sequence
+                # id or a deadline the host learnt a tick late): computed
+                # and dropped. Its pages are free already, which is safe in
+                # device order (whoever gets them runs after this tick),
+                # and are never hashed
+                void += 1
+            else:
+                self.scheduler.on_harvested(seq, cur.ends[i])
+                if seq.rid in cur.slots:
+                    events.append(self._emit_token(seq, int(nxt[i]),
+                                                   at=cur.ends[i]))
+        return void
 
     def _open_block(self, seq: Sequence):
         """Open the next block of a block-diffusion sequence: what its
@@ -1306,20 +1505,23 @@ class PagedServingEngine:
                 self.scheduler.on_computed(seq, Bd)
         return events
 
-    def _emit_token(self, seq: Sequence, tok: int) -> TokenEvent:
-        """Append one harvested token to a sequence and make its event: the
-        end-of-sequence token finishes it unsurfaced ("stop"), the
-        max_new_tokens-th finishes it ("length"). Token stamps stay on the
-        scheduler's clock (arrival and deadlines are time.monotonic()),
+    def _emit_token(self, seq: Sequence, tok: int,
+                    at: Optional[int] = None) -> TokenEvent:
+        """Give a sequence one harvested token (at position `at`, which
+        its tick's dispatch left unknown; None: appended) and make its
+        event: the end-of-sequence token finishes it unsurfaced ("stop"),
+        the max_new_tokens-th finishes it ("length"). Token stamps stay on
+        the scheduler's clock (arrival and deadlines are time.monotonic()),
         not on the span clock."""
         now = time.monotonic()
         first = seq.first_token_at is None
         if seq.eos >= 0 and tok == seq.eos:
-            self.scheduler.append_token(seq, tok)  # timestamps
-            seq.generated.pop()                    # eos not surfaced
-            seq.tokens.pop()
+            self.scheduler.append_token(seq, tok, at)  # timestamps
+            seq.generated.pop()                    # eos not surfaced,
+            # nor the position a row in flight has opened behind it
+            del seq.tokens[len(seq.prompt) + len(seq.generated):]
             return self._finish_event(seq, "stop")
-        self.scheduler.append_token(seq, tok)
+        self.scheduler.append_token(seq, tok, at)
         _emit("serving.token", rid=seq.rid, first=first,
               ttft_s=(now - seq.arrival) if first else None,
               tpot_s=None if first else now - seq._prev_token_at)
@@ -1396,7 +1598,9 @@ class PagedServingEngine:
 
     @property
     def engine_stats(self) -> dict:
-        """One merged host-side view (engine + scheduler + block pool)."""
+        """One merged host-side view (engine + scheduler + block pool), with
+        the tick in flight settled so that every dispatched row is in it."""
+        self._settle()
         out = {**self.stats, **self.scheduler.stats,
                "kv_utilization": round(self.blocks.utilization(), 4),
                "kv_page_bytes": self.kv_page_bytes,

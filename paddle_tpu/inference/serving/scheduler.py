@@ -43,7 +43,7 @@ from ...observability import tracing as _tracing
 from .block_manager import BlockManager, NoFreeBlocksError
 
 __all__ = ["RejectedError", "DeadlineExceededError", "Sequence",
-           "Completion", "ScheduledBatch", "Scheduler"]
+           "Completion", "ScheduledBatch", "Scheduler", "UNKNOWN"]
 
 flags.define_flag("serving_max_queue", 128,
                   "Serving admission control: submissions beyond this many "
@@ -64,6 +64,9 @@ class DeadlineExceededError(RuntimeError):
 
 
 WAITING, RUNNING, FINISHED = "waiting", "running", "finished"
+# in `Sequence.tokens`: a position a dispatched tick will yield, whose id
+# the host has not read yet (`Scheduler.on_dispatched`)
+UNKNOWN = -1
 
 
 @dataclass(eq=False)   # identity semantics: sequences live in sets/lists
@@ -230,6 +233,11 @@ class Scheduler:
         """Evict a running sequence: free its pages, reset to
         recompute-on-resume (the whole prompt+generated re-prefills when
         capacity returns — exactness over cache-migration complexity)."""
+        if seq.tokens[-1] == UNKNOWN:
+            raise RuntimeError(
+                f"sequence {seq.rid} has a row in flight: it re-prefills "
+                "from its ids, so the engine plans a tick that preempts "
+                "only with every tick harvested (next_fits)")
         self.blocks.free_sequence(seq.rid)
         seq.num_computed = 0
         seq.status = WAITING
@@ -349,17 +357,55 @@ class Scheduler:
         self.stats["scheduled_steps"] += 1 if items else 0
         return ScheduledBatch(items), expired
 
-    def on_computed(self, seq: Sequence, n: int):
-        """Commit a step's progress for one sequence and register freshly
-        completed cache blocks in the prefix cache."""
-        seq.num_computed += n
-        self.blocks.register_computed(seq.rid, seq.tokens, seq.num_computed)
+    def next_fits(self) -> bool:
+        """Whether the next `schedule()` can grow every running sequence
+        by its next chunk out of free pages, i.e. will preempt nobody
+        (admissions never preempt). An upper bound: each sequence is
+        charged its chunk at the whole token budget."""
+        need = 0
+        for seq in self.running:
+            n = self._chunk(seq, self.token_budget)
+            need += max(0, self.blocks.blocks_needed(seq.num_computed + n)
+                        - self.blocks.num_blocks_of(seq.rid))
+        return self.blocks.can_allocate(need)
 
-    def append_token(self, seq: Sequence, token: int):
-        """A harvested token extends the sequence (its KV is computed by
-        the NEXT step that schedules the sequence)."""
+    def on_dispatched(self, seq: Sequence, n: int) -> bool:
+        """The dispatch half of a tick's progress, by count: `n` rows of
+        `seq` are on their way, so `num_computed` advances now, and if
+        they reach the end of its tokens the tick yields it one more, a
+        position whose id stays `UNKNOWN` until the tick is harvested
+        (returns True). The scheduler can plan the next tick from this."""
+        seq.num_computed += n
+        if seq.num_computed < len(seq.tokens):
+            return False
+        seq.tokens.append(UNKNOWN)
+        return True
+
+    def on_harvested(self, seq: Sequence, upto: int):
+        """The harvest half: the tick that took `seq` to `upto` computed
+        positions has been read, so the pages they fill are registered in
+        the prefix cache. Every id below `upto` is known by now; the
+        block manager must never hash an `UNKNOWN` one."""
+        self.blocks.register_computed(seq.rid, seq.tokens, upto)
+
+    def on_computed(self, seq: Sequence, n: int):
+        """Both halves at once, at harvest: for a tick whose progress is
+        no count known at dispatch (a speculative chunk advances by the
+        accepted length, a block-diffusion block when it is committed)."""
+        seq.num_computed += n
+        self.on_harvested(seq, seq.num_computed)
+
+    def append_token(self, seq: Sequence, token: int,
+                     at: Optional[int] = None):
+        """At harvest: a token read from the device extends the sequence.
+        `at` is the position `on_dispatched` left `UNKNOWN` for it when the
+        tick was dispatched (its KV may already be on its way, computed by
+        the next tick from the id on the device); None appends it."""
         seq.generated.append(int(token))
-        seq.tokens.append(int(token))
+        if at is None:
+            seq.tokens.append(int(token))
+        else:
+            seq.tokens[at] = int(token)
         now = time.monotonic()
         if seq.first_token_at is None:
             seq.first_token_at = now

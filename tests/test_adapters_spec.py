@@ -391,6 +391,9 @@ class TestEngineAdapters:
         mixed = _run(eng, prompts, adapters=["t-a", None, "t-a", None])
         assert mixed[1] == base_out[1] and mixed[3] == base_out[3]
         assert mixed[0] != base_out[0] and mixed[2] != base_out[2]
+        # adapter rows are counts plus selectors the host holds: their
+        # ticks are launched ahead like any other
+        assert eng.stats["ticks_ahead"] > 0
 
     def test_mixed_batch_matches_solo_runs(self, tiny):
         """Segmented application: each adapter row in a 2-adapter mixed
@@ -502,6 +505,9 @@ class TestSpeculative:
         assert _run(eng, prompts, max_new=10) == base_out
         assert eng.stats["spec_ticks"] > 0
         assert 0.0 < eng.spec.acceptance_rate <= 1.0
+        # a speculative tick advances by the accepted length, which is no
+        # count the host holds at dispatch: none is launched ahead
+        assert eng.stats["ticks_ahead"] == 0
 
     def test_perfect_draft_full_acceptance(self, tiny):
         """Draft == target: every proposal is accepted, every tick emits

@@ -122,6 +122,9 @@ def test_engine_equals_the_reference_loop(tiny, prompt_len, steps, pallas):
     assert s["diff_rows"] == BD * (blocks + len(forwards))
     assert s["diff_tokens_unmasked"] == first + BD * (blocks - 1)
     assert s["decode_fast_steps"] == 0      # no one-row launch, ever
+    # and no tick launched ahead: the next block's ids, masks and quota are
+    # a host function of this tick's result
+    assert s["ticks_ahead"] == 0 == s["ahead_void_rows"]
 
 
 def test_chunked_prefill_cut_mid_prompt_and_two_sequences_at_different_steps(
