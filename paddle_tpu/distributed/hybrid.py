@@ -97,8 +97,10 @@ def param_specs(cfg: L.LlamaConfig) -> Dict[str, Any]:
     Layout: blocks leaves carry a leading 'pp' stage axis; projections are
     tp-sharded Megatron-style (wq/wk/wv/w1/w3 on the output dim, wo/w2 on the
     input dim); embed/lm_head are vocab-parallel; MoE experts are sharded over
-    'dp' (= the ep axis).
+    'dp' (= the ep axis). A config with a layer plan is refused: its
+    blocks are stacks by kind, not one [L, ...] stack to cut into stages.
     """
+    L.require_uniform(cfg, "distributed.hybrid")
     blocks = {
         "wq": P("pp", None, None, "tp"),
         "wk": P("pp", None, None, "tp"),
@@ -128,6 +130,7 @@ def shard_params(params: Dict[str, Any], mesh: Mesh, cfg):
     """Stage-stack + device_put with NamedShardings (host → HBM, laid out).
     cfg: LlamaConfig. (Generic Layers shard their params inside
     hybrid_generic.GenericHybridEngine — no call needed.)"""
+    L.require_uniform(cfg, "distributed.hybrid")
     pp = mesh.shape["pp"]
     stacked = stack_pipeline(params, pp)
     specs = param_specs(cfg)
@@ -353,6 +356,7 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
     Inside: GPipe pipeline over `num_microbatches`, TP/SP per block,
     vocab-parallel CE on the last stage, loss pre-scaled by 1/dp.
     """
+    L.require_uniform(cfg, "distributed.hybrid")
     M = num_microbatches
 
     def stage_fn(x, blocks_local, cos, sin):

@@ -187,6 +187,7 @@ class LLMPredictor:
                 f"mask; a block-diffusion config (block_length="
                 f"{cfg.block_length}) is served by "
                 f"inference.serving.PagedServingEngine")
+        L.require_uniform(cfg, "LLMPredictor")
         self.cfg = cfg
         if weight_dtype is not None:
             params = jax.tree.map(

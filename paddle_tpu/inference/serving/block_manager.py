@@ -21,7 +21,16 @@ of per-slot max_len reservations:
   hits until allocation pressure reclaims them (hash entries drop at
   reclaim, never silently);
 - utilization accounting for the observability gauges and the
-  scheduler's admission/preemption decisions.
+  scheduler's admission/preemption decisions;
+- for a model with sliding-window layers, a **second pool with a second
+  lifetime** (`window_blocks`, `window`): a sequence has one more block
+  table over it, whose pages come a chunk at a time (`ensure_capacity`) and
+  go back to the free list once every position in them lies more than
+  `window` - 1 behind the sequence's computed length (`release_behind`):
+  no query to come can see them. The table keeps its logical width, the
+  released entries read -1. Such pages hold the keys of the window layers
+  alone, so the prefix cache is off: a hit on the full pool's pages would
+  find no window keys beside them.
 
 Pure host-side bookkeeping: no jax imports, no device state. The engine
 owns the actual [num_blocks, KV, block_size, hd] cache arrays; block ids
@@ -46,7 +55,9 @@ def _chain_hash(prev_hash: int, tokens: Tuple[int, ...]) -> int:
 
 class BlockManager:
     def __init__(self, num_blocks: int, block_size: int,
-                 page_bytes: int = 0, hit_multiple: int = 1):
+                 page_bytes: int = 0, hit_multiple: int = 1,
+                 window_blocks: int = 0, window: int = 0,
+                 window_page_bytes: int = 0):
         if num_blocks < 1 or block_size < 1:
             raise ValueError(f"need num_blocks>=1 and block_size>=1, got "
                              f"{num_blocks}/{block_size}")
@@ -66,6 +77,18 @@ class BlockManager:
         # engine so byte gauges and router placement stay truthful when
         # int8 pages make a "block" 2-4x cheaper than its fp32 twin
         self.page_bytes = int(page_bytes)
+        # the window pool (0 blocks: the model has no window layer and
+        # nothing below differs from what it was): its free list, each
+        # sequence's table over it (-1 where a page went back) and how many
+        # leading entries of it were released
+        if bool(window_blocks) != bool(window):
+            raise ValueError("window_blocks and window come together")
+        self.window_blocks = int(window_blocks)
+        self.window = int(window)
+        self.window_page_bytes = int(window_page_bytes)
+        self._wfree: List[int] = list(range(self.window_blocks))[::-1]
+        self._wtables: Dict[int, List[int]] = {}
+        self._wreleased: Dict[int, int] = {}
         self._free: List[int] = list(range(num_blocks))[::-1]  # pop() = lowest
         self._refs: Dict[int, int] = {}
         # content-addressed full blocks: chain hash -> block id, the inverse
@@ -97,6 +120,8 @@ class BlockManager:
                       "prefix_hit_tokens": 0, "cow_copies": 0,
                       "cache_evictions": 0, "cow_purged": 0,
                       "adopted_pages": 0}
+        if self.window_blocks:
+            self.stats.update(window_allocs=0, window_released=0)
 
     # -- capacity ---------------------------------------------------------
     def num_free(self) -> int:
@@ -105,27 +130,43 @@ class BlockManager:
     def num_allocated(self) -> int:
         return self.num_blocks - self.num_free()
 
+    def window_allocated(self) -> int:
+        return self.window_blocks - len(self._wfree)
+
     def utilization(self) -> float:
-        return self.num_allocated() / self.num_blocks
+        """Pages allocated over pages held, both pools together."""
+        return ((self.num_allocated() + self.window_allocated())
+                / (self.num_blocks + self.window_blocks))
 
     def bytes_total(self) -> int:
-        """Device bytes of the whole page pool (0 when the engine did not
+        """Device bytes of the page pools (0 when the engine did not
         report a page size — e.g. unit tests building bare managers),
         plus any registered extra paged residency (adapter slot packs)."""
         extra = self.extra_bytes()[1] if self.extra_bytes else 0
-        return self.num_blocks * self.page_bytes + extra
+        return (self.num_blocks * self.page_bytes
+                + self.window_blocks * self.window_page_bytes + extra)
 
     def bytes_in_use(self) -> int:
         """Device bytes behind allocated pages, dtype-aware, plus any
         registered extra paged residency (adapter slot packs)."""
         extra = self.extra_bytes()[0] if self.extra_bytes else 0
-        return self.num_allocated() * self.page_bytes + extra
+        return (self.num_allocated() * self.page_bytes
+                + self.window_allocated() * self.window_page_bytes + extra)
 
     def blocks_needed(self, num_tokens: int) -> int:
         return -(-int(num_tokens) // self.block_size)
 
-    def can_allocate(self, n_blocks: int) -> bool:
-        return self.num_free() >= n_blocks
+    def can_allocate(self, n_blocks: int, n_window: int = 0) -> bool:
+        return (self.num_free() >= n_blocks
+                and len(self._wfree) >= n_window)
+
+    def growth(self, seq_id: int, num_tokens: int) -> Tuple[int, int]:
+        """(pages of the pool, pages of the window pool) that
+        `ensure_capacity(seq_id, num_tokens)` would take."""
+        need = self.blocks_needed(num_tokens)
+        return (max(0, need - len(self._tables[seq_id])),
+                max(0, need - len(self._wtables[seq_id]))
+                if self.window_blocks else 0)
 
     # -- raw page pool ----------------------------------------------------
     def _drop_hash(self, blk: int):
@@ -180,6 +221,19 @@ class BlockManager:
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id} already has a block table")
         tokens = [int(t) for t in tokens]
+        if self.window_blocks:
+            # no prefix cache beside a window pool; the window table fills
+            # a chunk at a time (`ensure_capacity`)
+            need = self.blocks_needed(len(tokens))
+            if not self.can_allocate(need):
+                raise NoFreeBlocksError(
+                    f"cannot map sequence {seq_id}: {need} blocks "
+                    f"({self.num_free()} free)")
+            self._tables[seq_id] = [self._alloc_block() for _ in range(need)]
+            self._hashed[seq_id] = (0, 0)
+            self._wtables[seq_id] = []
+            self._wreleased[seq_id] = 0
+            return 0
         bs = self.block_size
         table: List[int] = []
         new_copies: List[Tuple[int, int]] = []
@@ -278,16 +332,48 @@ class BlockManager:
         NoFreeBlocksError (leaving the table unchanged) when the pool is
         exhausted — the scheduler's preemption trigger."""
         table = self._tables[seq_id]
-        need = self.blocks_needed(num_tokens) - len(table)
-        if need <= 0:
-            return 0
-        if not self.can_allocate(need):
-            raise NoFreeBlocksError(
-                f"cannot grow sequence {seq_id} by {need} blocks "
-                f"({self.num_free()} free)")
+        if self.window_blocks:
+            need, wneed = self.growth(seq_id, num_tokens)
+            if not self.can_allocate(need, wneed):
+                raise NoFreeBlocksError(
+                    f"cannot grow sequence {seq_id} by {need} + {wneed} "
+                    f"window blocks ({self.num_free()} + "
+                    f"{len(self._wfree)} free)")
+            wtable = self._wtables[seq_id]
+            for _ in range(wneed):
+                wtable.append(self._wfree.pop())
+            self.stats["window_allocs"] += wneed
+        else:
+            need = self.blocks_needed(num_tokens) - len(table)
+            if need <= 0:
+                return 0
+            if not self.can_allocate(need):
+                raise NoFreeBlocksError(
+                    f"cannot grow sequence {seq_id} by {need} blocks "
+                    f"({self.num_free()} free)")
         for _ in range(need):
             table.append(self._alloc_block())
         return need
+
+    def release_behind(self, seq_id: int, num_computed: int) -> int:
+        """Give back the window-pool pages of `seq_id` that no query to
+        come can see: the next one sits at position `num_computed` or
+        later and sees no key before `num_computed` - (window - 1), so a
+        page whose last position lies before that goes back to the free
+        list and its table entry reads -1. Returns pages released."""
+        if not self.window_blocks or seq_id not in self._wtables:
+            return 0
+        wtable = self._wtables[seq_id]
+        first = self._wreleased[seq_id]
+        upto = min(max(0, num_computed - (self.window - 1))
+                   // self.block_size, len(wtable))
+        for p in range(first, upto):
+            self._wfree.append(wtable[p])
+            wtable[p] = -1
+        if upto > first:
+            self._wreleased[seq_id] = upto
+            self.stats["window_released"] += upto - first
+        return max(0, upto - first)
 
     def register_computed(self, seq_id: int, tokens: Sequence[int],
                           num_computed: int):
@@ -299,7 +385,7 @@ class BlockManager:
         `num_computed` has to be known."""
         bs = self.block_size
         table = self._tables.get(seq_id)
-        if table is None:
+        if table is None or self.window_blocks:
             return
         bi, prev_h = self._hashed[seq_id]
         full = min(num_computed, len(tokens)) // bs
@@ -319,6 +405,9 @@ class BlockManager:
     def free_sequence(self, seq_id: int):
         table = self._tables.pop(seq_id, None)
         self._hashed.pop(seq_id, None)
+        self._wreleased.pop(seq_id, None)
+        self._wfree.extend(p for p in self._wtables.pop(seq_id, ())
+                           if p >= 0)
         if not table:
             return
         if self._pending_copies:
@@ -342,6 +431,11 @@ class BlockManager:
 
     def block_table(self, seq_id: int) -> List[int]:
         return list(self._tables[seq_id])
+
+    def window_table(self, seq_id: int) -> List[int]:
+        """The sequence's table over the window pool: as wide as what it
+        has been grown to, -1 where a page was released."""
+        return list(self._wtables[seq_id])
 
     def num_blocks_of(self, seq_id: int) -> int:
         return len(self._tables[seq_id])

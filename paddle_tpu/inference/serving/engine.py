@@ -53,6 +53,20 @@ chunk the ``token_budget`` one; both run the mixed attention launch.
 Sampling (temperature > 0), a draft model, LoRA adapters and int8 pages
 are refused with a block-diffusion config.
 
+A layer plan (``cfg.layer_plan``: Laguna-XS.2's leading dense layer, window
+and full attention with their own head counts and ropes, sparse layers
+beside a shared expert) runs through the same tick: the layer loop is
+``llama.scan_plan`` over the plan's kinds, one traced body a kind. With
+window layers the pages live in two pools with two lifetimes: the full
+layers' ``[L_full, num_blocks, ...]``, which keeps every page of a sequence,
+and the window layers' ``[L_window, window_blocks, ...]``, whose pages go back
+once every position in them lies more than ``sliding_window`` - 1 behind the
+sequence's computed length (``BlockManager.release_behind``, after a tick is
+harvested). A sequence has a block table over each; the prefix cache is off
+(``engine_stats["prefix_cache"]`` says so). With a plan, int8 pages, weight
+quantisation, LoRA adapters, a draft model, the fused FFN and
+``extract_pages`` / ``ingest_pages`` are refused.
+
 SLO metrics (TTFT/TPOT histograms, queue-depth and KV-block-utilization
 gauges, admit/preempt/shed counters + flight-recorder events) flow
 through ``observability.emit`` — ``observability.summary()["serving"]``
@@ -100,6 +114,12 @@ flags.define_flag("serving_max_batch", 8,
                   "Default concurrent sequence slots per step when the "
                   "PagedServingEngine ctor leaves max_batch unset.")
 
+flags.define_flag("serving_window_blocks", 0,
+                  "Pages of the window layers' pool (a model with "
+                  "sliding-window layers) when the PagedServingEngine ctor "
+                  "leaves window_blocks unset; 0 = max_batch sequences' "
+                  "windows and chunks.")
+
 __all__ = ["PagedServingEngine", "TokenEvent", "RejectedError",
            "DeadlineExceededError"]
 
@@ -146,6 +166,8 @@ class _Tick:
     ffn_mode: bool = False
     lens: Tuple[Any, ...] = ()      # cu, dec_lens, this_lens
     pages: int = 0                  # table entries assigned (int8 pages)
+    # pages allocated in (the pool, the window pool) when it was launched
+    pool_pages: Tuple[int, int] = (0, 0)
 
 
 def _sample_rows(logits, keys, temps, top_ps, top_k: int):
@@ -225,7 +247,23 @@ class PagedServingEngine:
                  pallas_ffn: Optional[bool] = None,
                  adapter_slots: Optional[int] = None,
                  draft: Optional[Any] = None,
-                 spec_k: Optional[int] = None):
+                 spec_k: Optional[int] = None,
+                 window_blocks: Optional[int] = None):
+        plan = bool(cfg.layer_plan)
+        if plan:
+            asked = {"a draft model": draft is not None,
+                     "pallas_ffn": bool(pallas_ffn),
+                     "quant_mode": bool(Q.resolve_quant_mode(quant_mode)),
+                     "quant_kv (int8 pages)": bool(
+                         flags.flag_value("quant_kv_cache")
+                         if quant_kv is None else quant_kv),
+                     "adapter_slots (LoRA)": adapter_slots is not None}
+            if any(asked.values()):
+                raise NotImplementedError(
+                    "a config with a layer plan is served in fp weights and "
+                    "fp pages, one token a row: "
+                    f"{[k for k, v in asked.items() if v]} were never judged "
+                    "against a reference under a plan; drop them")
         if cfg.num_experts and (draft is not None or pallas_ffn
                                 or Q.resolve_quant_mode(quant_mode)):
             raise NotImplementedError(
@@ -307,13 +345,38 @@ class PagedServingEngine:
         # the per-page f32 scale rows when quantized) — keeps the byte
         # gauges and the router's least-loaded placement truthful
         kvh, hd = cfg.num_kv_heads, cfg.head_dim
-        self.kv_page_bytes = (2 * cfg.num_layers * kvh * self.block_size
-                              * hd * np.dtype(self.cache_dtype).itemsize)
+        # layers whose pages live in the pool and in the window pool (a
+        # config without window layers: all of them, and none)
+        n_window = sum(s.attn == "window" for s in cfg.layer_plan)
+        self._pool_layers = (cfg.num_layers - n_window, n_window)
+        layer_bytes = (2 * kvh * self.block_size * hd
+                       * np.dtype(self.cache_dtype).itemsize)
+        self.kv_page_bytes = cfg.num_layers * layer_bytes
         if self.quant_kv:
             self.kv_page_bytes += 2 * cfg.num_layers * kvh * 4
-        self.blocks = BlockManager(self.num_blocks, self.block_size,
-                                   page_bytes=self.kv_page_bytes,
-                                   hit_multiple=max(Bd, 1))
+        if n_window:
+            # a sequence's window pages: its window, the chunk in flight,
+            # and the partly filled pages at both ends
+            span = -(-(cfg.sliding_window + self.token_budget)
+                     // self.block_size) + 2
+            if window_blocks is None:
+                window_blocks = (
+                    int(flags.flag_value("serving_window_blocks"))
+                    or self.max_batch * span)
+            if window_blocks < span:
+                raise ValueError(
+                    f"window_blocks={window_blocks} cannot hold one "
+                    f"sequence's window and chunk ({span} pages)")
+        elif window_blocks:
+            raise ValueError("window_blocks without a window layer")
+        self.window_blocks = int(window_blocks or 0)
+        self.blocks = BlockManager(
+            self.num_blocks, self.block_size,
+            page_bytes=self._pool_layers[0] * layer_bytes
+            if n_window else self.kv_page_bytes,
+            hit_multiple=max(Bd, 1), window_blocks=self.window_blocks,
+            window=cfg.sliding_window if n_window else 0,
+            window_page_bytes=n_window * layer_bytes)
         self.scheduler = Scheduler(self.blocks, self.token_budget,
                                    self.max_batch,
                                    prefill_chunk=prefill_chunk,
@@ -336,6 +399,18 @@ class PagedServingEngine:
             # least one row, most rows on one expert in one layer
             self.stats.update(moe_pairs=0, moe_experts_hit=0,
                               moe_max_load=0)
+        if plan:
+            # keys and (row, key) pairs inside the masks, summed over ticks
+            # and over the layers of the kind, and the keys a causal mask
+            # would show in all layers (`_plan_keys`)
+            self.stats.update(attn_keys_full=0, attn_keys_window=0,
+                              attn_keys_causal=0, attn_pairs_full=0,
+                              attn_pairs_window=0)
+        if n_window:
+            # pages allocated in each pool, summed over ticks (their ratio
+            # is what the window pool saves), and pages given back so far
+            self.stats.update(full_pages_live=0, window_pages_live=0,
+                              window_pages_released=0)
         if Bd:
             # block diffusion, summed over ticks: sequence-forwards of each
             # kind (one sequence's block through one tick), blocks
@@ -371,8 +446,8 @@ class PagedServingEngine:
         # path elsewhere; True = force (interpret mode off-TPU — how CPU CI
         # drives it; a bad geometry fails here); False = the stock
         # reference
-        geometry = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                    self.block_size)
+        geometry = (max([s.heads for s in cfg.layer_plan] or [cfg.num_heads]),
+                    cfg.num_kv_heads, cfg.head_dim, self.block_size)
         if pallas and not PA.supported(*geometry):
             raise ValueError(
                 f"pallas=True forced but geometry H={cfg.num_heads} "
@@ -384,6 +459,11 @@ class PagedServingEngine:
         # whether a tick's read is one of the whole-page walks (their
         # counters are reckoned only then) or the BlockSpec walk
         self._whole_pages = self.pallas and PA.whole_pages(cfg.head_dim)
+        # the (query heads, window) of each attention launch a tick makes:
+        # the config's own, or each that occurs in its plan
+        self._launches = tuple(dict.fromkeys(
+            [(s.heads, cfg.sliding_window if s.attn == "window" else 0)
+             for s in cfg.layer_plan] or [(cfg.num_heads, 0)]))
         # fused-FFN routing mirrors the attention tri-state: None =
         # FLAGS_pallas_ffn per tick; True = force (interpret off-TPU);
         # False = off. Forced mode validates params + geometry eagerly.
@@ -407,9 +487,17 @@ class PagedServingEngine:
         # device state: the page pool, stacked over layers. The tick
         # donates it, carries it through its layer loop and returns it:
         # one buffer, updated in place
-        shape = (cfg.num_layers, self.num_blocks, kvh, self.block_size, hd)
+        shape = (self._pool_layers[0], self.num_blocks, kvh, self.block_size,
+                 hd)
         self._key_cache = jnp.zeros(shape, self.cache_dtype)
         self._value_cache = jnp.zeros(shape, self.cache_dtype)
+        if n_window:
+            # two pools ride the tick's carry: (full layers', window layers')
+            wshape = (n_window, self.window_blocks) + shape[2:]
+            self._key_cache = (self._key_cache,
+                               jnp.zeros(wshape, self.cache_dtype))
+            self._value_cache = (self._value_cache,
+                                 jnp.zeros(wshape, self.cache_dtype))
         if self.quant_kv:
             # static calibrated absmax per (layer, kv head) -> per-head
             # quant multipliers [L, KV] for the append path and GENUINELY
@@ -435,11 +523,18 @@ class PagedServingEngine:
             self._kv_scales = None
         # rope table in the kernel's stacked [2, 1, S, hd] layout (only the
         # first hd//2 lanes of each are read)
-        cos, sin = L.rope_cos_sin(jnp.arange(self.max_len), hd,
-                                  cfg.rope_theta)
-        self._rope_emb = jnp.stack([
-            jnp.concatenate([cos, cos], -1)[None],
-            jnp.concatenate([sin, sin], -1)[None]])
+        def rope_emb(cos, sin):
+            return jnp.stack([jnp.concatenate([cos, cos], -1)[None],
+                              jnp.concatenate([sin, sin], -1)[None]])
+        if plan:
+            # one table a rope of the plan, as wide as what it rotates
+            self._ropes = tuple(dict.fromkeys(s.rope for s in cfg.kinds))
+            self._rope_emb = tuple(
+                rope_emb(*L.rope_table(jnp.arange(self.max_len), hd, r))
+                for r in self._ropes)
+        else:
+            self._rope_emb = rope_emb(*L.rope_cos_sin(
+                jnp.arange(self.max_len), hd, cfg.rope_theta))
         # executables keyed by what differs between two ticks of this
         # engine: (token-budget, batch-slots, decode, ffn-mode, adapter
         # rank classes, spec-mode); `decode` = every chunk is one token
@@ -496,6 +591,10 @@ class PagedServingEngine:
                     "denoising_steps belongs to a block-diffusion config "
                     "(block_length > 0); this engine's model is "
                     "autoregressive")
+            if adapter is not None and self.cfg.layer_plan:
+                raise NotImplementedError(
+                    "LoRA adapters with a layer plan were never judged "
+                    "against a reference; submit without one")
             if total > self.max_len:
                 raise ValueError(
                     f"prompt {len(tokens)} + new {max_new_tokens} "
@@ -591,6 +690,7 @@ class PagedServingEngine:
         when this pool cannot serve the complete chain (never a partial
         payload — the receiver recomputes instead). Quantized engines
         export int8 pages plus their per-page dequant scale rows."""
+        self._refuse_page_handoff("extract_pages")
         self._settle()
         chain = self.blocks.prefix_chain(tokens)
         if not chain:
@@ -613,6 +713,14 @@ class PagedServingEngine:
                 jnp.take(self._kv_scales[3], ids, axis=1))
         return out
 
+    def _refuse_page_handoff(self, what: str):
+        if self.cfg.layer_plan:
+            raise NotImplementedError(
+                f"{what} with a layer plan: pages are handed off by prefix "
+                "hash over one pool [L, ...]; a plan's layers lie in stacks "
+                "by kind and, with window layers, in two pools of which one "
+                "keeps no prefix")
+
     def ingest_pages(self, payload: Dict[str, Any]) -> int:
         """Adopt migrated KV pages into this engine's pool and device
         caches. The pages park in the prefix cache exactly like locally
@@ -622,6 +730,7 @@ class PagedServingEngine:
         holds). Returns pages adopted (0 = all already present). Raises
         ValueError on cache-geometry/dtype mismatch (heterogeneous
         pools must recompute, not adopt)."""
+        self._refuse_page_handoff("ingest_pages")
         self._settle()
         if payload["dtype"] != np.dtype(self.cache_dtype).name:
             raise ValueError(
@@ -714,7 +823,7 @@ class PagedServingEngine:
         """Host-side fused-FFN dispatch for this tick: (on, fallback
         reason). None re-reads FLAGS_pallas_ffn every tick; the result
         rides the executable cache key so flag flips retrace exactly once."""
-        if self.pallas_ffn is False:
+        if self.pallas_ffn is False or self.cfg.layer_plan:
             return False, None
         if self.pallas_ffn:      # forced (params+geometry validated at init)
             return True, None
@@ -857,21 +966,29 @@ class PagedServingEngine:
                         x = x + Q.matmul_param(gate, lp, "w2")
                 return (x, kcs, vcs), None
 
-            # scanned over: the layer index and what a layer only reads.
-            # The expert matrices stay whole: the expert kernel finds a
-            # layer's by its index, like the page pool's readers
-            experts = {n: params["blocks"][n] for n in
-                       (("w1", "w3", "w2") if cfg.num_experts else ())}
-            xs = (jnp.arange(cfg.num_layers, dtype=jnp.int32),
-                  {n: v for n, v in params["blocks"].items()
-                   if n not in experts})
-            if quant_kv:
-                xs = xs + tuple(kv_scales)   # kq, vq [L,KV]; kdq,vdq [L,nb,KV]
-            # stacked adapter packs ride the layer scan like param leaves
-            xs = xs + tuple(a["packs"] for a in ad_args)
-            with jax.named_scope("layers"):
-                (x, kcs, vcs), loads = lax.scan(
-                    body, (x, key_cache, value_cache), xs)
+            if cfg.layer_plan:
+                with jax.named_scope("layers"):
+                    x, kcs, vcs, loads = self._plan_layers(
+                        params, x, key_cache, value_cache, block_tables,
+                        cu_seqlens_q, seq_lens_decoder, seq_lens_this_time,
+                        rope_emb, valid, use_pallas)
+            else:
+                # scanned over: the layer index and what a layer only
+                # reads. The expert matrices stay whole: the expert kernel
+                # finds a layer's by its index, like the page pool's readers
+                experts = {n: params["blocks"][n] for n in
+                           (("w1", "w3", "w2") if cfg.num_experts else ())}
+                xs = (jnp.arange(cfg.num_layers, dtype=jnp.int32),
+                      {n: v for n, v in params["blocks"].items()
+                       if n not in experts})
+                if quant_kv:
+                    # kq, vq [L,KV]; kdq,vdq [L,nb,KV]
+                    xs = xs + tuple(kv_scales)
+                # stacked adapter packs ride the layer scan like param leaves
+                xs = xs + tuple(a["packs"] for a in ad_args)
+                with jax.named_scope("layers"):
+                    (x, kcs, vcs), loads = lax.scan(
+                        body, (x, key_cache, value_cache), xs)
             with jax.named_scope("head"):
                 # last-token hidden state per slot (cu[1:]-1; idle slots
                 # gather garbage the host never reads); under block
@@ -931,6 +1048,86 @@ class PagedServingEngine:
             return nxt, kcs, vcs
 
         return step_fn
+
+    def _plan_layers(self, params, x, key_cache, value_cache, block_tables,
+                     cu, past, this, rope_emb, valid, use_pallas):
+        """The tick's layer loop under a layer plan (`llama.scan_plan`):
+        one body a kind, which finds its pages in its attention's pool
+        (`key_cache` / `value_cache` / `block_tables` are (full, window)
+        pairs with window layers, else the one pool) by the layer's place
+        in that pool, its rope's table among `rope_emb`, and its experts
+        in its kind's whole stack by its place there. Scopes as the
+        uniform tick's, with `paged_attention_full` / `_window` inside
+        `paged_attention`, `attn_gate`, and `shared_expert` inside `moe`.
+        Returns (x, key_cache, value_cache, (experts hit, largest load))."""
+        cfg = self.cfg
+        kinds, kind_of = cfg.kinds, cfg.kind_of_layer
+        two = isinstance(key_cache, tuple)
+        pools_k = key_cache if two else (key_cache,)
+        pools_v = value_cache if two else (value_cache,)
+        tables = block_tables if two else (block_tables,)
+        expert_names = ("w1", "w3", "w2")
+        stacks, experts = [], []
+        for k, spec in enumerate(kinds):
+            sparse = spec.ffn == "sparse"
+            leaves = params["blocks"][k]
+            experts.append({n: leaves[n] for n in expert_names} if sparse
+                           else {})
+            layers = [i for i, kk in enumerate(kind_of) if kk == k]
+            # the layer's place among the layers of its pool
+            same = [i for i, s in enumerate(cfg.layer_plan) if not two
+                    or (s.attn == "window") == (spec.attn == "window")]
+            stacks.append({
+                "lp": {n: v for n, v in leaves.items()
+                       if not (sparse and n in expert_names)},
+                "place": jnp.arange(len(layers), dtype=jnp.int32),
+                "page_layer": jnp.asarray([same.index(i) for i in layers],
+                                          jnp.int32)})
+
+        def body(kind, carry, leaves):
+            spec = kinds[kind]
+            x, pk, pv, hit, top = carry
+            lp = leaves["lp"]
+            pool = int(two and spec.attn == "window")
+            rot = int(cfg.head_dim * spec.rope.partial)
+            with jax.named_scope("qkv"):
+                h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+                qkv = jnp.concatenate(
+                    [Q.matmul_param(h, lp, n) for n in ("wq", "wk", "wv")],
+                    axis=-1)
+            o, _, kc, vc = paged_layer_attention(
+                qkv, pk[pool], pv[pool], leaves["page_layer"], past, this,
+                cu, tables[pool],
+                rope_emb=rope_emb[self._ropes.index(spec.rope)],
+                use_neox_style=True, use_pallas=use_pallas,
+                window=cfg.sliding_window if spec.attn == "window" else 0,
+                rotary_dim=rot if rot < cfg.head_dim else 0, kind=spec.attn)
+            pk = pk[:pool] + (kc,) + pk[pool + 1:]
+            pv = pv[:pool] + (vc,) + pv[pool + 1:]
+            if cfg.attn_gate:
+                o = L.attn_gated(o.reshape(o.shape[0], spec.heads, -1), h,
+                                 lp).reshape(o.shape)
+            with jax.named_scope("attn_out"):
+                x = x + Q.matmul_param(o, lp, "wo")
+            if spec.ffn == "sparse":
+                with jax.named_scope("moe"):
+                    h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+                    y, load = L.routed_ffn_load(
+                        h, {**lp, **experts[kind]}, cfg, valid,
+                        layer=leaves["place"])
+                    x = x + y
+                    hit = hit + jnp.sum(load > 0, dtype=jnp.int32)
+                    top = jnp.maximum(top, jnp.max(load))
+            else:
+                with jax.named_scope("ffn"):
+                    h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+                    x = x + L.ffn(h, lp)
+            return x, pk, pv, hit, top
+
+        zero = jnp.zeros((), jnp.int32)
+        x, pk, pv, hit, top = L.scan_plan(
+            cfg, body, (x, pools_k, pools_v, zero, zero), stacks)
+        return (x, pk if two else pk[0], pv if two else pv[0], (hit, top))
 
     def _get_step_fn(self, tok_pad: int, B: int, decode: bool = False,
                      ffn_mode=False, ad_sig: Tuple[int, ...] = (),
@@ -1195,6 +1392,8 @@ class PagedServingEngine:
             dec_lens = np.zeros((B,), np.int32)
             this_lens = np.zeros((B,), np.int32)
             tables = np.full((B, self.max_blocks_per_seq), -1, np.int32)
+            wtables = (np.full_like(tables, -1) if self.window_blocks
+                       else None)
             temps = np.ones((B,), np.float32)
             top_ps = np.ones((B,), np.float32)
             keys = np.zeros((B, 2), np.uint32)
@@ -1227,6 +1426,9 @@ class PagedServingEngine:
                 this_lens[i] = n
                 row = self.blocks.block_table(seq.rid)
                 tables[i, :len(row)] = row
+                if wtables is not None:
+                    row = self.blocks.window_table(seq.rid)
+                    wtables[i, :len(row)] = row
                 if seq.temperature > 0.0:
                     greedy[i] = False
                     temps[i] = seq.temperature
@@ -1272,6 +1474,8 @@ class PagedServingEngine:
             # chip, PERF.md PR 30), and nothing writes them afterwards.
             # `prev`/`feed` are always there, so a tick launched ahead runs
             # the executable every other tick of its shape runs
+            if wtables is not None:
+                tables = (tables, wtables)
             out = fn(self.params, self._key_cache, self._value_cache,
                      self._kv_scales, tokens, tables, cu, dec_lens,
                      this_lens, self._rope_emb, temps, top_ps, keys,
@@ -1317,6 +1521,9 @@ class PagedServingEngine:
             tick.was_decode = was_decode
             if self.quant_kv:
                 tick.pages = int((tables >= 0).sum())
+            if self.window_blocks:
+                tick.pool_pages = (self.blocks.num_allocated(),
+                                   self.blocks.window_allocated())
         return tick
 
     def _harvest(self, cur: "_Tick", span) -> List[TokenEvent]:
@@ -1365,20 +1572,39 @@ class PagedServingEngine:
                 cfg = self.cfg
                 pool = (cfg.head_dim, np.dtype(self.cache_dtype).itemsize,
                         self.max_blocks_per_seq)
-                if decode:
-                    walked = dict(zip(
-                        ("attn_pages_live", "attn_pages_fetched"),
-                        PA.decode_pages_walked(
-                            (dec_lens + this_lens)[this_lens > 0],
-                            self.block_size, cfg.num_kv_heads, *pool)))
-                else:
-                    walked = PA.mixed_work(
-                        dec_lens, this_lens, cur.tok_pad, self.block_size,
-                        cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads,
-                        *pool, block_len=Bd)
+                # one launch a kind of attention, summed
+                walked: Dict[str, int] = {}
+                for heads, window in self._launches:
+                    if decode:
+                        one = dict(zip(
+                            ("attn_pages_live", "attn_pages_fetched"),
+                            PA.decode_pages_walked(
+                                (dec_lens + this_lens)[this_lens > 0],
+                                self.block_size, cfg.num_kv_heads, *pool,
+                                window=window)))
+                    else:
+                        one = PA.mixed_work(
+                            dec_lens, this_lens, cur.tok_pad,
+                            self.block_size, cfg.num_kv_heads,
+                            heads // cfg.num_kv_heads, *pool, block_len=Bd,
+                            window=window)
+                    for name, n in one.items():
+                        walked[name] = walked.get(name, 0) + n
                 fields.update(walked)
                 for name, n in walked.items():
                     self.stats[name] += n
+            if self.cfg.layer_plan:
+                keys = self._plan_keys(dec_lens, this_lens)
+                if self.window_blocks:
+                    keys.update(
+                        full_pages_live=cur.pool_pages[0],
+                        window_pages_live=cur.pool_pages[1])
+                fields.update(keys)
+                for name, n in keys.items():
+                    self.stats[name] += n
+                if self.window_blocks:
+                    self.stats["window_pages_released"] = \
+                        self.blocks.stats["window_released"]
             if _tracing.trace_enabled():
                 # per-request tick attribution: each traced request in the
                 # batch gets a span over this tick's device interval, so a
@@ -1427,6 +1653,36 @@ class PagedServingEngine:
                     ahead=int(cur.ahead), void_rows=void, **fields)
             self._update_gauges()
             return events
+
+    def _plan_keys(self, past: np.ndarray, this: np.ndarray) -> dict:
+        """One tick's keys inside the masks of a layer plan, from the
+        host's own lengths, summed over the layers of the kind. `keys`:
+        the distinct keys a sequence's rows see (a chunk's rows share
+        theirs): past + this in a full layer, of those the ones from
+        position past - (sliding_window - 1) on in a window layer;
+        `attn_keys_causal` is what a causal mask would show in every
+        layer. `pairs`: the (query row, key) pairs, a row at position p
+        sees p + 1 keys in a full layer and min(p + 1, sliding_window) in
+        a window layer."""
+        cfg = self.cfg
+        n_full, n_window = self._pool_layers
+        live = this > 0
+        past, this = past[live].astype(np.int64), this[live].astype(np.int64)
+        keys = int((past + this).sum())
+        pairs = int((this * past + this * (this + 1) // 2).sum())
+        wkeys = wpairs = 0
+        if n_window:
+            W = cfg.sliding_window
+            wkeys = keys - int(np.maximum(past - (W - 1), 0).sum())
+            # the first `under` rows of a chunk see fewer than W keys
+            under = np.clip(W - 1 - past, 0, this)
+            wpairs = int((under * (past + 1) + under * (under - 1) // 2
+                          + (this - under) * W).sum())
+        return {"attn_keys_full": n_full * keys,
+                "attn_keys_window": n_window * wkeys,
+                "attn_keys_causal": cfg.num_layers * keys,
+                "attn_pairs_full": n_full * pairs,
+                "attn_pairs_window": n_window * wpairs}
 
     def _harvest_rows(self, cur: _Tick, nxt: np.ndarray, all_arg,
                       events: List[TokenEvent]) -> int:
@@ -1612,4 +1868,7 @@ class PagedServingEngine:
                "adapter_evictions": self.adapters.stats["evictions"]}
         if self.spec is not None:
             out["spec_acceptance_rate"] = self.spec.acceptance_rate
+        if self.window_blocks:
+            out["prefix_cache"] = ("off: the window pool keeps no page "
+                                   "behind a window for a prefix hit to map")
         return out

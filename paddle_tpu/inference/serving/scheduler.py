@@ -15,7 +15,10 @@ request mix into one fixed-shape compiled program):
 - **preemption under block exhaustion**: when the KV pool cannot grow a
   running sequence, the lowest-priority / youngest sequence is evicted —
   its pages freed, its state reset to recompute-on-resume (prompt +
-  generated tokens re-prefill when capacity returns, numerically exact);
+  generated tokens re-prefill when capacity returns, numerically exact).
+  With a window pool beside the pool (a model with sliding-window layers)
+  admission, growth and preemption look at both: a sequence runs only
+  with its pages in each;
 - **deadlines & cancellation**: per-request absolute deadlines checked at
   every schedule point; expired or cancelled requests free their pages
   immediately and finish with reason ``"deadline"`` / ``"cancelled"``;
@@ -345,6 +348,15 @@ class Scheduler:
                 seq.num_computed = cached
                 _emit("serving.prefix_hit", rid=seq.rid, tokens=cached)
             n = self._chunk(seq, budget)
+            if self.blocks.window_blocks:
+                # the window pool's pages come a chunk at a time: the first
+                # chunk's now, or the sequence is not admitted
+                try:
+                    self.blocks.ensure_capacity(seq.rid,
+                                                seq.num_computed + n)
+                except NoFreeBlocksError:
+                    self.blocks.free_sequence(seq.rid)
+                    break
             self.waiting.popleft()
             seq.status = RUNNING
             if seq._qw_span is not None:   # queue wait ends here
@@ -362,12 +374,12 @@ class Scheduler:
         by its next chunk out of free pages, i.e. will preempt nobody
         (admissions never preempt). An upper bound: each sequence is
         charged its chunk at the whole token budget."""
-        need = 0
+        need = wneed = 0
         for seq in self.running:
             n = self._chunk(seq, self.token_budget)
-            need += max(0, self.blocks.blocks_needed(seq.num_computed + n)
-                        - self.blocks.num_blocks_of(seq.rid))
-        return self.blocks.can_allocate(need)
+            grow = self.blocks.growth(seq.rid, seq.num_computed + n)
+            need, wneed = need + grow[0], wneed + grow[1]
+        return self.blocks.can_allocate(need, wneed)
 
     def on_dispatched(self, seq: Sequence, n: int) -> bool:
         """The dispatch half of a tick's progress, by count: `n` rows of
@@ -387,6 +399,8 @@ class Scheduler:
         the prefix cache. Every id below `upto` is known by now; the
         block manager must never hash an `UNKNOWN` one."""
         self.blocks.register_computed(seq.rid, seq.tokens, upto)
+        # with a window pool: the pages behind every window to come go back
+        self.blocks.release_behind(seq.rid, upto)
 
     def on_computed(self, seq: Sequence, n: int):
         """Both halves at once, at harvest: for a tick whose progress is

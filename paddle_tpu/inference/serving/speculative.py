@@ -62,6 +62,7 @@ class DraftModel:
             raise NotImplementedError(
                 "draft models are dense LLaMA (MoE drafts defeat the "
                 "latency purpose)")
+        L.require_uniform(cfg, "DraftModel")
         self.cfg = cfg
         self.params = params
         self.engine = None

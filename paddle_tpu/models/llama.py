@@ -19,16 +19,60 @@ ep axis) and optional QK-norm (`qk_normed`), as OLMoE has them or per head
 as Qwen3-MoE and SDAR have it; `block_length` > 0 puts the full-sequence
 forward under SDAR's block-causal mask (generation by diffusion over
 blocks itself is the serving engine's, inference/serving/engine.py).
+
+A stack that is not uniform is a *layer plan* (`LlamaConfig.layer_plan`,
+one `LayerSpec` a layer: full or sliding-window attention, its count of
+query heads, its rope, a dense or a routed FFN), as Laguna-XS.2 has one: a
+leading dense layer, then window and full attention 3:1 with 64 and 48
+query heads and two ropes, a per-head attention gate, 256 small routed
+experts beside a shared one. Layers that share a `LayerSpec` are one
+*kind*: their parameters are one stack (`params["blocks"]` is then a tuple
+of stacks, one a kind, in order of first occurrence) and they share one
+traced body; `scan_plan` runs the plan as scans over runs of one kind
+inside a scan over the plan's periods. Without a plan every layer is the
+config's own one kind (with `num_experts` > 0 every layer's FFN is
+routed), `params["blocks"]` is one dict and the program is what it was.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional
+import functools
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """One rotary embedding: plain rope of base `theta` over the leading
+    `partial` share of each head (rotate-half inside that slice, the rest
+    of the head passes through), or, with `yarn_factor` > 0, YaRN's
+    frequencies (`rope_inv_freq`) with cos and sin times
+    `attention_factor`, computed once, whatever the length."""
+    theta: float = 10000.0
+    partial: float = 1.0
+    yarn_factor: float = 0.0
+    yarn_original: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What one layer of a layer plan is: `attn` "full" (causal) or
+    "window" (causal over the last `LlamaConfig.sliding_window` keys, the
+    query's own among them), its query heads, its rope, and `ffn` "dense"
+    (SwiGLU of `dense_intermediate_size`) or "sparse" (the routed experts
+    of `intermediate_size`, with the shared expert where the config has
+    one). Layers with equal specs are one kind."""
+    attn: str = "full"
+    heads: int = 0
+    rope: RopeSpec = RopeSpec()
+    ffn: str = "dense"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,7 +86,8 @@ class LlamaConfig:
     max_seq_len: int = 2048
     rope_theta: float = 10000.0
     rms_eps: float = 1e-5
-    # MoE: 0 = dense MLP. When >0, every layer's MLP is a top-k gated MoE.
+    # MoE: 0 = dense MLP. When >0, every layer's MLP is a top-k gated MoE
+    # (with a `layer_plan`: every layer whose spec says "sparse").
     num_experts: int = 0
     top_k: int = 2
     # two shape keys of a model's own config.json (defaults: what every
@@ -55,8 +100,10 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     # the width of one head; 0 = hidden_size // num_heads, what every model
     # before SDAR had (its 32 heads of 128 are wider than its hidden 2048).
-    # `dataclasses.replace(cfg, hidden_size=..)` keeps the resolved value:
-    # pass head_dim=0 with it to derive it anew
+    # A derived width remembers what it was derived from
+    # (`head_dim_derived_from`), so `dataclasses.replace(cfg,
+    # hidden_size=..)` derives it anew instead of keeping a stale one; a
+    # width that was given stays what was given
     head_dim: int = 0
     # with `qk_norm`: the second form of QK-norm (Qwen3-MoE's, which SDAR
     # keeps), RMSNorm over each head's vector (weight [head_dim]) after the
@@ -69,39 +116,124 @@ class LlamaConfig:
     # forwards and one commit forward (inference/serving/engine.py)
     block_length: int = 0
     mask_token_id: int = 0
+    # (hidden_size, num_heads) a derived `head_dim` was derived from; ()
+    # for one that was given. Set by `__post_init__`, never by a caller
+    head_dim_derived_from: Tuple[int, ...] = ()
+    # a stack that is not uniform: one LayerSpec a layer (len ==
+    # num_layers), () = every layer is the one kind the fields above
+    # describe. With a plan `num_heads` is not read (a spec has its own
+    # heads), `rope_theta` neither; `sliding_window` is the span of a
+    # "window" layer, `dense_intermediate_size` the width of a "dense"
+    # layer's FFN beside experts of `intermediate_size`
+    layer_plan: Tuple[LayerSpec, ...] = ()
+    sliding_window: int = 0
+    dense_intermediate_size: int = 0
+    # one more SwiGLU expert of this width that every row passes through,
+    # beside the routed ones and ungated (0 = none)
+    shared_expert_width: int = 0
+    # the router's scores, "softmax" or "sigmoid" over all experts in
+    # float32, and a factor on the k weights after their renormalisation
+    router_score: str = "softmax"
+    router_scale: float = 1.0
+    # a sigmoid gate on each head's attention output, from the layer's
+    # normed input through `wg` [d, heads]
+    attn_gate: bool = False
 
     def __post_init__(self):
-        if not self.head_dim:
-            object.__setattr__(self, "head_dim",
-                               self.hidden_size // self.num_heads)
+        here = (self.hidden_size, self.num_heads)
+        was = self.head_dim_derived_from
+        if not self.head_dim or (was and self.head_dim == was[0] // was[1]):
+            object.__setattr__(self, "head_dim", here[0] // here[1])
+            object.__setattr__(self, "head_dim_derived_from", here)
+        else:   # given, here or over a derived one by `replace`
+            object.__setattr__(self, "head_dim_derived_from", ())
+        if self.layer_plan:
+            if len(self.layer_plan) != self.num_layers:
+                raise ValueError(
+                    f"layer_plan has {len(self.layer_plan)} layers, "
+                    f"num_layers is {self.num_layers}")
+            for spec in self.layer_plan:
+                if (spec.attn not in ("full", "window")
+                        or spec.ffn not in ("dense", "sparse")
+                        or spec.heads % self.num_kv_heads):
+                    raise ValueError(f"layer_plan: bad layer {spec}")
+                if spec.attn == "window" and self.sliding_window < 1:
+                    raise ValueError("a window layer needs sliding_window")
+                if spec.ffn == "sparse" and not self.num_experts:
+                    raise ValueError("a sparse layer needs num_experts")
+            if self.block_length or self.qk_norm:
+                raise NotImplementedError(
+                    "a layer plan with block_length or qk_norm: no model "
+                    "served has both, and neither was judged under a plan")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score={self.router_score!r}")
+
+    # -- the layer plan ---------------------------------------------------
+    @property
+    def kinds(self) -> Tuple[LayerSpec, ...]:
+        """The distinct specs of the plan, in order of first occurrence."""
+        return tuple(dict.fromkeys(self.layer_plan))
+
+    @property
+    def kind_of_layer(self) -> Tuple[int, ...]:
+        kinds = self.kinds
+        return tuple(kinds.index(s) for s in self.layer_plan)
+
+    def _layer_params(self, heads: int, ffn: str) -> Tuple[int, int]:
+        """(all, active a token) matmul and norm parameters of one layer."""
+        d, hd = self.hidden_size, self.head_dim
+        attn = 2 * d * heads * hd + 2 * d * self.num_kv_heads * hd
+        if self.attn_gate:
+            attn += d * heads
+        norms = 2 * d
+        if self.qk_norm:
+            norms += (2 * hd if self.qk_norm_per_head
+                      else (heads + self.num_kv_heads) * hd)
+        if ffn == "sparse":
+            one = 3 * d * self.intermediate_size
+            fixed = d * self.num_experts + 3 * d * self.shared_expert_width
+            mlp = self.num_experts * one + fixed
+            active = min(self.top_k, self.num_experts) * one + fixed
+        else:
+            mlp = active = 3 * d * (self.dense_intermediate_size
+                                    if self.layer_plan
+                                    else self.intermediate_size)
+        return attn + mlp + norms, attn + active
+
+    def _layers(self):
+        if self.layer_plan:
+            return [(s.heads, s.ffn) for s in self.layer_plan]
+        return [(self.num_heads, "sparse" if self.num_experts else "dense")
+                ] * self.num_layers
 
     def num_params(self) -> int:
-        d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
-        hd = self.head_dim
-        attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
-        if self.qk_norm:
-            attn += (2 * hd if self.qk_norm_per_head
-                     else (self.num_heads + self.num_kv_heads) * hd)
-        if self.num_experts:
-            mlp = self.num_experts * 3 * d * f + d * self.num_experts
-        else:
-            mlp = 3 * d * f
-        per_layer = attn + mlp + 2 * d
-        return v * d + self.num_layers * per_layer + d + d * v
+        d, v = self.hidden_size, self.vocab_size
+        return (v * d + sum(self._layer_params(*l)[0] for l in self._layers())
+                + d + d * v)
+
+    def num_active_params(self) -> int:
+        """Matmul parameters one token is multiplied with: a token passes
+        through `top_k` of the experts, the router and the shared expert;
+        the head counts, the embedding (a row lookup) does not."""
+        return (sum(self._layer_params(*l)[1] for l in self._layers())
+                + self.hidden_size * self.vocab_size)
 
     def flops_per_token(self) -> int:
         """Approximate training FLOPs/token (fwd+bwd ≈ 6·N_active): a
         token passes through `top_k` of the experts and the router."""
-        d, f = self.hidden_size, self.intermediate_size
-        hd = self.head_dim
-        attn = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd + self.num_heads * hd * d
-        if self.num_experts:
-            mlp = (3 * d * f * min(self.top_k, self.num_experts)
-                   + d * self.num_experts)
-        else:
-            mlp = 3 * d * f
-        dense = self.num_layers * (attn + mlp) + 2 * self.hidden_size * self.vocab_size
-        return 6 * dense
+        return 6 * (self.num_active_params()
+                    + self.hidden_size * self.vocab_size)
+
+
+def require_uniform(cfg: LlamaConfig, what: str) -> None:
+    """Raise for a config with a layer plan, in the name of a block body
+    that runs one uniform stack (`params["blocks"]` one dict) and would
+    compute another model under a plan's name."""
+    if cfg.layer_plan:
+        raise NotImplementedError(
+            f"{what} runs one uniform stack of layers and does not take a "
+            "layer plan (LlamaConfig.layer_plan); `llama.forward` and "
+            "`PagedServingEngine` do")
 
 
 # Predefined sizes (the reference's headline configs; LLaMA-7B/13B per
@@ -116,17 +248,20 @@ CONFIGS = {
 }
 
 
-def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
-    """Build the parameter pytree. Block params are stacked on a leading
-    num_layers axis so the forward is a lax.scan and the pipeline engine can
-    reshape to [pp, layers_per_stage, ...]."""
-    d, f, v = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
-    hd, nh, nkv, L = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads, cfg.num_layers
+def _normal(key: jax.Array, shape, dtype, scale: float = 0.02):
+    return (scale * jax.random.normal(key, shape, jnp.float32)).astype(dtype)
+
+
+def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
+                 ffn_kind: str) -> Dict[str, jax.Array]:
+    """One stack of `L` layers with `nh` query heads and an FFN of
+    `ffn_kind` ("dense" | "sparse"); the keys are split as they always
+    were, so a uniform config draws the weights it drew."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    hd, nkv = cfg.head_dim, cfg.num_kv_heads
     pt = cfg.param_dtype
     keys = jax.random.split(key, 10)
-
-    def normal(k, shape, scale=0.02):
-        return (scale * jax.random.normal(k, shape, jnp.float32)).astype(pt)
+    normal = functools.partial(_normal, dtype=pt)
 
     blocks = {
         "wq": normal(keys[0], (L, d, nh * hd)),
@@ -136,22 +271,55 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "attn_norm": jnp.ones((L, d), pt),
         "mlp_norm": jnp.ones((L, d), pt),
     }
+    if cfg.attn_gate:
+        blocks["wg"] = normal(keys[8], (L, d, nh))
     if cfg.qk_norm and cfg.qk_norm_per_head:
         blocks["q_norm"] = jnp.ones((L, hd), pt)
         blocks["k_norm"] = jnp.ones((L, hd), pt)
     elif cfg.qk_norm:
         blocks["q_norm"] = jnp.ones((L, nh * hd), pt)
         blocks["k_norm"] = jnp.ones((L, nkv * hd), pt)
-    if cfg.num_experts:
+    if ffn_kind == "sparse":
         e = cfg.num_experts
         blocks["router"] = normal(keys[4], (L, d, e))
         blocks["w1"] = normal(keys[5], (L, e, d, f))
         blocks["w3"] = normal(keys[6], (L, e, d, f))
         blocks["w2"] = normal(keys[7], (L, e, f, d))
+        if cfg.shared_expert_width:
+            fs = cfg.shared_expert_width
+            ks = jax.random.split(keys[9], 3)
+            blocks["ws1"] = normal(ks[0], (L, d, fs))
+            blocks["ws3"] = normal(ks[1], (L, d, fs))
+            blocks["ws2"] = normal(ks[2], (L, fs, d))
     else:
+        if cfg.layer_plan:
+            f = cfg.dense_intermediate_size
         blocks["w1"] = normal(keys[5], (L, d, f))
         blocks["w3"] = normal(keys[6], (L, d, f))
         blocks["w2"] = normal(keys[7], (L, f, d))
+    return blocks
+
+
+def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    """Build the parameter pytree. Block params are stacked on a leading
+    num_layers axis so the forward is a lax.scan and the pipeline engine can
+    reshape to [pp, layers_per_stage, ...]. With a layer plan
+    `params["blocks"]` is a tuple of such stacks, one a kind
+    (`cfg.kinds`), each as deep as the plan has layers of that kind."""
+    d, v = cfg.hidden_size, cfg.vocab_size
+    pt = cfg.param_dtype
+    keys = jax.random.split(key, 10)
+    normal = functools.partial(_normal, dtype=pt)
+
+    if cfg.layer_plan:
+        kind_of = cfg.kind_of_layer
+        blocks = tuple(
+            _init_blocks(cfg, jax.random.fold_in(key, 1 + k),
+                         kind_of.count(k), spec.heads, spec.ffn)
+            for k, spec in enumerate(cfg.kinds))
+    else:
+        blocks = _init_blocks(cfg, key, cfg.num_layers, cfg.num_heads,
+                              "sparse" if cfg.num_experts else "dense")
     return {
         "embed": normal(keys[8], (v, d)),
         "blocks": blocks,
@@ -168,13 +336,53 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
 
 def rope_cos_sin(positions: jax.Array, head_dim: int, theta: float):
     """positions [T] int → (cos, sin) [T, head_dim/2] in f32."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    inv_freq = rope_inv_freq(head_dim, RopeSpec(theta=theta))
     angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
     return jnp.cos(angles), jnp.sin(angles)
 
 
+def rope_inv_freq(head_dim: int, spec: RopeSpec) -> jax.Array:
+    """The inverse frequencies [rot / 2] of `spec` over the rot =
+    `spec.partial` * head_dim leading values of a head. YaRN
+    (`yarn_factor` s > 0, the `yarn` rule of the `rope_parameters`
+    convention): with d(b) = rot * ln(original / (2 pi b)) / (2 ln theta),
+    low = max(floor(d(beta_fast)), 0), high = min(ceil(d(beta_slow)),
+    rot - 1) and ramp_i = clip((i - low) / (high - low), 0, 1), frequency
+    i is f_i / s where the ramp is 1 (long wavelengths: interpolated), f_i
+    where it is 0 (short ones: kept), and a blend between."""
+    rot = int(head_dim * spec.partial)
+    f = 1.0 / (spec.theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    if not spec.yarn_factor:
+        return f
+
+    def dim_of(beta):
+        return (rot * math.log(spec.yarn_original / (2 * math.pi * beta))
+                / (2 * math.log(spec.theta)))
+
+    low = max(math.floor(dim_of(spec.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_of(spec.yarn_beta_slow)), rot - 1)
+    ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return f / spec.yarn_factor * ramp + f * (1.0 - ramp)
+
+
+def rope_table(positions: jax.Array, head_dim: int, spec: RopeSpec):
+    """positions [T] int → (cos, sin) [T, rot / 2] in f32 for `spec`
+    (`rope_inv_freq`), times its `attention_factor`."""
+    angles = (positions.astype(jnp.float32)[:, None]
+              * rope_inv_freq(head_dim, spec)[None, :])
+    return (jnp.cos(angles) * spec.attention_factor,
+            jnp.sin(angles) * spec.attention_factor)
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x [B, T, H, hd]; rotate-half convention, f32 math."""
+    """x [B, T, H, hd]; rotate-half convention, f32 math. Tables narrower
+    than hd / 2 rotate the leading 2 * width values of each head (rotate-half
+    inside them) and pass the rest through."""
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     x32 = x.astype(jnp.float32)
     x1, x2 = jnp.split(x32, 2, axis=-1)
     c = cos[None, :, None, :]
@@ -184,7 +392,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto",
-              block_length: int = 0) -> jax.Array:
+              block_length: int = 0, window: int = 0) -> jax.Array:
     """Causal MHA/GQA. q [B,T,H,hd], k/v [B,T,KV,hd] → [B,T,H,hd].
 
     impl: 'auto' uses the Pallas flash kernel on TPU when available, else the
@@ -194,11 +402,17 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto",
     over blocks, position i sees j iff j // Bd <= i // Bd (full inside a
     block, causal across blocks), on the XLA path alone: the flash kernel
     knows the causal mask only, and impl='flash' with it raises.
+
+    window W > 0: position i sees j iff i - W < j <= i (W keys, the
+    query's own among them), on the XLA path alone, as `block_length`.
     """
     if block_length and impl == "flash":
         raise ValueError("the flash kernel has no block-causal mask: "
                          "block_length > 0 takes impl='auto' or 'xla'")
-    if block_length:
+    if window and impl == "flash":
+        raise ValueError("the flash kernel has no window: window > 0 takes "
+                         "impl='auto' or 'xla'")
+    if block_length or window:
         impl = "xla"
     if impl == "flash":
         # explicit request: no silent fallback — unsupported shapes raise
@@ -223,6 +437,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto",
         mask = blk[None, :] <= blk[:, None]
     else:
         mask = jnp.tril(jnp.ones((T, T), bool))
+    if window:
+        pos = jnp.arange(T)
+        mask = mask & (pos[None, :] > pos[:, None] - window)
     scores = jnp.where(mask[None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bshd->bthd", probs, v)
@@ -249,13 +466,20 @@ def qk_normed(q: jax.Array, k: jax.Array, lp: Dict[str, jax.Array],
 
 def route(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig):
     """Top-k routing of rows h [T, d]: (w [T, k] f32, e [T, k] i32). The
-    softmax runs in float32 over ALL experts; the k weights are renormalised
-    to sum to one only where the model's config says so (`norm_topk_prob`)."""
-    gate = jax.nn.softmax(
-        h.astype(jnp.float32) @ lp["router"].astype(jnp.float32), axis=-1)
+    scores (`router_score`: a softmax, or a sigmoid of each logit) run in
+    float32 over ALL experts; the k weights are renormalised to sum to one
+    only where the model's config says so (`norm_topk_prob`), and then
+    take the config's `router_scale`."""
+    logits = h.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
+    if cfg.router_score == "sigmoid":
+        gate = jax.nn.sigmoid(logits)
+    else:
+        gate = jax.nn.softmax(logits, axis=-1)
     w, e = lax.top_k(gate, cfg.top_k)
     if cfg.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
+    if cfg.router_scale != 1.0:
+        w = w * cfg.router_scale
     return w, e.astype(jnp.int32)
 
 
@@ -387,7 +611,9 @@ def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     are the stacked [L, E, ...] leaves, as a layer loop that must not
     slice them hands them over; the router is the layer's own. Returns
     (y [..., d], load [E] i32: valid rows on each expert). Scopes: router,
-    dispatch, experts, combine."""
+    dispatch, experts, combine. With `cfg.shared_expert_width` the layer's
+    shared expert (`ws1`, `ws3`, `ws2` of `lp`, ungated) is added on the
+    valid rows under scope `shared_expert`."""
     shape = h.shape
     h = h.reshape(-1, shape[-1])
     T = h.shape[0]
@@ -403,6 +629,11 @@ def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
         y = _experts_sorted(h, w, e, valid, load, lp, cfg, layer)
     else:
         y = _experts_dense(h, w, e, valid, lp, cfg, layer)
+    if cfg.shared_expert_width:
+        with jax.named_scope("shared_expert"):
+            shared = ffn(h, {"w1": lp["ws1"], "w3": lp["ws3"],
+                             "w2": lp["ws2"]})
+            y = y + jnp.where(valid[:, None], shared, 0)
     return y.reshape(shape), load
 
 
@@ -436,25 +667,122 @@ def ffn(h: jax.Array, lp: Dict[str, jax.Array], impl: str = "stock") -> jax.Arra
 
 def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
           cos: jax.Array, sin: jax.Array, attn_impl: str = "auto",
-          ffn_impl: str = "stock") -> jax.Array:
-    """One transformer block; lp leaves have the layer axis already indexed."""
+          ffn_impl: str = "stock",
+          spec: Optional[LayerSpec] = None) -> jax.Array:
+    """One transformer block; lp leaves have the layer axis already indexed.
+    `spec` is the layer's entry of a layer plan (its heads, window or full
+    attention, dense or sparse FFN; cos and sin are its rope's, narrower
+    than a head for a partial one); None: the config's one kind."""
     B, T, d = x.shape
-    hd, nh, nkv = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    hd, nkv = cfg.head_dim, cfg.num_kv_heads
+    nh = spec.heads if spec else cfg.num_heads
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     q, k = qk_normed(h @ lp["wq"].astype(h.dtype),
                      h @ lp["wk"].astype(h.dtype), lp, cfg)
     v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, nkv, hd)
     q = apply_rope(q.reshape(B, T, nh, hd), cos, sin)
     k = apply_rope(k.reshape(B, T, nkv, hd), cos, sin)
-    o = attention(q, k, v, impl=attn_impl,
-                  block_length=cfg.block_length).reshape(B, T, nh * hd)
+    window = cfg.sliding_window if spec and spec.attn == "window" else 0
+    o = attention(q, k, v, impl=attn_impl, block_length=cfg.block_length,
+                  window=window)
+    if cfg.attn_gate:
+        o = attn_gated(o, h, lp)
+    o = o.reshape(B, T, nh * hd)
     x = x + o @ lp["wo"].astype(o.dtype)
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    if cfg.num_experts:
+    if (spec.ffn == "sparse") if spec else cfg.num_experts:
         x = x + routed_ffn(h, lp, cfg)
     else:
         x = x + ffn(h, lp, impl=ffn_impl)
     return x
+
+
+def attn_gated(o: jax.Array, h: jax.Array, lp: Dict[str, jax.Array]):
+    """The per-head attention gate: o [..., H, hd] times sigmoid(h wg)
+    [..., H], from the layer's normed input h [..., d] (scope
+    `attn_gate`)."""
+    with jax.named_scope("attn_gate"):
+        g = jax.nn.sigmoid((h @ lp["wg"].astype(h.dtype)
+                            ).astype(jnp.float32))
+        return (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
+
+
+def plan_segments(cfg: LlamaConfig):
+    """The plan as the loops that run it: [(repeats, ((kind, count),
+    ...)), ...]. Consecutive layers of one kind are a run; the longest
+    stretch that is one sequence of runs repeated is a period (Laguna's 40
+    layers: its leading dense layer once, then (3 window, 1 full) nine
+    times, then 3 window once)."""
+    runs: List[Tuple[int, int]] = []
+    for k in cfg.kind_of_layer:
+        if runs and runs[-1][0] == k:
+            runs[-1] = (k, runs[-1][1] + 1)
+        else:
+            runs.append((k, 1))
+    best = None
+    for p in range(len(runs)):
+        for q in range(1, (len(runs) - p) // 2 + 1):
+            n = 1
+            while runs[p + n * q:p + (n + 1) * q] == runs[p:p + q]:
+                n += 1
+            if n >= 2 and (best is None or n * q > best[1] * best[2]):
+                best = (p, q, n)
+    if best is None:
+        return [(1, tuple(runs))]
+    p, q, n = best
+    parts = [(1, tuple(runs[:p])), (n, tuple(runs[p:p + q])),
+             (1, tuple(runs[p + n * q:]))]
+    return [part for part in parts if part[1]]
+
+
+def scan_plan(cfg: LlamaConfig, body: Callable, carry, stacks):
+    """Run a layer plan: `body(kind, carry, leaves) -> carry` once a layer,
+    in the plan's order, with `leaves` the layer's slice of `stacks[kind]`
+    (a pytree whose leaves are stacked over that kind's layers; what a
+    layer must find by index instead, the page pools and the expert
+    matrices, the caller keeps out of it and hands the layer's place in
+    its stack as one more leaf). A run of layers of one kind is one
+    `lax.scan`, and a period of runs that repeats is a scan over the
+    periods with the runs' scans inside, so a kind's body is traced once a
+    run of the period, not once a layer. A stack is sliced only where a
+    run does not cover it whole."""
+    done = [0] * len(cfg.kinds)
+
+    def part(stack, start, count):
+        return jax.tree.map(
+            lambda a: a if (start, count) == (0, a.shape[0])
+            else a[start:start + count], stack)
+
+    def run_of(kind):
+        return lambda c, leaves: (body(kind, c, leaves), None)
+
+    for repeats, runs in plan_segments(cfg):
+        if repeats == 1:
+            for kind, count in runs:
+                carry, _ = lax.scan(run_of(kind), carry,
+                                    part(stacks[kind], done[kind], count))
+                done[kind] += count
+            continue
+        per = {}
+        for kind, count in runs:
+            per[kind] = per.get(kind, 0) + count
+        xs = {kind: jax.tree.map(
+            lambda a, n=n: a.reshape(repeats, n, *a.shape[1:]),
+            part(stacks[kind], done[kind], repeats * n))
+            for kind, n in per.items()}
+
+        def period(c, xs_p):
+            at = dict.fromkeys(per, 0)
+            for kind, count in runs:
+                c, _ = lax.scan(run_of(kind), c,
+                                part(xs_p[kind], at[kind], count))
+                at[kind] += count
+            return c, None
+
+        carry, _ = lax.scan(period, carry, xs)
+        for kind, n in per.items():
+            done[kind] += repeats * n
+    return carry
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
@@ -462,12 +790,23 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
     """tokens [B, T] int32 → logits [B, T, vocab] (f32)."""
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
     T = tokens.shape[1]
-    cos, sin = rope_cos_sin(jnp.arange(T), cfg.head_dim, cfg.rope_theta)
+    if cfg.layer_plan:
+        kinds = cfg.kinds
+        ropes = {spec.rope: rope_table(jnp.arange(T), cfg.head_dim,
+                                       spec.rope) for spec in kinds}
 
-    def body(carry, lp):
-        return block(carry, lp, cfg, cos, sin, attn_impl, ffn_impl), None
+        def plan_body(kind, carry, lp):
+            return block(carry, lp, cfg, *ropes[kinds[kind].rope],
+                         attn_impl, ffn_impl, spec=kinds[kind])
 
-    x, _ = lax.scan(body, x, params["blocks"])
+        x = scan_plan(cfg, plan_body, x, params["blocks"])
+    else:
+        cos, sin = rope_cos_sin(jnp.arange(T), cfg.head_dim, cfg.rope_theta)
+
+        def body(carry, lp):
+            return block(carry, lp, cfg, cos, sin, attn_impl, ffn_impl), None
+
+        x, _ = lax.scan(body, x, params["blocks"])
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     return (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
 

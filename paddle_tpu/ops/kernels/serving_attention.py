@@ -32,6 +32,7 @@ out_shift / out_smooth) still raise explicitly.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Sequence
 
 import jax
@@ -536,7 +537,8 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
                           rope_emb=None, quant_scales=None, qkv_bias=None,
                           use_neox_style=False, quant_max_bound=127.0,
                           quant_min_bound=-127.0, use_pallas=False,
-                          block_length=0):
+                          block_length=0, window=0, rotary_dim=0,
+                          kind=None):
     """One layer of `block_multihead_attention_` on the stacked page pool
     [L, num_blocks, KV, block_size, hd]: split and rotate `qkv`, write the
     new tokens' rows into `layer`'s pages where they lie, then attend over
@@ -550,7 +552,14 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
     of generation by diffusion over blocks in place of the causal one: the
     row at absolute position p sees key j iff j < (p // Bd + 1) * Bd and
     j < past + this, on either read path (not the decode launch: one row
-    a sequence is no block). Returns (fmha_out, qkv_out, key_pool,
+    a sequence is no block). `window` W > 0 (static) keeps of those keys
+    the last W, the row's own among them (i - W < j <= i), on either read
+    path and in the decode launch; table entries behind every window may
+    be -1. `rotary_dim` > 0: `rope_emb` is [2, 1, S, rotary_dim] and only
+    the leading `rotary_dim` values of each head are rotated (rotate-half
+    inside them). `kind` names the layer's kind in a layer plan: the read
+    then runs under scope `paged_attention_<kind>` inside
+    `paged_attention`. Returns (fmha_out, qkv_out, key_pool,
     value_pool)."""
     from ..pallas import paged_attention as PA
     _, num_blocks, KV, bs, hd = key_pool.shape
@@ -590,12 +599,20 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
         tok_valid = tok_local < this[tok_b]
 
         if rope_emb is not None:
-            cos_t, sin_t = _rotary_table(rope_emb, hd)           # [Br, S, hd//2]
+            rot = rotary_dim or hd
+            cos_t, sin_t = _rotary_table(rope_emb, rot)          # [Br, S, rot//2]
             tb = jnp.zeros_like(tok_b) if cos_t.shape[0] == 1 else tok_b
-            cos = cos_t[tb, tok_pos]                             # [tok, hd//2]
+            cos = cos_t[tb, tok_pos]                             # [tok, rot//2]
             sin = sin_t[tb, tok_pos]
-            q_tok = _rope_pairwise(q_tok, cos[:, None], sin[:, None], use_neox_style)
-            k_tok = _rope_pairwise(k_tok, cos[:, None], sin[:, None], use_neox_style)
+
+            def rotated(x):
+                if rot == hd:
+                    return _rope_pairwise(x, cos[:, None], sin[:, None],
+                                          use_neox_style)
+                return jnp.concatenate(
+                    [_rope_pairwise(x[..., :rot], cos[:, None], sin[:, None],
+                                    use_neox_style), x[..., rot:]], axis=-1)
+            q_tok, k_tok = rotated(q_tok), rotated(k_tok)
 
         # ---- quantize-on-append: per-head static multipliers, round+clip to
         # the int8 page dtype. Quantization is per-token VALUE-based (no
@@ -642,7 +659,9 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
             value_pool = write_rows(value_pool, layer, tok_page, tok_slot,
                                     v_store)
 
-    with jax.named_scope("paged_attention"):
+    with jax.named_scope("paged_attention"), (
+            jax.named_scope(f"paged_attention_{kind}") if kind
+            else contextlib.nullcontext()):
         G = H // KV
         q_g = q_tok.reshape(token_num, KV, G, hd)                # head h = kv*G+g
         if use_pallas:
@@ -658,14 +677,14 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
                 o = PA.paged_attention(
                     q_g[row_tok], key_pool, value_pool, block_tables, past,
                     this, G, sm_scale, k_dequant=k_dequant,
-                    v_dequant=v_dequant, layer=layer)[tok_b]
+                    v_dequant=v_dequant, layer=layer, window=window)[tok_b]
                 o = jnp.where(tok_valid[:, None, None, None], o, 0)
             else:
                 # ragged chunks: the packed stream goes in as it is
                 o = PA.paged_attention_packed(
                     q_g, key_pool, value_pool, block_tables, past, this, cu,
                     sm_scale, k_dequant=k_dequant, v_dequant=v_dequant,
-                    layer=layer, block_len=block_length)
+                    layer=layer, block_len=block_length, window=window)
             fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
             return fmha_out, qkv3.reshape(token_num, -1), key_pool, value_pool
 
@@ -710,6 +729,8 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
         else:
             see = tok_pos
         ok = (kv_pos <= see[:, None]) & page_valid[tok_b]        # [tok, max_kv]
+        if window:
+            ok &= kv_pos > (tok_pos - window)[:, None]
         s = jnp.where(ok[:, None, None, :], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
         if kv_quant:

@@ -85,6 +85,15 @@ position p sees key j iff j < (p // block_len + 1) * block_len and
 j < past + this (`_see_limit`); a tile's key blocks then end with its last
 row's block. With 0 the kernels are what they were.
 
+Every walk takes a static `window` W (0 = none): the query at position p
+sees key j iff p - W < j <= p, W keys with its own. It is a lower limit
+beside `_see_limit`'s upper one (`_see_from`): a whole-page walk begins at
+the key block that holds position max(0, p_first - W + 1) of its first
+query row, masks below each row's limit inside the blocks it visits, and
+never reads a table entry behind that block, so a caller may have given
+the pages behind the window back (entries of -1). With 0 the kernels
+are what they were.
+
 The BlockSpec walk (`_kernel`; rows `[B, KV, max_q * G, hd]` packed per
 sequence, max_q = 1 for a decode launch): grid `(B, KV, table width)`
 with the page axis innermost, one `[block_size, hd]` page of one KV head
@@ -150,6 +159,13 @@ def _see_limit(pos, end, block_len: int):
     return jnp.minimum((jax.lax.div(pos, bd) + _i32(1)) * bd, end) - _i32(1)
 
 
+def _see_from(pos, window: int):
+    """The first key position a query at absolute position `pos` may see
+    under a window of `window` keys (the query's own among them): pos -
+    window + 1, where that is negative every key from 0 on."""
+    return pos - _i32(window - 1)
+
+
 def supported(num_heads: int, num_kv_heads: int, head_dim: int,
               block_size: int) -> bool:
     """Static gate: can this head/page geometry run through the kernel?
@@ -195,7 +211,7 @@ def whole_pages(head_dim: int, interpret: Optional[bool] = None) -> bool:
 
 def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
             sm_scale: float, block_size: int, group: int, has_quant: bool,
-            block_len: int = 0):
+            block_len: int = 0, window: int = 0):
     """One (sequence b, kv head, page p) grid step. `layer_ref` is read
     by the K/V index maps only.
 
@@ -228,6 +244,8 @@ def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
     # pages hold positions [p*bs, (p+1)*bs); only those below the live
     # length past+this can ever be unmasked — skip the rest entirely
     needed = p * block_size < past + this
+    if window:      # nor a page wholly behind the first row's window
+        needed &= (p + _i32(1)) * block_size > _see_from(past, window)
 
     @pl.when(needed)
     def _():
@@ -249,6 +267,8 @@ def _kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
         # causal (or block-causal: `_see_limit`) + live rows
         ok = (kv_abs <= _see_limit(past + t, past + this if block_len
                                    else None, block_len)) & (t < this)
+        if window:
+            ok &= kv_abs >= _see_from(past + t, window)
         s = jnp.where(ok, s, NEG_INF)
         m_prev = m_sc[:, :1]                          # [rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -300,18 +320,23 @@ def _pages_per_block(keys: int, block_size: int, num_kv_heads: int,
 
 
 def decode_pages_walked(ends, block_size: int, num_kv_heads: int,
-                        head_dim: int, itemsize: int, max_blocks: int):
+                        head_dim: int, itemsize: int, max_blocks: int,
+                        window: int = 0):
     """(live, fetched) pages of one decode launch, reckoned on the host:
     `ends` [n] are the live lengths `past + 1` of the sequences that take
-    part (idle slots left out). Live pages hold a key the query may see;
-    fetched pages are the walk's trip count (`n_blocks` of
-    `_decode_kernel`) times its P whole pages a key block."""
+    part (idle slots left out). Live pages hold a key the query may see
+    (under a `window`: one of its last `window` positions); fetched pages
+    are the walk's trip count (`n_blocks` of `_decode_kernel`, less the
+    blocks behind the window) times its P whole pages a key block."""
     pages = decode_pages_per_block(block_size, num_kv_heads, head_dim,
                                    itemsize, max_blocks)
     ends = np.asarray(ends, np.int64)
-    blocks = np.minimum(-(-ends // (pages * block_size)),
-                        -(-max_blocks // pages))
-    return int((-(-ends // block_size)).sum()), int(blocks.sum()) * pages
+    span = pages * block_size
+    first = np.maximum(ends - window, 0) if window else np.zeros_like(ends)
+    blocks = (np.minimum(-(-ends // span), -(-max_blocks // pages))
+              - first // span)
+    return (int((-(-ends // block_size) - first // block_size).sum()),
+            int(blocks.sum()) * pages)
 
 
 def _stacked(key_cache, value_cache, layer):
@@ -379,7 +404,7 @@ def _page_scales(scale_ref, slot, kv, pages: int, block_size: int):
 
 def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
                    sm_scale: float, block_size: int, pages: int,
-                   has_quant: bool):
+                   has_quant: bool, window: int = 0):
     """One sequence b of a decode launch (one query token, rows = the GQA
     group): walk its live key blocks of `pages` whole pages each.
 
@@ -406,6 +431,12 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
         this_ref[b] > 0,
         jnp.minimum(jax.lax.div(past + _i32(span), _i32(span)),
                     _i32(width // pages)), _i32(0))
+    # under a window the walk begins at the block that holds the first key
+    # the query sees; the table's entries behind it are never read
+    # (with window 0 nothing below traces an operation it did not trace
+    # before the window was there)
+    first = (jax.lax.div(jnp.maximum(_see_from(past, window), _i32(0)),
+                         _i32(span)) if window else _i32(0))
 
     def copies(i, slot):
         return _block_copies(tables_ref, b, i, slot, pages, layer, pools,
@@ -415,9 +446,10 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
     l_sc[...] = jnp.zeros_like(l_sc)
     acc[...] = jnp.zeros_like(acc)
 
-    @pl.when(n_blocks > 0)
+    @pl.when(n_blocks > (first if window else 0))
     def _():
-        for c in copies(_i32(0), _i32(0)):
+        for c in copies(first, jax.lax.rem(first, _i32(2)) if window
+                        else _i32(0)):
             c.start()
 
     def block(i, _):
@@ -433,6 +465,8 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
         kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (G, span), 1)
                   + i * _i32(span))
         ok = kv_abs <= past                           # causal = live keys
+        if window:
+            ok &= kv_abs >= _see_from(past, window)
         for kv in range(KV):
             q = q_ref[0, kv].astype(jnp.float32)      # [G, hd]
             k = kbuf[slot, :, kv].astype(jnp.float32).reshape(span, hd)
@@ -464,7 +498,7 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
                 preferred_element_type=jnp.float32)
             m_sc[kv] = jnp.broadcast_to(m_new, m_sc.shape[1:])
 
-    jax.lax.fori_loop(_i32(0), n_blocks, block, None)
+    jax.lax.fori_loop(first, n_blocks, block, None)
     # an idle slot walked nothing and has l == 0: divide by 1, emit 0
     l = l_sc[...][:, :, :1]
     o_ref[0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
@@ -495,7 +529,7 @@ def _walk_operands(key_cache, value_cache, tables, k_dequant, v_dequant,
 
 
 def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
-                 sm_scale, k_dequant, v_dequant, interpret):
+                 sm_scale, k_dequant, v_dequant, interpret, window: int = 0):
     """The decode launch (`rows == group`): grid over sequences, pools
     left in HBM, whole pages gathered by the kernel."""
     B, KV, G, hd = q_rows.shape
@@ -526,7 +560,7 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
     )
     kernel = functools.partial(
         _decode_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
-        pages=int(pages), has_quant=has_quant)
+        pages=int(pages), has_quant=has_quant, window=int(window))
     count_launch()
     return pl.pallas_call(
         kernel,
@@ -587,7 +621,7 @@ def mixed_items(token_num: int, batch: int, tq: int) -> int:
 
 def mixed_work(past, this, token_num: int, block_size: int,
                num_kv_heads: int, group: int, head_dim: int, itemsize: int,
-               max_blocks: int, block_len: int = 0):
+               max_blocks: int, block_len: int = 0, window: int = 0):
     """What one mixed launch walks, reckoned on the host from the
     scheduler's own lengths (`past`, `this` [B], idle slots 0): the trip
     counts of `_mixed_kernel`, as `decode_pages_walked` mirrors
@@ -596,7 +630,10 @@ def mixed_work(past, this, token_num: int, block_size: int,
     token rows of the tiles they are computed on (TS or TQ an item: their
     ratio is the tile occupancy); `attn_pages_live` pages that hold a key
     some query may see; `attn_pages_fetched` key blocks walked x P, every
-    item's own (a chunk's later tiles walk its earlier keys again)."""
+    item's own (a chunk's later tiles walk its earlier keys again). Under
+    a `window` a page is live if it holds one of the last `window`
+    positions of some query of the chunk, and an item's walk begins at
+    the block of its first row's first visible key."""
     tq, ts = mixed_tiles(token_num, group, num_kv_heads, head_dim)
     pages = mixed_pages_per_block(block_size, num_kv_heads, head_dim,
                                   itemsize, max_blocks)
@@ -614,10 +651,16 @@ def mixed_work(past, this, token_num: int, block_size: int,
                           past[seq] + this[seq])
     blocks = np.minimum(-(-seen // (pages * block_size)),
                         -(-max_blocks // pages))
+    live_pages = -(-(past + this) // block_size)
+    if window:
+        blocks = blocks - (np.maximum(past[seq] + t0 - (window - 1), 0)
+                           // (pages * block_size))
+        live_pages = live_pages - (np.maximum(past - (window - 1), 0)
+                                   // block_size)
     return {"attn_q_tiles": int(tiles.sum()),
             "attn_rows_live": int(this.sum()),
             "attn_rows_packed": int(np.where(live <= ts, ts, tq).sum()),
-            "attn_pages_live": int((-(-(past + this) // block_size)).sum()),
+            "attn_pages_live": int(live_pages.sum()),
             "attn_pages_fetched": int(blocks.sum()) * pages}
 
 
@@ -652,7 +695,7 @@ def _loop_i32(n: int, body) -> None:
 def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
                   *refs, sm_scale: float, block_size: int, pages: int,
                   group: int, small: int, has_quant: bool,
-                  block_len: int = 0):
+                  block_len: int = 0, window: int = 0):
     """One work item j of a mixed launch: the query rows of sequence
     seq[j] from chunk offset t0[j] on (row r = t * G + g of the tile, its
     query at position past + t0 + t), against that sequence's key blocks
@@ -696,6 +739,10 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
         live > 0,
         jnp.minimum(jax.lax.div(seen() + _i32(span - 1), _i32(span)),
                     _i32(width // pages)), _i32(0))
+    # under a window the tile's walk begins at the block that holds the
+    # first key its first row sees; table entries behind it are never read
+    first = (jax.lax.div(jnp.maximum(_see_from(past + t0, window), _i32(0)),
+                         _i32(span)) if window else _i32(0))
     # the products' operand type: q's and the pages' own (int8 pages
     # convert exactly), never wider than what either holds
     ct = (q_ref.dtype if kbuf.dtype == jnp.int8
@@ -741,7 +788,9 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
         l_sc[:, :rows] = jnp.zeros((KV, rows, _STAT_LANES), jnp.float32)
         acc[:, :rows] = jnp.zeros((KV, rows, hd), jnp.float32)
 
-        pl.when(n_blocks > 0)(lambda: fetch(_i32(0), _i32(0)))
+        pl.when(n_blocks > (first if window else 0))(
+            lambda: fetch(first, jax.lax.rem(first, _i32(2)) if window
+                          else _i32(0)))
 
         def block(i, _):
             slot = jax.lax.rem(i, _i32(2))
@@ -751,6 +800,8 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
             kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
                       + i * _i32(span))
             ok = kv_abs <= pos                        # [rows, span]
+            if window:
+                ok &= kv_abs >= _see_from(pos, window)
 
             def head(kv):
                 s = jax.lax.dot_general(
@@ -773,6 +824,11 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
                 # m) = 0 exactly; a row with none yet (only rows without
                 # a query: key 0 is in every first block) is zeroed below
                 prob = jnp.exp(s - m_new)                     # [rows, span]
+                if window:
+                    # a row may see no key of the tile's first blocks (they
+                    # hold its earlier rows' windows): there m_new is still
+                    # -1e30 and exp(0) = 1 would count every masked key
+                    prob = jnp.where(ok, prob, 0.0)
                 alpha = jnp.exp(m_prev - m_new)               # [rows, 1]
                 l_sc[kv, :rows] = (l_sc[kv, :rows] * alpha
                                    + jnp.sum(prob, axis=-1, keepdims=True))
@@ -793,7 +849,7 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
             # PR 30)
             _loop_i32(KV, head)
 
-        jax.lax.fori_loop(_i32(0), n_blocks, block, None)
+        jax.lax.fori_loop(first, n_blocks, block, None)
         l = l_sc[:, :rows, :1]
         out = acc[:, :rows] / jnp.where(l == 0.0, 1.0, l)
         o_ref[0, :, :rows] = jnp.where(pos >= 0, out, 0.0).astype(o_ref.dtype)
@@ -809,7 +865,7 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
 
 def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
                 seq, t0, group, small, sm_scale, k_dequant, v_dequant,
-                interpret, block_len: int = 0):
+                interpret, block_len: int = 0, window: int = 0):
     """The mixed launch: grid over work items, pools left in HBM, whole
     pages gathered by the kernel."""
     items, KV, R, hd = q_items.shape
@@ -839,7 +895,7 @@ def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
     kernel = functools.partial(
         _mixed_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
         pages=int(pages), group=int(group), small=int(small),
-        has_quant=has_quant, block_len=int(block_len))
+        has_quant=has_quant, block_len=int(block_len), window=int(window))
     count_launch()
     return pl.pallas_call(
         kernel,
@@ -856,7 +912,7 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
                            seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
                            sm_scale: float, k_dequant=None, v_dequant=None,
                            interpret: Optional[bool] = None, layer=None,
-                           block_len: int = 0):
+                           block_len: int = 0, window: int = 0):
     """Attention of a ragged mixed batch (prefill chunks, decode rows and
     idle slots in one launch) over paged caches, on the packed token
     stream itself.
@@ -870,7 +926,9 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
     `block_len` (static; 0 = causal) is the block length Bd of generation
     by diffusion over blocks: the query at position p sees key j iff
     j < (p // Bd + 1) * Bd and j < past + this (`_see_limit`), so the rows
-    of one block see one another whichever tile they fall in.
+    of one block see one another whichever tile they fall in. `window`
+    (static; 0 = none) keeps of those the last `window` keys, the query's
+    own among them; the pages behind every window need not be in the table.
 
     Where whole pages can be copied (`whole_pages`) this is the mixed
     walk: the launch runs over work items reckoned here from the lengths
@@ -900,7 +958,7 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
             q_pack.reshape(B, KV, token_num * G, hd), key_cache, value_cache,
             block_tables, past, this, G, sm_scale, k_dequant=k_dequant,
             v_dequant=v_dequant, interpret=interpret, layer=layer,
-            block_len=block_len)
+            block_len=block_len, window=window)
         o_pack = o_pack.reshape(B, KV, token_num, G, hd)
         return jnp.where(tok_valid, o_pack[tok_b, :, tok_local], 0
                          ).astype(q_tok.dtype)
@@ -917,7 +975,7 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
         q_items.reshape(items, KV, tq * G, hd), key_cache, value_cache,
         jnp.maximum(block_tables.astype(jnp.int32), 0), past, this, layer,
         seq, t0, G, ts, sm_scale, k_dequant, v_dequant, interpret,
-        block_len)
+        block_len, window)
     o_items = o_items.reshape(items, KV, tq, G, hd)
     item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
     return jnp.where(tok_valid, o_items[item, :, tok_local % tq], 0
@@ -928,7 +986,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
                     seq_lens_decoder, seq_lens_this_time, group: int,
                     sm_scale: float, k_dequant=None, v_dequant=None,
                     interpret: Optional[bool] = None, layer=None,
-                    block_len: int = 0):
+                    block_len: int = 0, window: int = 0):
     """Attention over paged caches, block table walked in-kernel.
 
     q_rows [B, KV, max_q * G, hd] — per-sequence packed rows (row
@@ -951,7 +1009,9 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     says Mosaic lowers it, any other launch the BlockSpec walk (`_kernel`).
     `block_len` > 0 (static) asks for the block-causal mask
     (`paged_attention_packed`), which the BlockSpec walk has and the
-    decode walk has not: one row a sequence is no block.
+    decode walk has not: one row a sequence is no block. `window` > 0
+    (static) keeps the last `window` keys of what a row sees, in every
+    walk.
     """
     if (k_dequant is None) != (v_dequant is None):
         raise ValueError("pass both k_dequant and v_dequant or neither")
@@ -981,7 +1041,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
                 "go through paged_attention_packed")
         return _decode_call(q_rows, key_cache, value_cache, tables, past,
                             this, layer, sm_scale, k_dequant, v_dequant,
-                            interpret)
+                            interpret, window)
 
     mem = {"memory_space": pltpu.VMEM}
     # the layer axis is squeezed: the body sees [1, 1, bs, hd] pages
@@ -1031,7 +1091,8 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     )
     kernel = functools.partial(
         _kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
-        group=int(group), has_quant=has_quant, block_len=int(block_len))
+        group=int(group), has_quant=has_quant, block_len=int(block_len),
+        window=int(window))
     count_launch()
     return pl.pallas_call(
         kernel,

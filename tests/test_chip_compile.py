@@ -568,6 +568,95 @@ def test_sdar_depth7_ticks_fit_the_chip(one_chip, monkeypatch):
     print("SDAR depth-7 GiB by tok_pad:", gib)
 
 
+@pytest.mark.parametrize("group, window, launch", [
+    (8, 512, "decode"), (8, 512, "mixed"),     # Laguna's window layers
+    (6, 0, "decode"), (6, 0, "mixed"),         # its full layers: 48 / 8
+])
+def test_laguna_walks_compile(one_chip, group, window, launch):
+    """Both whole-page walks at Laguna-XS.2's shapes (8 key-value heads of
+    128, 6 and 8 query rows a head, tables of 576 entries, 32 slots, the
+    window pool's 4096 pages of 3 layers and the full pool's 20480 of 2)
+    under the window and without: the lower limit's arithmetic and row
+    counts off the 16-row tile lower for the chip."""
+    layers, blocks = (3, 4096) if window else (2, 20480)
+    batch, kv, tokens = 32, 8, 512
+    pool = _bf16(layers, blocks, kv, 16, HEAD_DIM)
+    lens = [((batch, 576), jnp.int32), ((batch,), jnp.int32),
+            ((batch,), jnp.int32)]
+    if launch == "decode":
+        def fn(q, k, v, tables, past, this, layer):
+            return pa.paged_attention(q, k, v, tables, past, this, group,
+                                      HEAD_DIM ** -0.5, interpret=False,
+                                      layer=layer, window=window)
+        shapes = [_bf16(batch, kv, group, HEAD_DIM), pool, pool, *lens,
+                  ((), jnp.int32)]
+    else:
+        def fn(q, k, v, tables, past, this, cu, layer):
+            return pa.paged_attention_packed(
+                q, k, v, tables, past, this, cu, HEAD_DIM ** -0.5,
+                interpret=False, layer=layer, window=window)
+        shapes = [_bf16(tokens, kv, group, HEAD_DIM), pool, pool, *lens,
+                  ((batch + 1,), jnp.int32), ((), jnp.int32)]
+    text = _compile(fn, one_chip, *shapes).as_text()
+    assert f"paged_attention_{launch}" in text
+
+
+def test_laguna_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
+    """The cell `serve_window_longctx_decode` as the engine builds it on a
+    TPU (`available` steered true), from the configuration file itself:
+    both executables (a tick with a prefill chunk, 512 rows; a decode tick,
+    32 rows) compile for the described v5e with both pools in their carry,
+    and the compiler counts each over 25 % and under the chip's 15.75 GiB
+    (10.56 and 10.49 GiB, PR 34)."""
+    import json
+    import os
+
+    from benchmark.drivers import closed_loop_serve_longctx as D
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import llama as L
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-xs2-serve.json")) as f:
+        file = json.load(f)
+    cfg, e = D.laguna_config(file, jnp.bfloat16), file["engine"]
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    monkeypatch.setattr(fa, "available", lambda: True)
+    monkeypatch.setattr(pa, "available", lambda: True)
+    eng = PagedServingEngine(
+        cfg, params, num_blocks=e["num_blocks"], block_size=e["block_size"],
+        max_batch=e["max_batch"], token_budget=e["token_budget"],
+        max_len=e["max_len"], pallas=True, pallas_ffn=False)
+    assert eng.window_blocks == e["window_blocks"]      # through the flag
+    build, gib = eng._build_step, {}
+
+    def compiled_not_run(tok_pad, B, *rest):
+        fn = build(tok_pad, B, *rest)
+
+        def tick(*args):
+            abstract = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            compiled = fn.lower(*abstract).compile()
+            text = compiled.as_text()
+            assert ("paged_attention_mixed" in text) == (tok_pad == 512)
+            assert ("paged_attention_decode" in text) == (tok_pad == 32)
+            m = compiled.memory_analysis()
+            gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                            + m.temp_size_in_bytes
+                            - m.alias_size_in_bytes) / 2 ** 30
+            return jnp.zeros((B + 3,), jnp.int32), args[1], args[2]
+        return tick
+
+    monkeypatch.setattr(eng, "_build_step", compiled_not_run)
+    eng.submit(list(range(1, 70)), max_new_tokens=4)
+    eng.step()                  # the prompt, one chunk
+    eng.step()                  # a decode row
+    assert set(gib) == {512, 32}
+    assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
+    print("Laguna depth-5 GiB by tok_pad:", gib)
+
+
 @pytest.mark.parametrize("top_k", [0, 50])
 def test_fused_sample_prep_compiles(one_chip, top_k):
     batch = 8
