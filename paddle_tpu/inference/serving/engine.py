@@ -168,6 +168,7 @@ class _Tick:
     pages: int = 0                  # table entries assigned (int8 pages)
     # pages allocated in (the pool, the window pool) when it was launched
     pool_pages: Tuple[int, int] = (0, 0)
+    sampled_rows: int = 0           # rows with a temperature: 0 skips the sort
 
 
 def _sample_rows(logits, keys, temps, top_ps, top_k: int):
@@ -392,7 +393,8 @@ class PagedServingEngine:
                       "spec_accepted": 0, "attn_pages_live": 0,
                       "attn_pages_fetched": 0, "attn_q_tiles": 0,
                       "attn_rows_live": 0, "attn_rows_packed": 0,
-                      "ticks_ahead": 0, "ahead_void_rows": 0}
+                      "ticks_ahead": 0, "ahead_void_rows": 0,
+                      "ticks_sampled": 0, "sampled_rows": 0}
         if cfg.num_experts:
             # routed-expert work, summed over ticks (max_load: the largest
             # seen): (row, expert) pairs, (layer, expert) groups with at
@@ -1007,24 +1009,36 @@ class PagedServingEngine:
                     with jax.named_scope("unmask"):
                         nxt = _unmask_rows(logits.reshape(B, Bd, -1),
                                            masked, quota)
-                elif fused_tick and FS.supported(B, logits.shape[-1]):
-                    # fused decode tick "+1": argmax + temperature/top-k/
-                    # top-p masking in ONE launch; the categorical draw
-                    # stays outside on bit-identical masked logits (token
-                    # parity vs stock)
-                    masked, nxt_greedy = FS.fused_sample_prep(
-                        logits, temps, top_ps, top_k)
-                    nxt_sampled = jax.vmap(
-                        lambda k_, row: jax.random.categorical(
-                            jax.random.wrap_key_data(k_), row)
-                    )(keys, masked).astype(jnp.int32)
                 else:
                     nxt_greedy = jnp.argmax(logits,
                                             axis=-1).astype(jnp.int32)
-                    nxt_sampled = _sample_rows(logits, keys, temps, top_ps,
-                                               top_k)
-                if not Bd:
-                    nxt = jnp.where(greedy, nxt_greedy, nxt_sampled)
+
+                    def sampled():
+                        if fused_tick and FS.supported(B, logits.shape[-1]):
+                            # fused decode tick "+1": the temperature/
+                            # top-k/top-p masking in ONE launch; the
+                            # categorical draw stays outside on
+                            # bit-identical masked logits (token parity vs
+                            # stock)
+                            kept, _ = FS.fused_sample_prep(
+                                logits, temps, top_ps, top_k)
+                            draw = jax.vmap(
+                                lambda k_, row: jax.random.categorical(
+                                    jax.random.wrap_key_data(k_), row)
+                            )(keys, kept).astype(jnp.int32)
+                        else:
+                            draw = _sample_rows(logits, keys, temps, top_ps,
+                                                top_k)
+                        return jnp.where(greedy, nxt_greedy, draw)
+
+                    # what only a sampled row needs (the divide, the sort
+                    # of the vocabulary, softmax, cumsum, the draw) runs in
+                    # a tick that has one; a tick of greedy rows, which is
+                    # every tick of a request that passes no temperature,
+                    # ends at the argmax. One executable either way: the
+                    # tick's own input picks the branch on the device
+                    nxt = lax.cond(jnp.any(~greedy), sampled,
+                                   lambda: nxt_greedy)
             if cfg.num_experts:
                 # the tick's expert counters ride behind the B tokens, so
                 # the host's one fetch brings both
@@ -1235,8 +1249,10 @@ class PagedServingEngine:
         tick in flight launches that one first, so it has schedule,
         prepare, dispatch twice) are contiguous children, so a step's
         self time is what no phase covers. The span's fields describe the
-        tick harvested, with ``ahead`` = 1 if it had been launched ahead
-        and ``void_rows``. The clock readings at the phase boundaries are
+        tick harvested, with ``ahead`` = 1 if it had been launched ahead,
+        ``void_rows`` and ``sampled_rows`` (the rows with a temperature; 0
+        means the tick took no sort, ``stats["ticks_sampled"]`` counts the
+        others). The clock readings at the phase boundaries are
         the ones the ring's cow.copy / prefill.chunk / decode.tick spans
         get."""
         with _tracing.phase("serve.step", tick=self.stats["steps"]) as span:
@@ -1436,6 +1452,7 @@ class PagedServingEngine:
                     seq._key, sub = jax.random.split(seq._key)
                     keys[i] = _key_bits(sub)
             cu[len(batch.items) + 1:] = pos
+            tick.sampled_rows = int(np.count_nonzero(~greedy))
 
             # per-class [tok_pad, slots] selectors: each adapter-bound chunk's
             # rows carry its slot's alpha/rank scaling; everything else is 0.0
@@ -1635,6 +1652,8 @@ class PagedServingEngine:
                       pages=cur.pages * self.cfg.num_layers)
             self.stats["steps"] += 1
             self.stats["ticks_ahead"] += cur.ahead
+            self.stats["ticks_sampled"] += cur.sampled_rows > 0
+            self.stats["sampled_rows"] += cur.sampled_rows
             self.stats["tokens_computed"] += batch.total_tokens + spec_extra
             void = 0
             if Bd:
@@ -1650,7 +1669,8 @@ class PagedServingEngine:
                     prefill_tokens=cur.n_prefill,
                     kind=("decode" if decode else
                           "block" if Bd and all(in_block) else "mixed"),
-                    ahead=int(cur.ahead), void_rows=void, **fields)
+                    ahead=int(cur.ahead), void_rows=void,
+                    sampled_rows=cur.sampled_rows, **fields)
             self._update_gauges()
             return events
 

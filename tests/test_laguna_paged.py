@@ -325,11 +325,25 @@ def test_a_uniform_config_builds_the_parents_pytree_and_programs(name):
     jaxpr and the ticks' jaxprs (stock path; kernel path in interpret
     mode, mixed and decode) of a dense, a routed-expert and a
     block-diffusion config, against digests taken with this function's
-    code on the parent commit (PR 33, tests/data/uniform_digests_pr33.json):
-    letter for letter the programs they were."""
-    with open(os.path.join(HERE, "data", "uniform_digests_pr33.json")) as f:
-        want = {k: v for k, v in json.load(f).items()
-                if k.startswith(name + ".")}
+    code: letter for letter the programs they were. PR 35 changed the
+    autoregressive ticks on purpose (the sampler behind a `cond`), so
+    tests/data/uniform_digests_pr35.json holds those as that PR's tree
+    builds them; the parameters, `forward` and every block-diffusion
+    program are still what PR 33's file holds, which is the proof that
+    block diffusion bypasses that change."""
+    def digests(pr):
+        with open(os.path.join(HERE, "data",
+                               f"uniform_digests_pr{pr}.json")) as f:
+            return {k: v for k, v in json.load(f).items()
+                    if k.startswith(name + ".")}
+
+    want, before = digests(35), digests(33)
+    assert set(want) == set(before)
+    for key in want:
+        if ".tick." not in key or name == "blockdiff":
+            assert want[key] == before[key], key
+        else:
+            assert want[key] != before[key], key
     cfg = L.LlamaConfig(**{**dict(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
         num_heads=4, num_kv_heads=2, max_seq_len=64), **UNIFORM[name]})

@@ -421,8 +421,9 @@ class TestPhasesOnTheProfilersClock:
             range(first, first + len(steps)))
         for f in steps:
             assert set(f) == {"tick", "batch", "tokens", "prefill_tokens",
-                              "kind", "ahead", "void_rows"}
+                              "kind", "ahead", "void_rows", "sampled_rows"}
             assert f["ahead"] in (0, 1) and f["void_rows"] == 0
+            assert f["sampled_rows"] == 0           # greedy requests
             assert 1 <= f["batch"] <= 2 and f["tokens"] >= f["batch"]
             assert f["kind"] in ("decode", "mixed")
         # two prompts of 12 and 13 tokens in chunks of 8: the first
@@ -434,7 +435,7 @@ class TestPhasesOnTheProfilersClock:
     def test_moe_tick_adds_its_three_counters_to_the_step(
             self, tiny_moe, tmp_path):
         """A routed-expert tick's step span carries `moe_pairs`,
-        `moe_experts_hit` and `moe_max_load` beside the five fields of a
+        `moe_experts_hit` and `moe_max_load` beside the fields of a
         dense tick (which the test above pins)."""
         eng = _factory(tiny_moe)()
         eng.submit(_prompt(tiny_moe[0], 6), max_new_tokens=2)
@@ -454,8 +455,9 @@ class TestPhasesOnTheProfilersClock:
         cfg = tiny_moe[0]
         for f in steps:
             assert set(f) == {"tick", "batch", "tokens", "prefill_tokens",
-                              "kind", "ahead", "void_rows", "moe_pairs",
-                              "moe_experts_hit", "moe_max_load"}
+                              "kind", "ahead", "void_rows", "sampled_rows",
+                              "moe_pairs", "moe_experts_hit",
+                              "moe_max_load"}
             assert f["moe_pairs"] == f["tokens"] * cfg.top_k
             assert 1 <= f["moe_max_load"] <= f["tokens"] * cfg.num_layers
             assert (cfg.top_k * cfg.num_layers <= f["moe_experts_hit"]
@@ -488,8 +490,8 @@ class TestPhasesOnTheProfilersClock:
         spans, _ = _profiled(str(tmp_path), drive)
         steps = [s[3] for s in spans if s[0] == "ptpu.serve.step"]
         assert [f["kind"] for f in steps] == ["mixed", "decode", "decode"]
-        five = {"tick", "batch", "tokens", "prefill_tokens", "kind",
-                "ahead", "void_rows"}
+        base = {"tick", "batch", "tokens", "prefill_tokens", "kind",
+                "ahead", "void_rows", "sampled_rows"}
         pair = {"attn_pages_live", "attn_pages_fetched"}
         rows = {"attn_q_tiles", "attn_rows_live", "attn_rows_packed"}
         geometry = (4, tiny[0].num_kv_heads, tiny[0].head_dim, 4,
@@ -498,14 +500,14 @@ class TestPhasesOnTheProfilersClock:
         # whole tile of 16 tokens (the budget; the small one holds 8),
         # 3 live pages, one key block fetched
         assert PA.mixed_tiles(16, 2, *geometry[1:3]) == (16, 8)
-        assert set(steps[0]) == five | pair | rows
+        assert set(steps[0]) == base | pair | rows
         assert {k: steps[0][k] for k in pair | rows} == {
             "attn_q_tiles": 1, "attn_rows_live": 9, "attn_rows_packed": 16,
             "attn_pages_live": 3,
             "attn_pages_fetched": PA.mixed_pages_per_block(*geometry)}
         P = PA.decode_pages_per_block(*geometry)
         for k, f in enumerate(steps[1:], start=1):
-            assert set(f) == five | pair
+            assert set(f) == base | pair
             assert f["attn_pages_live"] == -(-(9 + k) // 4)
             assert f["attn_pages_fetched"] == -(-(9 + k) // (4 * P)) * P
         for name in pair | rows:
