@@ -391,7 +391,7 @@ class PagedServingEngine:
                       "fused_ticks": 0, "tick_pallas_launches": 0,
                       "spec_ticks": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "attn_pages_live": 0,
-                      "attn_pages_fetched": 0, "attn_q_tiles": 0,
+                      "attn_pages_fetched": 0,
                       "attn_rows_live": 0, "attn_rows_packed": 0,
                       "ticks_ahead": 0, "ahead_void_rows": 0,
                       "ticks_sampled": 0, "sampled_rows": 0}
@@ -552,6 +552,9 @@ class PagedServingEngine:
             (self.max_batch * (3 * Bd if Bd else 1)
              + (3 if cfg.num_experts else 0),), np.int32)
         self._device_free_ns = 0
+        # the device's time at work so far, by the ticks' own intervals
+        # (`_harvest`): what a request's `device_s` is a difference of
+        self._device_busy_ns = 0
         # set by ReplicaHandle so this engine's tick spans say which
         # replica served them (the merged-trace failover story)
         self._trace_replica: Optional[int] = None
@@ -580,6 +583,7 @@ class PagedServingEngine:
         default); the rows to unmask are picked by the one rule served,
         ``low_confidence_static``."""
         with _tracing.phase("serve.submit"):
+            submit_ns = time.perf_counter_ns()
             tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
             total = len(tokens) + max(int(max_new_tokens), 0)
             Bd = self.cfg.block_length
@@ -628,7 +632,7 @@ class PagedServingEngine:
                           if deadline_s is not None else None),
                 temperature=float(temperature) if sample else 0.0,
                 top_p=float(top_p) if top_p is not None else 1.0,
-                seed=int(seed), denoising_steps=steps)
+                seed=int(seed), denoising_steps=steps, submit_ns=submit_ns)
             if trace is not None:
                 seq.trace_id, seq.parent_span = int(trace[0]), int(trace[1])
             seq._key = jax.random.PRNGKey(int(seed)) if sample else None
@@ -1254,8 +1258,14 @@ class PagedServingEngine:
         means the tick took no sort, ``stats["ticks_sampled"]`` counts the
         others). The clock readings at the phase boundaries are
         the ones the ring's cow.copy / prefill.chunk / decode.tick spans
-        get."""
-        with _tracing.phase("serve.step", tick=self.stats["steps"]) as span:
+        get, and ``perf_ns`` is the ``perf_counter_ns()`` reading taken as
+        the span opens: its start on the profile's axis less ``perf_ns``
+        lays every such reading of the process over the device's
+        operations. A request's first token is the mark
+        ``ptpu.serve.first_token`` inside ``harvest``
+        (`_emit_first_token`)."""
+        with _tracing.phase("serve.step", tick=self.stats["steps"],
+                            perf_ns=time.perf_counter_ns()) as span:
             if self._held:      # a tick settled outside step(): its events
                 events, self._held = self._held, []
                 return events
@@ -1520,7 +1530,15 @@ class PagedServingEngine:
             # block-diffusion tick by what the block's state says: their
             # harvest does both halves, as `ends[i] is None` tells it
             tick.ends = [None] * len(batch.items)
+            # the device's time at work up to this call: the ticks read so
+            # far and, of the one in flight, what lies behind. A request's
+            # device time counts from its first dispatch
+            busy0 = self._device_busy_ns
+            if prev is not None:
+                busy0 += tick.t0 - max(prev.t0, self._device_free_ns)
             for i, (seq, n) in enumerate(batch.items):
+                if seq.busy0_ns is None:
+                    seq.busy0_ns = busy0
                 if Bd:
                     if not in_block[i]:
                         tick.n_prefill += n
@@ -1563,6 +1581,7 @@ class PagedServingEngine:
             # when the one before it ends, not when it was called
             t0 = max(cur.t0, self._device_free_ns)
             self._device_free_ns = now
+            self._device_busy_ns += now - t0
             dur = (now - t0) * 1e-9
             moe = None
             if self.cfg.num_experts:
@@ -1624,9 +1643,10 @@ class PagedServingEngine:
                         self.blocks.stats["window_released"]
             if _tracing.trace_enabled():
                 # per-request tick attribution: each traced request in the
-                # batch gets a span over this tick's device interval, so a
-                # request's TTFT decomposes into queue.wait + its prefill
-                # chunks (+ cow copies) and TPOT into decode ticks
+                # batch gets a span over this tick's device interval: of a
+                # request's TTFT, queue.wait and the prefill chunks are the
+                # queue and device parts (the host's part lies in no ring
+                # span: `_emit_first_token`), and TPOT is its decode ticks
                 for (seq, n), dec in zip(batch.items, cur.was_decode):
                     if seq.trace_id:
                         _tracing.record_span(
@@ -1786,9 +1806,10 @@ class PagedServingEngine:
         """Give a sequence one harvested token (at position `at`, which
         its tick's dispatch left unknown; None: appended) and make its
         event: the end-of-sequence token finishes it unsurfaced ("stop"),
-        the max_new_tokens-th finishes it ("length"). Token stamps stay on
-        the scheduler's clock (arrival and deadlines are time.monotonic()),
-        not on the span clock."""
+        the max_new_tokens-th finishes it ("length"). Token stamps and the
+        time between tokens stay on the scheduler's clock (arrival and
+        deadlines are time.monotonic()); the first token's `ttft_s` is read
+        on the span clock (`_emit_first_token`)."""
         now = time.monotonic()
         first = seq.first_token_at is None
         if seq.eos >= 0 and tok == seq.eos:
@@ -1798,9 +1819,11 @@ class PagedServingEngine:
             del seq.tokens[len(seq.prompt) + len(seq.generated):]
             return self._finish_event(seq, "stop")
         self.scheduler.append_token(seq, tok, at)
-        _emit("serving.token", rid=seq.rid, first=first,
-              ttft_s=(now - seq.arrival) if first else None,
-              tpot_s=None if first else now - seq._prev_token_at)
+        if first:
+            self._emit_first_token(seq)
+        else:
+            _emit("serving.token", rid=seq.rid, first=False, ttft_s=None,
+                  tpot_s=now - seq._prev_token_at)
         seq._prev_token_at = now
         if len(seq.generated) >= seq.max_new_tokens:
             ev = TokenEvent(seq.rid, tok, True, "length")
@@ -1810,6 +1833,34 @@ class PagedServingEngine:
             ev = TokenEvent(seq.rid, tok, False)
         self._events_by_rid[seq.rid].append(ev)
         return ev
+
+    def _emit_first_token(self, seq: Sequence):
+        """A request's first surfaced token: where its time went, on the
+        span clock. One `ptpu.serve.first_token` mark in the profile (a
+        child of `serve.harvest`; after `_settle` it has no step around it)
+        with the request's two stamps as they are, and on the
+        `serving.token` event `ttft_s` from the entry of `submit` to here,
+        split into `queue_s` (submitted, not yet planned), `device_s` (of
+        the time since its first dispatch, what lay inside a tick's
+        interval, its own ticks' or another's: a tick's interval runs from
+        its call, or the end of the tick before it, to the end of its
+        read-back) and `host_s`, the rest: no tick was in flight or being
+        read. The executable's call and the read-back's tail, the host's
+        too, are inside a tick's interval, so `host_s` is a lower bound
+        (by 0.3 to 1.5 ms a synchronous tick on the chip, PERF.md PR 39)."""
+        now_ns = time.perf_counter_ns()
+        with _tracing.phase("serve.first_token", rid=seq.rid,
+                            submit_ns=seq.submit_ns, admit_ns=seq.admit_ns):
+            pass
+        device_ns = self._device_busy_ns - seq.busy0_ns
+        if self._in_flight is not None and self._in_flight.batch is not None:
+            # the tick launched ahead runs from where this one ended
+            device_ns += now_ns - self._device_free_ns
+        _emit("serving.token", rid=seq.rid, first=True,
+              ttft_s=(now_ns - seq.submit_ns) * 1e-9, tpot_s=None,
+              queue_s=(seq.admit_ns - seq.submit_ns) * 1e-9,
+              device_s=device_ns * 1e-9,
+              host_s=(now_ns - seq.admit_ns - device_ns) * 1e-9)
 
     def _harvest_spec(self, seq: Sequence, props: List[int], base: int,
                       all_arg: np.ndarray) -> List[TokenEvent]:

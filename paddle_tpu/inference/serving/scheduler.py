@@ -101,6 +101,16 @@ class Sequence:
     trace_id: int = 0
     parent_span: int = 0
     _qw_span: Optional[object] = None   # open queue.wait span, if any
+    # the request's time to its first token on the span clock
+    # (time.perf_counter_ns, the ring's and, through a step's `perf_ns`,
+    # the profile's): entry of `engine.submit`; the admission attempt in
+    # `schedule()` that first took it (a re-admission after a preemption
+    # keeps the first); and the engine's odometer of device time at its
+    # first dispatch. Never read by the scheduler: deadlines and `arrival`
+    # are monotonic()
+    submit_ns: int = 0
+    admit_ns: int = 0
+    busy0_ns: Optional[int] = None
     # generation by diffusion over blocks (the engine's, for a config with
     # block_length > 0): the request's denoise forwards a block at most,
     # and the open block — its ids (a masked row carries mask_token_id),
@@ -340,6 +350,9 @@ class Scheduler:
         while (self.waiting and budget > 0 and len(items) < self.max_batch
                and budget >= self.block_length):
             seq = self.waiting[0]
+            # queue wait ends where the attempt that admits begins: hashing
+            # the prompt's pages is the host at work on it, not waiting
+            now_ns = time.perf_counter_ns()
             try:
                 cached = self.blocks.allocate_sequence(seq.rid, seq.tokens)
             except NoFreeBlocksError:
@@ -359,8 +372,10 @@ class Scheduler:
                     break
             self.waiting.popleft()
             seq.status = RUNNING
-            if seq._qw_span is not None:   # queue wait ends here
-                _tracing.end_span(seq._qw_span)
+            if not seq.admit_ns:
+                seq.admit_ns = now_ns
+            if seq._qw_span is not None:
+                _tracing.end_span(seq._qw_span, end_ns=now_ns)
                 seq._qw_span = None
             self.running.append(seq)
             items.append((seq, n))

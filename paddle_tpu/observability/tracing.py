@@ -23,9 +23,11 @@ Design constraints, in order:
   ``time.perf_counter_ns()`` (monotonic, process-local).  :func:`phase`
   names the host's work as a ``jax.profiler.TraceAnnotation`` (prefix
   ``ptpu.``), so a ``jax.profiler`` trace holds the host phases and the
-  device's operations on one axis; :func:`clock_anchor` writes one
-  ``ptpu.clock`` annotation carrying ``perf_counter_ns()``, from which
-  a reader gets the offset that lays the ring's spans over that axis.
+  device's operations on one axis. Every ``ptpu.serve.step`` carries
+  the field ``perf_ns``, the ``perf_counter_ns()`` reading taken as it
+  opens: the span's start on the profile's axis less ``perf_ns`` is the
+  offset that lays any ``perf_counter_ns`` stamp of the process (the
+  ring's spans, a request's ``submit_ns`` / ``admit_ns``) over that axis.
 
 Spans form a tree per trace: the serving root span ("request") parents
 queue.wait / prefill.chunk / decode.tick / cow.copy / failover.replay;
@@ -47,7 +49,7 @@ from ..core import flags
 __all__ = [
     "Span", "trace_enabled", "new_trace", "start_span", "end_span",
     "record_span", "span", "active_spans", "active_tree", "finished_spans",
-    "to_chrome_trace", "phase", "clock_anchor", "PHASE_PREFIX",
+    "to_chrome_trace", "phase", "PHASE_PREFIX",
     "measured_schedule_stats", "reset",
 ]
 
@@ -139,12 +141,15 @@ def start_span(name: str, trace_id: int, parent_id: int = 0,
     return sp
 
 
-def end_span(sp: Optional[Span], **fields) -> Optional[Span]:
+def end_span(sp: Optional[Span], end_ns: Optional[int] = None,
+             **fields) -> Optional[Span]:
     """Close an open span (idempotent; None-tolerant so call sites can
-    thread maybe-None contexts without guards)."""
+    thread maybe-None contexts without guards). ``end_ns``: a
+    ``perf_counter_ns()`` reading the caller has taken already, for a
+    span that ends where another stamp is set."""
     if sp is None or sp.end_ns:
         return sp
-    sp.end_ns = time.perf_counter_ns()
+    sp.end_ns = time.perf_counter_ns() if end_ns is None else end_ns
     if fields:
         sp.fields.update(fields)
     with _lock:
@@ -239,8 +244,8 @@ def to_chrome_trace(pid="paddle_tpu", offset_ns: int = 0,
                     include_active: bool = False) -> dict:
     """Finished spans as a chrome://tracing document (distress dumps
     read it). ``offset_ns`` shifts the ring's ``perf_counter_ns`` stamps,
-    e.g. by the offset a ``ptpu.clock`` anchor gives onto a profile's
-    axis; tid groups spans by trace."""
+    e.g. by the offset a ``ptpu.serve.step``'s ``perf_ns`` gives onto a
+    profile's axis; tid groups spans by trace."""
     with _lock:
         spans = list(_finished)
         if include_active:
@@ -272,20 +277,6 @@ def phase(name: str, **fields):
     lock, no ``emit``, no ring write. Outside a profiler session a
     TraceAnnotation is a flag test, so "off" is the default state."""
     return jax.profiler.TraceAnnotation(PHASE_PREFIX + name, **fields)
-
-
-def clock_anchor() -> int:
-    """Emit one ``ptpu.clock`` annotation carrying
-    ``perf_ns=time.perf_counter_ns()``. A reader of the profile subtracts
-    that from the event's start on the profile's axis and has the offset
-    that maps every ring span (``queue.wait``, ``prefill.chunk``,
-    ``decode.tick``, ``cow.copy``, ``failover.replay``, ``pp.*``) onto
-    it, whichever clock the profiler uses. Returns the stamp."""
-    perf_ns = time.perf_counter_ns()
-    with jax.profiler.TraceAnnotation(PHASE_PREFIX + "clock",
-                                      perf_ns=perf_ns):
-        pass
-    return perf_ns
 
 
 # ---------------------------------------------------------------------------

@@ -625,10 +625,10 @@ def mixed_work(past, this, token_num: int, block_size: int,
     """What one mixed launch walks, reckoned on the host from the
     scheduler's own lengths (`past`, `this` [B], idle slots 0): the trip
     counts of `_mixed_kernel`, as `decode_pages_walked` mirrors
-    `_decode_kernel`. Returns a dict: `attn_q_tiles` work items with a
-    query row; `attn_rows_live` query tokens and `attn_rows_packed` the
-    token rows of the tiles they are computed on (TS or TQ an item: their
-    ratio is the tile occupancy); `attn_pages_live` pages that hold a key
+    `_decode_kernel`. Returns a dict: `attn_rows_live` query tokens and
+    `attn_rows_packed` the token rows of the tiles the work items with a
+    query row are computed on (TS or TQ an item: their ratio is the tile
+    occupancy); `attn_pages_live` pages that hold a key
     some query may see; `attn_pages_fetched` key blocks walked x P, every
     item's own (a chunk's later tiles walk its earlier keys again). Under
     a `window` a page is live if it holds one of the last `window`
@@ -657,8 +657,7 @@ def mixed_work(past, this, token_num: int, block_size: int,
                            // (pages * block_size))
         live_pages = live_pages - (np.maximum(past - (window - 1), 0)
                                    // block_size)
-    return {"attn_q_tiles": int(tiles.sum()),
-            "attn_rows_live": int(this.sum()),
+    return {"attn_rows_live": int(this.sum()),
             "attn_rows_packed": int(np.where(live <= ts, ts, tq).sum()),
             "attn_pages_live": int(live_pages.sum()),
             "attn_pages_fetched": int(blocks.sum()) * pages}

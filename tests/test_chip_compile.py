@@ -344,7 +344,7 @@ def test_default_engine_ticks_compile(one_chip, monkeypatch, hidden,
     assert ("paged_attention_mixed" in texts[False]) == whole_pages
     assert ("paged_attention_decode" in texts[True]) == whole_pages
     assert (eng.stats["attn_pages_fetched"] > 0) == whole_pages
-    assert (eng.stats["attn_q_tiles"] > 0) == whole_pages
+    assert (eng.stats["attn_rows_packed"] > 0) == whole_pages
 
 
 @pytest.mark.parametrize("rows", [16, 512])        # decode / mixed tick

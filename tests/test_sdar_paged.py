@@ -335,7 +335,7 @@ def test_mixed_work_counts_to_the_end_of_the_block():
     args = ([0, 14], [6, 2], 16, 4, 2, 2, 8, 4, 8)    # pages of 4 positions
     causal = PA.mixed_work(*args)
     block = PA.mixed_work(*args, block_len=4)
-    assert block["attn_q_tiles"] == causal["attn_q_tiles"]
+    assert block["attn_rows_packed"] == causal["attn_rows_packed"]
     assert block["attn_pages_fetched"] >= causal["attn_pages_fetched"]
 
 

@@ -873,15 +873,15 @@ class TestPallasPagedAttention:
         assert not clean[12:].any()                  # no sequence's rows
 
     def test_mixed_work_hand_counted(self, monkeypatch):
-        """`mixed_work` mirrors the kernel's trip counts: work items,
+        """`mixed_work` mirrors the kernel's trip counts: the work items'
         live and packed rows, live and fetched pages."""
         from paddle_tpu.ops.pallas import paged_attention as PA
         _steer_mixed_walk(monkeypatch, dict(TQ=8, TS=4, P=2, bs=4))
         # group 4, pages of 4, TQ 8, TS 4, key blocks of 2 pages = 8 keys
         work = PA.mixed_work([3, 5, 0, 16], [0, 1, 8, 19], 32, 4, 2, 4, 8,
                              4, 9)
-        # items: (1: 1 token), (2: 8), (3: 8, 8, 3): five, the idle slot none
-        assert work["attn_q_tiles"] == 5
+        # items: (1: 1 token), (2: 8), (3: 8, 8, 3): five, the idle slot
+        # none; each packed on a tile of 4 or 8 rows
         assert work["attn_rows_live"] == 1 + 8 + 19
         assert work["attn_rows_packed"] == 4 + 8 + 8 + 8 + 4
         # pages: 6 keys -> 2, 8 -> 2, 35 -> 9
@@ -1091,7 +1091,8 @@ class TestEnginePallas:
         assert on == off
         mixed = stats["steps"] - stats["decode_fast_steps"]
         assert mixed >= 4                         # 63 tokens, 16 a tick
-        assert stats["attn_q_tiles"] > mixed      # ticks of several items
+        # ticks of several items, each on a tile of 8 rows at least
+        assert stats["attn_rows_packed"] > 8 * mixed
         assert stats["attn_rows_live"] >= 37 + 5 + 21   # + decode rows
         assert stats["attn_rows_packed"] >= stats["attn_rows_live"]
 
@@ -1146,7 +1147,7 @@ class TestEnginePallas:
         assert eng.stats["decode_fast_steps"] == 0
         # two items, 7 + 3 tokens on two small tiles of 8; keys 0..6 and
         # 0..2: 2 + 1 pages, one block of 2 pages each
-        mixed = {"attn_q_tiles": 2, "attn_rows_live": 10,
+        mixed = {"attn_rows_live": 10,
                  "attn_rows_packed": 16, "attn_pages_live": 3,
                  "attn_pages_fetched": 4}
         assert {k: eng.stats[k] for k in mixed} == mixed
@@ -1158,7 +1159,7 @@ class TestEnginePallas:
         assert eng.stats["attn_pages_live"] == 3 + 8 + 5
         # (the two idle slots of the four walk and count nothing)
         assert eng.stats["attn_pages_fetched"] == 4 + (5 + 3) * 2
-        assert eng.stats["attn_q_tiles"] == 2     # decode ticks add none
+        assert eng.stats["attn_rows_packed"] == 16  # decode ticks add none
         # the helper alone: a full table of 5 pages is 3 blocks of 2 (the
         # wrapper pads the table to 6), and no sequence is no page
         assert PA.decode_pages_walked([20, 1], 4, 2, 8, 4, 5) == (6, 8)

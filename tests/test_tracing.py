@@ -7,8 +7,8 @@ The contracts under test, in dependency order:
   export, and the flag kill switch;
 - phases on the profiler's clock: inside a jax.profiler session every
   tick is one ptpu.serve.step with its five phases as contiguous
-  children, ptpu.clock lays the ring's spans over the profile's axis,
-  and outside a session phase() records and builds nothing;
+  children, a step's perf_ns lays the ring's spans over the profile's
+  axis, and outside a session phase() records and builds nothing;
 - stable device names: the lowered serve and train steps carry every
   named scope, and every pallas_call has a name;
 - serving propagation: one router submission = one trace whose child
@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import json
 import socket
+import time
 
 import jax
 import jax.numpy as jnp
@@ -122,7 +123,7 @@ class TestSpanPlane:
             {"pipeline.batch", "pp.F"}
         assert all(e["ph"] == "X" and e["dur"] >= 0
                    for e in doc["traceEvents"])
-        # an offset (e.g. the one a ptpu.clock anchor gives) shifts every
+        # an offset (e.g. the one a step's perf_ns gives) shifts every
         # stamp onto the other axis
         base = {e["name"]: e["ts"] for e in doc["traceEvents"]}
         moved = tracing.to_chrome_trace(pid="rank1", offset_ns=int(1e9))
@@ -300,16 +301,16 @@ class TestServingPropagation:
 
         assert not jax.profiler.TraceAnnotation.is_enabled()
         builds_outside = run()
-        with tracing.phase("serve.step", tick=0) as ph:
+        outside_ns = time.perf_counter_ns()
+        with tracing.phase("serve.step", tick=0, perf_ns=outside_ns) as ph:
             ph.set_metadata(batch=1)      # a no-op outside a session
-        tracing.clock_anchor()
         spans, builds_inside = _profiled(str(tmp_path), run)
         assert builds_inside == builds_outside
         # only what ran inside the session is in the profile
         steps = [s for s in spans if s[0] == "ptpu.serve.step"]
         assert steps and all(s[3]["tick"] >= 0 and "batch" in s[3]
                              for s in steps if "kind" in s[3])
-        assert not any(s[0] == "ptpu.clock" for s in spans)
+        assert all(s[3]["perf_ns"] > outside_ns for s in steps)
 
 
 # ---------------------------------------------------------------------------
@@ -354,7 +355,7 @@ def profiled_engine(tiny, tmp_path_factory):
     eng.run()                              # both executables built
 
     def drive():
-        anchor = tracing.clock_anchor()
+        anchor = time.perf_counter_ns()
         roots = [tracing.new_trace("request", rid=i) for i in range(2)]
         for i, root in enumerate(roots):
             eng.submit(_prompt(tiny[0], 12 + i, seed=70 + i),
@@ -365,17 +366,30 @@ def profiled_engine(tiny, tmp_path_factory):
             eng.step()
         return anchor, steps0, eng.stats["steps"] - steps0
 
+    # every reading of the span clock the drive takes, as it was returned
+    readings, clock = [], time.perf_counter_ns
+
+    def reading():
+        readings.append(clock())
+        return readings[-1]
+
     out = str(tmp_path_factory.mktemp("profile"))
-    spans, (anchor, steps0, ticks) = _profiled(out, drive)
+    time.perf_counter_ns = reading
+    try:
+        spans, (anchor, steps0, ticks) = _profiled(out, drive)
+    finally:
+        time.perf_counter_ns = clock
     ring = tracing.finished_spans()
     obs.reset()
     return {"spans": spans, "ring": ring, "anchor": anchor,
-            "steps0": steps0, "ticks": ticks}
+            "steps0": steps0, "ticks": ticks, "readings": readings}
 
 
 def _children(spans, step):
     _, start, dur, _ = step
-    return [s for s in spans if s[0] != "ptpu.serve.step"
+    # (a request's first token is a mark inside harvest, not a phase)
+    return [s for s in spans
+            if s[0] not in ("ptpu.serve.step", "ptpu.serve.first_token")
             and start <= s[1] and s[1] + s[2] <= start + dur]
 
 
@@ -420,8 +434,9 @@ class TestPhasesOnTheProfilersClock:
         assert [f["tick"] for f in steps] == list(
             range(first, first + len(steps)))
         for f in steps:
-            assert set(f) == {"tick", "batch", "tokens", "prefill_tokens",
-                              "kind", "ahead", "void_rows", "sampled_rows"}
+            assert set(f) == {"tick", "perf_ns", "batch", "tokens",
+                              "prefill_tokens", "kind", "ahead", "void_rows",
+                              "sampled_rows"}
             assert f["ahead"] in (0, 1) and f["void_rows"] == 0
             assert f["sampled_rows"] == 0           # greedy requests
             assert 1 <= f["batch"] <= 2 and f["tokens"] >= f["batch"]
@@ -454,9 +469,9 @@ class TestPhasesOnTheProfilersClock:
         assert len(steps) == ticks == 3
         cfg = tiny_moe[0]
         for f in steps:
-            assert set(f) == {"tick", "batch", "tokens", "prefill_tokens",
-                              "kind", "ahead", "void_rows", "sampled_rows",
-                              "moe_pairs", "moe_experts_hit",
+            assert set(f) == {"tick", "perf_ns", "batch", "tokens",
+                              "prefill_tokens", "kind", "ahead", "void_rows",
+                              "sampled_rows", "moe_pairs", "moe_experts_hit",
                               "moe_max_load"}
             assert f["moe_pairs"] == f["tokens"] * cfg.top_k
             assert 1 <= f["moe_max_load"] <= f["tokens"] * cfg.num_layers
@@ -474,8 +489,8 @@ class TestPhasesOnTheProfilersClock:
     def test_attention_walk_counts_ride_on_the_step(self, tiny, tmp_path):
         """A tick through the decode launch of paged attention carries
         `attn_pages_live` and `attn_pages_fetched`; a tick through the
-        mixed launch those and its work items with their live and packed
-        rows. All from the host's lengths, summed into `engine.stats`."""
+        mixed launch those and its work items' live and packed rows. All
+        from the host's lengths, summed into `engine.stats`."""
         from paddle_tpu.ops.pallas import paged_attention as PA
         eng = _factory(tiny, pallas=True)()
         eng.submit(_prompt(tiny[0], 6), max_new_tokens=2)
@@ -490,10 +505,10 @@ class TestPhasesOnTheProfilersClock:
         spans, _ = _profiled(str(tmp_path), drive)
         steps = [s[3] for s in spans if s[0] == "ptpu.serve.step"]
         assert [f["kind"] for f in steps] == ["mixed", "decode", "decode"]
-        base = {"tick", "batch", "tokens", "prefill_tokens", "kind",
-                "ahead", "void_rows", "sampled_rows"}
+        base = {"tick", "perf_ns", "batch", "tokens", "prefill_tokens",
+                "kind", "ahead", "void_rows", "sampled_rows"}
         pair = {"attn_pages_live", "attn_pages_fetched"}
-        rows = {"attn_q_tiles", "attn_rows_live", "attn_rows_packed"}
+        rows = {"attn_rows_live", "attn_rows_packed"}
         geometry = (4, tiny[0].num_kv_heads, tiny[0].head_dim, 4,
                     eng.max_blocks_per_seq)
         # the mixed tick: 9 tokens at past 0 are one work item on the
@@ -502,7 +517,7 @@ class TestPhasesOnTheProfilersClock:
         assert PA.mixed_tiles(16, 2, *geometry[1:3]) == (16, 8)
         assert set(steps[0]) == base | pair | rows
         assert {k: steps[0][k] for k in pair | rows} == {
-            "attn_q_tiles": 1, "attn_rows_live": 9, "attn_rows_packed": 16,
+            "attn_rows_live": 9, "attn_rows_packed": 16,
             "attn_pages_live": 3,
             "attn_pages_fetched": PA.mixed_pages_per_block(*geometry)}
         P = PA.decode_pages_per_block(*geometry)
@@ -522,15 +537,21 @@ class TestPhasesOnTheProfilersClock:
         for sub in submits:
             assert not any(st[1] <= sub[1] < st[1] + st[2] for st in steps)
 
-    def test_clock_anchor_lays_ring_spans_over_the_profile(
+    def test_step_perf_ns_lays_ring_spans_over_the_profile(
             self, profiled_engine):
         spans, ring = profiled_engine["spans"], profiled_engine["ring"]
-        (clock,) = [s for s in spans if s[0] == "ptpu.clock"]
-        assert clock[3]["perf_ns"] == profiled_engine["anchor"]
-        # the one event gives the offset from the ring's clock
-        # (perf_counter_ns) to the profile's axis
-        offset = clock[1] - clock[3]["perf_ns"]
         steps = [s for s in spans if s[0] == "ptpu.serve.step"]
+        # a step's perf_ns is a reading of the ring's clock as it was
+        # returned, one a step, in the steps' order
+        stamps = [s[3]["perf_ns"] for s in steps]
+        assert stamps == sorted(set(stamps)) and stamps[0] > \
+            profiled_engine["anchor"]
+        assert set(stamps) <= set(profiled_engine["readings"])
+        # so any step gives the offset from that clock (perf_counter_ns)
+        # to the profile's axis, and all give the same one
+        offset = steps[0][1] - stamps[0]
+        assert all(abs(s[1] - s[3]["perf_ns"] - offset) < 2_000_000
+                   for s in steps)
         ticks = [d for d in ring
                  if d["name"] in ("decode.tick", "prefill.chunk")]
         assert any(d["name"] == "decode.tick" for d in ticks)
@@ -562,7 +583,8 @@ class TestPhasesOnTheProfilersClock:
         with tracing.phase("serve.step", tick=1):
             with tracing.phase("serve.wait"):
                 pass
-        tracing.clock_anchor()
+        with tracing.phase("serve.first_token", rid=1, submit_ns=1):
+            pass
         spans, _ = _profiled(str(tmp_path), lambda: None)
         assert spans == []
 

@@ -145,5 +145,5 @@ def test_host_mirrors_count_the_walk_behind_the_window(small_blocks):
     assert (mixed["attn_pages_fetched"], plain["attn_pages_fetched"]) == (
         4 * (6 - 2), 4 * 6)                       # blocks of 4 pages
     assert mixed["attn_pages_live"] == 23 - (62 - W + 1) // BS
-    assert {k: mixed[k] for k in ("attn_q_tiles", "attn_rows_live")} == {
-        k: plain[k] for k in ("attn_q_tiles", "attn_rows_live")}
+    assert {k: mixed[k] for k in ("attn_rows_packed", "attn_rows_live")} == {
+        k: plain[k] for k in ("attn_rows_packed", "attn_rows_live")}
