@@ -50,6 +50,16 @@ come out as TokenEvents of one tick. What the last block holds beyond
 ``max_new_tokens`` is computed and dropped. A tick that holds only block
 rows takes the ``tok_pad = max_batch * Bd`` executable, one with a prefill
 chunk the ``token_budget`` one; both run the mixed attention launch.
+These ticks are launched ahead like any other. A forward takes exactly k_s
+rows, so the masks a block has left, hence whether its next forward is a
+denoise or the commit, that forward's quota, the block behind a commit
+(all masked) and the tick in which ``max_new_tokens`` is reached are counts
+the host holds before it reads a tick. Which rows were taken and their ids
+it does not hold: the tick behind gets the block as the tick in flight got
+it, and on the device takes x0 and drops the mask flag where that tick's
+output says a row was taken. The sequence itself (``block_ids``,
+``block_masked``, ``num_computed``, tokens, events, counters) is written at
+the harvest alone, a tick late.
 Sampling (temperature > 0), a draft model, LoRA adapters and int8 pages
 are refused with a block-diffusion config.
 
@@ -151,12 +161,16 @@ class _Tick:
     out: Any = None                 # the executable's `nxt`, on the device
     all_arg: Any = None             # a speculative tick's verify read
     t0: int = 0                     # perf_counter_ns at the call
-    # item index by rid of the sequences this tick yields a token: the
-    # entry of `out` that a row launched behind it feeds from
+    # item index by rid of the sequences this tick yields a token (under
+    # block diffusion: whose open block it runs): the entry of `out` that a
+    # row launched behind it feeds from
     slots: Dict[int, int] = field(default_factory=dict)
     # per item, `num_computed` once this tick's rows are in (None: a
     # speculative chunk or a block-diffusion tick, counted at harvest)
     ends: List[Optional[int]] = field(default_factory=list)
+    # block diffusion: the `quota` the tick was called with, per item (0
+    # beside a block: its commit forward)
+    quota: Any = None
     n_prefill: int = 0
     spec_plan: Dict[int, List[int]] = field(default_factory=dict)
     in_block: List[bool] = field(default_factory=list)
@@ -877,6 +891,17 @@ class PagedServingEngine:
         use_pallas = self.pallas and ("decode" if decode else True)
         fused_tick = bool(ffn_mode) and use_pallas == "decode"
 
+        def block_rows(cu):
+            # the packed row of every slot's last token (cu[1:] - 1; an idle
+            # slot's is garbage the host never reads); under block
+            # diffusion of its last Bd rows, its whole block: [B (* Bd)]
+            last = jnp.clip(cu[1:] - 1, 0, tok_pad - 1)
+            if Bd:
+                last = jnp.clip(
+                    last[:, None] - (Bd - 1 - jnp.arange(Bd))[None],
+                    0, tok_pad - 1).reshape(-1)
+            return last
+
         @functools.partial(jax.jit, donate_argnums=(1, 2))
         def step_fn(params, key_cache, value_cache, kv_scales, tokens,
                     block_tables, cu_seqlens_q, seq_lens_decoder,
@@ -887,8 +912,20 @@ class PagedServingEngine:
             # the host does not hold yet from the last tick's output, on
             # the device: `prev` is that tick's `nxt` (any earlier one when
             # no row needs it) and `feed` [tok_pad] the entry of it a row
-            # embeds, -1 for a row whose id the host wrote into `tokens`
-            if feed is not None:
+            # embeds, -1 for a row whose id the host wrote into `tokens`.
+            # Under block diffusion `prev` is the last forward's transfer
+            # and `feed` the block row of it [B * Bd, 3] that a row follows:
+            # the host sent the block as that forward got it, and a row it
+            # took carries its x0 from here on and is masked no longer
+            if feed is not None and Bd:
+                with jax.named_scope("sample"), jax.named_scope("unmask"):
+                    was = prev[:B * Bd * 3].reshape(B * Bd, 3)[
+                        jnp.maximum(feed, 0)]
+                    took = (feed >= 0) & (was[:, 2] > 0)
+                    tokens = jnp.where(took, was[:, 0], tokens)
+                    masked = masked & ~took[block_rows(cu_seqlens_q)
+                                            ].reshape(B, Bd)
+            elif feed is not None:
                 tokens = jnp.where(feed >= 0, prev[jnp.maximum(feed, 0)],
                                    tokens)
             # named scopes: every device operation of the tick belongs to
@@ -996,15 +1033,8 @@ class PagedServingEngine:
                     (x, kcs, vcs), loads = lax.scan(
                         body, (x, key_cache, value_cache), xs)
             with jax.named_scope("head"):
-                # last-token hidden state per slot (cu[1:]-1; idle slots
-                # gather garbage the host never reads); under block
-                # diffusion the slot's last Bd rows, its whole block
-                last_idx = jnp.clip(cu_seqlens_q[1:] - 1, 0, tok_pad - 1)
-                if Bd:
-                    last_idx = jnp.clip(
-                        last_idx[:, None] - (Bd - 1 - jnp.arange(Bd))[None],
-                        0, tok_pad - 1).reshape(-1)
-                hlast = x[last_idx]                          # [B (* Bd), d]
+                # last-token hidden state per slot, or its whole block's
+                hlast = x[block_rows(cu_seqlens_q)]          # [B (* Bd), d]
                 hlast = L.rms_norm(hlast, params["final_norm"], cfg.rms_eps)
                 logits = Q.matmul_param(hlast, params, "lm_head"
                                         ).astype(jnp.float32)  # [B (* Bd), V]
@@ -1233,19 +1263,24 @@ class PagedServingEngine:
         plans a mixed batch from counts the host holds, calls the
         executable and advances every scheduled sequence by count:
         ``num_computed`` by its rows, and one position of unknown id where
-        the tick yields it a token. Its *harvest* (wait, then harvest)
+        the tick yields it a token (a block-diffusion sequence: the rows
+        ``num_computed`` will take at the harvest, in ``in_flight``). Its
+        *harvest* (wait, then harvest)
         reads the tick's ids — the one sync —, writes them into the
         sequences, runs `_emit_token` / `TokenEvent` / completions, hashes
         the pages that filled into the prefix cache, and keeps the books.
 
         The next tick is launched before this one is harvested wherever it
         is determined without this one's ids (`_next_is_determined`): its
-        decode rows take their ids from this tick's output on the device,
-        so the chip runs it while the host reads, harvests and plans.
-        `step()` then returns this tick's events with the next one in
-        flight. What the host learns a tick late — an end-of-sequence id,
-        a deadline — leaves the sequence a row in the tick in flight: it is
-        computed and dropped (``stats["ahead_void_rows"]``).
+        decode rows take their ids, and the rows of an open block their ids
+        and mask flags, from this tick's output on the device, so the chip
+        runs it while the host reads, harvests and plans. `step()` then
+        returns this tick's events with the next one in flight. Planned
+        with every id read are only a speculative tick, the tick behind one
+        that frees a slot by count, and one that would preempt. What the
+        host learns a tick late — an end-of-sequence id, a deadline —
+        leaves the sequence a row (a block's Bd rows) in the tick in
+        flight: computed and dropped (``stats["ahead_void_rows"]``).
 
         In a ``jax.profiler`` trace the call is ``ptpu.serve.step`` and
         its phases (schedule, prepare, dispatch of the tick launched here;
@@ -1296,23 +1331,32 @@ class PagedServingEngine:
     def _next_is_determined(self, cur: "_Tick") -> bool:
         """Whether the tick after `cur` can be planned before `cur`'s ids
         are read: everything the scheduler decides for it must be a count
-        the host holds now. Not after a block-diffusion tick (the next
-        `block_ids`, `masked` and `quota` are a host function of this
-        tick's result) and not with a draft model (a speculative tick
-        advances by the accepted length, and the next proposal starts from
-        this tick's id): those engines keep the synchronous order, in this
-        same loop. Not when a sequence of `cur` reaches `max_new_tokens` in
-        it: a tick that frees a slot is followed by one planned with full
-        knowledge, so whoever waits for the slot, or submits when the last
-        token is seen, is admitted as in the synchronous order. And not
-        when planning would have to preempt: a preempted sequence
-        re-prefills from its ids."""
-        if (cur.batch is None or self.cfg.block_length
-                or (self.spec is not None and self.spec_k > 0)):
+        the host holds now. A block-diffusion tick is such: a forward takes
+        exactly min(quota, masks left) rows (`_unmask_rows`), so which
+        forward of its block a sequence has next, that forward's quota and
+        where the block stands are counts; which rows were taken, and
+        their ids, stay on the device (`_launch`). Not with a draft model
+        (a speculative tick advances by the accepted length, and the next
+        proposal starts from this tick's id): that engine alone keeps the
+        synchronous order, in this same loop. Not when a sequence of `cur`
+        reaches `max_new_tokens` in it (under block diffusion: by the
+        commit forward it has in `cur`): a tick that frees a slot is
+        followed by one planned with full knowledge, so whoever waits for
+        the slot, or submits when the last token is seen, is admitted as in
+        the synchronous order. And not when planning would have to
+        preempt: a preempted sequence re-prefills from its ids."""
+        if cur.batch is None or (self.spec is not None and self.spec_k > 0):
             return False
+        Bd = self.cfg.block_length
         for i in cur.slots.values():
             seq = cur.batch.items[i][0]
-            if len(seq.generated) + 1 >= seq.max_new_tokens:
+            new = 1
+            if Bd:
+                # a denoise forward yields no token, a commit forward the
+                # block's rows behind the sequence's tokens
+                new = 0 if cur.quota[i] else Bd - (len(seq.tokens)
+                                                   - seq.num_computed)
+            if new and len(seq.generated) + new >= seq.max_new_tokens:
                 return False
         return self.scheduler.next_fits()
 
@@ -1392,10 +1436,11 @@ class PagedServingEngine:
             tok_pad, B = self.token_budget, self.max_batch
             Bd = self.cfg.block_length
             # block diffusion: which items bring their open block (the
-            # others are prefill chunks), opened here where it is not yet
+            # others are prefill chunks)
             in_block = [bool(Bd) and self.scheduler.prefill_left(seq) <= 0
                         for seq, _ in batch.items]
             for (seq, _n), blk in zip(batch.items, in_block):
+                # a sequence's first block; the next opens as one commits
                 if blk and seq.block_ids is None:
                     self._open_block(seq)
             if Bd and all(in_block):
@@ -1412,7 +1457,8 @@ class PagedServingEngine:
                 tok_pad = B
             tokens = np.zeros((tok_pad,), np.int32)
             # the entry of `prev`'s output a row embeds, where the host
-            # does not hold its id yet (-1: `tokens` has it)
+            # does not hold its id yet (-1: `tokens` has it); for a row of
+            # a block with a denoise forward in flight, its row there
             feed = np.full((tok_pad,), -1, np.int32)
             cu = np.zeros((B + 1,), np.int32)
             dec_lens = np.zeros((B,), np.int32)
@@ -1430,12 +1476,26 @@ class PagedServingEngine:
             masked = np.zeros((B, Bd), bool)
             pos = 0
             for i, (seq, n) in enumerate(batch.items):
-                chunk = seq.tokens[seq.num_computed:seq.num_computed + n]
+                start = seq.planned()
+                chunk = seq.tokens[start:start + n]
                 if in_block[i]:
-                    chunk = seq.block_ids
-                    quota[i] = min(sum(seq.block_masked),
-                                   -(-Bd // seq.denoising_steps))
-                    masked[i] = seq.block_masked
+                    chunk, flags = seq.block_ids, seq.block_masked
+                    left = sum(flags)
+                    j = None if prev is None else prev.slots.get(seq.rid)
+                    if j is not None and left:
+                        # a denoise forward of this block is in flight: it
+                        # takes `prev.quota[j]` of the rows, which ones the
+                        # device says (`feed`); the block goes as it got it
+                        left -= prev.quota[j]
+                        feed[pos:pos + Bd] = np.arange(j * Bd, (j + 1) * Bd)
+                    elif j is not None:
+                        # its commit forward is: this is the next block,
+                        # every row masked, which the sequence will hold
+                        # once the commit is harvested
+                        chunk = [self.cfg.mask_token_id] * Bd
+                        flags, left = [True] * Bd, Bd
+                    quota[i] = min(left, -(-Bd // seq.denoising_steps))
+                    masked[i] = flags
                 props = spec_plan.get(i)
                 if props is not None:
                     chunk = list(chunk) + props   # [t_c, d1..dk]: verify rows
@@ -1448,7 +1508,7 @@ class PagedServingEngine:
                     feed[pos + n - 1] = prev.slots[seq.rid]
                 pos += n
                 cu[i + 1] = pos
-                dec_lens[i] = seq.num_computed
+                dec_lens[i] = start
                 this_lens[i] = n
                 row = self.blocks.block_table(seq.rid)
                 tables[i, :len(row)] = row
@@ -1526,9 +1586,14 @@ class PagedServingEngine:
                                                       - launches0)
             # progress by count: every plain row advances its sequence now,
             # so the next tick can be planned before this one is read. A
-            # speculative chunk advances by what is accepted and a
-            # block-diffusion tick by what the block's state says: their
-            # harvest does both halves, as `ends[i] is None` tells it
+            # speculative chunk advances by what is accepted, at its
+            # harvest, and so no tick is planned behind it. A
+            # block-diffusion tick advances `num_computed` at its harvest
+            # too (`ends[i] is None` for both), because the sequence keeps
+            # the state its tick in flight was called with until then
+            # (`_harvest_blocks`); the rows that will count, a prefill
+            # chunk's and a commit forward's, stand in `seq.in_flight` for
+            # the plan of the tick behind
             tick.ends = [None] * len(batch.items)
             # the device's time at work up to this call: the ticks read so
             # far and, of the one in flight, what lies behind. A request's
@@ -1542,6 +1607,11 @@ class PagedServingEngine:
                 if Bd:
                     if not in_block[i]:
                         tick.n_prefill += n
+                        seq.in_flight += n
+                    else:
+                        tick.slots[seq.rid] = i
+                        if not quota[i]:
+                            seq.in_flight += Bd
                 elif i not in spec_plan:
                     if self.scheduler.on_dispatched(seq, n):
                         tick.slots[seq.rid] = i
@@ -1553,6 +1623,7 @@ class PagedServingEngine:
             tick.tok_pad, tick.decode, tick.ffn_mode = (tok_pad, decode,
                                                         bool(ffn_mode))
             tick.lens = (cu, dec_lens, this_lens)
+            tick.quota = quota
             tick.was_decode = was_decode
             if self.quant_kv:
                 tick.pages = int((tables >= 0).sum())
@@ -1675,13 +1746,14 @@ class PagedServingEngine:
             self.stats["ticks_sampled"] += cur.sampled_rows > 0
             self.stats["sampled_rows"] += cur.sampled_rows
             self.stats["tokens_computed"] += batch.total_tokens + spec_extra
-            void = 0
+            void0 = self.stats["ahead_void_rows"]
             if Bd:
                 events.extend(self._harvest_blocks(
                     batch, in_block, nxt.reshape(B, Bd, 3)))
             else:
-                void = self._harvest_rows(cur, nxt, all_arg, events)
-            self.stats["ahead_void_rows"] += void
+                self.stats["ahead_void_rows"] += self._harvest_rows(
+                    cur, nxt, all_arg, events)
+            void = self.stats["ahead_void_rows"] - void0
             if span is not None:
                 span.set_metadata(
                     batch=len(batch.items),
@@ -1755,7 +1827,7 @@ class PagedServingEngine:
         tokens hold behind the last whole block (the prompt's tail, for the
         first block alone) as known rows, the rest masked."""
         Bd = self.cfg.block_length
-        tail = seq.tokens[seq.num_computed:]
+        tail = seq.tokens[seq.planned():]
         seq.block_ids = tail + [self.cfg.mask_token_id] * (Bd - len(tail))
         seq.block_masked = [False] * len(tail) + [True] * (Bd - len(tail))
         seq.block_forwards = 0
@@ -1768,12 +1840,26 @@ class PagedServingEngine:
         `num_computed`. A block with masked rows had a denoise forward:
         the rows taken become tokens, nothing is committed. A block with
         none had its commit forward: its keys and values stand, it is
-        committed, and its new tokens come out together."""
+        committed, its new tokens come out together, and the sequence's
+        next block opens.
+
+        On entry a sequence holds what this tick was called with: its
+        `num_computed`, `block_ids` and `block_masked` are written here and
+        nowhere else, a tick late where the next was launched ahead (which
+        planned from counts and took the rest from this tick's `out` on
+        the device). The rows of a sequence that finished meanwhile (an
+        end-of-sequence id inside its last block, a deadline) were computed
+        and are dropped, as `_harvest_rows` drops a plain row: counted in
+        `ahead_void_rows` and in nothing else."""
         Bd = self.cfg.block_length
         events: List[TokenEvent] = []
         for i, (seq, n) in enumerate(batch.items):
+            if seq.status == FINISHED:
+                self.stats["ahead_void_rows"] += n
+                continue
             if not in_block[i]:
                 self.scheduler.on_computed(seq, n)
+                seq.in_flight -= n
                 continue
             self.stats["diff_rows"] += Bd
             if any(seq.block_masked):
@@ -1786,10 +1872,10 @@ class PagedServingEngine:
                 continue
             self.stats["diff_commit_forwards"] += 1
             self.stats["diff_blocks_committed"] += 1
+            seq.in_flight -= Bd
             new = seq.block_ids[len(seq.tokens) - seq.num_computed:]
             _emit("serving.block_commit", rid=seq.rid, start=seq.num_computed,
                   tokens=len(new), denoise_forwards=seq.block_forwards)
-            seq.block_ids = seq.block_masked = None
             for tok in new:
                 # what the last block holds beyond max_new_tokens (or
                 # behind an end-of-sequence token) is computed and dropped
@@ -1799,6 +1885,7 @@ class PagedServingEngine:
                     break
             else:
                 self.scheduler.on_computed(seq, Bd)
+                self._open_block(seq)
         return events
 
     def _emit_token(self, seq: Sequence, tok: int,
@@ -1910,6 +1997,10 @@ class PagedServingEngine:
             self.adapters.unpin(seq.adapter)
         if self.spec is not None:
             self.spec.forget(seq.rid)
+        if seq.block_masked:
+            # a finished sequence denoises nothing: what it has in flight
+            # is dropped, and whoever reads its state sees no masked row
+            seq.block_masked = [False] * len(seq.block_masked)
         self._completions.append(Completion(seq.rid, list(seq.prompt),
                                             list(seq.generated), reason))
         _emit("serving.complete", rid=seq.rid, reason=reason,

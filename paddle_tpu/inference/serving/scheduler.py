@@ -117,17 +117,26 @@ class Sequence:
     # whether each row is still masked (the sequence's own state, never
     # `id == mask_token_id`: a prompt may hold that id), and the denoise
     # forwards it has had. They outlive a preemption: the block's rows
-    # depend only on what is committed before it
+    # depend only on what is committed before it. A block-diffusion tick
+    # writes none of this, nor `num_computed`, before its harvest; the rows
+    # of a prefill chunk or a commit forward that `num_computed` will take
+    # then stand in `in_flight` from the dispatch on, for the next plan
     denoising_steps: int = 0
     block_ids: Optional[List[int]] = None
     block_masked: Optional[List[bool]] = None
     block_forwards: int = 0
+    in_flight: int = 0
 
     def __post_init__(self):
         self.tokens = list(self.prompt)
 
     def remaining(self) -> int:
         return len(self.tokens) - self.num_computed
+
+    def planned(self) -> int:
+        """Positions computed once every dispatched tick is in: what the
+        next tick of the sequence starts behind."""
+        return self.num_computed + self.in_flight
 
 
 @dataclass
@@ -246,7 +255,7 @@ class Scheduler:
         """Evict a running sequence: free its pages, reset to
         recompute-on-resume (the whole prompt+generated re-prefills when
         capacity returns — exactness over cache-migration complexity)."""
-        if seq.tokens[-1] == UNKNOWN:
+        if seq.tokens[-1] == UNKNOWN or seq.in_flight:
             raise RuntimeError(
                 f"sequence {seq.rid} has a row in flight: it re-prefills "
                 "from its ids, so the engine plans a tick that preempts "
@@ -295,7 +304,7 @@ class Scheduler:
         if not self.block_length:
             return seq.remaining()
         bd = self.block_length
-        return len(seq.tokens) // bd * bd - seq.num_computed
+        return len(seq.tokens) // bd * bd - seq.planned()
 
     def _chunk(self, seq: Sequence, budget: int) -> int:
         """Rows to run for `seq` in this step inside `budget`: a prefill
@@ -330,7 +339,7 @@ class Scheduler:
             while True:
                 try:
                     self.blocks.ensure_capacity(seq.rid,
-                                                seq.num_computed + n)
+                                                seq.planned() + n)
                     break
                 except NoFreeBlocksError:
                     # block exhaustion: evict the lowest-priority running
@@ -366,7 +375,7 @@ class Scheduler:
                 # chunk's now, or the sequence is not admitted
                 try:
                     self.blocks.ensure_capacity(seq.rid,
-                                                seq.num_computed + n)
+                                                seq.planned() + n)
                 except NoFreeBlocksError:
                     self.blocks.free_sequence(seq.rid)
                     break
@@ -392,7 +401,7 @@ class Scheduler:
         need = wneed = 0
         for seq in self.running:
             n = self._chunk(seq, self.token_budget)
-            grow = self.blocks.growth(seq.rid, seq.num_computed + n)
+            grow = self.blocks.growth(seq.rid, seq.planned() + n)
             need, wneed = need + grow[0], wneed + grow[1]
         return self.blocks.can_allocate(need, wneed)
 

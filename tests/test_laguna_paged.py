@@ -329,8 +329,11 @@ def test_a_uniform_config_builds_the_parents_pytree_and_programs(name):
     autoregressive ticks on purpose (the sampler behind a `cond`), so
     tests/data/uniform_digests_pr35.json holds those as that PR's tree
     builds them; the parameters, `forward` and every block-diffusion
-    program are still what PR 33's file holds, which is the proof that
-    block diffusion bypasses that change."""
+    program were still what PR 33's file holds, which is the proof that
+    block diffusion bypassed that change. PR 40 changed the
+    block-diffusion ticks on purpose (a block's ids and mask flags taken
+    from the last tick's transfer), and nothing else: its file holds those
+    two, every autoregressive program is PR 35's."""
     def digests(pr):
         with open(os.path.join(HERE, "data",
                                f"uniform_digests_pr{pr}.json")) as f:
@@ -344,6 +347,11 @@ def test_a_uniform_config_builds_the_parents_pytree_and_programs(name):
             assert want[key] == before[key], key
         else:
             assert want[key] != before[key], key
+    now = digests(40)
+    assert set(now) == {k for k in want
+                        if ".tick." in k and name == "blockdiff"}
+    assert all(now[key] != want[key] for key in now)
+    want.update(now)
     cfg = L.LlamaConfig(**{**dict(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
         num_heads=4, num_kv_heads=2, max_seq_len=64), **UNIFORM[name]})

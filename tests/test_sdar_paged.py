@@ -14,6 +14,7 @@ confidences agree to 1e-4 of themselves (float32 sums in another order).
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ import pytest
 from benchmark.lib import reference_sdar as R
 from benchmark.lib.agreement_blockdiff import record_forwards
 from paddle_tpu.inference.serving import PagedServingEngine
+from paddle_tpu.inference.serving.scheduler import UNKNOWN
 from paddle_tpu.models import llama as L
 from paddle_tpu.ops.kernels import serving_attention as SA
 from paddle_tpu.ops.pallas import paged_attention as PA
@@ -122,9 +124,9 @@ def test_engine_equals_the_reference_loop(tiny, prompt_len, steps, pallas):
     assert s["diff_rows"] == BD * (blocks + len(forwards))
     assert s["diff_tokens_unmasked"] == first + BD * (blocks - 1)
     assert s["decode_fast_steps"] == 0      # no one-row launch, ever
-    # and no tick launched ahead: the next block's ids, masks and quota are
-    # a host function of this tick's result
-    assert s["ticks_ahead"] == 0 == s["ahead_void_rows"]
+    # ticks are launched ahead (which forward a block has next and its
+    # quota are counts), and every row of them was wanted
+    assert s["ticks_ahead"] > 0 == s["ahead_void_rows"]
 
 
 def test_chunked_prefill_cut_mid_prompt_and_two_sequences_at_different_steps(
@@ -236,6 +238,275 @@ def test_stream_yields_a_block_at_a_time_and_eos_stops_inside_one(tiny):
     (done,) = eng.run()
     assert done.output_tokens == want[:cut] and done.finish_reason == "stop"
     assert rid == done.rid
+
+
+# ---- the block tick launched ahead ------------------------------------------
+#
+# `PagedServingEngine.step` launches a block-diffusion tick before the last
+# one is read wherever counts determine it: which forward a block has next,
+# its quota, where the block stands. The rows the last forward took, and
+# their ids, reach the next one on the device. Contract, as for plain rows
+# (tests/test_serving_ahead.py): the events of every `step()` call, every
+# completion, every `diff_*` counter and every recorded forward are those of
+# the synchronous order (`_next_is_determined` patched to False).
+
+DIFF = ("diff_denoise_forwards", "diff_commit_forwards",
+        "diff_blocks_committed", "diff_tokens_unmasked", "diff_rows")
+# more requests than slots, every remainder of the prompt, every step count,
+# a prompt in chunks: (prompt_len, denoising_steps, max_new_tokens)
+MIXED = [(43, 2, 9), (10, 4, 14), (21, 1, 8), (7, 2, 17), (12, 4, 5),
+         (18, 1, 11), (5, 2, 12)]
+
+
+def drive(eng):
+    """Step until idle: the event list of every call, as plain tuples."""
+    calls = []
+    while eng.has_work():
+        calls.append([(e.rid, e.token, e.finished, e.reason)
+                      for e in eng.step()])
+    return calls
+
+
+def synchronous(eng, monkeypatch):
+    monkeypatch.setattr(eng, "_next_is_determined", lambda cur: False)
+
+
+def mixed_run(tiny, pallas, ahead, monkeypatch):
+    cfg, params = tiny
+    eng = engine(cfg, params, pallas=pallas, prefill_chunk=16)
+    if not ahead:
+        synchronous(eng, monkeypatch)
+    seqs = [eng.scheduler.get(eng.submit(
+        prompt_of(n, seed=n), max_new_tokens=new, denoising_steps=steps))
+        for n, steps, new in MIXED]
+    calls = drive(eng)
+    done = {c.rid: (c.output_tokens, c.finish_reason) for c in eng.run()}
+    return eng, seqs, calls, done
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["stock", "kernel"])
+def test_ahead_events_counters_and_forwards_are_the_synchronous_order(
+        tiny, pallas, monkeypatch):
+    eng, seqs, calls, done = mixed_run(tiny, pallas, True, monkeypatch)
+    sync, _, calls0, done0 = mixed_run(tiny, pallas, False, monkeypatch)
+    assert calls == calls0            # one tick's events a call, in order
+    assert done == done0 and all(r == "length" for _, r in done.values())
+    assert sync.stats["ticks_ahead"] == 0 == sync.stats["ahead_void_rows"]
+    assert eng.stats["ticks_ahead"] > eng.stats["steps"] // 2
+    assert eng.stats["steps"] == sync.stats["steps"] == len(calls)
+    for name in DIFF:
+        assert eng.stats[name] == sync.stats[name] > 0, name
+    assert eng.stats["tokens_computed"] == (
+        sync.stats["tokens_computed"] + eng.stats["ahead_void_rows"])
+    # the recorder reads a sequence's state on entry to `_harvest_blocks`:
+    # it is what the harvested tick was launched with, forward for forward
+    assert eng.forwards == sync.forwards
+    # no new executable, and nothing unknown or masked left behind
+    assert eng.stats["step_builds"] == sync.stats["step_builds"]
+    assert len(eng._step_fns) == len(sync._step_fns) == 2
+    for seq in seqs:
+        assert seq.tokens == seq.prompt + seq.generated
+        assert UNKNOWN not in seq.tokens and MASK not in seq.generated
+        assert seq.in_flight == 0
+    assert eng.blocks.num_allocated() == 0
+    if not pallas:      # and the tokens are the reference loop's
+        cfg, params = tiny
+        for seq, (n, steps, new) in zip(seqs, MIXED):
+            want, forwards = reference(cfg, params, prompt_of(n, seed=n),
+                                       new, steps)
+            assert done[seq.rid][0] == want
+            same_forwards(eng, seq.rid, forwards)
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["stock", "kernel"])
+def test_two_tick_shapes_are_two_executables_either_way(tiny, pallas,
+                                                        monkeypatch):
+    """A budget above max_batch x Bd: a tick of blocks alone and a tick
+    with a prefill chunk are the engine's two executables, launched ahead
+    or not."""
+    cfg, params = tiny
+    builds = []
+    for ahead in (True, False):
+        eng = engine(cfg, params, pallas=pallas, max_batch=2,
+                     token_budget=16)
+        if not ahead:
+            synchronous(eng, monkeypatch)
+        for n in (19, 6, 9):
+            eng.submit(prompt_of(n, seed=n), max_new_tokens=9,
+                       denoising_steps=2)
+        eng.run()
+        assert sorted(k[0] for k in eng._step_fns) == [8, 16]
+        builds.append((eng.stats["step_builds"], len(eng._step_fns)))
+    assert builds == [(2, 2), (2, 2)]
+
+
+def step_until_block_in_flight(eng, rid, blocks_out=1):
+    """Step until `rid` has streamed `blocks_out` blocks and a tick that
+    runs its open block is in flight; returns the tokens streamed."""
+    got, blocks = 0, 0
+    for _ in range(64):
+        n = sum(e.rid == rid and e.token >= 0 for e in eng.step())
+        got, blocks = got + n, blocks + bool(n)
+        cur = eng._in_flight
+        if blocks >= blocks_out and cur is not None and rid in cur.slots:
+            return got
+    raise AssertionError("no tick with a block of the request in flight")
+
+
+def books_balance(eng, prefill_rows):
+    """Every row computed is a prefill row, a row of a counted forward or
+    a void row, and no counter holds a void one."""
+    s = eng.stats
+    assert s["diff_rows"] == BD * (s["diff_denoise_forwards"]
+                                   + s["diff_commit_forwards"])
+    assert s["diff_commit_forwards"] == s["diff_blocks_committed"]
+    assert s["tokens_computed"] == (prefill_rows + s["diff_rows"]
+                                    + s["ahead_void_rows"])
+
+
+@pytest.mark.parametrize("pallas", [False, True], ids=["stock", "kernel"])
+def test_eos_inside_a_block_with_the_next_block_in_flight(tiny, pallas,
+                                                          monkeypatch):
+    """The end-of-sequence id is found when the commit is harvested, with
+    the next block's first denoise forward in flight: those Bd rows are
+    computed and dropped, counted in `ahead_void_rows` alone."""
+    cfg, params = tiny
+    prompt = prompt_of(9, seed=9)
+    want, _ = reference(cfg, params, prompt, 14, 2)
+    stop = want[4]                       # inside the second block
+    cut = want.index(stop)
+    runs = []
+    for ahead in (True, False):
+        eng = engine(cfg, params, pallas=pallas)
+        if not ahead:
+            synchronous(eng, monkeypatch)
+        rid = eng.submit(prompt, max_new_tokens=14, eos_token_id=stop,
+                         denoising_steps=2)
+        calls = drive(eng)
+        (done,) = eng.run()
+        if not pallas:
+            assert done.output_tokens == want[:cut]
+        assert done.finish_reason == "stop" and done.rid == rid
+        assert eng.blocks.num_allocated() == 0
+        books_balance(eng, prefill_rows=8)
+        runs.append((eng, calls, done.output_tokens))
+    (eng, calls, out), (sync, calls0, out0) = runs
+    assert out == out0 and [c for c in calls if c] == [c for c in calls0 if c]
+    assert eng.stats["ahead_void_rows"] == BD
+    assert sync.stats["ahead_void_rows"] == 0
+    for name in DIFF:
+        assert eng.stats[name] == sync.stats[name], name
+    assert eng.forwards == sync.forwards
+    # whoever gets the pages next is served as the reference says
+    other = prompt_of(13, seed=14)
+    r2 = eng.submit(other, max_new_tokens=6, denoising_steps=2)
+    (done,) = eng.run()
+    assert done.rid == r2
+    if not pallas:
+        assert done.output_tokens == reference(cfg, params, other, 6, 2)[0]
+
+
+def test_cancel_with_a_block_tick_in_flight(tiny):
+    """`cancel` settles the tick in flight: what it committed is not lost,
+    nothing is void, and the other request is served as the reference."""
+    cfg, params = tiny
+    prompt, other = prompt_of(9, seed=21), prompt_of(14, seed=22)
+    eng = engine(cfg, params)
+    rid = eng.submit(prompt, max_new_tokens=40, denoising_steps=2)
+    keep = eng.submit(other, max_new_tokens=9, denoising_steps=4)
+    seen = step_until_block_in_flight(eng, rid, blocks_out=2)
+    assert eng.cancel(rid) and eng._in_flight is None
+    assert not eng.cancel(rid)
+    seq = eng.scheduler.get(rid)
+    assert len(seq.generated) >= seen and not any(seq.block_masked)
+    held = [e.token for e in eng.step() if e.rid == rid and e.token >= 0]
+    assert seq.generated == reference(cfg, params, prompt, 40, 2)[0][
+        :seen + len(held)]
+    done = {c.rid: c for c in eng.run()}
+    assert done[rid].finish_reason == "cancelled"
+    assert done[rid].output_tokens == seq.generated
+    assert done[keep].output_tokens == reference(cfg, params, other, 9, 4)[0]
+    assert eng.stats["ahead_void_rows"] == 0
+    assert eng.blocks.num_allocated() == 0
+    books_balance(eng, prefill_rows=8 + 12)
+
+
+def test_deadline_with_a_block_tick_in_flight(tiny):
+    """A deadline that falls while the sequence's block is in a tick in
+    flight: the forward is computed and dropped, in no `diff_*` counter and
+    in no record, and the stream ends `deadline` behind the blocks
+    committed before it."""
+    cfg, params = tiny
+    prompt, other = prompt_of(9, seed=23), prompt_of(14, seed=24)
+    eng = engine(cfg, params)
+    rid = eng.submit(prompt, max_new_tokens=40, denoising_steps=2,
+                     deadline_s=3600.0)
+    keep = eng.submit(other, max_new_tokens=9, denoising_steps=4)
+    seen = step_until_block_in_flight(eng, rid, blocks_out=2)
+    before = {k: eng.stats[k] for k in DIFF}
+    eng.scheduler.get(rid).deadline = time.monotonic() - 1.0
+    eng.step()      # launches a tick without it, harvests the one with it
+    assert eng.stats["ahead_void_rows"] == BD
+    assert eng.stats["diff_rows"] - before["diff_rows"] == BD   # `keep`'s
+    done = {c.rid: c for c in eng.run()}
+    assert done[rid].finish_reason == "deadline"
+    assert done[rid].output_tokens == reference(
+        cfg, params, prompt, 40, 2)[0][:seen]
+    assert done[keep].output_tokens == reference(cfg, params, other, 9, 4)[0]
+    assert eng.scheduler.stats["deadline_expired"] == 1
+    assert eng.stats["diff_denoise_forwards"] == len(eng.forwards)
+    assert eng.blocks.num_allocated() == 0
+    books_balance(eng, prefill_rows=8 + 12)
+
+
+def test_tick_behind_the_last_commit_is_planned_with_everything_known(tiny):
+    """The commit forward that reaches `max_new_tokens` frees a slot: the
+    tick behind it is not launched ahead, and admits both the request that
+    waited and one submitted on seeing the last token."""
+    cfg, params = tiny
+    eng = engine(cfg, params, max_batch=2)
+    short = eng.submit(prompt_of(6, seed=31), max_new_tokens=6,
+                       denoising_steps=2)
+    eng.submit(prompt_of(9, seed=32), max_new_tokens=60, denoising_steps=2)
+    waiting = eng.submit(prompt_of(5, seed=33), max_new_tokens=30,
+                         denoising_steps=2)
+    late = None
+    for _ in range(40):
+        events = eng.step()
+        if late is not None:
+            # the very next tick runs its first block (a prompt of 3 has no
+            # whole block to prefill)
+            assert eng.scheduler.get(late).block_forwards == 1
+            break
+        if any(e.rid == short and e.finished for e in events):
+            assert eng._in_flight is None     # nothing was planned blind
+            eng.cancel(waiting)
+            late = eng.submit(prompt_of(3, seed=34), max_new_tokens=30,
+                              denoising_steps=2)
+    else:
+        raise AssertionError("the short request never finished")
+    assert eng.stats["ticks_ahead"] > 0 == eng.stats["ahead_void_rows"]
+    eng.run()
+
+
+def test_sixteen_staggered_requests_are_mostly_launched_ahead(tiny):
+    """The cell's traffic at a tiny size: 16 slots, requests of 40 blocks
+    (120 ticks at 2 denoising steps) staggered three ticks apart. Only the
+    tick behind a request's last commit is planned synchronously."""
+    cfg, params = dataclasses.replace(tiny[0], max_seq_len=256), tiny[1]
+    eng = engine(cfg, params, max_batch=16, token_budget=64, num_blocks=352,
+                 max_len=256)
+    left = [prompt_of(5 + i, seed=40 + i) for i in range(16)]
+    while eng.has_work() or left:
+        if left and eng.stats["steps"] % 3 == 0:
+            eng.submit(left.pop(), max_new_tokens=160, denoising_steps=2)
+        eng.step()
+    s = eng.stats
+    assert len(eng.run()) == 16 and s["ahead_void_rows"] == 0
+    assert s["diff_blocks_committed"] == sum(
+        -(-((5 + i) % BD + 160) // BD) for i in range(16))
+    assert s["ticks_ahead"] / s["steps"] >= 0.85
+    assert s["step_builds"] == 1
 
 
 # ---- the block-causal read, both paths -------------------------------------
