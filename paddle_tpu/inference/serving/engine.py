@@ -77,6 +77,23 @@ harvested). A sequence has a block table over each; the prefix cache is off
 quantisation, LoRA adapters, a draft model, the fused FFN and
 ``extract_pages`` / ``ingest_pages`` are refused.
 
+Latent attention (a plan whose layers are ``attn="latent"``: DeepSeek-V3's
+block, Kimi-K2's) is served over a LATENT page pool: one array
+``[L, num_blocks, 1, block_size, W]`` and no value pool. A position's row is
+``llama.latent_kv``'s (latent | rope key), 576 values at Kimi's widths, in W
+= 640 whole lanes (``paged_attention_latent`` says why); every launch, the
+decode tick's and the tick's with a prefill chunk, goes through
+``paged_latent_attention``, which states the one rule of which form a row
+attends in: every row in the ABSORBED form (64 query heads over one key
+row whose first 512 values are the value row), one-row sequences through
+the decode launch in either tick and chunks through the mixed walk; the
+expanded form for chunks was measured and is not taken. The pages are of
+one kind and one lifetime, so the prefix
+cache, copy-on-write and preemption work as for a uniform model; page
+hand-off is refused as for every plan. A config that holds a share of its
+routed experts (``cfg.experts_held``) routes over all of them and computes
+its own (``llama.routed_ffn_load``); ``moe_pairs_held`` counts those pairs.
+
 SLO metrics (TTFT/TPOT histograms, queue-depth and KV-block-utilization
 gauges, admit/preempt/shed counters + flight-recorder events) flow
 through ``observability.emit`` — ``observability.summary()["serving"]``
@@ -98,11 +115,13 @@ from ...core import flags
 from ...models import llama as L
 from ...observability import emit as _emit
 from ...observability import tracing as _tracing
-from ...ops.kernels.serving_attention import paged_layer_attention
+from ...ops.kernels.serving_attention import (paged_latent_attention,
+                                              paged_layer_attention)
 from ...ops.pallas import flash_attention as FA
 from ...ops.pallas import fused_ffn as FF
 from ...ops.pallas import fused_sample as FS
 from ...ops.pallas import paged_attention as PA
+from ...ops.pallas import paged_attention_latent as PL
 from .. import quant as Q
 from . import adapters as AD
 from . import speculative as SP
@@ -360,11 +379,17 @@ class PagedServingEngine:
         # the per-page f32 scale rows when quantized) — keeps the byte
         # gauges and the router's least-loaded placement truthful
         kvh, hd = cfg.num_kv_heads, cfg.head_dim
+        # a latent pool: one side, a row of (latent | rope key) in whole
+        # lanes for every head
+        self.latent = cfg.latent
+        sides = 2
+        if self.latent:
+            sides, hd = 1, PL.padded_width(cfg.latent_width)
         # layers whose pages live in the pool and in the window pool (a
         # config without window layers: all of them, and none)
         n_window = sum(s.attn == "window" for s in cfg.layer_plan)
         self._pool_layers = (cfg.num_layers - n_window, n_window)
-        layer_bytes = (2 * kvh * self.block_size * hd
+        layer_bytes = (sides * kvh * self.block_size * hd
                        * np.dtype(self.cache_dtype).itemsize)
         self.kv_page_bytes = cfg.num_layers * layer_bytes
         if self.quant_kv:
@@ -409,13 +434,26 @@ class PagedServingEngine:
                       "attn_rows_live": 0, "attn_rows_packed": 0,
                       "ticks_ahead": 0, "ahead_void_rows": 0,
                       "ticks_sampled": 0, "sampled_rows": 0}
+        # the expert counters a tick sends behind its tokens
+        self._moe_fields = ()
         if cfg.num_experts:
             # routed-expert work, summed over ticks (max_load: the largest
-            # seen): (row, expert) pairs, (layer, expert) groups with at
-            # least one row, most rows on one expert in one layer
-            self.stats.update(moe_pairs=0, moe_experts_hit=0,
-                              moe_max_load=0)
-        if plan:
+            # seen): (row, expert) pairs a sparse layer, (layer, expert)
+            # groups with at least one row, most rows on one expert in one
+            # layer; with a held share of the experts the groups and the
+            # load are over the held ones, and `moe_pairs_held` the pairs
+            # on them, summed over the sparse layers
+            self._moe_fields = ("moe_pairs", "moe_experts_hit",
+                                "moe_max_load") + (
+                ("moe_pairs_held",) if cfg.experts_held else ())
+            self.stats.update(dict.fromkeys(self._moe_fields, 0))
+        if self.latent:
+            # keys and (row, key) pairs inside the causal mask, summed over
+            # ticks and layers (`_plan_keys`), and pages allocated when a
+            # tick is launched, summed over ticks
+            self.stats.update(attn_keys_latent=0, attn_pairs_latent=0,
+                              latent_pages_live=0)
+        elif plan:
             # keys and (row, key) pairs inside the masks, summed over ticks
             # and over the layers of the kind, and the keys a causal mask
             # would show in all layers (`_plan_keys`)
@@ -474,7 +512,8 @@ class PagedServingEngine:
                            else pallas)
         # whether a tick's read is one of the whole-page walks (their
         # counters are reckoned only then) or the BlockSpec walk
-        self._whole_pages = self.pallas and PA.whole_pages(cfg.head_dim)
+        self._whole_pages = (self.pallas and not self.latent
+                             and PA.whole_pages(cfg.head_dim))
         # the (query heads, window) of each attention launch a tick makes:
         # the config's own, or each that occurs in its plan
         self._launches = tuple(dict.fromkeys(
@@ -506,7 +545,9 @@ class PagedServingEngine:
         shape = (self._pool_layers[0], self.num_blocks, kvh, self.block_size,
                  hd)
         self._key_cache = jnp.zeros(shape, self.cache_dtype)
-        self._value_cache = jnp.zeros(shape, self.cache_dtype)
+        # a latent pool has no value side: the values are the rows' latents
+        self._value_cache = (None if self.latent
+                             else jnp.zeros(shape, self.cache_dtype))
         if n_window:
             # two pools ride the tick's carry: (full layers', window layers')
             wshape = (n_window, self.window_blocks) + shape[2:]
@@ -546,7 +587,8 @@ class PagedServingEngine:
             # one table a rope of the plan, as wide as what it rotates
             self._ropes = tuple(dict.fromkeys(s.rope for s in cfg.kinds))
             self._rope_emb = tuple(
-                rope_emb(*L.rope_table(jnp.arange(self.max_len), hd, r))
+                rope_emb(*L.rope_table(jnp.arange(self.max_len),
+                                       cfg.rope_dim, r))
                 for r in self._ropes)
         else:
             self._rope_emb = rope_emb(*L.rope_cos_sin(
@@ -564,7 +606,7 @@ class PagedServingEngine:
         self._held: List[TokenEvent] = []
         self._last_out: Any = np.zeros(
             (self.max_batch * (3 * Bd if Bd else 1)
-             + (3 if cfg.num_experts else 0),), np.int32)
+             + len(self._moe_fields),), np.int32)
         self._device_free_ns = 0
         # the device's time at work so far, by the ticks' own intervals
         # (`_harvest`): what a request's `device_s` is a difference of
@@ -996,7 +1038,9 @@ class PagedServingEngine:
                         y, load = L.routed_ffn_load(
                             h, {**lp, **experts}, cfg, valid, layer=li)
                         x = x + y
-                    return (x, kcs, vcs), (jnp.sum(load > 0), jnp.max(load))
+                    return (x, kcs, vcs), (
+                        jnp.sum(load > 0), jnp.max(load),
+                        *((jnp.sum(load),) if cfg.experts_held else ()))
                 with jax.named_scope("ffn"):
                     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
                     if ffn_mode:
@@ -1077,10 +1121,11 @@ class PagedServingEngine:
                 # the tick's expert counters ride behind the B tokens, so
                 # the host's one fetch brings both
                 with jax.named_scope("moe"):
-                    hit, max_load = loads
+                    hit, max_load, *held = loads
                     nxt = jnp.concatenate([nxt, jnp.stack([
                         cu_seqlens_q[B] * cfg.top_k, jnp.sum(hit),
-                        jnp.max(max_load)]).astype(jnp.int32)])
+                        jnp.max(max_load), *map(jnp.sum, held)]
+                    ).astype(jnp.int32)])
             if spec_mode:
                 # the verify read: greedy argmax at EVERY packed row, so
                 # a k+1-wide speculative chunk's per-position targets
@@ -1107,7 +1152,9 @@ class PagedServingEngine:
         in its kind's whole stack by its place there. Scopes as the
         uniform tick's, with `paged_attention_full` / `_window` inside
         `paged_attention`, `attn_gate`, and `shared_expert` inside `moe`.
-        Returns (x, key_cache, value_cache, (experts hit, largest load))."""
+        Latent layers (`latent_attention` below) have one pool and no
+        value side. Returns (x, key_cache, value_cache, (experts hit,
+        largest load[, pairs on held experts]))."""
         cfg = self.cfg
         kinds, kind_of = cfg.kinds, cfg.kind_of_layer
         two = isinstance(key_cache, tuple)
@@ -1132,31 +1179,72 @@ class PagedServingEngine:
                 "page_layer": jnp.asarray([same.index(i) for i in layers],
                                           jnp.int32)})
 
+        if self.latent:
+            # every packed row's position, for its rope: the rows are one
+            # "sequence" of the model's latent functions ([1, tok, ...])
+            tok = jnp.arange(x.shape[0], dtype=jnp.int32)
+            tok_b = jnp.clip(jnp.searchsorted(cu, tok, side="right") - 1, 0,
+                             cu.shape[0] - 2)
+            tok_pos = jnp.clip(past[tok_b] + tok - cu[tok_b], 0,
+                               self.max_len - 1)
+
+        def latent_attention(spec, x, lp, pool, page_layer):
+            """x + the latent attention sub-block (scopes `latent_q` and
+            `latent_kv`, inside `qkv` here and around the absorption in the
+            op; `paged_attention_latent` inside `paged_attention`;
+            `latent_out` in the op and around Wo); the
+            pool comes back with the rows' own. Which form a row attends
+            in is `paged_latent_attention`'s to say."""
+            half = cfg.qk_rope_head_dim // 2
+            with jax.named_scope("qkv"):
+                h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)[None]
+                table = rope_emb[self._ropes.index(spec.rope)]
+                cos, sin = (table[i, 0, tok_pos, :half] for i in (0, 1))
+                with jax.named_scope("latent_q"):
+                    q_nope, q_rope = L.latent_q(h, lp, cfg, spec.heads, cos,
+                                                sin)
+                with jax.named_scope("latent_kv"):
+                    row = L.latent_kv(h, lp, cfg, cos, sin)[0]
+            o, pool = paged_latent_attention(
+                q_nope[0], q_rope[0], row,
+                *L.latent_wkvb(lp, cfg, spec.heads, x.dtype), pool,
+                page_layer, past, this, cu, tables[0], cfg.score_scale,
+                use_pallas)
+            with jax.named_scope("attn_out"), jax.named_scope("latent_out"):
+                return x + Q.matmul_param(o, lp, "wo"), pool
+
         def body(kind, carry, leaves):
             spec = kinds[kind]
-            x, pk, pv, hit, top = carry
+            x, pk, pv, hit, top, *held = carry
             lp = leaves["lp"]
-            pool = int(two and spec.attn == "window")
-            rot = int(cfg.head_dim * spec.rope.partial)
-            with jax.named_scope("qkv"):
-                h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-                qkv = jnp.concatenate(
-                    [Q.matmul_param(h, lp, n) for n in ("wq", "wk", "wv")],
-                    axis=-1)
-            o, _, kc, vc = paged_layer_attention(
-                qkv, pk[pool], pv[pool], leaves["page_layer"], past, this,
-                cu, tables[pool],
-                rope_emb=rope_emb[self._ropes.index(spec.rope)],
-                use_neox_style=True, use_pallas=use_pallas,
-                window=cfg.sliding_window if spec.attn == "window" else 0,
-                rotary_dim=rot if rot < cfg.head_dim else 0, kind=spec.attn)
-            pk = pk[:pool] + (kc,) + pk[pool + 1:]
-            pv = pv[:pool] + (vc,) + pv[pool + 1:]
-            if cfg.attn_gate:
-                o = L.attn_gated(o.reshape(o.shape[0], spec.heads, -1), h,
-                                 lp).reshape(o.shape)
-            with jax.named_scope("attn_out"):
-                x = x + Q.matmul_param(o, lp, "wo")
+            if spec.attn == "latent":
+                x, kc = latent_attention(spec, x, lp, pk[0],
+                                         leaves["page_layer"])
+                pk = (kc,)
+            else:
+                pool = int(two and spec.attn == "window")
+                rot = int(cfg.head_dim * spec.rope.partial)
+                with jax.named_scope("qkv"):
+                    h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+                    qkv = jnp.concatenate(
+                        [Q.matmul_param(h, lp, n)
+                         for n in ("wq", "wk", "wv")], axis=-1)
+                o, _, kc, vc = paged_layer_attention(
+                    qkv, pk[pool], pv[pool], leaves["page_layer"], past,
+                    this, cu, tables[pool],
+                    rope_emb=rope_emb[self._ropes.index(spec.rope)],
+                    use_neox_style=True, use_pallas=use_pallas,
+                    window=cfg.sliding_window if spec.attn == "window"
+                    else 0,
+                    rotary_dim=rot if rot < cfg.head_dim else 0,
+                    kind=spec.attn)
+                pk = pk[:pool] + (kc,) + pk[pool + 1:]
+                pv = pv[:pool] + (vc,) + pv[pool + 1:]
+                if cfg.attn_gate:
+                    o = L.attn_gated(o.reshape(o.shape[0], spec.heads, -1),
+                                     h, lp).reshape(o.shape)
+                with jax.named_scope("attn_out"):
+                    x = x + Q.matmul_param(o, lp, "wo")
             if spec.ffn == "sparse":
                 with jax.named_scope("moe"):
                     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
@@ -1166,16 +1254,20 @@ class PagedServingEngine:
                     x = x + y
                     hit = hit + jnp.sum(load > 0, dtype=jnp.int32)
                     top = jnp.maximum(top, jnp.max(load))
+                    if cfg.experts_held:
+                        held = [held[0] + jnp.sum(load, dtype=jnp.int32)]
             else:
                 with jax.named_scope("ffn"):
                     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
                     x = x + L.ffn(h, lp)
-            return x, pk, pv, hit, top
+            return (x, pk, pv, hit, top, *held)
 
         zero = jnp.zeros((), jnp.int32)
-        x, pk, pv, hit, top = L.scan_plan(
-            cfg, body, (x, pools_k, pools_v, zero, zero), stacks)
-        return (x, pk if two else pk[0], pv if two else pv[0], (hit, top))
+        x, pk, pv, *counts = L.scan_plan(
+            cfg, body, (x, pools_k, pools_v, zero, zero)
+            + ((zero,) if cfg.experts_held else ()), stacks)
+        return (x, pk if two else pk[0], pv if two else pv[0],
+                tuple(counts))
 
     def _get_step_fn(self, tok_pad: int, B: int, decode: bool = False,
                      ffn_mode=False, ad_sig: Tuple[int, ...] = (),
@@ -1197,7 +1289,9 @@ class PagedServingEngine:
                   cache_write="pallas_pages" if self.pallas
                   else "scatter_rows",
                   experts=L.expert_form(self.cfg),
-                  block_length=self.cfg.block_length)
+                  block_length=self.cfg.block_length,
+                  latent=self.latent,
+                  experts_held=list(self.cfg.experts_held))
         return fn
 
     def _copy_blocks(self, pairs: List[Tuple[int, int]]):
@@ -1221,9 +1315,10 @@ class PagedServingEngine:
                         sel = (jnp.arange(nb) == dst[i])[None, :, None,
                                                          None, None]
                         blk_k = lax.dynamic_slice_in_dim(kc, s, 1, axis=1)
-                        blk_v = lax.dynamic_slice_in_dim(vc, s, 1, axis=1)
                         kc = jnp.where(sel, blk_k, kc)
-                        vc = jnp.where(sel, blk_v, vc)
+                        if vc is not None:      # a latent pool has no values
+                            blk_v = lax.dynamic_slice_in_dim(vc, s, 1, axis=1)
+                            vc = jnp.where(sel, blk_v, vc)
                         if quant_kv:
                             sel3 = (jnp.arange(nb) == dst[i])[None, :, None]
                             kdq = jnp.where(sel3, lax.dynamic_slice_in_dim(
@@ -1630,6 +1725,8 @@ class PagedServingEngine:
             if self.window_blocks:
                 tick.pool_pages = (self.blocks.num_allocated(),
                                    self.blocks.window_allocated())
+            elif self.latent:
+                tick.pool_pages = (self.blocks.num_allocated(), 0)
         return tick
 
     def _harvest(self, cur: "_Tick", span) -> List[TokenEvent]:
@@ -1655,8 +1752,9 @@ class PagedServingEngine:
             self._device_busy_ns += now - t0
             dur = (now - t0) * 1e-9
             moe = None
-            if self.cfg.num_experts:
-                nxt, moe = nxt[:-3], [int(c) for c in nxt[-3:]]
+            if self._moe_fields:
+                n = len(self._moe_fields)
+                nxt, moe = nxt[:-n], [int(c) for c in nxt[-n:]]
 
         with _tracing.phase("serve.harvest"):
             spec_extra = sum(len(p) for p in cur.spec_plan.values())
@@ -1665,12 +1763,11 @@ class PagedServingEngine:
                   batch=len(batch.items), prefill_tokens=cur.n_prefill)
             fields = {}
             if moe is not None:
-                fields = dict(zip(("moe_pairs", "moe_experts_hit",
-                                   "moe_max_load"), moe))
-                self.stats["moe_pairs"] += moe[0]
-                self.stats["moe_experts_hit"] += moe[1]
-                self.stats["moe_max_load"] = max(
-                    self.stats["moe_max_load"], moe[2])
+                fields = dict(zip(self._moe_fields, moe))
+                for name, n in fields.items():
+                    self.stats[name] = (max(self.stats[name], n)
+                                        if name == "moe_max_load"
+                                        else self.stats[name] + n)
             if self._whole_pages:
                 # how well the launch's walk fits the traffic, from the
                 # host's own lengths: pages that hold a live key against
@@ -1702,6 +1799,8 @@ class PagedServingEngine:
                     self.stats[name] += n
             if self.cfg.layer_plan:
                 keys = self._plan_keys(dec_lens, this_lens)
+                if self.latent:
+                    keys["latent_pages_live"] = cur.pool_pages[0]
                 if self.window_blocks:
                     keys.update(
                         full_pages_live=cur.pool_pages[0],
@@ -1782,6 +1881,9 @@ class PagedServingEngine:
         past, this = past[live].astype(np.int64), this[live].astype(np.int64)
         keys = int((past + this).sum())
         pairs = int((this * past + this * (this + 1) // 2).sum())
+        if self.latent:
+            return {"attn_keys_latent": n_full * keys,
+                    "attn_pairs_latent": n_full * pairs}
         wkeys = wpairs = 0
         if n_window:
             W = cfg.sliding_window

@@ -32,6 +32,19 @@ traced body; `scan_plan` runs the plan as scans over runs of one kind
 inside a scan over the plan's periods. Without a plan every layer is the
 config's own one kind (with `num_experts` > 0 every layer's FFN is
 routed), `params["blocks"]` is one dict and the program is what it was.
+
+A third kind of attention is *latent* (`LayerSpec.attn = "latent"`:
+DeepSeek-V3's block, which Kimi-K2 keeps): queries through a low-rank
+bottleneck with its own norm, keys and values through one latent a
+position with its own norm beside one rope key that all heads share
+(`latent_q`, `latent_kv`, `latent_wkvb`; `latent_self_attention` is the
+expanded form that `forward` runs, the paged engine attends over the
+cached latents themselves). And a config may hold ONE CHIP'S SHARE of its
+routed experts (`experts_held` = (first, count)): `route` runs over all
+`num_experts`, with a selection bias where the model has one
+(`router_bias`), and `routed_ffn_load` computes the pairs of the held
+experts and leaves out what the absent ones would add; nothing here
+stands in for the chips that hold them.
 """
 from __future__ import annotations
 
@@ -63,9 +76,11 @@ class RopeSpec:
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
-    """What one layer of a layer plan is: `attn` "full" (causal) or
+    """What one layer of a layer plan is: `attn` "full" (causal),
     "window" (causal over the last `LlamaConfig.sliding_window` keys, the
-    query's own among them), its query heads, its rope, and `ffn` "dense"
+    query's own among them) or "latent" (causal multi-head latent
+    attention, `latent_*` below: the config's `kv_lora_rank` and its
+    sibling widths), its query heads, its rope, and `ffn` "dense"
     (SwiGLU of `dense_intermediate_size`) or "sparse" (the routed experts
     of `intermediate_size`, with the shared expert where the config has
     one). Layers with equal specs are one kind."""
@@ -138,6 +153,31 @@ class LlamaConfig:
     # a sigmoid gate on each head's attention output, from the layer's
     # normed input through `wg` [d, heads]
     attn_gate: bool = False
+    # multi-head latent attention (DeepSeek-V3's block, Kimi-K2's): a
+    # "latent" layer projects queries through a `q_lora_rank` bottleneck
+    # with its own norm to heads of `qk_nope_head_dim` + `qk_rope_head_dim`,
+    # and keys and values through a `kv_lora_rank` latent with its own norm
+    # beside ONE rope key of `qk_rope_head_dim` that all heads share; the
+    # latent expands to a head's `qk_nope_head_dim` key and `v_head_dim`
+    # value. A cache holds (latent | rope key) a position. `softmax_scale`
+    # is the factor on the scores (0: head_dim ** -0.5; a YaRN model folds
+    # its mscale ** 2 into it)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    softmax_scale: float = 0.0
+    # a learned bias [num_experts] added to the router's scores for the
+    # CHOICE of the k experts alone; their weights are the scores without
+    # it (`topk_method: noaux_tc`)
+    router_bias: bool = False
+    # one chip's share of the routed experts: (first, count) = this
+    # config HOLDS experts first .. first + count - 1 of `num_experts`. The
+    # router runs over all of them; a (row, expert) pair whose expert is
+    # not held is computed by no one here and adds nothing (its chip's
+    # part of the sum). () = every expert is held
+    experts_held: Tuple[int, ...] = ()
 
     def __post_init__(self):
         here = (self.hidden_size, self.num_heads)
@@ -153,10 +193,17 @@ class LlamaConfig:
                     f"layer_plan has {len(self.layer_plan)} layers, "
                     f"num_layers is {self.num_layers}")
             for spec in self.layer_plan:
-                if (spec.attn not in ("full", "window")
+                if (spec.attn not in ("full", "window", "latent")
                         or spec.ffn not in ("dense", "sparse")
                         or spec.heads % self.num_kv_heads):
                     raise ValueError(f"layer_plan: bad layer {spec}")
+                if spec.attn == "latent" and not (
+                        self.kv_lora_rank and self.q_lora_rank
+                        and self.qk_nope_head_dim and self.qk_rope_head_dim
+                        and self.v_head_dim):
+                    raise ValueError(
+                        "a latent layer needs q_lora_rank, kv_lora_rank, "
+                        "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
                 if spec.attn == "window" and self.sliding_window < 1:
                     raise ValueError("a window layer needs sliding_window")
                 if spec.ffn == "sparse" and not self.num_experts:
@@ -167,6 +214,48 @@ class LlamaConfig:
                     "served has both, and neither was judged under a plan")
         if self.router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"router_score={self.router_score!r}")
+        if self.experts_held:
+            first, count = self.experts_held
+            if not (0 <= first and 0 < count
+                    and first + count <= self.num_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held}: (first, count) "
+                    f"inside num_experts={self.num_experts}")
+        latent = {s.attn == "latent" for s in self.layer_plan}
+        if True in latent and (False in latent or self.attn_gate
+                               or self.num_kv_heads != 1):
+            raise NotImplementedError(
+                "latent layers beside other attention, under an attention "
+                "gate, or with num_kv_heads != 1 (the latent is one key "
+                "row for every head): no model served has them")
+
+    # -- the held share of the routed experts ------------------------------
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this config holds."""
+        return tuple(self.experts_held) or (0, self.num_experts)
+
+    @property
+    def latent(self) -> bool:
+        """Every layer's attention is latent (`__post_init__` allows no
+        mix)."""
+        return bool(self.layer_plan) and self.layer_plan[0].attn == "latent"
+
+    @property
+    def rope_dim(self) -> int:
+        """The width a layer's rope table is made for (of which a
+        `RopeSpec.partial` share is rotated): a head, or a latent layer's
+        rope slice."""
+        return self.qk_rope_head_dim if self.latent else self.head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """Values a position's cache row holds: latent | rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def score_scale(self) -> float:
+        return self.softmax_scale or float(self.head_dim) ** -0.5
 
     # -- the layer plan ---------------------------------------------------
     @property
@@ -179,19 +268,32 @@ class LlamaConfig:
         kinds = self.kinds
         return tuple(kinds.index(s) for s in self.layer_plan)
 
-    def _layer_params(self, heads: int, ffn: str) -> Tuple[int, int]:
-        """(all, active a token) matmul and norm parameters of one layer."""
+    def _layer_params(self, heads: int, ffn: str,
+                      kind: str = "full") -> Tuple[int, int]:
+        """(all, active a token) matmul and norm parameters of one layer.
+        The routed experts count whole (`num_experts`), whatever share of
+        them a config holds: this is the model's size, not a chip's."""
         d, hd = self.hidden_size, self.head_dim
-        attn = 2 * d * heads * hd + 2 * d * self.num_kv_heads * hd
+        norms = 2 * d
+        if kind == "latent":
+            r, c = self.q_lora_rank, self.kv_lora_rank
+            nope, rope, v = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                             self.v_head_dim)
+            attn = (d * r + r * heads * (nope + rope) + d * (c + rope)
+                    + c * heads * (nope + v) + heads * v * d)
+            norms += r + c
+        else:
+            attn = 2 * d * heads * hd + 2 * d * self.num_kv_heads * hd
         if self.attn_gate:
             attn += d * heads
-        norms = 2 * d
         if self.qk_norm:
             norms += (2 * hd if self.qk_norm_per_head
                       else (heads + self.num_kv_heads) * hd)
         if ffn == "sparse":
             one = 3 * d * self.intermediate_size
             fixed = d * self.num_experts + 3 * d * self.shared_expert_width
+            if self.router_bias:
+                norms += self.num_experts
             mlp = self.num_experts * one + fixed
             active = min(self.top_k, self.num_experts) * one + fixed
         else:
@@ -202,7 +304,7 @@ class LlamaConfig:
 
     def _layers(self):
         if self.layer_plan:
-            return [(s.heads, s.ffn) for s in self.layer_plan]
+            return [(s.heads, s.ffn, s.attn) for s in self.layer_plan]
         return [(self.num_heads, "sparse" if self.num_experts else "dense")
                 ] * self.num_layers
 
@@ -226,13 +328,19 @@ class LlamaConfig:
 
 
 def require_uniform(cfg: LlamaConfig, what: str) -> None:
-    """Raise for a config with a layer plan, in the name of a block body
-    that runs one uniform stack (`params["blocks"]` one dict) and would
-    compute another model under a plan's name."""
+    """Raise for a config with a layer plan or a held share of its
+    experts, in the name of a block body that runs one uniform stack of
+    whole layers (`params["blocks"]` one dict, every expert) and would
+    compute another model under the config's name."""
     if cfg.layer_plan:
         raise NotImplementedError(
             f"{what} runs one uniform stack of layers and does not take a "
             "layer plan (LlamaConfig.layer_plan); `llama.forward` and "
+            "`PagedServingEngine` do")
+    if cfg.experts_held:
+        raise NotImplementedError(
+            f"{what} holds every routed expert and does not take a chip's "
+            "share of them (LlamaConfig.experts_held); `llama.forward` and "
             "`PagedServingEngine` do")
 
 
@@ -253,24 +361,46 @@ def _normal(key: jax.Array, shape, dtype, scale: float = 0.02):
 
 
 def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
-                 ffn_kind: str) -> Dict[str, jax.Array]:
-    """One stack of `L` layers with `nh` query heads and an FFN of
-    `ffn_kind` ("dense" | "sparse"); the keys are split as they always
-    were, so a uniform config draws the weights it drew."""
+                 ffn_kind: str, attn_kind: str = "full"
+                 ) -> Dict[str, jax.Array]:
+    """One stack of `L` layers with `nh` query heads, an attention of
+    `attn_kind` and an FFN of `ffn_kind` ("dense" | "sparse"); the keys
+    are split as they always were, so a uniform config draws the weights
+    it drew. A config that holds a share of its experts
+    (`experts_held`) draws the whole router and the held experts'
+    matrices alone."""
     d, f = cfg.hidden_size, cfg.intermediate_size
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
     pt = cfg.param_dtype
     keys = jax.random.split(key, 10)
     normal = functools.partial(_normal, dtype=pt)
 
-    blocks = {
-        "wq": normal(keys[0], (L, d, nh * hd)),
-        "wk": normal(keys[1], (L, d, nkv * hd)),
-        "wv": normal(keys[2], (L, d, nkv * hd)),
-        "wo": normal(keys[3], (L, nh * hd, d)),
-        "attn_norm": jnp.ones((L, d), pt),
-        "mlp_norm": jnp.ones((L, d), pt),
-    }
+    if attn_kind == "latent":
+        r, c = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        ka = jax.random.split(keys[0], 2)
+        kb = jax.random.split(keys[1], 2)
+        blocks = {
+            "wqa": normal(ka[0], (L, d, r)),
+            "wqb": normal(ka[1], (L, r, nh * (nope + rope))),
+            "wkva": normal(kb[0], (L, d, c + rope)),
+            "wkvb": normal(kb[1], (L, c, nh * (nope + vd))),
+            "wo": normal(keys[3], (L, nh * vd, d)),
+            "qa_norm": jnp.ones((L, r), pt),
+            "kva_norm": jnp.ones((L, c), pt),
+            "attn_norm": jnp.ones((L, d), pt),
+            "mlp_norm": jnp.ones((L, d), pt),
+        }
+    else:
+        blocks = {
+            "wq": normal(keys[0], (L, d, nh * hd)),
+            "wk": normal(keys[1], (L, d, nkv * hd)),
+            "wv": normal(keys[2], (L, d, nkv * hd)),
+            "wo": normal(keys[3], (L, nh * hd, d)),
+            "attn_norm": jnp.ones((L, d), pt),
+            "mlp_norm": jnp.ones((L, d), pt),
+        }
     if cfg.attn_gate:
         blocks["wg"] = normal(keys[8], (L, d, nh))
     if cfg.qk_norm and cfg.qk_norm_per_head:
@@ -280,8 +410,13 @@ def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
         blocks["q_norm"] = jnp.ones((L, nh * hd), pt)
         blocks["k_norm"] = jnp.ones((L, nkv * hd), pt)
     if ffn_kind == "sparse":
-        e = cfg.num_experts
-        blocks["router"] = normal(keys[4], (L, d, e))
+        e = cfg.held[1]
+        blocks["router"] = normal(keys[4], (L, d, cfg.num_experts))
+        if cfg.router_bias:
+            # float32 whatever the weights' dtype: it decides a choice
+            blocks["router_bias"] = _normal(
+                jax.random.fold_in(keys[4], 1), (L, cfg.num_experts),
+                jnp.float32)
         blocks["w1"] = normal(keys[5], (L, e, d, f))
         blocks["w3"] = normal(keys[6], (L, e, d, f))
         blocks["w2"] = normal(keys[7], (L, e, f, d))
@@ -315,7 +450,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         kind_of = cfg.kind_of_layer
         blocks = tuple(
             _init_blocks(cfg, jax.random.fold_in(key, 1 + k),
-                         kind_of.count(k), spec.heads, spec.ffn)
+                         kind_of.count(k), spec.heads, spec.ffn, spec.attn)
             for k, spec in enumerate(cfg.kinds))
     else:
         blocks = _init_blocks(cfg, key, cfg.num_layers, cfg.num_heads,
@@ -469,13 +604,20 @@ def route(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig):
     scores (`router_score`: a softmax, or a sigmoid of each logit) run in
     float32 over ALL experts; the k weights are renormalised to sum to one
     only where the model's config says so (`norm_topk_prob`), and then
-    take the config's `router_scale`."""
+    take the config's `router_scale`. With `router_bias` the k experts are
+    those of the largest score + `lp["router_bias"]`, and their weights
+    the scores themselves."""
     logits = h.astype(jnp.float32) @ lp["router"].astype(jnp.float32)
     if cfg.router_score == "sigmoid":
         gate = jax.nn.sigmoid(logits)
     else:
         gate = jax.nn.softmax(logits, axis=-1)
-    w, e = lax.top_k(gate, cfg.top_k)
+    if cfg.router_bias:
+        _, e = lax.top_k(gate + lp["router_bias"].astype(jnp.float32),
+                         cfg.top_k)
+        w = jnp.take_along_axis(gate, e, axis=-1)
+    else:
+        w, e = lax.top_k(gate, cfg.top_k)
     if cfg.norm_topk_prob:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     if cfg.router_scale != 1.0:
@@ -507,7 +649,7 @@ def _experts_dense(h, w, e, valid, lp, cfg: LlamaConfig, layer):
     """Every expert over every row; a row's output keeps its k experts by a
     [T, E] combine weight that is zero elsewhere (and on a padding row)."""
     with jax.named_scope("dispatch"):
-        comb = jnp.sum(jax.nn.one_hot(e, cfg.num_experts, dtype=w.dtype)
+        comb = jnp.sum(jax.nn.one_hot(e, cfg.held[1], dtype=w.dtype)
                        * w[..., None], axis=-2)                   # [T, E]
         comb = jnp.where(valid[:, None], comb, 0.0)
     with jax.named_scope("experts"):
@@ -576,8 +718,8 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
     each launch."""
     T, k = e.shape
     pad = -(T * k) % GMM_ROWS
-    offset = jnp.asarray(0 if layer is None else -layer * cfg.num_experts,
-                         jnp.int32)
+    held = cfg.held[1]
+    offset = jnp.asarray(0 if layer is None else -layer * held, jnp.int32)
 
     def dot(x, name):
         wn = lp[name].astype(h.dtype)
@@ -588,7 +730,10 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
     # either direction: select them away, do not multiply by zero
     keep = (jnp.arange(T * k + pad) < jnp.sum(load))[:, None]
     with jax.named_scope("dispatch"):
-        flat_e = jnp.where(valid[:, None], e, cfg.num_experts).reshape(-1)
+        mine = valid[:, None]
+        if cfg.experts_held:    # a pair of an expert held elsewhere: no one's
+            mine = mine & (e >= 0) & (e < held)
+        flat_e = jnp.where(mine, e, held).reshape(-1)
         order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)  # [T*k]
         xs = jnp.where(keep, jnp.take(h, jnp.pad(order // k, (0, pad)),
                                       axis=0), 0)
@@ -621,8 +766,12 @@ def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
              else valid.reshape(-1))
     with jax.named_scope("router"):
         w, e = route(h, lp, cfg)
+        if cfg.experts_held:
+            # from here on an expert is its place among the held ones; one
+            # held elsewhere falls outside [0, count) and into no group
+            e = e - cfg.held[0]
     with jax.named_scope("dispatch"):
-        load = jnp.sum(jax.nn.one_hot(e, cfg.num_experts, dtype=jnp.int32)
+        load = jnp.sum(jax.nn.one_hot(e, cfg.held[1], dtype=jnp.int32)
                        * valid[:, None, None], axis=(0, 1),
                        dtype=jnp.int32)                           # [E]
     if expert_form(cfg) == "sorted_gmm":
@@ -677,18 +826,21 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
     nh = spec.heads if spec else cfg.num_heads
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    q, k = qk_normed(h @ lp["wq"].astype(h.dtype),
-                     h @ lp["wk"].astype(h.dtype), lp, cfg)
-    v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, nkv, hd)
-    q = apply_rope(q.reshape(B, T, nh, hd), cos, sin)
-    k = apply_rope(k.reshape(B, T, nkv, hd), cos, sin)
-    window = cfg.sliding_window if spec and spec.attn == "window" else 0
-    o = attention(q, k, v, impl=attn_impl, block_length=cfg.block_length,
-                  window=window)
-    if cfg.attn_gate:
-        o = attn_gated(o, h, lp)
-    o = o.reshape(B, T, nh * hd)
-    x = x + o @ lp["wo"].astype(o.dtype)
+    if spec and spec.attn == "latent":
+        x = x + latent_self_attention(h, lp, cfg, nh, cos, sin)
+    else:
+        q, k = qk_normed(h @ lp["wq"].astype(h.dtype),
+                         h @ lp["wk"].astype(h.dtype), lp, cfg)
+        v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, nkv, hd)
+        q = apply_rope(q.reshape(B, T, nh, hd), cos, sin)
+        k = apply_rope(k.reshape(B, T, nkv, hd), cos, sin)
+        window = cfg.sliding_window if spec and spec.attn == "window" else 0
+        o = attention(q, k, v, impl=attn_impl, block_length=cfg.block_length,
+                      window=window)
+        if cfg.attn_gate:
+            o = attn_gated(o, h, lp)
+        o = o.reshape(B, T, nh * hd)
+        x = x + o @ lp["wo"].astype(o.dtype)
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     if (spec.ffn == "sparse") if spec else cfg.num_experts:
         x = x + routed_ffn(h, lp, cfg)
@@ -705,6 +857,68 @@ def attn_gated(o: jax.Array, h: jax.Array, lp: Dict[str, jax.Array]):
         g = jax.nn.sigmoid((h @ lp["wg"].astype(h.dtype)
                             ).astype(jnp.float32))
         return (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
+
+
+def latent_q(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+             heads: int, cos: jax.Array, sin: jax.Array):
+    """The queries of a latent layer from its normed input h [B, T, d]:
+    c_q = RMSNorm(h Wqa), q = c_q Wqb, a head's (q_nope | q_rope) with rope
+    on the `qk_rope_head_dim` slice (cos, sin [T, rope / 2]). Returns
+    (q_nope [B, T, H, nope], q_rope [B, T, H, rope])."""
+    nope = cfg.qk_nope_head_dim
+    cq = rms_norm(h @ lp["wqa"].astype(h.dtype), lp["qa_norm"], cfg.rms_eps)
+    q = (cq @ lp["wqb"].astype(h.dtype)).reshape(*h.shape[:2], heads, -1)
+    return q[..., :nope], apply_rope(q[..., nope:], cos, sin)
+
+
+def latent_kv(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+              cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """What a latent layer's cache holds of each position, from the
+    layer's normed input h [B, T, d]: (c | k_rope) [B, T, kv_lora_rank +
+    rope], c = RMSNorm(the latent part of h Wkva), k_rope the rest under
+    rope: one key vector for every head."""
+    C = cfg.kv_lora_rank
+    ckr = h @ lp["wkva"].astype(h.dtype)
+    c = rms_norm(ckr[..., :C], lp["kva_norm"], cfg.rms_eps)
+    k_r = apply_rope(ckr[..., None, C:], cos, sin)[..., 0, :]
+    return jnp.concatenate([c, k_r], axis=-1)
+
+
+def latent_wkvb(lp: Dict[str, jax.Array], cfg: LlamaConfig, heads: int,
+                dtype):
+    """Wkvb as (keys [C, H, nope], values [C, H, v]): what rebuilds a
+    head's key and value from a latent (the expanded form), and what the
+    absorbed form folds into its queries and applies to its outputs
+    (`ops.kernels.serving_attention.paged_latent_attention`)."""
+    w = lp["wkvb"].astype(dtype).reshape(cfg.kv_lora_rank, heads, -1)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def latent_self_attention(h: jax.Array, lp: Dict[str, jax.Array],
+                          cfg: LlamaConfig, heads: int, cos: jax.Array,
+                          sin: jax.Array) -> jax.Array:
+    """Causal latent attention of whole sequences h [B, T, d] in the
+    EXPANDED form (no cache): every position's latent through Wkvb to a
+    head's (k_nope | v), k_h = (k_nope_h | k_rope), scores times
+    `cfg.score_scale`, softmax in float32; the heads' outputs through Wo.
+    The paged engine computes the same numbers over its latent pages,
+    decode rows in the absorbed form."""
+    B, T, _ = h.shape
+    C = cfg.kv_lora_rank
+    q = jnp.concatenate(latent_q(h, lp, cfg, heads, cos, sin), axis=-1)
+    row = latent_kv(h, lp, cfg, cos, sin)
+    wk, wv = latent_wkvb(lp, cfg, heads, h.dtype)
+    k = jnp.concatenate(
+        [jnp.einsum("btc,chn->bthn", row[..., :C], wk),
+         jnp.broadcast_to(row[:, :, None, C:],
+                          (B, T, heads, cfg.qk_rope_head_dim))], axis=-1)
+    v = jnp.einsum("btc,chv->bthv", row[..., :C], wv)
+    s = (jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32)
+         * cfg.score_scale)
+    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, None], s, -1e30)
+    o = jnp.einsum("bhts,bshv->bthv", jax.nn.softmax(s, axis=-1
+                                                     ).astype(h.dtype), v)
+    return o.reshape(B, T, -1) @ lp["wo"].astype(o.dtype)
 
 
 def plan_segments(cfg: LlamaConfig):
@@ -792,7 +1006,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
     T = tokens.shape[1]
     if cfg.layer_plan:
         kinds = cfg.kinds
-        ropes = {spec.rope: rope_table(jnp.arange(T), cfg.head_dim,
+        ropes = {spec.rope: rope_table(jnp.arange(T), cfg.rope_dim,
                                        spec.rope) for spec in kinds}
 
         def plan_body(kind, carry, lp):

@@ -742,6 +742,116 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
         return fmha_out, qkv3.reshape(token_num, -1), key_pool, value_pool
 
 
+def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
+                           seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
+                           block_tables, sm_scale, use_pallas=False):
+    """One layer of multi-head latent attention on the stacked LATENT page
+    pool [L, num_blocks, 1, block_size, W]: write the new tokens' cache rows
+    `row_tok` [tok, w] (latent | rope key, `models.llama.latent_kv`; w <= W,
+    the pool's rows are whole lanes and the rest of a row is zeros) into
+    `layer`'s pages where they lie, then attend, causal, scores times
+    `sm_scale`. There is no value pool. q_nope [tok, H, nope] and q_rope
+    [tok, H, rope] are a token's queries (`models.llama.latent_q`), wk
+    [C, H, nope] and wv [C, H, v] the halves of Wkvb
+    (`models.llama.latent_wkvb`).
+
+    WHICH FORM A LAUNCH TAKES (the one rule, here and nowhere else): every
+    row attends in the ABSORBED form, (q_nope wk_h^T | q_rope) against the
+    cache rows themselves, its head's output the probabilities' sum over
+    the rows' latents, then through wv_h: 2 x (W + C) FLOPs a (row, key,
+    head). `use_pallas` "decode" is the decode launch (one token a
+    sequence); True is a tick with a chunk, whose one-row sequences go
+    through that same decode launch and whose chunks go through the mixed
+    walk (`paged_attention_latent`): a work item of the walk moves 4.6 MB
+    of rows for one live token, and 63 of them cost a layer 2.6 ms where
+    the decode launch takes under 1 (PERF.md section 6, PR 41). The
+    EXPANDED form for a chunk (the sequence's cache rows rebuilt through
+    Wkvb to a head's keys and values, 2 x (nope + rope + v) FLOPs a (row,
+    key, head): 3.6 x fewer) was built and measured in that PR and is not
+    taken: its kernel ran the chunk at 37 TFLOP/s where the walk runs it
+    at 131, and the rebuild cost 0.9 ms a layer beside it. False is the
+    stock dense gather (CPU tests). Scopes: `latent_q` (the absorption),
+    `cache_write`, `paged_attention` > `paged_attention_latent` (the
+    launches), `latent_out` (wv). Returns (o [tok, H * v], pool)."""
+    from ..pallas import paged_attention_latent as PL
+    _, num_blocks, _, bs, W = pool.shape
+    B, max_blocks = block_tables.shape
+    token_num, H, nope = q_nope.shape
+    C, w = wk.shape[0], row_tok.shape[-1]
+    max_kv = max_blocks * bs
+    cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
+    tok_idx = jnp.arange(token_num, dtype=jnp.int32)
+    tok_b = jnp.clip(jnp.searchsorted(cu, tok_idx, side="right") - 1, 0, B - 1)
+    tok_local = tok_idx - cu[tok_b]
+    past = seq_lens_decoder.reshape(-1).astype(jnp.int32)
+    this = seq_lens_this_time.reshape(-1).astype(jnp.int32)
+    tok_pos = past[tok_b] + tok_local
+    tok_valid = tok_local < this[tok_b]
+    with jax.named_scope("latent_q"):
+        q_tok = jnp.concatenate(
+            [jnp.einsum("thn,chn->thc", q_nope, wk.astype(q_nope.dtype)),
+             q_rope, jnp.zeros((token_num, H, W - w), q_nope.dtype)], axis=-1)
+    rows = jnp.pad(row_tok, ((0, 0), (0, W - w)))[:, None]     # [tok, 1, W]
+
+    with jax.named_scope("cache_write"):
+        if use_pallas:
+            pages, lo, hi, src = page_plan(past, this, cu, block_tables,
+                                           num_blocks, bs, token_num)
+            pool = PL.write_latent_pages(
+                pool, layer, pages, lo, hi,
+                rows[src].transpose(0, 2, 1, 3))               # [n, 1, bs, W]
+        else:
+            tok_page = jnp.take_along_axis(
+                block_tables[tok_b], (tok_pos // bs)[:, None], axis=1)[:, 0]
+            tok_page = jnp.where(tok_valid & (tok_page >= 0), tok_page,
+                                 num_blocks + tok_idx)
+            pool = write_rows(pool, layer, tok_page, tok_pos % bs, rows)
+
+    def way_out(o_latent):                          # [tok, H, C] -> [tok, H*v]
+        with jax.named_scope("latent_out"):
+            return jnp.einsum("thc,chv->thv", o_latent,
+                              wv.astype(o_latent.dtype)
+                              ).reshape(token_num, -1)
+
+    with jax.named_scope("paged_attention"):
+        if use_pallas == "decode":
+            with jax.named_scope("paged_attention_latent"):
+                first = jnp.clip(cu[:B], 0, token_num - 1)
+                o = PL.latent_attention(q_tok[first], pool, block_tables,
+                                        past, this, sm_scale, layer, C)[tok_b]
+                o = jnp.where(tok_valid[:, None, None], o, 0)
+            return way_out(o), pool
+        if use_pallas:
+            with jax.named_scope("paged_attention_latent"):
+                single = this == 1
+                first = jnp.clip(cu[:B], 0, token_num - 1)
+                rows_1 = PL.latent_attention(
+                    q_tok[first], pool, block_tables, past,
+                    single.astype(jnp.int32), sm_scale, layer, C)[tok_b]
+                chunks = PL.latent_attention_packed(
+                    q_tok, pool, block_tables, past,
+                    jnp.where(single, 0, this), cu, sm_scale, layer, C)
+                o = jnp.where((single[tok_b] & tok_valid)[:, None, None],
+                              rows_1, chunks)
+            return way_out(o), pool
+        # ---- stock read (CPU tests): a dense gather of every row's pages
+        with jax.named_scope("paged_attention_latent"):
+            pages = lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+            keys = pages[block_tables][:, :, 0].reshape(B, max_kv, W)
+            keys = keys[tok_b].astype(jnp.float32)             # [tok, S, W]
+            s = jnp.einsum("thw,tsw->ths", q_tok.astype(jnp.float32),
+                           keys) * sm_scale
+            live = jnp.broadcast_to((block_tables >= 0)[:, :, None],
+                                    (B, max_blocks, bs)).reshape(B, max_kv)
+            ok = ((jnp.arange(max_kv)[None, :] <= tok_pos[:, None])
+                  & live[tok_b])
+            p = jax.nn.softmax(jnp.where(ok[:, None, :], s, -1e30), axis=-1)
+            o = jnp.einsum("ths,tsc->thc", p, keys[..., :C])
+            o = jnp.where(tok_valid[:, None, None], o, 0.0
+                          ).astype(q_tok.dtype)
+        return way_out(o), pool
+
+
 # ---------------------------------------------------------------------------
 # fused_multi_transformer_ (whole serving stack)
 # ---------------------------------------------------------------------------

@@ -1,0 +1,402 @@
+"""Paged attention over a LATENT page pool (Pallas): the read and the write
+of multi-head latent attention's cache (`models.llama.latent_kv`: one row
+(c | k_rope) a position for every head) in the absorbed form, where a
+head's query row (q_nope Wkvb,K^T | q_rope) meets the cache row itself and
+its output is the probabilities' sum over the rows' first `value_dim`
+values (the latents), before Wkvb,V. It is multi-query attention with H
+query heads over ONE key row whose leading slice is also the value row.
+
+What differs from `paged_attention.py`, whose two whole-page walks these
+follow step for step (`_work_items`, `mixed_items` and the double-buffered
+page copies are its own):
+
+- one pool `[L, num_blocks, 1, block_size, W]`, no value pool: a page is
+  copied once and read twice, as keys `[span, W]` and as values
+  `[span, :value_dim]`;
+- the group is all H heads (64 at Kimi-K2's widths), so a decode row is
+  already an MXU tile of rows: the small row tile of a mixed launch is one
+  token (`_SMALL_TOKENS`), a work item's 32 tokens (2,048 rows), a decode
+  walk's key block 512 positions; a work item's blocks of rows are 4.6 MB
+  in and out, so the caller sends a mixed tick's one-row sequences through
+  the decode launch (`serving_attention.paged_latent_attention`) and the
+  items behind the last one in use touch no block;
+- both products run on the operands' own 16-bit type with float32
+  accumulation (the probabilities are rounded to the pages' type for p.v,
+  as flash kernels do): at 64 rows a key the read is near the chip's
+  ridge (121 FLOP/B against 240), and float32 products, which the MXU
+  makes in several bf16 passes, would put it far on the compute side;
+- no int8 pages, no window, no block-causal mask: no latent model served
+  has them.
+
+W is the pool's row width, whole lanes: the engine pads a row of 576
+values (512 + 64) to 640 with zeros, which add nothing to a score and are
+never read as values. Mosaic takes no whole-page copy out of a pool whose
+rows are 4.5 lane tiles wide ("Slice shape along dimension 4 must be
+aligned to tiling (128)", tests/test_chip_compile.py), so the choice was
+between these 64 idle lanes (a ninth more bytes a key) and the BlockSpec
+walk that PRs 28 and 30 measured 18-83 x slower.
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import NEG_INF, _i32, available, count_launch
+from .paged_attention import (_STAT_LANES, _loop_i32, _work_items,
+                              mixed_items)
+
+__all__ = ["latent_attention", "latent_attention_packed",
+           "write_latent_pages", "padded_width", "LANES"]
+
+LANES = 128
+# key positions of a key block (whole pages), tokens of a work item's row
+# tile and of its small row tile (x H rows each)
+_DECODE_KEYS = 512
+_MIXED_KEYS = 512
+_MIXED_TOKENS = 32
+_SMALL_TOKENS = 1
+_VMEM_LIMIT = 96 << 20
+
+
+def padded_width(width: int) -> int:
+    """A cache row's width in the pool: `width` values in whole lanes."""
+    return -(-width // LANES) * LANES
+
+
+def _pages(keys: int, block_size: int, max_blocks: int) -> int:
+    return max(1, min(keys // block_size, max_blocks))
+
+
+def _copies(tables_ref, b, i, slot, pages: int, layer, pool, buf, sems):
+    """The copies that bring key block i of sequence b into buffer `slot`:
+    one whole page `pool[layer, page, 0]` = [block_size, W] each."""
+    return [pltpu.make_async_copy(
+        pool.at[layer, tables_ref[b, i * _i32(pages) + _i32(j)], _i32(0)],
+        buf.at[slot, _i32(j)], sems.at[slot]) for j in range(pages)]
+
+
+def _block_products(q, kbuf, slot, ok, sm_scale, value_dim, m_prev, l_prev,
+                    acc_prev):
+    """One key block's online-softmax step for query rows q [R, W] against
+    the block in `kbuf[slot]` [pages, bs, W]; `ok` [R | 1, span] marks the
+    keys a row sees. Returns (m, l, acc)."""
+    pages, bs, W = kbuf.shape[1:]
+    k = kbuf[slot].reshape(pages * bs, W)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.DEFAULT,
+                            preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(ok, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    # a masked key of a row with a live one gives exp(-1e30 - m) = 0; a
+    # row with none yet is a row without a query (key 0 is in every first
+    # block), zeroed by the caller
+    prob = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_new = l_prev * alpha + jnp.sum(prob, axis=-1, keepdims=True)
+    acc = acc_prev * alpha + jax.lax.dot_general(
+        prob.astype(k.dtype), k[:, :value_dim], (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc
+
+
+def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, q_ref, pool,
+                   o_ref, kbuf, sems, acc, m_sc, l_sc, *, sm_scale: float,
+                   block_size: int, pages: int, value_dim: int):
+    """One sequence b of a decode launch: its one token's H query rows
+    against its live key blocks of `pages` whole pages."""
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+    H = acc.shape[0]
+    span = pages * block_size
+    width = tables_ref.shape[1]
+    past = past_ref[b]
+    n_blocks = jnp.where(
+        this_ref[b] > 0,
+        jnp.minimum(jax.lax.div(past + _i32(span), _i32(span)),
+                    _i32(width // pages)), _i32(0))
+
+    def copies(i, slot):
+        return _copies(tables_ref, b, i, slot, pages, layer, pool, kbuf, sems)
+
+    m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+    l_sc[...] = jnp.zeros_like(l_sc)
+    acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(n_blocks > 0)
+    def _():
+        for c in copies(_i32(0), _i32(0)):
+            c.start()
+
+    def block(i, _):
+        slot = jax.lax.rem(i, _i32(2))
+
+        @pl.when(i + _i32(1) < n_blocks)
+        def _():
+            for c in copies(i + _i32(1), _i32(1) - slot):
+                c.start()
+
+        for c in copies(i, slot):
+            c.wait()
+        kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+                  + i * _i32(span))
+        m, l, a = _block_products(
+            q_ref[0], kbuf, slot, kv_abs <= past, sm_scale, value_dim,
+            m_sc[:, :1], l_sc[:, :1], acc[...])
+        acc[...] = a
+        m_sc[...] = jnp.broadcast_to(m, (H, _STAT_LANES))
+        l_sc[...] = jnp.broadcast_to(l, (H, _STAT_LANES))
+
+    jax.lax.fori_loop(_i32(0), n_blocks, block, None)
+    l = l_sc[:, :1]
+    o_ref[0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _check(q, pool, value_dim):
+    W = pool.shape[-1]
+    if pool.ndim != 5 or pool.shape[2] != 1 or q.shape[-1] != W:
+        raise ValueError(
+            f"latent pool {pool.shape}: pass the stacked [L, nb, 1, bs, W] "
+            f"and query rows [..., H, W]={q.shape}")
+    if not 0 < value_dim <= W:
+        raise ValueError(f"value_dim={value_dim} outside the row of {W}")
+
+
+def latent_attention(q_rows, pool, block_tables, seq_lens_decoder,
+                     seq_lens_this_time, sm_scale: float, layer,
+                     value_dim: int, interpret: Optional[bool] = None):
+    """The decode launch: q_rows [B, H, W], one token a sequence at
+    position `seq_lens_decoder[b]` (an idle slot: `seq_lens_this_time[b]`
+    0), against the pages `block_tables[b]` of `pool[layer]`, which already
+    hold the token's own row. Returns [B, H, value_dim], idle slots 0."""
+    _check(q_rows, pool, value_dim)
+    B, H, W = q_rows.shape
+    bs = pool.shape[3]
+    if interpret is None:
+        interpret = not available()
+    pages = _pages(_DECODE_KEYS, bs, block_tables.shape[1])
+    tables = jnp.maximum(block_tables.astype(jnp.int32), 0)
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % pages)))
+    row = lambda w: pl.BlockSpec((1, H, w), lambda b, *_: (b, _i32(0), _i32(0)),
+                                 memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(B,),
+        in_specs=[row(W), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row(value_dim),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, bs, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((H, value_dim), jnp.float32),
+            pltpu.VMEM((H, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((H, _STAT_LANES), jnp.float32)])
+    kernel = functools.partial(
+        _decode_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
+        pages=int(pages), value_dim=int(value_dim))
+    count_launch()
+    return pl.pallas_call(
+        kernel, name="paged_attention_latent_decode", grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, value_dim), q_rows.dtype),
+        interpret=interpret,
+    )(tables, seq_lens_decoder.reshape(-1).astype(jnp.int32),
+      seq_lens_this_time.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q_rows, pool)
+
+
+def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
+                  used_ref, q_ref, pool, o_ref, kbuf, sems, acc, m_sc, l_sc,
+                  *,
+                  sm_scale: float, block_size: int, pages: int, heads: int,
+                  small: int, value_dim: int):
+    """One work item j of a mixed launch: the query rows of sequence
+    seq[j] from chunk offset t0[j] on (row r = t * H + h), against that
+    sequence's key blocks up to the tile's own causal limit. An item with
+    at most `small` live tokens computes on its first small * H rows. An
+    item behind the last one in use (`used_ref`) does nothing: its blocks
+    are the last used item's (the index maps say so), which it must leave
+    as they are."""
+    del used_ref
+    j = pl.program_id(0)
+    b = seq_ref[j]
+    t0 = t0_ref[j]
+    layer = layer_ref[0]
+    R = acc.shape[0]
+    span = pages * block_size
+    width = tables_ref.shape[1]
+    past = past_ref[b]
+    live = jnp.clip(this_ref[b] - t0, _i32(0), _i32(R // heads))
+    n_blocks = jnp.where(
+        live > 0,
+        jnp.minimum(jax.lax.div(past + t0 + live + _i32(span - 1),
+                                _i32(span)), _i32(width // pages)), _i32(0))
+
+    def fetch(i, slot, wait=False):
+        def page(p):
+            c = pltpu.make_async_copy(
+                pool.at[layer, tables_ref[b, i * _i32(pages) + p], _i32(0)],
+                kbuf.at[slot, p], sems.at[slot])
+            c.wait() if wait else c.start()
+        _loop_i32(pages, page)
+
+    def walk(rows):
+        t = jax.lax.div(jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0),
+                        _i32(heads))
+        # a row's own position; rows without a query sit before every key
+        pos = jnp.where(t < live, past + t0 + t, _i32(-1))       # [rows, 1]
+        m_sc[:rows] = jnp.full((rows, _STAT_LANES), NEG_INF, jnp.float32)
+        l_sc[:rows] = jnp.zeros((rows, _STAT_LANES), jnp.float32)
+        acc[:rows] = jnp.zeros((rows, value_dim), jnp.float32)
+        pl.when(n_blocks > 0)(lambda: fetch(_i32(0), _i32(0)))
+
+        def block(i, _):
+            slot = jax.lax.rem(i, _i32(2))
+            pl.when(i + _i32(1) < n_blocks)(
+                lambda: fetch(i + _i32(1), _i32(1) - slot))
+            fetch(i, slot, wait=True)
+            kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+                      + i * _i32(span))
+            m, l, a = _block_products(
+                q_ref[0, :rows], kbuf, slot, kv_abs <= pos, sm_scale,
+                value_dim, m_sc[:rows, :1], l_sc[:rows, :1], acc[:rows])
+            acc[:rows] = a
+            m_sc[:rows] = jnp.broadcast_to(m, (rows, _STAT_LANES))
+            l_sc[:rows] = jnp.broadcast_to(l, (rows, _STAT_LANES))
+
+        jax.lax.fori_loop(_i32(0), n_blocks, block, None)
+        l = l_sc[:rows, :1]
+        out = acc[:rows] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[0, :rows] = jnp.where(pos >= 0, out, 0.0).astype(o_ref.dtype)
+        if rows < R:
+            o_ref[0, rows:] = jnp.zeros((R - rows, value_dim), o_ref.dtype)
+
+    if 0 < small * heads < R:
+        pl.when((live > 0) & (live <= small))(lambda: walk(small * heads))
+        pl.when(live > small)(lambda: walk(R))
+    else:
+        pl.when(live > 0)(lambda: walk(R))
+
+
+def mixed_tokens(token_num: int) -> int:
+    """Tokens of a work item's row tile."""
+    return max(1, min(_MIXED_TOKENS, token_num))
+
+
+def latent_attention_packed(q_tok, pool, block_tables, seq_lens_decoder,
+                            seq_lens_this_time, cu_seqlens_q,
+                            sm_scale: float, layer, value_dim: int,
+                            interpret: Optional[bool] = None):
+    """The mixed launch, on the packed token stream: q_tok [token_num, H,
+    W], sequence b's `seq_lens_this_time[b]` tokens at rows cu_seqlens_q[b]
+    on, its token t at position `seq_lens_decoder[b] + t`, causal. Returns
+    [token_num, H, value_dim], rows that are no sequence's token 0."""
+    _check(q_tok, pool, value_dim)
+    token_num, H, W = q_tok.shape
+    B = block_tables.shape[0]
+    bs = pool.shape[3]
+    if interpret is None:
+        interpret = not available()
+    cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
+    past = seq_lens_decoder.reshape(-1).astype(jnp.int32)
+    this = seq_lens_this_time.reshape(-1).astype(jnp.int32)
+    tok_idx = jnp.arange(token_num, dtype=jnp.int32)
+    tok_b = jnp.clip(jnp.searchsorted(cu, tok_idx, side="right",
+                                      method="compare_all") - 1, 0, B - 1)
+    tok_local = tok_idx - cu[tok_b]
+    tok_valid = (tok_local < this[tok_b])[:, None, None]
+
+    tq = mixed_tokens(token_num)
+    items = mixed_items(token_num, B, tq)
+    seq, t0, first = _work_items(cu, this, tq, items, token_num)
+    row_tok = jnp.clip((cu[seq] + t0)[:, None]
+                       + jnp.arange(tq, dtype=jnp.int32)[None, :],
+                       0, token_num - 1)                          # [items, tq]
+    q_items = q_tok[row_tok].reshape(items, tq * H, W)
+    pages = _pages(_MIXED_KEYS, bs, block_tables.shape[1])
+    tables = jnp.maximum(block_tables.astype(jnp.int32), 0)
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % pages)))
+    # the items in use come first; one behind them names the last one's
+    # blocks, so that its 4.6 MB of rows are neither fetched nor written
+    used = jnp.maximum(jnp.sum(-(-this // tq)), 1).astype(jnp.int32)
+    row = lambda w: pl.BlockSpec(
+        (1, tq * H, w),
+        lambda j, tb, pa, th, ly, sq, t0_, used_: (
+            jnp.minimum(j, used_[0] - _i32(1)), _i32(0), _i32(0)),
+        memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=7, grid=(items,),
+        in_specs=[row(W), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=row(value_dim),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, bs, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((tq * H, value_dim), jnp.float32),
+            pltpu.VMEM((tq * H, _STAT_LANES), jnp.float32),
+            pltpu.VMEM((tq * H, _STAT_LANES), jnp.float32)])
+    kernel = functools.partial(
+        _mixed_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
+        pages=int(pages), heads=int(H), small=min(_SMALL_TOKENS, tq),
+        value_dim=int(value_dim))
+    count_launch()
+    o_items = pl.pallas_call(
+        kernel, name="paged_attention_latent_mixed", grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((items, tq * H, value_dim),
+                                       q_tok.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(tables, past, this, jnp.asarray(layer, jnp.int32).reshape(1), seq, t0,
+      used.reshape(1), q_items, pool)
+    o_items = o_items.reshape(items, tq, H, value_dim)
+    item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
+    return jnp.where(tok_valid, o_items[item, tok_local % tq], 0
+                     ).astype(q_tok.dtype)
+
+
+def _write_kernel(layer_ref, page_ref, lo_ref, hi_ref, new_ref, in_ref,
+                  out_ref):
+    """One touched page: slots [lo, hi) take the staged rows, the others
+    keep what the page held (`paged_attention._write_kernel`, one pool)."""
+    del layer_ref, page_ref
+    j = pl.program_id(0)
+    slot = jax.lax.broadcasted_iota(jnp.int32, in_ref.shape, 2)
+    fresh = (slot >= lo_ref[j]) & (slot < hi_ref[j])
+    out_ref[...] = jnp.where(fresh, new_ref[...].astype(jnp.float32),
+                             in_ref[...].astype(jnp.float32)
+                             ).astype(out_ref.dtype)
+
+
+def write_latent_pages(pool, layer, pages, lo, hi, new,
+                       interpret: Optional[bool] = None):
+    """`paged_attention.write_pages` for the one latent pool [L, nb, 1,
+    bs, W], aliased input to output: for each plan entry j, slots [lo[j],
+    hi[j]) of `pool[layer, pages[j]]` take `new[j]`'s rows ([n, 1, bs, W])
+    and the other slots keep theirs. Returns the pool."""
+    _, _, _, bs, W = pool.shape
+    n = pages.shape[0]
+    if interpret is None:
+        interpret = not available()
+    mem = {"memory_space": pltpu.VMEM}
+    new_spec = pl.BlockSpec(
+        (1, 1, bs, W),
+        lambda j, ly, pg, lo_, hi_: (j, _i32(0), _i32(0), _i32(0)), **mem)
+    page_spec = pl.BlockSpec(
+        (None, 1, 1, bs, W),
+        lambda j, ly, pg, lo_, hi_: (ly[0], pg[j], _i32(0), _i32(0),
+                                     _i32(0)), **mem)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(n,),
+        in_specs=[new_spec, page_spec], out_specs=page_spec)
+    count_launch()
+    return pl.pallas_call(
+        _write_kernel, name="paged_cache_write_latent", grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        # operands count the four prefetched scalars: the pool is 5
+        input_output_aliases={5: 0}, interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), pages.astype(jnp.int32),
+      lo.astype(jnp.int32), hi.astype(jnp.int32), new.astype(pool.dtype),
+      pool)

@@ -657,6 +657,121 @@ def test_laguna_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
     print("Laguna depth-5 GiB by tok_pad:", gib)
 
 
+@pytest.mark.parametrize("launch", ["decode", "mixed", "write"])
+def test_latent_walks_compile(one_chip, launch):
+    """Both latent walks and the page write at Kimi-K2.6's shapes (one
+    latent pool of 6 layers x 38,912 pages of 16 rows, a row of 576 values
+    in 640 lanes, group 64: all heads on one key row, 64 slots, tables of
+    576 entries, a 1,024-token stream) lower for the chip."""
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+    batch, heads, tokens = 64, 64, 1024
+    width = pl_.padded_width(576)
+    assert width == 640
+    pool = _bf16(6, 38912, 1, 16, width)
+    lens = [((batch, 576), jnp.int32), ((batch,), jnp.int32),
+            ((batch,), jnp.int32)]
+    if launch == "decode":
+        def fn(q, pool, tables, past, this, layer):
+            return pl_.latent_attention(q, pool, tables, past, this, 0.1447,
+                                        layer, 512, interpret=False)
+        shapes = [_bf16(batch, heads, width), pool, *lens, ((), jnp.int32)]
+    elif launch == "mixed":
+        def fn(q, pool, tables, past, this, cu, layer):
+            return pl_.latent_attention_packed(
+                q, pool, tables, past, this, cu, 0.1447, layer, 512,
+                interpret=False)
+        shapes = [_bf16(tokens, heads, width), pool, *lens,
+                  ((batch + 1,), jnp.int32), ((), jnp.int32)]
+    else:
+        n = tokens // 16 + 2 * batch
+
+        def fn(pool, layer, pages, lo, hi, new):
+            return pl_.write_latent_pages(pool, layer, pages, lo, hi, new,
+                                          interpret=False)
+        shapes = [pool, ((), jnp.int32), *[((n,), jnp.int32)] * 3,
+                  _bf16(n, 1, 16, width)]
+    text = _compile(fn, one_chip, *shapes).as_text()
+    assert ("paged_cache_write_latent" if launch == "write"
+            else f"paged_attention_latent_{launch}") in text
+
+
+def test_latent_walk_is_refused_at_a_row_off_whole_lanes(one_chip):
+    """Why the pool's rows are 640 lanes and not 576: Mosaic takes no
+    whole-page copy out of a pool whose rows are 4.5 lane tiles wide."""
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+
+    def fn(q, pool, tables, past, this, layer):
+        return pl_.latent_attention(q, pool, tables, past, this, 0.1447,
+                                    layer, 512, interpret=False)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(fn, one_chip, _bf16(8, 64, 576), _bf16(2, 64, 1, 16, 576),
+                 ((8, 16), jnp.int32), ((8,), jnp.int32), ((8,), jnp.int32),
+                 ((), jnp.int32))
+
+
+def test_kimi_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
+    """The cell `serve_latent_longctx_decode` as the engine builds it on a
+    TPU (`available` steered true), from the configuration file itself:
+    both executables (a tick with a prefill chunk, 1,024 rows; a decode
+    tick, 64 rows) compile for the described v5e with the one latent pool
+    in their carry and 12 of 384 experts held, and the compiler counts each
+    over 25 % and under the chip's 15.75 GiB."""
+    import json
+    import os
+
+    from benchmark.drivers import closed_loop_serve_latent as D
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import llama as L
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-k2.6-serve.json")) as f:
+        file = json.load(f)
+    cfg, e = D.kimi_config(file, jnp.bfloat16), file["engine"]
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert params["blocks"][1]["w1"].shape == (5, 12, 7168, 2048)
+    assert params["blocks"][1]["router"].shape == (5, 7168, 384)
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+    for module in (fa, pa, pl_):
+        monkeypatch.setattr(module, "available", lambda: True)
+    eng = PagedServingEngine(
+        cfg, params, num_blocks=e["num_blocks"], block_size=e["block_size"],
+        max_batch=e["max_batch"], token_budget=e["token_budget"],
+        max_len=e["max_len"], pallas=True, pallas_ffn=False)
+    assert eng._value_cache is None
+    assert eng._key_cache.shape == (6, 38912, 1, 16, 640)
+    build, gib = eng._build_step, {}
+
+    def compiled_not_run(tok_pad, B, *rest):
+        fn = build(tok_pad, B, *rest)
+
+        def tick(*args):
+            abstract = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            compiled = fn.lower(*abstract).compile()
+            text = compiled.as_text()
+            # a tick with a chunk runs both: its one-row sequences go
+            # through the decode launch
+            assert ("paged_attention_latent_mixed" in text) == (
+                tok_pad == 1024)
+            assert "paged_attention_latent_decode" in text
+            m = compiled.memory_analysis()
+            gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                            + m.temp_size_in_bytes
+                            - m.alias_size_in_bytes) / 2 ** 30
+            return jnp.zeros((B + 4,), jnp.int32), args[1], args[2]
+        return tick
+
+    monkeypatch.setattr(eng, "_build_step", compiled_not_run)
+    eng.submit(list(range(1, 70)), max_new_tokens=4)
+    eng.step()                  # the prompt, one chunk
+    eng.step()                  # a decode row
+    assert set(gib) == {1024, 64}
+    assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
+    print("Kimi depth-6 GiB by tok_pad:", gib)
+
+
 @pytest.mark.parametrize("top_k", [0, 50])
 def test_fused_sample_prep_compiles(one_chip, top_k):
     batch = 8
