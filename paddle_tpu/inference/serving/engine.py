@@ -92,7 +92,9 @@ one kind and one lifetime, so the prefix
 cache, copy-on-write and preemption work as for a uniform model; page
 hand-off is refused as for every plan. A config that holds a share of its
 routed experts (``cfg.experts_held``) routes over all of them and computes
-its own (``llama.routed_ffn_load``); ``moe_pairs_held`` counts those pairs.
+its own (``llama.routed_ffn_load``); ``moe_pairs_held`` counts those pairs,
+and ``moe_compact_overflow`` the launches that had more of them than
+``llama.held_pair_slots`` gives places and so took the whole form.
 
 SLO metrics (TTFT/TPOT histograms, queue-depth and KV-block-utilization
 gauges, admit/preempt/shed counters + flight-recorder events) flow
@@ -441,11 +443,15 @@ class PagedServingEngine:
             # seen): (row, expert) pairs a sparse layer, (layer, expert)
             # groups with at least one row, most rows on one expert in one
             # layer; with a held share of the experts the groups and the
-            # load are over the held ones, and `moe_pairs_held` the pairs
-            # on them, summed over the sparse layers
+            # load are over the held ones, `moe_pairs_held` the pairs on
+            # them, summed over the sparse layers, and
+            # `moe_compact_overflow` the (tick, sparse layer) launches whose
+            # held pairs passed `llama.held_pair_slots` and took the
+            # whole form
             self._moe_fields = ("moe_pairs", "moe_experts_hit",
                                 "moe_max_load") + (
-                ("moe_pairs_held",) if cfg.experts_held else ())
+                ("moe_pairs_held", "moe_compact_overflow")
+                if cfg.experts_held else ())
             self.stats.update(dict.fromkeys(self._moe_fields, 0))
         if self.latent:
             # keys and (row, key) pairs inside the causal mask, summed over
@@ -1040,7 +1046,7 @@ class PagedServingEngine:
                         x = x + y
                     return (x, kcs, vcs), (
                         jnp.sum(load > 0), jnp.max(load),
-                        *((jnp.sum(load),) if cfg.experts_held else ()))
+                        *self._held_counts(load, h.shape[0]))
                 with jax.named_scope("ffn"):
                     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
                     if ffn_mode:
@@ -1254,8 +1260,8 @@ class PagedServingEngine:
                     x = x + y
                     hit = hit + jnp.sum(load > 0, dtype=jnp.int32)
                     top = jnp.maximum(top, jnp.max(load))
-                    if cfg.experts_held:
-                        held = [held[0] + jnp.sum(load, dtype=jnp.int32)]
+                    held = [c + n for c, n in zip(
+                        held, self._held_counts(load, h.shape[0]))]
             else:
                 with jax.named_scope("ffn"):
                     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
@@ -1265,9 +1271,20 @@ class PagedServingEngine:
         zero = jnp.zeros((), jnp.int32)
         x, pk, pv, *counts = L.scan_plan(
             cfg, body, (x, pools_k, pools_v, zero, zero)
-            + ((zero,) if cfg.experts_held else ()), stacks)
+            + ((zero, zero) if cfg.experts_held else ()), stacks)
         return (x, pk if two else pk[0], pv if two else pv[0],
                 tuple(counts))
+
+    def _held_counts(self, load, rows: int):
+        """One sparse layer's two counters under a held share of the
+        experts, () without one: the pairs on held experts, and 1 where
+        they pass `llama.held_pair_slots`, the places the sorted form gives
+        them, past which its launch takes the whole form."""
+        if not self.cfg.experts_held:
+            return ()
+        pairs = jnp.sum(load, dtype=jnp.int32)
+        return (pairs, (pairs > L.held_pair_slots(rows, self.cfg)
+                        ).astype(jnp.int32))
 
     def _get_step_fn(self, tok_pad: int, B: int, decode: bool = False,
                      ffn_mode=False, ad_sig: Tuple[int, ...] = (),

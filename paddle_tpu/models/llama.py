@@ -44,7 +44,13 @@ routed experts (`experts_held` = (first, count)): `route` runs over all
 `num_experts`, with a selection bias where the model has one
 (`router_bias`), and `routed_ffn_load` computes the pairs of the held
 experts and leaves out what the absent ones would add; nothing here
-stands in for the chips that hold them.
+stands in for the chips that hold them. In the sorted form only those
+pairs are gathered, multiplied and added into their rows (the COMPACT form
+of `_experts_sorted`): they get `held_pair_slots` places, `HELD_ROOM`
+times what an even router sends to a share of that size, in whole tiles of
+the kernel's rows, and a launch with more held pairs than places takes
+the form that moves all T*k pairs, which is also the one form of a config
+that holds every expert; no pair is ever dropped.
 """
 from __future__ import annotations
 
@@ -664,6 +670,31 @@ def _experts_dense(h, w, e, valid, lp, cfg: LlamaConfig, layer):
 
 
 GMM_ROWS = 128      # the grouped-matmul kernel's row tile
+# A config that holds a share of its experts gives the pairs on them this
+# many times the places an even router would fill (`held_pair_slots`). The
+# count on 12 of 384 experts is a sum of T*k rare draws, so it scatters
+# like a Poisson count about its mean and four times the mean is out of
+# its reach (256 expected in a chunk tick of 1,024 rows: 1,024 places are
+# 48 standard deviations off); what the factor leaves room for is a router
+# that prefers the held experts, which PR 41 saw carry 2.5-3.9 % of the
+# pairs by seed against the even 3.125 %, and a trained one's hot experts.
+# Past it nothing is dropped: the tick takes the whole form.
+HELD_ROOM = 4
+
+
+def held_pair_slots(rows: int, cfg: LlamaConfig) -> int:
+    """The places `_experts_sorted` gives the (row, expert) pairs of a
+    launch of `rows` rows: all rows * top_k of them where every expert is
+    held; under a held share `HELD_ROOM` times what an even router sends
+    to the held experts (rows * top_k * count / num_experts, rounded up),
+    in whole tiles of `GMM_ROWS`, and never more than all. A function of
+    the launch's rows, `top_k` and the config's share alone."""
+    pairs = rows * cfg.top_k
+    if not cfg.experts_held:
+        return pairs
+    even = -(-pairs * cfg.held[1] // cfg.num_experts)
+    slots = HELD_ROOM * even
+    return min(slots + -slots % GMM_ROWS, pairs)
 
 
 def _gmm(xs, w, group_sizes, group_offset):
@@ -707,9 +738,20 @@ _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
 def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
-    """The T*k (row, expert) pairs sorted by expert, three grouped matmuls
-    over `load` rows a group, then un-sorted and summed over a row's k. A
-    padding row's pairs sort behind every group and belong to none.
+    """The (row, expert) pairs sorted by expert, three grouped matmuls
+    over `load` rows a group, and each pair's output times its router
+    weight summed into its row. A padding row's pairs, and under a held
+    share (`experts_held`) the pairs of experts held elsewhere, sort behind
+    every group and belong to none.
+
+    Only the first `held_pair_slots(T, cfg)` places of that order are
+    gathered, multiplied and combined. Where every expert is held that is
+    all T*k (the WHOLE form: the outputs are un-sorted and a row's k
+    summed). Under a share it is a few tiles (the COMPACT form: C rows
+    gathered, and each of them added into its row of [T, d], so a row with
+    no held pair gets an exact zero), chosen on the device by
+    sum(load) <= C; a launch whose held pairs do not fit takes the whole
+    form, so no pair is ever dropped.
 
     With `layer`, the weights are the stacked [L, E, ...] leaves and the
     kernel finds the layer's experts by its index map (group g reads
@@ -717,7 +759,6 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
     stack would be copied whole, 268 MB a matrix at OLMoE's widths, before
     each launch."""
     T, k = e.shape
-    pad = -(T * k) % GMM_ROWS
     held = cfg.held[1]
     offset = jnp.asarray(0 if layer is None else -layer * held, jnp.int32)
 
@@ -726,24 +767,46 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
         return _grouped_matmul(x, wn.reshape(-1, *wn.shape[-2:]), load,
                                offset)
 
-    # rows behind the last group are whatever the kernel left there, in
-    # either direction: select them away, do not multiply by zero
-    keep = (jnp.arange(T * k + pad) < jnp.sum(load))[:, None]
-    with jax.named_scope("dispatch"):
-        mine = valid[:, None]
-        if cfg.experts_held:    # a pair of an expert held elsewhere: no one's
-            mine = mine & (e >= 0) & (e < held)
-        flat_e = jnp.where(mine, e, held).reshape(-1)
-        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)  # [T*k]
-        xs = jnp.where(keep, jnp.take(h, jnp.pad(order // k, (0, pad)),
-                                      axis=0), 0)
-    with jax.named_scope("experts"):
-        a = jnp.where(keep, jax.nn.silu(dot(xs, "w1")) * dot(xs, "w3"), 0)
-        ys = dot(a, "w2")
-    with jax.named_scope("combine"):
-        ys = jnp.where(keep, ys, 0)[:T * k].astype(jnp.float32)
-        y = jnp.take(ys, jnp.argsort(order), axis=0).reshape(T, k, -1)
-        return jnp.sum(y * w[..., None], axis=1).astype(h.dtype)
+    def form(slots):
+        pad = -slots % GMM_ROWS
+        # rows behind the last group are whatever the kernel left there, in
+        # either direction: select them away, do not multiply by zero
+        keep = (jnp.arange(slots + pad) < jnp.sum(load))[:, None]
+        with jax.named_scope("dispatch"):
+            mine = valid[:, None]
+            if cfg.experts_held:
+                # a pair of an expert held elsewhere: no one's
+                mine = mine & (e >= 0) & (e < held)
+            flat_e = jnp.where(mine, e, held).reshape(-1)
+            order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+            pair = order[:slots]
+            xs = jnp.where(keep, jnp.take(h, jnp.pad(pair // k, (0, pad)),
+                                          axis=0), 0)
+        with jax.named_scope("experts"):
+            a = jnp.where(keep, jax.nn.silu(dot(xs, "w1")) * dot(xs, "w3"), 0)
+            ys = dot(a, "w2")
+        with jax.named_scope("combine"):
+            ys = jnp.where(keep, ys, 0)[:slots].astype(jnp.float32)
+            if slots == T * k:
+                y = jnp.take(ys, jnp.argsort(order), axis=0).reshape(T, k, -1)
+                return jnp.sum(y * w[..., None], axis=1).astype(h.dtype)
+            # [T, C]: a pair's router weight at (its row, its place), zero
+            # elsewhere and behind the last group. As a float32 product
+            # over the C rows it adds each into its row; on the v5e at
+            # Kimi's widths (C 1,024 of 8,192) that took 0.15 ms a layer
+            # where a scatter-add of the same rows took 0.84 and the whole
+            # form's un-sort and sum 2.05 (PERF.md section 6, PR 42)
+            comb = jnp.where(
+                (pair // k == jnp.arange(T)[:, None]) & keep[:slots, 0],
+                jnp.take(w.reshape(-1), pair), 0.0)
+            return jnp.dot(comb, ys, precision=lax.Precision.HIGHEST
+                           ).astype(h.dtype)
+
+    slots = held_pair_slots(T, cfg)
+    if slots == T * k:
+        return form(slots)
+    return lax.cond(jnp.sum(load) <= slots, lambda: form(slots),
+                    lambda: form(T * k))
 
 
 def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,

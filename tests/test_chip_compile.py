@@ -709,23 +709,29 @@ def test_latent_walk_is_refused_at_a_row_off_whole_lanes(one_chip):
                  ((), jnp.int32))
 
 
+def _kimi_file():
+    """`benchmark/configs/kimi-k2.6-serve.json`, as the cell reads it."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-k2.6-serve.json")) as f:
+        return json.load(f)
+
+
 def test_kimi_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
     """The cell `serve_latent_longctx_decode` as the engine builds it on a
     TPU (`available` steered true), from the configuration file itself:
     both executables (a tick with a prefill chunk, 1,024 rows; a decode
     tick, 64 rows) compile for the described v5e with the one latent pool
     in their carry and 12 of 384 experts held, and the compiler counts each
-    over 25 % and under the chip's 15.75 GiB."""
-    import json
-    import os
-
+    over 25 % and under the chip's 15.75 GiB, and no higher than before the
+    held experts' pairs got their compact form."""
     from benchmark.drivers import closed_loop_serve_latent as D
     from paddle_tpu.inference.serving import PagedServingEngine
     from paddle_tpu.models import llama as L
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs",
-                           "kimi-k2.6-serve.json")) as f:
-        file = json.load(f)
+    file = _kimi_file()
     cfg, e = D.kimi_config(file, jnp.bfloat16), file["engine"]
     params = jax.eval_shape(lambda k: L.init_params(cfg, k),
                             jax.random.PRNGKey(0))
@@ -760,7 +766,8 @@ def test_kimi_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
             gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
                             + m.temp_size_in_bytes
                             - m.alias_size_in_bytes) / 2 ** 30
-            return jnp.zeros((B + 4,), jnp.int32), args[1], args[2]
+            return (jnp.zeros((B + len(eng._moe_fields),), jnp.int32),
+                    args[1], args[2])
         return tick
 
     monkeypatch.setattr(eng, "_build_step", compiled_not_run)
@@ -769,7 +776,55 @@ def test_kimi_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
     eng.step()                  # a decode row
     assert set(gib) == {1024, 64}
     assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
+    # PR 41's readings, 12.79 and 12.27: the compact form of the held
+    # experts (PR 42) and the whole form behind one `cond` share their
+    # buffers (12.7878 -> 12.7884, 12.2705 -> 12.2711)
+    assert gib[1024] < 12.795 and gib[64] < 12.275, gib
     print("Kimi depth-6 GiB by tok_pad:", gib)
+
+
+@pytest.mark.parametrize("rows, slots", [(64, 128), (1024, 1024)])
+def test_a_held_share_compiles_its_compact_form_at_kimi_widths(one_chip, rows,
+                                                               slots):
+    """A sparse layer of `kimi-k2.6-serve` (12 of 384 experts held, 8 a
+    row) at the cell's two launches, a decode tick's 64 rows and a chunk
+    tick's 1,024: one `conditional` whose branches run the grouped matmuls
+    on `slots` rows (the compact form) and on all rows x 8 (the whole
+    form), and neither branch, nor handing the stacked experts to them,
+    makes anything of an expert matrix's size."""
+    import re
+
+    from benchmark.drivers import closed_loop_serve_latent as D
+    from paddle_tpu.models import llama as L
+    cfg = D.kimi_config(_kimi_file(), jnp.bfloat16)
+    assert L.held_pair_slots(rows, cfg) == slots
+    stack = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                           jax.random.PRNGKey(0))["blocks"][1]
+    names = sorted(n for n in stack if n in (
+        "router", "router_bias", "w1", "w3", "w2", "ws1", "ws3", "ws2"))
+
+    def fn(h, valid, layer, *leaves):
+        lp = {n: (w if n in ("w1", "w3", "w2") else w[0])
+              for n, w in zip(names, leaves)}
+        return L.routed_ffn_load(h, lp, cfg, valid, layer=layer)
+
+    available = fa.available
+    fa.available = lambda: True         # expert_form and interpret read it
+    try:
+        assert L.expert_form(cfg) == "sorted_gmm"
+        text = _compile(fn, one_chip, _bf16(rows, 7168),
+                        ((rows,), jnp.bool_), ((), jnp.int32),
+                        *((stack[n].shape, stack[n].dtype) for n in names)
+                        ).as_text()
+    finally:
+        fa.available = available
+    assert len(re.findall(r" conditional\(", text)) == 1
+    for m in (slots, rows * 8):
+        assert re.search(rf"bf16\[{m},2048\]\S* custom-call\(", text), m
+    made = re.findall(r"%(\S+) = bf16\[(?:5,)?12,(?:7168|2048),"
+                      r"(?:2048|7168)\]\S* (\w[\w-]*)\(", text)
+    assert {op for _, op in made} <= {"parameter", "bitcast",
+                                      "get-tuple-element"}, made
 
 
 @pytest.mark.parametrize("top_k", [0, 50])

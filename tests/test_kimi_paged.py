@@ -31,6 +31,7 @@ from benchmark.lib import agreement, reference_kimi as R
 from paddle_tpu.inference.serving import PagedServingEngine
 from paddle_tpu.models import llama as L
 from paddle_tpu.ops.pallas import paged_attention_latent as PL
+from tests.test_tracing import _profiled
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 with open(os.path.join(HERE, "..", "benchmark", "tests", "fixtures",
@@ -238,6 +239,157 @@ def test_a_share_computes_its_own_pairs_in_both_expert_forms(
                                       router_scale=cfg.router_scale,
                                       held=(4, 4)))
     assert np.abs(np.asarray(y)[:41] - ref).max() < 1e-4
+
+
+# ---- the compact form of a held share ----------------------------------------
+
+# 4 of 64 experts: a sixteenth, small enough that the held pairs get fewer
+# places than there are pairs (at 4 of 16, and at these row counts, they get
+# them all)
+SMALL = {**TINY, "router_width": 64, "held_experts_first": 20}
+
+
+@pytest.fixture(scope="module")
+def small_share():
+    """No selection bias: `sharpened`'s is the same for every row and
+    larger than the spread of the scores, so that all rows would choose the
+    same few experts and none of the held four."""
+    cfg, params = make(SMALL, seed=2)
+    return cfg, to_the_held(params, by=0.0)
+
+
+def to_the_held(params, by=100.0):
+    """`params` with a selection bias that sends every row's two experts to
+    the four held ones (experts 20-23), so that a launch's held pairs are
+    two a valid row."""
+    dense, sparse = params["blocks"]
+    bias = jnp.zeros_like(sparse["router_bias"]).at[:, 20:24].set(by)
+    return {**params, "blocks": (dense, {**sparse, "router_bias": bias})}
+
+
+def sorted_ffn(cfg, lp, h, valid):
+    """The sorted form of one sparse layer on rows h, in the kernel's
+    interpreter: (y, load, its program's text)."""
+    fn = lambda h: L.routed_ffn_load(h, lp, cfg, valid)
+    y, load = fn(h)
+    return np.asarray(y), np.asarray(load), str(jax.make_jaxpr(fn)(h))
+
+
+@pytest.mark.parametrize("rows, live, slots", [(200, 190, 128), (72, 72, 128),
+                                               (300, 263, 256)])
+def test_the_compact_form_equals_the_whole_form_and_the_reference(
+        small_share, rows, live, slots, monkeypatch):
+    """Under a share small enough (4 of 64) the held pairs of `rows` rows,
+    no multiple of `GMM_ROWS`, get `slots` places of rows x 2: the compact
+    form equals the whole form (forced by a factor that gives every pair a
+    place) and the reference, padding rows are exactly zero and a row with
+    no held pair is the shared expert alone, bit for bit."""
+    cfg, params = small_share
+    monkeypatch.setattr(L, "expert_form", lambda cfg: "sorted_gmm")
+    lp = {n: w[0] for n, w in params["blocks"][1].items()}
+    h = jax.random.normal(jax.random.PRNGKey(rows), (rows, 64), jnp.float32)
+    valid = jnp.arange(rows) < live
+    assert L.held_pair_slots(rows, cfg) == slots < rows * 2
+    y, load, text = sorted_ffn(cfg, lp, h, valid)
+    assert 0 < load.sum() <= slots
+    monkeypatch.setattr(L, "HELD_ROOM", rows * 2)
+    whole, load_whole, text_whole = sorted_ffn(cfg, lp, h, valid)
+    # the [T, C] weights that add C rows into T: in the one, not the other
+    combine = f"f32[{rows},{slots}]"
+    assert combine in text and combine not in text_whole
+    assert np.array_equal(load, load_whole)
+    assert np.abs(y - whole).max() < 1e-5
+    assert not np.any(y[live:])
+    picked = np.asarray(R.chosen_experts(h, lp, 2))[:live]
+    mine = (picked >= 20) & (picked < 24)
+    assert int(load.sum()) == mine.sum()
+    shared = np.asarray(L.ffn(h, {"w1": lp["ws1"], "w3": lp["ws3"],
+                                  "w2": lp["ws2"]}))
+    alone = ~mine.any(axis=-1)
+    assert 0 < alone.sum() < live
+    assert np.array_equal(y[:live][alone], shared[:live][alone])
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(R.sparse_ffn(h[:live], lp, top_k=2,
+                                      router_scale=cfg.router_scale,
+                                      held=(20, 4)))
+    assert np.abs(y[:live] - ref).max() < 1e-4
+
+
+@pytest.mark.parametrize("live, over", [(200, True), (65, True), (64, False)])
+def test_held_pairs_past_their_places_take_the_whole_form(
+        small_share, live, over, monkeypatch):
+    """A selection bias that sends every row to held experts: 2 x `live`
+    pairs against 128 places of 400. Past them the launch takes the whole
+    form and drops nothing; either way the output is the reference's."""
+    cfg, params = small_share
+    monkeypatch.setattr(L, "expert_form", lambda cfg: "sorted_gmm")
+    lp = {n: w[0] for n, w in to_the_held(params)["blocks"][1].items()}
+    h = jax.random.normal(jax.random.PRNGKey(live), (200, 64), jnp.float32)
+    y, load, _ = sorted_ffn(cfg, lp, h, jnp.arange(200) < live)
+    assert load.sum() == 2 * live
+    assert (load.sum() > L.held_pair_slots(200, cfg)) == over
+    assert not np.any(y[live:])
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(R.sparse_ffn(h[:live], lp, top_k=2,
+                                      router_scale=cfg.router_scale,
+                                      held=(20, 4)))
+    assert np.abs(y[:live] - ref).max() < 1e-4 * max(1, np.abs(ref).max())
+
+
+@pytest.mark.parametrize("rows, share, top_k, slots", [
+    (1024, (60, 12, 384), 8, 1024),     # the cell's chunk tick: 256 even
+    (64, (60, 12, 384), 8, 128),        # its decode tick: 16 even, one tile
+    (1024, (), 8, 8192),                # every expert held: every pair
+    (48, (4, 4, 16), 2, 96),            # the tiny fixture: never above all
+    (200, (20, 4, 64), 2, 128),
+    (1000, (0, 1, 384), 8, 128)])       # 21 even: 84 in one tile
+def test_the_places_follow_from_rows_top_k_and_the_share(rows, share, top_k,
+                                                         slots):
+    cfg = L.LlamaConfig(num_experts=share[2] if share else 64, top_k=top_k,
+                        experts_held=share[:2])
+    assert L.held_pair_slots(rows, cfg) == slots
+    if not share:
+        assert slots == rows * top_k
+
+
+@pytest.mark.parametrize("biased", [True, False])
+def test_the_engine_counts_the_launches_that_took_the_whole_form(
+        small_share, biased, monkeypatch, tmp_path):
+    """`moe_compact_overflow` against a hand count, in `engine.stats` and
+    on the step spans: chunks of 256 rows have 128 places a sparse layer
+    (of two), a decode tick of 4 rows holds all 8 pairs. With every row
+    sent to held experts the prompt of 150 has 300 held pairs and both its
+    sparse layers take the whole form, the prompt of 40 has 80 and none
+    does; with the seeded router none ever does. The tokens are the
+    reference's either way."""
+    cfg, params = small_share
+    if biased:
+        params = to_the_held(params)
+    monkeypatch.setattr(L, "expert_form", lambda cfg: "sorted_gmm")
+    eng = engine(cfg, params, token_budget=256)
+    eng._next_is_determined = lambda cur: False     # a tick a step
+    prompts = [prompt_of(150, seed=5), prompt_of(40, seed=6)]
+    done = []
+
+    def drive():
+        for p in prompts:
+            eng.submit(p, max_new_tokens=3)
+            done.extend(eng.run())
+
+    spans, _ = _profiled(str(tmp_path), drive)
+    steps = [s[3] for s in spans
+             if s[0] == "ptpu.serve.step" and "batch" in s[3]]
+    assert L.held_pair_slots(256, cfg) == 128 and L.held_pair_slots(4, cfg) == 8
+    want = [2 * biased, 0, 0, 0, 0, 0]      # a chunk tick, two decode ticks
+    assert [f["moe_compact_overflow"] for f in steps] == want
+    assert eng.stats["moe_compact_overflow"] == sum(want)
+    if biased:
+        assert [f["moe_pairs_held"] for f in steps] == [
+            2 * 2 * n for n in (150, 1, 1, 40, 1, 1)]
+    with jax.default_matmul_precision("highest"):
+        for d, p in zip(done, prompts):
+            assert d.output_tokens == R.generate(
+                params, p, 3, 192, **R.model_kw(SMALL))[0]
 
 
 # ---- the engine --------------------------------------------------------------
