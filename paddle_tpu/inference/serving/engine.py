@@ -78,19 +78,34 @@ quantisation, LoRA adapters, a draft model, the fused FFN and
 ``extract_pages`` / ``ingest_pages`` are refused.
 
 Latent attention (a plan whose layers are ``attn="latent"``: DeepSeek-V3's
-block, Kimi-K2's) is served over a LATENT page pool: one array
+block, Kimi-K2's, dots3-note's) is served over LATENT page pools: an array
 ``[L, num_blocks, 1, block_size, W]`` and no value pool. A position's row is
-``llama.latent_kv``'s (latent | rope key), 576 values at Kimi's widths, in W
-= 640 whole lanes (``paged_attention_latent`` says why); every launch, the
-decode tick's and the tick's with a prefill chunk, goes through
-``paged_latent_attention``, which states the one rule of which form a row
-attends in: every row in the ABSORBED form (64 query heads over one key
-row whose first 512 values are the value row), one-row sequences through
-the decode launch in either tick and chunks through the mixed walk; the
-expanded form for chunks was measured and is not taken. The pages are of
-one kind and one lifetime, so the prefix
-cache, copy-on-write and preemption work as for a uniform model; page
-hand-off is refused as for every plan. A config that holds a share of its
+``llama.latent_kv``'s (latent | rope key) at the widths of the layer's own
+spec (``LayerSpec.latent``), in whole lanes: 576 values in W = 640 at Kimi's
+widths (``paged_attention_latent`` says why). A plan's latent layers may be
+of two kinds (``_pool_plan``): those WITHOUT a window share the pool, one
+row width; those WITH one (``LatentSpec.window``) live in the window pool at
+their own row width (dots3-note: 1,088 values in 1,152 lanes) under the
+window pool's block table and lifetime, released behind the window as
+Laguna's are. Where the full kind has a learned sparse INDEX
+(``LatentSpec.index``), its pages carry a second row a position, the index
+key (``[L_full, num_blocks, 1, block_size, index_head_dim]``, riding where a
+value pool would: same page numbers, same block table, same lifetime), and
+the layer runs ``paged_index_select`` (index keys written, every visible
+key scored, the ``topk`` best selected exactly) before its read. Every
+launch, the decode tick's and the tick's with a prefill chunk, goes through
+``paged_latent_attention``, which states the one rule of which read a row
+takes (the dense walk; the sparse read over the selected rows for a
+sequence that holds more than ``topk`` keys; the windowed walk) and in
+which form (absorbed; the expanded form for chunks was measured and is not
+taken). Counters (``_plan_keys``): ``attn_keys_latent`` / ``attn_pairs_latent``
+of the dense walk, ``attn_*_latent_window`` of the windowed one,
+``index_keys`` / ``index_pairs`` scored, ``sparse_pairs_selected`` read,
+``sparse_rows_dense`` rows that had no selection to make,
+``index_pages_live``. Without a window layer the pages are of one kind and
+one lifetime, so the prefix cache, copy-on-write and preemption work as for
+a uniform model; with one the prefix cache is off, as for every window
+pool. Page hand-off is refused as for every plan. A config that holds a share of its
 routed experts (``cfg.experts_held``) routes over all of them and computes
 its own (``llama.routed_ffn_load``); ``moe_pairs_held`` counts those pairs,
 and ``moe_compact_overflow`` the launches that had more of them than
@@ -117,7 +132,8 @@ from ...core import flags
 from ...models import llama as L
 from ...observability import emit as _emit
 from ...observability import tracing as _tracing
-from ...ops.kernels.serving_attention import (paged_latent_attention,
+from ...ops.kernels.serving_attention import (paged_index_select,
+                                              paged_latent_attention,
                                               paged_layer_attention)
 from ...ops.pallas import flash_attention as FA
 from ...ops.pallas import fused_ffn as FF
@@ -204,6 +220,48 @@ class _Tick:
     # pages allocated in (the pool, the window pool) when it was launched
     pool_pages: Tuple[int, int] = (0, 0)
     sampled_rows: int = 0           # rows with a temperature: 0 skips the sort
+
+
+def _in_window_pool(spec: "L.LayerSpec") -> bool:
+    """Whether a layer's pages live in the window pool: a window layer of
+    heads' own keys and values, or a latent layer whose spec has a
+    window."""
+    return spec.attn == "window" or bool(spec.latent and spec.latent.window)
+
+
+def _pool_plan(cfg: "L.LlamaConfig"):
+    """((layers of the pool, of the window pool), (a row's width in each
+    pool), the window, the full layers' IndexSpec or None) of a config. A
+    row is a head (`head_dim`), or a latent layer's (latent | rope key) in
+    whole lanes. Each pool is one array, so its layers share one row width,
+    one window and one index geometry; a plan that asks for two of a kind
+    in one pool is refused."""
+    window = [s for s in cfg.layer_plan if _in_window_pool(s)]
+    full = [s for s in cfg.layer_plan if not _in_window_pool(s)]
+
+    def one(values, what):
+        values = set(values)
+        if len(values) > 1:
+            raise NotImplementedError(
+                f"layers of one page pool with {what} {sorted(values)}: a "
+                "pool is one array, and no model served has them")
+        return next(iter(values), 0)
+
+    def width(specs):
+        return one([PL.padded_width(s.latent.width) if s.latent
+                    else cfg.head_dim for s in specs] or [cfg.head_dim],
+                   "row widths")
+
+    index = {s.latent.index for s in full if s.latent} - {None}
+    if len({(i.head_dim, i.topk) for i in index}) > 1:
+        raise NotImplementedError(
+            f"index layers of two geometries {sorted(index)}: their keys "
+            "share the full layers' pages, one width a position")
+    return ((cfg.num_layers - len(window), len(window)),
+            (width(full), width(window)),
+            one([s.latent.window if s.latent else cfg.sliding_window
+                 for s in window], "windows"),
+            next(iter(index), None))
 
 
 def _sample_rows(logits, keys, temps, top_ps, top_k: int):
@@ -380,26 +438,34 @@ class PagedServingEngine:
         # dtype-aware page footprint (both cache sides, all layers, plus
         # the per-page f32 scale rows when quantized) — keeps the byte
         # gauges and the router's least-loaded placement truthful
-        kvh, hd = cfg.num_kv_heads, cfg.head_dim
+        kvh = cfg.num_kv_heads
         # a latent pool: one side, a row of (latent | rope key) in whole
-        # lanes for every head
-        self.latent = cfg.latent
-        sides = 2
-        if self.latent:
-            sides, hd = 1, PL.padded_width(cfg.latent_width)
+        # lanes for every head. A plan's latent layers may be of two
+        # kinds, the one with a window in the window pool at its own
+        # width; the full kind's pages carry its index keys as a second
+        # row a position (`_pool_plan`)
+        self.latent = any(s.attn == "latent" for s in cfg.layer_plan)
+        (self._pool_layers, self._row_widths, self.window,
+         self._index) = _pool_plan(cfg)
         # layers whose pages live in the pool and in the window pool (a
         # config without window layers: all of them, and none)
-        n_window = sum(s.attn == "window" for s in cfg.layer_plan)
-        self._pool_layers = (cfg.num_layers - n_window, n_window)
-        layer_bytes = (sides * kvh * self.block_size * hd
-                       * np.dtype(self.cache_dtype).itemsize)
-        self.kv_page_bytes = cfg.num_layers * layer_bytes
+        n_window = self._pool_layers[1]
+        item = np.dtype(self.cache_dtype).itemsize
+        sides = 1 if self.latent else 2
+        full_bytes, window_bytes = (
+            sides * kvh * self.block_size * w * item
+            for w in self._row_widths)
+        if self._index is not None:
+            full_bytes += self.block_size * self._index.head_dim * item
+        hd = self._row_widths[0]
+        self.kv_page_bytes = (self._pool_layers[0] * full_bytes
+                              + n_window * window_bytes)
         if self.quant_kv:
             self.kv_page_bytes += 2 * cfg.num_layers * kvh * 4
         if n_window:
             # a sequence's window pages: its window, the chunk in flight,
             # and the partly filled pages at both ends
-            span = -(-(cfg.sliding_window + self.token_budget)
+            span = -(-(self.window + self.token_budget)
                      // self.block_size) + 2
             if window_blocks is None:
                 window_blocks = (
@@ -414,11 +480,10 @@ class PagedServingEngine:
         self.window_blocks = int(window_blocks or 0)
         self.blocks = BlockManager(
             self.num_blocks, self.block_size,
-            page_bytes=self._pool_layers[0] * layer_bytes
+            page_bytes=self._pool_layers[0] * full_bytes
             if n_window else self.kv_page_bytes,
             hit_multiple=max(Bd, 1), window_blocks=self.window_blocks,
-            window=cfg.sliding_window if n_window else 0,
-            window_page_bytes=n_window * layer_bytes)
+            window=self.window, window_page_bytes=n_window * window_bytes)
         self.scheduler = Scheduler(self.blocks, self.token_budget,
                                    self.max_batch,
                                    prefill_chunk=prefill_chunk,
@@ -459,6 +524,19 @@ class PagedServingEngine:
             # tick is launched, summed over ticks
             self.stats.update(attn_keys_latent=0, attn_pairs_latent=0,
                               latent_pages_live=0)
+            if n_window:
+                # the same of the window layers, inside their windows
+                self.stats.update(attn_keys_latent_window=0,
+                                  attn_pairs_latent_window=0)
+            if self._index is not None:
+                # the sparse index, summed over ticks and index layers:
+                # index keys read and (row, key) pairs scored (the causal
+                # keys and pairs of the sequences that select), the pairs
+                # selected, the rows that had no selection to make and
+                # took the dense walk, and the pages that carry index keys
+                self.stats.update(index_keys=0, index_pairs=0,
+                                  sparse_pairs_selected=0,
+                                  sparse_rows_dense=0, index_pages_live=0)
         elif plan:
             # keys and (row, key) pairs inside the masks, summed over ticks
             # and over the layers of the kind, and the keys a causal mask
@@ -523,7 +601,7 @@ class PagedServingEngine:
         # the (query heads, window) of each attention launch a tick makes:
         # the config's own, or each that occurs in its plan
         self._launches = tuple(dict.fromkeys(
-            [(s.heads, cfg.sliding_window if s.attn == "window" else 0)
+            [(s.heads, self.window if _in_window_pool(s) else 0)
              for s in cfg.layer_plan] or [(cfg.num_heads, 0)]))
         # fused-FFN routing mirrors the attention tri-state: None =
         # FLAGS_pallas_ffn per tick; True = force (interpret off-TPU);
@@ -551,16 +629,23 @@ class PagedServingEngine:
         shape = (self._pool_layers[0], self.num_blocks, kvh, self.block_size,
                  hd)
         self._key_cache = jnp.zeros(shape, self.cache_dtype)
-        # a latent pool has no value side: the values are the rows' latents
-        self._value_cache = (None if self.latent
-                             else jnp.zeros(shape, self.cache_dtype))
+        # a latent pool has no value side: the values are the rows'
+        # latents. What rides in its place is the full layers' second row
+        # a position where they have one: the index keys, same pages
+        self._value_cache = (
+            jnp.zeros(shape, self.cache_dtype) if not self.latent
+            else None if self._index is None
+            else jnp.zeros(shape[:-1] + (self._index.head_dim,),
+                           self.cache_dtype))
         if n_window:
             # two pools ride the tick's carry: (full layers', window layers')
-            wshape = (n_window, self.window_blocks) + shape[2:]
+            wshape = ((n_window, self.window_blocks) + shape[2:-1]
+                      + (self._row_widths[1],))
             self._key_cache = (self._key_cache,
                                jnp.zeros(wshape, self.cache_dtype))
             self._value_cache = (self._value_cache,
-                                 jnp.zeros(wshape, self.cache_dtype))
+                                 None if self.latent
+                                 else jnp.zeros(wshape, self.cache_dtype))
         if self.quant_kv:
             # static calibrated absmax per (layer, kv head) -> per-head
             # quant multipliers [L, KV] for the append path and GENUINELY
@@ -591,10 +676,11 @@ class PagedServingEngine:
                               jnp.concatenate([sin, sin], -1)[None]])
         if plan:
             # one table a rope of the plan, as wide as what it rotates
+            widths = {s.rope: cfg.rope_width(s) for s in cfg.kinds[::-1]}
             self._ropes = tuple(dict.fromkeys(s.rope for s in cfg.kinds))
             self._rope_emb = tuple(
                 rope_emb(*L.rope_table(jnp.arange(self.max_len),
-                                       cfg.rope_dim, r))
+                                       widths[r], r))
                 for r in self._ropes)
         else:
             self._rope_emb = rope_emb(*L.rope_cos_sin(
@@ -1177,7 +1263,7 @@ class PagedServingEngine:
             layers = [i for i, kk in enumerate(kind_of) if kk == k]
             # the layer's place among the layers of its pool
             same = [i for i, s in enumerate(cfg.layer_plan) if not two
-                    or (s.attn == "window") == (spec.attn == "window")]
+                    or _in_window_pool(s) == _in_window_pool(spec)]
             stacks.append({
                 "lp": {n: v for n, v in leaves.items()
                        if not (sparse and n in expert_names)},
@@ -1194,39 +1280,61 @@ class PagedServingEngine:
             tok_pos = jnp.clip(past[tok_b] + tok - cu[tok_b], 0,
                                self.max_len - 1)
 
-        def latent_attention(spec, x, lp, pool, page_layer):
-            """x + the latent attention sub-block (scopes `latent_q` and
-            `latent_kv`, inside `qkv` here and around the absorption in the
-            op; `paged_attention_latent` inside `paged_attention`;
-            `latent_out` in the op and around Wo); the
-            pool comes back with the rows' own. Which form a row attends
-            in is `paged_latent_attention`'s to say."""
-            half = cfg.qk_rope_head_dim // 2
+        def latent_attention(spec, x, lp, pool, index_pool, table,
+                             page_layer):
+            """x + the latent attention sub-block at the spec's widths
+            (scopes `latent_q` and `latent_kv`, inside `qkv` here and
+            around the absorption in the op; `index_q`, `index_k` and the
+            index's own in `paged_index_select` for a layer with one;
+            `paged_attention_latent` | `_latent_window` | `_sparse` inside
+            `paged_attention`; `attn_gate`; `latent_out` in the op and
+            around Wo); the pools come back with the rows' own. Which
+            read a row takes and in which form is
+            `paged_latent_attention`'s to say."""
+            ls = spec.latent
+            half = ls.qk_rope_head_dim // 2
+            select = None
             with jax.named_scope("qkv"):
                 h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)[None]
-                table = rope_emb[self._ropes.index(spec.rope)]
-                cos, sin = (table[i, 0, tok_pos, :half] for i in (0, 1))
+                table_r = rope_emb[self._ropes.index(spec.rope)]
+                cos, sin = (table_r[i, 0, tok_pos, :half] for i in (0, 1))
                 with jax.named_scope("latent_q"):
+                    cq = L.latent_cq(h, lp, cfg, ls)
                     q_nope, q_rope = L.latent_q(h, lp, cfg, spec.heads, cos,
-                                                sin)
+                                                sin, ls, cq)
                 with jax.named_scope("latent_kv"):
-                    row = L.latent_kv(h, lp, cfg, cos, sin)[0]
+                    row = L.latent_kv(h, lp, cfg, cos, sin, ls)[0]
+                if ls.index is not None:
+                    qi, ki, w = L.index_qkw(h, cq, lp, cfg, ls, cos, sin)
+            if ls.index is not None:
+                *select, index_pool = paged_index_select(
+                    qi[0], w[0], ki[0], index_pool, page_layer, past, this,
+                    cu, table, ls.index.topk, use_pallas)
             o, pool = paged_latent_attention(
                 q_nope[0], q_rope[0], row,
-                *L.latent_wkvb(lp, cfg, spec.heads, x.dtype), pool,
-                page_layer, past, this, cu, tables[0], cfg.score_scale,
-                use_pallas)
+                *L.latent_wkvb(lp, cfg, spec.heads, x.dtype, ls), pool,
+                page_layer, past, this, cu, table, ls.score_scale,
+                use_pallas, window=ls.window,
+                select=tuple(select) if select else None)
+            if cfg.attn_gate:
+                o = L.attn_gated(o.reshape(o.shape[0], spec.heads, -1),
+                                 h[0], lp).reshape(o.shape)
             with jax.named_scope("attn_out"), jax.named_scope("latent_out"):
-                return x + Q.matmul_param(o, lp, "wo"), pool
+                return x + Q.matmul_param(o, lp, "wo"), pool, index_pool
+
+        def put(pools, at, new):
+            return pools[:at] + (new,) + pools[at + 1:]
 
         def body(kind, carry, leaves):
             spec = kinds[kind]
             x, pk, pv, hit, top, *held = carry
             lp = leaves["lp"]
             if spec.attn == "latent":
-                x, kc = latent_attention(spec, x, lp, pk[0],
-                                         leaves["page_layer"])
-                pk = (kc,)
+                pool = int(two and _in_window_pool(spec))
+                x, kc, ic = latent_attention(
+                    spec, x, lp, pk[pool], pv[pool], tables[pool],
+                    leaves["page_layer"])
+                pk, pv = put(pk, pool, kc), put(pv, pool, ic)
             else:
                 pool = int(two and spec.attn == "window")
                 rot = int(cfg.head_dim * spec.rope.partial)
@@ -1818,6 +1926,8 @@ class PagedServingEngine:
                 keys = self._plan_keys(dec_lens, this_lens)
                 if self.latent:
                     keys["latent_pages_live"] = cur.pool_pages[0]
+                if self._index is not None:
+                    keys["index_pages_live"] = cur.pool_pages[0]
                 if self.window_blocks:
                     keys.update(
                         full_pages_live=cur.pool_pages[0],
@@ -1894,21 +2004,45 @@ class PagedServingEngine:
         a window layer."""
         cfg = self.cfg
         n_full, n_window = self._pool_layers
+        out: Dict[str, int] = {}
         live = this > 0
         past, this = past[live].astype(np.int64), this[live].astype(np.int64)
-        keys = int((past + this).sum())
-        pairs = int((this * past + this * (this + 1) // 2).sum())
-        if self.latent:
-            return {"attn_keys_latent": n_full * keys,
-                    "attn_pairs_latent": n_full * pairs}
+        each_keys = past + this
+        each_pairs = this * past + this * (this + 1) // 2
+        keys, pairs = int(each_keys.sum()), int(each_pairs.sum())
+        if self.latent and self._index is not None:
+            # the full layers' rows divide by the rule of
+            # `paged_latent_attention`: a sequence that holds more than
+            # `topk` keys after this tick selects (its rows score every
+            # key they see and attend over min(p + 1, topk) of them), the
+            # others walk densely
+            k = self._index.topk
+            sel = each_keys > k
+            under = np.clip(k - past, 0, this)   # rows that see <= k keys
+            chosen = (under * past + under * (under + 1) // 2
+                      + (this - under) * k)
+            out = {"attn_keys_latent": n_full * int(each_keys[~sel].sum()),
+                   "attn_pairs_latent": n_full * int(each_pairs[~sel].sum()),
+                   "index_keys": n_full * int(each_keys[sel].sum()),
+                   "index_pairs": n_full * int(each_pairs[sel].sum()),
+                   "sparse_pairs_selected": n_full * int(chosen[sel].sum()),
+                   "sparse_rows_dense": n_full * int(this[~sel].sum())}
+        elif self.latent:
+            out = {"attn_keys_latent": n_full * keys,
+                   "attn_pairs_latent": n_full * pairs}
         wkeys = wpairs = 0
         if n_window:
-            W = cfg.sliding_window
+            W = self.window
             wkeys = keys - int(np.maximum(past - (W - 1), 0).sum())
             # the first `under` rows of a chunk see fewer than W keys
             under = np.clip(W - 1 - past, 0, this)
             wpairs = int((under * (past + 1) + under * (under - 1) // 2
                           + (this - under) * W).sum())
+        if self.latent:
+            if n_window:
+                out.update(attn_keys_latent_window=n_window * wkeys,
+                           attn_pairs_latent_window=n_window * wpairs)
+            return out
         return {"attn_keys_full": n_full * keys,
                 "attn_keys_window": n_window * wkeys,
                 "attn_keys_causal": cfg.num_layers * keys,

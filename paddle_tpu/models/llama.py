@@ -39,7 +39,13 @@ bottleneck with its own norm, keys and values through one latent a
 position with its own norm beside one rope key that all heads share
 (`latent_q`, `latent_kv`, `latent_wkvb`; `latent_self_attention` is the
 expanded form that `forward` runs, the paged engine attends over the
-cached latents themselves). And a config may hold ONE CHIP'S SHARE of its
+cached latents themselves). A latent layer's widths are its spec's
+(`LayerSpec.latent`, a `LatentSpec`), so one model may have latent layers
+of two kinds, as dots3-note has: a kind with a causal WINDOW over its
+latent cache, and a kind with a learned sparse INDEX (`IndexSpec`,
+DeepSeek-V3.2's lightning indexer: `index_qkw` scores every visible key
+with a few narrow heads, `select_topk` keeps the `topk` best exactly, and
+the layer attends over those alone). And a config may hold ONE CHIP'S SHARE of its
 routed experts (`experts_held` = (first, count)): `route` runs over all
 `num_experts`, with a selection bias where the model has one
 (`router_bias`), and `routed_ffn_load` computes the pairs of the held
@@ -63,6 +69,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.kernels.sparse_index import index_scores, select_topk
+
 
 @dataclasses.dataclass(frozen=True)
 class RopeSpec:
@@ -81,19 +89,72 @@ class RopeSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class IndexSpec:
+    """A latent layer's learned sparse index (DeepSeek-V3.2's lightning
+    indexer): `heads` index heads of `head_dim` from the layer's query
+    latent (`wiq`), one index key of `head_dim` a position from the layer's
+    normed input through a LayerNorm with a bias (`wik`, `ik_norm`,
+    `ik_bias`), a weight a head (`wiw`), the layer's rope on the leading
+    `qk_rope_head_dim` values of each. A query attends over the `topk`
+    visible keys of largest score alone (`select_topk`)."""
+    heads: int
+    head_dim: int
+    topk: int
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """The widths of one kind of latent layer (DeepSeek-V3's block): the
+    queries through a `q_lora_rank` bottleneck with its own norm to heads
+    of `qk_nope_head_dim` + `qk_rope_head_dim`, keys and values through a
+    `kv_lora_rank` latent with its own norm beside ONE rope key of
+    `qk_rope_head_dim` that all heads share; the latent expands to a
+    head's `qk_nope_head_dim` key and `v_head_dim` value. A cache holds
+    (latent | rope key) a position, `width` values. `softmax_scale` is the
+    factor on the scores (0: head_dim ** -0.5; a YaRN model folds its
+    mscale ** 2 into it). `q_scale` and `kv_scale` are fixed factors on
+    the two normed latents (LongCat-Flash's `mla_scale_q_lora` /
+    `mla_scale_kv_lora`; 1: none). `window` W > 0: a query sees the last W
+    keys, its own among them. `index`: the layer's sparse index."""
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    softmax_scale: float = 0.0
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
+    window: int = 0
+    index: Optional[IndexSpec] = None
+
+    @property
+    def width(self) -> int:
+        """Values a position's cache row holds: latent | rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def score_scale(self) -> float:
+        return self.softmax_scale or float(
+            self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """What one layer of a layer plan is: `attn` "full" (causal),
     "window" (causal over the last `LlamaConfig.sliding_window` keys, the
     query's own among them) or "latent" (causal multi-head latent
-    attention, `latent_*` below: the config's `kv_lora_rank` and its
-    sibling widths), its query heads, its rope, and `ffn` "dense"
-    (SwiGLU of `dense_intermediate_size`) or "sparse" (the routed experts
-    of `intermediate_size`, with the shared expert where the config has
+    attention at the widths of `latent`, which also says whether the layer
+    has a window or a sparse index; a latent spec without one takes the
+    config's `q_lora_rank` and its sibling fields, `__post_init__` writes
+    them here), its query heads, its rope, and `ffn` "dense" (SwiGLU of
+    `dense_intermediate_size`) or "sparse" (the routed experts of
+    `intermediate_size`, with the shared expert where the config has
     one). Layers with equal specs are one kind."""
     attn: str = "full"
     heads: int = 0
     rope: RopeSpec = RopeSpec()
     ffn: str = "dense"
+    latent: Optional[LatentSpec] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,15 +220,10 @@ class LlamaConfig:
     # a sigmoid gate on each head's attention output, from the layer's
     # normed input through `wg` [d, heads]
     attn_gate: bool = False
-    # multi-head latent attention (DeepSeek-V3's block, Kimi-K2's): a
-    # "latent" layer projects queries through a `q_lora_rank` bottleneck
-    # with its own norm to heads of `qk_nope_head_dim` + `qk_rope_head_dim`,
-    # and keys and values through a `kv_lora_rank` latent with its own norm
-    # beside ONE rope key of `qk_rope_head_dim` that all heads share; the
-    # latent expands to a head's `qk_nope_head_dim` key and `v_head_dim`
-    # value. A cache holds (latent | rope key) a position. `softmax_scale`
-    # is the factor on the scores (0: head_dim ** -0.5; a YaRN model folds
-    # its mscale ** 2 into it)
+    # the widths of a plan's latent layers whose spec states none
+    # (`LayerSpec.latent`): a model with one kind of latent layer may give
+    # them here, and `__post_init__` writes them into its plan as a
+    # `LatentSpec`, which is what every function reads
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -198,18 +254,16 @@ class LlamaConfig:
                 raise ValueError(
                     f"layer_plan has {len(self.layer_plan)} layers, "
                     f"num_layers is {self.num_layers}")
+            object.__setattr__(self, "layer_plan", tuple(
+                self._with_widths(s) for s in self.layer_plan))
             for spec in self.layer_plan:
                 if (spec.attn not in ("full", "window", "latent")
                         or spec.ffn not in ("dense", "sparse")
                         or spec.heads % self.num_kv_heads):
                     raise ValueError(f"layer_plan: bad layer {spec}")
-                if spec.attn == "latent" and not (
-                        self.kv_lora_rank and self.q_lora_rank
-                        and self.qk_nope_head_dim and self.qk_rope_head_dim
-                        and self.v_head_dim):
-                    raise ValueError(
-                        "a latent layer needs q_lora_rank, kv_lora_rank, "
-                        "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+                if spec.attn != "latent" and spec.latent is not None:
+                    raise ValueError(f"layer_plan: {spec.attn} layer with "
+                                     "latent widths")
                 if spec.attn == "window" and self.sliding_window < 1:
                     raise ValueError("a window layer needs sliding_window")
                 if spec.ffn == "sparse" and not self.num_experts:
@@ -228,12 +282,27 @@ class LlamaConfig:
                     f"experts_held={self.experts_held}: (first, count) "
                     f"inside num_experts={self.num_experts}")
         latent = {s.attn == "latent" for s in self.layer_plan}
-        if True in latent and (False in latent or self.attn_gate
-                               or self.num_kv_heads != 1):
+        if True in latent and (False in latent or self.num_kv_heads != 1):
             raise NotImplementedError(
-                "latent layers beside other attention, under an attention "
-                "gate, or with num_kv_heads != 1 (the latent is one key "
-                "row for every head): no model served has them")
+                "latent layers beside heads' own keys and values, or with "
+                "num_kv_heads != 1 (the latent is one key row for every "
+                "head): no model served has them")
+
+    def _with_widths(self, spec: LayerSpec) -> LayerSpec:
+        """A latent spec that states no widths takes the config's."""
+        if spec.attn != "latent" or spec.latent is not None:
+            return spec
+        if not (self.kv_lora_rank and self.q_lora_rank
+                and self.qk_nope_head_dim and self.qk_rope_head_dim
+                and self.v_head_dim):
+            raise ValueError(
+                "a latent layer needs its widths: LayerSpec.latent (a "
+                "LatentSpec), or the config's q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim for a "
+                "plan with one kind of them")
+        return dataclasses.replace(spec, latent=LatentSpec(
+            self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+            self.qk_rope_head_dim, self.v_head_dim, self.softmax_scale))
 
     # -- the held share of the routed experts ------------------------------
     @property
@@ -241,27 +310,36 @@ class LlamaConfig:
         """(first, count) of the routed experts this config holds."""
         return tuple(self.experts_held) or (0, self.num_experts)
 
-    @property
-    def latent(self) -> bool:
-        """Every layer's attention is latent (`__post_init__` allows no
-        mix)."""
-        return bool(self.layer_plan) and self.layer_plan[0].attn == "latent"
-
-    @property
-    def rope_dim(self) -> int:
+    def rope_width(self, spec: Optional[LayerSpec] = None) -> int:
         """The width a layer's rope table is made for (of which a
         `RopeSpec.partial` share is rotated): a head, or a latent layer's
         rope slice."""
-        return self.qk_rope_head_dim if self.latent else self.head_dim
+        if spec is not None and spec.latent is not None:
+            return spec.latent.qk_rope_head_dim
+        return self.head_dim
 
+    def one_latent(self) -> LatentSpec:
+        """The widths of a plan with ONE kind of latent layer; a plan with
+        two has no answer, and a caller reads a layer's own spec."""
+        kinds = {s.latent for s in self.layer_plan if s.latent is not None}
+        if len(kinds) != 1:
+            raise ValueError(
+                f"the plan has {len(kinds)} kinds of latent layer: read "
+                "LayerSpec.latent")
+        return next(iter(kinds))
+
+    # what the accepted benchmark's driver of a one-kind latent model
+    # still asks of the whole config (benchmark/drivers/
+    # closed_loop_serve_latent.py, benchmark/tests/test_latent.py); the
+    # program itself reads a layer's spec
     @property
-    def latent_width(self) -> int:
-        """Values a position's cache row holds: latent | rope key."""
-        return self.kv_lora_rank + self.qk_rope_head_dim
+    def rope_dim(self) -> int:
+        return self.rope_width(self.layer_plan[0] if self.layer_plan
+                               else None)
 
     @property
     def score_scale(self) -> float:
-        return self.softmax_scale or float(self.head_dim) ** -0.5
+        return self.one_latent().score_scale
 
     # -- the layer plan ---------------------------------------------------
     @property
@@ -275,19 +353,24 @@ class LlamaConfig:
         return tuple(kinds.index(s) for s in self.layer_plan)
 
     def _layer_params(self, heads: int, ffn: str,
-                      kind: str = "full") -> Tuple[int, int]:
+                      ls: Optional[LatentSpec] = None) -> Tuple[int, int]:
         """(all, active a token) matmul and norm parameters of one layer.
         The routed experts count whole (`num_experts`), whatever share of
         them a config holds: this is the model's size, not a chip's."""
         d, hd = self.hidden_size, self.head_dim
         norms = 2 * d
-        if kind == "latent":
-            r, c = self.q_lora_rank, self.kv_lora_rank
-            nope, rope, v = (self.qk_nope_head_dim, self.qk_rope_head_dim,
-                             self.v_head_dim)
+        if ls is not None:
+            r, c = ls.q_lora_rank, ls.kv_lora_rank
+            nope, rope, v = (ls.qk_nope_head_dim, ls.qk_rope_head_dim,
+                             ls.v_head_dim)
             attn = (d * r + r * heads * (nope + rope) + d * (c + rope)
                     + c * heads * (nope + v) + heads * v * d)
             norms += r + c
+            if ls.index is not None:
+                ix = ls.index
+                attn += (r * ix.heads * ix.head_dim + d * ix.head_dim
+                         + d * ix.heads)
+                norms += 2 * ix.head_dim
         else:
             attn = 2 * d * heads * hd + 2 * d * self.num_kv_heads * hd
         if self.attn_gate:
@@ -310,7 +393,7 @@ class LlamaConfig:
 
     def _layers(self):
         if self.layer_plan:
-            return [(s.heads, s.ffn, s.attn) for s in self.layer_plan]
+            return [(s.heads, s.ffn, s.latent) for s in self.layer_plan]
         return [(self.num_heads, "sparse" if self.num_experts else "dense")
                 ] * self.num_layers
 
@@ -367,24 +450,24 @@ def _normal(key: jax.Array, shape, dtype, scale: float = 0.02):
 
 
 def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
-                 ffn_kind: str, attn_kind: str = "full"
+                 ffn_kind: str, ls: Optional[LatentSpec] = None
                  ) -> Dict[str, jax.Array]:
-    """One stack of `L` layers with `nh` query heads, an attention of
-    `attn_kind` and an FFN of `ffn_kind` ("dense" | "sparse"); the keys
-    are split as they always were, so a uniform config draws the weights
-    it drew. A config that holds a share of its experts
-    (`experts_held`) draws the whole router and the held experts'
-    matrices alone."""
+    """One stack of `L` layers with `nh` query heads, latent attention at
+    the widths `ls` (None: heads' own keys and values) and an FFN of
+    `ffn_kind` ("dense" | "sparse"); the keys are split as they always
+    were, so a uniform config draws the weights it drew. A config that
+    holds a share of its experts (`experts_held`) draws the whole router
+    and the held experts' matrices alone."""
     d, f = cfg.hidden_size, cfg.intermediate_size
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
     pt = cfg.param_dtype
     keys = jax.random.split(key, 10)
     normal = functools.partial(_normal, dtype=pt)
 
-    if attn_kind == "latent":
-        r, c = cfg.q_lora_rank, cfg.kv_lora_rank
-        nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                          cfg.v_head_dim)
+    if ls is not None:
+        r, c = ls.q_lora_rank, ls.kv_lora_rank
+        nope, rope, vd = (ls.qk_nope_head_dim, ls.qk_rope_head_dim,
+                          ls.v_head_dim)
         ka = jax.random.split(keys[0], 2)
         kb = jax.random.split(keys[1], 2)
         blocks = {
@@ -398,6 +481,15 @@ def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
             "attn_norm": jnp.ones((L, d), pt),
             "mlp_norm": jnp.ones((L, d), pt),
         }
+        if ls.index is not None:
+            ix = ls.index
+            ki = jax.random.split(keys[2], 3)
+            blocks.update(
+                wiq=normal(ki[0], (L, r, ix.heads * ix.head_dim)),
+                wik=normal(ki[1], (L, d, ix.head_dim)),
+                wiw=normal(ki[2], (L, d, ix.heads)),
+                ik_norm=jnp.ones((L, ix.head_dim), pt),
+                ik_bias=jnp.zeros((L, ix.head_dim), pt))
     else:
         blocks = {
             "wq": normal(keys[0], (L, d, nh * hd)),
@@ -456,7 +548,7 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         kind_of = cfg.kind_of_layer
         blocks = tuple(
             _init_blocks(cfg, jax.random.fold_in(key, 1 + k),
-                         kind_of.count(k), spec.heads, spec.ffn, spec.attn)
+                         kind_of.count(k), spec.heads, spec.ffn, spec.latent)
             for k, spec in enumerate(cfg.kinds))
     else:
         blocks = _init_blocks(cfg, key, cfg.num_layers, cfg.num_heads,
@@ -890,7 +982,7 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     nh = spec.heads if spec else cfg.num_heads
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     if spec and spec.attn == "latent":
-        x = x + latent_self_attention(h, lp, cfg, nh, cos, sin)
+        x = x + latent_self_attention(h, lp, cfg, nh, cos, sin, spec.latent)
     else:
         q, k = qk_normed(h @ lp["wq"].astype(h.dtype),
                          h @ lp["wk"].astype(h.dtype), lp, cfg)
@@ -922,65 +1014,127 @@ def attn_gated(o: jax.Array, h: jax.Array, lp: Dict[str, jax.Array]):
         return (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
 
 
-def latent_q(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
-             heads: int, cos: jax.Array, sin: jax.Array):
-    """The queries of a latent layer from its normed input h [B, T, d]:
-    c_q = RMSNorm(h Wqa), q = c_q Wqb, a head's (q_nope | q_rope) with rope
-    on the `qk_rope_head_dim` slice (cos, sin [T, rope / 2]). Returns
-    (q_nope [B, T, H, nope], q_rope [B, T, H, rope])."""
-    nope = cfg.qk_nope_head_dim
+def latent_cq(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+              ls: Optional[LatentSpec] = None) -> jax.Array:
+    """A latent layer's query latent from its normed input h [B, T, d]:
+    c_q = q_scale * RMSNorm(h Wqa) [B, T, q_lora_rank]: what the queries
+    and, where the layer has one, the index queries are made of."""
+    ls = ls or cfg.one_latent()
     cq = rms_norm(h @ lp["wqa"].astype(h.dtype), lp["qa_norm"], cfg.rms_eps)
+    return cq if ls.q_scale == 1.0 else (
+        cq.astype(jnp.float32) * ls.q_scale).astype(cq.dtype)
+
+
+def latent_q(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+             heads: int, cos: jax.Array, sin: jax.Array,
+             ls: Optional[LatentSpec] = None, cq: Optional[jax.Array] = None):
+    """The queries of a latent layer from its normed input h [B, T, d]:
+    q = c_q Wqb (`latent_cq`, or `cq` where the caller has it), a head's
+    (q_nope | q_rope) with rope on the `qk_rope_head_dim` slice (cos, sin
+    [T, rope / 2]). Returns (q_nope [B, T, H, nope], q_rope [B, T, H,
+    rope]). `ls`: the layer's widths (None: the plan's one kind)."""
+    ls = ls or cfg.one_latent()
+    nope = ls.qk_nope_head_dim
+    cq = latent_cq(h, lp, cfg, ls) if cq is None else cq
     q = (cq @ lp["wqb"].astype(h.dtype)).reshape(*h.shape[:2], heads, -1)
     return q[..., :nope], apply_rope(q[..., nope:], cos, sin)
 
 
 def latent_kv(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
-              cos: jax.Array, sin: jax.Array) -> jax.Array:
+              cos: jax.Array, sin: jax.Array,
+              ls: Optional[LatentSpec] = None) -> jax.Array:
     """What a latent layer's cache holds of each position, from the
     layer's normed input h [B, T, d]: (c | k_rope) [B, T, kv_lora_rank +
-    rope], c = RMSNorm(the latent part of h Wkva), k_rope the rest under
-    rope: one key vector for every head."""
-    C = cfg.kv_lora_rank
+    rope], c = kv_scale * RMSNorm(the latent part of h Wkva), k_rope the
+    rest under rope: one key vector for every head."""
+    ls = ls or cfg.one_latent()
+    C = ls.kv_lora_rank
     ckr = h @ lp["wkva"].astype(h.dtype)
     c = rms_norm(ckr[..., :C], lp["kva_norm"], cfg.rms_eps)
+    if ls.kv_scale != 1.0:
+        c = (c.astype(jnp.float32) * ls.kv_scale).astype(c.dtype)
     k_r = apply_rope(ckr[..., None, C:], cos, sin)[..., 0, :]
     return jnp.concatenate([c, k_r], axis=-1)
 
 
 def latent_wkvb(lp: Dict[str, jax.Array], cfg: LlamaConfig, heads: int,
-                dtype):
+                dtype, ls: Optional[LatentSpec] = None):
     """Wkvb as (keys [C, H, nope], values [C, H, v]): what rebuilds a
     head's key and value from a latent (the expanded form), and what the
     absorbed form folds into its queries and applies to its outputs
     (`ops.kernels.serving_attention.paged_latent_attention`)."""
-    w = lp["wkvb"].astype(dtype).reshape(cfg.kv_lora_rank, heads, -1)
-    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+    ls = ls or cfg.one_latent()
+    w = lp["wkvb"].astype(dtype).reshape(ls.kv_lora_rank, heads, -1)
+    return w[..., :ls.qk_nope_head_dim], w[..., ls.qk_nope_head_dim:]
+
+
+def index_qkw(h: jax.Array, cq: jax.Array, lp: Dict[str, jax.Array],
+              cfg: LlamaConfig, ls: LatentSpec, cos: jax.Array,
+              sin: jax.Array):
+    """The sparse index's three parts from a latent layer's normed input h
+    [B, T, d] and its query latent cq (`latent_cq`): index queries qI
+    [B, T, IH, ID] = cq Wiq, ONE index key a position kI [B, T, ID] =
+    LayerNorm(h Wik) (weight and bias), both with the layer's rope on
+    their leading `qk_rope_head_dim` values, and the heads' weights w
+    [B, T, IH] float32 = (h Wiw) * IH ** -0.5 * ID ** -0.5."""
+    ix = ls.index
+    with jax.named_scope("index_q"):
+        qi = apply_rope((cq @ lp["wiq"].astype(h.dtype)).reshape(
+            *h.shape[:2], ix.heads, ix.head_dim), cos, sin)
+        w = ((h @ lp["wiw"].astype(h.dtype)).astype(jnp.float32)
+             * (ix.heads ** -0.5 * ix.head_dim ** -0.5))
+    with jax.named_scope("index_k"):
+        k = (h @ lp["wik"].astype(h.dtype)).astype(jnp.float32)
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = k * lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True)
+                          + cfg.rms_eps)
+        k = (k * lp["ik_norm"].astype(jnp.float32)
+             + lp["ik_bias"].astype(jnp.float32)).astype(h.dtype)
+        k = apply_rope(k[..., None, :], cos, sin)[..., 0, :]
+    return qi, k, w
 
 
 def latent_self_attention(h: jax.Array, lp: Dict[str, jax.Array],
                           cfg: LlamaConfig, heads: int, cos: jax.Array,
-                          sin: jax.Array) -> jax.Array:
+                          sin: jax.Array,
+                          ls: Optional[LatentSpec] = None) -> jax.Array:
     """Causal latent attention of whole sequences h [B, T, d] in the
     EXPANDED form (no cache): every position's latent through Wkvb to a
-    head's (k_nope | v), k_h = (k_nope_h | k_rope), scores times
-    `cfg.score_scale`, softmax in float32; the heads' outputs through Wo.
-    The paged engine computes the same numbers over its latent pages,
-    decode rows in the absorbed form."""
+    head's (k_nope | v), k_h = (k_nope_h | k_rope), scores times the
+    spec's `score_scale`, softmax in float32; under `ls.window` over the
+    last W keys, under `ls.index` over the selected keys alone
+    (`index_qkw`, `index_scores`, `select_topk`); with `cfg.attn_gate` a
+    head's output times its gate; the heads' outputs through Wo. The paged
+    engine computes the same numbers over its latent pages, in the
+    absorbed form. `ls`: the layer's widths (None: the plan's one kind)."""
+    ls = ls or cfg.one_latent()
     B, T, _ = h.shape
-    C = cfg.kv_lora_rank
-    q = jnp.concatenate(latent_q(h, lp, cfg, heads, cos, sin), axis=-1)
-    row = latent_kv(h, lp, cfg, cos, sin)
-    wk, wv = latent_wkvb(lp, cfg, heads, h.dtype)
+    C = ls.kv_lora_rank
+    cq = latent_cq(h, lp, cfg, ls)
+    q = jnp.concatenate(latent_q(h, lp, cfg, heads, cos, sin, ls, cq),
+                        axis=-1)
+    row = latent_kv(h, lp, cfg, cos, sin, ls)
+    wk, wv = latent_wkvb(lp, cfg, heads, h.dtype, ls)
     k = jnp.concatenate(
         [jnp.einsum("btc,chn->bthn", row[..., :C], wk),
          jnp.broadcast_to(row[:, :, None, C:],
-                          (B, T, heads, cfg.qk_rope_head_dim))], axis=-1)
+                          (B, T, heads, ls.qk_rope_head_dim))], axis=-1)
     v = jnp.einsum("btc,chv->bthv", row[..., :C], wv)
     s = (jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32)
-         * cfg.score_scale)
-    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, None], s, -1e30)
+         * ls.score_scale)
+    pos = jnp.arange(T)
+    mask = jnp.broadcast_to(pos[None, :] <= pos[:, None], (B, T, T))
+    if ls.window:
+        mask = mask & (pos[None, :] > pos[:, None] - ls.window)
+    if ls.index is not None:
+        qi, ki, w = index_qkw(h, cq, lp, cfg, ls, cos, sin)
+        mask = jax.vmap(lambda a, b, c, m: select_topk(
+            index_scores(a, b, c), m, ls.index.topk))(qi, ki, w, mask)
+    s = jnp.where(mask[:, None], s, -1e30)
     o = jnp.einsum("bhts,bshv->bthv", jax.nn.softmax(s, axis=-1
                                                      ).astype(h.dtype), v)
+    if cfg.attn_gate:
+        o = attn_gated(o, h, lp)
     return o.reshape(B, T, -1) @ lp["wo"].astype(o.dtype)
 
 
@@ -1069,7 +1223,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
     T = tokens.shape[1]
     if cfg.layer_plan:
         kinds = cfg.kinds
-        ropes = {spec.rope: rope_table(jnp.arange(T), cfg.rope_dim,
+        ropes = {spec.rope: rope_table(jnp.arange(T), cfg.rope_width(spec),
                                        spec.rope) for spec in kinds}
 
         def plan_body(kind, carry, lp):

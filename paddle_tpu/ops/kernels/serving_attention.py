@@ -742,9 +742,146 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
         return fmha_out, qkv3.reshape(token_num, -1), key_pool, value_pool
 
 
+def _packed_rows(past, this, cu, token_num: int, B: int):
+    """A packed stream's rows: (their sequence [tok], their absolute
+    position [tok], whether they exist [tok])."""
+    tok_idx = jnp.arange(token_num, dtype=jnp.int32)
+    tok_b = jnp.clip(jnp.searchsorted(cu, tok_idx, side="right") - 1, 0, B - 1)
+    tok_local = tok_idx - cu[tok_b]
+    return tok_b, past[tok_b] + tok_local, tok_local < this[tok_b]
+
+
+def _write_latent_rows(pool, layer, rows, past, this, cu, block_tables,
+                       tok_b, tok_pos, tok_valid, use_pallas):
+    """The new tokens' rows [tok, 1, W] into `layer`'s pages of a one-side
+    pool [L, nb, 1, bs, W] where they lie: a page at a time through the
+    kernel beside the Pallas read, an XLA row scatter on the stock path."""
+    from ..pallas import paged_attention_latent as PL
+    _, num_blocks, _, bs, _ = pool.shape
+    token_num = rows.shape[0]
+    if use_pallas:
+        pages, lo, hi, src = page_plan(past, this, cu, block_tables,
+                                       num_blocks, bs, token_num)
+        return PL.write_latent_pages(
+            pool, layer, pages, lo, hi,
+            rows[src].transpose(0, 2, 1, 3))                   # [n, 1, bs, W]
+    tok_page = jnp.take_along_axis(
+        block_tables[tok_b], (tok_pos // bs)[:, None], axis=1)[:, 0]
+    tok_page = jnp.where(tok_valid & (tok_page >= 0), tok_page,
+                         num_blocks + jnp.arange(token_num, dtype=jnp.int32))
+    return write_rows(pool, layer, tok_page, tok_pos % bs, rows)
+
+
+# rows of a block of the sparse read: a block gathers RB x topk cache rows
+# (671 MB at 256 x 2,048 x 640 lanes)
+_SPARSE_ROWS = 256
+
+
+def _by_row_blocks(fn, args, rows: int):
+    """`fn` over blocks of `_SPARSE_ROWS` leading rows of `args` (a tuple
+    of arrays with `rows` leading rows), concatenated: what a block holds
+    at once is bounded whatever the tick's rows."""
+    if rows <= _SPARSE_ROWS:
+        return fn(*args)
+    pad = -rows % _SPARSE_ROWS
+    blocks = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)
+                               ).reshape(-1, _SPARSE_ROWS, *a.shape[1:])
+    out = lax.map(lambda xs: fn(*xs), tuple(blocks(a) for a in args))
+    return out.reshape(-1, *out.shape[2:])[:rows]
+
+
+def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
+                       seq_lens_this_time, cu_seqlens_q, block_tables,
+                       topk: int, use_pallas=False):
+    """A latent layer's sparse index on the stacked INDEX-KEY pool
+    [L, num_blocks, 1, block_size, ID] (the second row a position of the
+    full layers' pages, under their block table): write the new tokens'
+    index keys `ki_tok` [tok, ID] into `layer`'s pages, then, for the rows
+    of every sequence that holds more than `topk` keys after this tick,
+    score every key the row sees (qi [tok, IH, ID] index queries, w [tok,
+    IH] float32 head weights; `sparse_index.index_scores`) and select the
+    `topk` best EXACTLY (`sparse_index.select_topk`: no approximate top-k,
+    no scores in fewer bits). A sequence with at most `topk` keys has no
+    selection to make and its rows take the dense walk
+    (`paged_latent_attention` says the whole rule).
+
+    `use_pallas` as `paged_latent_attention`'s: "decode" and the one-row
+    sequences of a tick with a chunk score a sequence's keys in one
+    batched product; a chunk's rows go through the index walk
+    (`paged_attention_latent.index_scores_packed`), which never holds
+    [rows, heads, keys]; False scores each row against its own gathered
+    keys (CPU tests). Scopes: `cache_write`, `index_scores`,
+    `index_select`. Returns (positions [tok, topk] int32 ascending, -1
+    behind a row's last and everywhere in a row that takes the dense walk;
+    the page of each selected key by its row's block table [tok, topk];
+    sparse [B] bool: the sequences whose rows were selected for; pool).
+    `block_size` divides 128."""
+    from ..pallas import paged_attention_latent as PL
+    from . import sparse_index
+    L_, num_blocks, _, bs, ID = pool.shape
+    B, max_blocks = block_tables.shape
+    token_num = qi.shape[0]
+    max_kv = max_blocks * bs
+    cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
+    past = seq_lens_decoder.reshape(-1).astype(jnp.int32)
+    this = seq_lens_this_time.reshape(-1).astype(jnp.int32)
+    tok_b, tok_pos, tok_valid = _packed_rows(past, this, cu, token_num, B)
+    with jax.named_scope("cache_write"):
+        pool = _write_latent_rows(pool, layer, ki_tok[:, None], past, this,
+                                  cu, block_tables, tok_b, tok_pos,
+                                  tok_valid, use_pallas)
+    sparse = (this > 0) & (past + this > topk)
+
+    def select():
+        with jax.named_scope("index_scores"):
+            # every sequence's index keys by position: 256 B a key at the
+            # published widths, one gather of its pages, no layer sliced
+            flat = pool.reshape(L_ * num_blocks, bs * ID)
+            keys = jnp.take(flat, layer * num_blocks
+                            + jnp.maximum(block_tables, 0), axis=0
+                            ).reshape(B, max_kv, ID)
+            if not use_pallas:
+                scores = sparse_index.index_scores(qi, keys[tok_b], w)
+            else:
+                first = jnp.clip(cu[:B], 0, token_num - 1)
+                scores = jnp.sum(jnp.maximum(jnp.einsum(
+                    "bhd,bsd->bhs", qi[first], keys,
+                    preferred_element_type=jnp.float32), 0.0)
+                    * w[first][..., None], axis=1)[tok_b]
+                if use_pallas != "decode":
+                    single = this == 1
+                    scores = jnp.where(
+                        single[tok_b][:, None], scores,
+                        PL.index_scores_packed(
+                            qi, w, keys, past, jnp.where(single, 0, this),
+                            cu))
+        with jax.named_scope("index_select"):
+            visible = ((jnp.arange(max_kv)[None, :] <= tok_pos[:, None])
+                       & (tok_valid & sparse[tok_b])[:, None])
+            # a row's table in eights (the pages of a block of 128 keys)
+            # rides along, so that each selected key comes with its page
+            # and the read looks nothing up
+            per = sparse_index.BLOCK // bs
+            mine = jnp.pad(block_tables, ((0, 0), (0, -max_blocks % per))
+                           )[tok_b].reshape(token_num, -1, per)
+            pos, pages = sparse_index.selected_positions(
+                sparse_index.select_topk(scores, visible, topk), topk,
+                carry=mine)
+            sub = jnp.maximum(pos, 0) % sparse_index.BLOCK // bs
+            page = pages[0]
+            for i in range(1, per):
+                page = jnp.where(sub == i, pages[i], page)
+            return pos, page
+
+    none = jnp.full((token_num, topk), -1, jnp.int32)
+    idx, page = lax.cond(jnp.any(sparse), select, lambda: (none, none))
+    return idx, page, sparse, pool
+
+
 def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
                            seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
-                           block_tables, sm_scale, use_pallas=False):
+                           block_tables, sm_scale, use_pallas=False,
+                           window: int = 0, select=None):
     """One layer of multi-head latent attention on the stacked LATENT page
     pool [L, num_blocks, 1, block_size, W]: write the new tokens' cache rows
     `row_tok` [tok, w] (latent | rope key, `models.llama.latent_kv`; w <= W,
@@ -755,12 +892,31 @@ def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
     [C, H, nope] and wv [C, H, v] the halves of Wkvb
     (`models.llama.latent_wkvb`).
 
-    WHICH FORM A LAUNCH TAKES (the one rule, here and nowhere else): every
-    row attends in the ABSORBED form, (q_nope wk_h^T | q_rope) against the
-    cache rows themselves, its head's output the probabilities' sum over
-    the rows' latents, then through wv_h: 2 x (W + C) FLOPs a (row, key,
-    head). `use_pallas` "decode" is the decode launch (one token a
-    sequence); True is a tick with a chunk, whose one-row sequences go
+    WHICH READ A ROW TAKES, AND IN WHICH FORM (the one rule, here and
+    nowhere else). Every row attends in the ABSORBED form, (q_nope wk_h^T |
+    q_rope) against the cache rows themselves, its head's output the
+    probabilities' sum over the rows' latents, then through wv_h: 2 x (W +
+    C) FLOPs a (row, key, head). Which keys:
+
+    - WINDOW (`window` W > 0, static: a layer whose spec has one): the last
+      W keys, the row's own among them, by the dense walks below with
+      their window bound; the table's entries behind every window may be
+      -1 (`paged_attention_latent_window` inside `paged_attention`);
+    - SPARSE (`select` = (positions [tok, k], their pages [tok, k], sparse
+      [B]) from `paged_index_select`: a layer whose spec has an index): the
+      rows of a
+      sequence that holds more than k keys after this tick attend over
+      their k selected cache rows alone, gathered from the pool by position
+      (`paged_attention_sparse`); a row that sees at most k keys has them
+      all selected. The rows of the other sequences take the DENSE walk.
+      Which of the two reads runs is decided on the device by the tick's
+      own lengths (a tick without a sparse sequence gathers nothing, one
+      without a dense sequence walks nothing);
+    - DENSE (everything else): every key up to the row's own
+      (`paged_attention_latent`).
+
+    The dense walks: `use_pallas` "decode" is the decode launch (one token
+    a sequence); True is a tick with a chunk, whose one-row sequences go
     through that same decode launch and whose chunks go through the mixed
     walk (`paged_attention_latent`): a work item of the walk moves 4.6 MB
     of rows for one live token, and 63 of them cost a layer 2.6 ms where
@@ -770,23 +926,22 @@ def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
     key, head): 3.6 x fewer) was built and measured in that PR and is not
     taken: its kernel ran the chunk at 37 TFLOP/s where the walk runs it
     at 131, and the rebuild cost 0.9 ms a layer beside it. False is the
-    stock dense gather (CPU tests). Scopes: `latent_q` (the absorption),
-    `cache_write`, `paged_attention` > `paged_attention_latent` (the
-    launches), `latent_out` (wv). Returns (o [tok, H * v], pool)."""
+    stock read (CPU tests): the sparse read's own gather over every key a
+    row sees, in float32, so that a selection of every key IS the dense
+    read. Scopes: `latent_q` (the absorption), `cache_write`,
+    `paged_attention` > `paged_attention_latent` | `_latent_window` |
+    `_sparse` (the launches), `latent_out` (wv). Returns (o [tok, H * v],
+    pool)."""
     from ..pallas import paged_attention_latent as PL
-    _, num_blocks, _, bs, W = pool.shape
+    L_, num_blocks, _, bs, W = pool.shape
     B, max_blocks = block_tables.shape
     token_num, H, nope = q_nope.shape
     C, w = wk.shape[0], row_tok.shape[-1]
     max_kv = max_blocks * bs
     cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
-    tok_idx = jnp.arange(token_num, dtype=jnp.int32)
-    tok_b = jnp.clip(jnp.searchsorted(cu, tok_idx, side="right") - 1, 0, B - 1)
-    tok_local = tok_idx - cu[tok_b]
     past = seq_lens_decoder.reshape(-1).astype(jnp.int32)
     this = seq_lens_this_time.reshape(-1).astype(jnp.int32)
-    tok_pos = past[tok_b] + tok_local
-    tok_valid = tok_local < this[tok_b]
+    tok_b, tok_pos, tok_valid = _packed_rows(past, this, cu, token_num, B)
     with jax.named_scope("latent_q"):
         q_tok = jnp.concatenate(
             [jnp.einsum("thn,chn->thc", q_nope, wk.astype(q_nope.dtype)),
@@ -794,18 +949,9 @@ def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
     rows = jnp.pad(row_tok, ((0, 0), (0, W - w)))[:, None]     # [tok, 1, W]
 
     with jax.named_scope("cache_write"):
-        if use_pallas:
-            pages, lo, hi, src = page_plan(past, this, cu, block_tables,
-                                           num_blocks, bs, token_num)
-            pool = PL.write_latent_pages(
-                pool, layer, pages, lo, hi,
-                rows[src].transpose(0, 2, 1, 3))               # [n, 1, bs, W]
-        else:
-            tok_page = jnp.take_along_axis(
-                block_tables[tok_b], (tok_pos // bs)[:, None], axis=1)[:, 0]
-            tok_page = jnp.where(tok_valid & (tok_page >= 0), tok_page,
-                                 num_blocks + tok_idx)
-            pool = write_rows(pool, layer, tok_page, tok_pos % bs, rows)
+        pool = _write_latent_rows(pool, layer, rows, past, this, cu,
+                                  block_tables, tok_b, tok_pos, tok_valid,
+                                  use_pallas)
 
     def way_out(o_latent):                          # [tok, H, C] -> [tok, H*v]
         with jax.named_scope("latent_out"):
@@ -813,43 +959,75 @@ def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
                               wv.astype(o_latent.dtype)
                               ).reshape(token_num, -1)
 
-    with jax.named_scope("paged_attention"):
-        if use_pallas == "decode":
-            with jax.named_scope("paged_attention_latent"):
-                first = jnp.clip(cu[:B], 0, token_num - 1)
-                o = PL.latent_attention(q_tok[first], pool, block_tables,
-                                        past, this, sm_scale, layer, C)[tok_b]
-                o = jnp.where(tok_valid[:, None, None], o, 0)
-            return way_out(o), pool
-        if use_pallas:
-            with jax.named_scope("paged_attention_latent"):
-                single = this == 1
-                first = jnp.clip(cu[:B], 0, token_num - 1)
-                rows_1 = PL.latent_attention(
-                    q_tok[first], pool, block_tables, past,
-                    single.astype(jnp.int32), sm_scale, layer, C)[tok_b]
-                chunks = PL.latent_attention_packed(
-                    q_tok, pool, block_tables, past,
-                    jnp.where(single, 0, this), cu, sm_scale, layer, C)
-                o = jnp.where((single[tok_b] & tok_valid)[:, None, None],
-                              rows_1, chunks)
-            return way_out(o), pool
-        # ---- stock read (CPU tests): a dense gather of every row's pages
-        with jax.named_scope("paged_attention_latent"):
-            pages = lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
-            keys = pages[block_tables][:, :, 0].reshape(B, max_kv, W)
-            keys = keys[tok_b].astype(jnp.float32)             # [tok, S, W]
-            s = jnp.einsum("thw,tsw->ths", q_tok.astype(jnp.float32),
-                           keys) * sm_scale
-            live = jnp.broadcast_to((block_tables >= 0)[:, :, None],
-                                    (B, max_blocks, bs)).reshape(B, max_kv)
-            ok = ((jnp.arange(max_kv)[None, :] <= tok_pos[:, None])
-                  & live[tok_b])
-            p = jax.nn.softmax(jnp.where(ok[:, None, :], s, -1e30), axis=-1)
-            o = jnp.einsum("ths,tsc->thc", p, keys[..., :C])
-            o = jnp.where(tok_valid[:, None, None], o, 0.0
+    def read_rows(q, idx, page):
+        """Rows q [n, H, W] over the cache rows at positions idx [n, k]
+        (-1: none) of pages `page` [n, k] alone."""
+        at = ((layer * num_blocks + jnp.maximum(page, 0)) * bs
+              + jnp.maximum(idx, 0) % bs)
+        # (inside the pool by construction: no fill, no select over the
+        # gathered rows)
+        keys = jnp.take(pool.reshape(L_ * num_blocks * bs, W), at, axis=0,
+                        mode="clip")
+        if not use_pallas:
+            q, keys = q.astype(jnp.float32), keys.astype(jnp.float32)
+        s = jnp.einsum("nhw,nkw->nhk", q, keys,
+                       preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(((idx >= 0) & (page >= 0))[:, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(keys.dtype)
+        return jnp.einsum("nhk,nkc->nhc", p, keys[..., :C],
+                          preferred_element_type=jnp.float32
                           ).astype(q_tok.dtype)
-        return way_out(o), pool
+
+    def dense(this_d):
+        """The dense walks over the sequences whose `this_d` is not 0."""
+        kw = {"window": window} if window else {}
+        first = jnp.clip(cu[:B], 0, token_num - 1)
+        valid = tok_valid[:, None, None]
+        if use_pallas == "decode":
+            o = PL.latent_attention(q_tok[first], pool, block_tables, past,
+                                    this_d, sm_scale, layer, C, **kw)[tok_b]
+            return jnp.where(valid, o, 0)
+        if use_pallas:
+            single = this_d == 1
+            rows_1 = PL.latent_attention(
+                q_tok[first], pool, block_tables, past,
+                single.astype(jnp.int32), sm_scale, layer, C, **kw)[tok_b]
+            chunks = PL.latent_attention_packed(
+                q_tok, pool, block_tables, past,
+                jnp.where(single, 0, this_d), cu, sm_scale, layer, C, **kw)
+            # (the walk leaves the rows that are no sequence's zero)
+            return jnp.where(single[tok_b][:, None, None] & valid, rows_1,
+                             chunks)
+        # ---- stock read (CPU tests): every key a row sees, selected
+        pos = jnp.arange(max_kv, dtype=jnp.int32)[None, :]
+        seen = pos <= tok_pos[:, None]
+        if window:
+            seen &= pos > (tok_pos - window)[:, None]
+        return jnp.where(valid, read_rows(
+            q_tok, jnp.where(seen, pos, -1),
+            jnp.repeat(block_tables[tok_b], bs, axis=1)), 0)
+
+    with jax.named_scope("paged_attention"):
+        if select is None:
+            name = "paged_attention_latent" + ("_window" if window else "")
+            with jax.named_scope(name):
+                o = dense(this)
+        else:
+            idx, page, sparse = select
+            with jax.named_scope("paged_attention_latent"):
+                o = lax.cond(
+                    jnp.any((this > 0) & ~sparse),
+                    lambda: dense(jnp.where(sparse, 0, this)),
+                    lambda: jnp.zeros((token_num, H, C), q_tok.dtype))
+            with jax.named_scope("paged_attention_sparse"):
+                o = lax.cond(
+                    jnp.any(sparse),
+                    lambda: jnp.where(
+                        (sparse[tok_b] & tok_valid)[:, None, None],
+                        _by_row_blocks(read_rows, (q_tok, idx, page),
+                                       token_num), o),
+                    lambda: o)
+    return way_out(o.astype(q_tok.dtype)), pool
 
 
 # ---------------------------------------------------------------------------

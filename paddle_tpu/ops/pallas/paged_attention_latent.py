@@ -1,4 +1,4 @@
-"""Paged attention over a LATENT page pool (Pallas): the read and the write
+"""Paged attention over LATENT page pools (Pallas): the read and the write
 of multi-head latent attention's cache (`models.llama.latent_kv`: one row
 (c | k_rope) a position for every head) in the absorbed form, where a
 head's query row (q_nope Wkvb,K^T | q_rope) meets the cache row itself and
@@ -25,8 +25,26 @@ page copies are its own):
   as flash kernels do): at 64 rows a key the read is near the chip's
   ridge (121 FLOP/B against 240), and float32 products, which the MXU
   makes in several bf16 passes, would put it far on the compute side;
-- no int8 pages, no window, no block-causal mask: no latent model served
-  has them.
+- a static `window` W in both walks (0 = none): the query at position p
+  sees keys p - W + 1 .. p, its own among them, and a walk begins at the
+  key block that holds the first key its first row sees; the pages behind
+  every window need not be in the table (a latent layer with a window,
+  dots3-note's sliding kind: 64 heads over rows of 1,088 values in 1,152
+  lanes, 1,024 of them the value row);
+- at more than 64 heads a work item's row tile holds fewer tokens
+  (`mixed_tokens`: 16 at 128 heads), so that a tile stays 2,048 rows;
+- no int8 pages and no block-causal mask: no latent model served has them.
+
+Beside the walks, for a latent layer with a learned sparse index
+(`models.llama.IndexSpec`): the page write serves the index keys' pool as
+it serves the latent pool (`write_latent_pages`: a row of 128 lanes), and
+`index_scores_packed` is the INDEX WALK of a chunk: a work item's tokens'
+index heads against its sequence's index keys, ReLU, the heads' weighted
+sum, [rows, keys] in float32, a key tile at a time, so that [rows, heads,
+keys] never exists. The exact selection and the read over the selected
+rows are plain XLA (`ops/kernels/sparse_index.py`,
+`serving_attention.paged_latent_attention`, which also states the one rule
+of which read a row takes: dense, sparse or window).
 
 W is the pool's row width, whole lanes: the engine pads a row of 576
 values (512 + 64) to 640 with zeros, which add nothing to a score and are
@@ -53,7 +71,8 @@ from .paged_attention import (_STAT_LANES, _loop_i32, _work_items,
                               mixed_items)
 
 __all__ = ["latent_attention", "latent_attention_packed",
-           "write_latent_pages", "padded_width", "LANES"]
+           "index_scores_packed", "write_latent_pages", "padded_width",
+           "LANES"]
 
 LANES = 128
 # key positions of a key block (whole pages), tokens of a work item's row
@@ -83,10 +102,12 @@ def _copies(tables_ref, b, i, slot, pages: int, layer, pool, buf, sems):
 
 
 def _block_products(q, kbuf, slot, ok, sm_scale, value_dim, m_prev, l_prev,
-                    acc_prev):
+                    acc_prev, windowed: bool = False):
     """One key block's online-softmax step for query rows q [R, W] against
     the block in `kbuf[slot]` [pages, bs, W]; `ok` [R | 1, span] marks the
-    keys a row sees. Returns (m, l, acc)."""
+    keys a row sees. `windowed`: a row may see no key of the walk's first
+    blocks (they hold its tile's earlier rows' windows). Returns (m, l,
+    acc)."""
     pages, bs, W = kbuf.shape[1:]
     k = kbuf[slot].reshape(pages * bs, W)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -98,6 +119,10 @@ def _block_products(q, kbuf, slot, ok, sm_scale, value_dim, m_prev, l_prev,
     # row with none yet is a row without a query (key 0 is in every first
     # block), zeroed by the caller
     prob = jnp.exp(s - m_new)
+    if windowed:
+        # there m_new is still -1e30 and exp(0) = 1 would count every
+        # masked key
+        prob = jnp.where(ok, prob, 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_new = l_prev * alpha + jnp.sum(prob, axis=-1, keepdims=True)
     acc = acc_prev * alpha + jax.lax.dot_general(
@@ -109,9 +134,11 @@ def _block_products(q, kbuf, slot, ok, sm_scale, value_dim, m_prev, l_prev,
 
 def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, q_ref, pool,
                    o_ref, kbuf, sems, acc, m_sc, l_sc, *, sm_scale: float,
-                   block_size: int, pages: int, value_dim: int):
+                   block_size: int, pages: int, value_dim: int,
+                   window: int = 0):
     """One sequence b of a decode launch: its one token's H query rows
-    against its live key blocks of `pages` whole pages."""
+    against its live key blocks of `pages` whole pages; under a `window`
+    from the block that holds the first key it sees."""
     b = pl.program_id(0)
     layer = layer_ref[0]
     H = acc.shape[0]
@@ -122,6 +149,7 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, q_ref, pool,
         this_ref[b] > 0,
         jnp.minimum(jax.lax.div(past + _i32(span), _i32(span)),
                     _i32(width // pages)), _i32(0))
+    first = _first_block(past, window, span)
 
     def copies(i, slot):
         return _copies(tables_ref, b, i, slot, pages, layer, pool, kbuf, sems)
@@ -130,9 +158,9 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, q_ref, pool,
     l_sc[...] = jnp.zeros_like(l_sc)
     acc[...] = jnp.zeros_like(acc)
 
-    @pl.when(n_blocks > 0)
+    @pl.when(n_blocks > first)
     def _():
-        for c in copies(_i32(0), _i32(0)):
+        for c in copies(first, jax.lax.rem(first, _i32(2))):
             c.start()
 
     def block(i, _):
@@ -148,15 +176,33 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, q_ref, pool,
         kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
                   + i * _i32(span))
         m, l, a = _block_products(
-            q_ref[0], kbuf, slot, kv_abs <= past, sm_scale, value_dim,
-            m_sc[:, :1], l_sc[:, :1], acc[...])
+            q_ref[0], kbuf, slot, _sees(kv_abs, past, window), sm_scale,
+            value_dim, m_sc[:, :1], l_sc[:, :1], acc[...])
         acc[...] = a
         m_sc[...] = jnp.broadcast_to(m, (H, _STAT_LANES))
         l_sc[...] = jnp.broadcast_to(l, (H, _STAT_LANES))
 
-    jax.lax.fori_loop(_i32(0), n_blocks, block, None)
+    jax.lax.fori_loop(first, n_blocks, block, None)
     l = l_sc[:, :1]
     o_ref[0] = (acc[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _first_block(pos, window: int, span: int):
+    """The key block a walk begins at: 0, or under a window the block that
+    holds the first key the query at `pos` sees."""
+    if not window:
+        return _i32(0)
+    return jax.lax.div(jnp.maximum(pos - _i32(window - 1), _i32(0)),
+                       _i32(span))
+
+
+def _sees(kv_abs, pos, window: int):
+    """Causal, and under a window W the last W keys, the query's own among
+    them."""
+    ok = kv_abs <= pos
+    if window:
+        ok &= kv_abs >= pos - _i32(window - 1)
+    return ok
 
 
 def _check(q, pool, value_dim):
@@ -171,11 +217,14 @@ def _check(q, pool, value_dim):
 
 def latent_attention(q_rows, pool, block_tables, seq_lens_decoder,
                      seq_lens_this_time, sm_scale: float, layer,
-                     value_dim: int, interpret: Optional[bool] = None):
+                     value_dim: int, interpret: Optional[bool] = None,
+                     window: int = 0):
     """The decode launch: q_rows [B, H, W], one token a sequence at
     position `seq_lens_decoder[b]` (an idle slot: `seq_lens_this_time[b]`
     0), against the pages `block_tables[b]` of `pool[layer]`, which already
-    hold the token's own row. Returns [B, H, value_dim], idle slots 0."""
+    hold the token's own row. `window` W > 0 (static): the last W keys, the
+    row's own among them; table entries behind it may be anything inside
+    the pool. Returns [B, H, value_dim], idle slots 0."""
     _check(q_rows, pool, value_dim)
     B, H, W = q_rows.shape
     bs = pool.shape[3]
@@ -198,7 +247,7 @@ def latent_attention(q_rows, pool, block_tables, seq_lens_decoder,
             pltpu.VMEM((H, _STAT_LANES), jnp.float32)])
     kernel = functools.partial(
         _decode_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
-        pages=int(pages), value_dim=int(value_dim))
+        pages=int(pages), value_dim=int(value_dim), window=int(window))
     count_launch()
     return pl.pallas_call(
         kernel, name="paged_attention_latent_decode", grid_spec=grid_spec,
@@ -213,7 +262,7 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
                   used_ref, q_ref, pool, o_ref, kbuf, sems, acc, m_sc, l_sc,
                   *,
                   sm_scale: float, block_size: int, pages: int, heads: int,
-                  small: int, value_dim: int):
+                  small: int, value_dim: int, window: int = 0):
     """One work item j of a mixed launch: the query rows of sequence
     seq[j] from chunk offset t0[j] on (row r = t * H + h), against that
     sequence's key blocks up to the tile's own causal limit. An item with
@@ -235,6 +284,9 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
         live > 0,
         jnp.minimum(jax.lax.div(past + t0 + live + _i32(span - 1),
                                 _i32(span)), _i32(width // pages)), _i32(0))
+    # under a window the tile's walk begins at the block that holds the
+    # first key its first row sees
+    first = _first_block(past + t0, window, span)
 
     def fetch(i, slot, wait=False):
         def page(p):
@@ -252,7 +304,8 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
         m_sc[:rows] = jnp.full((rows, _STAT_LANES), NEG_INF, jnp.float32)
         l_sc[:rows] = jnp.zeros((rows, _STAT_LANES), jnp.float32)
         acc[:rows] = jnp.zeros((rows, value_dim), jnp.float32)
-        pl.when(n_blocks > 0)(lambda: fetch(_i32(0), _i32(0)))
+        pl.when(n_blocks > first)(
+            lambda: fetch(first, jax.lax.rem(first, _i32(2))))
 
         def block(i, _):
             slot = jax.lax.rem(i, _i32(2))
@@ -262,13 +315,14 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
             kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
                       + i * _i32(span))
             m, l, a = _block_products(
-                q_ref[0, :rows], kbuf, slot, kv_abs <= pos, sm_scale,
-                value_dim, m_sc[:rows, :1], l_sc[:rows, :1], acc[:rows])
+                q_ref[0, :rows], kbuf, slot, _sees(kv_abs, pos, window),
+                sm_scale, value_dim, m_sc[:rows, :1], l_sc[:rows, :1],
+                acc[:rows], windowed=window > 0)
             acc[:rows] = a
             m_sc[:rows] = jnp.broadcast_to(m, (rows, _STAT_LANES))
             l_sc[:rows] = jnp.broadcast_to(l, (rows, _STAT_LANES))
 
-        jax.lax.fori_loop(_i32(0), n_blocks, block, None)
+        jax.lax.fori_loop(first, n_blocks, block, None)
         l = l_sc[:rows, :1]
         out = acc[:rows] / jnp.where(l == 0.0, 1.0, l)
         o_ref[0, :rows] = jnp.where(pos >= 0, out, 0.0).astype(o_ref.dtype)
@@ -282,19 +336,25 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
         pl.when(live > 0)(lambda: walk(R))
 
 
-def mixed_tokens(token_num: int) -> int:
-    """Tokens of a work item's row tile."""
-    return max(1, min(_MIXED_TOKENS, token_num))
+def mixed_tokens(token_num: int, heads: int = 64) -> int:
+    """Tokens of a work item's row tile: `_MIXED_TOKENS` at up to 64
+    heads, fewer above, so that a tile keeps its `_MIXED_TOKENS` x 64 rows
+    (at 128 heads twice the rows would be 9 MB of queries in and 8 MB of
+    accumulator beside a 8 MB score block)."""
+    tokens = max(1, _MIXED_TOKENS * 64 // max(heads, 64))
+    return max(1, min(tokens, token_num))
 
 
 def latent_attention_packed(q_tok, pool, block_tables, seq_lens_decoder,
                             seq_lens_this_time, cu_seqlens_q,
                             sm_scale: float, layer, value_dim: int,
-                            interpret: Optional[bool] = None):
+                            interpret: Optional[bool] = None,
+                            window: int = 0):
     """The mixed launch, on the packed token stream: q_tok [token_num, H,
     W], sequence b's `seq_lens_this_time[b]` tokens at rows cu_seqlens_q[b]
-    on, its token t at position `seq_lens_decoder[b] + t`, causal. Returns
-    [token_num, H, value_dim], rows that are no sequence's token 0."""
+    on, its token t at position `seq_lens_decoder[b] + t`, causal, under
+    `window` W > 0 (static) over the last W keys. Returns [token_num, H,
+    value_dim], rows that are no sequence's token 0."""
     _check(q_tok, pool, value_dim)
     token_num, H, W = q_tok.shape
     B = block_tables.shape[0]
@@ -310,7 +370,7 @@ def latent_attention_packed(q_tok, pool, block_tables, seq_lens_decoder,
     tok_local = tok_idx - cu[tok_b]
     tok_valid = (tok_local < this[tok_b])[:, None, None]
 
-    tq = mixed_tokens(token_num)
+    tq = mixed_tokens(token_num, H)
     items = mixed_items(token_num, B, tq)
     seq, t0, first = _work_items(cu, this, tq, items, token_num)
     row_tok = jnp.clip((cu[seq] + t0)[:, None]
@@ -341,7 +401,7 @@ def latent_attention_packed(q_tok, pool, block_tables, seq_lens_decoder,
     kernel = functools.partial(
         _mixed_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
         pages=int(pages), heads=int(H), small=min(_SMALL_TOKENS, tq),
-        value_dim=int(value_dim))
+        value_dim=int(value_dim), window=int(window))
     count_launch()
     o_items = pl.pallas_call(
         kernel, name="paged_attention_latent_mixed", grid_spec=grid_spec,
@@ -400,3 +460,103 @@ def write_latent_pages(pool, layer, pages, lo, hi, new,
     )(jnp.asarray(layer, jnp.int32).reshape(1), pages.astype(jnp.int32),
       lo.astype(jnp.int32), hi.astype(jnp.int32), new.astype(pool.dtype),
       pool)
+
+
+# ---------------------------------------------------------------------------
+# the index walk of a chunk (a latent layer's sparse index)
+# ---------------------------------------------------------------------------
+_INDEX_KEYS = 512       # index keys of a key tile
+_INDEX_TOKENS = 32      # tokens of a work item's row tile (x IH rows)
+
+
+def _index_kernel(seq_ref, t0_ref, past_ref, this_ref, q_ref, w_ref, k_ref,
+                  o_ref, *, heads: int, keys: int):
+    """Key tile i of work item j: the item's tokens' index heads against
+    `keys` index keys of its sequence, I(t, s) = sum_h w[t, h] ReLU(q[t,
+    h] . k[s]). A tile wholly behind the item's last row, and every tile
+    of an item without rows, is written as zeros and multiplies nothing
+    (its blocks are the last needed tile's, so nothing is fetched for it
+    either)."""
+    j, i = pl.program_id(0), pl.program_id(1)
+    b = seq_ref[j]
+    tq = o_ref.shape[1]
+    live = jnp.clip(this_ref[b] - t0_ref[j], _i32(0), _i32(tq))
+    last = past_ref[b] + t0_ref[j] + live - _i32(1)
+    needed = (live > 0) & (i * _i32(keys) <= last)
+
+    @pl.when(needed)
+    def _():
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.DEFAULT,
+                                preferred_element_type=jnp.float32)
+        s = jnp.maximum(s, 0.0) * w_ref[0]                 # [tq * IH, keys]
+        o_ref[0] = jnp.sum(s.reshape(tq, heads, keys), axis=1)
+
+    @pl.when(jnp.logical_not(needed))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def index_tokens(token_num: int) -> int:
+    return max(1, min(_INDEX_TOKENS, token_num))
+
+
+def index_scores_packed(qi_tok, w_tok, keys, seq_lens_decoder,
+                        seq_lens_this_time, cu_seqlens_q,
+                        interpret: Optional[bool] = None):
+    """The index walk of the packed token stream's chunks: qi_tok
+    [token_num, IH, ID] index queries, w_tok [token_num, IH] float32 head
+    weights, keys [B, S, ID] each sequence's index keys by position (the
+    caller gathered its pages: 256 B a position). Returns I [token_num, S]
+    float32; what lies behind a row's own position is not a score (zeros
+    or a later row's) and the caller masks it."""
+    token_num, IH, ID = qi_tok.shape
+    B, S, _ = keys.shape
+    if interpret is None:
+        interpret = not available()
+    cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
+    past = seq_lens_decoder.reshape(-1).astype(jnp.int32)
+    this = seq_lens_this_time.reshape(-1).astype(jnp.int32)
+    tok_idx = jnp.arange(token_num, dtype=jnp.int32)
+    tok_b = jnp.clip(jnp.searchsorted(cu, tok_idx, side="right",
+                                      method="compare_all") - 1, 0, B - 1)
+    tok_local = tok_idx - cu[tok_b]
+    tq = index_tokens(token_num)
+    tk = min(_INDEX_KEYS, S + -S % LANES)
+    keys = jnp.pad(keys, ((0, 0), (0, -S % tk), (0, 0)))
+    tiles = keys.shape[1] // tk
+    items = mixed_items(token_num, B, tq)
+    seq, t0, first = _work_items(cu, this, tq, items, token_num)
+    row_tok = jnp.clip((cu[seq] + t0)[:, None]
+                       + jnp.arange(tq, dtype=jnp.int32)[None, :],
+                       0, token_num - 1)                          # [items, tq]
+    q_items = qi_tok[row_tok].reshape(items, tq * IH, ID)
+    w_items = w_tok.astype(jnp.float32)[row_tok].reshape(items, tq * IH, 1)
+
+    def key_tile(j, i, sq, t0_, pa, th):
+        # the last tile a row of the item reads; an item without rows
+        # stays on tile 0
+        b = sq[j]
+        live = jnp.clip(th[b] - t0_[j], _i32(0), _i32(tq))
+        last = jnp.maximum(pa[b] + t0_[j] + live - _i32(1), _i32(0))
+        return (b, jnp.minimum(i, jax.lax.div(last, _i32(tk))), _i32(0))
+
+    rows = lambda w: pl.BlockSpec(
+        (1, tq * IH, w), lambda j, i, *_: (j, _i32(0), _i32(0)),
+        memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(items, tiles),
+        in_specs=[rows(ID), rows(1),
+                  pl.BlockSpec((1, tk, ID), key_tile,
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((1, tq, tk), lambda j, i, *_: (j, _i32(0), i),
+                               memory_space=pltpu.VMEM))
+    count_launch()
+    o_items = pl.pallas_call(
+        functools.partial(_index_kernel, heads=int(IH), keys=int(tk)),
+        name="paged_index_scores_chunk", grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((items, tq, tiles * tk), jnp.float32),
+        interpret=interpret,
+    )(seq, t0, past, this, q_items, w_items, keys)
+    item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
+    return o_items[item, tok_local % tq, :S]

@@ -783,6 +783,133 @@ def test_kimi_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
     print("Kimi depth-6 GiB by tok_pad:", gib)
 
 
+@pytest.mark.parametrize("launch", [
+    "decode_window", "mixed_window", "decode_full", "mixed_full", "index",
+    "write_index"])
+def test_sparse_latent_walks_compile(one_chip, launch):
+    """What dots3-note-prev's two kinds of latent layer launch, at the
+    cell's shapes (32 slots, tables of 2,080 entries, a 2,048-token
+    stream): the windowed walks of the sliding layers (a pool of 3 layers x
+    6,144 pages of 16 rows, a row of 1,088 values in 1,152 lanes, 64 heads,
+    a window of 513), the dense walks of the full layers at 128 heads (2
+    layers x 68,608 pages, 640 lanes; a work item's row tile halves to 16
+    tokens), the index walk of a chunk (64 index heads of 128 against each
+    sequence's 33,280 index keys) and the page write of the index keys (a
+    row of 128 lanes) lower for the chip."""
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+    batch, tokens, entries = 32, 2048, 2080
+    lens = [((batch, entries), jnp.int32), ((batch,), jnp.int32),
+            ((batch,), jnp.int32)]
+    kind, _, pool_kind = launch.partition("_")
+    heads, pool, vdim, kw = (
+        (64, _bf16(3, 6144, 1, 16, pl_.padded_width(1088)), 1024,
+         dict(window=513)) if pool_kind == "window"
+        else (128, _bf16(2, 68608, 1, 16, pl_.padded_width(576)), 512, {}))
+    width = pool[0][-1]
+    if kind == "decode":
+        def fn(q, pool, tables, past, this, layer):
+            return pl_.latent_attention(q, pool, tables, past, this, 0.0625,
+                                        layer, vdim, interpret=False, **kw)
+        shapes = [_bf16(batch, heads, width), pool, *lens, ((), jnp.int32)]
+        name = "paged_attention_latent_decode"
+    elif kind == "mixed":
+        assert pl_.mixed_tokens(tokens, heads) * heads == 2048
+        def fn(q, pool, tables, past, this, cu, layer):
+            return pl_.latent_attention_packed(
+                q, pool, tables, past, this, cu, 0.0625, layer, vdim,
+                interpret=False, **kw)
+        shapes = [_bf16(tokens, heads, width), pool, *lens,
+                  ((batch + 1,), jnp.int32), ((), jnp.int32)]
+        name = "paged_attention_latent_mixed"
+    elif kind == "index":
+        def fn(qi, w, keys, past, this, cu):
+            return pl_.index_scores_packed(qi, w, keys, past, this, cu,
+                                           interpret=False)
+        shapes = [_bf16(tokens, 64, 128), ((tokens, 64), jnp.float32),
+                  _bf16(batch, entries * 16, 128), *lens[1:],
+                  ((batch + 1,), jnp.int32)]
+        name = "paged_index_scores_chunk"
+    else:
+        n = tokens // 16 + 2 * batch
+
+        def fn(pool, layer, pages, lo, hi, new):
+            return pl_.write_latent_pages(pool, layer, pages, lo, hi, new,
+                                          interpret=False)
+        shapes = [_bf16(2, 68608, 1, 16, 128), ((), jnp.int32),
+                  *[((n,), jnp.int32)] * 3, _bf16(n, 1, 16, 128)]
+        name = "paged_cache_write_latent"
+    assert name in _compile(fn, one_chip, *shapes).as_text()
+
+
+def test_dots3_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
+    """The cell `serve_sparse_latent_longctx` as the engine builds it on a
+    TPU (`available` steered true), from the configuration file itself:
+    both executables (a tick with a prefill chunk, 2,048 rows; a decode
+    tick, 32 rows) compile for the described v5e with both latent pools
+    and the index keys in their carry and 32 of 256 experts held, and the
+    compiler counts each over 25 % and under the chip's 15.75 GiB, pinned
+    where PR 43 read them, so that a later change of a pool's layout, of
+    the selection's blocks or of `token_budget` cannot outgrow the chip
+    unseen."""
+    import json
+    import os
+
+    from benchmark.drivers import closed_loop_serve_sparse_latent as D
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import llama as L
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "dots3-note-prev-serve.json")) as f:
+        file = json.load(f)
+    cfg, e = D.dots3_config(file, jnp.bfloat16), file["engine"]
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    for module in (fa, pa, pl_):
+        monkeypatch.setattr(module, "available", lambda: True)
+    eng = PagedServingEngine(
+        cfg, params, num_blocks=e["num_blocks"], block_size=e["block_size"],
+        max_batch=e["max_batch"], token_budget=e["token_budget"],
+        max_len=e["max_len"], pallas=True, pallas_ffn=False)
+    shapes = jax.tree.map(lambda a: a.shape,
+                          (eng._key_cache, eng._value_cache))
+    assert shapes == (((2, 68608, 1, 16, 640), (3, 6144, 1, 16, 1152)),
+                      ((2, 68608, 1, 16, 128), None))
+    build, gib = eng._build_step, {}
+
+    def compiled_not_run(tok_pad, B, *rest):
+        fn = build(tok_pad, B, *rest)
+
+        def tick(*args):
+            abstract = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            compiled = fn.lower(*abstract).compile()
+            text = compiled.as_text()
+            # a tick with a chunk runs the index walk and both dense walks
+            for name in ("paged_index_scores_chunk",
+                         "paged_attention_latent_mixed"):
+                assert (name in text) == (tok_pad == 2048)
+            assert "paged_attention_latent_decode" in text
+            m = compiled.memory_analysis()
+            gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                            + m.temp_size_in_bytes
+                            - m.alias_size_in_bytes) / 2 ** 30
+            return (jnp.zeros((B + len(eng._moe_fields),), jnp.int32),
+                    args[1], args[2])
+        return tick
+
+    monkeypatch.setattr(eng, "_build_step", compiled_not_run)
+    eng.submit(list(range(1, 70)), max_new_tokens=4)
+    eng.step()                  # the prompt, one chunk
+    eng.step()                  # a decode row
+    assert set(gib) == {2048, 32}
+    assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
+    # PR 43's readings, 14.46 and 12.31 (11.42 of them weights and pools)
+    assert gib[2048] < 14.5 and gib[32] < 12.35, gib
+    print("dots3 depth-5 GiB by tok_pad:", gib)
+
+
 @pytest.mark.parametrize("rows, slots", [(64, 128), (1024, 1024)])
 def test_a_held_share_compiles_its_compact_form_at_kimi_widths(one_chip, rows,
                                                                slots):

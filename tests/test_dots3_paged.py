@@ -1,0 +1,500 @@
+"""dots3-note-shaped models (latent attention of TWO kinds in one model: one
+under a learned sparse index over latent pages that carry an index key
+beside each cache row, one under a window in a latent pool of its own
+width; a head-wise gate on both; a leading dense layer; a chip's share of
+the routed experts beside a shared one) through `llama.forward` and
+`PagedServingEngine`, against the plain float32 reference
+`benchmark/lib/reference_dots3.py`.
+
+Everything here is float32 at a tiny size whose ratios stay the model's
+(the benchmark's fixture `tiny-dots3.json`: 5 layers full, full, sliding x
+3; d 64; full layers 8 heads of 16 + 8, ranks 48 and 40, a cache row of 48
+values, 4 index heads of 16 keeping 8 keys; sliding layers 4 heads of 24 +
+8, ranks 32 and 56, a cache row of 64 values, a window of 5; 16 experts of
+32 of which 4 are held, two a row; vocabulary 512), with contexts of 40 and
+more, so that a row keeps a fifth of its keys and the window has long
+released its first pages.
+"""
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.drivers import closed_loop_serve_sparse_latent as D
+from benchmark.lib import agreement, agreement_blockdiff
+from benchmark.lib import reference_dots3 as R
+from paddle_tpu.inference.serving import PagedServingEngine
+from paddle_tpu.models import llama as L
+from paddle_tpu.ops.kernels import serving_attention as SA
+from paddle_tpu.ops.kernels import sparse_index as SI
+from paddle_tpu.ops.pallas import paged_attention_latent as PL
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+with open(os.path.join(HERE, "..", "benchmark", "tests", "fixtures",
+                       "configs", "tiny-dots3.json")) as f:
+    TINY = json.load(f)
+WIDTH = 128             # the reference's padded length (one compile)
+
+
+def sharpened(params):
+    """A router and a head sharp enough that top-k sets and argmaxes
+    differ, a selection bias that changes choices, queries, rope keys and
+    an index large enough that neither the scores nor the selection are
+    flat, an index-key bias that is not zero, and routed experts that weigh
+    as much as the shared one."""
+    def one(b):
+        out = {**b, "wqb": b["wqb"] * 30.0, "wkva": b["wkva"] * 5.0,
+               "wg": b["wg"] * 20.0}
+        if "router" in b:
+            out.update(router=b["router"] * 20.0,
+                       router_bias=b["router_bias"] * 10.0, w2=b["w2"] * 8.0)
+        if "wiq" in b:
+            out.update(wiq=b["wiq"] * 30.0, wik=b["wik"] * 30.0,
+                       wiw=b["wiw"] * 30.0, ik_bias=b["ik_bias"] + 0.5)
+        return out
+    return {**params, "blocks": tuple(map(one, params["blocks"])),
+            "lm_head": params["lm_head"] * 8.0}
+
+
+def make(file=TINY, seed=0):
+    cfg = dataclasses.replace(D.dots3_config(file, jnp.float32),
+                              dtype=jnp.float32)
+    return cfg, sharpened(L.init_params(cfg, jax.random.PRNGKey(seed)))
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return make()
+
+
+def prompt_of(n, seed=1):
+    return np.random.default_rng(seed).integers(1, 500, n).tolist()
+
+
+def reference_tokens(params, prompt, new, **fault):
+    with jax.default_matmul_precision("highest"):
+        return R.generate(params, prompt, new, WIDTH, **R.model_kw(TINY),
+                          **fault)[0]
+
+
+def reference_logits(params, tokens, file=TINY, **kw):
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(R.logits_at(
+            params, jnp.asarray(tokens, jnp.int32), jnp.arange(len(tokens)),
+            **{**R.model_kw(file), **kw}))
+
+
+def engine(cfg, params, **kw):
+    e = TINY["engine"]
+    kw = {**dict(num_blocks=e["num_blocks"], block_size=e["block_size"],
+                 max_batch=e["max_batch"], token_budget=e["token_budget"],
+                 max_len=e["max_len"], pallas=False,
+                 window_blocks=e["window_blocks"]), **kw}
+    return PagedServingEngine(cfg, params, **kw)
+
+
+# ---- the model ---------------------------------------------------------------
+
+def test_a_plan_may_hold_two_latent_kinds_a_window_an_index_and_a_gate():
+    """What `LlamaConfig` refused before this model: latent layers under
+    an attention gate, of two kinds with their own widths, head counts and
+    ropes, a window on one and an index on the other."""
+    cfg = D.dots3_config(TINY, jnp.bfloat16)
+    assert [(s.attn, s.heads, s.ffn) for s in cfg.layer_plan] == [
+        ("latent", 8, "dense"), ("latent", 8, "sparse"),
+        *[("latent", 4, "sparse")] * 3]
+    assert cfg.attn_gate and len(cfg.kinds) == 3
+    full, swa = cfg.layer_plan[1].latent, cfg.layer_plan[2].latent
+    assert (full.width, full.window, full.index) == (
+        48, 0, L.IndexSpec(heads=4, head_dim=16, topk=8))
+    assert (swa.width, swa.window, swa.index) == (64, 5, None)
+    assert full.q_scale == pytest.approx((64 / 48) ** 0.5)
+    assert swa.kv_scale == pytest.approx((64 / 56) ** 0.5)
+    assert (full.score_scale, swa.score_scale) == (24 ** -0.5, 32 ** -0.5)
+    assert cfg.layer_plan[1].rope.theta != cfg.layer_plan[2].rope.theta
+    with pytest.raises(ValueError, match="2 kinds"):
+        cfg.one_latent()        # no widths "of the config": read the spec
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    dense, sparse, window = params["blocks"]
+    assert sparse["wiq"].shape == (1, 48, 4 * 16) and "wiq" not in window
+    assert sparse["wkva"].shape == (1, 64, 48)
+    assert window["wkva"].shape == (3, 64, 64)
+    assert sparse["wg"].shape == (1, 64, 8) and window["wg"].shape == (3, 64, 4)
+    assert sparse["w1"].shape == (1, 4, 64, 32)         # the held experts
+    assert dense["w1"].shape == (1, 64, 96)
+
+
+def test_counts_at_the_published_config():
+    """279.55 B parameters, 15.48 B of them active a token (the head
+    counted, the embedding's row lookup not), and the chip's share
+    as the configuration's file cuts it: 4.087 B = 8.17 GB in bf16, of
+    which full attention 134.68 M + index 9.37 M, sliding attention
+    90.83 M, an expert 23.59 M, the dense FFN 212.34 M."""
+    with open(os.path.join(HERE, "..", "benchmark", "configs",
+                           "dots3-note-prev-serve.json")) as f:
+        file = json.load(f)
+    whole = {**file, **file["published"],
+             "n_routed_experts": file["router_width"]}
+    cfg = D.dots3_config(whole, jnp.bfloat16)
+    assert cfg.experts_held == () and cfg.num_layers == 46
+    assert sum(s.latent.index is not None for s in cfg.layer_plan) == 13
+    assert round(cfg.num_params() / 1e9, 2) == 279.55
+    assert round(cfg.num_active_params() / 1e9, 2) == 15.48
+    cut = D.dots3_config(file, jnp.bfloat16)
+    held = jax.eval_shape(lambda k: L.init_params(cut, k),
+                          jax.random.PRNGKey(0))
+    size = lambda t: sum(int(np.prod(a.shape)) for a in jax.tree.leaves(t))
+    assert round(size(held) * 2 / 1e9, 2) == 8.17
+    dense, sparse, window = held["blocks"]
+    attn = ("wqa", "wqb", "wkva", "wkvb", "wo", "wg")
+    index = ("wiq", "wik", "wiw")
+    m = lambda stack, names: round(sum(
+        int(np.prod(stack[n].shape[1:])) for n in names) / 1e6, 2)
+    assert (m(sparse, attn), m(sparse, index), m(window, attn)) == (
+        134.68, 9.37, 90.83)
+    assert m(dense, ("w1", "w3", "w2")) == 212.34
+    assert round(3 * 5120 * 1536 / 1e6, 2) == 23.59
+    assert sparse["w1"].shape == (1, 32, 5120, 1536)
+    assert sparse["router"].shape == (1, 5120, 256)
+
+
+@pytest.mark.parametrize("held", ["share", "every_expert"])
+def test_forward_equals_the_reference_on_logits(tiny, held):
+    if held == "share":
+        cfg, params, file = *tiny, TINY
+    else:
+        file = {**TINY, "n_routed_experts": TINY["router_width"]}
+        cfg, params = make(file)
+        assert cfg.experts_held == ()
+    tokens = prompt_of(100, seed=11)
+    ref = reference_logits(params, tokens, file)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(L.forward(params, jnp.asarray(tokens)[None], cfg)[0])
+    assert np.abs(got - ref).max() < 1e-4 * np.abs(ref).max()
+
+
+@pytest.mark.parametrize("fault", R.FAULTS)
+def test_a_seeded_fault_moves_forward_and_the_engine_off_the_reference(
+        tiny, fault):
+    """The negative controls, one in each new part (the rescale, the
+    window's bound, the index, its key's bias, the selection's size, the
+    gate): the reference with that part computed wrongly is another model,
+    and both `forward`'s logits and the engine's tokens (judged as the
+    cell's check judges them) show it."""
+    cfg, params = tiny
+    tokens = prompt_of(100, seed=11)
+    bad = reference_logits(params, tokens, fault=fault)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(L.forward(params, jnp.asarray(tokens)[None], cfg)[0])
+    assert np.abs(got - bad).max() > 1e-2 * np.abs(bad).max()
+    eng = engine(cfg, params)
+    prompts = [prompt_of(n, seed=n) for n in (70, 55, 41)]
+    rids = [eng.submit(p, max_new_tokens=24) for p in prompts]
+    done = {d.rid: d.output_tokens for d in eng.run()}
+    shares = []
+    for rid, p in zip(rids, prompts):
+        seq = p + done[rid]
+        at = np.arange(len(p) - 1, len(seq) - 1)
+        sound = reference_logits(params, seq + [0] * (WIDTH - len(seq)))[at]
+        wrong = reference_logits(params, seq + [0] * (WIDTH - len(seq)),
+                                 fault=fault)[at]
+        assert agreement.judge(sound, done[rid])[0] == 1.0
+        shares.append(agreement.judge(wrong, done[rid])[0])
+    assert min(shares) < 1.0
+
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """The share test: the routed parts that the four shares (4 of 16
+    experts each; 8 shares of 32 of 256 at the published widths) give,
+    with the shared expert counted once, equal the uncut reference
+    layer."""
+    from benchmark.lib import reference_kimi
+    whole_file = {**TINY, "n_routed_experts": TINY["router_width"]}
+    cfg, params = make(whole_file)
+    lp = {n: w[0] for n, w in params["blocks"][1].items()}
+    h = jax.random.normal(jax.random.PRNGKey(5), (40, 64), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(reference_kimi.sparse_ffn(
+            h, lp, top_k=2, router_scale=cfg.router_scale, held=None))
+        shared = np.asarray(L.ffn(h, {"w1": lp["ws1"], "w3": lp["ws3"],
+                                      "w2": lp["ws2"]}))
+        total, pairs = shared.copy(), 0
+        for first in range(0, 16, 4):
+            part = dataclasses.replace(cfg, experts_held=(first, 4))
+            mine = {**lp, **{n: lp[n][first:first + 4]
+                             for n in ("w1", "w3", "w2")}}
+            y, load = L.routed_ffn_load(h, mine, part)
+            total += np.asarray(y) - shared
+            pairs += int(load.sum())
+    assert pairs == 40 * 2              # every pair is someone's
+    assert np.abs(total - want).max() < 1e-5 * max(1, np.abs(want).max())
+
+
+# ---- the exact selection -----------------------------------------------------
+
+def test_the_selection_is_the_stable_sort_s_with_ties_to_the_lower_position():
+    """`select_topk` against a stable full sort on scores with many exact
+    ties (values from a set of nine, -0.0 among them), rows that see fewer
+    than k keys, rows that see none; `selected_positions` lists the set
+    ascending with -1 behind it, across its blocks of 128 keys."""
+    rng = np.random.default_rng(0)
+    T, S, k = 37, 300, 24
+    scores = rng.choice([-2.0, -0.0, 0.0, 0.5, 0.5, 1.0, 3.0, 7.25, -1e30],
+                        (T, S)).astype(np.float32)
+    seen = rng.integers(0, S + 1, T)
+    seen[:3] = (0, 5, k)
+    visible = (np.arange(S)[None, :] < seen[:, None]) & (
+        rng.random((T, S)) < 0.9)
+    mask = np.asarray(SI.select_topk(jnp.asarray(scores),
+                                     jnp.asarray(visible), k))
+    want = np.asarray(R.selected(jnp.asarray(scores), jnp.asarray(visible),
+                                 k))
+    assert np.array_equal(mask, want)
+    assert np.array_equal(mask.sum(1), np.minimum(visible.sum(1), k))
+    pos = np.asarray(SI.selected_positions(jnp.asarray(mask), k))
+    for t in range(T):
+        mine = np.flatnonzero(mask[t])
+        assert np.array_equal(pos[t, :len(mine)], mine)
+        assert np.all(pos[t, len(mine):] == -1)
+
+
+@pytest.mark.parametrize("decode", [True, False])
+def test_the_layer_ops_select_the_reference_s_sets_and_read_them(decode):
+    """The cell's direct check of a full layer's ops at the fixture's
+    shapes in float32 (Pallas interpreter): `paged_index_select` selects
+    exactly the reference's sets, the index pool and the latent pool hold
+    the new rows bit for bit, and the read over the selection is dense
+    float32 attention over those keys, within a hundredth of the check's
+    tolerance."""
+    case = D.op_case(TINY, 7, jnp.float32, "full_attention", decode)
+    res = D.op_outputs(TINY, case, decode)
+    assert res["pool_ok"] and res["index_pool_ok"]
+    assert res["selection"].all() and len(res["selection"]) >= 3
+    good, worst = agreement_blockdiff.judge_attention(res["out"], res["ref"])
+    assert good and worst < 0.01
+
+
+@pytest.mark.parametrize("decode", [True, False])
+def test_the_windowed_latent_walks_equal_dense_attention_in_the_window(
+        decode):
+    """The same for a sliding layer: the decode launch and the mixed walk
+    under their window bound (interpreter), over tables that hold -1
+    behind the windows."""
+    case = D.op_case(TINY, 7, jnp.float32, "sliding_attention", decode)
+    assert (np.asarray(case["tables"]) < 0).any()
+    res = D.op_outputs(TINY, case, decode)
+    good, worst = agreement_blockdiff.judge_attention(res["out"], res["ref"])
+    assert res["pool_ok"] and good and worst < 0.01
+    # one key more or fewer is another answer
+    wider = D.op_outputs(TINY, {**case, "window": case["window"] + 1},
+                         decode)
+    assert not agreement_blockdiff.judge_attention(wider["out"],
+                                                   res["ref"])[0]
+
+
+def eight_bits(*names):
+    def corrupt(case):
+        for n in names:
+            case[n] = case[n].astype(jnp.float8_e4m3fn).astype(case[n].dtype)
+        return case
+    return corrupt
+
+
+@pytest.mark.parametrize("what", ["index keys in 8 bits", "pages in 8 bits",
+                                  "an approximate selection"])
+def test_lower_precision_fails_the_direct_check(what, monkeypatch):
+    """What a token cannot see, each caught by a limit of part 2: index
+    keys rounded to 8 bits on their way into their pages (the pool no
+    longer holds them and the selected sets move), cache rows rounded
+    likewise, and a selection that is not exact (a row's least key swapped
+    for the best one left out)."""
+    from benchmark.lib import agreement_sparse_latent as A
+    corrupt = None
+    if what == "index keys in 8 bits":
+        corrupt = eight_bits("ki", "index_pool")
+    elif what == "pages in 8 bits":
+        corrupt = eight_bits("rows")
+    else:
+        exact = SI.select_topk
+
+        def nearly(scores, visible, k):
+            mask = exact(scores, visible, k)
+            lost = jnp.argmin(jnp.where(mask, scores, jnp.inf), axis=1)
+            got = jnp.argmax(jnp.where(visible & ~mask, scores, -jnp.inf),
+                             axis=1)
+            rows = jnp.arange(mask.shape[0])
+            full = jnp.sum(visible, axis=1) > k
+            return (mask.at[rows, lost].set(~full & mask[rows, lost])
+                    .at[rows, got].set(full | mask[rows, got]))
+        monkeypatch.setattr(SI, "select_topk", nearly)
+    # a tick with a chunk: thirty rows that select
+    case = D.op_case(TINY, 7, jnp.bfloat16, "full_attention", False)
+    res = D.op_outputs(TINY, case, False, corrupt)
+    share = res["selection"].mean()
+    if what == "pages in 8 bits":
+        assert not res["pool_ok"] and share == 1.0
+    elif what == "index keys in 8 bits":
+        assert not res["index_pool_ok"] and share < A.MIN_SELECTION
+    else:
+        assert res["pool_ok"] and share < A.MIN_SELECTION
+
+
+def test_the_sparse_read_with_every_key_selected_is_the_dense_walk():
+    """Bit for bit, on the stock path: a selection of every key a row sees
+    (`topk` = the table's width) through `paged_attention_sparse` against
+    the same rows through the dense read."""
+    case = D.op_case(TINY, 3, jnp.float32, "full_attention", False)
+    c = case
+    cu = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                          jnp.cumsum(c["this"]).astype(jnp.int32)])
+    S = c["tables"].shape[1] * TINY["engine"]["block_size"]
+
+    def read(select):
+        return SA.paged_latent_attention(
+            c["q_nope"], c["q_rope"], c["rows"], c["wk"], c["wv"], c["pool"],
+            jnp.int32(0), c["past"], c["this"], cu, c["tables"], c["scale"],
+            use_pallas=False, select=select)[0]
+
+    tok = c["rows"].shape[0]
+    tok_b = np.repeat(np.arange(len(c["this"])), np.asarray(c["this"]))
+    pos = np.asarray(c["past"])[tok_b] + np.arange(tok) - np.asarray(cu)[tok_b]
+    every = np.where(np.arange(S)[None, :] <= pos[:, None],
+                     np.arange(S)[None, :], -1).astype(np.int32)
+    pages = np.repeat(np.asarray(c["tables"])[tok_b],
+                      TINY["engine"]["block_size"], axis=1)
+    sparse = read((jnp.asarray(every), jnp.asarray(pages),
+                   jnp.ones((len(c["this"]),), bool)))
+    assert np.array_equal(np.asarray(sparse), np.asarray(read(None)))
+
+
+# ---- the engine --------------------------------------------------------------
+
+@pytest.mark.parametrize("pallas", [False, True])
+def test_engine_equals_the_reference_through_both_pools(tiny, pallas):
+    """Chunked prefill (chunks of 32 over prompts of 70 and 6), then decode
+    across page edges, two sequences in different phases: the short one
+    starts under `index_topk` (the dense walk), crosses it while decoding
+    (the selection starts) and crosses the window; the long one selects
+    from its first chunk on. Full layers' pages carry two rows a position,
+    the window layers' pool has its own width and gives pages back."""
+    cfg, params = tiny
+    eng = engine(cfg, params, pallas=pallas)
+    (full, window), (index, none) = eng._key_cache, eng._value_cache
+    assert full.shape == (2, 64, 1, 8, PL.padded_width(48))
+    assert window.shape == (3, 32, 1, 8, PL.padded_width(64))
+    assert index.shape == (2, 64, 1, 8, 16) and none is None
+    prompts = [prompt_of(70, seed=3), prompt_of(6, seed=4)]
+    news = [20, 30]
+    rids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    done = {d.rid: d.output_tokens for d in eng.run()}
+    for rid, p, n in zip(rids, prompts, news):
+        assert done[rid] == reference_tokens(params, p, n)
+    st = eng.engine_stats
+    assert eng.blocks.num_allocated() == 0
+    assert eng.blocks.window_allocated() == 0
+    assert st["window_pages_released"] > 0 and st["sparse_rows_dense"] > 0
+    assert 0 < st["sparse_pairs_selected"] < st["index_pairs"]
+    assert st["prefix_cache"].startswith("off")
+    assert 0 < st["moe_pairs_held"] < 4 * st["moe_pairs"]
+
+
+def test_counters_equal_hand_counts_for_two_requests_alone(tiny):
+    """Each request alone, so that every tick is known. A prompt of 37 in
+    chunks of 32 and 5, then 7 decode rows: every tick's sequence holds
+    more than 8 keys, so its rows select (a row at p keeps min(p + 1, 8))
+    and none walks densely. A prompt of 5 and one decode row stay under 8:
+    the dense walk. The window layers count the keys and pairs inside a
+    window of 5; every count is times the layers of its kind (2 full, 3
+    sliding)."""
+    cfg, params = tiny
+    eng = engine(cfg, params)
+    eng._next_is_determined = lambda cur: False     # no void row
+    eng.submit(prompt_of(37, seed=2), max_new_tokens=8)
+    eng.run()
+    st = dict(eng.stats)
+    keys = 32 + 37 + sum(range(38, 45))
+    pairs = 37 * 38 // 2 + sum(range(38, 45))
+    assert (st["steps"], st["tokens_computed"]) == (9, 44)
+    assert (st["index_keys"], st["index_pairs"]) == (2 * keys, 2 * pairs)
+    assert st["sparse_pairs_selected"] == 2 * (36 + 24 * 8 + 5 * 8 + 7 * 8)
+    assert st["sparse_rows_dense"] == st["attn_keys_latent"] == 0
+    assert st["attn_keys_latent_window"] == 3 * (32 + 9 + 7 * 5)
+    assert st["attn_pairs_latent_window"] == 3 * (
+        (1 + 2 + 3 + 4) + 28 * 5 + 5 * 5 + 7 * 5)
+    assert st["index_pages_live"] == st["latent_pages_live"] > 0
+    # while it ran: the pages wholly behind position 43's window
+    assert st["window_pages_released"] == (43 - 4) // 8
+    eng.submit(prompt_of(5, seed=2), max_new_tokens=2)
+    eng.run()
+    new = {k: eng.stats[k] - st[k] for k in st}
+    assert (new["index_keys"], new["sparse_pairs_selected"]) == (0, 0)
+    assert new["sparse_rows_dense"] == 2 * (5 + 1)
+    assert new["attn_keys_latent"] == 2 * (5 + 6)
+    assert new["attn_pairs_latent"] == 2 * (15 + 6)
+
+
+@pytest.mark.parametrize("pallas", [False, True])
+def test_a_preempted_sequence_resumes_through_both_pools(tiny, pallas):
+    cfg, params = tiny
+    eng = engine(cfg, params, max_batch=3, num_blocks=16, pallas=pallas)
+    prompts = [prompt_of(n, seed=n) for n in (60, 50, 44)]
+    rids = [eng.submit(p, max_new_tokens=16) for p in prompts]
+    done = {d.rid: d.output_tokens for d in eng.run()}
+    assert eng.engine_stats["preemptions"] >= 1
+    for rid, p in zip(rids, prompts):
+        assert done[rid] == reference_tokens(params, p, 16)
+    assert eng.blocks.num_allocated() == 0
+    assert eng.blocks.window_allocated() == 0
+
+
+@pytest.mark.parametrize("what, kw", [
+    ("int8 pages", dict(quant_kv=True)),
+    ("LoRA", dict(adapter_slots=2)),
+    ("a draft model", dict(draft=(None, None))),
+])
+def test_what_the_engine_refuses_under_this_plan_raises_at_construction(
+        tiny, what, kw):
+    cfg, params = tiny
+    with pytest.raises(NotImplementedError, match="layer plan"):
+        engine(cfg, params, **kw)
+
+
+def test_page_hand_off_is_refused_and_two_widths_in_one_pool_too(tiny):
+    cfg, params = tiny
+    eng = engine(cfg, params)
+    with pytest.raises(NotImplementedError, match="layer plan"):
+        eng.extract_pages(prompt_of(40))
+    # a pool is one array: two full kinds of different cache rows have none
+    wide = dataclasses.replace(cfg.layer_plan[1].latent, kv_lora_rank=200)
+    plan = (cfg.layer_plan[0],
+            dataclasses.replace(cfg.layer_plan[1], latent=wide),
+            *cfg.layer_plan[2:])
+    with pytest.raises(NotImplementedError, match="row widths"):
+        engine(dataclasses.replace(cfg, layer_plan=plan), params)
+
+
+def test_the_tick_runs_under_the_new_scopes(tiny):
+    """The named scopes the per-layer metrics read, in the tick's program."""
+    cfg, params = tiny
+    eng = engine(cfg, params)
+    fn = eng._build_step(32, 4)
+    B, mb = 4, eng.max_blocks_per_seq
+    z = lambda *s: np.zeros(s, np.int32)
+    text = fn.lower(
+        eng.params, eng._key_cache, eng._value_cache, None, z(32),
+        (z(B, mb), z(B, mb)), z(B + 1), z(B), z(B), eng._rope_emb,
+        np.ones((B,), np.float32), np.ones((B,), np.float32),
+        np.zeros((B, 2), np.uint32), np.ones((B,), bool), (), None, None,
+        eng._last_out, np.full((32,), -1, np.int32)).as_text(debug_info=True)
+    for scope in ("index_q", "index_k", "index_scores", "index_select",
+                  "paged_attention_sparse", "paged_attention_latent_window",
+                  "paged_attention_latent/", "latent_q", "latent_kv",
+                  "latent_out", "attn_gate", "shared_expert"):
+        assert scope in text, scope

@@ -98,7 +98,9 @@ def test_kimi_config_carries_the_latent_plan_and_the_share():
     assert [(s.attn, s.heads, s.ffn) for s in cfg.layer_plan] == [
         ("latent", 8, "dense"), ("latent", 8, "sparse"),
         ("latent", 8, "sparse")]
-    assert cfg.latent and cfg.latent_width == 48 and cfg.rope_dim == 8
+    ls = cfg.one_latent()
+    assert ls.width == 48 and cfg.rope_dim == ls.qk_rope_head_dim == 8
+    assert {s.latent for s in cfg.layer_plan} == {ls}
     assert (cfg.num_experts, cfg.held, cfg.top_k, cfg.router_bias) == (
         16, (4, 4), 2, True)
     m = 0.1 * np.log(8) + 1
@@ -496,12 +498,17 @@ def test_page_hand_off_is_refused_for_a_latent_plan(tiny):
     ("mixed with full attention", dict(layer_plan=(
         L.LayerSpec("latent", 8), L.LayerSpec("full", 8)), num_layers=2)),
     ("more than one key row", dict(num_kv_heads=2)),
-    ("a latent layer without its widths", dict(kv_lora_rank=0)),
+    # neither on its spec (`LayerSpec.latent`) nor among the config's own
+    ("a latent layer without its widths", dict(
+        kv_lora_rank=0, num_layers=1,
+        layer_plan=(L.LayerSpec("latent", 8),))),
     ("a share outside the experts", dict(experts_held=(14, 4))),
 ])
 def test_what_a_latent_config_refuses_raises_at_construction(tiny, what, kw):
     cfg, _ = tiny
-    with pytest.raises((ValueError, NotImplementedError)):
+    with pytest.raises((ValueError, NotImplementedError),
+                       match="LayerSpec.latent" if "widths" in what
+                       else None):
         dataclasses.replace(cfg, **kw)
 
 
