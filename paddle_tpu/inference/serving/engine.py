@@ -96,16 +96,19 @@ key scored, the ``topk`` best selected exactly) before its read. Every
 launch, the decode tick's and the tick's with a prefill chunk, goes through
 ``paged_latent_attention``, which states the one rule of which read a row
 takes (the dense walk; the sparse read over the selected rows for a
-sequence that holds more than ``topk`` keys; the windowed walk) and in
+sequence that holds more than ``topk`` keys, gathered or, for a chunk under
+the crossing, through the masked walk; the windowed walk) and in
 which form (absorbed; the expanded form for chunks was measured and is not
 taken). Counters (``_plan_keys``): ``attn_keys_latent`` / ``attn_pairs_latent``
 of the dense walk, ``attn_*_latent_window`` of the windowed one,
 ``index_keys`` / ``index_pairs`` scored, ``sparse_pairs_selected`` read,
 ``sparse_rows_dense`` rows that had no selection to make,
-``index_pages_live``. Without a window layer the pages are of one kind and
-one lifetime, so the prefix cache, copy-on-write and preemption work as for
-a uniform model; with one the prefix cache is off, as for every window
-pool. Page hand-off is refused as for every plan. A config that holds a share of its
+``sparse_rows_walked`` / ``sparse_pairs_walked`` the selecting chunk rows
+that read their keys through the masked walk and the (row, key) pairs it
+multiplied for them, ``index_pages_live``. Without a window layer the pages
+are of one kind and one lifetime, so the prefix cache, copy-on-write and
+preemption work as for a uniform model; with one the prefix cache is off,
+as for every window pool. Page hand-off is refused as for every plan. A config that holds a share of its
 routed experts (``cfg.experts_held``) routes over all of them and computes
 its own (``llama.routed_ffn_load``); ``moe_pairs_held`` counts those pairs,
 and ``moe_compact_overflow`` the launches that had more of them than
@@ -134,7 +137,8 @@ from ...observability import emit as _emit
 from ...observability import tracing as _tracing
 from ...ops.kernels.serving_attention import (paged_index_select,
                                               paged_latent_attention,
-                                              paged_layer_attention)
+                                              paged_layer_attention,
+                                              sparse_walk_keys)
 from ...ops.pallas import flash_attention as FA
 from ...ops.pallas import fused_ffn as FF
 from ...ops.pallas import fused_sample as FS
@@ -533,10 +537,13 @@ class PagedServingEngine:
                 # index keys read and (row, key) pairs scored (the causal
                 # keys and pairs of the sequences that select), the pairs
                 # selected, the rows that had no selection to make and
-                # took the dense walk, and the pages that carry index keys
+                # took the dense walk, the selecting rows that took the
+                # masked walk and the pairs it multiplied for them, and
+                # the pages that carry index keys
                 self.stats.update(index_keys=0, index_pairs=0,
                                   sparse_pairs_selected=0,
-                                  sparse_rows_dense=0, index_pages_live=0)
+                                  sparse_rows_dense=0, sparse_rows_walked=0,
+                                  sparse_pairs_walked=0, index_pages_live=0)
         elif plan:
             # keys and (row, key) pairs inside the masks, summed over ticks
             # and over the layers of the kind, and the keys a causal mask
@@ -2021,12 +2028,25 @@ class PagedServingEngine:
             under = np.clip(k - past, 0, this)   # rows that see <= k keys
             chosen = (under * past + under * (under + 1) // 2
                       + (this - under) * k)
+            # of the selecting sequences the chunks under the crossing
+            # read through the masked walk, which multiplies their rows'
+            # causal pairs (the kernels' rule: the stock read gathers)
+            rows_walked = pairs_walked = 0
+            for spec in cfg.layer_plan if self.pallas else ():
+                if spec.attn == "latent" and spec.latent.index is not None:
+                    walk = sel & (this > 1) & (each_keys <= sparse_walk_keys(
+                        spec.heads, self._row_widths[0],
+                        spec.latent.kv_lora_rank, k))
+                    rows_walked += int(this[walk].sum())
+                    pairs_walked += int(each_pairs[walk].sum())
             out = {"attn_keys_latent": n_full * int(each_keys[~sel].sum()),
                    "attn_pairs_latent": n_full * int(each_pairs[~sel].sum()),
                    "index_keys": n_full * int(each_keys[sel].sum()),
                    "index_pairs": n_full * int(each_pairs[sel].sum()),
                    "sparse_pairs_selected": n_full * int(chosen[sel].sum()),
-                   "sparse_rows_dense": n_full * int(this[~sel].sum())}
+                   "sparse_rows_dense": n_full * int(this[~sel].sum()),
+                   "sparse_rows_walked": rows_walked,
+                   "sparse_pairs_walked": pairs_walked}
         elif self.latent:
             out = {"attn_keys_latent": n_full * keys,
                    "attn_pairs_latent": n_full * pairs}

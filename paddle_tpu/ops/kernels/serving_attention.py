@@ -772,9 +772,39 @@ def _write_latent_rows(pool, layer, rows, past, this, cu, block_tables,
     return write_rows(pool, layer, tok_page, tok_pos % bs, rows)
 
 
-# rows of a block of the sparse read: a block gathers RB x topk cache rows
-# (671 MB at 256 x 2,048 x 640 lanes)
+# rows of a block of the sparse read's GATHER: a block gathers RB x topk
+# cache rows (671 MB at 256 x 2,048 x 640 lanes). The rows that still
+# gather: a selecting sequence's ONE row of a tick (a decode row: one block
+# over the sequences' first rows), and the rows of a selecting chunk whose
+# sequence holds more keys than `sparse_walk_keys` (by row blocks); the
+# other chunks' rows take the masked walk and gather nothing.
 _SPARSE_ROWS = 256
+# The two sparse reads of a chunk as chip constants (TPU v5e; PR 44's chip
+# readings at the cell's widths: one chunk of 2,016 rows, 128 heads, W 640,
+# C 512, topk 2,048, beside 31 one-row sequences; PERF.md section 6).
+# The masked walk, every row charged its sequence's keys after the tick as
+# `sparse_walk_keys` charges it: 84.6 ms at 22,016 keys and 121.6 ms at
+# 32,016 (3.5 ms + 3.69 ms a thousand keys; 25.7 ms at 6,016) = 155 and
+# 157 TFLOP/s by that count (148 and 152 over the causal pairs alone; the
+# walk without a mask: 79.8 and 115.1 ms).
+_WALK_FLOPS = 1.55e14
+# The gather by row blocks with the attention over the gathered rows: 96.0
+# ms for the chunk at every context = 23.2 ns a (row, selected key).
+_GATHER_ROW_S = 2.32e-8
+
+
+def sparse_walk_keys(heads: int, width: int, value_dim: int,
+                     topk: int) -> int:
+    """The CROSSING: the most keys a selecting sequence may hold after a
+    tick for the masked walk to be the cheaper read of its chunk's rows.
+    For one query row the walk multiplies every key the sequence holds,
+    keys x heads x 2 x (width + value_dim) FLOPs at `_WALK_FLOPS`; the
+    gather moves `topk` cache rows at `_GATHER_ROW_S` each, the attention
+    behind the gather counted in. From shapes and the two constants alone:
+    the device's rule (`paged_latent_attention`) and the host's count of it
+    (`PagedServingEngine._plan_keys`) both ask here."""
+    return min(int(topk * _GATHER_ROW_S * _WALK_FLOPS
+                   / (heads * 2 * (width + value_dim))), 2 ** 31 - 1)
 
 
 def _by_row_blocks(fn, args, rows: int):
@@ -814,8 +844,12 @@ def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
     `index_select`. Returns (positions [tok, topk] int32 ascending, -1
     behind a row's last and everywhere in a row that takes the dense walk;
     the page of each selected key by its row's block table [tok, topk];
-    sparse [B] bool: the sequences whose rows were selected for; pool).
-    `block_size` divides 128."""
+    sparse [B] bool: the sequences whose rows were selected for; the
+    selection itself as `select_topk` made it, a bit a key position of the
+    table (`sparse_index.pack_mask`: [tok, blocks of 128 keys, 4] uint32,
+    the words the positions are counted from), which the masked walk reads
+    where the gather reads the positions; pool). `block_size` divides
+    128."""
     from ..pallas import paged_attention_latent as PL
     from . import sparse_index
     L_, num_blocks, _, bs, ID = pool.shape
@@ -864,18 +898,22 @@ def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
             per = sparse_index.BLOCK // bs
             mine = jnp.pad(block_tables, ((0, 0), (0, -max_blocks % per))
                            )[tok_b].reshape(token_num, -1, per)
-            pos, pages = sparse_index.selected_positions(
-                sparse_index.select_topk(scores, visible, topk), topk,
-                carry=mine)
+            bits = sparse_index.pack_mask(
+                sparse_index.select_topk(scores, visible, topk))
+            pos, pages = sparse_index.selected_positions(bits, topk,
+                                                         carry=mine)
             sub = jnp.maximum(pos, 0) % sparse_index.BLOCK // bs
             page = pages[0]
             for i in range(1, per):
                 page = jnp.where(sub == i, pages[i], page)
-            return pos, page
+            return pos, page, bits
 
     none = jnp.full((token_num, topk), -1, jnp.int32)
-    idx, page = lax.cond(jnp.any(sparse), select, lambda: (none, none))
-    return idx, page, sparse, pool
+    idx, page, bits = lax.cond(
+        jnp.any(sparse), select,
+        lambda: (none, none, jnp.zeros(
+            (token_num, -(-max_kv // sparse_index.BLOCK), 4), jnp.uint32)))
+    return idx, page, sparse, bits, pool
 
 
 def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
@@ -903,15 +941,32 @@ def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
       their window bound; the table's entries behind every window may be
       -1 (`paged_attention_latent_window` inside `paged_attention`);
     - SPARSE (`select` = (positions [tok, k], their pages [tok, k], sparse
-      [B]) from `paged_index_select`: a layer whose spec has an index): the
-      rows of a
-      sequence that holds more than k keys after this tick attend over
-      their k selected cache rows alone, gathered from the pool by position
-      (`paged_attention_sparse`); a row that sees at most k keys has them
-      all selected. The rows of the other sequences take the DENSE walk.
-      Which of the two reads runs is decided on the device by the tick's
-      own lengths (a tick without a sparse sequence gathers nothing, one
-      without a dense sequence walks nothing);
+      [B], the selection's bits [tok, max_kv / 128, 4]) from
+      `paged_index_select`: a layer whose spec has an index): the rows of a
+      sequence that holds
+      more than k keys after this tick attend over their k selected cache
+      rows alone (`paged_attention_sparse`); a row that sees at most k keys
+      has them all selected. The rows of the other sequences take the DENSE
+      walk. The selected rows are GATHERED or WALKED, in three launches,
+      each under its own `lax.cond` on the tick's own lengths (a tick
+      without such a sequence launches nothing for it; the stock read
+      gathers every selecting row and needs no mask):
+      * a selecting sequence with ONE row this tick (a decode row): the
+        GATHER of its k cache rows by position, launched over the
+        sequences' first rows (at most `max_batch` of them);
+      * a selecting CHUNK that holds at most `sparse_walk_keys` keys after
+        this tick: the MASKED WALK (`paged_attention_latent`'s mixed walk
+        with the selection mask ANDed into what a row sees): every page of
+        the context read once for a tile of rows, no cache row moved twice,
+        at context / k times the gather's FLOPs;
+      * a selecting chunk beyond that crossing: the gather again, by blocks
+        of `_SPARSE_ROWS` rows.
+      The crossing is a formula of shapes and two chip constants, nobody's
+      setting: for one query row the walk costs keys x H x 2 x (W + C) /
+      `_WALK_FLOPS` seconds and the gather k x `_GATHER_ROW_S`
+      (`sparse_walk_keys`, where both readings stand). Softmax over exactly
+      the selected keys either way, bf16 products, float32 sums,
+      probabilities rounded to the pages' type for p.v;
     - DENSE (everything else): every key up to the row's own
       (`paged_attention_latent`).
 
@@ -1013,20 +1068,42 @@ def paged_latent_attention(q_nope, q_rope, row_tok, wk, wv, pool, layer,
             with jax.named_scope(name):
                 o = dense(this)
         else:
-            idx, page, sparse = select
+            idx, page, sparse, *mask = select
             with jax.named_scope("paged_attention_latent"):
                 o = lax.cond(
                     jnp.any((this > 0) & ~sparse),
                     lambda: dense(jnp.where(sparse, 0, this)),
                     lambda: jnp.zeros((token_num, H, C), q_tok.dtype))
+
+            def read(o, seqs, launch):
+                """`o` with the rows of the sequences `seqs` [B] taken
+                from `launch()`, which runs only in a tick that has one."""
+                mine = (seqs[tok_b] & tok_valid)[:, None, None]
+                return lax.cond(jnp.any(seqs),
+                                lambda: jnp.where(mine, launch(), o),
+                                lambda: o)
+
+            def gathered():
+                return _by_row_blocks(read_rows, (q_tok, idx, page),
+                                      token_num)
+
             with jax.named_scope("paged_attention_sparse"):
-                o = lax.cond(
-                    jnp.any(sparse),
-                    lambda: jnp.where(
-                        (sparse[tok_b] & tok_valid)[:, None, None],
-                        _by_row_blocks(read_rows, (q_tok, idx, page),
-                                       token_num), o),
-                    lambda: o)
+                if not use_pallas:
+                    o = read(o, sparse, gathered)
+                else:
+                    first = jnp.clip(cu[:B], 0, token_num - 1)
+                    o = read(o, sparse & (this == 1), lambda: _by_row_blocks(
+                        read_rows, (q_tok[first], idx[first], page[first]),
+                        B)[tok_b])
+                    if use_pallas != "decode":
+                        chunk = sparse & (this > 1)
+                        walk = chunk & (past + this <= sparse_walk_keys(
+                            H, W, C, idx.shape[1]))
+                        o = read(o, walk, lambda: PL.latent_attention_packed(
+                            q_tok, pool, block_tables, past,
+                            jnp.where(walk, this, 0), cu, sm_scale, layer, C,
+                            mask=mask[0]))
+                        o = read(o, chunk & ~walk, gathered)
     return way_out(o.astype(q_tok.dtype)), pool
 
 

@@ -11,9 +11,11 @@ The selection sorts nothing. `lax.top_k` and `lax.sort` at k = 2,048 of
 (PERF.md section 6, PR 43, has the chip's readings of both beside this
 form's), and `lax.approx_max_k` is another model. Instead the k-th largest
 score's order key is found bit by bit, in 32 counting passes over the
-scores (`select_topk`), and the mask becomes positions by popcounts over
-blocks of 128 keys and one exact product that hands each slot its block
-(`selected_positions`): compares, adds, selects and a matmul.
+scores (`select_topk`), and the mask, packed a bit a key (`pack_mask`: the
+form in which the engine hands a selection on), becomes positions by
+popcounts over blocks of 128 keys and one exact product that hands each
+slot its block (`selected_positions`): compares, adds, selects and a
+matmul.
 """
 from __future__ import annotations
 
@@ -23,7 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["index_scores", "select_topk", "selected_positions"]
+__all__ = ["index_scores", "select_topk", "pack_mask", "unpack_mask",
+           "selected_positions"]
 
 BLOCK = 128         # keys of a block of `selected_positions`
 
@@ -71,16 +74,40 @@ def select_topk(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
                             <= room[:, None]))
 
 
+def pack_mask(mask: jax.Array) -> jax.Array:
+    """A selection mask [T, S] bool as bits: [T, nb, 4] uint32, nb blocks of
+    `BLOCK` keys (S padded up with keys not selected), bit j of word w of
+    block b the key b * 128 + w * 32 + j. What `selected_positions` counts
+    in, and the form in which a selection is handed on (33,280 keys a row
+    in 4 KB)."""
+    T, S = mask.shape
+    mask = jnp.pad(mask, ((0, 0), (0, -S % BLOCK)))
+    bits = mask.reshape(T, -1, 4, 32).astype(jnp.uint32)
+    return jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                   dtype=jnp.uint32)
+
+
+def unpack_mask(words: jax.Array) -> jax.Array:
+    """`pack_mask`'s words [..., nb, 4] uint32 as a byte a key, [..., nb *
+    128] int8 (1: selected)."""
+    u8 = jnp.uint8      # a word's four bytes first: nothing wider than a
+    #                     byte a key is ever laid out
+    octets = ((words[..., None] >> jnp.arange(0, 32, 8, dtype=jnp.uint32))
+              & 0xFF).astype(u8)
+    bits = (octets[..., None] >> jnp.arange(8, dtype=u8)) & u8(1)
+    return bits.astype(jnp.int8).reshape(*words.shape[:-2], -1)
+
+
 _SPREAD_ROWS = 256      # rows of a block of `selected_positions`' product
 
 
-def selected_positions(mask: jax.Array, k: int,
+def selected_positions(words: jax.Array, k: int,
                        carry: Optional[jax.Array] = None):
-    """A selection mask [T, S] with at most k keys a row as positions
-    [T, k] int32, ascending, -1 behind a row's last; with `carry` [T, nb,
-    C] int32 (values under 2 ** 24 that belong to a row's blocks of 128
-    keys: a page table cut into eights), also each slot's block's values
-    [C, T, k].
+    """A selection's bits (`pack_mask`'s `words` [T, nb, 4] uint32) with at
+    most k keys a row as positions [T, k] int32, ascending, -1 behind a
+    row's last; with `carry` [T, nb, C] int32 (values under 2 ** 24 that
+    belong to a row's blocks of 128 keys: a page table cut into eights),
+    also each slot's block's values [C, T, k].
 
     No sort, no gather and no scatter: on this chip a gather of one word a
     slot and a scatter of one row a block are both the slowest way to move
@@ -96,13 +123,8 @@ def selected_positions(mask: jax.Array, k: int,
     the block the slot's key is the (slot - count before)-th set bit: the
     word by the words' running popcounts, the bit by five halvings of the
     word, each a popcount of its lower half; selects, no indexing."""
-    T, S = mask.shape
-    pad = -S % BLOCK
-    mask = jnp.pad(mask, ((0, 0), (0, pad)))
-    nb = (S + pad) // BLOCK
     u32, i32, f32 = jnp.uint32, jnp.int32, jnp.float32
-    bits = mask.reshape(T, nb, 4, 32).astype(u32)
-    words = jnp.sum(bits << jnp.arange(32, dtype=u32), axis=-1, dtype=u32)
+    T, nb, _ = words.shape
     count = jnp.sum(lax.population_count(words).astype(i32), axis=-1,
                     dtype=i32)
     upto = jnp.cumsum(count, axis=-1, dtype=i32)                # [T, nb]

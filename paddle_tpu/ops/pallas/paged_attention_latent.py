@@ -33,7 +33,23 @@ page copies are its own):
   lanes, 1,024 of them the value row);
 - at more than 64 heads a work item's row tile holds fewer tokens
   (`mixed_tokens`: 16 at 128 heads), so that a tile stays 2,048 rows;
-- no int8 pages and no block-causal mask: no latent model served has them.
+- no int8 pages and no block-causal mask: no latent model served has them;
+- the MASKED WALK (`latent_attention_packed(mask=...)`, static): the mixed
+  walk with a selection mask, a row a token over the table's key positions
+  (handed in as bits, `sparse_index.pack_mask`, and widened to 8 bits for
+  the items' rows alone), cut into the walk's key blocks ([items, key
+  blocks, tq, span]: an item's whole mask is one VMEM block, 0.5 MB at 16
+  tokens x 33,280 keys). Inside a key block the tile's [tq, span] bytes are
+  widened and each token's row is broadcast over its H heads' sublanes (row
+  r = t * H + h), then ANDed into what the row sees; a row may see no key
+  of a block, so the probabilities are zeroed as under a window. It is the
+  third way to read a sparse layer's selected keys (the other two gather):
+  which one a row takes is `serving_attention.paged_latent_attention`'s
+  rule, whose cost formula, keys x H x 2 x (W + C) / `_WALK_FLOPS` against
+  topk x `_GATHER_ROW_S`, reads this walk's measured rate. Without a mask
+  the kernel traces to what it was before the mask existed: Kimi's chunk
+  walk, dots3's window walks and the dense first chunk are the same
+  instructions.
 
 Beside the walks, for a latent layer with a learned sparse index
 (`models.llama.IndexSpec`): the page write serves the index keys' pool as
@@ -41,10 +57,11 @@ it serves the latent pool (`write_latent_pages`: a row of 128 lanes), and
 `index_scores_packed` is the INDEX WALK of a chunk: a work item's tokens'
 index heads against its sequence's index keys, ReLU, the heads' weighted
 sum, [rows, keys] in float32, a key tile at a time, so that [rows, heads,
-keys] never exists. The exact selection and the read over the selected
-rows are plain XLA (`ops/kernels/sparse_index.py`,
+keys] never exists. The exact selection and the gathered read over the
+selected rows are plain XLA (`ops/kernels/sparse_index.py`,
 `serving_attention.paged_latent_attention`, which also states the one rule
-of which read a row takes: dense, sparse or window).
+of which read a row takes: dense, sparse (gathered or the masked walk) or
+window).
 
 W is the pool's row width, whole lanes: the engine pads a row of 576
 values (512 + 64) to 640 with zeros, which add nothing to a score and are
@@ -66,6 +83,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..kernels.sparse_index import unpack_mask
 from .flash_attention import NEG_INF, _i32, available, count_launch
 from .paged_attention import (_STAT_LANES, _loop_i32, _work_items,
                               mixed_items)
@@ -258,19 +276,33 @@ def latent_attention(q_rows, pool, block_tables, seq_lens_decoder,
       jnp.asarray(layer, jnp.int32).reshape(1), q_rows, pool)
 
 
+def _spread_over_heads(mask, tokens: int, heads: int):
+    """A tile's selection `mask` [tq, span] int8 (a row a token) as the
+    keys each query row may see, [tokens * heads, span] bool, row r =
+    t * H + h: token t's row broadcast over its heads' sublanes."""
+    mask = mask.astype(jnp.int32)
+    rows = [jnp.broadcast_to(mask[t:t + 1], (heads, mask.shape[1]))
+            for t in range(tokens)]
+    return (rows[0] if tokens == 1 else jnp.concatenate(rows, axis=0)) != 0
+
+
 def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
-                  used_ref, q_ref, pool, o_ref, kbuf, sems, acc, m_sc, l_sc,
-                  *,
+                  used_ref, q_ref, *refs,
                   sm_scale: float, block_size: int, pages: int, heads: int,
-                  small: int, value_dim: int, window: int = 0):
+                  small: int, value_dim: int, window: int = 0,
+                  masked: bool = False):
     """One work item j of a mixed launch: the query rows of sequence
     seq[j] from chunk offset t0[j] on (row r = t * H + h), against that
     sequence's key blocks up to the tile's own causal limit. An item with
     at most `small` live tokens computes on its first small * H rows. An
     item behind the last one in use (`used_ref`) does nothing: its blocks
     are the last used item's (the index maps say so), which it must leave
-    as they are."""
+    as they are. `masked` (static): the MASKED WALK; the first of `refs`
+    is then the item's selection mask [1, key blocks, tq, span] int8, and
+    a row sees of a key block only the keys its token selected."""
     del used_ref
+    mask_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
+    pool, o_ref, kbuf, sems, acc, m_sc, l_sc = refs
     j = pl.program_id(0)
     b = seq_ref[j]
     t0 = t0_ref[j]
@@ -314,10 +346,15 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
             fetch(i, slot, wait=True)
             kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
                       + i * _i32(span))
+            ok = _sees(kv_abs, pos, window)
+            if masked:
+                ok &= _spread_over_heads(mask_ref[0, i], rows // heads, heads)
+            # (under a mask, as under a window, a row may see no key of
+            # the walk's first blocks)
             m, l, a = _block_products(
-                q_ref[0, :rows], kbuf, slot, _sees(kv_abs, pos, window),
-                sm_scale, value_dim, m_sc[:rows, :1], l_sc[:rows, :1],
-                acc[:rows], windowed=window > 0)
+                q_ref[0, :rows], kbuf, slot, ok, sm_scale, value_dim,
+                m_sc[:rows, :1], l_sc[:rows, :1], acc[:rows],
+                windowed=window > 0 or masked)
             acc[:rows] = a
             m_sc[:rows] = jnp.broadcast_to(m, (rows, _STAT_LANES))
             l_sc[:rows] = jnp.broadcast_to(l, (rows, _STAT_LANES))
@@ -349,12 +386,18 @@ def latent_attention_packed(q_tok, pool, block_tables, seq_lens_decoder,
                             seq_lens_this_time, cu_seqlens_q,
                             sm_scale: float, layer, value_dim: int,
                             interpret: Optional[bool] = None,
-                            window: int = 0):
+                            window: int = 0, mask=None):
     """The mixed launch, on the packed token stream: q_tok [token_num, H,
     W], sequence b's `seq_lens_this_time[b]` tokens at rows cu_seqlens_q[b]
     on, its token t at position `seq_lens_decoder[b] + t`, causal, under
-    `window` W > 0 (static) over the last W keys. Returns [token_num, H,
-    value_dim], rows that are no sequence's token 0."""
+    `window` W > 0 (static) over the last W keys. `mask` (a selection over
+    the table's key positions as bits, [token_num, blocks of 128 keys, 4]
+    uint32 as `sparse_index.pack_mask` lays them; or None: static) makes
+    it the MASKED WALK: row t sees of the keys above only those whose bit
+    is set, and one with none set comes back 0. Without a mask nothing of
+    it is traced: no operand, no scratch, the same kernel as before it
+    existed. Returns [token_num, H, value_dim], rows that are no
+    sequence's token 0."""
     _check(q_tok, pool, value_dim)
     token_num, H, W = q_tok.shape
     B = block_tables.shape[0]
@@ -388,9 +431,25 @@ def latent_attention_packed(q_tok, pool, block_tables, seq_lens_decoder,
         lambda j, tb, pa, th, ly, sq, t0_, used_: (
             jnp.minimum(j, used_[0] - _i32(1)), _i32(0), _i32(0)),
         memory_space=pltpu.VMEM)
+    operands, in_specs = [q_items], [row(W)]
+    if mask is not None:
+        # an item's tokens' mask rows cut into the walk's key blocks:
+        # [items, key blocks, tq, span], an item's whole in VMEM (0.5 MB at
+        # 16 tokens x 33,280 keys), block i of it read by number
+        span, blocks = pages * bs, tables.shape[1] // pages
+        m = unpack_mask(mask[row_tok])                        # [items, tq, S]
+        m = jnp.pad(m, ((0, 0), (0, 0), (0, max(
+            blocks * span - m.shape[-1], 0))))[..., :blocks * span]
+        operands.append(m.reshape(items, tq, blocks, span
+                                  ).transpose(0, 2, 1, 3))
+        in_specs.append(pl.BlockSpec(
+            (1, blocks, tq, span),
+            lambda j, tb, pa, th, ly, sq, t0_, used_: (
+                jnp.minimum(j, used_[0] - _i32(1)), _i32(0), _i32(0),
+                _i32(0)), memory_space=pltpu.VMEM))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7, grid=(items,),
-        in_specs=[row(W), pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=row(value_dim),
         scratch_shapes=[
             pltpu.VMEM((2, pages, bs, W), pool.dtype),
@@ -401,16 +460,25 @@ def latent_attention_packed(q_tok, pool, block_tables, seq_lens_decoder,
     kernel = functools.partial(
         _mixed_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
         pages=int(pages), heads=int(H), small=min(_SMALL_TOKENS, tq),
-        value_dim=int(value_dim), window=int(window))
+        value_dim=int(value_dim), window=int(window),
+        masked=mask is not None)
     count_launch()
-    o_items = pl.pallas_call(
-        kernel, name="paged_attention_latent_mixed", grid_spec=grid_spec,
+    call = dict(
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((items, tq * H, value_dim),
                                        q_tok.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret,
-    )(tables, past, this, jnp.asarray(layer, jnp.int32).reshape(1), seq, t0,
-      used.reshape(1), q_items, pool)
+        interpret=interpret)
+    # (a site a name, each a literal: what a trace calls the launch)
+    if mask is None:
+        launch = pl.pallas_call(kernel, name="paged_attention_latent_mixed",
+                                **call)
+    else:
+        launch = pl.pallas_call(kernel, name="paged_attention_latent_masked",
+                                **call)
+    o_items = launch(tables, past, this,
+                     jnp.asarray(layer, jnp.int32).reshape(1), seq, t0,
+                     used.reshape(1), *operands, pool)
     o_items = o_items.reshape(items, tq, H, value_dim)
     item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
     return jnp.where(tok_valid, o_items[item, tok_local % tq], 0

@@ -784,8 +784,8 @@ def test_kimi_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
 
 
 @pytest.mark.parametrize("launch", [
-    "decode_window", "mixed_window", "decode_full", "mixed_full", "index",
-    "write_index"])
+    "decode_window", "mixed_window", "decode_full", "mixed_full",
+    "masked_full", "index", "write_index"])
 def test_sparse_latent_walks_compile(one_chip, launch):
     """What dots3-note-prev's two kinds of latent layer launch, at the
     cell's shapes (32 slots, tables of 2,080 entries, a 2,048-token
@@ -793,9 +793,10 @@ def test_sparse_latent_walks_compile(one_chip, launch):
     6,144 pages of 16 rows, a row of 1,088 values in 1,152 lanes, 64 heads,
     a window of 513), the dense walks of the full layers at 128 heads (2
     layers x 68,608 pages, 640 lanes; a work item's row tile halves to 16
-    tokens), the index walk of a chunk (64 index heads of 128 against each
-    sequence's 33,280 index keys) and the page write of the index keys (a
-    row of 128 lanes) lower for the chip."""
+    tokens; and the same walk under a selection's bits [2,048, 260, 4], the
+    masked walk of a selecting chunk, PR 44), the index walk of a chunk (64
+    index heads of 128 against each sequence's 33,280 index keys) and the
+    page write of the index keys (a row of 128 lanes) lower for the chip."""
     from paddle_tpu.ops.pallas import paged_attention_latent as pl_
     batch, tokens, entries = 32, 2048, 2080
     lens = [((batch, entries), jnp.int32), ((batch,), jnp.int32),
@@ -812,15 +813,17 @@ def test_sparse_latent_walks_compile(one_chip, launch):
                                         layer, vdim, interpret=False, **kw)
         shapes = [_bf16(batch, heads, width), pool, *lens, ((), jnp.int32)]
         name = "paged_attention_latent_decode"
-    elif kind == "mixed":
+    elif kind in ("mixed", "masked"):
         assert pl_.mixed_tokens(tokens, heads) * heads == 2048
-        def fn(q, pool, tables, past, this, cu, layer):
+        def fn(q, pool, tables, past, this, cu, layer, *mask):
             return pl_.latent_attention_packed(
                 q, pool, tables, past, this, cu, 0.0625, layer, vdim,
-                interpret=False, **kw)
+                interpret=False, **kw, **dict(zip(("mask",), mask)))
         shapes = [_bf16(tokens, heads, width), pool, *lens,
                   ((batch + 1,), jnp.int32), ((), jnp.int32)]
-        name = "paged_attention_latent_mixed"
+        if kind == "masked":
+            shapes.append(((tokens, entries * 16 // 128, 4), jnp.uint32))
+        name = "paged_attention_latent_" + kind
     elif kind == "index":
         def fn(qi, w, keys, past, this, cu):
             return pl_.index_scores_packed(qi, w, keys, past, this, cu,
@@ -839,6 +842,52 @@ def test_sparse_latent_walks_compile(one_chip, launch):
                   *[((n,), jnp.int32)] * 3, _bf16(n, 1, 16, 128)]
         name = "paged_cache_write_latent"
     assert name in _compile(fn, one_chip, *shapes).as_text()
+
+
+def test_the_latent_walk_without_a_mask_is_the_kernel_it_was():
+    """`latent_attention_packed` without a mask (Kimi's chunk walk, dots3's
+    window walks and dense first chunk) launches with the parent's
+    signature, written here: seven prefetched scalars, the query items and
+    the pool, one output, five scratch buffers of the shapes they had; the
+    masked walk takes one operand more, the items' mask (handed in as bits)
+    in 8 bits cut into key blocks, and the same scratch."""
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+    batch, tokens, entries, heads, tq = 4, 64, 64, 128, 16
+    S = jax.ShapeDtypeStruct
+    shapes = [S((tokens, heads, 640), jnp.bfloat16),
+              S((2, 64, 1, 16, 640), jnp.bfloat16),
+              S((batch, entries), jnp.int32), S((batch,), jnp.int32),
+              S((batch,), jnp.int32), S((batch + 1,), jnp.int32),
+              S((), jnp.int32)]
+
+    def launch(*mask):
+        def fn(q, pool, tables, past, this, cu, layer, *mask):
+            return pl_.latent_attention_packed(
+                q, pool, tables, past, this, cu, 0.0625, layer, 512,
+                interpret=False, **dict(zip(("mask",), mask)))
+        call, = [e for e in jax.make_jaxpr(fn)(*shapes, *mask).jaxpr.eqns
+                 if e.primitive.name == "pallas_call"]
+        grid = call.params["grid_mapping"]
+        refs = [(str(v.aval.dtype), v.aval.shape)
+                for v in call.params["jaxpr"].invars]
+        assert (grid.num_index_operands, grid.num_outputs) == (7, 1)
+        return len(call.invars), grid.num_inputs, refs
+
+    items = pl_.mixed_tokens(tokens, heads)
+    assert items == tq
+    rows = tq * heads
+    scratch = [("bfloat16", (2, 32, 16, 640)), ("dma_sem", (2,)),
+               ("float32", (rows, 512)), ("float32", (rows, 128)),
+               ("float32", (rows, 128))]
+    operands, inputs, refs = launch()
+    assert (operands, inputs) == (9, 2)
+    assert refs[7:] == [("bfloat16", (1, rows, 640)),
+                        ("bfloat16", (2, 64, 1, 16, 640)),
+                        ("bfloat16", (1, rows, 512))] + scratch
+    operands, inputs, refs = launch(
+        S((tokens, entries * 16 // 128, 4), jnp.uint32))
+    assert (operands, inputs) == (10, 3)
+    assert refs[8] == ("int8", (1, 2, tq, 512)) and refs[11:] == scratch
 
 
 def test_dots3_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
@@ -886,9 +935,11 @@ def test_dots3_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
                                                sharding=one_chip), args)
             compiled = fn.lower(*abstract).compile()
             text = compiled.as_text()
-            # a tick with a chunk runs the index walk and both dense walks
+            # a tick with a chunk runs the index walk, both dense walks
+            # and the masked walk of a selecting chunk
             for name in ("paged_index_scores_chunk",
-                         "paged_attention_latent_mixed"):
+                         "paged_attention_latent_mixed",
+                         "paged_attention_latent_masked"):
                 assert (name in text) == (tok_pad == 2048)
             assert "paged_attention_latent_decode" in text
             m = compiled.memory_analysis()
@@ -905,7 +956,8 @@ def test_dots3_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
     eng.step()                  # a decode row
     assert set(gib) == {2048, 32}
     assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
-    # PR 43's readings, 14.46 and 12.31 (11.42 of them weights and pools)
+    # PR 44's readings, 14.47 and 12.32 (11.42 of them weights and pools;
+    # PR 43's: 14.46 and 12.31)
     assert gib[2048] < 14.5 and gib[32] < 12.35, gib
     print("dots3 depth-5 GiB by tok_pad:", gib)
 

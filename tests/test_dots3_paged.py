@@ -258,7 +258,8 @@ def test_the_selection_is_the_stable_sort_s_with_ties_to_the_lower_position():
                                  k))
     assert np.array_equal(mask, want)
     assert np.array_equal(mask.sum(1), np.minimum(visible.sum(1), k))
-    pos = np.asarray(SI.selected_positions(jnp.asarray(mask), k))
+    pos = np.asarray(SI.selected_positions(
+        SI.pack_mask(jnp.asarray(mask)), k))
     for t in range(T):
         mine = np.flatnonzero(mask[t])
         assert np.array_equal(pos[t, :len(mine)], mine)
@@ -374,6 +375,173 @@ def test_the_sparse_read_with_every_key_selected_is_the_dense_walk():
     assert np.array_equal(np.asarray(sparse), np.asarray(read(None)))
 
 
+# ---- the masked walk: the third way to read a selecting row -------------------
+
+def tick_case(past, this, seed=5):
+    """`D.op_case`'s seeded arrays of a full layer under another tick: slot
+    b holds `past[b]` keys and brings `this[b]` rows, over tables drawn
+    anew from the case's pages (every page of its pools holds seeded
+    rows)."""
+    case = D.op_case(TINY, seed, jnp.float32, "full_attention", False)
+    past, this = np.asarray(past, np.int32), np.asarray(this, np.int32)
+    assert int(this.sum()) == case["rows"].shape[0]
+    need = -(-(past + this) // TINY["engine"]["block_size"])
+    pages = np.random.default_rng(seed).permutation(case["pool"].shape[1])
+    tables = np.full(case["tables"].shape, -1, np.int32)
+    for b, n in enumerate(need):
+        tables[b, :n] = pages[need[:b].sum():need[:b].sum() + n]
+    return {**case, "tables": jnp.asarray(tables), "past": jnp.asarray(past),
+            "this": jnp.asarray(this)}
+
+
+def layer_out(case):
+    """Every row of the tick through the layer's ops as a tick with a chunk
+    calls them on the kernels (interpreter): [tok, H * v]."""
+    c = case
+    cu = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                          jnp.cumsum(c["this"]).astype(jnp.int32)])
+    return np.asarray(D._layer_ops(
+        {n: c[n] for n in ("pool", "index_pool")},
+        {n: c[n] for n in ("q_nope", "q_rope", "rows", "wk", "wv", "qi", "iw",
+                           "ki")},
+        dict(past=c["past"], this=c["this"], cu=cu, tables=c["tables"]),
+        decode=False, topk=c["topk"], window=0, scale=c["scale"])[0])
+
+
+def crossing_at(monkeypatch, keys):
+    """The two rates patched so that a selecting chunk of the fixture's
+    full layers walks up to `keys` keys and gathers beyond."""
+    cfg = D.dots3_config(TINY, jnp.float32)
+    spec = cfg.layer_plan[0]
+    per_key = spec.heads * 2 * (PL.padded_width(spec.latent.width)
+                                + spec.latent.kv_lora_rank)
+    monkeypatch.setattr(SA, "_GATHER_ROW_S", 1.0)
+    monkeypatch.setattr(SA, "_WALK_FLOPS",
+                        (keys + 0.5) * per_key / spec.latent.index.topk)
+    assert SA.sparse_walk_keys(
+        spec.heads, PL.padded_width(spec.latent.width),
+        spec.latent.kv_lora_rank, spec.latent.index.topk) == keys
+
+
+@pytest.mark.parametrize("past, what", [
+    (20, "a chunk that starts mid-page"),
+    (3, "a chunk whose first rows see at most topk keys")])
+def test_the_masked_walk_reads_what_the_gather_reads(past, what,
+                                                     monkeypatch):
+    """One seeded selection, two reads of the same chunk of 28 rows beside
+    three decode rows: through the masked walk (the crossing beyond every
+    length) and through the gather by row blocks (the crossing at 0)."""
+    case = tick_case([50, 6, 30, past], [1, 1, 1, 28])
+    assert past % TINY["engine"]["block_size"] and past + 28 > case["topk"]
+    crossing_at(monkeypatch, 1 << 20)
+    walked = layer_out(case)
+    crossing_at(monkeypatch, 0)
+    gathered = layer_out(case)
+    good, worst = agreement_blockdiff.judge_attention(walked, gathered)
+    assert good and worst < 0.01
+    # two programs: the chunk's rows differ in their last bits, the one-row
+    # sequences' rows gather either way
+    assert np.array_equal(walked[:3], gathered[:3])
+    assert not np.array_equal(walked[3:], gathered[3:])
+
+
+@pytest.mark.parametrize("window", [0, 5])
+def test_the_masked_walk_with_every_visible_key_selected_is_the_walk(window):
+    """Bit for bit (interpreter), a chunk beside one-row sequences: a mask
+    of every key a row sees, and one of every key of the table (as bits,
+    the form `paged_index_select` hands on), against the walk without a
+    mask."""
+    c = tick_case([50, 6, 30, 20], [1, 1, 1, 28])
+    tok, H = c["q_nope"].shape[:2]
+    W, C = c["pool"].shape[-1], c["wk"].shape[0]
+    cu = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                          jnp.cumsum(c["this"]).astype(jnp.int32)])
+    q = jax.random.normal(jax.random.PRNGKey(1), (tok, H, W), jnp.float32)
+    S = c["tables"].shape[1] * TINY["engine"]["block_size"]
+    _, pos, _ = SA._packed_rows(c["past"], c["this"], cu, tok, 4)
+
+    def walk(mask):
+        return np.asarray(PL.latent_attention_packed(
+            q, c["pool"], c["tables"], c["past"], c["this"], cu, c["scale"],
+            jnp.int32(0), C, window=window, mask=mask))
+
+    plain = walk(None)
+    assert np.abs(plain).max() > 0
+    every = jnp.ones((tok, S), bool)
+    assert np.array_equal(walk(SI.pack_mask(
+        jnp.arange(S)[None, :] <= pos[:, None])), plain)
+    assert np.array_equal(walk(SI.pack_mask(every)), plain)
+    # and a row that selected nothing comes back 0
+    none = walk(SI.pack_mask(every.at[5].set(False)))
+    assert not none[5].any() and np.array_equal(np.delete(none, 5, 0),
+                                                np.delete(plain, 5, 0))
+
+
+def test_a_tick_sends_each_selecting_sequence_through_its_own_launch(
+        monkeypatch):
+    """A tick of four slots: a one-row sequence that selects (50 keys), a
+    chunk of 12 rows under the crossing (32 keys after the tick), a chunk
+    of 17 over it (77), and a row that sees 5 keys (the dense walk); the
+    two rates patched so that the crossing lies at 40 keys. Every judged
+    row equals the reference over the op's own selection, the selection is
+    the reference's, and each chunk's rows are bit for bit those of the
+    launch the rule names: the walk's for the one under the crossing, the
+    gather's for the one over it, the gather's for the one-row sequence."""
+    case = tick_case([50, 20, 60, 4], [1, 12, 17, 1])
+    crossing_at(monkeypatch, 1 << 20)
+    walked = layer_out(case)
+    crossing_at(monkeypatch, 0)
+    gathered = layer_out(case)
+    crossing_at(monkeypatch, 40)
+    mixed = layer_out(case)
+    assert np.array_equal(mixed[:1], gathered[:1])
+    assert np.array_equal(mixed[1:13], walked[1:13])
+    assert not np.array_equal(mixed[1:13], gathered[1:13])
+    assert np.array_equal(mixed[13:30], gathered[13:30])
+    assert not np.array_equal(mixed[13:30], walked[13:30])
+    assert np.array_equal(mixed[30:], walked[30:])          # the dense walk
+    res = D.op_outputs(TINY, case, False)
+    good, worst = agreement_blockdiff.judge_attention(res["out"], res["ref"])
+    assert res["pool_ok"] and res["index_pool_ok"] and res["pages_ok"]
+    assert res["selection"].all() and good and worst < 0.01
+
+
+def test_the_walked_rows_and_pairs_equal_hand_counts(tiny, monkeypatch):
+    """`_plan_keys` for that tick, by the formula the device uses: on the
+    kernels the chunk under the crossing walks (12 rows, their causal
+    pairs, in each of the 2 index layers), the chunk over it and the
+    one-row sequence gather, and the stock read walks nothing; a model
+    without an index has no such counter."""
+    from benchmark.drivers import closed_loop_serve_latent as DK
+    cfg, params = tiny
+    crossing_at(monkeypatch, 40)
+    past, this = np.array([50, 20, 60, 4]), np.array([1, 12, 17, 1])
+    keys = engine(cfg, params, pallas=True)._plan_keys(past, this)
+    assert keys["sparse_rows_walked"] == 2 * 12
+    assert keys["sparse_pairs_walked"] == 2 * (12 * 20 + 12 * 13 // 2)
+    assert keys["sparse_pairs_selected"] == 2 * (8 + 12 * 8 + 17 * 8)
+    assert keys["sparse_rows_dense"] == 2 * 1
+    decode = engine(cfg, params, pallas=True)._plan_keys(
+        np.array([50, 20, 60, 4]), np.array([1, 1, 1, 1]))
+    stock = engine(cfg, params)._plan_keys(past, this)
+    for k in ("sparse_rows_walked", "sparse_pairs_walked"):
+        assert decode[k] == stock[k] == 0
+    with open(os.path.join(HERE, "..", "benchmark", "tests", "fixtures",
+                           "configs", "tiny-kimi.json")) as f:
+        kimi = json.load(f)
+    kcfg = dataclasses.replace(DK.kimi_config(kimi, jnp.float32),
+                               dtype=jnp.float32)
+    e = kimi["engine"]
+    eng = PagedServingEngine(
+        kcfg, L.init_params(kcfg, jax.random.PRNGKey(0)),
+        num_blocks=e["num_blocks"], block_size=e["block_size"],
+        max_batch=e["max_batch"], token_budget=e["token_budget"],
+        max_len=e["max_len"], pallas=True)
+    assert not any(k.startswith("sparse_") for k in eng.stats)
+    assert not any(k.startswith("sparse_")
+                   for k in eng._plan_keys(past, this))
+
+
 # ---- the engine --------------------------------------------------------------
 
 @pytest.mark.parametrize("pallas", [False, True])
@@ -401,6 +569,9 @@ def test_engine_equals_the_reference_through_both_pools(tiny, pallas):
     assert eng.blocks.window_allocated() == 0
     assert st["window_pages_released"] > 0 and st["sparse_rows_dense"] > 0
     assert 0 < st["sparse_pairs_selected"] < st["index_pairs"]
+    # the kernels read a selecting chunk through the masked walk
+    assert (st["sparse_rows_walked"] > 0) == pallas
+    assert (st["sparse_pairs_walked"] > st["sparse_rows_walked"]) == pallas
     assert st["prefix_cache"].startswith("off")
     assert 0 < st["moe_pairs_held"] < 4 * st["moe_pairs"]
 
