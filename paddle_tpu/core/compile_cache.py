@@ -1,10 +1,12 @@
 """Where JAX's persistent compilation cache lives.
 
 Entry scripts (`chip_smoke.py`, `__graft_entry__.py`) call `configure()`
-before their first compile; the package never does at import, so tests
-compile uncached. The directory is part of every cache
-key's lookup path, so it is fixed: the one `JAX_COMPILATION_CACHE_DIR`
-names, or one inside the checkout — never a temp name, pid or timestamp.
+before their first compile; the package never does at import. The suite
+names its own in `tests/conftest.py` (the one `JAX_COMPILATION_CACHE_DIR`
+names, or a fixed name under the temporary directory). The directory is
+part of every cache key's lookup path, so it is fixed: the one
+`JAX_COMPILATION_CACHE_DIR` names, or one inside the checkout — never a
+pid or timestamp.
 """
 from __future__ import annotations
 

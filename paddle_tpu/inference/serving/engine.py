@@ -101,7 +101,9 @@ the crossing, through the masked walk; the windowed walk) and in
 which form (absorbed; the expanded form for chunks was measured and is not
 taken). Counters (``_plan_keys``): ``attn_keys_latent`` / ``attn_pairs_latent``
 of the dense walk, ``attn_*_latent_window`` of the windowed one,
-``index_keys`` / ``index_pairs`` scored, ``sparse_pairs_selected`` read,
+``index_keys`` / ``index_pairs`` scored (``index_keys_fetched``: the keys
+the index's two launches copied out of their pages to score them, whole key
+tiles and a chunk's every work item its own), ``sparse_pairs_selected`` read,
 ``sparse_rows_dense`` rows that had no selection to make,
 ``sparse_rows_walked`` / ``sparse_pairs_walked`` the selecting chunk rows
 that read their keys through the masked walk and the (row, key) pairs it
@@ -535,13 +537,14 @@ class PagedServingEngine:
             if self._index is not None:
                 # the sparse index, summed over ticks and index layers:
                 # index keys read and (row, key) pairs scored (the causal
-                # keys and pairs of the sequences that select), the pairs
+                # keys and pairs of the sequences that select), the keys
+                # the kernels' two launches copied to score them, the pairs
                 # selected, the rows that had no selection to make and
                 # took the dense walk, the selecting rows that took the
                 # masked walk and the pairs it multiplied for them, and
                 # the pages that carry index keys
-                self.stats.update(index_keys=0, index_pairs=0,
-                                  sparse_pairs_selected=0,
+                self.stats.update(index_keys=0, index_keys_fetched=0,
+                                  index_pairs=0, sparse_pairs_selected=0,
                                   sparse_rows_dense=0, sparse_rows_walked=0,
                                   sparse_pairs_walked=0, index_pages_live=0)
         elif plan:
@@ -1930,7 +1933,7 @@ class PagedServingEngine:
                 for name, n in walked.items():
                     self.stats[name] += n
             if self.cfg.layer_plan:
-                keys = self._plan_keys(dec_lens, this_lens)
+                keys = self._plan_keys(dec_lens, this_lens, cur.tok_pad)
                 if self.latent:
                     keys["latent_pages_live"] = cur.pool_pages[0]
                 if self._index is not None:
@@ -1999,9 +2002,12 @@ class PagedServingEngine:
             self._update_gauges()
             return events
 
-    def _plan_keys(self, past: np.ndarray, this: np.ndarray) -> dict:
+    def _plan_keys(self, past: np.ndarray, this: np.ndarray,
+                   tok_pad: int = 0) -> dict:
         """One tick's keys inside the masks of a layer plan, from the
-        host's own lengths, summed over the layers of the kind. `keys`:
+        host's own lengths (and, for what the index walk's work items
+        fetch, the tick's padded row count `tok_pad`; 0: the token
+        budget's), summed over the layers of the kind. `keys`:
         the distinct keys a sequence's rows see (a chunk's rows share
         theirs): past + this in a full layer, of those the ones from
         position past - (sliding_window - 1) on in a window layer;
@@ -2039,9 +2045,16 @@ class PagedServingEngine:
                         spec.latent.kv_lora_rank, k))
                     rows_walked += int(this[walk].sum())
                     pairs_walked += int(each_pairs[walk].sum())
+            # what the index's two launches copy out of their pages to
+            # score `index_keys` (the kernels' read: the stock path gathers
+            # every table whole and has no such count)
+            fetched = n_full * PL.index_keys_fetched(
+                past[sel], this[sel], tok_pad or self.token_budget,
+                self.block_size, self.max_blocks_per_seq) if self.pallas else 0
             out = {"attn_keys_latent": n_full * int(each_keys[~sel].sum()),
                    "attn_pairs_latent": n_full * int(each_pairs[~sel].sum()),
                    "index_keys": n_full * int(each_keys[sel].sum()),
+                   "index_keys_fetched": fetched,
                    "index_pairs": n_full * int(each_pairs[sel].sum()),
                    "sparse_pairs_selected": n_full * int(chosen[sel].sum()),
                    "sparse_rows_dense": n_full * int(this[~sel].sum()),

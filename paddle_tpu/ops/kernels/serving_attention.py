@@ -835,12 +835,18 @@ def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
     selection to make and its rows take the dense walk
     (`paged_latent_attention` says the whole rule).
 
-    `use_pallas` as `paged_latent_attention`'s: "decode" and the one-row
-    sequences of a tick with a chunk score a sequence's keys in one
-    batched product; a chunk's rows go through the index walk
-    (`paged_attention_latent.index_scores_packed`), which never holds
-    [rows, heads, keys]; False scores each row against its own gathered
-    keys (CPU tests). Scopes: `cache_write`, `index_scores`,
+    `use_pallas` as `paged_latent_attention`'s, and the same split by the
+    rows a sequence has in the tick: "decode" and the one-row sequences of
+    a tick with a chunk go through the one-row form of the index walk
+    (`paged_attention_latent.index_scores_rows`: a sequence's row against
+    its key blocks), a chunk's rows through the index walk
+    (`index_scores_packed`), which never holds [rows, heads, keys]. Both
+    launches take the stacked pool, the layer and the block table and copy
+    a key tile's whole pages themselves, up to the last key a row sees:
+    nothing lays a sequence's keys out by position and no layer of the
+    pool is sliced or copied. False gathers every sequence's keys by
+    position and scores each row against its own (the stock path: CPU
+    tests, the reference-side form). Scopes: `cache_write`, `index_scores`,
     `index_select`. Returns (positions [tok, topk] int32 ascending, -1
     behind a row's last and everywhere in a row that takes the dense walk;
     the page of each selected key by its row's block table [tok, topk];
@@ -868,27 +874,34 @@ def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
 
     def select():
         with jax.named_scope("index_scores"):
-            # every sequence's index keys by position: 256 B a key at the
-            # published widths, one gather of its pages, no layer sliced
-            flat = pool.reshape(L_ * num_blocks, bs * ID)
-            keys = jnp.take(flat, layer * num_blocks
-                            + jnp.maximum(block_tables, 0), axis=0
-                            ).reshape(B, max_kv, ID)
             if not use_pallas:
+                # every sequence's index keys by position, one gather of
+                # its pages, and each row against its own sequence's
+                flat = pool.reshape(L_ * num_blocks, bs * ID)
+                keys = jnp.take(flat, layer * num_blocks
+                                + jnp.maximum(block_tables, 0), axis=0
+                                ).reshape(B, max_kv, ID)
                 scores = sparse_index.index_scores(qi, keys[tok_b], w)
             else:
+                # the two launches copy a sequence's pages themselves;
+                # a sequence that does not select copies nothing
                 first = jnp.clip(cu[:B], 0, token_num - 1)
-                scores = jnp.sum(jnp.maximum(jnp.einsum(
-                    "bhd,bsd->bhs", qi[first], keys,
-                    preferred_element_type=jnp.float32), 0.0)
-                    * w[first][..., None], axis=1)[tok_b]
-                if use_pallas != "decode":
-                    single = this == 1
-                    scores = jnp.where(
-                        single[tok_b][:, None], scores,
-                        PL.index_scores_packed(
-                            qi, w, keys, past, jnp.where(single, 0, this),
-                            cu))
+                one = sparse & (this == 1)
+                rows = PL.index_scores_rows(
+                    qi[first], w[first], pool, block_tables, past,
+                    one.astype(jnp.int32), layer)              # [B, max_kv]
+                if use_pallas == "decode":
+                    scores = rows[tok_b]
+                else:
+                    # the one-row sequences' rows into the walk's output,
+                    # in place (a `where` over [tok, max_kv] is three
+                    # passes over it)
+                    scores = PL.index_scores_packed(
+                        qi, w, pool, block_tables, past,
+                        jnp.where(sparse & ~one, this, 0), cu, layer)
+                    scores = scores.at[jnp.where(
+                        one, first, token_num + jnp.arange(B))].set(
+                        rows, mode="drop", unique_indices=True)
         with jax.named_scope("index_select"):
             visible = ((jnp.arange(max_kv)[None, :] <= tok_pos[:, None])
                        & (tok_valid & sparse[tok_b])[:, None])

@@ -57,7 +57,17 @@ it serves the latent pool (`write_latent_pages`: a row of 128 lanes), and
 `index_scores_packed` is the INDEX WALK of a chunk: a work item's tokens'
 index heads against its sequence's index keys, ReLU, the heads' weighted
 sum, [rows, keys] in float32, a key tile at a time, so that [rows, heads,
-keys] never exists. The exact selection and the gathered read over the
+keys] never exists; `index_scores_rows` is its ONE-ROW FORM (grid
+(sequence, key block): a decode row's index heads against its sequence's
+key blocks), the split the latent read makes between its decode launch and
+its mixed walk. Both take the stacked index pool [L, num_blocks, 1,
+block_size, ID], the layer and the block table as the walks above do, and
+copy a key tile's whole pages themselves (`_copies`: a page of 16 rows of
+128 lanes is an aligned 4 KB copy), double-buffered from one grid step to
+the next, up to the last key a row of the item sees: no copy of a
+sequence's keys by position exists, and a slot without a row copies
+nothing. `index_keys_fetched` is the host's count of those copies. The
+exact selection and the gathered read over the
 selected rows are plain XLA (`ops/kernels/sparse_index.py`,
 `serving_attention.paged_latent_attention`, which also states the one rule
 of which read a row takes: dense, sparse (gathered or the masked walk) or
@@ -89,8 +99,8 @@ from .paged_attention import (_STAT_LANES, _loop_i32, _work_items,
                               mixed_items)
 
 __all__ = ["latent_attention", "latent_attention_packed",
-           "index_scores_packed", "write_latent_pages", "padded_width",
-           "LANES"]
+           "index_scores_packed", "index_scores_rows", "index_keys_fetched",
+           "write_latent_pages", "padded_width", "LANES"]
 
 LANES = 128
 # key positions of a key block (whole pages), tokens of a work item's row
@@ -531,55 +541,146 @@ def write_latent_pages(pool, layer, pages, lo, hi, new,
 
 
 # ---------------------------------------------------------------------------
-# the index walk of a chunk (a latent layer's sparse index)
+# the index's two launches (a latent layer's sparse index)
 # ---------------------------------------------------------------------------
-_INDEX_KEYS = 512       # index keys of a key tile
+_INDEX_KEYS = 512       # index keys of a key tile of a chunk's walk
 _INDEX_TOKENS = 32      # tokens of a work item's row tile (x IH rows)
+_INDEX_ROW_KEYS = 1024  # index keys of a key block of the one-row form
 
 
-def _index_kernel(seq_ref, t0_ref, past_ref, this_ref, q_ref, w_ref, k_ref,
-                  o_ref, *, heads: int, keys: int):
-    """Key tile i of work item j: the item's tokens' index heads against
-    `keys` index keys of its sequence, I(t, s) = sum_h w[t, h] ReLU(q[t,
-    h] . k[s]). A tile wholly behind the item's last row, and every tile
-    of an item without rows, is written as zeros and multiplies nothing
-    (its blocks are the last needed tile's, so nothing is fetched for it
-    either)."""
-    j, i = pl.program_id(0), pl.program_id(1)
-    b = seq_ref[j]
-    tq = o_ref.shape[1]
-    live = jnp.clip(this_ref[b] - t0_ref[j], _i32(0), _i32(tq))
-    last = past_ref[b] + t0_ref[j] + live - _i32(1)
-    needed = (live > 0) & (i * _i32(keys) <= last)
+def _key_tile(tables_ref, b, i, more, layer, pool, kbuf, sems):
+    """Key tile i of sequence b, [pages * bs, ID], out of its pages of
+    `pool[layer]`: the tiles of one sequence come to consecutive grid steps
+    from tile 0 on, so a tile's page copies were started by the step before
+    it (tile 0's start here) and tile i + 1's start now where `more` says a
+    step will wait for them; buffer i % 2. The copies of a tile are written
+    out once (a loop over them costs the one-row form half its speed,
+    PERF.md section 6, PR 45) at ONE site, a loop over the zero to two
+    tiles that start in this step, and waited for as one, by a descriptor
+    of the whole buffer (a DMA semaphore counts bytes): a launch holds
+    pages + 1 descriptors, not three times the pages, which is what its
+    lowering costs every start-up."""
+    _, pages, bs, ID = kbuf.shape
+
+    def start(t):
+        for c in _copies(tables_ref, b, t, jax.lax.rem(t, _i32(2)), pages,
+                         layer, pool, kbuf, sems):
+            c.start()
+        return t + _i32(1)
+
+    jax.lax.while_loop(
+        lambda t: t < jnp.where(more, i + _i32(2), i + _i32(1)), start,
+        jnp.where(i == 0, _i32(0), i + _i32(1)))
+    slot = jax.lax.rem(i, _i32(2))
+    pltpu.make_async_copy(kbuf.at[slot], kbuf.at[slot], sems.at[slot]).wait()
+    return kbuf[slot].reshape(pages * bs, ID)
+
+
+def _score_tile(tables_ref, b, i, rows, last, layer_ref, q_ref, w_ref, pool,
+                o_ref, kbuf, sems):
+    """Key tile i of sequence b for the query rows q_ref [1, tq * IH, ID]
+    (row r = t * IH + h; w_ref their float32 head weights), the last of
+    which sits at position `last`: I(t, s) = sum_h w[t, h] ReLU(q[t, h] .
+    k[s]) into o_ref [1, tq, keys]. A tile wholly behind `last`, and every
+    tile where `rows` is false, is written as zeros: nothing is copied for
+    it and nothing multiplied."""
+    tq, keys = o_ref.shape[1:]
+    needed = rows & (i * _i32(keys) <= last)
 
     @pl.when(needed)
     def _():
-        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+        k = _key_tile(tables_ref, b, i, (i + _i32(1)) * _i32(keys) <= last,
+                      layer_ref[0], pool, kbuf, sems)
+        s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
                                 precision=jax.lax.Precision.DEFAULT,
                                 preferred_element_type=jnp.float32)
         s = jnp.maximum(s, 0.0) * w_ref[0]                 # [tq * IH, keys]
-        o_ref[0] = jnp.sum(s.reshape(tq, heads, keys), axis=1)
+        o_ref[0] = jnp.sum(s.reshape(tq, -1, keys), axis=1)
 
     @pl.when(jnp.logical_not(needed))
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+def _index_kernel(tables_ref, seq_ref, t0_ref, past_ref, this_ref, layer_ref,
+                  q_ref, w_ref, pool, o_ref, kbuf, sems):
+    """Key tile i of work item j: the item's tokens' index heads against
+    one tile of its sequence's index keys, up to the item's last row."""
+    j, i = pl.program_id(0), pl.program_id(1)
+    b = seq_ref[j]
+    live = jnp.clip(this_ref[b] - t0_ref[j], _i32(0), _i32(o_ref.shape[1]))
+    _score_tile(tables_ref, b, i, live > 0,
+                past_ref[b] + t0_ref[j] + live - _i32(1), layer_ref, q_ref,
+                w_ref, pool, o_ref, kbuf, sems)
+
+
+def _index_row_kernel(tables_ref, past_ref, this_ref, layer_ref, q_ref, w_ref,
+                      pool, o_ref, kbuf, sems):
+    """Key block i of sequence b of the one-row form: its one row's index
+    heads (a row tile of one token) against one block of its index keys,
+    up to its own position."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    _score_tile(tables_ref, b, i, this_ref[b] > 0, past_ref[b], layer_ref,
+                q_ref, w_ref, pool, o_ref, kbuf, sems)
+
+
 def index_tokens(token_num: int) -> int:
     return max(1, min(_INDEX_TOKENS, token_num))
 
 
-def index_scores_packed(qi_tok, w_tok, keys, seq_lens_decoder,
-                        seq_lens_this_time, cu_seqlens_q,
+def _index_tables(pool, block_tables, keys: int):
+    """The index launches' view of a block table: (tables with -1 made 0
+    and padded to whole key tiles, pages of a tile, tiles): a tile is
+    `keys` index keys in whole pages, or the whole table where that is
+    shorter."""
+    if pool.ndim != 5 or pool.shape[2] != 1:
+        raise ValueError(f"index pool {pool.shape}: pass the stacked "
+                         "[L, nb, 1, bs, ID]")
+    pages = _pages(keys, pool.shape[3], block_tables.shape[1])
+    tables = jnp.maximum(block_tables.astype(jnp.int32), 0)
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % pages)))
+    return tables, pages, tables.shape[1] // pages
+
+
+def index_keys_fetched(past, this, token_num: int, block_size: int,
+                       max_blocks: int) -> int:
+    """The index keys the two launches copy out of their pages for one
+    layer, reckoned on the host from the lengths of the sequences that
+    SELECT (`past`, `this` numpy [n]; the others copy nothing): a one-row
+    sequence its past + 1 keys in whole key blocks of the one-row form; a
+    chunk's every work item of `index_tokens` rows the keys up to its last
+    row in whole key tiles (a chunk's later items read its earlier keys
+    again: over `index_keys` this is how many times a key is read). The
+    trip counts of `_index_row_kernel` and `_index_kernel`, from the same
+    constants."""
+    tile = lambda keys: _pages(keys, block_size, max_blocks) * block_size
+    rows, tk, tq = tile(_INDEX_ROW_KEYS), tile(_INDEX_KEYS), index_tokens(
+        token_num)
+    total = 0
+    for p, n in zip(np.asarray(past).tolist(), np.asarray(this).tolist()):
+        if n == 1:
+            total += (p // rows + 1) * rows
+        elif n > 1:
+            last = p + np.minimum(np.arange(0, n, tq) + tq, n) - 1
+            total += int(((last // tk + 1) * tk).sum())
+    return total
+
+
+def index_scores_packed(qi_tok, w_tok, pool, block_tables, seq_lens_decoder,
+                        seq_lens_this_time, cu_seqlens_q, layer,
                         interpret: Optional[bool] = None):
     """The index walk of the packed token stream's chunks: qi_tok
     [token_num, IH, ID] index queries, w_tok [token_num, IH] float32 head
-    weights, keys [B, S, ID] each sequence's index keys by position (the
-    caller gathered its pages: 256 B a position). Returns I [token_num, S]
-    float32; what lies behind a row's own position is not a score (zeros
-    or a later row's) and the caller masks it."""
+    weights, against the index keys in the pages `block_tables[b]` of the
+    stacked index pool `pool[layer]` ([L, nb, 1, bs, ID]: a key tile is
+    `_INDEX_KEYS` keys, whole pages copied inside the launch as the latent
+    walks copy theirs, double-buffered; no copy of a sequence's keys by
+    position exists). Returns I [token_num, max_blocks * bs] float32; what
+    lies behind a row's own position is not a score (zeros or a later
+    row's) and the caller masks it."""
     token_num, IH, ID = qi_tok.shape
-    B, S, _ = keys.shape
+    B, bs = block_tables.shape[0], pool.shape[3]
+    S = block_tables.shape[1] * bs
     if interpret is None:
         interpret = not available()
     cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
@@ -590,9 +691,8 @@ def index_scores_packed(qi_tok, w_tok, keys, seq_lens_decoder,
                                       method="compare_all") - 1, 0, B - 1)
     tok_local = tok_idx - cu[tok_b]
     tq = index_tokens(token_num)
-    tk = min(_INDEX_KEYS, S + -S % LANES)
-    keys = jnp.pad(keys, ((0, 0), (0, -S % tk), (0, 0)))
-    tiles = keys.shape[1] // tk
+    tables, pages, tiles = _index_tables(pool, block_tables, _INDEX_KEYS)
+    tk = pages * bs
     items = mixed_items(token_num, B, tq)
     seq, t0, first = _work_items(cu, this, tq, items, token_num)
     row_tok = jnp.clip((cu[seq] + t0)[:, None]
@@ -600,31 +700,64 @@ def index_scores_packed(qi_tok, w_tok, keys, seq_lens_decoder,
                        0, token_num - 1)                          # [items, tq]
     q_items = qi_tok[row_tok].reshape(items, tq * IH, ID)
     w_items = w_tok.astype(jnp.float32)[row_tok].reshape(items, tq * IH, 1)
-
-    def key_tile(j, i, sq, t0_, pa, th):
-        # the last tile a row of the item reads; an item without rows
-        # stays on tile 0
-        b = sq[j]
-        live = jnp.clip(th[b] - t0_[j], _i32(0), _i32(tq))
-        last = jnp.maximum(pa[b] + t0_[j] + live - _i32(1), _i32(0))
-        return (b, jnp.minimum(i, jax.lax.div(last, _i32(tk))), _i32(0))
-
     rows = lambda w: pl.BlockSpec(
         (1, tq * IH, w), lambda j, i, *_: (j, _i32(0), _i32(0)),
         memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=(items, tiles),
-        in_specs=[rows(ID), rows(1),
-                  pl.BlockSpec((1, tk, ID), key_tile,
-                               memory_space=pltpu.VMEM)],
+        num_scalar_prefetch=6, grid=(items, tiles),
+        in_specs=[rows(ID), rows(1), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, tq, tk), lambda j, i, *_: (j, _i32(0), i),
-                               memory_space=pltpu.VMEM))
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((2, pages, bs, ID), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
     count_launch()
     o_items = pl.pallas_call(
-        functools.partial(_index_kernel, heads=int(IH), keys=int(tk)),
-        name="paged_index_scores_chunk", grid_spec=grid_spec,
+        _index_kernel, name="paged_index_scores_chunk", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((items, tq, tiles * tk), jnp.float32),
         interpret=interpret,
-    )(seq, t0, past, this, q_items, w_items, keys)
+    )(tables, seq, t0, past, this, jnp.asarray(layer, jnp.int32).reshape(1),
+      q_items, w_items, pool)
     item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
     return o_items[item, tok_local % tq, :S]
+
+
+def index_scores_rows(qi_rows, w_rows, pool, block_tables, seq_lens_decoder,
+                      seq_lens_this_time, layer,
+                      interpret: Optional[bool] = None):
+    """The one-row form of the index walk, as the decode launch is the
+    latent walk's: qi_rows [B, IH, ID] the index queries of each sequence's
+    one row at position `seq_lens_decoder[b]` (a slot without one:
+    `seq_lens_this_time[b]` 0), w_rows [B, IH] float32 head weights,
+    against the index keys in the pages `block_tables[b]` of `pool[layer]`,
+    a key block of `_INDEX_ROW_KEYS` keys a grid step, whole pages copied
+    inside the launch. Returns I [B, max_blocks * bs] float32: the row's
+    scores up to its key block's end (behind its own position they are not
+    scores and the caller masks them), zeros behind it and in a slot
+    without a row."""
+    B, IH, ID = qi_rows.shape
+    bs = pool.shape[3]
+    S = block_tables.shape[1] * bs
+    if interpret is None:
+        interpret = not available()
+    tables, pages, blocks = _index_tables(pool, block_tables, _INDEX_ROW_KEYS)
+    keys = pages * bs
+    row = lambda w: pl.BlockSpec(
+        (1, IH, w), lambda b, i, *_: (b, _i32(0), _i32(0)),
+        memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(B, blocks),
+        in_specs=[row(ID), row(1), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, 1, keys), lambda b, i, *_: (b, _i32(0), i),
+                               memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((2, pages, bs, ID), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))])
+    count_launch()
+    return pl.pallas_call(
+        _index_row_kernel, name="paged_index_scores_decode",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, blocks * keys), jnp.float32),
+        interpret=interpret,
+    )(tables, seq_lens_decoder.reshape(-1).astype(jnp.int32),
+      seq_lens_this_time.reshape(-1).astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qi_rows,
+      w_rows.astype(jnp.float32)[..., None], pool)[:, 0, :S]

@@ -785,7 +785,7 @@ def test_kimi_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
 
 @pytest.mark.parametrize("launch", [
     "decode_window", "mixed_window", "decode_full", "mixed_full",
-    "masked_full", "index", "write_index"])
+    "masked_full", "index", "index_decode", "write_index"])
 def test_sparse_latent_walks_compile(one_chip, launch):
     """What dots3-note-prev's two kinds of latent layer launch, at the
     cell's shapes (32 slots, tables of 2,080 entries, a 2,048-token
@@ -795,8 +795,10 @@ def test_sparse_latent_walks_compile(one_chip, launch):
     layers x 68,608 pages, 640 lanes; a work item's row tile halves to 16
     tokens; and the same walk under a selection's bits [2,048, 260, 4], the
     masked walk of a selecting chunk, PR 44), the index walk of a chunk (64
-    index heads of 128 against each sequence's 33,280 index keys) and the
-    page write of the index keys (a row of 128 lanes) lower for the chip."""
+    index heads of 128 against each sequence's 33,280 index keys, copied a
+    key tile's 32 pages at a time out of the stacked index pool of 2 layers
+    x 68,608 pages, PR 45), its one-row form at 32 sequences and the page
+    write of the index keys (a row of 128 lanes) lower for the chip."""
     from paddle_tpu.ops.pallas import paged_attention_latent as pl_
     batch, tokens, entries = 32, 2048, 2080
     lens = [((batch, entries), jnp.int32), ((batch,), jnp.int32),
@@ -824,14 +826,21 @@ def test_sparse_latent_walks_compile(one_chip, launch):
         if kind == "masked":
             shapes.append(((tokens, entries * 16 // 128, 4), jnp.uint32))
         name = "paged_attention_latent_" + kind
-    elif kind == "index":
-        def fn(qi, w, keys, past, this, cu):
-            return pl_.index_scores_packed(qi, w, keys, past, this, cu,
-                                           interpret=False)
+    elif launch == "index":
+        def fn(qi, w, pool, tables, past, this, cu, layer):
+            return pl_.index_scores_packed(qi, w, pool, tables, past, this,
+                                           cu, layer, interpret=False)
         shapes = [_bf16(tokens, 64, 128), ((tokens, 64), jnp.float32),
-                  _bf16(batch, entries * 16, 128), *lens[1:],
-                  ((batch + 1,), jnp.int32)]
+                  _bf16(2, 68608, 1, 16, 128), *lens,
+                  ((batch + 1,), jnp.int32), ((), jnp.int32)]
         name = "paged_index_scores_chunk"
+    elif launch == "index_decode":
+        def fn(qi, w, pool, tables, past, this, layer):
+            return pl_.index_scores_rows(qi, w, pool, tables, past, this,
+                                         layer, interpret=False)
+        shapes = [_bf16(batch, 64, 128), ((batch, 64), jnp.float32),
+                  _bf16(2, 68608, 1, 16, 128), *lens, ((), jnp.int32)]
+        name = "paged_index_scores_decode"
     else:
         n = tokens // 16 + 2 * batch
 
@@ -897,7 +906,7 @@ def test_dots3_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
     tick, 32 rows) compile for the described v5e with both latent pools
     and the index keys in their carry and 32 of 256 experts held, and the
     compiler counts each over 25 % and under the chip's 15.75 GiB, pinned
-    where PR 43 read them, so that a later change of a pool's layout, of
+    where PR 45 read them, so that a later change of a pool's layout, of
     the selection's blocks or of `token_budget` cannot outgrow the chip
     unseen."""
     import json
@@ -942,6 +951,13 @@ def test_dots3_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
                          "paged_attention_latent_masked"):
                 assert (name in text) == (tok_pad == 2048)
             assert "paged_attention_latent_decode" in text
+            # the one-row sequences of either tick score through the
+            # one-row form; both launches copy their pages themselves
+            # (PR 45): no relayout of the stacked index pool, no gather of
+            # every sequence's 33,280 index keys
+            assert "paged_index_scores_decode" in text
+            assert "bf16[137216,2048]" not in text
+            assert "bf16[32,33280,128]" not in text
             m = compiled.memory_analysis()
             gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
                             + m.temp_size_in_bytes
@@ -956,9 +972,10 @@ def test_dots3_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
     eng.step()                  # a decode row
     assert set(gib) == {2048, 32}
     assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
-    # PR 44's readings, 14.47 and 12.32 (11.42 of them weights and pools;
-    # PR 43's: 14.46 and 12.31)
-    assert gib[2048] < 14.5 and gib[32] < 12.35, gib
+    # PR 45's readings, 14.37 and 11.57 (11.42 of them weights and pools;
+    # PR 44's: 14.47 and 12.32, with a copy of the index pool and every
+    # sequence's gathered index keys among the temporaries)
+    assert gib[2048] < 14.4 and gib[32] < 11.6, gib
     print("dots3 depth-5 GiB by tok_pad:", gib)
 
 

@@ -65,12 +65,25 @@ def sharpened(params):
 def make(file=TINY, seed=0):
     cfg = dataclasses.replace(D.dots3_config(file, jnp.float32),
                               dtype=jnp.float32)
-    return cfg, sharpened(L.init_params(cfg, jax.random.PRNGKey(seed)))
+    # (one compiled program, not an eager dispatch a leaf: half the time of
+    # a fixture that every worker of the suite's run builds for itself)
+    init = jax.jit(lambda key: L.init_params(cfg, key))
+    return cfg, sharpened(init(jax.random.PRNGKey(seed)))
 
 
 @pytest.fixture(scope="module")
 def tiny():
     return make()
+
+
+@pytest.fixture(autouse=True)
+def index_key_tiles_of_four_pages(monkeypatch):
+    """The index's two launches on key tiles of 32 keys, so that a table
+    of 128 keys is four tiles (the walks cross tile edges in every engine
+    test here) and a tile's page copies, which the kernels write out, are
+    4 and not 16 in every interpreted program this module compiles."""
+    for name in ("_INDEX_KEYS", "_INDEX_ROW_KEYS", "_DECODE_KEYS"):
+        monkeypatch.setattr(PL, name, 32)
 
 
 def prompt_of(n, seed=1):
@@ -180,33 +193,44 @@ def test_forward_equals_the_reference_on_logits(tiny, held):
     assert np.abs(got - ref).max() < 1e-4 * np.abs(ref).max()
 
 
+@pytest.fixture(scope="module")
+def sound(tiny):
+    """What the fault cases below share, computed once: the sound
+    program's `forward` logits on a prompt, and the engine's tokens for
+    three requests, each judged against the sound reference (all tie)."""
+    cfg, params = tiny
+    tokens = prompt_of(100, seed=11)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(L.forward(params, jnp.asarray(tokens)[None], cfg)[0])
+    eng = engine(cfg, params)
+    prompts = [prompt_of(n, seed=n) for n in (70, 55, 41)]
+    rids = [eng.submit(p, max_new_tokens=24) for p in prompts]
+    done = {d.rid: d.output_tokens for d in eng.run()}
+    judged = []
+    for rid, p in zip(rids, prompts):
+        seq = p + done[rid]
+        at = np.arange(len(p) - 1, len(seq) - 1)
+        seq = seq + [0] * (WIDTH - len(seq))
+        assert agreement.judge(reference_logits(params, seq)[at],
+                               done[rid])[0] == 1.0
+        judged.append((seq, at, done[rid]))
+    return tokens, got, judged
+
+
 @pytest.mark.parametrize("fault", R.FAULTS)
 def test_a_seeded_fault_moves_forward_and_the_engine_off_the_reference(
-        tiny, fault):
+        tiny, sound, fault):
     """The negative controls, one in each new part (the rescale, the
     window's bound, the index, its key's bias, the selection's size, the
     gate): the reference with that part computed wrongly is another model,
     and both `forward`'s logits and the engine's tokens (judged as the
     cell's check judges them) show it."""
-    cfg, params = tiny
-    tokens = prompt_of(100, seed=11)
+    _, params = tiny
+    tokens, got, judged = sound
     bad = reference_logits(params, tokens, fault=fault)
-    with jax.default_matmul_precision("highest"):
-        got = np.asarray(L.forward(params, jnp.asarray(tokens)[None], cfg)[0])
     assert np.abs(got - bad).max() > 1e-2 * np.abs(bad).max()
-    eng = engine(cfg, params)
-    prompts = [prompt_of(n, seed=n) for n in (70, 55, 41)]
-    rids = [eng.submit(p, max_new_tokens=24) for p in prompts]
-    done = {d.rid: d.output_tokens for d in eng.run()}
-    shares = []
-    for rid, p in zip(rids, prompts):
-        seq = p + done[rid]
-        at = np.arange(len(p) - 1, len(seq) - 1)
-        sound = reference_logits(params, seq + [0] * (WIDTH - len(seq)))[at]
-        wrong = reference_logits(params, seq + [0] * (WIDTH - len(seq)),
-                                 fault=fault)[at]
-        assert agreement.judge(sound, done[rid])[0] == 1.0
-        shares.append(agreement.judge(wrong, done[rid])[0])
+    shares = [agreement.judge(reference_logits(params, seq, fault=fault)[at],
+                              out)[0] for seq, at, out in judged]
     assert min(shares) < 1.0
 
 
@@ -542,6 +566,74 @@ def test_the_walked_rows_and_pairs_equal_hand_counts(tiny, monkeypatch):
                    for k in eng._plan_keys(past, this))
 
 
+# ---- the index's two launches: a sequence's keys out of its pages -----------
+
+@pytest.mark.parametrize("launch", ["paged_index_scores_chunk",
+                                    "paged_index_scores_decode"])
+def test_an_index_launch_scores_a_sequence_s_keys_out_of_its_pages(
+        launch, monkeypatch):
+    """Each launch (interpreter) against `sparse_index.index_scores` over
+    keys gathered by hand, on the stacked pool, `layer` 1 of 2, key tiles
+    of 32 keys (4 pages of 8), row tiles of 4 tokens. The block tables'
+    pages are out of order and not contiguous, with -1 behind a sequence's
+    last page; the pool's other layer and every page no table lists hold
+    NaN, so a copy of a wrong page shows. The slots: a sequence that ends
+    on a page's edge (40 keys), one that ends on a key tile's edge (64),
+    one mid-page whose table is full, one that does not select (at most
+    `topk` keys: the caller hands the launch no row of it), one with no
+    row."""
+    monkeypatch.setattr(PL, "_INDEX_TOKENS", 4)
+    rng = np.random.default_rng(3)
+    bs, ID, IH, nb, mb, topk, tok = 8, 16, 4, 64, 12, 8, 32
+    chunk = launch.endswith("chunk")
+    ends = np.array([40, 64, 93, 7, 0])
+    this = np.array([9, 6, 5, 4, 0]) if chunk else np.array([1, 1, 1, 1, 0])
+    past = ends - this
+    pool = np.full((2, nb, 1, bs, ID), np.nan, np.float32)
+    tables = np.full((5, mb), -1, np.int32)
+    free = rng.permutation(nb)[::2]                 # every other page
+    for b, n in enumerate(-(-ends // bs)):
+        tables[b, :n], free = free[:n], free[n:]
+        pool[1, tables[b, :n]] = rng.normal(size=(n, 1, bs, ID))
+    qi = rng.normal(size=(tok, IH, ID)).astype(np.float32)
+    w = rng.normal(size=(tok, IH)).astype(np.float32)
+    cu = np.concatenate([[0], np.cumsum(this)]).astype(np.int32)
+    rows = np.where(ends > topk, this, 0)           # `paged_index_select`'s
+    args = (jnp.asarray(pool), jnp.asarray(tables), jnp.asarray(past),
+            jnp.asarray(rows))
+    if chunk:
+        got = PL.index_scores_packed(jnp.asarray(qi), jnp.asarray(w), *args,
+                                     jnp.asarray(cu), jnp.int32(1))
+    else:
+        got = PL.index_scores_rows(jnp.asarray(qi[cu[:5]]),
+                                   jnp.asarray(w[cu[:5]]), *args,
+                                   jnp.int32(1))
+    got = np.asarray(got)
+    assert got.shape == ((tok if chunk else 5), mb * bs)
+    for b in range(5):
+        by_hand = pool[1, np.maximum(tables[b], 0), 0].reshape(mb * bs, ID)
+        want = np.asarray(SI.index_scores(
+            jnp.asarray(qi), jnp.asarray(by_hand), jnp.asarray(w)))
+        for t in range(rows[b]):
+            r, p = cu[b] + t, past[b] + t
+            mine = got[r if chunk else b]
+            assert np.isfinite(want[r, :p + 1]).all()
+            assert np.allclose(mine[:p + 1], want[r, :p + 1], rtol=1e-5,
+                               atol=1e-5), (b, t)
+            # behind the last key tile of the row's work item (of the row
+            # itself in the one-row form) nothing was copied or multiplied
+            last = past[b] + min(t // 4 * 4 + 4, rows[b]) - 1
+            assert not mine[(last // 32 + 1) * 32:].any()
+        if not chunk and not rows[b]:
+            assert not got[b].any()
+    # the host's count of the copies, by hand: a one-row sequence its keys
+    # in whole key blocks; a chunk's every work item (4 rows) the keys up
+    # to its last row in whole tiles
+    by_hand = ((64 + 64 + 64, 64 + 64, 96 + 96) if chunk else (64, 64, 96))
+    assert PL.index_keys_fetched(past[:3], this[:3], tok, bs, mb) == sum(
+        by_hand)
+
+
 # ---- the engine --------------------------------------------------------------
 
 @pytest.mark.parametrize("pallas", [False, True])
@@ -609,6 +701,37 @@ def test_counters_equal_hand_counts_for_two_requests_alone(tiny):
     assert new["sparse_rows_dense"] == 2 * (5 + 1)
     assert new["attn_keys_latent"] == 2 * (5 + 6)
     assert new["attn_pairs_latent"] == 2 * (15 + 6)
+
+
+def test_the_index_keys_fetched_equal_hand_counts_for_two_requests_alone(
+        tiny, monkeypatch):
+    """`index_keys_fetched` on the kernels' path, each request alone and
+    the ticks' program stood in for (the count is the host's, from the
+    lengths it plans with): key tiles of 32 keys, row tiles of 4 tokens. A
+    prompt of 37 in chunks of 32 and 5, then 7 decode rows, selects in
+    every tick: the first chunk's 8 work items fetch the keys up to rows
+    3, 7, ... 31 in whole tiles (32 each), the second chunk's two up to
+    rows 35 and 36 (64 each), a decode row at position p its p // 32 + 1
+    key blocks; times the 2 index layers. A prompt of 5 never selects and
+    fetches nothing; the stock path gathers and counts nothing."""
+    cfg, params = tiny
+    monkeypatch.setattr(PL, "_INDEX_TOKENS", 4)
+    eng = engine(cfg, params, pallas=True)
+    eng._next_is_determined = lambda cur: False     # no void row
+    monkeypatch.setattr(eng, "_build_step", lambda tok_pad, B, *rest: (
+        lambda *args: (jnp.zeros((B + len(eng._moe_fields),), jnp.int32),
+                       args[1], args[2])))
+    eng.submit(prompt_of(37, seed=2), max_new_tokens=8)
+    eng.run()
+    st = dict(eng.stats)
+    assert (st["steps"], st["tokens_computed"]) == (9, 44)
+    assert st["index_keys"] == 2 * (32 + 37 + sum(range(38, 45)))
+    assert st["index_keys_fetched"] == 2 * (8 * 32 + 2 * 64 + 7 * 64)
+    eng.submit(prompt_of(5, seed=2), max_new_tokens=2)
+    eng.run()
+    assert eng.stats["index_keys_fetched"] == st["index_keys_fetched"]
+    assert engine(cfg, params)._plan_keys(
+        np.array([32]), np.array([5]))["index_keys_fetched"] == 0
 
 
 @pytest.mark.parametrize("pallas", [False, True])
