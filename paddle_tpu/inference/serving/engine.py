@@ -63,19 +63,28 @@ the harvest alone, a tick late.
 Sampling (temperature > 0), a draft model, LoRA adapters and int8 pages
 are refused with a block-diffusion config.
 
-A layer plan (``cfg.layer_plan``: Laguna-XS.2's leading dense layer, window
+The tick has ONE loop over layers, for every config (``_layer_loop``):
+``llama.scan_plan`` over ``cfg.kinds``, one traced body a kind. What a
+config's layers are is ``LlamaConfig.layers``' to say and nobody else's: a
+uniform config is a plan of one kind (one ``lax.scan`` over its whole
+stack, ``params["blocks"]`` read as a tuple of one), so its page pools,
+attention launches and rope tables are derived as a plan's are. A written
+layer plan (``cfg.layer_plan``: Laguna-XS.2's leading dense layer, window
 and full attention with their own head counts and ropes, sparse layers
-beside a shared expert) runs through the same tick: the layer loop is
-``llama.scan_plan`` over the plan's kinds, one traced body a kind. With
+beside a shared expert) is the same loop with more kinds. With
 window layers the pages live in two pools with two lifetimes: the full
 layers' ``[L_full, num_blocks, ...]``, which keeps every page of a sequence,
 and the window layers' ``[L_window, window_blocks, ...]``, whose pages go back
 once every position in them lies more than ``sliding_window`` - 1 behind the
 sequence's computed length (``BlockManager.release_behind``, after a tick is
 harvested). A sequence has a block table over each; the prefix cache is off
-(``engine_stats["prefix_cache"]`` says so). With a plan, int8 pages, weight
-quantisation, LoRA adapters, a draft model, the fused FFN and
-``extract_pages`` / ``ingest_pages`` are refused.
+(``engine_stats["prefix_cache"]`` says so). With a WRITTEN plan, int8 pages,
+weight quantisation, LoRA adapters, a draft model, the fused FFN and
+``extract_pages`` / ``ingest_pages`` are refused: each was never judged
+against a reference under a plan, so each refusal is a policy stated once
+(``__init__``, ``submit``, ``_refuse_page_handoff``, ``_resolve_ffn``), not
+a branch of the loop, which carries LoRA, the int8 pages' scales, QK-norm,
+``block_length`` and the fused FFN for whatever kind of layer takes them.
 
 Latent attention (a plan whose layers are ``attn="latent"``: DeepSeek-V3's
 block, Kimi-K2's, dots3-note's) is served over LATENT page pools: an array
@@ -242,8 +251,8 @@ def _pool_plan(cfg: "L.LlamaConfig"):
     whole lanes. Each pool is one array, so its layers share one row width,
     one window and one index geometry; a plan that asks for two of a kind
     in one pool is refused."""
-    window = [s for s in cfg.layer_plan if _in_window_pool(s)]
-    full = [s for s in cfg.layer_plan if not _in_window_pool(s)]
+    window = [s for s in cfg.layers if _in_window_pool(s)]
+    full = [s for s in cfg.layers if not _in_window_pool(s)]
 
     def one(values, what):
         values = set(values)
@@ -450,7 +459,7 @@ class PagedServingEngine:
         # kinds, the one with a window in the window pool at its own
         # width; the full kind's pages carry its index keys as a second
         # row a position (`_pool_plan`)
-        self.latent = any(s.attn == "latent" for s in cfg.layer_plan)
+        self.latent = any(s.attn == "latent" for s in cfg.layers)
         (self._pool_layers, self._row_widths, self.window,
          self._index) = _pool_plan(cfg)
         # layers whose pages live in the pool and in the window pool (a
@@ -594,8 +603,8 @@ class PagedServingEngine:
         # path elsewhere; True = force (interpret mode off-TPU — how CPU CI
         # drives it; a bad geometry fails here); False = the stock
         # reference
-        geometry = (max([s.heads for s in cfg.layer_plan] or [cfg.num_heads]),
-                    cfg.num_kv_heads, cfg.head_dim, self.block_size)
+        geometry = (max(s.heads for s in cfg.layers), cfg.num_kv_heads,
+                    cfg.head_dim, self.block_size)
         if pallas and not PA.supported(*geometry):
             raise ValueError(
                 f"pallas=True forced but geometry H={cfg.num_heads} "
@@ -608,11 +617,10 @@ class PagedServingEngine:
         # counters are reckoned only then) or the BlockSpec walk
         self._whole_pages = (self.pallas and not self.latent
                              and PA.whole_pages(cfg.head_dim))
-        # the (query heads, window) of each attention launch a tick makes:
-        # the config's own, or each that occurs in its plan
+        # the (query heads, window) of each attention launch a tick makes
         self._launches = tuple(dict.fromkeys(
-            [(s.heads, self.window if _in_window_pool(s) else 0)
-             for s in cfg.layer_plan] or [(cfg.num_heads, 0)]))
+            (s.heads, self.window if _in_window_pool(s) else 0)
+            for s in cfg.layers))
         # fused-FFN routing mirrors the attention tri-state: None =
         # FLAGS_pallas_ffn per tick; True = force (interpret off-TPU);
         # False = off. Forced mode validates params + geometry eagerly.
@@ -679,22 +687,18 @@ class PagedServingEngine:
                 jnp.tile((vab / Q.QMAX)[:, None, :], (1, self.num_blocks, 1)))
         else:
             self._kv_scales = None
-        # rope table in the kernel's stacked [2, 1, S, hd] layout (only the
-        # first hd//2 lanes of each are read)
+        # rope tables in the kernel's stacked [2, 1, S, hd] layout (only the
+        # first hd//2 lanes of each are read): one a rope of the layers, as
+        # wide as what it rotates (a uniform config: its one, whose bits
+        # are `rope_cos_sin`'s)
         def rope_emb(cos, sin):
             return jnp.stack([jnp.concatenate([cos, cos], -1)[None],
                               jnp.concatenate([sin, sin], -1)[None]])
-        if plan:
-            # one table a rope of the plan, as wide as what it rotates
-            widths = {s.rope: cfg.rope_width(s) for s in cfg.kinds[::-1]}
-            self._ropes = tuple(dict.fromkeys(s.rope for s in cfg.kinds))
-            self._rope_emb = tuple(
-                rope_emb(*L.rope_table(jnp.arange(self.max_len),
-                                       widths[r], r))
-                for r in self._ropes)
-        else:
-            self._rope_emb = rope_emb(*L.rope_cos_sin(
-                jnp.arange(self.max_len), hd, cfg.rope_theta))
+        widths = {s.rope: cfg.rope_width(s) for s in cfg.kinds[::-1]}
+        self._ropes = tuple(dict.fromkeys(s.rope for s in cfg.kinds))
+        self._rope_emb = tuple(
+            rope_emb(*L.rope_table(jnp.arange(self.max_len), widths[r], r))
+            for r in self._ropes)
         # executables keyed by what differs between two ticks of this
         # engine: (token-budget, batch-slots, decode, ffn-mode, adapter
         # rank classes, spec-mode); `decode` = every chunk is one token
@@ -1015,7 +1019,9 @@ class PagedServingEngine:
         adapter-signature, spec-mode) signature. `decode` is the caller's
         promise that every scheduled chunk is one token: beside the
         kernel (`self.pallas`) the read then takes its max_q=1 launch.
-        `ffn_mode` swaps the per-layer SwiGLU for the fused Pallas
+        The executable is embed, the layers (`_layer_loop`, the one loop
+        over layers of every config), head and sample; no layer is written
+        here. `ffn_mode` swaps the per-layer SwiGLU for the fused Pallas
         kernel; combined with the decode launch it also swaps the
         sampling tail for the one-launch sampler prep — the fused decode
         tick (~2 launches/layer + 1 sampler).
@@ -1030,7 +1036,6 @@ class PagedServingEngine:
         cfg = self.cfg
         top_k = self.top_k
         Bd = cfg.block_length      # static: 0 = one token a slot comes back
-        quant_kv = self.quant_kv   # static: selects the int8-cache trace
         # the op's vocabulary for the engine's constant and the tick's shape
         use_pallas = self.pallas and ("decode" if decode else True)
         fused_tick = bool(ffn_mode) and use_pallas == "decode"
@@ -1082,102 +1087,14 @@ class PagedServingEngine:
             with jax.named_scope("embed"):
                 x = jnp.take(params["embed"], tokens,
                              axis=0).astype(cfg.dtype)
-            # per-class token->slot scaling selectors (closed over by the
-            # scan body — they carry no layer axis)
-            ad_sels = tuple(a["sel"] for a in ad_args)
             # rows are packed from 0: what lies behind the last chunk is
             # padding, which no expert may see
             valid = jnp.arange(tok_pad) < cu_seqlens_q[B]
-
-            def body(carry, layer):
-                # the stacked page pool rides the carry, so the step's
-                # input, the loop's state and the step's output are one
-                # buffer; a layer finds its pages by its index
-                x, kcs, vcs = carry
-                li, lp = layer[:2]
-                if quant_kv:
-                    kv_layer, ad_layers = layer[2:6], layer[6:]
-                else:
-                    kv_layer, ad_layers = None, layer[2:]
-
-                def lora(h, t, y):
-                    # segmented/gathered LoRA: every slot of every active
-                    # rank class applies at once; sel[row, slot] carries
-                    # alpha/rank for the row's adapter and 0 elsewhere,
-                    # so a zero row contributes an EXACT 0.0 delta (base
-                    # rows bit-match the adapter-free math) and the
-                    # slot-reduction has one nonzero term (mixed batches
-                    # bit-match solo runs)
-                    for sel, packs in zip(ad_sels, ad_layers):
-                        A, Bm = packs[t]        # [S,din,c] / [S,c,dout]
-                        u = jnp.einsum("td,sdr->tsr",
-                                       h.astype(jnp.float32), A)
-                        w = jnp.einsum("tsr,sro->tso", u, Bm)
-                        y = y + jnp.einsum("tso,ts->to", w,
-                                           sel).astype(y.dtype)
-                    return y
-
-                with jax.named_scope("qkv"):
-                    h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-                    q = lora(h, "wq", Q.matmul_param(h, lp, "wq"))
-                    k = lora(h, "wk", Q.matmul_param(h, lp, "wk"))
-                    v = lora(h, "wv", Q.matmul_param(h, lp, "wv"))
-                    q, k = L.qk_normed(q, k, lp, cfg)
-                    qkv = jnp.concatenate([q, k, v], axis=-1)
-                # scopes itself: qkv (split, rope), cache_write,
-                # paged_attention
-                o, _, kcs, vcs = paged_layer_attention(
-                    qkv, kcs, vcs, li, seq_lens_decoder,
-                    seq_lens_this_time, cu_seqlens_q, block_tables,
-                    rope_emb=rope_emb, quant_scales=kv_layer,
-                    use_neox_style=True, use_pallas=use_pallas,
-                    block_length=Bd)
-                with jax.named_scope("attn_out"):
-                    x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
-                if cfg.num_experts:
-                    with jax.named_scope("moe"):
-                        h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-                        y, load = L.routed_ffn_load(
-                            h, {**lp, **experts}, cfg, valid, layer=li)
-                        x = x + y
-                    return (x, kcs, vcs), (
-                        jnp.sum(load > 0), jnp.max(load),
-                        *self._held_counts(load, h.shape[0]))
-                with jax.named_scope("ffn"):
-                    h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-                    if ffn_mode:
-                        # one launch: gate+up matmuls, silu·mul, down
-                        # matmul — the d_ff intermediate never leaves VMEM
-                        x = x + FF.apply_ffn(h, lp)
-                    else:
-                        gate = (jax.nn.silu(Q.matmul_param(h, lp, "w1"))
-                                * Q.matmul_param(h, lp, "w3"))
-                        x = x + Q.matmul_param(gate, lp, "w2")
-                return (x, kcs, vcs), None
-
-            if cfg.layer_plan:
-                with jax.named_scope("layers"):
-                    x, kcs, vcs, loads = self._plan_layers(
-                        params, x, key_cache, value_cache, block_tables,
-                        cu_seqlens_q, seq_lens_decoder, seq_lens_this_time,
-                        rope_emb, valid, use_pallas)
-            else:
-                # scanned over: the layer index and what a layer only
-                # reads. The expert matrices stay whole: the expert kernel
-                # finds a layer's by its index, like the page pool's readers
-                experts = {n: params["blocks"][n] for n in
-                           (("w1", "w3", "w2") if cfg.num_experts else ())}
-                xs = (jnp.arange(cfg.num_layers, dtype=jnp.int32),
-                      {n: v for n, v in params["blocks"].items()
-                       if n not in experts})
-                if quant_kv:
-                    # kq, vq [L,KV]; kdq,vdq [L,nb,KV]
-                    xs = xs + tuple(kv_scales)
-                # stacked adapter packs ride the layer scan like param leaves
-                xs = xs + tuple(a["packs"] for a in ad_args)
-                with jax.named_scope("layers"):
-                    (x, kcs, vcs), loads = lax.scan(
-                        body, (x, key_cache, value_cache), xs)
+            with jax.named_scope("layers"):
+                x, kcs, vcs, loads = self._layer_loop(
+                    params, x, key_cache, value_cache, kv_scales, ad_args,
+                    block_tables, cu_seqlens_q, seq_lens_decoder,
+                    seq_lens_this_time, rope_emb, valid, use_pallas, ffn_mode)
             with jax.named_scope("head"):
                 # last-token hidden state per slot, or its whole block's
                 hlast = x[block_rows(cu_seqlens_q)]          # [B (* Bd), d]
@@ -1244,19 +1161,28 @@ class PagedServingEngine:
 
         return step_fn
 
-    def _plan_layers(self, params, x, key_cache, value_cache, block_tables,
-                     cu, past, this, rope_emb, valid, use_pallas):
-        """The tick's layer loop under a layer plan (`llama.scan_plan`):
-        one body a kind, which finds its pages in its attention's pool
-        (`key_cache` / `value_cache` / `block_tables` are (full, window)
-        pairs with window layers, else the one pool) by the layer's place
-        in that pool, its rope's table among `rope_emb`, and its experts
-        in its kind's whole stack by its place there. Scopes as the
-        uniform tick's, with `paged_attention_full` / `_window` inside
-        `paged_attention`, `attn_gate`, and `shared_expert` inside `moe`.
-        Latent layers (`latent_attention` below) have one pool and no
-        value side. Returns (x, key_cache, value_cache, (experts hit,
-        largest load[, pairs on held experts]))."""
+    def _layer_loop(self, params, x, key_cache, value_cache, kv_scales,
+                    ad_args, block_tables, cu, past, this, rope_emb, valid,
+                    use_pallas, ffn_mode):
+        """The tick's layers, of every config (`llama.scan_plan` over
+        `cfg.kinds`; a uniform config is one kind, one `lax.scan` over its
+        whole stack): one body a kind, which finds its pages in its
+        attention's pool (`key_cache` / `value_cache` / `block_tables` are
+        (full, window) pairs with window layers, else the one pool) by the
+        layer's place in that pool, its rope's table among `rope_emb`, and
+        its experts in its kind's whole stack by its place there. What a
+        layer only reads rides its kind's stack: the parameters and, where
+        the tick has them, the int8 pages' four scale arrays `kv_scales`
+        and the LoRA packs of `ad_args` (both stacked over ALL layers:
+        `__init__` refuses them with a written plan, which alone can have
+        more than one kind; the selectors carry no layer axis and are
+        closed over). Scopes: qkv, cache_write,
+        paged_attention (`paged_attention_full` / `_window` inside it
+        where the tick makes more than one kind of launch), `attn_gate`,
+        attn_out, ffn or moe (`shared_expert` inside). Latent layers
+        (`latent_attention` below) have one pool and no value side.
+        Returns (x, key_cache, value_cache, (experts hit, largest load[,
+        pairs on held experts, launches past their places]) or ())."""
         cfg = self.cfg
         kinds, kind_of = cfg.kinds, cfg.kind_of_layer
         two = isinstance(key_cache, tuple)
@@ -1264,22 +1190,26 @@ class PagedServingEngine:
         pools_v = value_cache if two else (value_cache,)
         tables = block_tables if two else (block_tables,)
         expert_names = ("w1", "w3", "w2")
+        ad_sels = tuple(a["sel"] for a in ad_args)
         stacks, experts = [], []
-        for k, spec in enumerate(kinds):
+        for k, (spec, leaves) in enumerate(zip(
+                kinds, L.kind_stacks(params["blocks"]))):
             sparse = spec.ffn == "sparse"
-            leaves = params["blocks"][k]
             experts.append({n: leaves[n] for n in expert_names} if sparse
                            else {})
             layers = [i for i, kk in enumerate(kind_of) if kk == k]
             # the layer's place among the layers of its pool
-            same = [i for i, s in enumerate(cfg.layer_plan) if not two
+            same = [i for i, s in enumerate(cfg.layers) if not two
                     or _in_window_pool(s) == _in_window_pool(spec)]
             stacks.append({
                 "lp": {n: v for n, v in leaves.items()
                        if not (sparse and n in expert_names)},
                 "place": jnp.arange(len(layers), dtype=jnp.int32),
                 "page_layer": jnp.asarray([same.index(i) for i in layers],
-                                          jnp.int32)})
+                                          jnp.int32),
+                # kq, vq [L, KV]; kdq, vdq [L, nb, KV]
+                "kv": kv_scales and tuple(kv_scales),
+                "ad": tuple(a["packs"] for a in ad_args)})
 
         if self.latent:
             # every packed row's position, for its rope: the rows are one
@@ -1337,8 +1267,23 @@ class PagedServingEngine:
 
         def body(kind, carry, leaves):
             spec = kinds[kind]
-            x, pk, pv, hit, top, *held = carry
+            x, pk, pv, *counts = carry
             lp = leaves["lp"]
+
+            def lora(h, t, y):
+                # segmented/gathered LoRA: every slot of every active rank
+                # class applies at once; sel[row, slot] carries alpha/rank
+                # for the row's adapter and 0 elsewhere, so a zero row
+                # contributes an EXACT 0.0 delta (base rows bit-match the
+                # adapter-free math) and the slot-reduction has one nonzero
+                # term (mixed batches bit-match solo runs)
+                for sel, packs in zip(ad_sels, leaves["ad"]):
+                    A, Bm = packs[t]            # [S,din,c] / [S,c,dout]
+                    u = jnp.einsum("td,sdr->tsr", h.astype(jnp.float32), A)
+                    w = jnp.einsum("tsr,sro->tso", u, Bm)
+                    y = y + jnp.einsum("tso,ts->to", w, sel).astype(y.dtype)
+                return y
+
             if spec.attn == "latent":
                 pool = int(two and _in_window_pool(spec))
                 x, kc, ic = latent_attention(
@@ -1350,25 +1295,28 @@ class PagedServingEngine:
                 rot = int(cfg.head_dim * spec.rope.partial)
                 with jax.named_scope("qkv"):
                     h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-                    qkv = jnp.concatenate(
-                        [Q.matmul_param(h, lp, n)
-                         for n in ("wq", "wk", "wv")], axis=-1)
+                    q, k, v = (lora(h, n, Q.matmul_param(h, lp, n))
+                               for n in ("wq", "wk", "wv"))
+                    q, k = L.qk_normed(q, k, lp, cfg)
+                    qkv = jnp.concatenate([q, k, v], axis=-1)
+                # scopes itself: qkv (split, rope), cache_write,
+                # paged_attention
                 o, _, kc, vc = paged_layer_attention(
                     qkv, pk[pool], pv[pool], leaves["page_layer"], past,
                     this, cu, tables[pool],
                     rope_emb=rope_emb[self._ropes.index(spec.rope)],
-                    use_neox_style=True, use_pallas=use_pallas,
+                    quant_scales=leaves["kv"], use_neox_style=True,
+                    use_pallas=use_pallas, block_length=cfg.block_length,
                     window=cfg.sliding_window if spec.attn == "window"
                     else 0,
                     rotary_dim=rot if rot < cfg.head_dim else 0,
-                    kind=spec.attn)
-                pk = pk[:pool] + (kc,) + pk[pool + 1:]
-                pv = pv[:pool] + (vc,) + pv[pool + 1:]
+                    kind=spec.attn if len(self._launches) > 1 else None)
+                pk, pv = put(pk, pool, kc), put(pv, pool, vc)
                 if cfg.attn_gate:
                     o = L.attn_gated(o.reshape(o.shape[0], spec.heads, -1),
                                      h, lp).reshape(o.shape)
                 with jax.named_scope("attn_out"):
-                    x = x + Q.matmul_param(o, lp, "wo")
+                    x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
             if spec.ffn == "sparse":
                 with jax.named_scope("moe"):
                     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
@@ -1376,20 +1324,31 @@ class PagedServingEngine:
                         h, {**lp, **experts[kind]}, cfg, valid,
                         layer=leaves["place"])
                     x = x + y
-                    hit = hit + jnp.sum(load > 0, dtype=jnp.int32)
-                    top = jnp.maximum(top, jnp.max(load))
-                    held = [c + n for c, n in zip(
-                        held, self._held_counts(load, h.shape[0]))]
+                    hit, top, *held = counts
+                    counts = (hit + jnp.sum(load > 0, dtype=jnp.int32),
+                              jnp.maximum(top, jnp.max(load)),
+                              *(c + n for c, n in zip(
+                                  held, self._held_counts(load, h.shape[0]))))
             else:
                 with jax.named_scope("ffn"):
                     h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-                    x = x + L.ffn(h, lp)
-            return (x, pk, pv, hit, top, *held)
+                    if ffn_mode:
+                        # one launch: gate+up matmuls, silu·mul, down
+                        # matmul — the d_ff intermediate never leaves VMEM
+                        x = x + FF.apply_ffn(h, lp)
+                    else:
+                        gate = (jax.nn.silu(Q.matmul_param(h, lp, "w1"))
+                                * Q.matmul_param(h, lp, "w3"))
+                        x = x + Q.matmul_param(gate, lp, "w2")
+            return (x, pk, pv, *counts)
 
+        # the expert counters ride the carry: one a field the tick sends
+        # behind its tokens but `moe_pairs`, which the lengths give
         zero = jnp.zeros((), jnp.int32)
         x, pk, pv, *counts = L.scan_plan(
-            cfg, body, (x, pools_k, pools_v, zero, zero)
-            + ((zero, zero) if cfg.experts_held else ()), stacks)
+            cfg, body,
+            (x, pools_k, pools_v) + (zero,) * len(self._moe_fields[1:]),
+            stacks)
         return (x, pk if two else pk[0], pv if two else pv[0],
                 tuple(counts))
 
@@ -2038,7 +1997,7 @@ class PagedServingEngine:
             # read through the masked walk, which multiplies their rows'
             # causal pairs (the kernels' rule: the stock read gathers)
             rows_walked = pairs_walked = 0
-            for spec in cfg.layer_plan if self.pallas else ():
+            for spec in cfg.layers if self.pallas else ():
                 if spec.attn == "latent" and spec.latent.index is not None:
                     walk = sel & (this > 1) & (each_keys <= sparse_walk_keys(
                         spec.heads, self._row_widths[0],

@@ -31,7 +31,12 @@ of stacks, one a kind, in order of first occurrence) and they share one
 traced body; `scan_plan` runs the plan as scans over runs of one kind
 inside a scan over the plan's periods. Without a plan every layer is the
 config's own one kind (with `num_experts` > 0 every layer's FFN is
-routed), `params["blocks"]` is one dict and the program is what it was.
+routed) and `params["blocks"]` is one dict. `LlamaConfig.layers` is the one
+answer to "what are this config's layers", for both: the written plan, or
+`num_layers` times that one kind, so `kinds`, `plan_segments` and
+`scan_plan` serve a uniform config as a plan of one kind (one run, one
+`lax.scan` over the unsliced stack, `kind_stacks` its one dict as a tuple
+of one); the serving tick has no other layer loop.
 
 A third kind of attention is *latent* (`LayerSpec.attn = "latent"`:
 DeepSeek-V3's block, which Kimi-K2 keeps): queries through a low-rank
@@ -334,29 +339,42 @@ class LlamaConfig:
     # program itself reads a layer's spec
     @property
     def rope_dim(self) -> int:
-        return self.rope_width(self.layer_plan[0] if self.layer_plan
-                               else None)
+        return self.rope_width(self.layers[0])
 
     @property
     def score_scale(self) -> float:
         return self.one_latent().score_scale
 
-    # -- the layer plan ---------------------------------------------------
+    # -- the layers -------------------------------------------------------
+    @property
+    def layers(self) -> Tuple[LayerSpec, ...]:
+        """One LayerSpec a layer, of every config: the plan as written, or
+        `num_layers` times the one kind the config's own fields describe
+        (its heads, causal attention over the heads' own keys, its one
+        rope, a routed FFN where it has experts). What asks "what are this
+        config's layers" reads this; `layer_plan` stays what a user wrote
+        (() = uniform: `params["blocks"]` one dict), and what
+        `__post_init__` refuses of a written plan is not refused of this."""
+        return self.layer_plan or (LayerSpec(
+            "full", self.num_heads, RopeSpec(theta=self.rope_theta),
+            "sparse" if self.num_experts else "dense"),) * self.num_layers
+
     @property
     def kinds(self) -> Tuple[LayerSpec, ...]:
-        """The distinct specs of the plan, in order of first occurrence."""
-        return tuple(dict.fromkeys(self.layer_plan))
+        """The distinct specs of the layers, in order of first occurrence
+        (a uniform config: its one)."""
+        return tuple(dict.fromkeys(self.layers))
 
     @property
     def kind_of_layer(self) -> Tuple[int, ...]:
         kinds = self.kinds
-        return tuple(kinds.index(s) for s in self.layer_plan)
+        return tuple(kinds.index(s) for s in self.layers)
 
-    def _layer_params(self, heads: int, ffn: str,
-                      ls: Optional[LatentSpec] = None) -> Tuple[int, int]:
+    def _layer_params(self, spec: LayerSpec) -> Tuple[int, int]:
         """(all, active a token) matmul and norm parameters of one layer.
         The routed experts count whole (`num_experts`), whatever share of
         them a config holds: this is the model's size, not a chip's."""
+        heads, ffn, ls = spec.heads, spec.ffn, spec.latent
         d, hd = self.hidden_size, self.head_dim
         norms = 2 * d
         if ls is not None:
@@ -391,22 +409,16 @@ class LlamaConfig:
                                     else self.intermediate_size)
         return attn + mlp + norms, attn + active
 
-    def _layers(self):
-        if self.layer_plan:
-            return [(s.heads, s.ffn, s.latent) for s in self.layer_plan]
-        return [(self.num_heads, "sparse" if self.num_experts else "dense")
-                ] * self.num_layers
-
     def num_params(self) -> int:
         d, v = self.hidden_size, self.vocab_size
-        return (v * d + sum(self._layer_params(*l)[0] for l in self._layers())
+        return (v * d + sum(self._layer_params(s)[0] for s in self.layers)
                 + d + d * v)
 
     def num_active_params(self) -> int:
         """Matmul parameters one token is multiplied with: a token passes
         through `top_k` of the experts, the router and the shared expert;
         the head counts, the embedding (a row lookup) does not."""
-        return (sum(self._layer_params(*l)[1] for l in self._layers())
+        return (sum(self._layer_params(s)[1] for s in self.layers)
                 + self.hidden_size * self.vocab_size)
 
     def flops_per_token(self) -> int:
@@ -559,6 +571,12 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "final_norm": jnp.ones((d,), pt),
         "lm_head": normal(keys[9], (d, v)),
     }
+
+
+def kind_stacks(blocks) -> Tuple[Dict[str, jax.Array], ...]:
+    """`params["blocks"]` as one stack a kind (`cfg.kinds`): a written
+    plan's tuple, a uniform config's one dict as a tuple of one."""
+    return (blocks,) if isinstance(blocks, dict) else tuple(blocks)
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
