@@ -21,9 +21,24 @@ jitted function where:
   the schedule is differentiated through (ppermute transposes to the inverse
   permutation), so one `jax.grad` covers the whole pipeline instead of the
   reference's hand-built forward_backward_pipeline (pipeline_parallel.py:575).
-- **EP (MoE)**: GShard-style capacity dispatch + `all_to_all` over the 'dp'
-  axis (expert parallelism rides the data-parallel axis, as in the reference's
-  global_scatter/global_gather design, moe_layer.py:263).
+- **EP (MoE)**: where dp > 1 exchanges the experts, GShard-style capacity
+  dispatch + `all_to_all` over the 'dp' axis (expert parallelism rides the
+  data-parallel axis, as in the reference's global_scatter/global_gather
+  design, moe_layer.py:263; `_moe_ffn`, which drops the pairs over its
+  capacity). Where nothing is exchanged (dp = 1: every expert here, or one
+  chip's share of them, `LlamaConfig.experts_held`) a sparse layer is
+  `llama.routed_ffn_load`: the router over all experts in float32, the
+  pairs of the experts held here sorted and multiplied by the grouped-matmul
+  kernel forward and backward, no pair dropped whatever the load;
+  `make_train_step(with_stats=True)` returns the routed layers' counters
+  as a fourth output.
+- **A layer plan** (`LlamaConfig.layer_plan`: full and sliding-window
+  layers, a rope a kind, dense or routed FFNs): `params["blocks"]` is a
+  tuple of stacks by kind, a stage's layers run by `llama.scan_plan` under
+  the same remat policy as a uniform stack (which is a plan of one kind),
+  a window layer through the flash kernel's window. Refused by name: a plan
+  with pp > 1 (stages would be cut by whole periods), a held share with
+  dp > 1 (the exchange), latent layers, a window under cp > 1.
 - **DP**: gradient psum over 'dp' — the EagerReducer (reducer.h:88) collapses
   to one fused collective XLA schedules during the backward.
 - **ZeRO-ish**: optimizer states live sharded exactly like the params (tp/pp
@@ -38,6 +53,7 @@ mean.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict, Optional, Tuple, Union
@@ -73,7 +89,8 @@ def build_mesh(dp: int = 1, pp: int = 1, tp: int = 1, cp: int = 1,
 
 
 def stack_pipeline(params: Dict[str, Any], pp: int) -> Dict[str, Any]:
-    """Reshape block leaves [L, ...] → [pp, L//pp, ...] (stage-major)."""
+    """Reshape block leaves [L, ...] → [pp, L//pp, ...] (stage-major); a
+    layer plan's stacks by kind each get the stage axis (pp = 1 there)."""
     def f(x):
         Lg = x.shape[0]
         assert Lg % pp == 0, f"num_layers {Lg} not divisible by pp {pp}"
@@ -91,16 +108,50 @@ def unstack_pipeline(params: Dict[str, Any]) -> Dict[str, Any]:
     return out
 
 
-def param_specs(cfg: L.LlamaConfig) -> Dict[str, Any]:
-    """PartitionSpecs for the stage-stacked param pytree.
+# what `_moe_stats` counts of one step's launches of `llama.routed_ffn_load`
+# (int32 scalars; all zero where no layer runs it: a dense config, or
+# experts exchanged over dp > 1)
+MOE_STATS = ("moe_launches", "moe_pairs", "moe_pairs_held", "moe_load_max",
+             "moe_whole_form")
 
-    Layout: blocks leaves carry a leading 'pp' stage axis; projections are
-    tp-sharded Megatron-style (wq/wk/wv/w1/w3 on the output dim, wo/w2 on the
-    input dim); embed/lm_head are vocab-parallel; MoE experts are sharded over
-    'dp' (= the ep axis). A config with a layer plan is refused: its
-    blocks are stacks by kind, not one [L, ...] stack to cut into stages.
-    """
-    L.require_uniform(cfg, "distributed.hybrid")
+
+def require_trainable(cfg: L.LlamaConfig, dp: int = 1, pp: int = 1,
+                      cp: int = 1) -> None:
+    """Raise, by name, for what this engine would compute wrongly: the
+    block body is `_block_sp` (full or window attention over the heads' own
+    keys and values, one rope a kind, a dense or a routed FFN), a layer
+    plan is not cut into stages, and a held share is not exchanged."""
+    what = "distributed.hybrid trains a layer plan of full and window "\
+           "layers with dense or routed FFNs, and"
+    for name, has in (
+            ("latent attention (LayerSpec.attn = 'latent')",
+             any(s.attn == "latent" for s in cfg.layers)),
+            ("a per-head attention gate (attn_gate)", cfg.attn_gate),
+            ("a shared expert (shared_expert_width)",
+             cfg.shared_expert_width),
+            ("a router bias (router_bias)", cfg.router_bias),
+            ("QK-norm (qk_norm)", cfg.qk_norm),
+            ("block diffusion (block_length)", cfg.block_length)):
+        if has:
+            raise NotImplementedError(f"{what} does not take {name}")
+    if cfg.layer_plan and pp > 1:
+        raise NotImplementedError(
+            f"{what} does not cut a layer plan into pipeline stages "
+            f"(pp = {pp}): stages would have to be cut by whole periods")
+    if cfg.experts_held and dp > 1:
+        raise NotImplementedError(
+            "distributed.hybrid does not exchange a held share of the "
+            f"experts (LlamaConfig.experts_held with dp = {dp}): the "
+            "all_to_all over the chips that hold the other shares is not "
+            "built; dp = 1 trains this chip's share")
+    if cp > 1 and any(s.attn == "window" for s in cfg.layers):
+        raise NotImplementedError(
+            f"{what} does not take a window layer under context "
+            f"parallelism (cp = {cp}): ring attention has no window")
+
+
+def _kind_specs(spec: L.LayerSpec) -> Dict[str, P]:
+    """PartitionSpecs of one kind's stack (leaves [pp, L_kind, ...])."""
     blocks = {
         "wq": P("pp", None, None, "tp"),
         "wk": P("pp", None, None, "tp"),
@@ -109,7 +160,7 @@ def param_specs(cfg: L.LlamaConfig) -> Dict[str, Any]:
         "attn_norm": P("pp", None, None),
         "mlp_norm": P("pp", None, None),
     }
-    if cfg.num_experts:
+    if spec.ffn == "sparse":
         blocks["router"] = P("pp", None, None, None)
         blocks["w1"] = P("pp", None, "dp", None, "tp")
         blocks["w3"] = P("pp", None, "dp", None, "tp")
@@ -118,9 +169,24 @@ def param_specs(cfg: L.LlamaConfig) -> Dict[str, Any]:
         blocks["w1"] = P("pp", None, None, "tp")
         blocks["w3"] = P("pp", None, None, "tp")
         blocks["w2"] = P("pp", None, "tp", None)
+    return blocks
+
+
+def param_specs(cfg: L.LlamaConfig) -> Dict[str, Any]:
+    """PartitionSpecs for the stage-stacked param pytree.
+
+    Layout: blocks leaves carry a leading 'pp' stage axis; projections are
+    tp-sharded Megatron-style (wq/wk/wv/w1/w3 on the output dim, wo/w2 on the
+    input dim); embed/lm_head are vocab-parallel; MoE experts are sharded over
+    'dp' (= the ep axis; a held share, `experts_held`, lives on dp = 1). With
+    a layer plan `blocks` is a tuple of such dicts, one a kind, as
+    `llama.init_params` makes it.
+    """
+    require_trainable(cfg)
+    kinds = tuple(_kind_specs(spec) for spec in cfg.kinds)
     return {
         "embed": P("tp", None),
-        "blocks": blocks,
+        "blocks": kinds if cfg.layer_plan else kinds[0],
         "final_norm": P(),
         "lm_head": P(None, "tp"),
     }
@@ -130,8 +196,8 @@ def shard_params(params: Dict[str, Any], mesh: Mesh, cfg):
     """Stage-stack + device_put with NamedShardings (host → HBM, laid out).
     cfg: LlamaConfig. (Generic Layers shard their params inside
     hybrid_generic.GenericHybridEngine — no call needed.)"""
-    L.require_uniform(cfg, "distributed.hybrid")
     pp = mesh.shape["pp"]
+    require_trainable(cfg, mesh.shape["dp"], pp, mesh.shape["cp"])
     stacked = stack_pipeline(params, pp)
     specs = param_specs(cfg)
     return jax.tree.map(
@@ -150,6 +216,9 @@ class AdamWConfig:
     eps: float = 1e-8
     weight_decay: float = 0.1
     grad_clip: Optional[float] = 1.0
+    # > 0: step t runs at lr * min(1, t / warmup_steps), a linear warm-up
+    # by the optimizer state's own counter (0: lr from the first step)
+    warmup_steps: int = 0
 
 
 def init_opt_state(params):
@@ -170,6 +239,9 @@ def _adamw_update(params, grads, opt, hp: AdamWConfig, global_sq_sum,
     feed the compiled step without recompilation)."""
     lr = hp.lr if lr is None else lr
     step = opt["step"] + 1
+    if hp.warmup_steps:
+        lr = lr * jnp.minimum(1.0, step.astype(jnp.float32)
+                              / hp.warmup_steps)
     if hp.grad_clip is not None:
         gnorm = jnp.sqrt(global_sq_sum)
         scale = jnp.minimum(1.0, hp.grad_clip / (gnorm + 1e-6))
@@ -294,14 +366,41 @@ def _moe_ffn(h_full, lp, cfg: L.LlamaConfig, ep_size: int):
     return y.reshape(B, T, D).astype(h_full.dtype)
 
 
+def _moe_stats(load, rows: int, cfg: L.LlamaConfig):
+    """What one launch of `routed_ffn_load` adds to the step's counters
+    (`MOE_STATS`), from its `load` [held] (rows on each held expert): the
+    pairs the router made over all experts, those on the experts held here,
+    the fullest held expert's rows, and whether the sorted form moved all
+    rows * top_k pairs (its WHOLE form: every expert held, or a share whose
+    places, `llama.held_pair_slots`, are all of them or fewer than the
+    launch's held pairs)."""
+    held = jnp.sum(load)
+    slots = L.held_pair_slots(rows, cfg)
+    whole = (L.expert_form(cfg) == "sorted_gmm") & (
+        (slots == rows * cfg.top_k) | (held > slots))
+    stats = {"moe_launches": 1, "moe_pairs": rows * cfg.top_k,
+             "moe_pairs_held": held, "moe_load_max": jnp.max(load),
+             "moe_whole_form": whole}
+    # int32 whatever jax_enable_x64 makes of a sum: a scan's carry
+    return {k: jnp.asarray(v, jnp.int32) for k, v in stats.items()}
+
+
 def _block_sp(x, lp, cfg: L.LlamaConfig, cos, sin, ep_size: int,
-              attn_impl: str = "auto", cp: int = 1, ffn_impl: str = "stock"):
+              attn_impl: str = "auto", cp: int = 1, ffn_impl: str = "stock",
+              spec: Optional[L.LayerSpec] = None, chosen: bool = False):
     """One transformer block with Megatron TP + sequence parallelism.
 
     x: [B, T/tp, D] sequence-sharded. lp: this layer's local weight shards.
+    spec: the layer's kind (`cfg.kinds`; None: a uniform config's one).
+    Returns (x, the layer's `MOE_STATS` where its experts ran through
+    `llama.routed_ffn_load`, else {}; with `chosen` also the experts its
+    router gave each row, under "chosen").
     """
+    spec = spec or cfg.kinds[0]
     Bm, Tloc, D = x.shape
     hd = cfg.head_dim
+    window = cfg.sliding_window if spec.attn == "window" else 0
+    stats = {}
     # named scopes: forward, jvp and transpose operations of a region carry
     # its name inside JAX's wrappers, so a trace reader counts them to it
     with jax.named_scope("attention"):
@@ -329,14 +428,29 @@ def _block_sp(x, lp, cfg: L.LlamaConfig, cos, sin, ep_size: int,
             o = ring_attention_shard(q, kk, vv, "cp", causal=True)
             o = o.astype(h_full.dtype).reshape(Bm, T, nh_loc * hd)
         else:
-            o = L.attention(q, kk, vv, impl=attn_impl).reshape(Bm, T, nh_loc * hd)
+            # a plan's kernel calls carry their kind's name inside `attention`
+            with (jax.named_scope(f"attention_{spec.attn}") if cfg.layer_plan
+                  else contextlib.nullcontext()):
+                o = L.attention(q, kk, vv, impl=attn_impl, window=window)
+            o = o.reshape(Bm, T, nh_loc * hd)
         partial = o @ lp["wo"].astype(o.dtype)                         # row-parallel partial
         x = x + lax.psum_scatter(partial, "tp", scatter_dimension=1, tiled=True)
     with jax.named_scope("ffn"):
         h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         h_full = lax.all_gather(h, "tp", axis=1, tiled=True)
-        if cfg.num_experts:
+        if spec.ffn == "sparse" and ep_size > 1:
             y_partial = _moe_ffn(h_full, lp, cfg, ep_size)  # partial over tp
+            x = x + lax.psum_scatter(y_partial, "tp", scatter_dimension=1, tiled=True)
+        elif spec.ffn == "sparse":
+            # nothing to exchange: the one routed FFN of the tree, over the
+            # experts held here (their f/tp columns: SwiGLU is elementwise
+            # in f, so a shard's output is partial over tp like a dense one)
+            with jax.named_scope("moe"):
+                y_partial, load, *routed = L.routed_ffn_load(
+                    h_full, lp, cfg, chosen=chosen)
+                stats = _moe_stats(load, Bm * T, cfg)
+                if chosen:
+                    stats["chosen"] = routed[0]
             x = x + lax.psum_scatter(y_partial, "tp", scatter_dimension=1, tiled=True)
         else:
             # column-parallel w1/w3 + row-parallel w2 → the shard's FFN body is
@@ -344,37 +458,69 @@ def _block_sp(x, lp, cfg: L.LlamaConfig, cos, sin, ep_size: int,
             # kernel drops in per-shard, before the tp reduce-scatter
             partial = L.ffn(h_full, lp, impl=ffn_impl)
             x = x + lax.psum_scatter(partial, "tp", scatter_dimension=1, tiled=True)
-    return x
+    return x, stats
+
+
+def _add_stats(acc: dict, stats: dict) -> dict:
+    """The step's counters so far plus one layer's; the layer's chosen
+    experts, where `acc` keeps them, at the place of its launch."""
+    if not (acc and stats):
+        return acc
+    out = {k: acc[k] + stats[k] for k in MOE_STATS}
+    if "chosen" in acc:
+        out["chosen"] = lax.dynamic_update_index_in_dim(
+            acc["chosen"], stats["chosen"], acc["moe_launches"], 0)
+    return out
 
 
 def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
                      dp: int, pp: int, tp: int, cp: int = 1,
                      remat: Union[bool, str] = True,
-                     attn_impl: str = "auto", ffn_impl: str = "stock"):
-    """Build the per-shard loss(params, tokens, targets) -> scalar function.
+                     attn_impl: str = "auto", ffn_impl: str = "stock",
+                     with_stats: bool = False, chosen: bool = False):
+    """Build the per-shard loss(params, tokens, targets) -> (scalar, stats)
+    function; stats are the step's `MOE_STATS` with `with_stats`, else {}
+    (and nothing is counted). `chosen` adds the experts every launch of
+    `llama.routed_ffn_load` chose, "chosen" [launches, rows, top_k] i32 in
+    the order of the launches (a microbatch's layers one after another).
 
     Inside: GPipe pipeline over `num_microbatches`, TP/SP per block,
     vocab-parallel CE on the last stage, loss pre-scaled by 1/dp.
     """
-    L.require_uniform(cfg, "distributed.hybrid")
+    require_trainable(cfg, dp, pp, cp)
     M = num_microbatches
+    kinds = cfg.kinds
+    zero_stats = ({k: jnp.zeros((), jnp.int32) for k in MOE_STATS}
+                  if with_stats or chosen else {})
+    if chosen and (dp, pp, cp) != (1, 1, 1):
+        raise NotImplementedError(
+            "the chosen experts are kept on a mesh of dp = pp = cp = 1: "
+            "another stage's or shard's launches are not gathered")
 
-    def stage_fn(x, blocks_local, cos, sin):
-        body = lambda carry, lp: (_block_sp(carry, lp, cfg, cos, sin, dp,
-                                            attn_impl, cp, ffn_impl), None)
+    def stage_fn(x, stats, blocks_local, ropes):
         if remat not in (True, False, "dots"):
             raise ValueError(f"remat must be True, False or 'dots', got {remat!r}")
-        if remat == "dots":
-            # save matmul outputs, recompute elementwise/norms: trades a
-            # little HBM for skipping most of the backward's forward replay
-            body = jax.checkpoint(
-                body, prevent_cse=False,
-                policy=jax.checkpoint_policies.dots_saveable)
-        elif remat:
-            body = jax.checkpoint(body, prevent_cse=False)
+
+        def body_of(kind):
+            def body(carry, lp):
+                y, stats = _block_sp(
+                    carry[0], lp, cfg, *ropes[kinds[kind].rope], dp,
+                    attn_impl, cp, ffn_impl, kinds[kind], chosen)
+                return y, _add_stats(carry[1], stats)
+            if remat == "dots":
+                # save matmul outputs, recompute elementwise/norms: trades a
+                # little HBM for skipping most of the backward's forward replay
+                return jax.checkpoint(
+                    body, prevent_cse=False,
+                    policy=jax.checkpoint_policies.dots_saveable)
+            return jax.checkpoint(body, prevent_cse=False) if remat else body
+
+        bodies = [body_of(kind) for kind in range(len(kinds))]
+        # a uniform stack is a plan of one kind: one scan over its stack
         with jax.named_scope("layers"):
-            x, _ = lax.scan(body, x, blocks_local)
-        return x
+            return L.scan_plan(
+                cfg, lambda kind, carry, lp: bodies[kind](carry, lp),
+                (x, stats), L.kind_stacks(blocks_local))
 
     def shard_loss(params, tokens, targets):
         # local shapes: tokens [B/dp, T]; blocks leaves [1, L/pp, ...]
@@ -389,9 +535,17 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
         stage = lax.axis_index("pp")
         # T is the cp-local sequence; rope positions offset by the cp shard
         pos0 = lax.axis_index("cp") * T if cp > 1 else 0
-        cos, sin = L.rope_cos_sin(pos0 + jnp.arange(T), cfg.head_dim,
-                                  cfg.rope_theta)
+        # one table a rope: a plan's kinds have their own (YaRN with its
+        # attention factor on one, the default on another)
+        ropes = {spec.rope: L.rope_table(pos0 + jnp.arange(T),
+                                         cfg.rope_width(spec), spec.rope)
+                 for spec in kinds}
         vloc = params["lm_head"].shape[1]
+        stats0 = dict(zero_stats)
+        if chosen:
+            launches = M * sum(s.ffn == "sparse" for s in cfg.layers)
+            stats0["chosen"] = jnp.zeros((launches, Bm * T, cfg.top_k),
+                                         jnp.int32)
 
         def embed_mb(m):
             with jax.named_scope("embed"):
@@ -411,30 +565,39 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
                 return jnp.sum(per_tok)
 
         def pipe_step(carry, t):
-            x_in, loss_acc = carry
+            x_in, loss_acc, stats_acc = carry
             m = jnp.clip(t - stage, 0, M - 1)
             active = (t - stage >= 0) & (t - stage < M)
             x0 = embed_mb(m)
             x = jnp.where(stage == 0, x0, x_in)
-            y = stage_fn(x, blocks_local, cos, sin)
+            # the counters ride the stage's own carry: started from zero
+            # here and added outside, the compiled step ran the layers'
+            # forward pass a third time for them alone (PERF.md, PR 47)
+            y, stats = stage_fn(x, stats_acc, blocks_local, ropes)
             lmb = mb_loss(y, m)
             take = active & (stage == pp - 1)
             loss_acc = loss_acc + jnp.where(take, lmb, 0.0)
+            # a bubble's launches count for nothing
+            stats_acc = jax.tree.map(
+                lambda new, old: jnp.where(active, new, old), stats, stats_acc)
             with jax.named_scope("pp_send"):
                 y_send = lax.ppermute(
                     y, "pp", [(i, (i + 1) % pp) for i in range(pp)])
-            return (y_send, loss_acc), None
+            return (y_send, loss_acc, stats_acc), None
 
         x_init = jnp.zeros((Bm, Tloc, D), cfg.dtype)
         with jax.named_scope("pipeline"):
-            (_, loss_sum), _ = lax.scan(
-                pipe_step, (x_init, jnp.zeros((), jnp.float32)),
+            (_, loss_sum, stats), _ = lax.scan(
+                pipe_step, (x_init, jnp.zeros((), jnp.float32), stats0),
                 jnp.arange(M + pp - 1))
         # collect from the last stage (pp); already replicated over tp.
         # Normalize to the GLOBAL batch mean: local token count is M*Bm*T, and
         # the extra 1/dp makes the implicit sum over dp ranks a global mean.
         loss_sum = lax.psum(loss_sum, ("pp", "cp") if cp > 1 else "pp")
-        return loss_sum / (M * Bm * T * cp * dp)
+        # every stage counted its own layers, every cp shard its own rows
+        stats = jax.tree.map(
+            lambda c: lax.psum(c, ("pp", "cp") if cp > 1 else "pp"), stats)
+        return loss_sum / (M * Bm * T * cp * dp), stats
 
     return shard_loss
 
@@ -467,7 +630,8 @@ def make_train_step(cfg, mesh: Mesh, num_microbatches: Optional[int] = None,
                     hp: Optional[AdamWConfig] = None,
                     remat: Union[bool, str] = True,
                     attn_impl: str = "auto", loss_fn=None,
-                    ffn_impl: Optional[str] = None):
+                    ffn_impl: Optional[str] = None,
+                    with_stats: bool = False):
     """Model-agnostic entry (VERDICT r3 task #2).
 
     cfg: a LlamaConfig (the hand-optimized flagship path below) OR any
@@ -480,7 +644,12 @@ def make_train_step(cfg, mesh: Mesh, num_microbatches: Optional[int] = None,
     LlamaConfig path: returns jitted step(params, opt_state, tokens,
     targets) → (params, opt_state, loss). params must be stage-stacked +
     sharded (see shard_params); tokens/targets are [B_global, T] int32
-    sharded P('dp',None).
+    sharded P('dp',None). With `with_stats` the step returns a fourth
+    output whatever the config, its own `MOE_STATS` as a dict of int32
+    scalars: launches of `llama.routed_ffn_load` (a sparse layer on dp =
+    1), the pairs they routed, those on the experts held here, the sum over
+    launches of the fullest held expert's rows, and the launches that took
+    the sorted form's whole form; all zero where no layer runs it.
 
     remat: True = full per-block rematerialization (lowest memory);
     "dots" = jax.checkpoint_policies.dots_saveable — saves matmul outputs and
@@ -525,17 +694,13 @@ def make_train_step(cfg, mesh: Mesh, num_microbatches: Optional[int] = None,
 
         ffn_impl = "pallas" if (flags.flag_value("pallas_ffn")
                                 and _ff.available()) else "stock"
-    dp, pp, cp, tp = (mesh.shape[a] for a in MESH_AXES)
     specs = param_specs(cfg)
-    shard_loss = _make_shard_loss(cfg, num_microbatches, dp, pp, tp, cp,
-                                  remat, attn_impl, ffn_impl)
+    loss_and_grads = _per_shard_loss_and_grads(
+        cfg, mesh, num_microbatches, remat, attn_impl, ffn_impl, with_stats)
     opt_specs = {"m": specs, "v": specs, "step": P()}
 
     def per_shard_step(params, opt, tokens, targets):
-        loss, grads = jax.value_and_grad(shard_loss)(params, tokens, targets)
-        with jax.named_scope("grad_sync"):
-            grads = sync_grads(grads, specs)
-            loss = lax.psum(loss, "dp")  # replicate the global mean for reporting
+        loss, grads, stats = loss_and_grads(params, tokens, targets)
         # global grad-norm² for clipping: local shards' sq-sums + psum over the
         # axes each leaf is sharded on (replicated leaves are already synced).
         with jax.named_scope("grad_norm"):
@@ -549,14 +714,59 @@ def make_train_step(cfg, mesh: Mesh, num_microbatches: Optional[int] = None,
                 sq = sq + (lax.psum(loc, shard_axes) if shard_axes else loc)
         with jax.named_scope("adamw"):
             new_params, new_opt = _adamw_update(params, grads, opt, hp, sq)
-        return new_params, new_opt, loss
+        return (new_params, new_opt, loss) + (stats,) * with_stats
 
     step = jax.shard_map(
         per_shard_step, mesh=mesh,
         in_specs=(specs, opt_specs, P("dp", "cp"), P("dp", "cp")),
-        out_specs=(specs, opt_specs, P()),
+        out_specs=(specs, opt_specs, P()) + (P(),) * with_stats,
         check_vma=False)
     return jax.jit(step, donate_argnums=(0, 1))
+
+
+def _per_shard_loss_and_grads(cfg, mesh: Mesh, num_microbatches: int,
+                              remat, attn_impl: str, ffn_impl: str,
+                              with_stats: bool, chosen: bool = False):
+    """The half of the train step before the optimizer, per shard:
+    (params, tokens, targets) -> (loss, synced grads, stats), the loss the
+    global-batch mean on every device, stats as `_make_shard_loss` has
+    them."""
+    dp, pp, cp, tp = (mesh.shape[a] for a in MESH_AXES)
+    specs = param_specs(cfg)
+    shard_loss = _make_shard_loss(cfg, num_microbatches, dp, pp, tp, cp,
+                                  remat, attn_impl, ffn_impl, with_stats,
+                                  chosen)
+
+    def loss_and_grads(params, tokens, targets):
+        (loss, stats), grads = jax.value_and_grad(shard_loss, has_aux=True)(
+            params, tokens, targets)
+        with jax.named_scope("grad_sync"):
+            grads = sync_grads(grads, specs)
+            loss = lax.psum(loss, "dp")  # replicate the global mean for reporting
+        return loss, grads, stats
+
+    return loss_and_grads
+
+
+def make_loss_and_grads(cfg: L.LlamaConfig, mesh: Mesh,
+                        num_microbatches: int = 1,
+                        remat: Union[bool, str] = True,
+                        attn_impl: str = "auto", ffn_impl: str = "stock",
+                        chosen: bool = False):
+    """The very loss `make_train_step` differentiates, without the
+    optimizer: jitted f(params, tokens, targets) -> (loss, grads, the
+    step's `MOE_STATS`) on the step's sharding layout, grads laid out like
+    the params. What a comparison with a reference reads before any
+    optimizer state exists; with `chosen` (a mesh of dp = pp = cp = 1) the
+    stats also hold "chosen" [launches, rows, top_k] i32, the experts each
+    launch of the routed FFN gave its rows, for a reference to send every
+    row where the program sent it."""
+    specs = param_specs(cfg)
+    f = _per_shard_loss_and_grads(cfg, mesh, num_microbatches, remat,
+                                  attn_impl, ffn_impl, True, chosen)
+    return jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=(specs, P("dp", "cp"), P("dp", "cp")),
+        out_specs=(P(), specs, P()), check_vma=False))
 
 
 def make_eval_step(cfg, mesh: Mesh, num_microbatches: int = 1, loss_fn=None,
@@ -593,7 +803,7 @@ def make_eval_step(cfg, mesh: Mesh, num_microbatches: int = 1, loss_fn=None,
                                   remat=False)
 
     def per_shard(params, tokens, targets):
-        return lax.psum(shard_loss(params, tokens, targets), "dp")
+        return lax.psum(shard_loss(params, tokens, targets)[0], "dp")
 
     f = jax.shard_map(per_shard, mesh=mesh,
                       in_specs=(specs, P("dp", "cp"), P("dp", "cp")),

@@ -14,8 +14,9 @@ of its output/input dim is valid.
 TPU-first choices: bf16 compute / f32 master params, static shapes, scan over
 stacked layer params (one compiled block body, not L unrolled layers), GQA,
 RoPE computed in f32, optional MoE (top-k routing through `route` and
-`routed_ffn`; the hybrid engine dispatches tokens with all_to_all over the
-ep axis) and optional QK-norm (`qk_normed`), as OLMoE has them or per head
+`routed_ffn`; the hybrid engine runs the same `routed_ffn_load` on dp = 1
+and dispatches tokens with all_to_all over the ep axis where dp > 1) and
+optional QK-norm (`qk_normed`), as OLMoE has them or per head
 as Qwen3-MoE and SDAR have it; `block_length` > 0 puts the full-sequence
 forward under SDAR's block-causal mask (generation by diffusion over
 blocks itself is the serving engine's, inference/serving/engine.py).
@@ -432,17 +433,20 @@ def require_uniform(cfg: LlamaConfig, what: str) -> None:
     """Raise for a config with a layer plan or a held share of its
     experts, in the name of a block body that runs one uniform stack of
     whole layers (`params["blocks"]` one dict, every expert) and would
-    compute another model under the config's name."""
+    compute another model under the config's name. The paths that take
+    both: `llama.forward`, `PagedServingEngine`, and the trainer
+    (`distributed.hybrid`, which names what it refuses itself,
+    `hybrid.require_trainable`)."""
     if cfg.layer_plan:
         raise NotImplementedError(
             f"{what} runs one uniform stack of layers and does not take a "
-            "layer plan (LlamaConfig.layer_plan); `llama.forward` and "
-            "`PagedServingEngine` do")
+            "layer plan (LlamaConfig.layer_plan); `llama.forward`, "
+            "`PagedServingEngine` and `distributed.hybrid` do")
     if cfg.experts_held:
         raise NotImplementedError(
             f"{what} holds every routed expert and does not take a chip's "
-            "share of them (LlamaConfig.experts_held); `llama.forward` and "
-            "`PagedServingEngine` do")
+            "share of them (LlamaConfig.experts_held); `llama.forward`, "
+            "`PagedServingEngine` and `distributed.hybrid` (on dp = 1) do")
 
 
 # Predefined sizes (the reference's headline configs; LLaMA-7B/13B per
@@ -652,30 +656,30 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto",
     block_length Bd > 0: the block-causal mask of generation by diffusion
     over blocks, position i sees j iff j // Bd <= i // Bd (full inside a
     block, causal across blocks), on the XLA path alone: the flash kernel
-    knows the causal mask only, and impl='flash' with it raises.
+    has no such mask, and impl='flash' with it raises.
 
     window W > 0: position i sees j iff i - W < j <= i (W keys, the
-    query's own among them), on the XLA path alone, as `block_length`.
+    query's own among them). The flash kernel has the window, forward and
+    backward (key blocks wholly behind it are neither fetched nor
+    multiplied), so 'auto' and 'flash' take it as they take the causal
+    mask; the XLA path, with its [T, T] scores, remains the CPU's.
     """
     if block_length and impl == "flash":
         raise ValueError("the flash kernel has no block-causal mask: "
                          "block_length > 0 takes impl='auto' or 'xla'")
-    if window and impl == "flash":
-        raise ValueError("the flash kernel has no window: window > 0 takes "
-                         "impl='auto' or 'xla'")
-    if block_length or window:
+    if block_length:
         impl = "xla"
     if impl == "flash":
         # explicit request: no silent fallback — unsupported shapes raise
         from ..ops.pallas import flash_attention as _fa
 
-        return _fa.flash_attention(q, k, v, causal=True)
+        return _fa.flash_attention(q, k, v, causal=True, window=window)
     if impl == "auto":
         from ..ops.pallas import flash_attention as _fa
 
         if (_fa.available() and q.shape[1] == k.shape[1]
                 and _fa.supported(q.shape, k.shape)):
-            return _fa.flash_attention(q, k, v, causal=True)
+            return _fa.flash_attention(q, k, v, causal=True, window=window)
     B, T, H, hd = q.shape
     KV = k.shape[2]
     if KV != H:
@@ -807,6 +811,15 @@ def held_pair_slots(rows: int, cfg: LlamaConfig) -> int:
     return min(slots + -slots % GMM_ROWS, pairs)
 
 
+def _fit(n: int, cap: int) -> int:
+    """The largest whole-lane (multiple of 128) divisor of n that is no
+    larger than cap; n itself where it is within the cap or has none."""
+    if n <= cap:
+        return n
+    return max((t for t in range(128, cap + 1, 128) if n % t == 0),
+               default=n)
+
+
 def _gmm(xs, w, group_sizes, group_offset):
     from jax.experimental.pallas.ops.tpu import megablox
     from ..ops.pallas import flash_attention as _fa
@@ -828,20 +841,46 @@ def _grouped_matmul(xs: jax.Array, w: jax.Array, group_sizes: jax.Array,
     128 x 1024 x 1024: the fastest of five tilings on the v5e). It is
     traced with x64 off, forward and backward: under this package's
     jax_enable_x64 it hands the kernel a 64-bit scalar, which the TPU
-    compiler refuses."""
+    compiler refuses.
+
+    The backward pass is the two products megablox's own vjp makes (the
+    rows' gradient g w^T through `gmm` with the weights transposed in the
+    kernel, the weights' gradient xs^T g a group through `tgmm`), each
+    under a tiling of its own: megablox hands both the forward's, and at
+    an expert of 2304 x 896, whose sides are no multiple of 1024 and so
+    whole tiles, `tgmm`'s float32 accumulator and its double-buffered
+    output are 17.3 MB of the chip's 16 MB of scoped VMEM (the v5e's
+    compiler refuses it). `tgmm` gets output tiles of at most 1024 x 1024
+    values in whole-lane divisors of K and N (`_fit`)."""
     with jax.enable_x64(False):
         return _gmm(xs, w, group_sizes, group_offset)
 
 
 def _grouped_matmul_fwd(xs, w, group_sizes, group_offset):
     with jax.enable_x64(False):
-        return jax.vjp(lambda a, b: _gmm(a, b, group_sizes, group_offset),
-                       xs, w)
+        return (_gmm(xs, w, group_sizes, group_offset),
+                (xs, w, group_sizes, group_offset))
 
 
-def _grouped_matmul_bwd(pull, g):
+def _grouped_matmul_bwd(res, g):
+    # the kernels themselves (the package's own name `gmm` is its custom_vjp)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    from ..ops.pallas import flash_attention as _fa
+
+    xs, w, group_sizes, group_offset = res
+    K, N = w.shape[-2:]
+    interpret = not _fa.available()
     with jax.enable_x64(False):
-        return (*pull(g), None, None)
+        dxs = gmm(
+            g, w, group_sizes, xs.dtype,
+            (GMM_ROWS, _fit(N, 1024), _fit(K, 1024)), group_offset,
+            transpose_rhs=True, interpret=interpret)
+        tk = _fit(K, 1024)
+        dw = tgmm(
+            xs.swapaxes(0, 1), g, group_sizes, w.dtype,
+            (GMM_ROWS, tk, _fit(N, 1024 * 1024 // tk)), group_offset,
+            w.shape[0], interpret=interpret)
+    return dxs, dw, None, None
 
 
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
@@ -896,10 +935,19 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
             a = jnp.where(keep, jax.nn.silu(dot(xs, "w1")) * dot(xs, "w3"), 0)
             ys = dot(a, "w2")
         with jax.named_scope("combine"):
-            ys = jnp.where(keep, ys, 0)[:slots].astype(jnp.float32)
+            ys = jnp.where(keep, ys, 0)[:slots]
             if slots == T * k:
+                # un-sorted in the products' own dtype and float32 from
+                # there on: a gather moves values and rounds none, so the
+                # sum is what it would be un-sorted in float32, and neither
+                # pass holds a float32 copy of all T*k rows in the sorted
+                # order as well (1.2 GB at a trainer's launch of 16,384
+                # rows, the difference between a step that fits the chip
+                # and one that does not)
                 y = jnp.take(ys, jnp.argsort(order), axis=0).reshape(T, k, -1)
-                return jnp.sum(y * w[..., None], axis=1).astype(h.dtype)
+                return jnp.sum(y.astype(jnp.float32) * w[..., None],
+                               axis=1).astype(h.dtype)
+            ys = ys.astype(jnp.float32)
             # [T, C]: a pair's router weight at (its row, its place), zero
             # elsewhere and behind the last group. As a float32 product
             # over the C rows it adds each into its row; on the v5e at
@@ -920,7 +968,8 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
 
 
 def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
-                    valid: Optional[jax.Array] = None, layer=None):
+                    valid: Optional[jax.Array] = None, layer=None,
+                    chosen: bool = False):
     """The routed SwiGLU experts over normed activations h [..., d]:
     sum_j w_j * (silu(h W1[e_j]) * (h W3[e_j])) W2[e_j] over a row's top-k
     experts. `valid` [...] bool marks the rows that exist (a serving tick
@@ -928,7 +977,10 @@ def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     counts in no load. With `layer` (an int32 scalar) `lp`'s w1, w3 and w2
     are the stacked [L, E, ...] leaves, as a layer loop that must not
     slice them hands them over; the router is the layer's own. Returns
-    (y [..., d], load [E] i32: valid rows on each expert). Scopes: router,
+    (y [..., d], load [E] i32: valid rows on each expert), and with
+    `chosen` a third member, the experts `route` gave each row ([T, k]
+    i32 over all experts, held here or not: what a comparison hands a
+    reference so that it sends no row elsewhere). Scopes: router,
     dispatch, experts, combine. With `cfg.shared_expert_width` the layer's
     shared expert (`ws1`, `ws3`, `ws2` of `lp`, ungated) is added on the
     valid rows under scope `shared_expert`."""
@@ -938,11 +990,10 @@ def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     valid = (jnp.ones((T,), bool) if valid is None
              else valid.reshape(-1))
     with jax.named_scope("router"):
-        w, e = route(h, lp, cfg)
-        if cfg.experts_held:
-            # from here on an expert is its place among the held ones; one
-            # held elsewhere falls outside [0, count) and into no group
-            e = e - cfg.held[0]
+        w, routed = route(h, lp, cfg)
+        # from here on an expert is its place among the held ones; one held
+        # elsewhere falls outside [0, count) and into no group
+        e = routed - cfg.held[0] if cfg.experts_held else routed
     with jax.named_scope("dispatch"):
         load = jnp.sum(jax.nn.one_hot(e, cfg.held[1], dtype=jnp.int32)
                        * valid[:, None, None], axis=(0, 1),
@@ -956,14 +1007,17 @@ def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
             shared = ffn(h, {"w1": lp["ws1"], "w3": lp["ws3"],
                              "w2": lp["ws2"]})
             y = y + jnp.where(valid[:, None], shared, 0)
-    return y.reshape(shape), load
+    out = (y.reshape(shape), load)
+    return out + (routed,) if chosen else out
 
 
 def routed_ffn(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
                valid: Optional[jax.Array] = None) -> jax.Array:
     """`routed_ffn_load` without the load (the one routed FFN of the tree:
-    `block`, `inference/llm.py` and the paged engine's tick call it; the
-    hybrid trainer dispatches over the ep axis with its own all_to_all)."""
+    `block`, `inference/llm.py`, the paged engine's tick and the hybrid
+    trainer on dp = 1 call it or `routed_ffn_load`; only where dp > 1
+    exchanges the experts does the trainer dispatch over the ep axis with
+    its own all_to_all, `hybrid._moe_ffn`)."""
     return routed_ffn_load(h, lp, cfg, valid)[0]
 
 

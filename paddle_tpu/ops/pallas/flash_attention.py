@@ -11,6 +11,11 @@ is the standard online-softmax tiling written for the TPU memory hierarchy:
   VMEM, with the MXU doing the two matmuls per tile in f32 accumulation;
 - causal skipping via predicated iterations (`pl.when`): blocks strictly above
   the diagonal are never computed;
+- a static sliding `window` W (position i sees j iff i - W < j <= i): the
+  kv dimension of the grid shrinks to the few blocks a q block can see, and
+  the index maps start it at the first of them, so blocks wholly behind the
+  window are neither fetched nor multiplied, forward and backward (the
+  dk/dv kernel walks the q blocks that can see a kv block the same way);
 - GQA handled with BlockSpec index maps (q head h reads kv head h // group) —
   no materialized jnp.repeat of K/V;
 - backward = recomputation kernels (dq; dk/dv) from the saved logsumexp, the
@@ -127,6 +132,46 @@ def supported(q_shape, k_shape) -> bool:
 
 
 # ---------------------------------------------------------------------------
+# The window's walk: which blocks of the other axis a block can see
+# ---------------------------------------------------------------------------
+
+def _kv_span(i, block_q: int, block_k: int, window: int):
+    """(first, last) kv block that q block `i` sees under a causal window:
+    its rows are i*bq .. i*bq + bq - 1 and row r sees r - W + 1 .. r. On
+    int32 values: a grid index inside a kernel or an index map, an np.int32
+    in a test (`_kv_steps` is the same on python ints)."""
+    first = jax.lax.div(jnp.maximum(i * _i32(block_q) - _i32(window - 1),
+                                    _i32(0)), _i32(block_k))
+    last = jax.lax.div(i * _i32(block_q) + _i32(block_q - 1), _i32(block_k))
+    return first, last
+
+
+def _q_span(jk, block_q: int, block_k: int, window: int, nq: int):
+    """(first, last) q block that sees kv block `jk` under a causal window:
+    column c is seen by rows c .. c + W - 1."""
+    first = jax.lax.div(jk * _i32(block_k), _i32(block_q))
+    last = jnp.minimum(
+        jax.lax.div(jk * _i32(block_k) + _i32(block_k + window - 2),
+                    _i32(block_q)), _i32(nq - 1))
+    return first, last
+
+
+def _kv_steps(nq: int, block_q: int, block_k: int, window: int) -> int:
+    """The most kv blocks a q block sees (`_kv_span` on python ints): the
+    kv dimension of the window's grid."""
+    return max((i * block_q + block_q - 1) // block_k
+               - max(i * block_q - (window - 1), 0) // block_k + 1
+               for i in range(nq))
+
+
+def _q_steps(nk: int, block_q: int, block_k: int, window: int,
+             nq: int) -> int:
+    """The most q blocks that see a kv block (`_q_span` on python ints)."""
+    return max(min((jk * block_k + block_k + window - 2) // block_q, nq - 1)
+               - (jk * block_k) // block_q + 1 for jk in range(nk))
+
+
+# ---------------------------------------------------------------------------
 # Forward kernel
 # ---------------------------------------------------------------------------
 
@@ -140,21 +185,23 @@ def _seg_mask(qs_ref, ks_ref, block_k: int):
 
 
 def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
-                block_k: int, has_seg: bool):
+                block_k: int, has_seg: bool, window: int = 0):
     if has_seg:
         q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref, acc, m_sc, l_sc = refs
     else:
         q_ref, k_ref, v_ref, o_ref, lse_ref, acc, m_sc, l_sc = refs
         qs_ref = ks_ref = None
-    i, j = pl.program_id(2), pl.program_id(3)
+    i, step = pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _():
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
         acc[:] = jnp.zeros_like(acc)
 
+    # window: the grid's steps count from the first kv block q block i sees
+    j = step + _kv_span(i, block_q, block_k, window)[0] if window else step
     # causal: kv block j is needed iff its first col <= last row of q block i
     needed = (not causal) or (j * block_k <= i * block_q + block_q - 1)
 
@@ -168,7 +215,10 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         if causal:
             rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + i * block_q
             cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_k
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            seen = rows >= cols
+            if window:
+                seen = seen & (cols > rows - window)
+            s = jnp.where(seen, s, NEG_INF)
         if has_seg:
             # With causal=True every row keeps its diagonal entry (a token is
             # always in its own segment), so no all-NEG_INF row can poison
@@ -177,6 +227,10 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         m_prev = m_sc[:, :1]                          # [Bq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)                        # [Bq, Bk]
+        if window:
+            # a row may see no key of an early block of its span (its own
+            # comes last): exp(NEG_INF - NEG_INF) = 1 must not count
+            p = jnp.where(seen, p, np.float32(0))
         alpha = jnp.exp(m_prev - m_new)               # [Bq, 1]
         l_sc[:] = l_sc[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         v = v_ref[0, 0].astype(jnp.float32)           # [Bk, hd]
@@ -184,7 +238,7 @@ def _fwd_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
 
-    @pl.when(j == nj - 1)
+    @pl.when(step == nj - 1)
     def _():
         l = l_sc[:, :1]
         o_ref[0, 0] = (acc[:] / l).astype(o_ref.dtype)
@@ -202,11 +256,25 @@ def _seg_carriers(q_seg, kv_seg):
     return qs, ks
 
 
+def _kv_index(block_q: int, block_k: int, window: int):
+    """The kv block of grid step j for q block i: j itself, or under a
+    window the j-th block of q block i's span, held at its last (a block
+    index that does not change is not fetched again)."""
+    if not window:
+        return lambda i, j: j
+
+    def at(i, j):
+        first, last = _kv_span(i, block_q, block_k, window)
+        return jnp.minimum(first + j, last)
+    return at
+
+
 def _fwd(q, k, v, sm_scale: float, causal: bool, interpret: bool,
-         q_seg=None, kv_seg=None):
+         q_seg=None, kv_seg=None, window: int = 0):
     """q [B, H, T, hd]; k/v [B, KV, S, hd] →
     (o [B, H, T, hd], lse [B, H, T, LANES] lane-broadcast).
-    q_seg/kv_seg: optional [B, T] / [B, S] int32 segment ids (varlen)."""
+    q_seg/kv_seg: optional [B, T] / [B, S] int32 segment ids (varlen).
+    window W > 0 (causal): row i sees columns i - W + 1 .. i."""
     B, H, T, hd = q.shape
     KV, S = k.shape[1], k.shape[2]
     G = H // KV
@@ -215,9 +283,12 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, interpret: bool,
     if has_seg and bk % SEG_LANES != 0:
         raise ValueError(f"segment ids need block_k % {SEG_LANES} == 0; "
                          f"got block_k={bk} (S={S})")
-    grid = (B, H, T // bq, S // bk)
+    kv_at = _kv_index(bq, bk, window)
+    grid = (B, H, T // bq,
+            _kv_steps(T // bq, bq, bk, window) if window else S // bk)
     kernel = functools.partial(_fwd_kernel, sm_scale=np.float32(sm_scale), causal=causal,
-                               block_q=bq, block_k=bk, has_seg=has_seg)
+                               block_q=bq, block_k=bk, has_seg=has_seg,
+                               window=window)
     mem = {"memory_space": pltpu.VMEM}
     scratch = [
         pltpu.VMEM((bq, hd), jnp.float32),
@@ -226,15 +297,15 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, interpret: bool,
     ]
     in_specs = [
         pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, _i32(0)), **mem),
-        pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, jax.lax.div(h, _i32(G)), j, _i32(0)), **mem),
-        pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, jax.lax.div(h, _i32(G)), j, _i32(0)), **mem),
+        pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, jax.lax.div(h, _i32(G)), kv_at(i, j), _i32(0)), **mem),
+        pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, jax.lax.div(h, _i32(G)), kv_at(i, j), _i32(0)), **mem),
     ]
     inputs = [q, k, v]
     if has_seg:
         qs, ks = _seg_carriers(q_seg, kv_seg)
         in_specs += [
             pl.BlockSpec((1, bq, SEG_LANES), lambda b, h, i, j: (b, i, _i32(0)), **mem),
-            pl.BlockSpec((1, SEG_SUBLANES, bk), lambda b, h, i, j: (b, _i32(0), j), **mem),
+            pl.BlockSpec((1, SEG_SUBLANES, bk), lambda b, h, i, j: (b, _i32(0), kv_at(i, j)), **mem),
         ]
         inputs += [qs, ks]
     out_specs = [
@@ -268,20 +339,21 @@ def _fwd(q, k, v, sm_scale: float, causal: bool, interpret: bool,
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
-               block_k: int, has_seg: bool):
+               block_k: int, has_seg: bool, window: int = 0):
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
          dq_ref, dq_acc) = refs
     else:
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc = refs
         qs_ref = ks_ref = None
-    i, j = pl.program_id(2), pl.program_id(3)
+    i, step = pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
+    j = step + _kv_span(i, block_q, block_k, window)[0] if window else step
     needed = (not causal) or (j * block_k <= i * block_q + block_q - 1)
 
     @pl.when(needed)
@@ -298,7 +370,10 @@ def _dq_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         if causal:
             rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + i * block_q
             cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_k
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            seen = rows >= cols
+            if window:
+                seen = seen & (cols > rows - window)
+            s = jnp.where(seen, s, NEG_INF)
         if has_seg:
             s = jnp.where(_seg_mask(qs_ref, ks_ref, block_k), s, NEG_INF)
         p = jnp.exp(s - lse)                          # [Bq, Bk]
@@ -308,13 +383,14 @@ def _dq_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         dq_acc[:] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    @pl.when(j == nj - 1)
+    @pl.when(step == nj - 1)
     def _():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
-                block_k: int, group: int, has_seg: bool):
+                block_k: int, group: int, has_seg: bool, window: int = 0,
+                num_q_blocks: int = 0):
     if has_seg:
         (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qs_ref, ks_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
@@ -325,16 +401,24 @@ def _dkv_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
     # grid: (B, KV, kv_block, g, q_block)
     jk = pl.program_id(2)
     g = pl.program_id(3)
-    iq = pl.program_id(4)
+    step = pl.program_id(4)
     nq = pl.num_programs(4)
 
-    @pl.when((g == 0) & (iq == 0))
+    @pl.when((g == 0) & (step == 0))
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # causal: q block iq contributes iff its last row >= kv block's first col
-    needed = (not causal) or (iq * block_q + block_q - 1 >= jk * block_k)
+    if window:
+        # the grid's steps count from the first q block that sees kv block
+        # jk, and stop counting at the last one that does
+        first, last = _q_span(jk, block_q, block_k, window, num_q_blocks)
+        iq = step + first
+        needed = iq <= last
+    else:
+        iq = step
+        # causal: q block iq contributes iff its last row >= kv block's first col
+        needed = (not causal) or (iq * block_q + block_q - 1 >= jk * block_k)
 
     @pl.when(needed)
     def _():
@@ -350,7 +434,10 @@ def _dkv_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         if causal:
             rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + iq * block_q
             cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + jk * block_k
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            seen = rows >= cols
+            if window:
+                seen = seen & (cols > rows - window)
+            s = jnp.where(seen, s, NEG_INF)
         if has_seg:
             s = jnp.where(_seg_mask(qs_ref, ks_ref, block_k), s, NEG_INF)
         p = jnp.exp(s - lse)                          # [Bq, Bk]
@@ -362,27 +449,34 @@ def _dkv_kernel(*refs, sm_scale: float, causal: bool, block_q: int,
         dk_acc[:] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    @pl.when((g == group - 1) & (iq == nq - 1))
+    @pl.when((g == group - 1) & (step == nq - 1))
     def _():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bwd(sm_scale, causal, interpret, res, do):
+def _bwd(sm_scale, causal, interpret, window, res, do):
     q, k, v, o, lse, q_seg, kv_seg = res              # lse [B, H, T, LANES]
     B, H, T, hd = q.shape
     KV, S = k.shape[1], k.shape[2]
     G = H // KV
     bq, bk = _pick_block(T), _pick_block(S)
     has_seg = q_seg is not None
+    kv_at = _kv_index(bq, bk, window)
+    if window:
+        def q_at(jk, iq):
+            first, last = _q_span(jk, bq, bk, window, T // bq)
+            return jnp.minimum(first + iq, last)
+    else:
+        q_at = lambda jk, iq: iq
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], (B, H, T, LANES))
     mem = {"memory_space": pltpu.VMEM}
 
     dq_in_specs = [
         pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, _i32(0)), **mem),
-        pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, jax.lax.div(h, _i32(G)), j, _i32(0)), **mem),
-        pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, jax.lax.div(h, _i32(G)), j, _i32(0)), **mem),
+        pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, jax.lax.div(h, _i32(G)), kv_at(i, j), _i32(0)), **mem),
+        pl.BlockSpec((1, 1, bk, hd), lambda b, h, i, j: (b, jax.lax.div(h, _i32(G)), kv_at(i, j), _i32(0)), **mem),
         pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, _i32(0)), **mem),
         pl.BlockSpec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, _i32(0)), **mem),
         pl.BlockSpec((1, 1, bq, LANES), lambda b, h, i, j: (b, h, i, _i32(0)), **mem),
@@ -392,7 +486,7 @@ def _bwd(sm_scale, causal, interpret, res, do):
         qs, ks = _seg_carriers(q_seg, kv_seg)
         dq_in_specs += [
             pl.BlockSpec((1, bq, SEG_LANES), lambda b, h, i, j: (b, i, _i32(0)), **mem),
-            pl.BlockSpec((1, SEG_SUBLANES, bk), lambda b, h, i, j: (b, _i32(0), j), **mem),
+            pl.BlockSpec((1, SEG_SUBLANES, bk), lambda b, h, i, j: (b, _i32(0), kv_at(i, j)), **mem),
         ]
         dq_inputs += [qs, ks]
     dq_out_spec = pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, _i32(0)),
@@ -403,9 +497,11 @@ def _bwd(sm_scale, causal, interpret, res, do):
     count_launch()
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=np.float32(sm_scale), causal=causal,
-                          block_q=bq, block_k=bk, has_seg=has_seg),
+                          block_q=bq, block_k=bk, has_seg=has_seg,
+                          window=window),
         name="flash_attention_dq",
-        grid=(B, H, T // bq, S // bk),
+        grid=(B, H, T // bq,
+              _kv_steps(T // bq, bq, bk, window) if window else S // bk),
         in_specs=dq_in_specs,
         out_specs=dq_out_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, hd), q.dtype),
@@ -415,23 +511,23 @@ def _bwd(sm_scale, causal, interpret, res, do):
 
     dkv_in_specs = [
         pl.BlockSpec((1, 1, bq, hd),
-                     lambda b, kv, jk, g, iq: (b, kv * G + g, iq, _i32(0)), **mem),
+                     lambda b, kv, jk, g, iq: (b, kv * G + g, q_at(jk, iq), _i32(0)), **mem),
         pl.BlockSpec((1, 1, bk, hd),
                      lambda b, kv, jk, g, iq: (b, kv, jk, _i32(0)), **mem),
         pl.BlockSpec((1, 1, bk, hd),
                      lambda b, kv, jk, g, iq: (b, kv, jk, _i32(0)), **mem),
         pl.BlockSpec((1, 1, bq, hd),
-                     lambda b, kv, jk, g, iq: (b, kv * G + g, iq, _i32(0)), **mem),
+                     lambda b, kv, jk, g, iq: (b, kv * G + g, q_at(jk, iq), _i32(0)), **mem),
         pl.BlockSpec((1, 1, bq, LANES),
-                     lambda b, kv, jk, g, iq: (b, kv * G + g, iq, _i32(0)), **mem),
+                     lambda b, kv, jk, g, iq: (b, kv * G + g, q_at(jk, iq), _i32(0)), **mem),
         pl.BlockSpec((1, 1, bq, LANES),
-                     lambda b, kv, jk, g, iq: (b, kv * G + g, iq, _i32(0)), **mem),
+                     lambda b, kv, jk, g, iq: (b, kv * G + g, q_at(jk, iq), _i32(0)), **mem),
     ]
     dkv_inputs = [q, k, v, do, lse, delta]
     if has_seg:
         dkv_in_specs += [
             pl.BlockSpec((1, bq, SEG_LANES),
-                         lambda b, kv, jk, g, iq: (b, iq, _i32(0)), **mem),
+                         lambda b, kv, jk, g, iq: (b, q_at(jk, iq), _i32(0)), **mem),
             pl.BlockSpec((1, SEG_SUBLANES, bk),
                          lambda b, kv, jk, g, iq: (b, _i32(0), jk), **mem),
         ]
@@ -449,9 +545,12 @@ def _bwd(sm_scale, causal, interpret, res, do):
     count_launch()
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=np.float32(sm_scale), causal=causal,
-                          block_q=bq, block_k=bk, group=G, has_seg=has_seg),
+                          block_q=bq, block_k=bk, group=G, has_seg=has_seg,
+                          window=window, num_q_blocks=T // bq),
         name="flash_attention_dkv",
-        grid=(B, KV, S // bk, G, T // bq),
+        grid=(B, KV, S // bk, G,
+              _q_steps(S // bk, bq, bk, window, T // bq) if window
+              else T // bq),
         in_specs=dkv_in_specs,
         out_specs=dkv_out_specs,
         out_shape=[
@@ -472,14 +571,16 @@ def _bwd(sm_scale, causal, interpret, res, do):
 # Public API (custom_vjp over the BHTD kernels, BTHD at the boundary)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_bhtd_seg(q, k, v, q_seg, kv_seg, sm_scale, causal, interpret):
-    o, _ = _fwd(q, k, v, sm_scale, causal, interpret, q_seg, kv_seg)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_bhtd_seg(q, k, v, q_seg, kv_seg, sm_scale, causal, interpret,
+                    window=0):
+    o, _ = _fwd(q, k, v, sm_scale, causal, interpret, q_seg, kv_seg, window)
     return o
 
 
-def _flash_bhtd_seg_fwd(q, k, v, q_seg, kv_seg, sm_scale, causal, interpret):
-    o, lse = _fwd(q, k, v, sm_scale, causal, interpret, q_seg, kv_seg)
+def _flash_bhtd_seg_fwd(q, k, v, q_seg, kv_seg, sm_scale, causal, interpret,
+                        window=0):
+    o, lse = _fwd(q, k, v, sm_scale, causal, interpret, q_seg, kv_seg, window)
     return o, (q, k, v, o, lse, q_seg, kv_seg)
 
 
@@ -488,13 +589,15 @@ _flash_bhtd_seg.defvjp(_flash_bhtd_seg_fwd, _bwd)
 
 def _flash_bhtd(q, k, v, sm_scale, causal, interpret):
     """Segment-free entry (kept: the train step and AOT smoke target it)."""
-    return _flash_bhtd_seg(q, k, v, None, None, sm_scale, causal, interpret)
+    return _flash_bhtd_seg(q, k, v, None, None, sm_scale, causal, interpret,
+                           0)
 
 
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     interpret: Optional[bool] = None,
-                    q_segment_ids=None, kv_segment_ids=None):
+                    q_segment_ids=None, kv_segment_ids=None,
+                    window: int = 0):
     """Fused attention. q [B, T, H, hd], k/v [B, S, KV, hd] → [B, T, H, hd].
 
     GQA when H > KV (H % KV == 0). `interpret` forces the Pallas interpreter
@@ -504,6 +607,11 @@ def flash_attention(q, k, v, causal: bool = True,
     same-segment pairs (varlen/unpadded packing; the flash_attn_unpadded op).
     Rows must be self-aligned (token t's kv t shares its segment) so every
     row keeps >= 1 valid key — guaranteed for packed self-attention.
+
+    window W > 0 (static, causal only): position i sees j iff
+    i - W < j <= i, W keys with the query's own among them. Key blocks
+    wholly behind the window are neither fetched nor multiplied, forward and
+    backward; a window of T or more is the causal mask and runs as one.
     """
     B, T, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]
@@ -514,6 +622,13 @@ def flash_attention(q, k, v, causal: bool = True,
                          "use the XLA attention path")
     if (q_segment_ids is None) != (kv_segment_ids is None):
         raise ValueError("pass both q_segment_ids and kv_segment_ids or neither")
+    if window and not causal:
+        raise ValueError("a window is a causal window: window > 0 needs "
+                         "causal=True")
+    if window < 0:
+        raise ValueError(f"window={window}")
+    if window >= T:
+        window = 0                   # every earlier key is inside it
     if sm_scale is None:
         sm_scale = 1.0 / (hd ** 0.5)
     if interpret is None:
@@ -522,7 +637,8 @@ def flash_attention(q, k, v, causal: bool = True,
     kt = jnp.swapaxes(k, 1, 2)       # [B, KV, S, hd]
     vt = jnp.swapaxes(v, 1, 2)
     o = _flash_bhtd_seg(qt, kt, vt, q_segment_ids, kv_segment_ids,
-                        float(sm_scale), bool(causal), bool(interpret))
+                        float(sm_scale), bool(causal), bool(interpret),
+                        int(window))
     return jnp.swapaxes(o, 1, 2)
 
 

@@ -87,6 +87,24 @@ def test_flash_attention_compiles(one_chip, q_shape, kv_shape, grad):
     assert compiled.as_text().count("tpu_custom_call") >= (3 if grad else 1)
 
 
+@pytest.mark.parametrize("window", [1024, 700])
+def test_windowed_flash_attention_compiles_at_mellum_widths(one_chip, window):
+    """The trainer's window layer at the timed shapes: 2 sequences of 8192,
+    32 query heads over 4 key-value heads of 128, a window of 1024 (and one
+    that is no multiple of a block), forward, dq and dkv; the index maps
+    clamp on int32 and the grids are the window's span."""
+    def loss(q, k, v):
+        o = fa._flash_bhtd_seg(q, k, v, None, None,
+                               np.float32(HEAD_DIM ** -0.5), True, False,
+                               window)
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+                        _bf16(2, 32, 8192, HEAD_DIM), _bf16(2, 4, 8192, HEAD_DIM),
+                        _bf16(2, 4, 8192, HEAD_DIM))
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
 @pytest.mark.parametrize("rows, d, d_ff", [
     (SEQ, D, D_FF),          # the smoke's train step, batch 1
     (2 * SEQ, D, D_FF),
@@ -462,6 +480,34 @@ def test_routed_ffn_on_stacked_experts_compiles_without_a_copy(one_chip,
     made = re.findall(r"%(\S+) = bf16\[(?:64|1024),(?:2048|1024),"
                       r"(?:1024|2048)\]\S* (\w[\w-]*)\(", text)
     assert {op for _, op in made} <= {"parameter", "bitcast"}, made
+
+
+@pytest.mark.parametrize("k, n", [(2304, 896), (896, 2304)])
+def test_grouped_matmul_trains_at_mellum_widths(one_chip, k, n):
+    """The sorted expert form's grouped product forward and backward at
+    Mellum2-12B-A2.5B's expert (2304 x 896: w1 / w3 one way, w2 the
+    other), 16 held experts. The weights' gradient is megablox's `tgmm`
+    under `_grouped_matmul`'s own tiling: under the forward's (an
+    expert's sides are no multiple of 1024, so whole) its accumulator and
+    output tile pass the chip's 16 MB of scoped VMEM (17.31 MB; PR 47 read
+    the refusal here before any chip call)."""
+    from paddle_tpu.models import llama as L
+
+    def loss(xs, w, sizes):
+        y = L._grouped_matmul(xs, w, sizes, jnp.zeros((), jnp.int32))
+        return jnp.sum(y.astype(jnp.float32))
+
+    available = fa.available
+    fa.available = lambda: True         # `interpret` reads it
+    try:
+        text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), one_chip,
+                        _bf16(2048, k), _bf16(16, k, n),
+                        ((16,), jnp.int32)).as_text()
+    finally:
+        fa.available = available
+    assert text.count("tpu_custom_call") >= 3     # gmm, gmm transposed, tgmm
+    assert L._fit(2304, 1024) == 768 and L._fit(896, 1024) == 896
+    assert L._fit(2304, 1024 * 1024 // 896) == 1152
 
 
 # SDAR-30B-A3B-Chat (benchmark/configs/sdar30b-a3b-serve.json): hidden 2048,

@@ -175,3 +175,100 @@ def test_kernel_constants_are_f32():
     # Under jax_enable_x64 a bare python float is weak f64 and the resulting
     # f64->f32 convert fails Mosaic legalization (tpu.truncf). Pin the dtype.
     assert np.asarray(fa.NEG_INF).dtype == np.float32
+
+
+# --------------------------------------------------------------------------
+# the sliding window (position i sees j iff i - W < j <= i)
+# --------------------------------------------------------------------------
+
+def ref_window_attention(q, k, v, window):
+    """`ref_attention` under a causal window of `window` keys."""
+    B, T, H, hd = q.shape
+    KV = k.shape[2]
+    if KV != H:
+        k = jnp.repeat(k, H // KV, axis=2)
+        v = jnp.repeat(v, H // KV, axis=2)
+    s = jnp.einsum("bthd,bshd->bhts", q, k) / (hd ** 0.5)
+    pos = jnp.arange(T)
+    mask = (pos[None, :] <= pos[:, None]) & (pos[None, :] > pos[:, None] - window)
+    p = jax.nn.softmax(jnp.where(mask[None, None], s, -1e30), axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", p, v)
+
+
+# T 768 = 3 blocks of 256: a window smaller than, equal to and larger than
+# a block, and one that is no multiple of anything; T 96 = 3 blocks of 32
+WINDOW_CASES = [(768, 100), (768, 256), (768, 350), (768, 512),
+                (96, 1), (96, 32), (96, 33), (96, 95)]
+
+
+@pytest.mark.parametrize("T,window", WINDOW_CASES)
+def test_window_forward_matches_reference(T, window):
+    B, H, KV, hd = 1, 2, 1, 32
+    q, k, v = (_rand((B, T, n, hd), s) for n, s in ((H, 20), (KV, 21), (KV, 22)))
+    out = fa.flash_attention(q, k, v, window=window, interpret=True)
+    ref = ref_window_attention(q, k, v, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("which", ["dq", "dk", "dv"])
+@pytest.mark.parametrize("T,window", [(768, 100), (768, 256), (768, 350),
+                                      (96, 33)])
+def test_window_gradients_match_reference(T, window, which):
+    B, H, KV, hd = 1, 2, 1, 32
+    q, k, v = (_rand((B, T, n, hd), s) for n, s in ((H, 23), (KV, 24), (KV, 25)))
+    arg = "dq dk dv".split().index(which)
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+
+    got = jax.grad(loss(lambda q, k, v: fa.flash_attention(
+        q, k, v, window=window, interpret=True)), argnums=arg)(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: ref_window_attention(
+        q, k, v, window)), argnums=arg)(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=5e-4, atol=5e-5)
+
+
+def test_window_walks_only_the_blocks_it_sees():
+    """The grids shrink to the window's span: at T 8192 in blocks of 512 a
+    window of 1024 visits 3 kv blocks a q block and 3 q blocks a kv block
+    where the causal kernels step through 16."""
+    assert fa._kv_steps(16, 512, 512, 1024) == 3
+    assert fa._q_steps(16, 512, 512, 1024, 16) == 3
+    assert fa._kv_steps(16, 512, 512, 512) == 2
+    assert fa._kv_steps(16, 512, 512, 1) == 1
+    for i in range(16):      # the traced spans are the counted ones
+        first, last = fa._kv_span(np.int32(i), 512, 512, 1024)
+        assert (int(first), int(last)) == (max(i - 2, 0), i)
+        first, last = fa._q_span(np.int32(i), 512, 512, 1024, 16)
+        assert (int(first), int(last)) == (i, min(i + 2, 15))
+
+
+@pytest.mark.parametrize("window", [0, 128, 4096])
+def test_no_window_is_todays_program(window):
+    """window=0, and a window no shorter than the sequence, trace the very
+    kernels the causal path traced before there was a window: the same
+    jaxpr, forward and backward, and so bit-equal outputs."""
+    B, T, H, hd = 1, 128, 2, 32
+    q, k, v = (_rand((B, T, H, hd), s) for s in (26, 27, 28))
+
+    def run(**kw):
+        f = lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, interpret=True, **kw) ** 2)
+        return jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, k, v)
+
+    plain = run()
+    if window == 0 or window >= T:
+        assert str(run(window=window)) == str(plain)
+        a = fa.flash_attention(q, k, v, interpret=True, window=window)
+        b = fa.flash_attention(q, k, v, interpret=True)
+        assert (np.asarray(a) == np.asarray(b)).all()
+    else:
+        assert str(run(window=window)) != str(plain)
+
+
+def test_window_needs_the_causal_mask():
+    q = _rand((1, 64, 2, 32), 29)
+    with pytest.raises(ValueError, match="causal"):
+        fa.flash_attention(q, q, q, causal=False, window=8, interpret=True)

@@ -282,9 +282,17 @@ def test_what_a_layer_plan_refuses_raises_in_use(tiny):
         eng.ingest_pages({})
     with pytest.raises(ValueError, match="window_blocks"):
         engine(cfg, params, window_blocks=4)
+    # the flash kernel has no block-causal mask; since PR 47 it has the
+    # window, and gives what the XLA path gives
     with pytest.raises(ValueError, match="flash"):
         L.attention(jnp.zeros((1, 8, 2, 16)), jnp.zeros((1, 8, 2, 16)),
-                    jnp.zeros((1, 8, 2, 16)), impl="flash", window=4)
+                    jnp.zeros((1, 8, 2, 16)), impl="flash", block_length=4)
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (1, 8, 2, 16))
+               for i in range(3))
+    np.testing.assert_allclose(
+        np.asarray(L.attention(q, k, v, impl="flash", window=4)),
+        np.asarray(L.attention(q, k, v, impl="xla", window=4)),
+        rtol=2e-5, atol=2e-5)
 
 
 # ---- a uniform config is what it was -----------------------------------------
