@@ -59,10 +59,15 @@ experts and leaves out what the absent ones would add; nothing here
 stands in for the chips that hold them. In the sorted form only those
 pairs are gathered, multiplied and added into their rows (the COMPACT form
 of `_experts_sorted`): they get `held_pair_slots` places, `HELD_ROOM`
-times what an even router sends to a share of that size, in whole tiles of
-the kernel's rows, and a launch with more held pairs than places takes
-the form that moves all T*k pairs, which is also the one form of a config
-that holds every expert; no pair is ever dropped.
+times what an even router sends to a share of that size but never more
+than half of all pairs, in whole tiles of the kernel's rows, and a launch
+with more held pairs than places takes the form that moves all T*k pairs,
+which is also the one form of a config that holds every expert; no pair is
+ever dropped. The way from rows to places and the way back are one pair of
+transposed gathers (`_dispatch`, `_combine`: each is the other's
+derivative, so a trained launch adds no d-wide row into place one at a
+time), and the way back is the [T, C] product where the places are few
+(`combine_is_a_product`, from the launch's shapes and two measured rates).
 """
 from __future__ import annotations
 
@@ -792,7 +797,12 @@ GMM_ROWS = 128      # the grouped-matmul kernel's row tile
 # 48 standard deviations off); what the factor leaves room for is a router
 # that prefers the held experts, which PR 41 saw carry 2.5-3.9 % of the
 # pairs by seed against the even 3.125 %, and a trained one's hot experts.
-# Past it nothing is dropped: the tick takes the whole form.
+# Under a share the places never pass HALF of all pairs either
+# (`held_pair_slots`): the compact form exists to move well under all of
+# them, and at a share of a quarter (Mellum2's 16 of 64) four times the
+# even count would be every place, the whole form under another name. Past
+# the places nothing is dropped: the launch takes the whole form, which
+# then costs at most twice what its held pairs need.
 HELD_ROOM = 4
 
 
@@ -800,14 +810,15 @@ def held_pair_slots(rows: int, cfg: LlamaConfig) -> int:
     """The places `_experts_sorted` gives the (row, expert) pairs of a
     launch of `rows` rows: all rows * top_k of them where every expert is
     held; under a held share `HELD_ROOM` times what an even router sends
-    to the held experts (rows * top_k * count / num_experts, rounded up),
-    in whole tiles of `GMM_ROWS`, and never more than all. A function of
-    the launch's rows, `top_k` and the config's share alone."""
+    to the held experts (rows * top_k * count / num_experts, rounded up)
+    or half of all pairs, whichever is fewer, in whole tiles of `GMM_ROWS`,
+    and never more than all. A function of the launch's rows, `top_k` and
+    the config's share alone."""
     pairs = rows * cfg.top_k
     if not cfg.experts_held:
         return pairs
     even = -(-pairs * cfg.held[1] // cfg.num_experts)
-    slots = HELD_ROOM * even
+    slots = min(HELD_ROOM * even, pairs // 2)
     return min(slots + -slots % GMM_ROWS, pairs)
 
 
@@ -886,6 +897,131 @@ def _grouped_matmul_bwd(res, g):
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
+# The sorted order is a partial permutation: place p < sum(load) holds
+# exactly one (row, choice) pair. So the way from rows to places (DISPATCH:
+# xs[p] = h[row of p], a gather of C rows) and the way back (COMBINE: y[t]
+# = sum_j w[t, j] * ys[place of (t, j)], a gather of a row's k places, or
+# the [T, C] product where C is few) are each other's transposes, and the
+# derivative of either is the other: no scatter-add of d-wide rows in
+# either direction (JAX's transpose of a `jnp.take` is one; on the v5e
+# PR 42 read 0.8 us a row of 7,168 for it, and PR 47's step spent 231 ms
+# of 672 under the two scopes).
+#
+# The two rates of the way back, on the TPU v5e (PR 48's chip runs, call 1,
+# bf16 rows, host clock around a jitted call less 0.5 ms of launch; PERF.md
+# section 6). The product: 83.2 ms at T 16,384, C 65,536, d 2,304
+# (Mellum2's launch) and 3.68 ms at T 2,048, C 8,192, d 5,120 (dots3's
+# chunk) = 3.3e-14 and 3.7e-14 s a (row, place) and lane of d. The gather:
+# 7.91 ms at Mellum2's launch (a quarter of its T * k pairs held) and 1.22
+# ms at dots3's chunk (an eighth) = 2.5e-11 and 0.9e-11 s a (row, choice)
+# and lane; the slower one stands here, the one read at the share the
+# places are made for. Both grow with T * d, so the crossing is a count of
+# places a choice: 700, i.e. 5,600 places at k 8 (`combine_is_a_product`).
+_PRODUCT_PLACE_S = 3.5e-14
+_GATHER_PAIR_S = 2.45e-11
+
+
+def combine_is_a_product(places: int, rows: int, top_k: int) -> bool:
+    """Whether C = `places` places go back into the launch's `rows` rows as
+    the [T, C] float32 product (C few: a row meets every place once, on the
+    MXU) or as the gather of a row's k places: the product's T * C * d
+    against the gather's T * k * d at the two measured rates, so a count of
+    places a choice, from the launch's shapes alone. Where every pair has
+    its place (C = T * k: every expert held, or the fallback of a share) it
+    is the gather whatever the count: the un-sort then moves exactly the
+    rows that are summed, and a serving tick of a few rows is bound by its
+    launches either way (the rates were read at 2,048 and 16,384 rows)."""
+    return (places < rows * top_k
+            and places * _PRODUCT_PLACE_S < top_k * _GATHER_PAIR_S)
+
+
+def _to_places(x, pair, n, k: int):
+    """Rows to places: out[p] = x[pair[p] // k] for the places p < n, zero
+    behind them (x [T, d]; pair [C] i32, the pair t * k + j a place holds)."""
+    keep = (jnp.arange(pair.shape[0]) < n)[:, None]
+    return jnp.where(keep, jnp.take(x, pair // k, axis=0), 0)
+
+
+def _to_rows(ys, w, pair, place, n):
+    """Places to rows, in float32: out[t] = sum_j w[t, j] * ys[place[t, j]]
+    over the pairs (t, j) whose place is under n (w None: unit weights); a
+    row with none gets an exact zero. ys [C, d], whose rows from n on are
+    whatever the kernel left there (selected away, never multiplied by
+    zero); pair [C] i32 and place [T, k] i32 are the two directions of the
+    one order: the product reads the one, the gather the other."""
+    C = pair.shape[0]
+    T, k = place.shape
+    if combine_is_a_product(C, T, k):
+        live = jnp.arange(C) < n
+        # [T, C]: a pair's weight at (its row, its place), zero elsewhere
+        # and behind the last group. As a float32 product over the C rows
+        # it adds each into its row; on the v5e at Kimi's widths (C 1,024
+        # of 8,192) that took 0.15 ms a layer where a scatter-add of the
+        # same rows took 0.84 and the un-sort of all pairs and their sum
+        # 2.05 (PERF.md section 6, PR 42)
+        ys = jnp.where(live[:, None], ys, 0).astype(jnp.float32)
+        comb = jnp.where(
+            (pair // k == jnp.arange(T)[:, None]) & live,
+            1.0 if w is None else jnp.take(w.reshape(-1), pair), 0.0)
+        return jnp.dot(comb, ys, precision=lax.Precision.HIGHEST)
+    # un-sorted in the products' own dtype and float32 from there on: a
+    # gather moves values and rounds none, so the sum is what it would be
+    # un-sorted in float32, and no pass holds a float32 copy of the places
+    # in the sorted order as well (1.2 GB at a trainer's launch of 16,384
+    # rows on every place)
+    got = jnp.take(ys, jnp.minimum(place, C - 1), axis=0)      # [T, k, d]
+    got = jnp.where((place < n)[..., None], got, 0).astype(jnp.float32)
+    return jnp.sum(got if w is None else got * w[..., None], axis=1)
+
+
+@jax.custom_vjp
+def _dispatch(h, pair, place, n):
+    """The rows of the first C places of the sorted order, xs [C, d] (C =
+    len(pair); places from n on are zero). Its derivative is the way back
+    with unit weights."""
+    return _to_places(h, pair, n, place.shape[1])
+
+
+def _dispatch_fwd(h, pair, place, n):
+    return _dispatch(h, pair, place, n), (pair, place, n)
+
+
+def _dispatch_bwd(res, g):
+    pair, place, n = res
+    return _to_rows(g, None, pair, place, n).astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, w, pair, place, n):
+    """Each place's output times its pair's router weight, summed into its
+    row in float32: y [T, d] in ys' dtype. Its derivative by ys is the way
+    to the places (a gather of C rows of the rows' gradient, each times its
+    pair's weight: a place receives one contribution, nothing accumulates),
+    by w a row-wise dot at the C places, handed to their pairs as scalars."""
+    return _to_rows(ys, w, pair, place, n).astype(ys.dtype)
+
+
+def _combine_fwd(ys, w, pair, place, n):
+    return _combine(ys, w, pair, place, n), (ys, w, pair, place, n)
+
+
+def _combine_bwd(res, g):
+    ys, w, pair, place, n = res
+    C = pair.shape[0]
+    gp = _to_places(g, pair, n, w.shape[1]).astype(jnp.float32)    # [C, d]
+    dys = (gp * jnp.take(w.reshape(-1), pair)[:, None]).astype(ys.dtype)
+    at = jnp.where(jnp.arange(C) < n,
+                   jnp.sum(gp * ys.astype(jnp.float32), axis=-1), 0.0)
+    dw = jnp.where(place < n, jnp.take(at, jnp.minimum(place, C - 1)), 0.0)
+    return dys, dw.astype(w.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
     """The (row, expert) pairs sorted by expert, three grouped matmuls
     over `load` rows a group, and each pair's output times its router
@@ -894,13 +1030,14 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
     every group and belong to none.
 
     Only the first `held_pair_slots(T, cfg)` places of that order are
-    gathered, multiplied and combined. Where every expert is held that is
-    all T*k (the WHOLE form: the outputs are un-sorted and a row's k
-    summed). Under a share it is a few tiles (the COMPACT form: C rows
-    gathered, and each of them added into its row of [T, d], so a row with
-    no held pair gets an exact zero), chosen on the device by
+    gathered (`_dispatch`), multiplied and summed into their rows
+    (`_combine`); a row with no held pair gets an exact zero. Where every
+    expert is held that is all T*k (the WHOLE form). Under a share it is
+    at most half of them (the COMPACT form), chosen on the device by
     sum(load) <= C; a launch whose held pairs do not fit takes the whole
-    form, so no pair is ever dropped.
+    form, so no pair is ever dropped. One code path at either count: the
+    way back is the [T, C] product where C is few and the gather of a
+    row's k places where it is many (`combine_is_a_product`).
 
     With `layer`, the weights are the stacked [L, E, ...] leaves and the
     kernel finds the layer's experts by its index map (group g reads
@@ -918,9 +1055,10 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
 
     def form(slots):
         pad = -slots % GMM_ROWS
-        # rows behind the last group are whatever the kernel left there, in
-        # either direction: select them away, do not multiply by zero
-        keep = (jnp.arange(slots + pad) < jnp.sum(load))[:, None]
+        # summed here and again for the `cond`, not handed in: as one more
+        # operand of the branches it keeps XLA from sinking the router's
+        # tail into them (PERF.md section 6, PR 48)
+        n = jnp.sum(load)
         with jax.named_scope("dispatch"):
             mine = valid[:, None]
             if cfg.experts_held:
@@ -928,43 +1066,35 @@ def _experts_sorted(h, w, e, valid, load, lp, cfg: LlamaConfig, layer):
                 mine = mine & (e >= 0) & (e < held)
             flat_e = jnp.where(mine, e, held).reshape(-1)
             order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-            pair = order[:slots]
-            xs = jnp.where(keep, jnp.take(h, jnp.pad(pair // k, (0, pad)),
-                                          axis=0), 0)
+            # in whole tiles of the kernel's rows: a place behind `slots`
+            # is behind n too, so it holds no pair
+            pair = jnp.pad(order[:slots], (0, pad))
+            # the order's other direction; only the gather of the way back
+            # reads it, so a launch that never takes one never sorts twice
+            place = jnp.argsort(order).astype(jnp.int32).reshape(T, k)
+            xs = _dispatch(h, pair, place, n)
         with jax.named_scope("experts"):
+            # rows behind the last group are whatever the kernel left there,
+            # in either direction: select them away, do not multiply by zero
+            keep = (jnp.arange(slots + pad) < n)[:, None]
             a = jnp.where(keep, jax.nn.silu(dot(xs, "w1")) * dot(xs, "w3"), 0)
             ys = dot(a, "w2")
         with jax.named_scope("combine"):
-            ys = jnp.where(keep, ys, 0)[:slots]
-            if slots == T * k:
-                # un-sorted in the products' own dtype and float32 from
-                # there on: a gather moves values and rounds none, so the
-                # sum is what it would be un-sorted in float32, and neither
-                # pass holds a float32 copy of all T*k rows in the sorted
-                # order as well (1.2 GB at a trainer's launch of 16,384
-                # rows, the difference between a step that fits the chip
-                # and one that does not)
-                y = jnp.take(ys, jnp.argsort(order), axis=0).reshape(T, k, -1)
-                return jnp.sum(y.astype(jnp.float32) * w[..., None],
-                               axis=1).astype(h.dtype)
-            ys = ys.astype(jnp.float32)
-            # [T, C]: a pair's router weight at (its row, its place), zero
-            # elsewhere and behind the last group. As a float32 product
-            # over the C rows it adds each into its row; on the v5e at
-            # Kimi's widths (C 1,024 of 8,192) that took 0.15 ms a layer
-            # where a scatter-add of the same rows took 0.84 and the whole
-            # form's un-sort and sum 2.05 (PERF.md section 6, PR 42)
-            comb = jnp.where(
-                (pair // k == jnp.arange(T)[:, None]) & keep[:slots, 0],
-                jnp.take(w.reshape(-1), pair), 0.0)
-            return jnp.dot(comb, ys, precision=lax.Precision.HIGHEST
-                           ).astype(h.dtype)
+            return _combine(ys, w, pair, place, n)
 
     slots = held_pair_slots(T, cfg)
     if slots == T * k:
         return form(slots)
-    return lax.cond(jnp.sum(load) <= slots, lambda: form(slots),
-                    lambda: form(T * k))
+    # Under differentiation a `cond` hands the residuals of BOTH branches
+    # out of the conditional, each one materialised (a trainer's step of
+    # 16,384 rows then asks 17.2 GB of the chip's 15.75); under
+    # `jax.checkpoint` a branch's residuals are its arguments alone and its
+    # backward runs its forward again (the sorts, the C-row gather and the
+    # three products; not the way back, which nothing reads). Forward only,
+    # `jax.checkpoint` changes nothing.
+    return lax.cond(jnp.sum(load) <= slots,
+                    jax.checkpoint(lambda: form(slots)),
+                    jax.checkpoint(lambda: form(T * k)))
 
 
 def routed_ffn_load(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,

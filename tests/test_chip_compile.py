@@ -51,8 +51,11 @@ def one_chip():
 
 
 def _compile(fn, sharding, *shapes):
-    """Compile `fn` for the described chip; shapes are (shape, dtype)."""
-    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    """Compile `fn` for the described chip; shapes are (shape, dtype), or
+    a dict of them."""
+    args = jax.tree.map(
+        lambda sd: jax.ShapeDtypeStruct(*sd, sharding=sharding), list(shapes),
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
@@ -508,6 +511,97 @@ def test_grouped_matmul_trains_at_mellum_widths(one_chip, k, n):
     assert text.count("tpu_custom_call") >= 3     # gmm, gmm transposed, tgmm
     assert L._fit(2304, 1024) == 768 and L._fit(896, 1024) == 896
     assert L._fit(2304, 1024 * 1024 // 896) == 1152
+
+
+def _computations(text):
+    """{name: body} of every computation of an HLO module's text, and for
+    each the names of the computations it calls."""
+    import re
+    bodies = {}
+    for block in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", block)
+        if m:
+            bodies[m.group(1)] = block
+    calls = {n: set(re.findall(r"%([\w.\-]+)", " ".join(re.findall(
+        r"(?:calls|to_apply|body|condition|branch_computations|"
+        r"true_computation|false_computation)=\{?([^}\n]*?)[},\n]", b))))
+        & set(bodies) for n, b in bodies.items()}
+    return bodies, calls
+
+
+def _reached(calls, start):
+    seen, todo = set(), [start]
+    while todo:
+        n = todo.pop()
+        if n not in seen:
+            seen.add(n)
+            todo.extend(calls[n])
+    return seen
+
+
+def test_a_quarter_share_trains_its_compact_form_at_mellum_widths(one_chip):
+    """One sparse layer of `mellum2-12b-a2.5b-train` (16 of 64 experts
+    held, 8 a row), value and gradient at the cell's launch of 16,384 rows:
+    65,536 places of 131,072, so one `conditional` forward and one backward
+    whose compact branches run the grouped products on 65,536 rows and
+    whose other branches run them on all 131,072 (the fallback past the
+    places: nothing dropped); no float32 copy of 131,072 rows is made
+    outside that fallback (the way back gathers a row's k places in bf16
+    and sums them inside one fusion), no row is added into place one at a
+    time in the compact branches (the transposes are gathers), and the
+    compiler's peak
+    for the layer is under the 3.60 GB it read for the parent's whole form
+    (PR 48: 3.48; both branches recompute under `jax.checkpoint`, so what
+    crosses a `conditional` is the layer's input and output alone)."""
+    import re
+
+    from paddle_tpu.models import llama as L
+    rows, d, f = 16384, 2304, 896
+    cfg = L.LlamaConfig(hidden_size=d, intermediate_size=f, num_experts=64,
+                        top_k=8, experts_held=(0, 16), norm_topk_prob=True,
+                        dtype=jnp.bfloat16)
+    assert L.held_pair_slots(rows, cfg) == 65536
+    assert not L.combine_is_a_product(65536, rows, 8)
+
+    def loss(h, lp):
+        return jnp.sum(L.routed_ffn_load(h, lp, cfg)[0].astype(
+            jnp.float32) ** 2)
+
+    available = fa.available
+    fa.available = lambda: True         # expert_form and interpret read it
+    try:
+        compiled = _compile(
+            jax.value_and_grad(loss, argnums=(0, 1)), one_chip, _bf16(rows, d),
+            {"router": ((d, 64), jnp.float32), "w1": ((16, d, f), jnp.float32),
+             "w3": ((16, d, f), jnp.float32), "w2": ((16, f, d), jnp.float32)})
+    finally:
+        fa.available = available
+    text = compiled.as_text()
+    bodies, calls = _computations(text)
+    conds = re.findall(r" conditional\(.*?branch_computations=\{([^}]*)\}"
+                       r"|true_computation=%([\w.\-]+), "
+                       r"false_computation=%([\w.\-]+)", text)
+    assert len(conds) == 2, conds                   # forward, backward
+    fallback = set()
+    for listed, true, false in conds:
+        branches = re.findall(r"%([\w.\-]+)", listed) or [true, false]
+        by_rows = {}
+        for b in branches:
+            inside = "".join(bodies[c] for c in _reached(calls, b))
+            made = set(re.findall(r"bf16\[(\d+),896\]\S* custom-call\(",
+                                  inside))
+            assert len(made) == 1, (b, made)
+            by_rows[made.pop()] = b
+        assert set(by_rows) == {"65536", "131072"}, by_rows
+        fallback |= _reached(calls, by_rows["131072"])
+        compact = "".join(bodies[c] for c in _reached(calls, by_rows["65536"]))
+        wide = re.findall(r"= \w+\[\d+,2304\]\S* scatter\(", compact)
+        assert not wide, wide
+    outside = [n for n, b in bodies.items()
+               if n not in fallback and "f32[131072,2304]" in b]
+    assert not outside, outside
+    peak = getattr(compiled.memory_analysis(), "peak_memory_in_bytes", None)
+    assert peak is None or peak < 3.55e9, peak
 
 
 # SDAR-30B-A3B-Chat (benchmark/configs/sdar30b-a3b-serve.json): hidden 2048,

@@ -31,6 +31,7 @@ from benchmark.lib import agreement, reference_kimi as R
 from paddle_tpu.inference.serving import PagedServingEngine
 from paddle_tpu.models import llama as L
 from paddle_tpu.ops.pallas import paged_attention_latent as PL
+from tests.test_mellum2_train import _eqns
 from tests.test_tracing import _profiled
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -277,15 +278,32 @@ def sorted_ffn(cfg, lp, h, valid):
     return np.asarray(y), np.asarray(load), str(jax.make_jaxpr(fn)(h))
 
 
+def conditionals(cfg, lp, h, valid):
+    """How many `cond`s of the layer's program choose between launches of
+    the grouped-matmul kernel (the kernel's own body, in the interpreter,
+    holds `cond`s of its own: not descended into)."""
+    from jax._src import core
+
+    opaque = ("pallas_call",)
+    closed = jax.make_jaxpr(lambda h: L.routed_ffn_load(h, lp, cfg, valid))(h)
+    return sum(
+        1 for eqn in _eqns(closed.jaxpr, opaque)
+        if eqn.primitive.name == "cond" and any(
+            e.primitive.name == "pallas_call"
+            for sub in core.jaxprs_in_params(eqn.params)
+            for e in _eqns(sub, opaque)))
+
+
 @pytest.mark.parametrize("rows, live, slots", [(200, 190, 128), (72, 72, 128),
                                                (300, 263, 256)])
 def test_the_compact_form_equals_the_whole_form_and_the_reference(
         small_share, rows, live, slots, monkeypatch):
     """Under a share small enough (4 of 64) the held pairs of `rows` rows,
     no multiple of `GMM_ROWS`, get `slots` places of rows x 2: the compact
-    form equals the whole form (forced by a factor that gives every pair a
-    place) and the reference, padding rows are exactly zero and a row with
-    no held pair is the shared expert alone, bit for bit."""
+    form equals the whole form (forced by places for every pair: no factor
+    reaches them, the rule stops at half) and the reference, padding rows
+    are exactly zero and a row with no held pair is the shared expert
+    alone, bit for bit."""
     cfg, params = small_share
     monkeypatch.setattr(L, "expert_form", lambda cfg: "sorted_gmm")
     lp = {n: w[0] for n, w in params["blocks"][1].items()}
@@ -294,8 +312,11 @@ def test_the_compact_form_equals_the_whole_form_and_the_reference(
     assert L.held_pair_slots(rows, cfg) == slots < rows * 2
     y, load, text = sorted_ffn(cfg, lp, h, valid)
     assert 0 < load.sum() <= slots
-    monkeypatch.setattr(L, "HELD_ROOM", rows * 2)
+    assert conditionals(cfg, lp, h, valid) == 1
+    monkeypatch.setattr(L, "held_pair_slots",
+                        lambda rows, cfg: rows * cfg.top_k)
     whole, load_whole, text_whole = sorted_ffn(cfg, lp, h, valid)
+    assert conditionals(cfg, lp, h, valid) == 0     # one form: no choice
     # the [T, C] weights that add C rows into T: in the one, not the other
     combine = f"f32[{rows},{slots}]"
     assert combine in text and combine not in text_whole
@@ -344,7 +365,12 @@ def test_held_pairs_past_their_places_take_the_whole_form(
     (1024, (), 8, 8192),                # every expert held: every pair
     (48, (4, 4, 16), 2, 96),            # the tiny fixture: never above all
     (200, (20, 4, 64), 2, 128),
-    (1000, (0, 1, 384), 8, 128)])       # 21 even: 84 in one tile
+    (1000, (0, 1, 384), 8, 128),        # 21 even: 84 in one tile
+    (16384, (0, 16, 64), 8, 65536),     # Mellum2's launch: half, not all
+    (2048, (64, 32, 256), 8, 8192),     # dots3's chunk: 4 x 2,048 is half
+    (64, (64, 32, 256), 8, 256),        # a tick of 64 rows on that share
+    (100, (0, 16, 64), 2, 128),         # half is 100: up to a whole tile
+    (300, (0, 16, 64), 2, 384)])        # half is 300: three tiles of 600
 def test_the_places_follow_from_rows_top_k_and_the_share(rows, share, top_k,
                                                          slots):
     cfg = L.LlamaConfig(num_experts=share[2] if share else 64, top_k=top_k,

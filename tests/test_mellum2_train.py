@@ -123,9 +123,11 @@ def test_the_trainers_loss_and_gradients_are_the_references(
     assert int(stats["moe_launches"]) == 4
     assert int(stats["moe_pairs"]) == 4 * B * T * lcfg.top_k
     assert 0 < int(stats["moe_pairs_held"]) < int(stats["moe_pairs"])
-    # 4 of 16 held with HELD_ROOM 4: the places are all rows * top_k, so
-    # the sorted form takes its whole form in every launch
-    assert int(stats["moe_whole_form"]) == (4 if form == "sorted_gmm" else 0)
+    # 4 of 16 held: 128 places of the 256 pairs of 64 rows x 4 (half of
+    # them, where HELD_ROOM x the even count would be all), about 64 pairs
+    # held: every launch takes the compact form, differentiated too
+    assert L.held_pair_slots(B * T, lcfg) == 128
+    assert int(stats["moe_whole_form"]) == 0
 
 
 @pytest.mark.parametrize("microbatches, warmup", [(1, 0), (2, 0), (1, 4)])
@@ -334,7 +336,9 @@ def _forced(params):
 def test_a_forced_imbalance_drops_nothing(form, monkeypatch):
     """Every one of the 64 rows on each of the four held experts, where
     `_moe_ffn`'s capacity 2.0 is 2 * 64 * 4 / 16 = 32 rows an expert: the
-    trainer's loss and gradients are still the reference's."""
+    trainer's loss and gradients are still the reference's. The 256 held
+    pairs of a launch pass its 128 places, so the sorted form takes the
+    whole form under `jax.grad`, in every launch, and counts it."""
     monkeypatch.setattr(L, "expert_form", lambda c: form)
     cfg, lcfg, params = make(seed=7)
     params = _forced(params)
@@ -349,6 +353,159 @@ def test_a_forced_imbalance_drops_nothing(form, monkeypatch):
     # every pair the router made is on an expert held here
     assert int(stats["moe_load_max"]) == 4 * B * T
     assert int(stats["moe_pairs_held"]) == int(stats["moe_pairs"])
+    assert int(stats["moe_pairs"]) == 4 * 2 * L.held_pair_slots(B * T, lcfg)
+    assert int(stats["moe_whole_form"]) == (4 if form == "sorted_gmm" else 0)
+
+
+# ---- the compact form under the derivative ---------------------------------
+
+def _one_layer(seed=3):
+    """One sparse layer of the tiny share (4 of 16 held, 64 rows x 4) with
+    a router sharp enough to choose: (lcfg, lp, h, pull)."""
+    cfg, lcfg, _ = make()
+    lp = jax.tree.map(lambda a: a[0], L.init_params(
+        lcfg, jax.random.PRNGKey(seed))["blocks"][0])
+    lp = dict(lp, router=lp["router"] * 30.0)
+    h = jax.random.normal(jax.random.PRNGKey(4), (B * T, 64), jnp.float32)
+    pull = jax.random.normal(jax.random.PRNGKey(5), (B * T, 64), jnp.float32)
+    return lcfg, lp, h, pull
+
+
+def _value_and_grads(lcfg, lp, h, pull):
+    """(the layer's output, the gradients of its pull-weighted sum by the
+    layer's input and by the router and the three expert matrices)."""
+    names = ("router", "w1", "w3", "w2")
+
+    def f(h, ws):
+        y = L.routed_ffn_load(h, dict(lp, **ws), lcfg)[0]
+        return jnp.sum(y * pull), y
+
+    (_, y), grads = jax.value_and_grad(f, argnums=(0, 1), has_aux=True)(
+        h, {n: lp[n] for n in names})
+    return y, grads
+
+
+@pytest.mark.parametrize("product", [True, False],
+                         ids=["product", "gather"])
+def test_the_compact_forms_gradients_are_the_whole_forms(product,
+                                                         monkeypatch):
+    """Value and gradients (the layer's input, the router, the three expert
+    matrices) of the sorted form on 128 places equal those on all 256, in
+    float32, whichever way the places go back into their rows (both forms
+    are held to the reference by the tests above)."""
+    monkeypatch.setattr(L, "expert_form", lambda c: "sorted_gmm")
+    monkeypatch.setattr(L, "combine_is_a_product",
+                        lambda places, rows, k: product and places < rows * k)
+    lcfg, lp, h, pull = _one_layer()
+    load = L.routed_ffn_load(h, lp, lcfg)[1]
+    assert 0 < int(load.sum()) <= L.held_pair_slots(B * T, lcfg) == 128
+    text = str(jax.make_jaxpr(lambda h: _value_and_grads(lcfg, lp, h, pull))(h))
+    # the [T, C] product is the one product made at the highest precision
+    assert "cond[" in text and ("Precision.HIGHEST" in text) == product
+    compact = _value_and_grads(lcfg, lp, h, pull)
+    monkeypatch.setattr(L, "held_pair_slots",
+                        lambda rows, cfg: rows * cfg.top_k)
+    whole = _value_and_grads(lcfg, lp, h, pull)
+    assert_trees_close(compact, whole, 2e-5, "whole")
+
+
+def _an_order(T, k, held_of, seed):
+    """A launch's sorted order as `_experts_sorted` makes it, with about
+    one pair in `held_of` held: (pair [T*k], place [T, k], n)."""
+    held = jax.random.uniform(jax.random.PRNGKey(seed), (T * k,)) < 1 / held_of
+    order = jnp.argsort(~held, stable=True).astype(jnp.int32)
+    place = jnp.argsort(order).astype(jnp.int32).reshape(T, k)
+    return order, place, jnp.sum(held).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("product", [True, False],
+                         ids=["product", "gather"])
+@pytest.mark.parametrize("T, k, C", [(24, 4, 48), (24, 4, 96), (16, 2, 24)])
+def test_the_two_gathers_are_each_others_transposes(T, k, C, product,
+                                                    monkeypatch):
+    """<to_places(x), g> = <x, to_rows(g)> on random data, the derivative
+    of `_dispatch` IS the way back with unit weights and that of `_combine`
+    by the outputs the way to the places times the pairs' weights, and
+    both hold against finite differences. Rows behind the last held pair
+    are garbage on purpose: nothing may read them."""
+    monkeypatch.setattr(L, "combine_is_a_product", lambda *shape: product)
+    d = 8
+    order, place, n = _an_order(T, k, 4, seed=T + C)
+    assert 0 < int(n) <= C
+    pair = order[:C]
+    keys = jax.random.split(jax.random.PRNGKey(C), 4)
+    x = jax.random.normal(keys[0], (T, d), jnp.float32)
+    g = jax.random.normal(keys[1], (C, d), jnp.float32)
+    g = jnp.where((jnp.arange(C) < n)[:, None], g, jnp.nan)
+    w = jax.random.uniform(keys[2], (T, k), jnp.float32, 0.5, 1.5)
+    dy = jax.random.normal(keys[3], (T, d), jnp.float32)
+    there = L._to_places(x, pair, n, k)
+    back = L._to_rows(g, None, pair, place, n)
+    assert not np.any(np.isnan(np.asarray(back)))
+    np.testing.assert_allclose(float(jnp.sum(there * jnp.nan_to_num(g))),
+                               float(jnp.sum(x * back)), atol=1e-4)
+    # a row with no held pair gets an exact zero, a place behind n too
+    alone = ~np.asarray((place < n).any(axis=1))
+    assert alone.any() and not np.any(np.asarray(back)[alone])
+    assert not np.any(np.asarray(there)[int(n):])
+    # the derivatives are the other direction
+    dx, = jax.vjp(lambda x: L._dispatch(x, pair, place, n), x)[1](g)
+    np.testing.assert_allclose(np.asarray(dx), np.asarray(back), rtol=1e-6)
+    dys, dw = jax.vjp(lambda ys, w: L._combine(ys, w, pair, place, n),
+                      g, w)[1](dy)
+    want = L._to_places(dy, pair, n, k) * jnp.take(w.reshape(-1), pair)[:, None]
+    np.testing.assert_allclose(np.asarray(dys), np.asarray(want), rtol=1e-6)
+    got = jnp.take(jnp.nan_to_num(g), jnp.minimum(place, C - 1), axis=0)
+    want_dw = jnp.where(place < n, jnp.einsum("tkd,td->tk", got, dy), 0.0)
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(want_dw),
+                               rtol=1e-5, atol=1e-6)
+    # and both against finite differences (garbage rows replaced: a
+    # difference quotient through a NaN is a NaN)
+    from jax.test_util import check_grads
+    ys = jnp.nan_to_num(g)
+    with jax.default_matmul_precision("highest"):
+        check_grads(lambda x: L._dispatch(x, pair, place, n), (x,), order=1,
+                    modes=["rev"], atol=2e-2, rtol=2e-2)
+        check_grads(lambda ys, w: L._combine(ys, w, pair, place, n), (ys, w),
+                    order=1, modes=["rev"], atol=2e-2, rtol=2e-2)
+
+
+def _eqns(jaxpr, opaque=()):
+    """Every equation of a jaxpr, the nested ones too, but for what lies
+    inside the primitives named `opaque`."""
+    from jax._src import core
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name not in opaque:
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from _eqns(sub, opaque)
+
+
+def _wide_scatter_adds(jaxpr, d):
+    """The scatter-adds of a jaxpr, every nested one too, whose updates
+    are rows of d values."""
+    return [e for e in _eqns(jaxpr) if e.primitive.name == "scatter-add"
+            and e.invars[2].aval.shape[-1:] == (d,)]
+
+
+@pytest.mark.parametrize("whole", [False, True], ids=["share", "all_held"])
+def test_the_differentiated_routed_ffn_scatters_no_rows(whole, monkeypatch):
+    """Neither direction of the derivative adds d-wide rows into place one
+    at a time: the way to the places and the way back are gathers forward
+    and backward, under a share (both branches of its `cond`) and with
+    every expert held. JAX's own transpose of a row gather is such a
+    scatter-add, which is what the test would find."""
+    monkeypatch.setattr(L, "expert_form", lambda c: "sorted_gmm")
+    lcfg, lp, h, pull = _one_layer()
+    if whole:
+        lcfg = dataclasses.replace(lcfg, experts_held=(), num_experts=4)
+        lp = dict(lp, router=lp["router"][:, :4])
+    jaxpr = jax.make_jaxpr(lambda h: _value_and_grads(lcfg, lp, h, pull))(h)
+    assert "gather" in {e.primitive.name for e in _eqns(jaxpr.jaxpr)}
+    assert not _wide_scatter_adds(jaxpr.jaxpr, 64)
+    plain = jax.make_jaxpr(jax.grad(lambda h: jnp.sum(
+        jnp.take(h, jnp.arange(8) // 2, axis=0))))(h)
+    assert len(_wide_scatter_adds(plain.jaxpr, 64)) == 1
 
 
 def test_capacity_dispatch_would_have_dropped_those_rows():
