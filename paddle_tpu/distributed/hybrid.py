@@ -21,6 +21,8 @@ jitted function where:
   the schedule is differentiated through (ppermute transposes to the inverse
   permutation), so one `jax.grad` covers the whole pipeline instead of the
   reference's hand-built forward_backward_pipeline (pipeline_parallel.py:575).
+  A schedule of one slot (one microbatch on pp = 1) is one call of the
+  slot and no scan.
 - **EP (MoE)**: where dp > 1 exchanges the experts, GShard-style capacity
   dispatch + `all_to_all` over the 'dp' axis (expert parallelism rides the
   data-parallel axis, as in the reference's global_scatter/global_gather
@@ -585,11 +587,19 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
                     y, "pp", [(i, (i + 1) % pp) for i in range(pp)])
             return (y_send, loss_acc, stats_acc), None
 
-        x_init = jnp.zeros((Bm, Tloc, D), cfg.dtype)
+        carry0 = (jnp.zeros((Bm, Tloc, D), cfg.dtype),
+                  jnp.zeros((), jnp.float32), stats0)
+        slots = M + pp - 1
         with jax.named_scope("pipeline"):
-            (_, loss_sum, stats), _ = lax.scan(
-                pipe_step, (x_init, jnp.zeros((), jnp.float32), stats0),
-                jnp.arange(M + pp - 1))
+            if slots == 1:
+                # a schedule of one slot is a call: as a scan of length one
+                # its body is loop-invariant, the compiler cannot see the
+                # trip count, and it lifted a second copy of the layers'
+                # forward pass out of the loop (PERF.md, PR 49)
+                (_, loss_sum, stats), _ = pipe_step(carry0, 0)
+            else:
+                (_, loss_sum, stats), _ = lax.scan(
+                    pipe_step, carry0, jnp.arange(slots))
         # collect from the last stage (pp); already replicated over tp.
         # Normalize to the GLOBAL batch mean: local token count is M*Bm*T, and
         # the extra 1/dp makes the implicit sum over dp ranks a global mean.

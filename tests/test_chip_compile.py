@@ -604,6 +604,61 @@ def test_a_quarter_share_trains_its_compact_form_at_mellum_widths(one_chip):
     assert peak is None or peak < 3.55e9, peak
 
 
+@pytest.mark.parametrize("microbatches", [1, 2], ids=["one_slot", "two_slots"])
+def test_the_train_step_runs_a_layers_forward_pass_twice_not_three_times(
+        one_chip, monkeypatch, microbatches):
+    """`train_1chip`'s whole step (mistral7b-train-1chip: one layer, batch 4
+    x 4096, mesh 1·1·1, full remat) for the described chip: the layer's
+    flash forward kernel is launched from two sites, the forward pass and
+    the remat replay beside the one `dq` and the one `dkv`. As a scan of
+    length one the one-slot schedule compiled to three (PR 49: the body is
+    loop-invariant, the compiler cannot see the trip count, and it lifted a
+    second copy of the layers and the head out of the loop while the copy
+    that feeds the residuals stayed inside; 11.66 GiB, now 9.41). Two
+    microbatches keep the scan, and its two sites."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmark.lib.program import DTYPES, llama_config
+    from paddle_tpu.distributed import hybrid as H
+    from paddle_tpu.models import llama as L
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mistral7b-train-1chip.json")) as f:
+        cfg = json.load(f)
+    lcfg = llama_config(cfg, DTYPES[cfg["trainer"]["param_dtype"]])
+    assert lcfg.num_layers == 1
+    monkeypatch.setattr(fa, "available", lambda: True)
+    mesh = H.build_mesh(1, 1, 1, devices=list(one_chip.device_set))
+    specs = H.param_specs(lcfg)
+
+    def placed(shapes, specs):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+            shapes, specs)
+
+    shapes = jax.eval_shape(
+        lambda k: H.stack_pipeline(L.init_params(lcfg, k), 1),
+        jax.random.PRNGKey(0))
+    opt = placed(jax.eval_shape(H.init_opt_state, shapes),
+                 {"m": specs, "v": specs, "step": P()})
+    tokens = jax.ShapeDtypeStruct((4, 4096), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp", None)))
+    compiled = H.make_train_step(lcfg, mesh, num_microbatches=microbatches
+                                 ).lower(placed(shapes, specs), opt, tokens,
+                                         tokens).compile()
+    sites = re.findall(r"%flash_attention_(fwd|dq|dkv)[\w.]* = [^\n]*? "
+                       r"custom-call\(", compiled.as_text())
+    assert sorted(sites) == ["dkv", "dq", "fwd", "fwd"], sites
+    peak = getattr(compiled.memory_analysis(), "peak_memory_in_bytes", None)
+    if microbatches == 1:
+        assert peak is None or peak < 10 * 2 ** 30, peak
+
+
 # SDAR-30B-A3B-Chat (benchmark/configs/sdar30b-a3b-serve.json): hidden 2048,
 # 32 q / 4 kv heads of 128, 128 experts of 768, vocabulary 151,936
 SDAR = dict(vocab_size=151936, hidden_size=2048, intermediate_size=768,

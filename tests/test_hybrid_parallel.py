@@ -159,3 +159,136 @@ def test_cp_gqa_parity():
     sp = H.shard_params(params, mesh, cfg)
     loss = H.make_eval_step(cfg, mesh, num_microbatches=1)(sp, tokens, targets)
     np.testing.assert_allclose(float(loss), float(ref), rtol=3e-5)
+
+
+# ---- a schedule of one slot is a call of the slot, not a scan --------------
+
+def _plan_cfg():
+    """The tiny Mellum2 fixture: window and full layers (two kinds, two
+    ropes), every layer sparse with 4 of 16 experts held; float32."""
+    import dataclasses
+    import json
+    import os
+
+    from benchmark.drivers import train_steps_plan as D
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "tests", "fixtures",
+        "configs", "tiny-mellum2.json")
+    with open(path) as f:
+        return dataclasses.replace(D.mellum_config(json.load(f), jnp.float32),
+                                   dtype=jnp.float32)
+
+
+# name -> (config, (dp, pp, tp)): M = 1 on any of these is the one slot
+ONE_SLOT = {
+    "uniform": (lambda: _cfg(), (1, 1, 1)),
+    "uniform_dp2_tp2": (lambda: _cfg(), (2, 1, 2)),
+    "plan_sparse": (_plan_cfg, (1, 1, 1)),
+}
+
+
+def _loss_and_grads(cfg, mesh, microbatches, with_stats, chosen):
+    """`make_loss_and_grads` with the counters on or off, as
+    `make_train_step` builds it."""
+    specs = H.param_specs(cfg)
+    f = H._per_shard_loss_and_grads(cfg, mesh, microbatches, True, "xla",
+                                    "stock", with_stats, chosen)
+    P = jax.sharding.PartitionSpec
+    return jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=(specs, P("dp", "cp"), P("dp", "cp")),
+        out_specs=(P(), specs, P()), check_vma=False))
+
+
+# the chosen experts are kept where a layer routes, on one chip alone
+@pytest.mark.parametrize("name, with_stats, chosen", [
+    ("uniform", False, False), ("uniform", True, False),
+    ("uniform_dp2_tp2", False, False), ("plan_sparse", False, False),
+    ("plan_sparse", True, False), ("plan_sparse", True, True)],
+    ids=lambda v: v if isinstance(v, str) else str(int(v)))
+def test_one_slot_has_the_two_slot_loss_and_gradients(name, with_stats,
+                                                      chosen):
+    """M = 1 on pp = 1 runs `pipe_step` once, without a scan; the same
+    batch in two microbatches runs the scan. The loss is the global mean
+    on both, so loss and every gradient leaf agree, with the counters
+    riding the carry or not."""
+    make_cfg, (dp, pp, tp) = ONE_SLOT[name]
+    cfg = make_cfg()
+    mesh = H.build_mesh(dp=dp, pp=pp, tp=tp)
+    sp = H.shard_params(L.init_params(cfg, jax.random.PRNGKey(0)), mesh, cfg)
+    tokens, targets = _data(cfg)
+    one = _loss_and_grads(cfg, mesh, 1, with_stats, chosen)(
+        sp, tokens, targets)
+    two = _loss_and_grads(cfg, mesh, 2, with_stats, chosen)(
+        sp, tokens, targets)
+    np.testing.assert_allclose(float(one[0]), float(two[0]), rtol=2e-5)
+    flat = dict(jax.tree_util.tree_flatten_with_path(one[1])[0])
+    for path, w in jax.tree_util.tree_flatten_with_path(two[1])[0]:
+        g, w = np.asarray(flat[path]), np.asarray(w)
+        assert np.abs(g - w).max() <= 3e-4 * (np.abs(w).max() + 1e-12), (
+            jax.tree_util.keystr(path), np.abs(g - w).max(), np.abs(w).max())
+    want = set(H.MOE_STATS) | ({"chosen"} if chosen else set())
+    assert set(one[2]) == set(two[2]) == (want if with_stats else set())
+    # a sparse layer counts where dp = 1 runs `routed_ffn_load`
+    sparse = sum(s.ffn == "sparse" for s in cfg.layers) * (dp == 1)
+    if with_stats:
+        assert int(one[2]["moe_launches"]) == sparse
+        assert int(two[2]["moe_launches"]) == 2 * sparse
+        assert int(one[2]["moe_pairs"]) == int(two[2]["moe_pairs"])
+        assert int(one[2]["moe_pairs_held"]) == int(two[2]["moe_pairs_held"])
+    if chosen:
+        B, T = tokens.shape
+        launches = one[2]["chosen"].shape[0]
+        assert launches == sparse > 0
+        # two microbatches launch a microbatch's layers one after another
+        np.testing.assert_array_equal(
+            np.asarray(two[2]["chosen"]).reshape(
+                2, launches, B * T // 2, cfg.top_k).swapaxes(0, 1).reshape(
+                    launches, B * T, cfg.top_k), np.asarray(one[2]["chosen"]))
+
+
+def _scans(jaxpr, scope=()):
+    """(scope, length) of every `scan` of a jaxpr, the nested ones too;
+    scope is the named scopes around it, an enclosing equation's first."""
+    from jax._src import core
+    for eqn in jaxpr.eqns:
+        here = scope + tuple(
+            part for part in str(eqn.source_info.name_stack).split("/")
+            if part)
+        if eqn.primitive.name == "scan":
+            yield here, eqn.params["length"]
+        for sub in core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub, here)
+
+
+@pytest.mark.parametrize("differentiated", [False, True],
+                         ids=["loss", "loss_and_grads"])
+@pytest.mark.parametrize("name", ["uniform", "uniform_dp2_tp2",
+                                  "plan_sparse"])
+def test_one_slot_traces_no_scan_around_its_layers(name, differentiated):
+    """The jaxpr of the one-slot loss has the scans over the layers and
+    no other; two slots put one scan of length 2 around them (and its
+    transpose, differentiated). A loop of one trip hid its trip count from
+    the chip's compiler, which ran the layers' forward pass once inside it
+    and once more lifted out of it (PERF.md, PR 49)."""
+    make_cfg, (dp, pp, tp) = ONE_SLOT[name]
+    cfg = make_cfg()
+    mesh = H.build_mesh(dp=dp, pp=pp, tp=tp)
+    sp = H.shard_params(L.init_params(cfg, jax.random.PRNGKey(0)), mesh, cfg)
+    tokens, targets = _data(cfg)
+
+    def scans(microbatches):
+        f = (H.make_loss_and_grads(cfg, mesh, microbatches, attn_impl="xla")
+             if differentiated else
+             H.make_eval_step(cfg, mesh, num_microbatches=microbatches))
+        found = list(_scans(jax.make_jaxpr(f)(sp, tokens, targets).jaxpr))
+        around = [(s, n) for s, n in found if "layers" not in s]
+        return found, around
+
+    found, around = scans(1)
+    assert found and not around, around
+    layer_scans = len(found)
+    found, around = scans(2)
+    assert [n for _, n in around] == [2] * (2 if differentiated else 1), around
+    assert all(any("pipeline" in part for part in s) for s, _ in around)
+    if not differentiated:      # the same scans over the layers, inside it
+        assert len(found) - len(around) == layer_scans, found
