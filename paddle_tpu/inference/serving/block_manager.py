@@ -97,6 +97,10 @@ class BlockManager:
         self._hash_to_block: Dict[int, int] = {}
         self._block_hash: Dict[int, int] = {}
         self._hash_info: Dict[int, Tuple[int, Tuple[int, ...]]] = {}
+        # chain hash -> the hashes registered behind it, in the order they
+        # came (a dict as an ordered set): what a partial match looks
+        # through, instead of every page the cache holds
+        self._children: Dict[int, Dict[int, None]] = {}
         # refcount-0 blocks still holding cached KV, oldest first (LRU)
         self._cached_free: "OrderedDict[int, None]" = OrderedDict()
         # per-sequence block tables, and how far each is content-addressed:
@@ -174,7 +178,19 @@ class BlockManager:
         if h is not None:
             if self._hash_to_block.get(h) == blk:
                 del self._hash_to_block[h]
-            self._hash_info.pop(h, None)
+            info = self._hash_info.pop(h, None)
+            if info is not None:
+                kids = self._children[info[0]]
+                del kids[h]
+                if not kids:
+                    del self._children[info[0]]
+
+    def _index_hash(self, h: int, blk: int, prev_h: int,
+                    chunk: Tuple[int, ...]):
+        self._hash_to_block[h] = blk
+        self._block_hash[blk] = h
+        self._hash_info[h] = (prev_h, chunk)
+        self._children.setdefault(prev_h, {})[h] = None
 
     def _take_free(self) -> int:
         if self._free:
@@ -220,7 +236,10 @@ class BlockManager:
         last token's logits). Raises NoFreeBlocksError leaving no state."""
         if seq_id in self._tables:
             raise ValueError(f"sequence {seq_id} already has a block table")
-        tokens = [int(t) for t in tokens]
+        if type(tokens) is not list:
+            # a list is read as it stands (the scheduler's is `submit`'s,
+            # Python ints; numpy's integers hash and compare as theirs)
+            tokens = [int(t) for t in tokens]
         if self.window_blocks:
             # no prefix cache beside a window pool; the window table fills
             # a chunk at a time (`ensure_capacity`)
@@ -308,9 +327,8 @@ class BlockManager:
         is not worth one token)."""
         rest = list(rest)
         best_blk, best_n = None, 1
-        for h, (ph, chunk) in self._hash_info.items():
-            if ph != prev_h:
-                continue
+        for h in self._children.get(prev_h, ()):
+            chunk = self._hash_info[h][1]
             blk = self._hash_to_block.get(h)
             if blk is None or (blk not in self._refs
                                and blk not in self._cached_free):
@@ -396,9 +414,7 @@ class BlockManager:
             h = _chain_hash(prev_h, chunk)
             blk = table[bi]
             if h not in self._hash_to_block and blk not in self._block_hash:
-                self._hash_to_block[h] = blk
-                self._block_hash[blk] = h
-                self._hash_info[h] = (prev_h, chunk)
+                self._index_hash(h, blk, prev_h, chunk)
             prev_h = h
         self._hashed[seq_id] = (full, prev_h)
 
@@ -530,10 +546,8 @@ class BlockManager:
         blk = self._take_free()
         self._drop_hash(blk)       # fresh-list blocks may carry no hash;
         #                            reclaim path already dropped theirs
-        self._hash_to_block[chain_hash] = blk
-        self._block_hash[blk] = chain_hash
-        self._hash_info[chain_hash] = (
-            int(prev_hash), tuple(int(t) for t in chunk))
+        self._index_hash(chain_hash, blk, int(prev_hash),
+                         tuple(int(t) for t in chunk))
         self._cached_free[blk] = None
         self.stats["adopted_pages"] += 1
         return blk

@@ -98,8 +98,9 @@ their own row width (dots3-note: 1,088 values in 1,152 lanes) under the
 window pool's block table and lifetime, released behind the window as
 Laguna's are. Where the full kind has a learned sparse INDEX
 (``LatentSpec.index``), its pages carry a second row a position, the index
-key (``[L_full, num_blocks, 1, block_size, index_head_dim]``, riding where a
-value pool would: same page numbers, same block table, same lifetime), and
+key (``[L_full, num_blocks, 1, block_size, IW]``, the index head's width in
+whole lanes, riding where a value pool would, which a latent pool has not:
+same page numbers, same block table, same lifetime), and
 the layer runs ``paged_index_select`` (index keys written, every visible
 key scored, the ``topk`` best selected exactly) before its read. Every
 launch, the decode tick's and the tick's with a prefill chunk, goes through
@@ -125,6 +126,30 @@ its own (``llama.routed_ffn_load``); ``moe_pairs_held`` counts those pairs,
 and ``moe_compact_overflow`` the launches that had more of them than
 ``llama.held_pair_slots`` gives places and so took the whole form.
 
+A learned sparse index may also stand over the heads' OWN keys and values
+(``LlamaConfig.index`` on a uniform config: Keye-VL-2.0). Beside a value
+pool the index keys cannot ride in its place, so they live in a THIRD
+stacked array, ``_index_cache`` ``[L, num_blocks, 1, block_size, IW]`` (IW
+the index head's width in whole lanes), under the same page numbers, block
+table, lifetime and prefix hashes: the tick donates and carries it with the
+two pools, ``_copy_blocks`` copies a page's index keys with the page,
+preemption and resume rewrite them with the rows, ``kv_page_bytes`` (hence
+``BlockManager.bytes_total`` and ``engine_stats``) counts them, and the
+prefix cache serves pages whose index keys another request wrote. The layer
+runs ``paged_index_select`` and hands its selection to
+``paged_layer_attention``, which states the one rule of which read a row
+takes (the dense walks; for a sequence that holds more than ``topk`` keys
+the masked walk of a chunk's rows, and of a decode row the masked walk or,
+past a measured crossing, the gather). The index's counters are those above, and a
+step span also carries ``prefix_hit_tokens``. A tick with a chunk is padded
+to an eighth of the token budget where its rows fit (``_row_pads``): under
+the index a padded row costs ``max_len`` keys in the selection. What was
+never judged against a reference under such an index is refused once, where
+a written plan's refusals stand: int8 pages, weight quantisation, LoRA
+adapters, a draft model, the fused FFN (``__init__``, ``submit``) and
+``extract_pages`` / ``ingest_pages`` (``_refuse_page_handoff``: the payload
+carries no index keys).
+
 SLO metrics (TTFT/TPOT histograms, queue-depth and KV-block-utilization
 gauges, admit/preempt/shed counters + flight-recorder events) flow
 through ``observability.emit`` — ``observability.summary()["serving"]``
@@ -149,7 +174,8 @@ from ...observability import tracing as _tracing
 from ...ops.kernels.serving_attention import (paged_index_select,
                                               paged_latent_attention,
                                               paged_layer_attention,
-                                              sparse_walk_keys)
+                                              sparse_walk_keys,
+                                              sparse_walk_keys_heads)
 from ...ops.pallas import flash_attention as FA
 from ...ops.pallas import fused_ffn as FF
 from ...ops.pallas import fused_sample as FS
@@ -248,7 +274,10 @@ def _pool_plan(cfg: "L.LlamaConfig"):
     """((layers of the pool, of the window pool), (a row's width in each
     pool), the window, the full layers' IndexSpec or None) of a config. A
     row is a head (`head_dim`), or a latent layer's (latent | rope key) in
-    whole lanes. Each pool is one array, so its layers share one row width,
+    whole lanes. The index stands over a latent pool or over the heads' own
+    keys and values (`LayerSpec.sparse_index`): its keys are one more row a
+    position of the full pool's pages either way. Each pool is one array,
+    so its layers share one row width,
     one window and one index geometry; a plan that asks for two of a kind
     in one pool is refused."""
     window = [s for s in cfg.layers if _in_window_pool(s)]
@@ -267,7 +296,7 @@ def _pool_plan(cfg: "L.LlamaConfig"):
                     else cfg.head_dim for s in specs] or [cfg.head_dim],
                    "row widths")
 
-    index = {s.latent.index for s in full if s.latent} - {None}
+    index = {s.sparse_index for s in full} - {None}
     if len({(i.head_dim, i.topk) for i in index}) > 1:
         raise NotImplementedError(
             f"index layers of two geometries {sorted(index)}: their keys "
@@ -359,7 +388,10 @@ class PagedServingEngine:
                  spec_k: Optional[int] = None,
                  window_blocks: Optional[int] = None):
         plan = bool(cfg.layer_plan)
-        if plan:
+        # a sparse index over the heads' own keys and values (a uniform
+        # config's, `cfg.index`): refused what a written plan is refused
+        indexed = any(s.index is not None for s in cfg.layers)
+        if plan or indexed:
             asked = {"a draft model": draft is not None,
                      "pallas_ffn": bool(pallas_ffn),
                      "quant_mode": bool(Q.resolve_quant_mode(quant_mode)),
@@ -368,11 +400,13 @@ class PagedServingEngine:
                          if quant_kv is None else quant_kv),
                      "adapter_slots (LoRA)": adapter_slots is not None}
             if any(asked.values()):
+                what = ("a layer plan" if plan else "a sparse index over the "
+                        "heads' own keys and values")
                 raise NotImplementedError(
-                    "a config with a layer plan is served in fp weights and "
+                    f"a config with {what} is served in fp weights and "
                     "fp pages, one token a row: "
                     f"{[k for k, v in asked.items() if v]} were never judged "
-                    "against a reference under a plan; drop them")
+                    f"against a reference under {what}; drop them")
         if cfg.num_experts and (draft is not None or pallas_ffn
                                 or Q.resolve_quant_mode(quant_mode)):
             raise NotImplementedError(
@@ -471,7 +505,11 @@ class PagedServingEngine:
             sides * kvh * self.block_size * w * item
             for w in self._row_widths)
         if self._index is not None:
-            full_bytes += self.block_size * self._index.head_dim * item
+            # an index key's row in whole lanes, as a latent row's
+            # (`paged_attention_latent`: Mosaic copies no page whose rows
+            # are half a lane tile; 64 values in 128 at Keye-VL-2.0's)
+            self._index_width = PL.padded_width(self._index.head_dim)
+            full_bytes += self.block_size * self._index_width * item
         hd = self._row_widths[0]
         self.kv_page_bytes = (self._pool_layers[0] * full_bytes
                               + n_window * window_bytes)
@@ -543,19 +581,6 @@ class PagedServingEngine:
                 # the same of the window layers, inside their windows
                 self.stats.update(attn_keys_latent_window=0,
                                   attn_pairs_latent_window=0)
-            if self._index is not None:
-                # the sparse index, summed over ticks and index layers:
-                # index keys read and (row, key) pairs scored (the causal
-                # keys and pairs of the sequences that select), the keys
-                # the kernels' two launches copied to score them, the pairs
-                # selected, the rows that had no selection to make and
-                # took the dense walk, the selecting rows that took the
-                # masked walk and the pairs it multiplied for them, and
-                # the pages that carry index keys
-                self.stats.update(index_keys=0, index_keys_fetched=0,
-                                  index_pairs=0, sparse_pairs_selected=0,
-                                  sparse_rows_dense=0, sparse_rows_walked=0,
-                                  sparse_pairs_walked=0, index_pages_live=0)
         elif plan:
             # keys and (row, key) pairs inside the masks, summed over ticks
             # and over the layers of the kind, and the keys a causal mask
@@ -563,6 +588,18 @@ class PagedServingEngine:
             self.stats.update(attn_keys_full=0, attn_keys_window=0,
                               attn_keys_causal=0, attn_pairs_full=0,
                               attn_pairs_window=0)
+        if self._index is not None:
+            # the sparse index, summed over ticks and index layers: index
+            # keys read and (row, key) pairs scored (the causal keys and
+            # pairs of the sequences that select), the keys the kernels'
+            # two launches copied to score them, the pairs selected, the
+            # rows that had no selection to make and took the dense walk,
+            # the selecting rows that took the masked walk and the pairs it
+            # multiplied for them, and the pages that carry index keys
+            self.stats.update(index_keys=0, index_keys_fetched=0,
+                              index_pairs=0, sparse_pairs_selected=0,
+                              sparse_rows_dense=0, sparse_rows_walked=0,
+                              sparse_pairs_walked=0, index_pages_live=0)
         if n_window:
             # pages allocated in each pool, summed over ticks (their ratio
             # is what the window pool saves), and pages given back so far
@@ -613,6 +650,11 @@ class PagedServingEngine:
                 f"paged-attention kernel")
         self.pallas = bool(PA.selected(*geometry) if pallas is None
                            else pallas)
+        if indexed and self.pallas and not PA.whole_pages(cfg.head_dim):
+            raise NotImplementedError(
+                f"a sparse index over heads of {cfg.head_dim}: the masked "
+                "walk is the whole-page walks', which Mosaic takes at head "
+                "dims that are whole lanes; serve it with pallas=False")
         # whether a tick's read is one of the whole-page walks (their
         # counters are reckoned only then) or the BlockSpec walk
         self._whole_pages = (self.pallas and not self.latent
@@ -647,14 +689,18 @@ class PagedServingEngine:
         shape = (self._pool_layers[0], self.num_blocks, kvh, self.block_size,
                  hd)
         self._key_cache = jnp.zeros(shape, self.cache_dtype)
-        # a latent pool has no value side: the values are the rows'
-        # latents. What rides in its place is the full layers' second row
-        # a position where they have one: the index keys, same pages
-        self._value_cache = (
-            jnp.zeros(shape, self.cache_dtype) if not self.latent
-            else None if self._index is None
-            else jnp.zeros(shape[:-1] + (self._index.head_dim,),
-                           self.cache_dtype))
+        # The index keys of the full layers, where they have an index: one
+        # more row a position of the same pages (same page numbers, same
+        # block table, same lifetime, same prefix hashes). A latent pool
+        # has no value side (the values are the rows' latents) and the
+        # index keys ride in its place; beside heads' own keys AND values
+        # they are a third array, `_index_cache`
+        index_keys = self._index and jnp.zeros(
+            shape[:2] + (1, self.block_size, self._index_width),
+            self.cache_dtype)
+        self._value_cache = (index_keys if self.latent
+                             else jnp.zeros(shape, self.cache_dtype))
+        self._index_cache = None if self.latent else index_keys
         if n_window:
             # two pools ride the tick's carry: (full layers', window layers')
             wshape = ((n_window, self.window_blocks) + shape[2:-1]
@@ -699,6 +745,25 @@ class PagedServingEngine:
         self._rope_emb = tuple(
             rope_emb(*L.rope_table(jnp.arange(self.max_len), widths[r], r))
             for r in self._ropes)
+        if self._index_cache is not None:
+            # behind the layers' ropes: the table that turns the whole
+            # index head of a layer of heads' own keys (the layer's theta
+            # at the index head's width)
+            spec = next(s for s in cfg.kinds if s.index is not None)
+            self._rope_emb += (rope_emb(*L.rope_table(
+                jnp.arange(self.max_len), cfg.index_rope_width(spec),
+                spec.rope)),)
+        # The padded row counts of a tick with a chunk: the token budget,
+        # and under an index over heads' own keys an eighth of it for a
+        # tick whose rows fit there. Under a sparse index a padded row is
+        # not free: the selection runs over the table's whole width for
+        # every row of the executable (`select_topk`: 32 passes over
+        # [rows, max_len] scores), so a turn's hundred-odd new rows in the
+        # budget's executable would pay for all its rows
+        self._row_pads = (self.token_budget,)
+        if self._index_cache is not None and (
+                self.token_budget // 8 >= 2 * self.max_batch):
+            self._row_pads = (self.token_budget // 8, self.token_budget)
         # executables keyed by what differs between two ticks of this
         # engine: (token-budget, batch-slots, decode, ffn-mode, adapter
         # rank classes, spec-mode); `decode` = every chunk is one token
@@ -717,6 +782,7 @@ class PagedServingEngine:
         # the device's time at work so far, by the ticks' own intervals
         # (`_harvest`): what a request's `device_s` is a difference of
         self._device_busy_ns = 0
+        self._hits_seen = 0     # `prefix_hit_tokens` at the last harvest
         # set by ReplicaHandle so this engine's tick spans say which
         # replica served them (the merged-trace failover story)
         self._trace_replica: Optional[int] = None
@@ -746,7 +812,8 @@ class PagedServingEngine:
         ``low_confidence_static``."""
         with _tracing.phase("serve.submit"):
             submit_ns = time.perf_counter_ns()
-            tokens = [int(t) for t in np.asarray(tokens).reshape(-1)]
+            tokens = np.asarray(tokens).reshape(-1).astype(
+                np.int64, copy=False).tolist()     # Python ints, one pass
             total = len(tokens) + max(int(max_new_tokens), 0)
             Bd = self.cfg.block_length
             steps = 0
@@ -759,10 +826,11 @@ class PagedServingEngine:
                     "denoising_steps belongs to a block-diffusion config "
                     "(block_length > 0); this engine's model is "
                     "autoregressive")
-            if adapter is not None and self.cfg.layer_plan:
+            if adapter is not None and (self.cfg.layer_plan
+                                        or self._index is not None):
                 raise NotImplementedError(
-                    "LoRA adapters with a layer plan were never judged "
-                    "against a reference; submit without one")
+                    "LoRA adapters with a layer plan or a sparse index were "
+                    "never judged against a reference; submit without one")
             if total > self.max_len:
                 raise ValueError(
                     f"prompt {len(tokens)} + new {max_new_tokens} "
@@ -882,6 +950,12 @@ class PagedServingEngine:
         return out
 
     def _refuse_page_handoff(self, what: str):
+        if self._index_cache is not None:
+            raise NotImplementedError(
+                f"{what} under a sparse index over the heads' own keys and "
+                "values: a page's index keys live in a third array that the "
+                "hand-off's payload (k, v) does not carry, and a page "
+                "adopted without them would be selected from by zeros")
         if self.cfg.layer_plan:
             raise NotImplementedError(
                 f"{what} with a layer plan: pages are handed off by prefix "
@@ -991,7 +1065,8 @@ class PagedServingEngine:
         """Host-side fused-FFN dispatch for this tick: (on, fallback
         reason). None re-reads FLAGS_pallas_ffn every tick; the result
         rides the executable cache key so flag flips retrace exactly once."""
-        if self.pallas_ffn is False or self.cfg.layer_plan:
+        if (self.pallas_ffn is False or self.cfg.layer_plan
+                or self._index is not None):
             return False, None
         if self.pallas_ffn:      # forced (params+geometry validated at init)
             return True, None
@@ -1051,12 +1126,16 @@ class PagedServingEngine:
                     0, tok_pad - 1).reshape(-1)
             return last
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
+        @functools.partial(jax.jit, donate_argnums=(1, 2),
+                           donate_argnames=("index_cache",))
         def step_fn(params, key_cache, value_cache, kv_scales, tokens,
                     block_tables, cu_seqlens_q, seq_lens_decoder,
                     seq_lens_this_time, rope_emb, temps, top_ps, keys,
                     greedy, ad_args, quota=None, masked=None, prev=None,
-                    feed=None):
+                    feed=None, index_cache=None):
+            # `index_cache`: the third page array of a config with a sparse
+            # index over heads' own keys and values (`_index_cache`), given
+            # up and handed back behind the two pools as they are
             # a tick launched ahead of the last one's harvest takes the ids
             # the host does not hold yet from the last tick's output, on
             # the device: `prev` is that tick's `nxt` (any earlier one when
@@ -1091,10 +1170,12 @@ class PagedServingEngine:
             # padding, which no expert may see
             valid = jnp.arange(tok_pad) < cu_seqlens_q[B]
             with jax.named_scope("layers"):
-                x, kcs, vcs, loads = self._layer_loop(
-                    params, x, key_cache, value_cache, kv_scales, ad_args,
-                    block_tables, cu_seqlens_q, seq_lens_decoder,
-                    seq_lens_this_time, rope_emb, valid, use_pallas, ffn_mode)
+                x, kcs, vcs, ics, loads = self._layer_loop(
+                    params, x, key_cache, value_cache, index_cache,
+                    kv_scales, ad_args, block_tables, cu_seqlens_q,
+                    seq_lens_decoder, seq_lens_this_time, rope_emb, valid,
+                    use_pallas, ffn_mode)
+            pools = (kcs, vcs) if index_cache is None else (kcs, vcs, ics)
             with jax.named_scope("head"):
                 # last-token hidden state per slot, or its whole block's
                 hlast = x[block_rows(cu_seqlens_q)]          # [B (* Bd), d]
@@ -1156,14 +1237,14 @@ class PagedServingEngine:
                 with jax.named_scope("sample"):
                     all_arg = jnp.argmax(all_logits,
                                          axis=-1).astype(jnp.int32)
-                return nxt, all_arg, kcs, vcs
-            return nxt, kcs, vcs
+                return (nxt, all_arg, *pools)
+            return (nxt, *pools)
 
         return step_fn
 
-    def _layer_loop(self, params, x, key_cache, value_cache, kv_scales,
-                    ad_args, block_tables, cu, past, this, rope_emb, valid,
-                    use_pallas, ffn_mode):
+    def _layer_loop(self, params, x, key_cache, value_cache, index_cache,
+                    kv_scales, ad_args, block_tables, cu, past, this,
+                    rope_emb, valid, use_pallas, ffn_mode):
         """The tick's layers, of every config (`llama.scan_plan` over
         `cfg.kinds`; a uniform config is one kind, one `lax.scan` over its
         whole stack): one body a kind, which finds its pages in its
@@ -1180,9 +1261,15 @@ class PagedServingEngine:
         paged_attention (`paged_attention_full` / `_window` inside it
         where the tick makes more than one kind of launch), `attn_gate`,
         attn_out, ffn or moe (`shared_expert` inside). Latent layers
-        (`latent_attention` below) have one pool and no value side.
-        Returns (x, key_cache, value_cache, (experts hit, largest load[,
-        pairs on held experts, launches past their places]) or ())."""
+        (`latent_attention` below) have one pool and no value side; a
+        layer of heads' own keys and values under a sparse index
+        (`LayerSpec.index`) writes and scores its index keys in
+        `index_cache` (`index_q`, `index_k`, `paged_index_select`'s own)
+        and hands its selection to `paged_layer_attention`, which states
+        the rule of which read a row then takes.
+        Returns (x, key_cache, value_cache, index_cache, (experts hit,
+        largest load[, pairs on held experts, launches past their places])
+        or ())."""
         cfg = self.cfg
         kinds, kind_of = cfg.kinds, cfg.kind_of_layer
         two = isinstance(key_cache, tuple)
@@ -1211,9 +1298,10 @@ class PagedServingEngine:
                 "kv": kv_scales and tuple(kv_scales),
                 "ad": tuple(a["packs"] for a in ad_args)})
 
-        if self.latent:
+        if self.latent or index_cache is not None:
             # every packed row's position, for its rope: the rows are one
-            # "sequence" of the model's latent functions ([1, tok, ...])
+            # "sequence" of the model's latent and index functions
+            # ([1, tok, ...])
             tok = jnp.arange(x.shape[0], dtype=jnp.int32)
             tok_b = jnp.clip(jnp.searchsorted(cu, tok, side="right") - 1, 0,
                              cu.shape[0] - 2)
@@ -1245,7 +1333,8 @@ class PagedServingEngine:
                 with jax.named_scope("latent_kv"):
                     row = L.latent_kv(h, lp, cfg, cos, sin, ls)[0]
                 if ls.index is not None:
-                    qi, ki, w = L.index_qkw(h, cq, lp, cfg, ls, cos, sin)
+                    qi, ki, w = L.index_qkw(h, cq, lp, cfg, ls.index, cos,
+                                            sin)
             if ls.index is not None:
                 *select, index_pool = paged_index_select(
                     qi[0], w[0], ki[0], index_pool, page_layer, past, this,
@@ -1267,7 +1356,7 @@ class PagedServingEngine:
 
         def body(kind, carry, leaves):
             spec = kinds[kind]
-            x, pk, pv, *counts = carry
+            x, pk, pv, pi, *counts = carry
             lp = leaves["lp"]
 
             def lora(h, t, y):
@@ -1299,6 +1388,17 @@ class PagedServingEngine:
                                for n in ("wq", "wk", "wv"))
                     q, k = L.qk_normed(q, k, lp, cfg)
                     qkv = jnp.concatenate([q, k, v], axis=-1)
+                    if spec.index is not None:
+                        half = cfg.index_rope_width(spec) // 2
+                        cos, sin = (rope_emb[-1][i, 0, tok_pos, :half]
+                                    for i in (0, 1))
+                        qi, ki, w = L.index_qkw(h[None], None, lp, cfg,
+                                                spec.index, cos, sin)
+                select = None
+                if spec.index is not None:
+                    *select, pi = paged_index_select(
+                        qi[0], w[0], ki[0], pi, leaves["page_layer"], past,
+                        this, cu, tables[pool], spec.index.topk, use_pallas)
                 # scopes itself: qkv (split, rope), cache_write,
                 # paged_attention
                 o, _, kc, vc = paged_layer_attention(
@@ -1310,7 +1410,8 @@ class PagedServingEngine:
                     window=cfg.sliding_window if spec.attn == "window"
                     else 0,
                     rotary_dim=rot if rot < cfg.head_dim else 0,
-                    kind=spec.attn if len(self._launches) > 1 else None)
+                    kind=spec.attn if len(self._launches) > 1 else None,
+                    select=select and tuple(select))
                 pk, pv = put(pk, pool, kc), put(pv, pool, vc)
                 if cfg.attn_gate:
                     o = L.attn_gated(o.reshape(o.shape[0], spec.heads, -1),
@@ -1340,16 +1441,16 @@ class PagedServingEngine:
                         gate = (jax.nn.silu(Q.matmul_param(h, lp, "w1"))
                                 * Q.matmul_param(h, lp, "w3"))
                         x = x + Q.matmul_param(gate, lp, "w2")
-            return (x, pk, pv, *counts)
+            return (x, pk, pv, pi, *counts)
 
         # the expert counters ride the carry: one a field the tick sends
         # behind its tokens but `moe_pairs`, which the lengths give
         zero = jnp.zeros((), jnp.int32)
-        x, pk, pv, *counts = L.scan_plan(
+        x, pk, pv, pi, *counts = L.scan_plan(
             cfg, body,
-            (x, pools_k, pools_v) + (zero,) * len(self._moe_fields[1:]),
-            stacks)
-        return (x, pk if two else pk[0], pv if two else pv[0],
+            (x, pools_k, pools_v, index_cache)
+            + (zero,) * len(self._moe_fields[1:]), stacks)
+        return (x, pk if two else pk[0], pv if two else pv[0], pi,
                 tuple(counts))
 
     def _held_counts(self, load, rows: int):
@@ -1396,8 +1497,8 @@ class PagedServingEngine:
             nb = self.num_blocks
             quant_kv = self.quant_kv
 
-            @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
-            def copy_fn(kc, vc, kdq, vdq, src, dst):
+            @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 6))
+            def copy_fn(kc, vc, kdq, vdq, src, dst, ic=None):
                 # one-hot selects, statically unrolled over the pad width:
                 # a scatter-free page copy that rewrites the whole pool.
                 # When quantized, a page's dequant-scale rows move WITH the
@@ -1410,16 +1511,20 @@ class PagedServingEngine:
                                                          None, None]
                         blk_k = lax.dynamic_slice_in_dim(kc, s, 1, axis=1)
                         kc = jnp.where(sel, blk_k, kc)
-                        if vc is not None:      # a latent pool has no values
-                            blk_v = lax.dynamic_slice_in_dim(vc, s, 1, axis=1)
-                            vc = jnp.where(sel, blk_v, vc)
+                        # (a latent pool has no values, or its index
+                        # keys there; `ic`: the third array of an index
+                        # over heads' own keys: a copied page takes its
+                        # index keys with it)
+                        vc, ic = (a if a is None else jnp.where(
+                            sel, lax.dynamic_slice_in_dim(a, s, 1, axis=1), a)
+                            for a in (vc, ic))
                         if quant_kv:
                             sel3 = (jnp.arange(nb) == dst[i])[None, :, None]
                             kdq = jnp.where(sel3, lax.dynamic_slice_in_dim(
                                 kdq, s, 1, axis=1), kdq)
                             vdq = jnp.where(sel3, lax.dynamic_slice_in_dim(
                                 vdq, s, 1, axis=1), vdq)
-                return kc, vc, kdq, vdq
+                return kc, vc, kdq, vdq, ic
 
             self._copy_fn = copy_fn
         for i in range(0, len(pairs), PAD):
@@ -1431,9 +1536,10 @@ class PagedServingEngine:
             kdq = vdq = None
             if self.quant_kv:
                 kq, vq, kdq, vdq = self._kv_scales
-            self._key_cache, self._value_cache, kdq, vdq = self._copy_fn(
+            (self._key_cache, self._value_cache, kdq, vdq,
+             self._index_cache) = self._copy_fn(
                 self._key_cache, self._value_cache, kdq, vdq,
-                jnp.asarray(src), jnp.asarray(dst))
+                jnp.asarray(src), jnp.asarray(dst), self._index_cache)
             if self.quant_kv:
                 self._kv_scales = (kq, vq, kdq, vdq)
             self.stats["cow_block_copies"] += len(chunk)
@@ -1622,8 +1728,10 @@ class PagedServingEngine:
                     budget_left -= k_eff
             spec_mode = bool(spec_plan)
 
-            tok_pad, B = self.token_budget, self.max_batch
-            Bd = self.cfg.block_length
+            B, Bd = self.max_batch, self.cfg.block_length
+            rows = batch.total_tokens + sum(map(len, spec_plan.values()))
+            tok_pad = next((p for p in self._row_pads if p >= rows),
+                           self.token_budget)
             # block diffusion: which items bring their open block (the
             # others are prefill chunks)
             in_block = [bool(Bd) and self.scheduler.prefill_left(seq) <= 0
@@ -1757,12 +1865,16 @@ class PagedServingEngine:
                      this_lens, self._rope_emb, temps, top_ps, keys,
                      greedy, ad_args, *((quota, masked) if Bd
                                         else (None, None)),
-                     self._last_out, feed)
+                     self._last_out, feed,
+                     **({} if self._index_cache is None
+                        else {"index_cache": self._index_cache}))
             if spec_mode:
                 tick.out, tick.all_arg = out[:2]
             else:
                 tick.out = out[0]
             self._last_out = tick.out
+            if self._index_cache is not None:
+                *out, self._index_cache = out
             self._key_cache, self._value_cache = out[-2:]
             if fused_tick and self.stats["step_builds"] > builds0:
                 # fresh trace: the launch-counter delta counts the DISTINCT
@@ -1819,7 +1931,7 @@ class PagedServingEngine:
             if self.window_blocks:
                 tick.pool_pages = (self.blocks.num_allocated(),
                                    self.blocks.window_allocated())
-            elif self.latent:
+            elif self._index is not None or self.latent:
                 tick.pool_pages = (self.blocks.num_allocated(), 0)
         return tick
 
@@ -1891,12 +2003,19 @@ class PagedServingEngine:
                 fields.update(walked)
                 for name, n in walked.items():
                     self.stats[name] += n
-            if self.cfg.layer_plan:
+            if self.cfg.layer_plan or self._index is not None:
                 keys = self._plan_keys(dec_lens, this_lens, cur.tok_pad)
                 if self.latent:
                     keys["latent_pages_live"] = cur.pool_pages[0]
                 if self._index is not None:
                     keys["index_pages_live"] = cur.pool_pages[0]
+                if self._index_cache is not None:
+                    # prompt tokens the block manager served from cached
+                    # pages since the tick before was read: pages whose
+                    # index keys another request wrote
+                    hits = self.blocks.stats["prefix_hit_tokens"]
+                    fields["prefix_hit_tokens"] = hits - self._hits_seen
+                    self._hits_seen = hits
                 if self.window_blocks:
                     keys.update(
                         full_pages_live=cur.pool_pages[0],
@@ -1982,43 +2101,55 @@ class PagedServingEngine:
         each_keys = past + this
         each_pairs = this * past + this * (this + 1) // 2
         keys, pairs = int(each_keys.sum()), int(each_pairs.sum())
-        if self.latent and self._index is not None:
+        if self._index is not None:
             # the full layers' rows divide by the rule of
-            # `paged_latent_attention`: a sequence that holds more than
-            # `topk` keys after this tick selects (its rows score every
-            # key they see and attend over min(p + 1, topk) of them), the
-            # others walk densely
+            # `paged_latent_attention` and `paged_layer_attention`: a
+            # sequence that holds more than `topk` keys after this tick
+            # selects (its rows score every key they see and attend over
+            # min(p + 1, topk) of them), the others walk densely
             k = self._index.topk
             sel = each_keys > k
             under = np.clip(k - past, 0, this)   # rows that see <= k keys
             chosen = (under * past + under * (under + 1) // 2
                       + (this - under) * k)
-            # of the selecting sequences the chunks under the crossing
-            # read through the masked walk, which multiplies their rows'
-            # causal pairs (the kernels' rule: the stock read gathers)
+            # of the selecting sequences those under the crossing read
+            # through the masked walk, which multiplies their rows' causal
+            # pairs (the kernels' rule, the ops': a latent layer's chunks;
+            # of a layer of heads' own keys every chunk, and its one-row
+            # sequences under theirs; the stock read has no such form)
             rows_walked = pairs_walked = 0
+            item = np.dtype(self.cache_dtype).itemsize
             for spec in cfg.layers if self.pallas else ():
-                if spec.attn == "latent" and spec.latent.index is not None:
-                    walk = sel & (this > 1) & (each_keys <= sparse_walk_keys(
+                if spec.sparse_index is None:
+                    continue
+                if spec.latent is not None:
+                    walk = (this > 1) & (each_keys <= sparse_walk_keys(
                         spec.heads, self._row_widths[0],
                         spec.latent.kv_lora_rank, k))
-                    rows_walked += int(this[walk].sum())
-                    pairs_walked += int(each_pairs[walk].sum())
+                else:
+                    walk = (this > 1) | (each_keys <= sparse_walk_keys_heads(
+                        cfg.num_kv_heads, cfg.head_dim, item, k))
+                rows_walked += int(this[sel & walk].sum())
+                pairs_walked += int(each_pairs[sel & walk].sum())
             # what the index's two launches copy out of their pages to
             # score `index_keys` (the kernels' read: the stock path gathers
             # every table whole and has no such count)
             fetched = n_full * PL.index_keys_fetched(
                 past[sel], this[sel], tok_pad or self.token_budget,
                 self.block_size, self.max_blocks_per_seq) if self.pallas else 0
-            out = {"attn_keys_latent": n_full * int(each_keys[~sel].sum()),
-                   "attn_pairs_latent": n_full * int(each_pairs[~sel].sum()),
+            if self.latent:
+                out = {"attn_keys_latent":
+                       n_full * int(each_keys[~sel].sum()),
+                       "attn_pairs_latent":
+                       n_full * int(each_pairs[~sel].sum())}
+            out.update({
                    "index_keys": n_full * int(each_keys[sel].sum()),
                    "index_keys_fetched": fetched,
                    "index_pairs": n_full * int(each_pairs[sel].sum()),
                    "sparse_pairs_selected": n_full * int(chosen[sel].sum()),
                    "sparse_rows_dense": n_full * int(this[~sel].sum()),
                    "sparse_rows_walked": rows_walked,
-                   "sparse_pairs_walked": pairs_walked}
+                   "sparse_pairs_walked": pairs_walked})
         elif self.latent:
             out = {"attn_keys_latent": n_full * keys,
                    "attn_pairs_latent": n_full * pairs}
@@ -2034,8 +2165,9 @@ class PagedServingEngine:
             if n_window:
                 out.update(attn_keys_latent_window=n_window * wkeys,
                            attn_pairs_latent_window=n_window * wpairs)
+        if self.latent or not cfg.layer_plan:
             return out
-        return {"attn_keys_full": n_full * keys,
+        return {**out, "attn_keys_full": n_full * keys,
                 "attn_keys_window": n_window * wkeys,
                 "attn_keys_causal": cfg.num_layers * keys,
                 "attn_pairs_full": n_full * pairs,

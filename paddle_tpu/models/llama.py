@@ -51,7 +51,12 @@ of two kinds, as dots3-note has: a kind with a causal WINDOW over its
 latent cache, and a kind with a learned sparse INDEX (`IndexSpec`,
 DeepSeek-V3.2's lightning indexer: `index_qkw` scores every visible key
 with a few narrow heads, `select_topk` keeps the `topk` best exactly, and
-the layer attends over those alone). And a config may hold ONE CHIP'S SHARE of its
+the layer attends over those alone). The same index may stand over the
+heads' OWN keys and values (`LlamaConfig.index` on a uniform config, or
+`LayerSpec.index`: Keye-VL-2.0, whose index query comes from the layer's
+normed input, there being no query latent; `index_select` is the
+selection of either kind in the model's own forward). And a config may
+hold ONE CHIP'S SHARE of its
 routed experts (`experts_held` = (first, count)): `route` runs over all
 `num_experts`, with a selection bias where the model has one
 (`router_bias`), and `routed_ffn_load` computes the pairs of the held
@@ -101,13 +106,18 @@ class RopeSpec:
 
 @dataclasses.dataclass(frozen=True)
 class IndexSpec:
-    """A latent layer's learned sparse index (DeepSeek-V3.2's lightning
-    indexer): `heads` index heads of `head_dim` from the layer's query
-    latent (`wiq`), one index key of `head_dim` a position from the layer's
+    """A layer's learned sparse index (DeepSeek-V3.2's lightning indexer),
+    over a latent cache or over the heads' own keys and values: `heads`
+    index heads of `head_dim` (`wiq`), projected from the layer's query
+    latent where it has one (a latent layer, dots3-note) and from its
+    normed input where it has none (Keye-VL-2.0); one index key of `head_dim` a position from the layer's
     normed input through a LayerNorm with a bias (`wik`, `ik_norm`,
-    `ik_bias`), a weight a head (`wiw`), the layer's rope on the leading
-    `qk_rope_head_dim` values of each. A query attends over the `topk`
-    visible keys of largest score alone (`select_topk`)."""
+    `ik_bias`); a weight a head (`wiw`). Rope: a latent layer's on the
+    leading `qk_rope_head_dim` values of each index head and of the key; a
+    layer of heads' own keys turns the WHOLE index head, by a table of the
+    layer's theta made for `head_dim` (`LlamaConfig.index_rope_width`). A
+    query attends over the `topk` visible keys of largest score alone
+    (`select_topk`)."""
     heads: int
     head_dim: int
     topk: int
@@ -160,12 +170,21 @@ class LayerSpec:
     them here), its query heads, its rope, and `ffn` "dense" (SwiGLU of
     `dense_intermediate_size`) or "sparse" (the routed experts of
     `intermediate_size`, with the shared expert where the config has
-    one). Layers with equal specs are one kind."""
+    one). `index`: the sparse index of a layer of heads' own keys and
+    values (a latent layer's is its `LatentSpec.index`; `sparse_index` is
+    either). Layers with equal specs are one kind."""
     attn: str = "full"
     heads: int = 0
     rope: RopeSpec = RopeSpec()
     ffn: str = "dense"
     latent: Optional[LatentSpec] = None
+    index: Optional[IndexSpec] = None
+
+    @property
+    def sparse_index(self) -> Optional[IndexSpec]:
+        """The layer's sparse index, whichever kind of cache it stands
+        over; None: the layer attends over every key it sees."""
+        return self.latent.index if self.latent is not None else self.index
 
 
 @dataclasses.dataclass(frozen=True)
@@ -251,6 +270,10 @@ class LlamaConfig:
     # not held is computed by no one here and adds nothing (its chip's
     # part of the sum). () = every expert is held
     experts_held: Tuple[int, ...] = ()
+    # a uniform config's learned sparse index over its heads' own keys and
+    # values (every layer has it); a plan's layers
+    # carry their own (`LayerSpec.index`, `LatentSpec.index`)
+    index: Optional[IndexSpec] = None
 
     def __post_init__(self):
         here = (self.hidden_size, self.num_heads)
@@ -261,6 +284,10 @@ class LlamaConfig:
         else:   # given, here or over a derived one by `replace`
             object.__setattr__(self, "head_dim_derived_from", ())
         if self.layer_plan:
+            if self.index is not None:
+                raise ValueError(
+                    "a layer plan states its layers' indexes itself "
+                    "(LayerSpec.index, LatentSpec.index)")
             if len(self.layer_plan) != self.num_layers:
                 raise ValueError(
                     f"layer_plan has {len(self.layer_plan)} layers, "
@@ -292,6 +319,14 @@ class LlamaConfig:
                 raise ValueError(
                     f"experts_held={self.experts_held}: (first, count) "
                     f"inside num_experts={self.num_experts}")
+        for spec in self.kinds:
+            ix = spec.sparse_index
+            if ix is not None and spec.latent is None and (
+                    spec.attn != "full" or self.block_length):
+                raise NotImplementedError(
+                    "a sparse index stands over a latent layer or, over "
+                    "heads' own keys and values, over a causal "
+                    f"full-attention layer; no model served has {spec}")
         latent = {s.attn == "latent" for s in self.layer_plan}
         if True in latent and (False in latent or self.num_kv_heads != 1):
             raise NotImplementedError(
@@ -329,6 +364,15 @@ class LlamaConfig:
             return spec.latent.qk_rope_head_dim
         return self.head_dim
 
+    def index_rope_width(self, spec: LayerSpec) -> int:
+        """The width the rope table of a layer's sparse index is made for:
+        a latent layer's rope slice (the layer's own table turns the
+        leading values of each index head), the whole index head on a
+        layer of heads' own keys (a table of its own, the layer's theta)."""
+        if spec.latent is not None:
+            return spec.latent.qk_rope_head_dim
+        return spec.index.head_dim
+
     def one_latent(self) -> LatentSpec:
         """The widths of a plan with ONE kind of latent layer; a plan with
         two has no answer, and a caller reads a layer's own spec."""
@@ -363,7 +407,8 @@ class LlamaConfig:
         `__post_init__` refuses of a written plan is not refused of this."""
         return self.layer_plan or (LayerSpec(
             "full", self.num_heads, RopeSpec(theta=self.rope_theta),
-            "sparse" if self.num_experts else "dense"),) * self.num_layers
+            "sparse" if self.num_experts else "dense",
+            index=self.index),) * self.num_layers
 
     @property
     def kinds(self) -> Tuple[LayerSpec, ...]:
@@ -390,13 +435,13 @@ class LlamaConfig:
             attn = (d * r + r * heads * (nope + rope) + d * (c + rope)
                     + c * heads * (nope + v) + heads * v * d)
             norms += r + c
-            if ls.index is not None:
-                ix = ls.index
-                attn += (r * ix.heads * ix.head_dim + d * ix.head_dim
-                         + d * ix.heads)
-                norms += 2 * ix.head_dim
         else:
             attn = 2 * d * heads * hd + 2 * d * self.num_kv_heads * hd
+        ix = spec.sparse_index
+        if ix is not None:
+            attn += ((r if spec.latent else d) * ix.heads
+                     * ix.head_dim + d * ix.head_dim + d * ix.heads)
+            norms += 2 * ix.head_dim
         if self.attn_gate:
             attn += d * heads
         if self.qk_norm:
@@ -471,10 +516,11 @@ def _normal(key: jax.Array, shape, dtype, scale: float = 0.02):
 
 
 def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
-                 ffn_kind: str, ls: Optional[LatentSpec] = None
-                 ) -> Dict[str, jax.Array]:
+                 ffn_kind: str, ls: Optional[LatentSpec] = None,
+                 index: Optional[IndexSpec] = None) -> Dict[str, jax.Array]:
     """One stack of `L` layers with `nh` query heads, latent attention at
-    the widths `ls` (None: heads' own keys and values) and an FFN of
+    the widths `ls` (None: heads' own keys and values, under the sparse
+    index `index` where the layer has one) and an FFN of
     `ffn_kind` ("dense" | "sparse"); the keys are split as they always
     were, so a uniform config draws the weights it drew. A config that
     holds a share of its experts (`experts_held`) draws the whole router
@@ -502,15 +548,7 @@ def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
             "attn_norm": jnp.ones((L, d), pt),
             "mlp_norm": jnp.ones((L, d), pt),
         }
-        if ls.index is not None:
-            ix = ls.index
-            ki = jax.random.split(keys[2], 3)
-            blocks.update(
-                wiq=normal(ki[0], (L, r, ix.heads * ix.head_dim)),
-                wik=normal(ki[1], (L, d, ix.head_dim)),
-                wiw=normal(ki[2], (L, d, ix.heads)),
-                ik_norm=jnp.ones((L, ix.head_dim), pt),
-                ik_bias=jnp.zeros((L, ix.head_dim), pt))
+        ix, ki = ls.index, jax.random.split(keys[2], 3)
     else:
         blocks = {
             "wq": normal(keys[0], (L, d, nh * hd)),
@@ -520,6 +558,15 @@ def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
             "attn_norm": jnp.ones((L, d), pt),
             "mlp_norm": jnp.ones((L, d), pt),
         }
+        ix, ki = index, jax.random.split(jax.random.fold_in(keys[3], 1), 3)
+    if ix is not None:
+        blocks.update(
+            wiq=normal(ki[0], (L, d if ls is None else r,
+                               ix.heads * ix.head_dim)),
+            wik=normal(ki[1], (L, d, ix.head_dim)),
+            wiw=normal(ki[2], (L, d, ix.heads)),
+            ik_norm=jnp.ones((L, ix.head_dim), pt),
+            ik_bias=jnp.zeros((L, ix.head_dim), pt))
     if cfg.attn_gate:
         blocks["wg"] = normal(keys[8], (L, d, nh))
     if cfg.qk_norm and cfg.qk_norm_per_head:
@@ -569,11 +616,13 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         kind_of = cfg.kind_of_layer
         blocks = tuple(
             _init_blocks(cfg, jax.random.fold_in(key, 1 + k),
-                         kind_of.count(k), spec.heads, spec.ffn, spec.latent)
+                         kind_of.count(k), spec.heads, spec.ffn, spec.latent,
+                         spec.index)
             for k, spec in enumerate(cfg.kinds))
     else:
         blocks = _init_blocks(cfg, key, cfg.num_layers, cfg.num_heads,
-                              "sparse" if cfg.num_experts else "dense")
+                              "sparse" if cfg.num_experts else "dense",
+                              index=cfg.index)
     return {
         "embed": normal(keys[8], (v, d)),
         "blocks": blocks,
@@ -652,7 +701,8 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto",
-              block_length: int = 0, window: int = 0) -> jax.Array:
+              block_length: int = 0, window: int = 0,
+              select: Optional[jax.Array] = None) -> jax.Array:
     """Causal MHA/GQA. q [B,T,H,hd], k/v [B,T,KV,hd] → [B,T,H,hd].
 
     impl: 'auto' uses the Pallas flash kernel on TPU when available, else the
@@ -700,7 +750,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, impl: str = "auto",
     if window:
         pos = jnp.arange(T)
         mask = mask & (pos[None, :] > pos[:, None] - window)
-    scores = jnp.where(mask[None, None], scores, -1e30)
+    mask = (mask[None, None] if select is None
+            else (mask[None] & select)[:, None])
+    scores = jnp.where(mask, scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhts,bshd->bthd", probs, v)
 
@@ -1174,14 +1226,18 @@ def ffn(h: jax.Array, lp: Dict[str, jax.Array], impl: str = "stock") -> jax.Arra
 def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
           cos: jax.Array, sin: jax.Array, attn_impl: str = "auto",
           ffn_impl: str = "stock",
-          spec: Optional[LayerSpec] = None) -> jax.Array:
+          spec: Optional[LayerSpec] = None, index_rope=None) -> jax.Array:
     """One transformer block; lp leaves have the layer axis already indexed.
     `spec` is the layer's entry of a layer plan (its heads, window or full
     attention, dense or sparse FFN; cos and sin are its rope's, narrower
-    than a head for a partial one); None: the config's one kind."""
+    than a head for a partial one); None: the config's one kind.
+    `index_rope`: (cos, sin) of the sparse index of a layer of heads' own
+    keys and values (`LlamaConfig.index_rope_width`), which then attends
+    over its selection alone."""
     B, T, d = x.shape
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
     nh = spec.heads if spec else cfg.num_heads
+    ix = spec.index if spec else cfg.index
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     if spec and spec.attn == "latent":
         x = x + latent_self_attention(h, lp, cfg, nh, cos, sin, spec.latent)
@@ -1192,8 +1248,14 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
         q = apply_rope(q.reshape(B, T, nh, hd), cos, sin)
         k = apply_rope(k.reshape(B, T, nkv, hd), cos, sin)
         window = cfg.sliding_window if spec and spec.attn == "window" else 0
+        select = None
+        if ix is not None:
+            pos = jnp.arange(T)
+            select = index_select(
+                h, None, lp, cfg, ix, *index_rope,
+                jnp.broadcast_to(pos[None, :] <= pos[:, None], (B, T, T)))
         o = attention(q, k, v, impl=attn_impl, block_length=cfg.block_length,
-                      window=window)
+                      window=window, select=select)
         if cfg.attn_gate:
             o = attn_gated(o, h, lp)
         o = o.reshape(B, T, nh * hd)
@@ -1270,18 +1332,20 @@ def latent_wkvb(lp: Dict[str, jax.Array], cfg: LlamaConfig, heads: int,
     return w[..., :ls.qk_nope_head_dim], w[..., ls.qk_nope_head_dim:]
 
 
-def index_qkw(h: jax.Array, cq: jax.Array, lp: Dict[str, jax.Array],
-              cfg: LlamaConfig, ls: LatentSpec, cos: jax.Array,
-              sin: jax.Array):
-    """The sparse index's three parts from a latent layer's normed input h
-    [B, T, d] and its query latent cq (`latent_cq`): index queries qI
-    [B, T, IH, ID] = cq Wiq, ONE index key a position kI [B, T, ID] =
-    LayerNorm(h Wik) (weight and bias), both with the layer's rope on
-    their leading `qk_rope_head_dim` values, and the heads' weights w
-    [B, T, IH] float32 = (h Wiw) * IH ** -0.5 * ID ** -0.5."""
-    ix = ls.index
+def index_qkw(h: jax.Array, cq: Optional[jax.Array],
+              lp: Dict[str, jax.Array], cfg: LlamaConfig, ix: IndexSpec,
+              cos: jax.Array, sin: jax.Array):
+    """The sparse index's three parts from a layer's normed input h
+    [B, T, d] and, of a latent layer, its query latent cq (`latent_cq`;
+    None where the layer has none): index queries qI [B, T, IH, ID] = (cq | h)
+    Wiq, ONE index key a position kI [B, T, ID] = LayerNorm(h Wik) (weight
+    and bias), both under rope by the table handed in (cos, sin [T, n]:
+    the leading 2n values of each are turned, `LlamaConfig
+    .index_rope_width`), and the heads' weights w [B, T, IH] float32 =
+    (h Wiw) * IH ** -0.5 * ID ** -0.5."""
+    src = h if cq is None else cq
     with jax.named_scope("index_q"):
-        qi = apply_rope((cq @ lp["wiq"].astype(h.dtype)).reshape(
+        qi = apply_rope((src @ lp["wiq"].astype(h.dtype)).reshape(
             *h.shape[:2], ix.heads, ix.head_dim), cos, sin)
         w = ((h @ lp["wiw"].astype(h.dtype)).astype(jnp.float32)
              * (ix.heads ** -0.5 * ix.head_dim ** -0.5))
@@ -1296,6 +1360,19 @@ def index_qkw(h: jax.Array, cq: jax.Array, lp: Dict[str, jax.Array],
     return qi, k, w
 
 
+def index_select(h: jax.Array, cq: Optional[jax.Array],
+                 lp: Dict[str, jax.Array], cfg: LlamaConfig, ix: IndexSpec,
+                 cos: jax.Array, sin: jax.Array,
+                 visible: jax.Array) -> jax.Array:
+    """Whole sequences' selections [B, T, T] bool: of the keys `visible`
+    shows each row, the `ix.topk` of largest index score (`index_qkw`,
+    `index_scores`, `select_topk`): what the model's own forward attends
+    over, under either kind of cache."""
+    qi, ki, w = index_qkw(h, cq, lp, cfg, ix, cos, sin)
+    return jax.vmap(lambda a, b, c, m: select_topk(
+        index_scores(a, b, c), m, ix.topk))(qi, ki, w, visible)
+
+
 def latent_self_attention(h: jax.Array, lp: Dict[str, jax.Array],
                           cfg: LlamaConfig, heads: int, cos: jax.Array,
                           sin: jax.Array,
@@ -1305,7 +1382,7 @@ def latent_self_attention(h: jax.Array, lp: Dict[str, jax.Array],
     head's (k_nope | v), k_h = (k_nope_h | k_rope), scores times the
     spec's `score_scale`, softmax in float32; under `ls.window` over the
     last W keys, under `ls.index` over the selected keys alone
-    (`index_qkw`, `index_scores`, `select_topk`); with `cfg.attn_gate` a
+    (`index_select`); with `cfg.attn_gate` a
     head's output times its gate; the heads' outputs through Wo. The paged
     engine computes the same numbers over its latent pages, in the
     absorbed form. `ls`: the layer's widths (None: the plan's one kind)."""
@@ -1329,9 +1406,7 @@ def latent_self_attention(h: jax.Array, lp: Dict[str, jax.Array],
     if ls.window:
         mask = mask & (pos[None, :] > pos[:, None] - ls.window)
     if ls.index is not None:
-        qi, ki, w = index_qkw(h, cq, lp, cfg, ls, cos, sin)
-        mask = jax.vmap(lambda a, b, c, m: select_topk(
-            index_scores(a, b, c), m, ls.index.topk))(qi, ki, w, mask)
+        mask = index_select(h, cq, lp, cfg, ls.index, cos, sin, mask)
     s = jnp.where(mask[:, None], s, -1e30)
     o = jnp.einsum("bhts,bshv->bthv", jax.nn.softmax(s, axis=-1
                                                      ).astype(h.dtype), v)
@@ -1428,16 +1503,24 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
         ropes = {spec.rope: rope_table(jnp.arange(T), cfg.rope_width(spec),
                                        spec.rope) for spec in kinds}
 
+        iropes = {spec: rope_table(jnp.arange(T), cfg.index_rope_width(spec),
+                                   spec.rope)
+                  for spec in kinds if spec.index is not None}
+
         def plan_body(kind, carry, lp):
             return block(carry, lp, cfg, *ropes[kinds[kind].rope],
-                         attn_impl, ffn_impl, spec=kinds[kind])
+                         attn_impl, ffn_impl, spec=kinds[kind],
+                         index_rope=iropes.get(kinds[kind]))
 
         x = scan_plan(cfg, plan_body, x, params["blocks"])
     else:
         cos, sin = rope_cos_sin(jnp.arange(T), cfg.head_dim, cfg.rope_theta)
+        irope = cfg.index and rope_cos_sin(jnp.arange(T), cfg.index.head_dim,
+                                           cfg.rope_theta)
 
         def body(carry, lp):
-            return block(carry, lp, cfg, cos, sin, attn_impl, ffn_impl), None
+            return block(carry, lp, cfg, cos, sin, attn_impl, ffn_impl,
+                         index_rope=irope), None
 
         x, _ = lax.scan(body, x, params["blocks"])
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)

@@ -538,7 +538,7 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
                           use_neox_style=False, quant_max_bound=127.0,
                           quant_min_bound=-127.0, use_pallas=False,
                           block_length=0, window=0, rotary_dim=0,
-                          kind=None):
+                          kind=None, select=None):
     """One layer of `block_multihead_attention_` on the stacked page pool
     [L, num_blocks, KV, block_size, hd]: split and rotate `qkv`, write the
     new tokens' rows into `layer`'s pages where they lie, then attend over
@@ -559,8 +559,38 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
     the leading `rotary_dim` values of each head are rotated (rotate-half
     inside them). `kind` names the layer's kind in a layer plan: the read
     then runs under scope `paged_attention_<kind>` inside
-    `paged_attention`. Returns (fmha_out, qkv_out, key_pool,
-    value_pool)."""
+    `paged_attention`.
+
+    WHICH READ A ROW TAKES UNDER A SPARSE INDEX (`select` = (positions
+    [tok, k], their pages [tok, k], sparse [B], the selection's bits [tok,
+    max_kv / 128, 4]) from `paged_index_select`; the one rule, here and
+    nowhere else, as `paged_latent_attention` states its own). The rows of
+    a sequence that holds at most k keys after this tick have no selection
+    to make and take the DENSE walks as without an index (scope
+    `paged_attention`). The rows of a sequence that holds more attend over
+    their k selected keys alone (a row that sees at most k has them all
+    selected), softmax over exactly those, under scope
+    `paged_attention_sparse`, each form launched under its own `lax.cond`
+    on the tick's own lengths. A selecting CHUNK's rows (more than one row
+    of a sequence in a tick) always take the MASKED WALK:
+    `paged_attention`'s mixed walk with the selection's bits ANDed into
+    what a row sees: every page of the context read once for a tile of
+    rows, nothing moved twice, at context / k times the selected pairs'
+    FLOPs. A selecting sequence's ONE row of a tick (a decode row, in a
+    decode tick or beside a chunk) reads in the decode launch, over the
+    sequences' first rows, in one of two forms:
+    * the masked decode walk, bound by the bytes of the pages it copies;
+    * the GATHER: each selected position's `KV x hd` keys and values
+      fetched out of both pools by page and slot, and a softmax over
+      exactly them.
+    Which of the two is a formula of shapes and measured chip constants,
+    nobody's setting (`sparse_walk_keys_heads`, where the readings stand):
+    a one-row sequence walks while it holds at most that many keys. The stock
+    read (`use_pallas` False, CPU tests) is the dense float32 read with the
+    selection ANDed into its mask, so that a selection of every key IS the
+    dense read. Int8 pages, a window and a block-causal mask were never
+    judged under a selection and raise. Returns (fmha_out, qkv_out,
+    key_pool, value_pool)."""
     from ..pallas import paged_attention as PA
     _, num_blocks, KV, bs, hd = key_pool.shape
     B, max_blocks = block_tables.shape
@@ -577,6 +607,10 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
         raise ValueError(
             f"block_length={block_length}: the rows of a block go through "
             "the mixed launch (use_pallas=True), not the decode launch")
+    if select is not None and (kv_quant or window or block_length):
+        raise NotImplementedError(
+            "a sparse index's selection over int8 pages, under a window or "
+            "a block-causal mask was never judged against a reference")
 
     # named scopes (jax.named_scope): the device operations of this op
     # belong to `qkv` (split, bias, rope, token indices), `cache_write`
@@ -670,21 +704,88 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
             # freshly written pool goes in whole, with the layer index; int8
             # pages ride with their scale planes.
             sm_scale = float(1.0 / np.sqrt(hd))
-            if use_pallas == "decode":
-                # one token a sequence: rows [B, KV, G, hd], row b = token
-                # cu[b] (an idle slot's is masked by its length)
+
+            def walk_rows(this_w, mask=None):
+                """The decode walk over the sequences whose `this_w` is not
+                0: one token a sequence, rows [B, KV, G, hd], row b = token
+                cu[b] (an idle slot's is masked by its length); under the
+                selection `mask` where there is one."""
                 row_tok = jnp.clip(cu[:B], 0, token_num - 1)
                 o = PA.paged_attention(
                     q_g[row_tok], key_pool, value_pool, block_tables, past,
-                    this, G, sm_scale, k_dequant=k_dequant,
-                    v_dequant=v_dequant, layer=layer, window=window)[tok_b]
-                o = jnp.where(tok_valid[:, None, None, None], o, 0)
+                    this_w, G, sm_scale, k_dequant=k_dequant,
+                    v_dequant=v_dequant, layer=layer, window=window,
+                    mask=None if mask is None else mask[row_tok])[tok_b]
+                return jnp.where(tok_valid[:, None, None, None], o, 0)
+
+            def walk_packed(this_w, mask=None):
+                """The mixed walk: ragged chunks, the packed stream goes in
+                as it is."""
+                return PA.paged_attention_packed(
+                    q_g, key_pool, value_pool, block_tables, past, this_w,
+                    cu, sm_scale, k_dequant=k_dequant, v_dequant=v_dequant,
+                    layer=layer, block_len=block_length, window=window,
+                    mask=mask)
+
+            walk = walk_rows if use_pallas == "decode" else walk_packed
+            if select is None:
+                o = walk(this)
             else:
-                # ragged chunks: the packed stream goes in as it is
-                o = PA.paged_attention_packed(
-                    q_g, key_pool, value_pool, block_tables, past, this, cu,
-                    sm_scale, k_dequant=k_dequant, v_dequant=v_dequant,
-                    layer=layer, block_len=block_length, window=window)
+                idx, page, sparse, bits = select
+                o = lax.cond(
+                    jnp.any((this > 0) & ~sparse),
+                    lambda: walk(jnp.where(sparse, 0, this)),
+                    lambda: jnp.zeros_like(q_g))
+
+                def read(o, seqs, launch):
+                    """`o` with the rows of the sequences `seqs` [B] taken
+                    from `launch()`, which runs only in a tick with one."""
+                    mine = (seqs[tok_b] & tok_valid)[:, None, None, None]
+                    return lax.cond(jnp.any(seqs),
+                                    lambda: jnp.where(mine, launch(), o),
+                                    lambda: o)
+
+                def gathered_rows(q, idx, page):
+                    """Rows q [n, KV, G, hd] over the keys and values at
+                    positions idx [n, k] (-1: none) of pages `page`."""
+                    # a head's row of a position, hd values, by its place
+                    # among the pool's rows (a gather of [KV, 1, hd] slabs
+                    # makes XLA lay the whole pool out slot-major first)
+                    at = ((((layer * num_blocks + jnp.maximum(page, 0)) * KV
+                            )[..., None] + jnp.arange(KV, dtype=jnp.int32))
+                          * bs + (jnp.maximum(idx, 0) % bs)[..., None])
+                    k_sel, v_sel = (
+                        jnp.take(pool.reshape(-1, hd), at, axis=0,
+                                 mode="clip")
+                        for pool in (key_pool, value_pool))   # [n, k, KV, hd]
+                    s = jnp.einsum("nvgd,nkvd->nvgk", q, k_sel,
+                                   preferred_element_type=jnp.float32
+                                   ) * sm_scale
+                    s = jnp.where(((idx >= 0) & (page >= 0))[:, None, None],
+                                  s, -1e30)
+                    p = jax.nn.softmax(s, axis=-1).astype(v_sel.dtype)
+                    return jnp.einsum("nvgk,nkvd->nvgd", p, v_sel,
+                                      preferred_element_type=jnp.float32
+                                      ).astype(q.dtype)
+
+                # a selecting chunk's rows walk under the mask in the mixed
+                # launch; a selecting sequence's ONE row of a tick (a decode
+                # row, in a decode tick or beside a chunk) reads in the
+                # decode launch, over the sequences' first rows: the masked
+                # walk up to the crossing, the gather beyond it
+                one = this == 1
+                first = jnp.clip(cu[:B], 0, token_num - 1)
+                walks = sparse & one & (
+                    past + this <= sparse_walk_keys_heads(
+                        KV, hd, key_pool.dtype.itemsize, idx.shape[1]))
+                with jax.named_scope("paged_attention_sparse"):
+                    o = read(o, walks, lambda: walk_rows(
+                        walks.astype(this.dtype), bits))
+                    o = read(o, sparse & one & ~walks, lambda: gathered_rows(
+                        q_g[first], idx[first], page[first])[tok_b])
+                    if use_pallas != "decode":
+                        o = read(o, sparse & ~one, lambda: walk_packed(
+                            jnp.where(sparse & ~one, this, 0), bits))
             fmha_out = o.astype(qkv.dtype).reshape(token_num, H * hd)
             return fmha_out, qkv3.reshape(token_num, -1), key_pool, value_pool
 
@@ -731,6 +832,12 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
         ok = (kv_pos <= see[:, None]) & page_valid[tok_b]        # [tok, max_kv]
         if window:
             ok &= kv_pos > (tok_pos - window)[:, None]
+        if select is not None:
+            # the rows of a selecting sequence: their selected keys alone
+            from . import sparse_index
+            with jax.named_scope("paged_attention_sparse"):
+                chosen = sparse_index.unpack_mask(select[3])[:, :max_kv] != 0
+                ok &= chosen | ~select[2][tok_b][:, None]
         s = jnp.where(ok[:, None, None, :], s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
         if kv_quant:
@@ -807,6 +914,41 @@ def sparse_walk_keys(heads: int, width: int, value_dim: int,
                    / (heads * 2 * (width + value_dim))), 2 ** 31 - 1)
 
 
+# The two sparse reads of a layer of heads' own keys and values as chip
+# constants (TPU v5e, PR 50's chip readings at Keye-VL-2.0's widths: 32
+# query heads over 4 key-value heads of 128, bf16 pages of 16, topk 2,048;
+# PERF.md section 6 has the readings).
+# The masked decode walk of one-row sequences is bound by the bytes of the
+# pages it copies, keys x 2 x KV x hd x itemsize: 16 sequences of 33.8k-
+# 64.5k keys (786,064 in all, 1.61 GB) in 3.94 ms at key blocks of 1,024
+# (4.50 at 512, 6.07 at 256, 6.17 at 128; the unmasked walk at its 128:
+# 6.14):
+_HEADS_WALK_BYTES_S = 4.08e11
+# The gather with the attention over what it fetched, a selected position
+# (its KV heads' keys and values: 2 KV rows of hd, 12 ns a row of 256 B):
+# 3.23 ms for the same 16 rows x 2,048 positions whatever their contexts.
+# (A CHUNK's rows always walk: a turn's 143 rows took 49.3 ms through the
+# gather against 4 for the masked mixed walk at 50k keys, and a chunk of
+# 2,032 rows ending at 32,032 keys walks in 19.9 ms, by its products at
+# 53 TFLOP/s: its crossing would lie near 650k keys, past every context
+# the model's 262,144 positions allow.)
+_HEADS_GATHER_POS_S = 9.85e-8
+
+
+def sparse_walk_keys_heads(kv_heads: int, head_dim: int, itemsize: int,
+                           topk: int) -> int:
+    """The CROSSING of a layer of heads' own keys and values: the most
+    keys a selecting sequence may hold after a tick for the masked decode
+    walk to be the cheaper read of its ONE row. The row walks alone, so
+    its walk is the bytes of its pages at `_HEADS_WALK_BYTES_S`; the
+    gather costs it `topk` positions at `_HEADS_GATHER_POS_S`. From shapes
+    and the two constants alone: the device's rule
+    (`paged_layer_attention`) and the host's count of it
+    (`PagedServingEngine._plan_keys`) both ask here."""
+    per_key_s = 2 * kv_heads * head_dim * itemsize / _HEADS_WALK_BYTES_S
+    return min(int(topk * _HEADS_GATHER_POS_S / per_key_s), 2 ** 31 - 1)
+
+
 def _by_row_blocks(fn, args, rows: int):
     """`fn` over blocks of `_SPARSE_ROWS` leading rows of `args` (a tuple
     of arrays with `rows` leading rows), concatenated: what a block holds
@@ -823,9 +965,12 @@ def _by_row_blocks(fn, args, rows: int):
 def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
                        seq_lens_this_time, cu_seqlens_q, block_tables,
                        topk: int, use_pallas=False):
-    """A latent layer's sparse index on the stacked INDEX-KEY pool
-    [L, num_blocks, 1, block_size, ID] (the second row a position of the
-    full layers' pages, under their block table): write the new tokens'
+    """A layer's sparse index (over a latent cache or over heads' own
+    keys and values) on the stacked INDEX-KEY pool [L, num_blocks, 1,
+    block_size, IW] (one more row a position of the full layers' pages,
+    under their block table; IW is the index head's width ID in whole
+    lanes, the engine pads a 64-wide key to 128 with zeros, which add
+    nothing to a score): write the new tokens'
     index keys `ki_tok` [tok, ID] into `layer`'s pages, then, for the rows
     of every sequence that holds more than `topk` keys after this tick,
     score every key the row sees (qi [tok, IH, ID] index queries, w [tok,
@@ -861,6 +1006,8 @@ def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
     L_, num_blocks, _, bs, ID = pool.shape
     B, max_blocks = block_tables.shape
     token_num = qi.shape[0]
+    lanes = ((0, 0),) * (qi.ndim - 1) + ((0, ID - qi.shape[-1]),)
+    qi, ki_tok = jnp.pad(qi, lanes), jnp.pad(ki_tok, lanes[1:])
     max_kv = max_blocks * bs
     cu = cu_seqlens_q.astype(jnp.int32).reshape(-1)
     past = seq_lens_decoder.reshape(-1).astype(jnp.int32)

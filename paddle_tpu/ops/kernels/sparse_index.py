@@ -1,5 +1,6 @@
-"""The learned sparse index of a latent-attention layer (DeepSeek-V3.2's
-lightning indexer, `models.llama.IndexSpec`), the parts that are plain XLA:
+"""The learned sparse index of a layer (DeepSeek-V3.2's lightning indexer,
+`models.llama.IndexSpec`: over a latent cache, dots3-note's, or over the
+heads' own keys and values, Keye-VL-2.0's), the parts that are plain XLA:
 the scores of a row's index heads against index keys, the EXACT selection
 of a row's k best visible keys, and that selection as a list of positions.
 

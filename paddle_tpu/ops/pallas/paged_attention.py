@@ -94,6 +94,23 @@ never reads a table entry behind that block, so a caller may have given
 the pages behind the window back (entries of -1). With 0 the kernels
 are what they were.
 
+Both whole-page walks take a static `mask` (None = none): a SELECTION over
+the table's key positions a query token, handed in as bits
+(`sparse_index.pack_mask`: the selection a learned sparse index made,
+`serving_attention.paged_index_select`), widened to a byte a key for the
+launch's rows alone and cut into the walk's key blocks (the decode walk:
+`[B, key blocks, 1, span]`; the mixed walk: `[items, key blocks, TQ, span]`,
+an item's whole mask one VMEM block). Inside a key block the selection's
+bytes are ANDed into what a row sees, a token's row broadcast over its GQA
+group's sublanes (`_spread_over_heads`); a row may then see no key of a
+block, so the probabilities are zeroed as under a window. This is the MASKED
+WALK of a layer of heads' own keys and values under a sparse index: every
+page of the context is read once for a tile of rows and the softmax runs
+over exactly the selected keys. Which rows take it and which gather their
+selected keys instead is `serving_attention.paged_layer_attention`'s rule.
+Without a mask nothing of it is traced (no operand, no scratch): the
+kernels are what they were. The BlockSpec walk has no mask.
+
 The BlockSpec walk (`_kernel`; rows `[B, KV, max_q * G, hd]` packed per
 sequence, max_q = 1 for a decode launch): grid `(B, KV, table width)`
 with the page axis innermost, one `[block_size, hd]` page of one KV head
@@ -130,6 +147,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..kernels.sparse_index import unpack_mask
 from .flash_attention import (NEG_INF, _assert_mosaic_tileable, _i32,
                               available, count_launch)
 
@@ -319,6 +337,23 @@ def _pages_per_block(keys: int, block_size: int, num_kv_heads: int,
                       _PAGE_SCRATCH_BYTES // (4 * page_bytes), max_blocks))
 
 
+# the masked decode walk's key block: a selecting sequence holds thousands
+# of keys, so its blocks are as large as the page scratch allows (64 pages
+# of Keye-VL-2.0's 16 KB), not one MXU tile: on the v5e 16 sequences of
+# 34k-65k keys took 6.17 ms at 128 keys a block, 6.07 at 256, 4.50 at 512
+# and 3.94 at 1,024 (PERF.md section 6, PR 50)
+_MASKED_DECODE_KEYS = 1024
+
+
+def masked_decode_pages_per_block(block_size: int, num_kv_heads: int,
+                                  head_dim: int, itemsize: int,
+                                  max_blocks: int) -> int:
+    """Pages of a key block of the masked decode walk (`_decode_call` with
+    a mask): the decode walk's rule at `_MASKED_DECODE_KEYS` positions."""
+    return _pages_per_block(_MASKED_DECODE_KEYS, block_size, num_kv_heads,
+                            head_dim, itemsize, max_blocks)
+
+
 def decode_pages_walked(ends, block_size: int, num_kv_heads: int,
                         head_dim: int, itemsize: int, max_blocks: int,
                         window: int = 0):
@@ -402,9 +437,30 @@ def _page_scales(scale_ref, slot, kv, pages: int, block_size: int):
     return vec
 
 
+def _spread_over_heads(mask, tokens: int, heads: int):
+    """A tile's selection `mask` [tq, span] int8 (a row a token) as the
+    keys each query row may see, [tokens * heads, span] bool, row r =
+    t * heads + h: token t's row broadcast over its heads' sublanes (a
+    latent walk's H heads, a GQA walk's group)."""
+    mask = mask.astype(jnp.int32)
+    rows = [jnp.broadcast_to(mask[t:t + 1], (heads, mask.shape[1]))
+            for t in range(tokens)]
+    return (rows[0] if tokens == 1 else jnp.concatenate(rows, axis=0)) != 0
+
+
+def _mask_blocks(rows_mask, blocks: int, span: int):
+    """A selection's bits for some token rows [..., nb, 4] uint32
+    (`sparse_index.pack_mask`) as a byte a key cut into a walk's key
+    blocks: [..., blocks, span] int8."""
+    m = unpack_mask(rows_mask)                                  # [..., S]
+    pad = [(0, 0)] * (m.ndim - 1) + [(0, max(blocks * span - m.shape[-1], 0))]
+    return jnp.pad(m, pad)[..., :blocks * span].reshape(
+        *m.shape[:-1], blocks, span)
+
+
 def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
                    sm_scale: float, block_size: int, pages: int,
-                   has_quant: bool, window: int = 0):
+                   has_quant: bool, window: int = 0, masked: bool = False):
     """One sequence b of a decode launch (one query token, rows = the GQA
     group): walk its live key blocks of `pages` whole pages each.
 
@@ -414,7 +470,12 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
     [2, 2], acc [KV, G, hd], m, l [KV, G, LANES]. A page comes in one copy
     that serves every KV head, an int8 page's scale row in one more beside
     it; block i+1's copies start before block i's products. The online
-    softmax runs per head over the block's pages * bs keys."""
+    softmax runs per head over the block's pages * bs keys. `masked`
+    (static): the MASKED WALK; behind q comes the sequence's selection
+    [1, key blocks, 1, span] int8, and the row sees of a key block only the
+    keys it selected."""
+    if masked:
+        mask_ref, refs = refs[1], refs[:1] + refs[2:]
     q_ref, pools, o_ref, bufs, sems, acc, m_sc, l_sc = _walk_refs(
         refs, has_quant)
     kbuf, vbuf = bufs[:2]
@@ -467,6 +528,8 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
         ok = kv_abs <= past                           # causal = live keys
         if window:
             ok &= kv_abs >= _see_from(past, window)
+        if masked:
+            ok &= mask_ref[0, i].astype(jnp.int32) != 0
         for kv in range(KV):
             q = q_ref[0, kv].astype(jnp.float32)      # [G, hd]
             k = kbuf[slot, :, kv].astype(jnp.float32).reshape(span, hd)
@@ -529,17 +592,28 @@ def _walk_operands(key_cache, value_cache, tables, k_dequant, v_dequant,
 
 
 def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
-                 sm_scale, k_dequant, v_dequant, interpret, window: int = 0):
+                 sm_scale, k_dequant, v_dequant, interpret, window: int = 0,
+                 mask=None):
     """The decode launch (`rows == group`): grid over sequences, pools
-    left in HBM, whole pages gathered by the kernel."""
+    left in HBM, whole pages gathered by the kernel. `mask` [B, nb, 4]
+    uint32 (or None: static): the masked walk."""
     B, KV, G, hd = q_rows.shape
     _, _, _, bs, _ = key_cache.shape
     has_quant = k_dequant is not None
     max_blocks = tables.shape[1]
-    pages = decode_pages_per_block(bs, KV, hd, key_cache.dtype.itemsize,
-                                   max_blocks)
+    pages = (decode_pages_per_block if mask is None
+             else masked_decode_pages_per_block)(
+        bs, KV, hd, key_cache.dtype.itemsize, max_blocks)
     tables, pools, page_scratch = _walk_operands(
         key_cache, value_cache, tables, k_dequant, v_dequant, pages)
+    operands, mask_specs = [], []
+    if mask is not None:
+        span, blocks = pages * bs, tables.shape[1] // pages
+        operands = [_mask_blocks(mask, blocks, span)[:, :, None]]
+        mask_specs = [pl.BlockSpec(
+            (1, blocks, 1, span),
+            lambda b, *_: (b, _i32(0), _i32(0), _i32(0)),
+            memory_space=pltpu.VMEM)]
 
     row_spec = pl.BlockSpec((1, KV, G, hd),
                             lambda b, *_: (b, _i32(0), _i32(0), _i32(0)),
@@ -549,7 +623,7 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
-        in_specs=[row_spec] + [hbm] * len(pools),
+        in_specs=[row_spec] + mask_specs + [hbm] * len(pools),
         out_specs=row_spec,
         scratch_shapes=[
             *page_scratch,
@@ -560,15 +634,18 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
     )
     kernel = functools.partial(
         _decode_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
-        pages=int(pages), has_quant=has_quant, window=int(window))
+        pages=int(pages), has_quant=has_quant, window=int(window),
+        masked=mask is not None)
     count_launch()
-    return pl.pallas_call(
-        kernel,
-        name="paged_attention_decode",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q_rows.shape, q_rows.dtype),
-        interpret=interpret,
-    )(tables, past, this, layer, q_rows, *pools)
+    call = dict(grid_spec=grid_spec, interpret=interpret,
+                out_shape=jax.ShapeDtypeStruct(q_rows.shape, q_rows.dtype))
+    # (a site a name, each a literal: what a trace calls the launch)
+    if mask is None:
+        launch = pl.pallas_call(kernel, name="paged_attention_decode", **call)
+    else:
+        launch = pl.pallas_call(kernel, name="paged_attention_decode_masked",
+                                **call)
+    return launch(tables, past, this, layer, q_rows, *operands, *pools)
 
 
 # the mixed walk's work item: the row tile of this many tokens of one
@@ -694,7 +771,7 @@ def _loop_i32(n: int, body) -> None:
 def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
                   *refs, sm_scale: float, block_size: int, pages: int,
                   group: int, small: int, has_quant: bool,
-                  block_len: int = 0, window: int = 0):
+                  block_len: int = 0, window: int = 0, masked: bool = False):
     """One work item j of a mixed launch: the query rows of sequence
     seq[j] from chunk offset t0[j] on (row r = t * G + g of the tile, its
     query at position past + t0 + t), against that sequence's key blocks
@@ -706,7 +783,11 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
     decode walk's do (`_page_copies`); q.k runs on the operands' own
     type with f32 accumulation, p.v in f32. An item with at most `small`
     live tokens (a decode row or a verify run beside a chunk) computes on
-    the tile's first small * G rows only."""
+    the tile's first small * G rows only. `masked` (static): the MASKED
+    WALK; behind q comes the item's selection [1, key blocks, TQ, span] int8,
+    and a row sees of a key block only the keys its token selected."""
+    if masked:
+        mask_ref, refs = refs[1], refs[:1] + refs[2:]
     q_ref, pools, o_ref, bufs, sems, acc, m_sc, l_sc = _walk_refs(
         refs, has_quant)
     kbuf, vbuf = bufs[:2]
@@ -801,6 +882,9 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
             ok = kv_abs <= pos                        # [rows, span]
             if window:
                 ok &= kv_abs >= _see_from(pos, window)
+            if masked:
+                ok &= _spread_over_heads(mask_ref[0, i], rows // group,
+                                         group)
 
             def head(kv):
                 s = jax.lax.dot_general(
@@ -823,10 +907,11 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
                 # m) = 0 exactly; a row with none yet (only rows without
                 # a query: key 0 is in every first block) is zeroed below
                 prob = jnp.exp(s - m_new)                     # [rows, span]
-                if window:
+                if window or masked:
                     # a row may see no key of the tile's first blocks (they
-                    # hold its earlier rows' windows): there m_new is still
-                    # -1e30 and exp(0) = 1 would count every masked key
+                    # hold its earlier rows' windows, or no key it
+                    # selected): there m_new is still -1e30 and exp(0) = 1
+                    # would count every masked key
                     prob = jnp.where(ok, prob, 0.0)
                 alpha = jnp.exp(m_prev - m_new)               # [rows, 1]
                 l_sc[kv, :rows] = (l_sc[kv, :rows] * alpha
@@ -864,9 +949,11 @@ def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,
 
 def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
                 seq, t0, group, small, sm_scale, k_dequant, v_dequant,
-                interpret, block_len: int = 0, window: int = 0):
+                interpret, block_len: int = 0, window: int = 0,
+                mask=None):
     """The mixed launch: grid over work items, pools left in HBM, whole
-    pages gathered by the kernel."""
+    pages gathered by the kernel. `mask` [items, TQ, nb, 4] uint32 (or
+    None: static): the masked walk."""
     items, KV, R, hd = q_items.shape
     _, _, _, bs, _ = key_cache.shape
     has_quant = k_dequant is not None
@@ -879,10 +966,21 @@ def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
                             memory_space=pltpu.VMEM)
     _assert_mosaic_tileable(row_spec.block_shape, q_items.shape, "mixed rows")
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands, mask_specs = [], []
+    if mask is not None:
+        # an item's tokens' selections cut into the walk's key blocks, an
+        # item's whole in VMEM (4 MB at 64 tokens x 65,536 keys), block i
+        # of it read by number
+        span, blocks = pages * bs, tables.shape[1] // pages
+        operands = [_mask_blocks(mask, blocks, span).transpose(0, 2, 1, 3)]
+        mask_specs = [pl.BlockSpec(
+            (1, blocks, mask.shape[1], span),
+            lambda j, *_: (j, _i32(0), _i32(0), _i32(0)),
+            memory_space=pltpu.VMEM)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(items,),
-        in_specs=[row_spec] + [hbm] * len(pools),
+        in_specs=[row_spec] + mask_specs + [hbm] * len(pools),
         out_specs=row_spec,
         scratch_shapes=[
             *page_scratch,
@@ -894,24 +992,29 @@ def _mixed_call(q_items, key_cache, value_cache, tables, past, this, layer,
     kernel = functools.partial(
         _mixed_kernel, sm_scale=np.float32(sm_scale), block_size=int(bs),
         pages=int(pages), group=int(group), small=int(small),
-        has_quant=has_quant, block_len=int(block_len), window=int(window))
+        has_quant=has_quant, block_len=int(block_len), window=int(window),
+        masked=mask is not None)
     count_launch()
-    return pl.pallas_call(
-        kernel,
-        name="paged_attention_mixed",
+    call = dict(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_items.shape, q_items.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_MIXED_VMEM_LIMIT),
-        interpret=interpret,
-    )(tables, past, this, layer, seq, t0, q_items, *pools)
+        interpret=interpret)
+    if mask is None:
+        launch = pl.pallas_call(kernel, name="paged_attention_mixed", **call)
+    else:
+        launch = pl.pallas_call(kernel, name="paged_attention_mixed_masked",
+                                **call)
+    return launch(tables, past, this, layer, seq, t0, q_items, *operands,
+                  *pools)
 
 
 def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
                            seq_lens_decoder, seq_lens_this_time, cu_seqlens_q,
                            sm_scale: float, k_dequant=None, v_dequant=None,
                            interpret: Optional[bool] = None, layer=None,
-                           block_len: int = 0, window: int = 0):
+                           block_len: int = 0, window: int = 0, mask=None):
     """Attention of a ragged mixed batch (prefill chunks, decode rows and
     idle slots in one launch) over paged caches, on the packed token
     stream itself.
@@ -928,6 +1031,11 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
     of one block see one another whichever tile they fall in. `window`
     (static; 0 = none) keeps of those the last `window` keys, the query's
     own among them; the pages behind every window need not be in the table.
+    `mask` (a selection over the table's key positions as bits,
+    [token_num, blocks of 128 keys, 4] uint32 as `sparse_index.pack_mask`
+    lays them; or None: static) makes it the MASKED WALK: row t sees of the
+    keys above only those whose bit is set, and one with none set comes
+    back 0; the whole-page walk alone has it.
 
     Where whole pages can be copied (`whole_pages`) this is the mixed
     walk: the launch runs over work items reckoned here from the lengths
@@ -951,6 +1059,7 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
     tok_valid = (tok_local < this[tok_b])[:, None, None, None]
 
     if not whole_pages(hd, interpret):
+        _refuse_mask(mask, hd)
         row_tok = jnp.clip(cu[:B, None] + tok_idx[None, :], 0, token_num - 1)
         q_pack = q_tok[row_tok].transpose(0, 2, 1, 3, 4)  # [B, KV, tok, G, hd]
         o_pack = paged_attention(
@@ -974,7 +1083,7 @@ def paged_attention_packed(q_tok, key_cache, value_cache, block_tables,
         q_items.reshape(items, KV, tq * G, hd), key_cache, value_cache,
         jnp.maximum(block_tables.astype(jnp.int32), 0), past, this, layer,
         seq, t0, G, ts, sm_scale, k_dequant, v_dequant, interpret,
-        block_len, window)
+        block_len, window, None if mask is None else mask[row_tok])
     o_items = o_items.reshape(items, KV, tq, G, hd)
     item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
     return jnp.where(tok_valid, o_items[item, :, tok_local % tq], 0
@@ -985,7 +1094,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
                     seq_lens_decoder, seq_lens_this_time, group: int,
                     sm_scale: float, k_dequant=None, v_dequant=None,
                     interpret: Optional[bool] = None, layer=None,
-                    block_len: int = 0, window: int = 0):
+                    block_len: int = 0, window: int = 0, mask=None):
     """Attention over paged caches, block table walked in-kernel.
 
     q_rows [B, KV, max_q * G, hd] — per-sequence packed rows (row
@@ -1010,7 +1119,9 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
     (`paged_attention_packed`), which the BlockSpec walk has and the
     decode walk has not: one row a sequence is no block. `window` > 0
     (static) keeps the last `window` keys of what a row sees, in every
-    walk.
+    walk. `mask` [B, blocks of 128 keys, 4] uint32 (or None: static): the
+    decode walk as the MASKED WALK, sequence b's one row seeing only the
+    keys whose bit is set (`paged_attention_packed` says the rest).
     """
     if (k_dequant is None) != (v_dequant is None):
         raise ValueError("pass both k_dequant and v_dequant or neither")
@@ -1040,8 +1151,9 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
                 "go through paged_attention_packed")
         return _decode_call(q_rows, key_cache, value_cache, tables, past,
                             this, layer, sm_scale, k_dequant, v_dequant,
-                            interpret, window)
+                            interpret, window, mask)
 
+    _refuse_mask(mask, hd)
     mem = {"memory_space": pltpu.VMEM}
     # the layer axis is squeezed: the body sees [1, 1, bs, hd] pages
     page_spec = pl.BlockSpec(
@@ -1100,6 +1212,14 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
         out_shape=jax.ShapeDtypeStruct((B, KV, rows, hd), q_rows.dtype),
         interpret=interpret,
     )(tables, past, this, layer, *inputs)
+
+
+def _refuse_mask(mask, head_dim: int) -> None:
+    if mask is not None:
+        raise NotImplementedError(
+            f"a selection mask at head_dim={head_dim}: the whole-page walks "
+            "alone have one, and Mosaic copies whole pages at head dims "
+            "that are whole lanes (`whole_pages`)")
 
 
 def _write_kernel(layer_ref, page_ref, lo_ref, hi_ref, k_new_ref, v_new_ref,

@@ -95,8 +95,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..kernels.sparse_index import unpack_mask
 from .flash_attention import NEG_INF, _i32, available, count_launch
-from .paged_attention import (_STAT_LANES, _loop_i32, _work_items,
-                              mixed_items)
+from .paged_attention import (_STAT_LANES, _loop_i32, _spread_over_heads,
+                              _work_items, mixed_items)
 
 __all__ = ["latent_attention", "latent_attention_packed",
            "index_scores_packed", "index_scores_rows", "index_keys_fetched",
@@ -284,16 +284,6 @@ def latent_attention(q_rows, pool, block_tables, seq_lens_decoder,
     )(tables, seq_lens_decoder.reshape(-1).astype(jnp.int32),
       seq_lens_this_time.reshape(-1).astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q_rows, pool)
-
-
-def _spread_over_heads(mask, tokens: int, heads: int):
-    """A tile's selection `mask` [tq, span] int8 (a row a token) as the
-    keys each query row may see, [tokens * heads, span] bool, row r =
-    t * H + h: token t's row broadcast over its heads' sublanes."""
-    mask = mask.astype(jnp.int32)
-    rows = [jnp.broadcast_to(mask[t:t + 1], (heads, mask.shape[1]))
-            for t in range(tokens)]
-    return (rows[0] if tokens == 1 else jnp.concatenate(rows, axis=0)) != 0
 
 
 def _mixed_kernel(tables_ref, past_ref, this_ref, layer_ref, seq_ref, t0_ref,

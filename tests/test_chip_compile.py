@@ -1264,3 +1264,91 @@ def test_static_mirror_agrees_with_mosaic():
     # legal: trailing dim equals array dim
     fa._assert_mosaic_tileable((1, 1, 256, fa.LANES), (2, 4, 512, fa.LANES),
                                "lse output")
+
+
+def test_keye_depth4_ticks_fit_the_chip(one_chip, monkeypatch):
+    """The cell `serve_sparse_gqa_sessions_longctx` as the engine builds it
+    on a TPU (`available` steered true), from the configuration file
+    itself: its three executables (a tick with a chunk, 2,048 rows; a tick
+    with a turn's new rows, 256; a decode tick, 16) compile for the
+    described v5e with the three page arrays (keys, values, index keys in
+    whole lanes) in their carry and 16 of 128 experts held, the masked
+    walks and the index's two launches at a 64-wide key in 128 lanes among
+    them, and the compiler counts each over 25 % and under the chip's
+    15.75 GiB; the page copy that carries the index keys compiles too."""
+    import json
+    import os
+
+    from benchmark.drivers import closed_loop_sparse_sessions as D
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import llama as L
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye-vl2-30b-a3b-serve.json")) as f:
+        file = json.load(f)
+    cfg, e = D.keye_config(file, jnp.bfloat16), file["engine"]
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    for module in (fa, pa, pl_):
+        monkeypatch.setattr(module, "available", lambda: True)
+    eng = PagedServingEngine(
+        cfg, params, num_blocks=e["num_blocks"], block_size=e["block_size"],
+        max_batch=e["max_batch"], token_budget=e["token_budget"],
+        max_len=e["max_len"], pallas=True, pallas_ffn=False)
+    shapes = jax.tree.map(lambda a: a.shape, (
+        eng._key_cache, eng._value_cache, eng._index_cache))
+    assert shapes == ((4, 67584, 4, 16, 128), (4, 67584, 4, 16, 128),
+                      (4, 67584, 1, 16, 128))
+    assert eng.kv_page_bytes == 4 * 16 * 2304 and eng._row_pads == (256, 2048)
+    build, gib = eng._build_step, {}
+
+    def compiled_not_run(tok_pad, B, *rest):
+        fn = build(tok_pad, B, *rest)
+
+        def tick(*args, **kw):
+            abstract = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip),
+                (args, kw))
+            compiled = fn.lower(*abstract[0], **abstract[1]).compile()
+            text = compiled.as_text()
+            # a one-row sequence reads in the decode launch's forms in
+            # every tick; a tick with new rows runs the chunk's index walk
+            # and the masked mixed walk beside them
+            for name in ("paged_attention_mixed_masked",
+                         "paged_index_scores_chunk"):
+                assert (name in text) == (tok_pad > 16), name
+            assert "paged_attention_decode_masked" in text
+            assert "paged_index_scores_decode" in text
+            # no layout change of a pool, no copy of one
+            assert "bf16[4,67584,4,16,128]{4,2,3,1,0" not in text
+            m = compiled.memory_analysis()
+            gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                            + m.temp_size_in_bytes
+                            - m.alias_size_in_bytes) / 2 ** 30
+            return (jnp.zeros((B + len(eng._moe_fields),), jnp.int32),
+                    args[1], args[2], kw["index_cache"])
+        return tick
+
+    monkeypatch.setattr(eng, "_build_step", compiled_not_run)
+    eng.submit(list(range(1, 2100)), max_new_tokens=3)
+    eng.step()                  # the prompt's first chunk, 2,048 rows
+    eng.step()                  # its last 51 rows: the 256-row executable
+    eng.step()                  # a decode row
+    assert set(gib) == {2048, 256, 16}
+    assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
+    print("keye depth-4 GiB by tok_pad:", gib)
+    # the copy-on-write page copy, its index keys with it (built, not run:
+    # here it would rewrite 10 GB of pools on the CPU)
+    eng._copy_blocks([])
+    pools = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (eng._key_cache, eng._value_cache, eng._index_cache))
+    idx = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    m = eng._copy_fn.lower(pools[0], pools[1], None, None, idx, idx,
+                           pools[2]).compile().memory_analysis()
+    copy_gib = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes - m.alias_size_in_bytes) / 2 ** 30
+    print("keye page copy GiB:", copy_gib)
+    assert copy_gib < 15.75 - 1.0       # beside the weights

@@ -649,7 +649,9 @@ def test_engine_equals_the_reference_through_both_pools(tiny, pallas):
     (full, window), (index, none) = eng._key_cache, eng._value_cache
     assert full.shape == (2, 64, 1, 8, PL.padded_width(48))
     assert window.shape == (3, 32, 1, 8, PL.padded_width(64))
-    assert index.shape == (2, 64, 1, 8, 16) and none is None
+    # (an index key's row in whole lanes, as a latent row's: PR 50)
+    assert index.shape == (2, 64, 1, 8, PL.padded_width(16))
+    assert none is None
     prompts = [prompt_of(70, seed=3), prompt_of(6, seed=4)]
     news = [20, 30]
     rids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]

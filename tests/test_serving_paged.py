@@ -186,6 +186,70 @@ class TestBlockManager:
         assert dst == bm.block_table(2)[0]       # not recycled into the
         #                                          new table
 
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_partial_match_looks_behind_one_hash_and_finds_the_same(
+            self, seed):
+        """A partial match reads the pages registered behind the chain's
+        last hash (`_children`), not every page the cache holds: through
+        a churn of admissions, frees and evictions it names the page a
+        scan of every cached page names, and the index holds exactly the
+        hashes `_hash_info` holds, each behind its own parent."""
+        def scan(bm, prev_h, rest):
+            best_blk, best_n = None, 1
+            for h, (ph, chunk) in bm._hash_info.items():
+                blk = bm._hash_to_block.get(h)
+                if ph != prev_h or blk is None or (
+                        blk not in bm._refs and blk not in bm._cached_free):
+                    continue
+                n = next((i for i, (a, b) in enumerate(zip(chunk, rest))
+                          if a != b), len(chunk))
+                if n > best_n:
+                    best_blk, best_n = blk, n
+            return (best_blk, best_n) if best_blk is not None else None
+
+        rs = np.random.RandomState(seed)
+        bm = BlockManager(num_blocks=24, block_size=4)
+        live, asked = [], 0
+        for rid in range(200):
+            # few ids and short prompts: prefixes repeat, pages share
+            # parents, and 24 pages are reclaimed over and over
+            toks = rs.randint(0, 3, rs.randint(5, 17)).tolist()
+            try:
+                bm.allocate_sequence(rid, toks)
+            except NoFreeBlocksError:
+                bm.free_sequence(live.pop(0))
+                continue
+            bm.take_copies()
+            bm.register_computed(rid, toks, len(toks))
+            live.append(rid)
+            if len(live) > 3:
+                bm.free_sequence(live.pop(rs.randint(len(live))))
+            for prev_h in list(bm._children)[:6]:
+                rest = rs.randint(0, 3, 4).tolist()
+                assert bm._partial_match(prev_h, rest) == scan(
+                    bm, prev_h, rest)
+                asked += 1
+            behind = {h: p for p, kids in bm._children.items()
+                      for h in kids}
+            assert behind == {h: info[0]
+                              for h, info in bm._hash_info.items()}
+            assert all(bm._children.values())    # no empty set is kept
+        assert asked > 500 and bm.stats["cache_evictions"] > 20
+        assert bm.stats["cow_copies"] > 20
+
+    def test_a_list_is_read_as_it_stands_and_an_array_as_its_ints(self):
+        """`allocate_sequence` converts what is not a list and reads a
+        list as it stands; numpy's integers in one hash and compare as
+        the ints they stand for, so all three find the same pages."""
+        toks = list(range(10, 22))
+        bm = BlockManager(num_blocks=16, block_size=4)
+        bm.allocate_sequence(1, toks + [5])
+        bm.register_computed(1, toks + [5], 12)
+        for rid, form in enumerate((toks + [7], np.asarray(toks + [7]),
+                                    [np.int32(t) for t in toks + [7]]), 2):
+            assert bm.allocate_sequence(rid, form) == 12
+            assert bm.block_table(rid)[:3] == bm.block_table(1)[:3]
+
     def test_exhaustion_raises_and_leaves_no_state(self):
         bm = BlockManager(num_blocks=2, block_size=4)
         bm.allocate_sequence(1, list(range(8)))
@@ -262,6 +326,24 @@ class TestPagedEngineParity:
         done = {c.rid: c.output_tokens for c in eng.run()}
         assert done[r2] == hostloop_ref(p2, 7)
         assert done[r1] == hostloop_ref(p1, 12)
+
+    @pytest.mark.parametrize("form", [
+        list, tuple, lambda p: np.asarray(p, np.int32),
+        lambda p: np.asarray(p, np.int64)[None, :], jnp.asarray,
+        lambda p: [np.int32(t) for t in p]],
+        ids=["list", "tuple", "int32", "int64_2d", "jax", "numpy_ints"])
+    def test_submit_reads_any_form_of_ids_as_python_ints(self, tiny, form):
+        """`submit` turns a prompt into a list of Python ints in one pass
+        (the scheduler, the hashes and the tick's planning read that
+        list), whatever it was handed."""
+        cfg, params = tiny
+        prompt = _prompts(cfg, 1, [9], seed=31)[0]
+        eng = PagedServingEngine(cfg, params, num_blocks=32, block_size=4,
+                                 max_batch=2, token_budget=16)
+        eng.submit(form(prompt), max_new_tokens=2)
+        (seq,) = eng.scheduler.waiting
+        assert seq.tokens == prompt and type(seq.tokens) is list
+        assert all(type(t) is int for t in seq.tokens)
 
     def test_eos_returns_the_requests_pages(self, tiny, hostloop_ref):
         cfg, params = tiny
