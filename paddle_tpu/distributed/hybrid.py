@@ -22,7 +22,11 @@ jitted function where:
   permutation), so one `jax.grad` covers the whole pipeline instead of the
   reference's hand-built forward_backward_pipeline (pipeline_parallel.py:575).
   A schedule of one slot (one microbatch on pp = 1) is one call of the
-  slot and no scan.
+  slot and no scan. The slots run the layers alone: once the schedule has
+  run, the last stage's M outputs are shared over 'pp' (`head_rounds`) and
+  every stage runs the head and the loss on ceil(M / pp) of them
+  (`lm_head` and `final_norm` are held by every stage, and their gradients
+  summed over 'pp', as it is).
 - **EP (MoE)**: where dp > 1 exchanges the experts, GShard-style capacity
   dispatch + `all_to_all` over the 'dp' axis (expert parallelism rides the
   data-parallel axis, as in the reference's global_scatter/global_gather
@@ -108,6 +112,20 @@ def unstack_pipeline(params: Dict[str, Any]) -> Dict[str, Any]:
     out = dict(params)
     out["blocks"] = jax.tree.map(f, params["blocks"])
     return out
+
+
+def head_rounds(num_microbatches: int, pp: int) -> Tuple[Tuple[int, ...], ...]:
+    """Which microbatch's head and loss a stage runs in which round, once
+    the schedule has run: `[stage][round]` -> the microbatch, or -1 for a
+    place that is padding (pp does not divide M, or M < pp). Stage j takes
+    microbatches j, j + pp, ... in ceil(M / pp) rounds, so every microbatch
+    stands exactly once. The rounds are the head's passes a stage a step
+    (M + pp - 1 while the slots ran them)."""
+    rounds = -(-num_microbatches // pp)
+    return tuple(
+        tuple(m if m < num_microbatches else -1
+              for m in range(stage, rounds * pp, pp))
+        for stage in range(pp))
 
 
 # what `_moe_stats` counts of one step's launches of `llama.routed_ffn_load`
@@ -486,8 +504,10 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
     `llama.routed_ffn_load` chose, "chosen" [launches, rows, top_k] i32 in
     the order of the launches (a microbatch's layers one after another).
 
-    Inside: GPipe pipeline over `num_microbatches`, TP/SP per block,
-    vocab-parallel CE on the last stage, loss pre-scaled by 1/dp.
+    Inside: GPipe pipeline over `num_microbatches`, TP/SP per block; then
+    the last stage's outputs shared over pp (`head_rounds`) and the final
+    norm, the head and the vocab-parallel CE on every stage's share of the
+    microbatches; loss pre-scaled by 1/dp.
     """
     require_trainable(cfg, dp, pp, cp)
     M = num_microbatches
@@ -567,7 +587,7 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
                 return jnp.sum(per_tok)
 
         def pipe_step(carry, t):
-            x_in, loss_acc, stats_acc = carry
+            x_in, stats_acc = carry
             m = jnp.clip(t - stage, 0, M - 1)
             active = (t - stage >= 0) & (t - stage < M)
             x0 = embed_mb(m)
@@ -576,33 +596,57 @@ def _make_shard_loss(cfg: L.LlamaConfig, num_microbatches: int,
             # here and added outside, the compiled step ran the layers'
             # forward pass a third time for them alone (PERF.md, PR 47)
             y, stats = stage_fn(x, stats_acc, blocks_local, ropes)
-            lmb = mb_loss(y, m)
-            take = active & (stage == pp - 1)
-            loss_acc = loss_acc + jnp.where(take, lmb, 0.0)
             # a bubble's launches count for nothing
             stats_acc = jax.tree.map(
                 lambda new, old: jnp.where(active, new, old), stats, stats_acc)
             with jax.named_scope("pp_send"):
                 y_send = lax.ppermute(
                     y, "pp", [(i, (i + 1) % pp) for i in range(pp)])
-            return (y_send, loss_acc, stats_acc), None
+            return (y_send, stats_acc), y
 
-        carry0 = (jnp.zeros((Bm, Tloc, D), cfg.dtype),
-                  jnp.zeros((), jnp.float32), stats0)
+        def share_outputs(outs):
+            # outs [M, Bm, T/tp, D]: on the last stage the microbatches'
+            # outputs. Stage j gets its `head_rounds` of them from the last
+            # stage, which keeps its own: pp - 1 sends of [rounds, ...],
+            # each from the one source (a stage not named receives zeros)
+            def of(j):
+                return jnp.stack([outs[max(m, 0)] for m in share[j]])
+            mine = of(pp - 1)
+            with jax.named_scope("pp_share"):
+                for j in range(pp - 1):
+                    got = lax.ppermute(of(j), "pp", [(pp - 1, j)])
+                    mine = jnp.where(stage == j, got, mine)
+            return mine
+
+        carry0 = (jnp.zeros((Bm, Tloc, D), cfg.dtype), stats0)
         slots = M + pp - 1
+        share = head_rounds(M, pp)
         with jax.named_scope("pipeline"):
             if slots == 1:
                 # a schedule of one slot is a call: as a scan of length one
                 # its body is loop-invariant, the compiler cannot see the
                 # trip count, and it lifted a second copy of the layers'
                 # forward pass out of the loop (PERF.md, PR 49)
-                (_, loss_sum, stats), _ = pipe_step(carry0, 0)
+                (_, stats), y = pipe_step(carry0, 0)
+                outs = y[None]
             else:
-                (_, loss_sum, stats), _ = lax.scan(
+                (_, stats), ys = lax.scan(
                     pipe_step, carry0, jnp.arange(slots))
-        # collect from the last stage (pp); already replicated over tp.
-        # Normalize to the GLOBAL batch mean: local token count is M*Bm*T, and
-        # the extra 1/dp makes the implicit sum over dp ranks a global mean.
+                # the last stage's slot pp - 1 + m ran microbatch m
+                outs = ys[pp - 1:]
+            # the head and the loss, once a microbatch: every stage runs
+            # its rounds of them (a masked pass is a pass, forward and
+            # backward, and inside the slots there were M + pp - 1 a stage)
+            mine = share_outputs(outs)
+            mb_of = jnp.asarray(share)[stage]
+            loss_sum = jnp.zeros((), jnp.float32)
+            for r in range(len(share[0])):
+                lmb = mb_loss(mine[r], jnp.maximum(mb_of[r], 0))
+                loss_sum = loss_sum + jnp.where(mb_of[r] >= 0, lmb, 0.0)
+        # every stage summed its own microbatches; already replicated over
+        # tp. Normalize to the GLOBAL batch mean: local token count is
+        # M*Bm*T, and the extra 1/dp makes the implicit sum over dp ranks a
+        # global mean.
         loss_sum = lax.psum(loss_sum, ("pp", "cp") if cp > 1 else "pp")
         # every stage counted its own layers, every cp shard its own rows
         stats = jax.tree.map(
