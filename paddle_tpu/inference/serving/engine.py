@@ -250,6 +250,10 @@ class _Tick:
     # beside a block: its commit forward)
     quota: Any = None
     n_prefill: int = 0
+    # rows of sequences with no token out when the tick was planned, an
+    # open block's aside: a prompt's chunks, the last one too, and a
+    # turn's new part over cached pages
+    prompt_rows: int = 0
     spec_plan: Dict[int, List[int]] = field(default_factory=dict)
     in_block: List[bool] = field(default_factory=list)
     was_decode: List[bool] = field(default_factory=list)
@@ -548,8 +552,7 @@ class PagedServingEngine:
                       "cow_block_copies": 0, "pallas_steps": 0,
                       "decode_fast_steps": 0, "ffn_steps": 0,
                       "fused_ticks": 0, "tick_pallas_launches": 0,
-                      "spec_ticks": 0, "spec_proposed": 0,
-                      "spec_accepted": 0, "attn_pages_live": 0,
+                      "spec_ticks": 0, "attn_pages_live": 0,
                       "attn_pages_fetched": 0,
                       "attn_rows_live": 0, "attn_rows_packed": 0,
                       "ticks_ahead": 0, "ahead_void_rows": 0,
@@ -779,6 +782,9 @@ class PagedServingEngine:
             (self.max_batch * (3 * Bd if Bd else 1)
              + len(self._moe_fields),), np.int32)
         self._device_free_ns = 0
+        # when a `step()` last found nothing to schedule: what a tick
+        # behind an empty engine counts its `gap_ns` from
+        self._empty_ns = 0
         # the device's time at work so far, by the ticks' own intervals
         # (`_harvest`): what a request's `device_s` is a difference of
         self._device_busy_ns = 0
@@ -786,6 +792,10 @@ class PagedServingEngine:
         # set by ReplicaHandle so this engine's tick spans say which
         # replica served them (the merged-trace failover story)
         self._trace_replica: Optional[int] = None
+        # the trace its `serve.tick` spans share: an id with no root span,
+        # so that `active_spans()` and the distress dump's `traces` hold
+        # requests alone
+        self._trace_id = _tracing.new_id()
 
     # -- client API -------------------------------------------------------
     def submit(self, tokens, max_new_tokens: int = 32,
@@ -1603,8 +1613,7 @@ class PagedServingEngine:
             if cur is None:
                 cur = self._launch(None)
                 if cur.batch is None:
-                    self._update_gauges()
-                    return cur.events
+                    return self._harvest(cur, span)
                 self._in_flight = cur
             nxt = None
             if self._next_is_determined(cur):
@@ -1905,6 +1914,8 @@ class PagedServingEngine:
             for i, (seq, n) in enumerate(batch.items):
                 if seq.busy0_ns is None:
                     seq.busy0_ns = busy0
+                if not (was_decode[i] or in_block[i]):
+                    tick.prompt_rows += n
                 if Bd:
                     if not in_block[i]:
                         tick.n_prefill += n
@@ -1940,7 +1951,9 @@ class PagedServingEngine:
         sequences, events, prefix-cache hashes, books. `span` is the step
         span that takes the tick's fields (None outside `step()`)."""
         events = cur.events
-        if cur.batch is None:     # launched ahead, and only deadlines fell
+        if cur.batch is None:
+            # nothing to schedule (launched ahead: only deadlines fell)
+            self._empty_ns = time.perf_counter_ns()
             self._update_gauges()
             return events
         batch, in_block = cur.batch, cur.in_block
@@ -1953,7 +1966,8 @@ class PagedServingEngine:
             now = time.perf_counter_ns()
             # the tick's device interval: a tick launched ahead starts
             # when the one before it ends, not when it was called
-            t0 = max(cur.t0, self._device_free_ns)
+            free = self._device_free_ns       # the end of the tick before
+            t0 = max(cur.t0, free)
             self._device_free_ns = now
             self._device_busy_ns += now - t0
             dur = (now - t0) * 1e-9
@@ -1964,9 +1978,6 @@ class PagedServingEngine:
 
         with _tracing.phase("serve.harvest"):
             spec_extra = sum(len(p) for p in cur.spec_plan.values())
-            _emit("serving.step", dur_s=dur,
-                  tokens=batch.total_tokens + spec_extra,
-                  batch=len(batch.items), prefill_tokens=cur.n_prefill)
             fields = {}
             if moe is not None:
                 fields = dict(zip(self._moe_fields, moe))
@@ -2043,18 +2054,15 @@ class PagedServingEngine:
                 self.stats["pallas_steps"] += 1
                 if decode:
                     self.stats["decode_fast_steps"] += 1
-                _emit("serving.pallas_step",
-                      launch="decode" if decode else "mixed")
             if ffn_mode:
                 self.stats["ffn_steps"] += 1
                 if decode:
                     self.stats["fused_ticks"] += 1
-                _emit("pallas_ffn.step",
-                      launch="fused_tick" if decode else "serving")
             if self.quant_kv:
                 _emit("quant.kv_step",
                       tokens=batch.total_tokens * self.cfg.num_layers,
                       pages=cur.pages * self.cfg.num_layers)
+            tick = self.stats["steps"]
             self.stats["steps"] += 1
             self.stats["ticks_ahead"] += cur.ahead
             self.stats["ticks_sampled"] += cur.sampled_rows > 0
@@ -2068,15 +2076,33 @@ class PagedServingEngine:
                 self.stats["ahead_void_rows"] += self._harvest_rows(
                     cur, nxt, all_arg, events)
             void = self.stats["ahead_void_rows"] - void0
+            # the tick's fields, built once: the step span's in a profile,
+            # and with the launch's clock readings the `serve.tick` span
+            # of the ring and the tick's one event
+            kind = ("decode" if decode else
+                    "block" if Bd and all(in_block) else "mixed")
+            fields.update(
+                batch=len(batch.items),
+                tokens=batch.total_tokens + spec_extra,
+                prefill_tokens=cur.n_prefill,
+                ahead=int(cur.ahead), void_rows=void,
+                sampled_rows=cur.sampled_rows)
             if span is not None:
-                span.set_metadata(
-                    batch=len(batch.items),
-                    tokens=batch.total_tokens + spec_extra,
-                    prefill_tokens=cur.n_prefill,
-                    kind=("decode" if decode else
-                          "block" if Bd and all(in_block) else "mixed"),
-                    ahead=int(cur.ahead), void_rows=void,
-                    sampled_rows=cur.sampled_rows, **fields)
+                span.set_metadata(kind=kind, **fields)
+            # `gap_ns`: what the device had nothing queued before this
+            # tick's call, as far as the host sees it: from the end of the
+            # tick before, or of a `step()` that found nothing to schedule
+            # since (an engine with no request charges nothing), to the
+            # entry of `serve.dispatch`; an engine's first tick has none
+            free = max(free, self._empty_ns) or cur.t0
+            fields.update(prompt_rows=cur.prompt_rows, tick=tick,
+                          launch_ns=cur.t0, gap_ns=max(0, cur.t0 - free),
+                          replica=self._trace_replica)
+            _tracing.record_span("serve.tick", self._trace_id, 0, t0, dur,
+                                 event=False, kind=kind, **fields)
+            # an event's own `kind` is its name: the tick's is `tick_kind`
+            _emit("serving.step", dur_s=dur, pallas=bool(self.pallas),
+                  ffn=ffn_mode, tick_kind=kind, **fields)
             self._update_gauges()
             return events
 
@@ -2346,8 +2372,6 @@ class PagedServingEngine:
         self.spec.commit(seq, a)
         self.spec.record_tick(k, a)
         self.stats["spec_ticks"] += 1
-        self.stats["spec_proposed"] += k
-        self.stats["spec_accepted"] += a
         _emit("spec.tick", rid=seq.rid, proposed=k, accepted=a,
               emitted=len(emitted))
         events: List[TokenEvent] = []

@@ -541,10 +541,18 @@ def _h_srv_shed(dur_s, f):
 
 
 def _h_srv_step_h(dur_s, f):
+    """The engine's one event a tick: its fields are the `serve.tick`
+    span's (the span's `kind` as `tick_kind`: an event's `kind` is its
+    name), with `pallas` / `ffn` (what the build decided once)."""
     _c_srv_steps.inc()
     _c_tokens.inc(f.get("tokens", 0), labels={"phase": "mixed"})
     if dur_s is not None:
         _h_srv_step.observe(dur_s)
+    decode = f.get("tick_kind") == "decode"
+    if f.get("pallas"):
+        _c_srv_pallas.inc(labels={"kind": "decode" if decode else "mixed"})
+    if f.get("ffn"):
+        _c_ffn.inc(labels={"kind": "fused_tick" if decode else "serving"})
 
 
 def _h_srv_token(dur_s, f):
@@ -700,10 +708,6 @@ _HANDLERS = {
         f.get("tokens", 0)),
     "serving.cow": lambda d, f: _c_srv_cow.inc(f.get("copies", 1)),
     "serving.block_commit": lambda d, f: _c_srv_blocks.inc(),
-    "serving.pallas_step": lambda d, f: _c_srv_pallas.inc(
-        labels={"kind": f.get("launch", "mixed")}),
-    "pallas_ffn.step": lambda d, f: _c_ffn.inc(
-        labels={"kind": f.get("launch", "serving")}),
     "pallas_ffn.fallback": lambda d, f: _c_ffn_fb.inc(
         labels={"reason": f.get("reason", "")}),
     "serving.token": _h_srv_token,

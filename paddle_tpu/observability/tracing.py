@@ -32,7 +32,11 @@ Design constraints, in order:
 Spans form a tree per trace: the serving root span ("request") parents
 queue.wait / prefill.chunk / decode.tick / cow.copy / failover.replay;
 a pipeline batch root parents per-stage pp.stage and pp.p2p spans, each
-stamped with the elastic epoch that dispatched it.
+stamped with the elastic epoch that dispatched it. A serving engine
+records one ``serve.tick`` span a harvested tick besides, under a trace
+id of its own (:func:`new_id`): the tick's device interval as the host
+sees it, with the step span's fields, for the whole run and not only
+inside a profiler session (``PagedServingEngine._harvest``).
 """
 from __future__ import annotations
 
@@ -47,7 +51,7 @@ import jax
 from ..core import flags
 
 __all__ = [
-    "Span", "trace_enabled", "new_trace", "start_span", "end_span",
+    "Span", "trace_enabled", "new_id", "new_trace", "start_span", "end_span",
     "record_span", "span", "active_spans", "active_tree", "finished_spans",
     "to_chrome_trace", "phase", "PHASE_PREFIX",
     "measured_schedule_stats", "reset",
@@ -57,9 +61,11 @@ flags.define_flag("trace_spans", True,
                   "Enable the request/step span plane (tracing.py): span "
                   "context rides request and pipeline action objects and "
                   "finished spans feed paddle_trace_* metrics + the ring")
-flags.define_flag("trace_buffer_size", 4096,
+flags.define_flag("trace_buffer_size", 32768,
                   "Finished-span ring capacity per process; oldest spans "
-                  "are dropped first (chrome-trace export reads this ring)")
+                  "are dropped first (chrome-trace export reads this ring). "
+                  "The default keeps a serving engine's `serve.tick` spans "
+                  "for some minutes of 10 ms ticks")
 
 # cached enable knob, same idiom as observability._sampling
 _on = [1 if flags.flag_value("trace_spans") else 0]
@@ -118,6 +124,14 @@ class Span:
                 f"span={self.span_id}<-{self.parent_id} {state})")
 
 
+def new_id() -> int:
+    """A fresh trace id with no root span: for a producer whose spans
+    belong together (a chrome export puts them on one row) and whose life
+    is no request's, so that nothing of it stands in `active_spans()`
+    (a serving engine's ticks). Given whether tracing is on or off."""
+    return next(_ids)
+
+
 def new_trace(name: str, **fields) -> Optional[Span]:
     """Allocate a fresh trace: returns its root span (trace_id == the
     root's span_id), or None when tracing is off."""
@@ -163,20 +177,27 @@ def end_span(sp: Optional[Span], end_ns: Optional[int] = None,
 
 
 def record_span(name: str, trace_id: int, parent_id: int,
-                start_ns: int, dur_s: float, **fields) -> Optional[Span]:
+                start_ns: int, dur_s: float, event: bool = True,
+                **fields) -> Optional[Span]:
     """Record an already-measured interval as a finished span (the engine
-    tick attributions time with perf_counter and report after the fact)."""
+    tick attributions time with perf_counter and report after the fact).
+    ``dur_s`` is the difference of two ``perf_counter_ns()`` readings
+    times 1e-9, and ``end_ns`` comes out as the later reading to the
+    nanosecond. ``event=False``: the caller emits an event of its own with
+    these fields (the engine's ``serve.tick``: ``serving.step``), so the
+    span feeds no ``trace.span`` beside it."""
     if not _on[0] or not trace_id:
         return None
     sid = next(_ids)
     sp = Span(name, trace_id, sid, parent_id, start_ns, fields)
-    sp.end_ns = start_ns + int(dur_s * 1e9)
+    sp.end_ns = start_ns + round(dur_s * 1e9)
     with _lock:
         _finished.append(sp)
         n_active = len(_active)
-    from . import emit as _emit
-    _emit("trace.span", dur_s=dur_s, name=name, trace=trace_id,
-          span=sid, parent=parent_id, active=n_active)
+    if event:
+        from . import emit as _emit
+        _emit("trace.span", dur_s=dur_s, name=name, trace=trace_id,
+              span=sid, parent=parent_id, active=n_active)
     return sp
 
 
@@ -245,7 +266,10 @@ def to_chrome_trace(pid="paddle_tpu", offset_ns: int = 0,
     """Finished spans as a chrome://tracing document (distress dumps
     read it). ``offset_ns`` shifts the ring's ``perf_counter_ns`` stamps,
     e.g. by the offset a ``ptpu.serve.step``'s ``perf_ns`` gives onto a
-    profile's axis; tid groups spans by trace."""
+    profile's axis; tid groups spans by trace. A serving engine's
+    ``serve.tick`` spans share the engine's trace id, so with the offset
+    of ANY profiled step its whole run of ticks, the ones before and
+    behind the profiled stretch too, lies on one row over the profile."""
     with _lock:
         spans = list(_finished)
         if include_active:
