@@ -113,7 +113,9 @@ taken). Counters (``_plan_keys``): ``attn_keys_latent`` / ``attn_pairs_latent``
 of the dense walk, ``attn_*_latent_window`` of the windowed one,
 ``index_keys`` / ``index_pairs`` scored (``index_keys_fetched``: the keys
 the index's two launches copied out of their pages to score them, whole key
-tiles and a chunk's every work item its own), ``sparse_pairs_selected`` read,
+tiles and a chunk's every work item its own; ``index_blocks`` the key blocks
+those came in and ``index_blocks_run`` the ones that were ONE copy because
+their pages lie side by side in the pool), ``sparse_pairs_selected`` read,
 ``sparse_rows_dense`` rows that had no selection to make,
 ``sparse_rows_walked`` / ``sparse_pairs_walked`` the selecting chunk rows
 that read their keys through the masked walk and the (row, key) pairs it
@@ -265,6 +267,7 @@ class _Tick:
     # pages allocated in (the pool, the window pool) when it was launched
     pool_pages: Tuple[int, int] = (0, 0)
     sampled_rows: int = 0           # rows with a temperature: 0 skips the sort
+    tables: Any = None              # under a sparse index: the full pool's
 
 
 def _in_window_pool(spec: "L.LayerSpec") -> bool:
@@ -600,6 +603,7 @@ class PagedServingEngine:
             # the selecting rows that took the masked walk and the pairs it
             # multiplied for them, and the pages that carry index keys
             self.stats.update(index_keys=0, index_keys_fetched=0,
+                              index_blocks=0, index_blocks_run=0,
                               index_pairs=0, sparse_pairs_selected=0,
                               sparse_rows_dense=0, sparse_rows_walked=0,
                               sparse_pairs_walked=0, index_pages_live=0)
@@ -1867,6 +1871,8 @@ class PagedServingEngine:
             # chip, PERF.md PR 30), and nothing writes them afterwards.
             # `prev`/`feed` are always there, so a tick launched ahead runs
             # the executable every other tick of its shape runs
+            if self._index is not None:
+                tick.tables = tables
             if wtables is not None:
                 tables = (tables, wtables)
             out = fn(self.params, self._key_cache, self._value_cache,
@@ -2015,7 +2021,8 @@ class PagedServingEngine:
                 for name, n in walked.items():
                     self.stats[name] += n
             if self.cfg.layer_plan or self._index is not None:
-                keys = self._plan_keys(dec_lens, this_lens, cur.tok_pad)
+                keys = self._plan_keys(dec_lens, this_lens, cur.tok_pad,
+                                       cur.tables)
                 if self.latent:
                     keys["latent_pages_live"] = cur.pool_pages[0]
                 if self._index is not None:
@@ -2107,11 +2114,13 @@ class PagedServingEngine:
             return events
 
     def _plan_keys(self, past: np.ndarray, this: np.ndarray,
-                   tok_pad: int = 0) -> dict:
+                   tok_pad: int = 0, tables=None) -> dict:
         """One tick's keys inside the masks of a layer plan, from the
         host's own lengths (and, for what the index walk's work items
         fetch, the tick's padded row count `tok_pad`; 0: the token
-        budget's), summed over the layers of the kind. `keys`:
+        budget's; for how many of the key blocks they fetch come in one
+        copy, the tick's block `tables` [B, max_blocks]), summed over the
+        layers of the kind. `keys`:
         the distinct keys a sequence's rows see (a chunk's rows share
         theirs): past + this in a full layer, of those the ones from
         position past - (sliding_window - 1) on in a window layer;
@@ -2124,6 +2133,8 @@ class PagedServingEngine:
         out: Dict[str, int] = {}
         live = this > 0
         past, this = past[live].astype(np.int64), this[live].astype(np.int64)
+        if tables is not None:
+            tables = tables[live]
         each_keys = past + this
         each_pairs = this * past + this * (this + 1) // 2
         keys, pairs = int(each_keys.sum()), int(each_pairs.sum())
@@ -2163,6 +2174,15 @@ class PagedServingEngine:
             fetched = n_full * PL.index_keys_fetched(
                 past[sel], this[sel], tok_pad or self.token_budget,
                 self.block_size, self.max_blocks_per_seq) if self.pallas else 0
+            # the key blocks those copies come in, and how many of them are
+            # ONE copy because their pages lie side by side in the pool
+            # (`paged_attention.block_runs`, the launches' own rule)
+            blocks = (0, 0)
+            if self.pallas and tables is not None:
+                blocks = PL.index_blocks_walked(
+                    past[sel], this[sel], tables[sel],
+                    tok_pad or self.token_budget, self.block_size,
+                    self.num_blocks)
             if self.latent:
                 out = {"attn_keys_latent":
                        n_full * int(each_keys[~sel].sum()),
@@ -2171,6 +2191,8 @@ class PagedServingEngine:
             out.update({
                    "index_keys": n_full * int(each_keys[sel].sum()),
                    "index_keys_fetched": fetched,
+                   "index_blocks": n_full * blocks[0],
+                   "index_blocks_run": n_full * blocks[1],
                    "index_pairs": n_full * int(each_pairs[sel].sum()),
                    "sparse_pairs_selected": n_full * int(chosen[sel].sum()),
                    "sparse_rows_dense": n_full * int(this[~sel].sum()),

@@ -579,7 +579,11 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
     FLOPs. A selecting sequence's ONE row of a tick (a decode row, in a
     decode tick or beside a chunk) reads in the decode launch, over the
     sequences' first rows, in one of two forms:
-    * the masked decode walk, bound by the bytes of the pages it copies;
+    * the masked decode walk, bound by the bytes of the pages it copies:
+      a key block whose pages lie side by side in the pool
+      (`paged_attention.block_runs`: its table entries are p, p + 1, ...,
+      as a prompt's fresh pages are) comes in ONE copy a pool, any other
+      block page by page;
     * the GATHER: each selected position's `KV x hd` keys and values
       fetched out of both pools by page and slot, and a softmax over
       exactly them.

@@ -106,7 +106,11 @@ group's sublanes (`_spread_over_heads`); a row may then see no key of a
 block, so the probabilities are zeroed as under a window. This is the MASKED
 WALK of a layer of heads' own keys and values under a sparse index: every
 page of the context is read once for a tile of rows and the softmax runs
-over exactly the selected keys. Which rows take it and which gather their
+over exactly the selected keys. The masked DECODE walk also takes a key
+block in ONE copy a pool where the block's pages lie side by side in the
+pool (`block_runs`, computed from the table and prefetched as one more
+scalar plane; `_run_copies`): the contexts it walks are long prompts whose
+pages were taken in one go. Which rows take it and which gather their
 selected keys instead is `serving_attention.paged_layer_attention`'s rule.
 Without a mask nothing of it is traced (no operand, no scratch): the
 kernels are what they were. The BlockSpec walk has no mask.
@@ -153,7 +157,7 @@ from .flash_attention import (NEG_INF, _assert_mosaic_tileable, _i32,
 
 __all__ = ["paged_attention", "write_pages", "available", "supported",
            "selected", "whole_pages", "paged_attention_packed",
-           "mixed_work", "decode_pages_walked"]
+           "mixed_work", "decode_pages_walked", "block_runs"]
 
 # m/l carriers use the same [rows, LANES] lane-broadcast trick as
 # flash_attention.py (a [rows, 1] scratch column is not a legal vreg shape
@@ -397,7 +401,7 @@ def _walk_refs(refs, has_quant: bool):
 
 
 def _page_copies(tables_ref, b, i, j, slot, pages: int, layer, pools, bufs,
-                 sems):
+                 sems, first: int = 0):
     """The copies that bring page j (static or traced) of sequence b's key
     block i into buffer `slot`, shared by the decode walk and the mixed
     walk: one whole page `pool[layer, page]` = `[KV, block_size, hd]` of K
@@ -406,20 +410,65 @@ def _page_copies(tables_ref, b, i, j, slot, pages: int, layer, pools, bufs,
     v[, k_scale, v_scale [num_blocks, LANES]]), `bufs` their
     double-buffered scratch ([2, pages, KV, bs, hd], scale rows [2, pages,
     LANES] in SMEM), `sems` [2, 2]: K and its scales signal `sems[0,
-    slot]`, V and its scales `sems[1, slot]`."""
+    slot]`, V and its scales `sems[1, slot]`. `first` (static) leaves out
+    the pools before it: 2 gives the scale rows alone."""
     page = tables_ref[b, i * _i32(pages) + j]
     return [pltpu.make_async_copy(
         pool.at[layer, page] if n < 2 else pool.at[page], buf.at[slot, j],
         sems.at[_i32(n % 2), slot])
-        for n, (pool, buf) in enumerate(zip(pools, bufs))]
+        for n, (pool, buf) in enumerate(zip(pools, bufs)) if n >= first]
 
 
 def _block_copies(tables_ref, b, i, slot, pages: int, layer, pools, bufs,
                  sems):
-    """Every copy of key block i, page by page (`_page_copies`)."""
+    """Every copy of key block i, page by page (`_page_copies`): the form
+    of a block whose pages lie anywhere in the pool. A block that is a RUN
+    (`block_runs`) comes in `_run_copies` instead, where the launch was
+    handed the plane that says so (the masked decode walk alone)."""
     return [c for j in range(pages)
             for c in _page_copies(tables_ref, b, i, _i32(j), slot, pages,
                                   layer, pools, bufs, sems)]
+
+
+def block_runs(tables, pages: int, num_blocks: int, xp=jnp):
+    """Which key blocks of a block table are RUNS: `tables` [B, width]
+    int32 as the scheduler made it (-1 = no page), cut into key blocks of
+    `pages` entries (the last one padded with -1) -> [B, blocks] int32, 1
+    where a block's entries are p, p + 1, ..., p + pages - 1 with p >= 0
+    and p + pages <= `num_blocks`: its pages lie side by side in the pool,
+    so `pool[layer, p : p + pages]` is ONE contiguous region and one copy
+    brings it where `pages` copies bring any other block. A block with a
+    -1 or padded entry, a descending or broken sequence of pages, or one
+    that would end past the pool is no run. Read from the table alone, by
+    nobody's setting; `xp` is `jnp` for the plane a launch prefetches and
+    `numpy` for the host's count of the same blocks
+    (`paged_attention_latent.index_blocks_walked`), so the two cannot
+    drift."""
+    tables = xp.asarray(tables, dtype=xp.int32)
+    if tables.shape[1] % pages:
+        tables = xp.pad(tables, ((0, 0), (0, -tables.shape[1] % pages)),
+                        constant_values=-1)
+    blocks = tables.reshape(tables.shape[0], -1, pages)
+    first = blocks[:, :, 0]
+    run = ((blocks == first[:, :, None] + xp.arange(pages, dtype=xp.int32)
+            ).all(axis=-1) & (first >= 0) & (first + pages <= num_blocks))
+    return run.astype(xp.int32)
+
+
+def _run_copies(tables_ref, b, i, slot, pages: int, layer, pools, bufs, sems):
+    """Key block i of sequence b where it is a run (`block_runs`): ONE copy
+    of `pool[layer, p : p + pages]` = [pages, KV, block_size, hd] for K and
+    one for V, the same bytes into the same buffer under the same
+    semaphore as `_block_copies`' 2 x pages; an int8 pool's scale rows
+    still ride a page at a time."""
+    page = tables_ref[b, i * _i32(pages)]
+    whole = [pltpu.make_async_copy(
+        pool.at[layer, pl.ds(page, pages)], buf.at[slot],
+        sems.at[_i32(n), slot])
+        for n, (pool, buf) in enumerate(zip(pools[:2], bufs[:2]))]
+    return whole + [c for j in range(pages) for c in _page_copies(
+        tables_ref, b, i, _i32(j), slot, pages, layer, pools, bufs, sems,
+        first=2)]
 
 
 def _page_scales(scale_ref, slot, kv, pages: int, block_size: int):
@@ -473,9 +522,13 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
     softmax runs per head over the block's pages * bs keys. `masked`
     (static): the MASKED WALK; behind q comes the sequence's selection
     [1, key blocks, 1, span] int8, and the row sees of a key block only the
-    keys it selected."""
+    keys it selected; in front of q then comes one more prefetched scalar,
+    `runs_ref` [B, key blocks] (`block_runs`), and a block it marks comes
+    in one copy a pool (`_run_copies`), any other page by page as in the
+    unmasked walk, which is handed no such plane and traces as it did."""
+    runs_ref = None
     if masked:
-        mask_ref, refs = refs[1], refs[:1] + refs[2:]
+        runs_ref, mask_ref, refs = refs[0], refs[2], refs[1:2] + refs[3:]
     q_ref, pools, o_ref, bufs, sems, acc, m_sc, l_sc = _walk_refs(
         refs, has_quant)
     kbuf, vbuf = bufs[:2]
@@ -503,24 +556,41 @@ def _decode_kernel(tables_ref, past_ref, this_ref, layer_ref, *refs,
         return _block_copies(tables_ref, b, i, slot, pages, layer, pools,
                              bufs, sems)
 
+    def start(i, slot):
+        if runs_ref is None:
+            for c in copies(i, slot):
+                c.start()
+            return
+        run = runs_ref[b, i] != 0
+
+        @pl.when(run)
+        def _():
+            for c in _run_copies(tables_ref, b, i, slot, pages, layer, pools,
+                                 bufs, sems):
+                c.start()
+
+        @pl.when(jnp.logical_not(run))
+        def _():
+            for c in copies(i, slot):
+                c.start()
+
     m_sc[...] = jnp.full_like(m_sc, NEG_INF)
     l_sc[...] = jnp.zeros_like(l_sc)
     acc[...] = jnp.zeros_like(acc)
 
     @pl.when(n_blocks > (first if window else 0))
     def _():
-        for c in copies(first, jax.lax.rem(first, _i32(2)) if window
-                        else _i32(0)):
-            c.start()
+        start(first, jax.lax.rem(first, _i32(2)) if window else _i32(0))
 
     def block(i, _):
         slot = jax.lax.rem(i, _i32(2))
 
         @pl.when(i + _i32(1) < n_blocks)
         def _():
-            for c in copies(i + _i32(1), _i32(1) - slot):
-                c.start()
+            start(i + _i32(1), _i32(1) - slot)
 
+        # (a DMA semaphore counts bytes: a run's one copy is waited for by
+        # the pages' descriptors as their own copies are)
         for c in copies(i, slot):
             c.wait()
         kv_abs = (jax.lax.broadcasted_iota(jnp.int32, (G, span), 1)
@@ -593,10 +663,13 @@ def _walk_operands(key_cache, value_cache, tables, k_dequant, v_dequant,
 
 def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
                  sm_scale, k_dequant, v_dequant, interpret, window: int = 0,
-                 mask=None):
+                 mask=None, raw_tables=None):
     """The decode launch (`rows == group`): grid over sequences, pools
     left in HBM, whole pages gathered by the kernel. `mask` [B, nb, 4]
-    uint32 (or None: static): the masked walk."""
+    uint32 (or None: static): the masked walk, which also prefetches
+    which of its key blocks are runs (`block_runs` of `raw_tables`, the
+    table before its -1 entries were clamped) and fetches those in one
+    copy a pool."""
     B, KV, G, hd = q_rows.shape
     _, _, _, bs, _ = key_cache.shape
     has_quant = k_dequant is not None
@@ -606,9 +679,10 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
         bs, KV, hd, key_cache.dtype.itemsize, max_blocks)
     tables, pools, page_scratch = _walk_operands(
         key_cache, value_cache, tables, k_dequant, v_dequant, pages)
-    operands, mask_specs = [], []
+    operands, mask_specs, runs = [], [], []
     if mask is not None:
         span, blocks = pages * bs, tables.shape[1] // pages
+        runs = [block_runs(raw_tables, pages, key_cache.shape[1])]
         operands = [_mask_blocks(mask, blocks, span)[:, :, None]]
         mask_specs = [pl.BlockSpec(
             (1, blocks, 1, span),
@@ -621,7 +695,7 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
     _assert_mosaic_tileable(row_spec.block_shape, q_rows.shape, "decode rows")
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=4 + len(runs),
         grid=(B,),
         in_specs=[row_spec] + mask_specs + [hbm] * len(pools),
         out_specs=row_spec,
@@ -645,7 +719,7 @@ def _decode_call(q_rows, key_cache, value_cache, tables, past, this, layer,
     else:
         launch = pl.pallas_call(kernel, name="paged_attention_decode_masked",
                                 **call)
-    return launch(tables, past, this, layer, q_rows, *operands, *pools)
+    return launch(tables, past, this, layer, *runs, q_rows, *operands, *pools)
 
 
 # the mixed walk's work item: the row tile of this many tokens of one
@@ -1151,7 +1225,7 @@ def paged_attention(q_rows, key_cache, value_cache, block_tables,
                 "go through paged_attention_packed")
         return _decode_call(q_rows, key_cache, value_cache, tables, past,
                             this, layer, sm_scale, k_dequant, v_dequant,
-                            interpret, window, mask)
+                            interpret, window, mask, block_tables)
 
     _refuse_mask(mask, hd)
     mem = {"memory_space": pltpu.VMEM}

@@ -63,10 +63,13 @@ key blocks), the split the latent read makes between its decode launch and
 its mixed walk. Both take the stacked index pool [L, num_blocks, 1,
 block_size, ID], the layer and the block table as the walks above do, and
 copy a key tile's whole pages themselves (`_copies`: a page of 16 rows of
-128 lanes is an aligned 4 KB copy), double-buffered from one grid step to
-the next, up to the last key a row of the item sees: no copy of a
-sequence's keys by position exists, and a slot without a row copies
-nothing. `index_keys_fetched` is the host's count of those copies. The
+128 lanes is an aligned 4 KB copy; a tile whose pages lie side by side in
+the pool, `paged_attention.block_runs` of the table, in ONE copy,
+`_run_copy`), double-buffered from one grid step to the next, up to the
+last key a row of the item sees: no copy of a sequence's keys by position
+exists, and a slot without a row copies nothing. `index_keys_fetched` is
+the host's count of those keys, `index_blocks_walked` of the tiles they
+come in and of those that are one copy. The
 exact selection and the gathered read over the
 selected rows are plain XLA (`ops/kernels/sparse_index.py`,
 `serving_attention.paged_latent_attention`, which also states the one rule
@@ -96,10 +99,11 @@ from jax.experimental.pallas import tpu as pltpu
 from ..kernels.sparse_index import unpack_mask
 from .flash_attention import NEG_INF, _i32, available, count_launch
 from .paged_attention import (_STAT_LANES, _loop_i32, _spread_over_heads,
-                              _work_items, mixed_items)
+                              _work_items, block_runs, mixed_items)
 
 __all__ = ["latent_attention", "latent_attention_packed",
            "index_scores_packed", "index_scores_rows", "index_keys_fetched",
+           "index_blocks_walked",
            "write_latent_pages", "padded_width", "LANES"]
 
 LANES = 128
@@ -122,11 +126,25 @@ def _pages(keys: int, block_size: int, max_blocks: int) -> int:
 
 
 def _copies(tables_ref, b, i, slot, pages: int, layer, pool, buf, sems):
-    """The copies that bring key block i of sequence b into buffer `slot`:
-    one whole page `pool[layer, page, 0]` = [block_size, W] each."""
+    """The copies that bring key block i of sequence b into buffer `slot`
+    page by page: one whole page `pool[layer, page, 0]` = [block_size, W]
+    each, wherever the block's pages lie in the pool. (A block whose pages
+    lie side by side comes as one copy where a launch knows it:
+    `_run_copy`, the index walks.)"""
     return [pltpu.make_async_copy(
         pool.at[layer, tables_ref[b, i * _i32(pages) + _i32(j)], _i32(0)],
         buf.at[slot, _i32(j)], sems.at[slot]) for j in range(pages)]
+
+
+def _run_copy(tables_ref, b, i, slot, pages: int, layer, pool, buf, sems):
+    """Key block i of sequence b where it is a run
+    (`paged_attention.block_runs`: its table entries are p, p + 1, ..., p +
+    pages - 1): the ONE copy of `pool[layer, p : p + pages, 0]` = [pages,
+    block_size, W], the same bytes into the same buffer under the same
+    semaphore as `_copies`' `pages` copies."""
+    return pltpu.make_async_copy(
+        pool.at[layer, pl.ds(tables_ref[b, i * _i32(pages)], pages), _i32(0)],
+        buf.at[slot], sems.at[slot])
 
 
 def _block_products(q, kbuf, slot, ok, sm_scale, value_dim, m_prev, l_prev,
@@ -535,27 +553,44 @@ def write_latent_pages(pool, layer, pages, lo, hi, new,
 # ---------------------------------------------------------------------------
 _INDEX_KEYS = 512       # index keys of a key tile of a chunk's walk
 _INDEX_TOKENS = 32      # tokens of a work item's row tile (x IH rows)
-_INDEX_ROW_KEYS = 1024  # index keys of a key block of the one-row form
+# index keys of a key block of the one-row form: once a block in a run is
+# one copy, its grid steps are what is left of the launch. On the v5e, one
+# layer, 16 sequences of 33.8k-64.5k keys whose last 130 pages are dealt
+# page by page: 0.52 ms at 1,024 keys (64 pages), 0.43 at 2,048, 0.44 at
+# 4,096 (whose blocks are runs less often); tables with no run at all 1.15,
+# 1.08, 1.07 (PERF.md section 6, PR 53)
+_INDEX_ROW_KEYS = 2048
 
 
-def _key_tile(tables_ref, b, i, more, layer, pool, kbuf, sems):
+def _key_tile(tables_ref, runs_ref, b, i, more, layer, pool, kbuf, sems):
     """Key tile i of sequence b, [pages * bs, ID], out of its pages of
     `pool[layer]`: the tiles of one sequence come to consecutive grid steps
     from tile 0 on, so a tile's page copies were started by the step before
     it (tile 0's start here) and tile i + 1's start now where `more` says a
-    step will wait for them; buffer i % 2. The copies of a tile are written
-    out once (a loop over them costs the one-row form half its speed,
-    PERF.md section 6, PR 45) at ONE site, a loop over the zero to two
-    tiles that start in this step, and waited for as one, by a descriptor
-    of the whole buffer (a DMA semaphore counts bytes): a launch holds
-    pages + 1 descriptors, not three times the pages, which is what its
-    lowering costs every start-up."""
+    step will wait for them; buffer i % 2. A tile that `runs_ref` [B,
+    tiles] marks (`block_runs`: its pages lie side by side in the pool, as
+    a prompt's fresh pages do) comes in ONE copy, `_run_copy`: a page of
+    4 KB a copy leaves the launch bound by how fast descriptors are issued
+    (21 ns a copy, PERF.md section 6, PR 53). The page-by-page copies of
+    any other tile are written out once (a loop over them costs the
+    one-row form half its speed, PERF.md section 6, PR 45) at ONE site, a
+    loop over the zero to two tiles that start in this step, and either
+    form is waited for as one, by a descriptor of the whole buffer (a DMA
+    semaphore counts bytes): a launch holds pages + 2 descriptors, not
+    three times the pages, which is what its lowering costs every
+    start-up."""
     _, pages, bs, ID = kbuf.shape
 
     def start(t):
-        for c in _copies(tables_ref, b, t, jax.lax.rem(t, _i32(2)), pages,
-                         layer, pool, kbuf, sems):
-            c.start()
+        at = (tables_ref, b, t, jax.lax.rem(t, _i32(2)), pages, layer, pool,
+              kbuf, sems)
+        run = runs_ref[b, t] != 0
+        pl.when(run)(lambda: _run_copy(*at).start())
+
+        @pl.when(jnp.logical_not(run))
+        def _():
+            for c in _copies(*at):
+                c.start()
         return t + _i32(1)
 
     jax.lax.while_loop(
@@ -566,8 +601,8 @@ def _key_tile(tables_ref, b, i, more, layer, pool, kbuf, sems):
     return kbuf[slot].reshape(pages * bs, ID)
 
 
-def _score_tile(tables_ref, b, i, rows, last, layer_ref, q_ref, w_ref, pool,
-                o_ref, kbuf, sems):
+def _score_tile(tables_ref, runs_ref, b, i, rows, last, layer_ref, q_ref,
+                w_ref, pool, o_ref, kbuf, sems):
     """Key tile i of sequence b for the query rows q_ref [1, tq * IH, ID]
     (row r = t * IH + h; w_ref their float32 head weights), the last of
     which sits at position `last`: I(t, s) = sum_h w[t, h] ReLU(q[t, h] .
@@ -579,8 +614,9 @@ def _score_tile(tables_ref, b, i, rows, last, layer_ref, q_ref, w_ref, pool,
 
     @pl.when(needed)
     def _():
-        k = _key_tile(tables_ref, b, i, (i + _i32(1)) * _i32(keys) <= last,
-                      layer_ref[0], pool, kbuf, sems)
+        k = _key_tile(tables_ref, runs_ref, b, i,
+                      (i + _i32(1)) * _i32(keys) <= last, layer_ref[0], pool,
+                      kbuf, sems)
         s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())),
                                 precision=jax.lax.Precision.DEFAULT,
                                 preferred_element_type=jnp.float32)
@@ -592,26 +628,26 @@ def _score_tile(tables_ref, b, i, rows, last, layer_ref, q_ref, w_ref, pool,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-def _index_kernel(tables_ref, seq_ref, t0_ref, past_ref, this_ref, layer_ref,
-                  q_ref, w_ref, pool, o_ref, kbuf, sems):
+def _index_kernel(tables_ref, runs_ref, seq_ref, t0_ref, past_ref, this_ref,
+                  layer_ref, q_ref, w_ref, pool, o_ref, kbuf, sems):
     """Key tile i of work item j: the item's tokens' index heads against
     one tile of its sequence's index keys, up to the item's last row."""
     j, i = pl.program_id(0), pl.program_id(1)
     b = seq_ref[j]
     live = jnp.clip(this_ref[b] - t0_ref[j], _i32(0), _i32(o_ref.shape[1]))
-    _score_tile(tables_ref, b, i, live > 0,
+    _score_tile(tables_ref, runs_ref, b, i, live > 0,
                 past_ref[b] + t0_ref[j] + live - _i32(1), layer_ref, q_ref,
                 w_ref, pool, o_ref, kbuf, sems)
 
 
-def _index_row_kernel(tables_ref, past_ref, this_ref, layer_ref, q_ref, w_ref,
-                      pool, o_ref, kbuf, sems):
+def _index_row_kernel(tables_ref, runs_ref, past_ref, this_ref, layer_ref,
+                      q_ref, w_ref, pool, o_ref, kbuf, sems):
     """Key block i of sequence b of the one-row form: its one row's index
     heads (a row tile of one token) against one block of its index keys,
     up to its own position."""
     b, i = pl.program_id(0), pl.program_id(1)
-    _score_tile(tables_ref, b, i, this_ref[b] > 0, past_ref[b], layer_ref,
-                q_ref, w_ref, pool, o_ref, kbuf, sems)
+    _score_tile(tables_ref, runs_ref, b, i, this_ref[b] > 0, past_ref[b],
+                layer_ref, q_ref, w_ref, pool, o_ref, kbuf, sems)
 
 
 def index_tokens(token_num: int) -> int:
@@ -620,16 +656,40 @@ def index_tokens(token_num: int) -> int:
 
 def _index_tables(pool, block_tables, keys: int):
     """The index launches' view of a block table: (tables with -1 made 0
-    and padded to whole key tiles, pages of a tile, tiles): a tile is
+    and padded to whole key tiles, which of the tiles are runs
+    (`block_runs`, [B, tiles] int32), pages of a tile, tiles): a tile is
     `keys` index keys in whole pages, or the whole table where that is
     shorter."""
     if pool.ndim != 5 or pool.shape[2] != 1:
         raise ValueError(f"index pool {pool.shape}: pass the stacked "
                          "[L, nb, 1, bs, ID]")
     pages = _pages(keys, pool.shape[3], block_tables.shape[1])
+    runs = block_runs(block_tables, pages, pool.shape[1])
     tables = jnp.maximum(block_tables.astype(jnp.int32), 0)
     tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % pages)))
-    return tables, pages, tables.shape[1] // pages
+    return tables, runs, pages, tables.shape[1] // pages
+
+
+def _index_trips(past, this, token_num: int, block_size: int,
+                 max_blocks: int):
+    """The trip counts of `_index_row_kernel` and `_index_kernel`, from
+    their own constants, for the sequences that SELECT (`past`, `this`
+    numpy [n]), as a list of (places in `past` [m], the pages of a key
+    block of the launch that takes them, their trips [m]): the one-row
+    sequences together, each the key blocks of the one-row form up to its
+    own position; then every chunk, a place a work item of `index_tokens`
+    rows, each the key tiles up to its last row."""
+    past, this = np.asarray(past, np.int64), np.asarray(this, np.int64)
+    pr = _pages(_INDEX_ROW_KEYS, block_size, max_blocks)
+    pk = _pages(_INDEX_KEYS, block_size, max_blocks)
+    tq = index_tokens(token_num)
+    one = np.flatnonzero(this == 1)
+    out = [(one, pr, past[one] // (pr * block_size) + 1)]
+    for at in np.flatnonzero(this > 1).tolist():
+        n = int(this[at])
+        last = past[at] + np.minimum(np.arange(0, n, tq) + tq, n) - 1
+        out.append((np.full(len(last), at), pk, last // (pk * block_size) + 1))
+    return out
 
 
 def index_keys_fetched(past, this, token_num: int, block_size: int,
@@ -642,18 +702,32 @@ def index_keys_fetched(past, this, token_num: int, block_size: int,
     row in whole key tiles (a chunk's later items read its earlier keys
     again: over `index_keys` this is how many times a key is read). The
     trip counts of `_index_row_kernel` and `_index_kernel`, from the same
-    constants."""
-    tile = lambda keys: _pages(keys, block_size, max_blocks) * block_size
-    rows, tk, tq = tile(_INDEX_ROW_KEYS), tile(_INDEX_KEYS), index_tokens(
-        token_num)
-    total = 0
-    for p, n in zip(np.asarray(past).tolist(), np.asarray(this).tolist()):
-        if n == 1:
-            total += (p // rows + 1) * rows
-        elif n > 1:
-            last = p + np.minimum(np.arange(0, n, tq) + tq, n) - 1
-            total += int(((last // tk + 1) * tk).sum())
-    return total
+    constants (`_index_trips`)."""
+    return sum(int(trips.sum()) * pages * block_size
+               for _, pages, trips in _index_trips(
+                   past, this, token_num, block_size, max_blocks))
+
+
+def index_blocks_walked(past, this, tables, token_num: int, block_size: int,
+                        num_blocks: int):
+    """(key blocks the two index launches fetch for one layer, those of
+    them that come in ONE copy), reckoned on the host from the lengths and
+    the block tables (numpy [n, max_blocks], -1 = no page) of the
+    sequences that select: `index_keys_fetched`'s trips, each block judged
+    by `block_runs`, the rule the launches' prefetched plane is made by."""
+    tables = np.asarray(tables)
+    seen = {}       # pages of a key block -> runs up to each block of a row
+    blocks = run = 0
+    for at, pages, trips in _index_trips(past, this, token_num, block_size,
+                                         tables.shape[1]):
+        if not len(at):
+            continue
+        if pages not in seen:
+            seen[pages] = np.cumsum(block_runs(tables, pages, num_blocks,
+                                               xp=np), axis=1)
+        blocks += int(trips.sum())
+        run += int(seen[pages][at, trips - 1].sum())    # (a trip is >= 1)
+    return blocks, run
 
 
 def index_scores_packed(qi_tok, w_tok, pool, block_tables, seq_lens_decoder,
@@ -681,7 +755,8 @@ def index_scores_packed(qi_tok, w_tok, pool, block_tables, seq_lens_decoder,
                                       method="compare_all") - 1, 0, B - 1)
     tok_local = tok_idx - cu[tok_b]
     tq = index_tokens(token_num)
-    tables, pages, tiles = _index_tables(pool, block_tables, _INDEX_KEYS)
+    tables, runs, pages, tiles = _index_tables(pool, block_tables,
+                                               _INDEX_KEYS)
     tk = pages * bs
     items = mixed_items(token_num, B, tq)
     seq, t0, first = _work_items(cu, this, tq, items, token_num)
@@ -694,7 +769,7 @@ def index_scores_packed(qi_tok, w_tok, pool, block_tables, seq_lens_decoder,
         (1, tq * IH, w), lambda j, i, *_: (j, _i32(0), _i32(0)),
         memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6, grid=(items, tiles),
+        num_scalar_prefetch=7, grid=(items, tiles),
         in_specs=[rows(ID), rows(1), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, tq, tk), lambda j, i, *_: (j, _i32(0), i),
                                memory_space=pltpu.VMEM),
@@ -705,8 +780,8 @@ def index_scores_packed(qi_tok, w_tok, pool, block_tables, seq_lens_decoder,
         _index_kernel, name="paged_index_scores_chunk", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((items, tq, tiles * tk), jnp.float32),
         interpret=interpret,
-    )(tables, seq, t0, past, this, jnp.asarray(layer, jnp.int32).reshape(1),
-      q_items, w_items, pool)
+    )(tables, runs, seq, t0, past, this,
+      jnp.asarray(layer, jnp.int32).reshape(1), q_items, w_items, pool)
     item = jnp.clip(first[tok_b] + tok_local // tq, 0, items - 1)
     return o_items[item, tok_local % tq, :S]
 
@@ -729,13 +804,14 @@ def index_scores_rows(qi_rows, w_rows, pool, block_tables, seq_lens_decoder,
     S = block_tables.shape[1] * bs
     if interpret is None:
         interpret = not available()
-    tables, pages, blocks = _index_tables(pool, block_tables, _INDEX_ROW_KEYS)
+    tables, runs, pages, blocks = _index_tables(pool, block_tables,
+                                                _INDEX_ROW_KEYS)
     keys = pages * bs
     row = lambda w: pl.BlockSpec(
         (1, IH, w), lambda b, i, *_: (b, _i32(0), _i32(0)),
         memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=(B, blocks),
+        num_scalar_prefetch=5, grid=(B, blocks),
         in_specs=[row(ID), row(1), pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, 1, keys), lambda b, i, *_: (b, _i32(0), i),
                                memory_space=pltpu.VMEM),
@@ -747,7 +823,7 @@ def index_scores_rows(qi_rows, w_rows, pool, block_tables, seq_lens_decoder,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, 1, blocks * keys), jnp.float32),
         interpret=interpret,
-    )(tables, seq_lens_decoder.reshape(-1).astype(jnp.int32),
+    )(tables, runs, seq_lens_decoder.reshape(-1).astype(jnp.int32),
       seq_lens_this_time.reshape(-1).astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), qi_rows,
       w_rows.astype(jnp.float32)[..., None], pool)[:, 0, :S]

@@ -1048,6 +1048,53 @@ def test_sparse_latent_walks_compile(one_chip, launch):
     assert name in _compile(fn, one_chip, *shapes).as_text()
 
 
+@pytest.mark.parametrize("launch", ["index_decode", "index", "masked_decode"])
+def test_run_copy_launches_compile_at_keye_shapes(one_chip, launch):
+    """The three launches that take a key block in ONE copy where its
+    pages lie side by side in the pool (PR 53: `block_runs` as one more
+    prefetched scalar, `_run_copy` / `_run_copies` under `pl.when` beside
+    the page-by-page copies), at `keye-vl2-30b-a3b-serve`'s shapes: 16
+    sequences, tables of 4,096 entries (`max_len` 65,536), the index pool
+    [4, 67584, 1, 16, 128] (a block of 128 pages of 4 KB in the one-row
+    form, 32 in the chunk's, is the slice `pool[layer, p : p + pages, 0]`)
+    and the heads' pools [4, 67584, 4, 16, 128] (64 pages of 16 KB,
+    `pool[layer, p : p + 64]`). The index
+    launches at `dots3-note-prev-serve`'s shapes are
+    `test_sparse_latent_walks_compile`'s `index` and `index_decode`, which
+    take the run plane too."""
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+    batch, tokens, entries = 16, 256, 4096
+    lens = [((batch, entries), jnp.int32), ((batch,), jnp.int32),
+            ((batch,), jnp.int32)]
+    index_pool, heads_pool = (_bf16(4, 67584, 1, 16, 128),
+                              _bf16(4, 67584, 4, 16, 128))
+    if launch == "index_decode":
+        def fn(qi, w, pool, tables, past, this, layer):
+            return pl_.index_scores_rows(qi, w, pool, tables, past, this,
+                                         layer, interpret=False)
+        shapes = [_bf16(batch, 16, 128), ((batch, 16), jnp.float32),
+                  index_pool, *lens, ((), jnp.int32)]
+        name = "paged_index_scores_decode"
+    elif launch == "index":
+        def fn(qi, w, pool, tables, past, this, cu, layer):
+            return pl_.index_scores_packed(qi, w, pool, tables, past, this,
+                                           cu, layer, interpret=False)
+        shapes = [_bf16(tokens, 16, 128), ((tokens, 16), jnp.float32),
+                  index_pool, *lens, ((batch + 1,), jnp.int32),
+                  ((), jnp.int32)]
+        name = "paged_index_scores_chunk"
+    else:
+        def fn(q, kc, vc, tables, past, this, layer, mask):
+            return pa.paged_attention(q, kc, vc, tables, past, this, 8,
+                                      0.0884, interpret=False, layer=layer,
+                                      mask=mask)
+        shapes = [_bf16(batch, 4, 8, 128), heads_pool, heads_pool, *lens,
+                  ((), jnp.int32),
+                  ((batch, entries * 16 // 128, 4), jnp.uint32)]
+        name = "paged_attention_decode_masked"
+    assert name in _compile(fn, one_chip, *shapes).as_text()
+
+
 def test_the_latent_walk_without_a_mask_is_the_kernel_it_was():
     """`latent_attention_packed` without a mask (Kimi's chunk walk, dots3's
     window walks and dense first chunk) launches with the parent's

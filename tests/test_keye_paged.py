@@ -381,6 +381,36 @@ def test_the_page_accounting_counts_the_index_keys(tiny):
     assert eng.engine_stats["kv_page_bytes"] == eng.kv_page_bytes
 
 
+def test_the_engine_counts_the_index_blocks_that_come_in_one_copy(
+        tiny, monkeypatch):
+    """`index_blocks` / `index_blocks_run` on the kernels' path, the
+    ticks' program stood in for (the counts are the host's, from the
+    lengths and the block tables it plans with): key blocks of 32 keys =
+    4 pages of 8, row tiles of 4 tokens. A prompt of 37 alone in a fresh
+    pool gets pages 0..4 in one go: its first chunk's 8 work items fetch
+    block 0 (pages 0-3, a run) each; the second chunk's two items blocks 0
+    and 1, the 7 decode rows at positions 37..43 the same two, and block 1
+    (page 4, then 5 as the row at 40 needs it, the rest unassigned) is no
+    run; times the 2 index layers. The stock path counts nothing."""
+    cfg, params = tiny
+    monkeypatch.setattr(PL, "_INDEX_TOKENS", 4)
+    eng = engine(cfg, params, pallas=True)
+    eng._next_is_determined = lambda cur: False     # no void row
+    monkeypatch.setattr(eng, "_build_step", lambda tok_pad, B, *rest: (
+        lambda *args, index_cache: (
+            jnp.zeros((B + len(eng._moe_fields),), jnp.int32), args[1],
+            args[2], index_cache)))
+    eng.submit(prompt_of(37, seed=2), max_new_tokens=8)
+    eng.run()
+    st = eng.stats
+    assert st["index_keys_fetched"] == 2 * 32 * (8 + 2 * 2 + 7 * 2)
+    assert st["index_blocks"] == 2 * (8 + 2 * 2 + 7 * 2)
+    assert st["index_blocks_run"] == 2 * (8 + 2 + 7)
+    keys = engine(cfg, params)._plan_keys(
+        np.array([40]), np.array([1]), tables=np.arange(16)[None])
+    assert (keys["index_blocks"], keys["index_blocks_run"]) == (0, 0)
+
+
 def test_a_tick_with_few_rows_takes_the_eighth_of_the_budget(tiny):
     """Under the index a padded row costs `max_len` keys in the selection:
     a turn's few new rows run the small executable."""
