@@ -151,7 +151,8 @@ def require_trainable(cfg: L.LlamaConfig, dp: int = 1, pp: int = 1,
              cfg.shared_expert_width),
             ("a router bias (router_bias)", cfg.router_bias),
             ("QK-norm (qk_norm)", cfg.qk_norm),
-            ("block diffusion (block_length)", cfg.block_length)):
+            ("block diffusion (block_length)", cfg.block_length),
+            ("hyper-connections (hyper_lanes)", cfg.hyper_lanes)):
         if has:
             raise NotImplementedError(f"{what} does not take {name}")
     if cfg.layer_plan and pp > 1:

@@ -85,6 +85,13 @@ def quantize_llama_params(params: Dict, mode: str,
     if "blocks" not in params or "lm_head" not in params:
         raise ValueError("quantize_llama_params expects the stacked LLaMA "
                          "params pytree (init_params output)")
+    from ...models.llama import kind_stacks
+    if any(n.startswith("hc_") for stack in kind_stacks(params["blocks"])
+           for n in stack):
+        raise NotImplementedError(
+            "quantized transform covers blocks whose residual path is the "
+            "plain sum; hyper-connections (LlamaConfig.hyper_lanes: the "
+            "`hc_*` mixing weights) were never judged in fewer bits")
     blocks = dict(params["blocks"])
     missing = [n for n in WEIGHT_NAMES if n not in blocks]
     if missing:

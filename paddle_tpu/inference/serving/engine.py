@@ -152,6 +152,25 @@ adapters, a draft model, the fused FFN (``__init__``, ``submit``) and
 ``extract_pages`` / ``ingest_pages`` (``_refuse_page_handoff``: the payload
 carries no index keys).
 
+The residual stream may be LANES (``cfg.hyper_lanes``: manifold-constrained
+hyper-connections, Xing4.0's ``hc_mult`` 4): the embedded row is copied to n
+lanes kept flat, ``[tok, n d]`` (``llama.hyper_spread``), every sub-block of
+the layer loop goes through ``llama.residual`` (the one seam: the plain sum
+``x + F(norm(x))`` of every other config, traced as it always was; here a
+learned mix of the lanes in, a doubly stochastic mix of them and the
+sub-block's output back), and the head reads the lanes' sum
+(``llama.hyper_collapse``, in ``_build_step``'s one ``head``). The lanes live
+inside a tick: pages, block tables, the scheduler and the prefix cache hold
+what they held. Scopes ``hyper`` > ``hyper_coeff`` (a row's norm, its
+product with phi, the Sinkhorn rounds), ``hyper_pre`` (the sub-block's
+input), ``hyper_post`` (the lanes' update, inside the scope in which the
+sub-block's output is added: ``attn_out``, ``moe`` or ``ffn``); the step
+span's ``hyper_rows`` = live rows x 2 x layers, summed in
+``stats["hyper_rows"]``. Refused with such a stream, where a written plan's
+refusals stand and by name: int8 pages, weight quantisation, LoRA adapters,
+a draft model, the fused FFN (``__init__``, ``submit``, ``_resolve_ffn``)
+and ``extract_pages`` / ``ingest_pages`` (``_refuse_page_handoff``).
+
 SLO metrics (TTFT/TPOT histograms, queue-depth and KV-block-utilization
 gauges, admit/preempt/shed counters + flight-recorder events) flow
 through ``observability.emit`` — ``observability.summary()["serving"]``
@@ -398,7 +417,9 @@ class PagedServingEngine:
         # a sparse index over the heads' own keys and values (a uniform
         # config's, `cfg.index`): refused what a written plan is refused
         indexed = any(s.index is not None for s in cfg.layers)
-        if plan or indexed:
+        # and hyper-connections (`cfg.hyper_lanes`), under a plan or not
+        hyper = bool(cfg.hyper_lanes)
+        if plan or indexed or hyper:
             asked = {"a draft model": draft is not None,
                      "pallas_ffn": bool(pallas_ffn),
                      "quant_mode": bool(Q.resolve_quant_mode(quant_mode)),
@@ -407,8 +428,9 @@ class PagedServingEngine:
                          if quant_kv is None else quant_kv),
                      "adapter_slots (LoRA)": adapter_slots is not None}
             if any(asked.values()):
-                what = ("a layer plan" if plan else "a sparse index over the "
-                        "heads' own keys and values")
+                what = ("hyper-connections" if hyper else "a layer plan"
+                        if plan else "a sparse index over the heads' own "
+                        "keys and values")
                 raise NotImplementedError(
                     f"a config with {what} is served in fp weights and "
                     "fp pages, one token a row: "
@@ -577,6 +599,9 @@ class PagedServingEngine:
                 ("moe_pairs_held", "moe_compact_overflow")
                 if cfg.experts_held else ())
             self.stats.update(dict.fromkeys(self._moe_fields, 0))
+        if cfg.hyper_lanes:
+            # rows x sub-blocks whose lanes were mixed, summed over ticks
+            self.stats["hyper_rows"] = 0
         if self.latent:
             # keys and (row, key) pairs inside the causal mask, summed over
             # ticks and layers (`_plan_keys`), and pages allocated when a
@@ -841,10 +866,12 @@ class PagedServingEngine:
                     "(block_length > 0); this engine's model is "
                     "autoregressive")
             if adapter is not None and (self.cfg.layer_plan
-                                        or self._index is not None):
+                                        or self._index is not None
+                                        or self.cfg.hyper_lanes):
                 raise NotImplementedError(
-                    "LoRA adapters with a layer plan or a sparse index were "
-                    "never judged against a reference; submit without one")
+                    "LoRA adapters with a layer plan, a sparse index or "
+                    "hyper-connections were never judged against a "
+                    "reference; submit without one")
             if total > self.max_len:
                 raise ValueError(
                     f"prompt {len(tokens)} + new {max_new_tokens} "
@@ -964,6 +991,11 @@ class PagedServingEngine:
         return out
 
     def _refuse_page_handoff(self, what: str):
+        if self.cfg.hyper_lanes:
+            raise NotImplementedError(
+                f"{what} with hyper-connections (LlamaConfig.hyper_lanes): "
+                "a hand-off of pages was never judged against a reference "
+                "under a residual stream of lanes")
         if self._index_cache is not None:
             raise NotImplementedError(
                 f"{what} under a sparse index over the heads' own keys and "
@@ -1080,7 +1112,7 @@ class PagedServingEngine:
         reason). None re-reads FLAGS_pallas_ffn every tick; the result
         rides the executable cache key so flag flips retrace exactly once."""
         if (self.pallas_ffn is False or self.cfg.layer_plan
-                or self._index is not None):
+                or self._index is not None or self.cfg.hyper_lanes):
             return False, None
         if self.pallas_ffn:      # forced (params+geometry validated at init)
             return True, None
@@ -1140,6 +1172,18 @@ class PagedServingEngine:
                     0, tok_pad - 1).reshape(-1)
             return last
 
+        def head(params, x, cu=None):
+            # float32 logits [n, V] of the slots' last rows (`block_rows`
+            # of `cu`) or, without `cu`, of every row of the stream x
+            # [tok, d] (under hyper-connections [tok, lanes d], read as the
+            # lanes' sum): the one place the stream becomes logits
+            with jax.named_scope("head"):
+                rows = x if cu is None else x[block_rows(cu)]
+                h = L.rms_norm(L.hyper_collapse(rows, cfg),
+                               params["final_norm"], cfg.rms_eps)
+                return Q.matmul_param(h, params, "lm_head"
+                                      ).astype(jnp.float32)
+
         @functools.partial(jax.jit, donate_argnums=(1, 2),
                            donate_argnames=("index_cache",))
         def step_fn(params, key_cache, value_cache, kv_scales, tokens,
@@ -1173,13 +1217,14 @@ class PagedServingEngine:
             # named scopes: every device operation of the tick belongs to
             # a region named here (embed; layers > qkv, cache_write,
             # paged_attention, attn_out, ffn or moe > router, dispatch,
-            # experts, combine; head; sample > unmask), whatever number
+            # experts, combine, and with lanes hyper > hyper_coeff,
+            # hyper_pre, hyper_post; head; sample > unmask), whatever number
             # the compiler gives it. Metadata only. `quota` [B] and
             # `masked` [B, Bd] come with a block-diffusion tick alone
             # (`_unmask_rows` says what they are).
             with jax.named_scope("embed"):
-                x = jnp.take(params["embed"], tokens,
-                             axis=0).astype(cfg.dtype)
+                x = L.hyper_spread(jnp.take(params["embed"], tokens,
+                                            axis=0).astype(cfg.dtype), cfg)
             # rows are packed from 0: what lies behind the last chunk is
             # padding, which no expert may see
             valid = jnp.arange(tok_pad) < cu_seqlens_q[B]
@@ -1190,12 +1235,8 @@ class PagedServingEngine:
                     seq_lens_decoder, seq_lens_this_time, rope_emb, valid,
                     use_pallas, ffn_mode)
             pools = (kcs, vcs) if index_cache is None else (kcs, vcs, ics)
-            with jax.named_scope("head"):
-                # last-token hidden state per slot, or its whole block's
-                hlast = x[block_rows(cu_seqlens_q)]          # [B (* Bd), d]
-                hlast = L.rms_norm(hlast, params["final_norm"], cfg.rms_eps)
-                logits = Q.matmul_param(hlast, params, "lm_head"
-                                        ).astype(jnp.float32)  # [B (* Bd), V]
+            # last-token hidden state per slot, or its whole block's
+            logits = head(params, x, cu_seqlens_q)         # [B (* Bd), V]
             with jax.named_scope("sample"):
                 if Bd:
                     with jax.named_scope("unmask"):
@@ -1244,10 +1285,7 @@ class PagedServingEngine:
                 # the verify read: greedy argmax at EVERY packed row, so
                 # a k+1-wide speculative chunk's per-position targets
                 # come out of this same single launch
-                with jax.named_scope("head"):
-                    hall = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
-                    all_logits = Q.matmul_param(hall, params, "lm_head"
-                                                ).astype(jnp.float32)
+                all_logits = head(params, x)
                 with jax.named_scope("sample"):
                     all_arg = jnp.argmax(all_logits,
                                          axis=-1).astype(jnp.int32)
@@ -1274,7 +1312,10 @@ class PagedServingEngine:
         closed over). Scopes: qkv, cache_write,
         paged_attention (`paged_attention_full` / `_window` inside it
         where the tick makes more than one kind of launch), `attn_gate`,
-        attn_out, ffn or moe (`shared_expert` inside). Latent layers
+        attn_out, ffn or moe (`shared_expert` inside). Every sub-block
+        reads the stream and adds to it through `llama.residual` (the
+        plain sum, or with `cfg.hyper_lanes` the lanes' mixes under
+        `hyper`; x is then [tok, lanes d]). Latent layers
         (`latent_attention` below) have one pool and no value side; a
         layer of heads' own keys and values under a sparse index
         (`LayerSpec.index`) writes and scores its index keys in
@@ -1336,8 +1377,9 @@ class PagedServingEngine:
             ls = spec.latent
             half = ls.qk_rope_head_dim // 2
             select = None
+            h, out = L.residual(x, lp, cfg, "attn")
             with jax.named_scope("qkv"):
-                h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)[None]
+                h = L.rms_norm(h, lp["attn_norm"], cfg.rms_eps)[None]
                 table_r = rope_emb[self._ropes.index(spec.rope)]
                 cos, sin = (table_r[i, 0, tok_pos, :half] for i in (0, 1))
                 with jax.named_scope("latent_q"):
@@ -1363,7 +1405,7 @@ class PagedServingEngine:
                 o = L.attn_gated(o.reshape(o.shape[0], spec.heads, -1),
                                  h[0], lp).reshape(o.shape)
             with jax.named_scope("attn_out"), jax.named_scope("latent_out"):
-                return x + Q.matmul_param(o, lp, "wo"), pool, index_pool
+                return out(Q.matmul_param(o, lp, "wo")), pool, index_pool
 
         def put(pools, at, new):
             return pools[:at] + (new,) + pools[at + 1:]
@@ -1396,8 +1438,9 @@ class PagedServingEngine:
             else:
                 pool = int(two and spec.attn == "window")
                 rot = int(cfg.head_dim * spec.rope.partial)
+                h, out = L.residual(x, lp, cfg, "attn")
                 with jax.named_scope("qkv"):
-                    h = L.rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+                    h = L.rms_norm(h, lp["attn_norm"], cfg.rms_eps)
                     q, k, v = (lora(h, n, Q.matmul_param(h, lp, n))
                                for n in ("wq", "wk", "wv"))
                     q, k = L.qk_normed(q, k, lp, cfg)
@@ -1431,14 +1474,15 @@ class PagedServingEngine:
                     o = L.attn_gated(o.reshape(o.shape[0], spec.heads, -1),
                                      h, lp).reshape(o.shape)
                 with jax.named_scope("attn_out"):
-                    x = x + lora(o, "wo", Q.matmul_param(o, lp, "wo"))
+                    x = out(lora(o, "wo", Q.matmul_param(o, lp, "wo")))
+            h, out = L.residual(x, lp, cfg, "mlp")
             if spec.ffn == "sparse":
                 with jax.named_scope("moe"):
-                    h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+                    h = L.rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
                     y, load = L.routed_ffn_load(
                         h, {**lp, **experts[kind]}, cfg, valid,
                         layer=leaves["place"])
-                    x = x + y
+                    x = out(y)
                     hit, top, *held = counts
                     counts = (hit + jnp.sum(load > 0, dtype=jnp.int32),
                               jnp.maximum(top, jnp.max(load)),
@@ -1446,15 +1490,15 @@ class PagedServingEngine:
                                   held, self._held_counts(load, h.shape[0]))))
             else:
                 with jax.named_scope("ffn"):
-                    h = L.rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+                    h = L.rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
                     if ffn_mode:
                         # one launch: gate+up matmuls, silu·mul, down
                         # matmul — the d_ff intermediate never leaves VMEM
-                        x = x + FF.apply_ffn(h, lp)
+                        x = out(FF.apply_ffn(h, lp))
                     else:
                         gate = (jax.nn.silu(Q.matmul_param(h, lp, "w1"))
                                 * Q.matmul_param(h, lp, "w3"))
-                        x = x + Q.matmul_param(gate, lp, "w2")
+                        x = out(Q.matmul_param(gate, lp, "w2"))
             return (x, pk, pv, pi, *counts)
 
         # the expert counters ride the carry: one a field the tick sends
@@ -1991,6 +2035,11 @@ class PagedServingEngine:
                     self.stats[name] = (max(self.stats[name], n)
                                         if name == "moe_max_load"
                                         else self.stats[name] + n)
+            if self.cfg.hyper_lanes:
+                # the rows the lanes were mixed for: two sub-blocks a layer
+                fields["hyper_rows"] = (2 * self.cfg.num_layers
+                                        * (batch.total_tokens + spec_extra))
+                self.stats["hyper_rows"] += fields["hyper_rows"]
             if self._whole_pages:
                 # how well the launch's walk fits the traffic, from the
                 # host's own lengths: pages that hold a live key against

@@ -73,6 +73,17 @@ transposed gathers (`_dispatch`, `_combine`: each is the other's
 derivative, so a trained launch adds no d-wide row into place one at a
 time), and the way back is the [T, C] product where the places are few
 (`combine_is_a_product`, from the launch's shapes and two measured rates).
+
+The residual path itself may be other than the plain sum `x + F(norm(x))`:
+with `hyper_lanes` n > 0 (manifold-constrained hyper-connections, mHC,
+arXiv:2512.24880; Xing4.0's `hc_mult` 4) a row's state is n lanes of
+`hidden_size`, kept FLAT as [..., n d] (lane i the slice [i d, (i + 1) d)),
+and every sub-block reads a learned mix of the lanes and writes back
+through a doubly stochastic mix of them (`residual`, the one seam every
+`x + ...` of a block goes through; `hyper_coeff`, `hyper_pre`,
+`hyper_post`). The embedded row is copied to the lanes (`hyper_spread`)
+and the head reads their sum (`hyper_collapse`); with `hyper_lanes` 0 all
+of it is the identity and a block is traced as it always was.
 """
 from __future__ import annotations
 
@@ -274,6 +285,15 @@ class LlamaConfig:
     # values (every layer has it); a plan's layers
     # carry their own (`LayerSpec.index`, `LatentSpec.index`)
     index: Optional[IndexSpec] = None
+    # the residual stream as n lanes mixed by manifold-constrained
+    # hyper-connections (`residual`): 0 = the plain sum x + F(norm(x)).
+    # `hyper_sinkhorn_iters` rounds of column-then-row normalisation with
+    # `hyper_eps` in every denominator make the lanes' mix doubly
+    # stochastic; its logits are clipped to `hyper_clamp` before the exp
+    hyper_lanes: int = 0
+    hyper_sinkhorn_iters: int = 20
+    hyper_eps: float = 1e-6
+    hyper_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
         here = (self.hidden_size, self.num_heads)
@@ -327,6 +347,12 @@ class LlamaConfig:
                     "a sparse index stands over a latent layer or, over "
                     "heads' own keys and values, over a causal "
                     f"full-attention layer; no model served has {spec}")
+        if self.hyper_lanes and (self.hyper_lanes < 2 or self.block_length
+                                 or self.hyper_sinkhorn_iters < 1):
+            raise NotImplementedError(
+                f"hyper-connections (hyper_lanes={self.hyper_lanes}) take "
+                "two lanes or more and a Sinkhorn iteration or more, and "
+                "were never judged under block diffusion (block_length)")
         latent = {s.attn == "latent" for s in self.layer_plan}
         if True in latent and (False in latent or self.num_kv_heads != 1):
             raise NotImplementedError(
@@ -458,6 +484,10 @@ class LlamaConfig:
             mlp = active = 3 * d * (self.dense_intermediate_size
                                     if self.layer_plan
                                     else self.intermediate_size)
+        n = self.hyper_lanes
+        if n:   # a sub-block's phi [n d, n^2 + 2n]; its b and 3 alphas
+            attn += 2 * n * d * (n * n + 2 * n)
+            norms += 2 * (n * n + 2 * n + 3)
         return attn + mlp + norms, attn + active
 
     def num_params(self) -> int:
@@ -480,13 +510,14 @@ class LlamaConfig:
 
 
 def require_uniform(cfg: LlamaConfig, what: str) -> None:
-    """Raise for a config with a layer plan or a held share of its
-    experts, in the name of a block body that runs one uniform stack of
-    whole layers (`params["blocks"]` one dict, every expert) and would
-    compute another model under the config's name. The paths that take
-    both: `llama.forward`, `PagedServingEngine`, and the trainer
+    """Raise for a config with a layer plan, a held share of its experts
+    or hyper-connections, in the name of a block body that runs one
+    uniform stack of whole layers (`params["blocks"]` one dict, every
+    expert) around one residual stream and would compute another model
+    under the config's name. The paths that take the first two:
+    `llama.forward`, `PagedServingEngine`, and the trainer
     (`distributed.hybrid`, which names what it refuses itself,
-    `hybrid.require_trainable`)."""
+    `hybrid.require_trainable`); the lanes: the first two of those."""
     if cfg.layer_plan:
         raise NotImplementedError(
             f"{what} runs one uniform stack of layers and does not take a "
@@ -497,6 +528,11 @@ def require_uniform(cfg: LlamaConfig, what: str) -> None:
             f"{what} holds every routed expert and does not take a chip's "
             "share of them (LlamaConfig.experts_held); `llama.forward`, "
             "`PagedServingEngine` and `distributed.hybrid` (on dp = 1) do")
+    if cfg.hyper_lanes:
+        raise NotImplementedError(
+            f"{what} adds a sub-block's output to one residual stream and "
+            "does not take hyper-connections (LlamaConfig.hyper_lanes); "
+            "`llama.forward` and `PagedServingEngine` do")
 
 
 # Predefined sizes (the reference's headline configs; LLaMA-7B/13B per
@@ -598,6 +634,30 @@ def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
         blocks["w1"] = normal(keys[5], (L, d, f))
         blocks["w3"] = normal(keys[6], (L, d, f))
         blocks["w2"] = normal(keys[7], (L, f, d))
+    n = cfg.hyper_lanes
+    if n:
+        # A sub-block's mixing weights (`hyper_coeff`). phi lies
+        # TRANSPOSED, [n^2 + 2n, n d]: 24 rows pad to 32 in bf16 tiles
+        # where 24 columns would pad to 128 lanes. b and alpha are float32
+        # whatever the weights' dtype (they make coefficients, which are
+        # float32), and drawn so that both terms matter: phi at 2.4 /
+        # sqrt(n d) (0.02 at Xing4.0's 14,336) gives u = v phi, over n d
+        # values of unit mean square, a standard deviation of 2.4 at any
+        # width, alpha near 0.25 then puts alpha u near 0.6, and the lanes'
+        # mix leans to the identity (2 on the diagonal of b_res, as
+        # hyper-connections start from it) without being it
+        kh = jax.random.split(jax.random.fold_in(key, 11), 6)
+        k = n * n + 2 * n
+        lean = jnp.concatenate([
+            jnp.zeros((2 * n,), jnp.float32),
+            2.0 * jnp.eye(n, dtype=jnp.float32).reshape(-1)])
+        for which, (kp, kb, ka) in (("attn", kh[:3]), ("mlp", kh[3:])):
+            blocks[f"hc_{which}_phi"] = normal(kp, (L, k, n * d),
+                                               scale=2.4 * (n * d) ** -0.5)
+            blocks[f"hc_{which}_b"] = lean + _normal(
+                kb, (L, k), jnp.float32, 0.5)
+            blocks[f"hc_{which}_alpha"] = 0.25 + _normal(
+                ka, (L, 3), jnp.float32, 0.05)
     return blocks
 
 
@@ -1234,13 +1294,14 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     `index_rope`: (cos, sin) of the sparse index of a layer of heads' own
     keys and values (`LlamaConfig.index_rope_width`), which then attends
     over its selection alone."""
-    B, T, d = x.shape
+    B, T = x.shape[:2]
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
     nh = spec.heads if spec else cfg.num_heads
     ix = spec.index if spec else cfg.index
-    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    h, out = residual(x, lp, cfg, "attn")
+    h = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
     if spec and spec.attn == "latent":
-        x = x + latent_self_attention(h, lp, cfg, nh, cos, sin, spec.latent)
+        x = out(latent_self_attention(h, lp, cfg, nh, cos, sin, spec.latent))
     else:
         q, k = qk_normed(h @ lp["wq"].astype(h.dtype),
                          h @ lp["wk"].astype(h.dtype), lp, cfg)
@@ -1259,13 +1320,127 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
         if cfg.attn_gate:
             o = attn_gated(o, h, lp)
         o = o.reshape(B, T, nh * hd)
-        x = x + o @ lp["wo"].astype(o.dtype)
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+        x = out(o @ lp["wo"].astype(o.dtype))
+    h, out = residual(x, lp, cfg, "mlp")
+    h = rms_norm(h, lp["mlp_norm"], cfg.rms_eps)
     if (spec.ffn == "sparse") if spec else cfg.num_experts:
-        x = x + routed_ffn(h, lp, cfg)
-    else:
-        x = x + ffn(h, lp, impl=ffn_impl)
-    return x
+        return out(routed_ffn(h, lp, cfg))
+    return out(ffn(h, lp, impl=ffn_impl))
+
+
+def hyper_spread(x: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """The embedded rows x [..., d] as the stream's `hyper_lanes` lanes
+    [..., n d], every lane a copy (0 lanes: x itself)."""
+    n = cfg.hyper_lanes
+    return jnp.tile(x, (1,) * (x.ndim - 1) + (n,)) if n else x
+
+
+def _lanes(x: jax.Array, cfg: LlamaConfig) -> List[jax.Array]:
+    """The lanes of a flat stream x [..., n d], each [..., d] in float32."""
+    d = cfg.hidden_size
+    return [x[..., i * d:(i + 1) * d].astype(jnp.float32)
+            for i in range(cfg.hyper_lanes)]
+
+
+def hyper_collapse(x: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """What the final norm and the head read of the stream x [..., n d]:
+    the lanes' sum [..., d], added in float32 (0 lanes: x itself)."""
+    if not cfg.hyper_lanes:
+        return x
+    return functools.reduce(jnp.add, _lanes(x, cfg)).astype(x.dtype)
+
+
+def hyper_coeff(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+                which: str) -> jax.Array:
+    """The mixing coefficients of sub-block `which` ("attn" | "mlp") for
+    the rows x [..., n d], float32 [rows, n^2 + 2n]: n of `hyper_pre`
+    (sigmoid), n of the sub-block's output (2 sigmoid), and the lanes' mix
+    [out lane j, in lane i] at column 2n + j n + i, doubly stochastic:
+        v = x / sqrt(mean(x^2) + rms_eps) over all n d values (the norm's
+            weight is folded into phi);  u = v phi
+        logits = alpha_pre u[:n] + b[:n] | alpha_post u[n:2n] + b[n:2n] |
+            alpha_res u[2n:] + b[2n:]
+        M = exp(clip(the last, *hyper_clamp)), then `hyper_sinkhorn_iters`
+            times: every column over (its sum + hyper_eps), every row over
+            (its sum + hyper_eps).
+    The row's scale is applied to u, not to x (one pass over the stream:
+    its squares and its product with phi, float32 accumulation). The
+    iteration runs on [n^2 + 2n, rows], a row of the batch a lane, with
+    the 4 x 4 unrolled and its sums written as adds of rows: elementwise
+    work on dense vectors that XLA fuses, where a [rows, n, n] array would
+    pad 32 times."""
+    n, f32 = cfg.hyper_lanes, jnp.float32
+    rows = x.reshape(-1, x.shape[-1])
+    phi = lp[f"hc_{which}_phi"].astype(x.dtype)
+    alpha, b = (lp[f"hc_{which}_{name}"].astype(f32)
+                for name in ("alpha", "b"))
+    r32 = rows.astype(f32)
+    scale = lax.rsqrt(jnp.mean(r32 * r32, axis=-1) + cfg.rms_eps)
+    u = lax.dot_general(rows, phi, (((1,), (1,)), ((), ())),
+                        preferred_element_type=f32)
+    u = (u * scale[:, None]).T                             # [n^2 + 2n, rows]
+    pre = jax.nn.sigmoid(alpha[0] * u[:n] + b[:n, None])
+    post = 2.0 * jax.nn.sigmoid(alpha[1] * u[n:2 * n] + b[n:2 * n, None])
+    m = jnp.exp(jnp.clip(alpha[2] * u[2 * n:] + b[2 * n:, None],
+                         *cfg.hyper_clamp))
+    m = [m[j * n:(j + 1) * n] for j in range(n)]        # out lane j: [n, rows]
+    eps = cfg.hyper_eps
+    for _ in range(cfg.hyper_sinkhorn_iters):
+        col = functools.reduce(jnp.add, m) + eps
+        m = [mj / col for mj in m]
+        m = [mj / (functools.reduce(
+            jnp.add, [mj[i:i + 1] for i in range(n)]) + eps) for mj in m]
+    return jnp.concatenate([pre, post, *m]).T
+
+
+def hyper_pre(x: jax.Array, c: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """What a sub-block sees of the stream x [..., n d]: sum_i c[:, i] x_i
+    [..., d], float32 multiply-adds over the lanes' slices."""
+    lanes = _lanes(x.reshape(-1, x.shape[-1]), cfg)
+    h = functools.reduce(jnp.add, [c[:, i:i + 1] * lane
+                                   for i, lane in enumerate(lanes)])
+    return h.astype(x.dtype).reshape(*x.shape[:-1], cfg.hidden_size)
+
+
+def hyper_post(x: jax.Array, y: jax.Array, c: jax.Array,
+               cfg: LlamaConfig) -> jax.Array:
+    """The stream behind a sub-block whose output is y [..., d]: lane j is
+    sum_i M[j, i] x_i + post_j y (coefficients c of `hyper_coeff`)."""
+    n, d = cfg.hyper_lanes, cfg.hidden_size
+    lanes = _lanes(x.reshape(-1, n * d), cfg)
+    y32 = y.reshape(-1, d).astype(jnp.float32)
+    col = lambda k: c[:, k:k + 1]
+    out = [functools.reduce(jnp.add, [
+        col(2 * n + j * n + i) * lanes[i] for i in range(n)])
+        + col(n + j) * y32 for j in range(n)]
+    return jnp.concatenate(out, axis=-1).astype(x.dtype).reshape(x.shape)
+
+
+def residual(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+             which: str):
+    """The two ends of the residual seam around sub-block `which` ("attn" |
+    "mlp") of one layer: (h, out) with h what the sub-block's norm reads
+    of the stream x and out(y) the stream behind the sub-block whose output
+    is y. The plain sum: h is x and out(y) is x + y, traced where the
+    caller writes it, as every block always did. With `hyper_lanes`: h =
+    `hyper_pre` of the lanes under the row's coefficients (scopes `hyper` >
+    `hyper_coeff`, `hyper_pre`, here) and out(y) = `hyper_post` (scope
+    `hyper` > `hyper_post`, inside whatever scope the caller adds y in).
+    Every `x = x + ...` of `block` and of the serving tick's layer loop
+    goes through here."""
+    if not cfg.hyper_lanes:
+        return x, lambda y: x + y
+    with jax.named_scope("hyper"):
+        with jax.named_scope("hyper_coeff"):
+            c = hyper_coeff(x, lp, cfg, which)
+        with jax.named_scope("hyper_pre"):
+            h = hyper_pre(x, c, cfg)
+
+    def out(y):
+        with jax.named_scope("hyper"), jax.named_scope("hyper_post"):
+            return hyper_post(x, y, c, cfg)
+
+    return h, out
 
 
 def attn_gated(o: jax.Array, h: jax.Array, lp: Dict[str, jax.Array]):
@@ -1496,7 +1671,8 @@ def scan_plan(cfg: LlamaConfig, body: Callable, carry, stacks):
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
             attn_impl: str = "auto", ffn_impl: str = "stock") -> jax.Array:
     """tokens [B, T] int32 → logits [B, T, vocab] (f32)."""
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    x = hyper_spread(jnp.take(params["embed"], tokens,
+                              axis=0).astype(cfg.dtype), cfg)
     T = tokens.shape[1]
     if cfg.layer_plan:
         kinds = cfg.kinds
@@ -1523,7 +1699,7 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
                          index_rope=irope), None
 
         x, _ = lax.scan(body, x, params["blocks"])
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    x = rms_norm(hyper_collapse(x, cfg), params["final_norm"], cfg.rms_eps)
     return (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
 
 

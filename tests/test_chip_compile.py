@@ -978,6 +978,70 @@ def test_kimi_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
     print("Kimi depth-6 GiB by tok_pad:", gib)
 
 
+def test_xing_depth6_ticks_fit_the_chip(one_chip, monkeypatch):
+    """The cell `serve_hyper_latent_mixed_4k` as the engine builds it on a
+    TPU (`available` steered true), from the configuration file itself:
+    both executables (a tick with a prefill chunk, 2,048 rows of a stream
+    of four lanes; a decode tick, 64 rows) compile for the described v5e
+    with the latent walks at 32 heads, every one of the 64 experts held and
+    the lanes' mixing in every layer, and the compiler counts each over
+    25 % and under the chip's 15.75 GiB with room for the float32 check."""
+    import json
+    import os
+
+    from benchmark.drivers import closed_loop_serve_hyper as D
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import llama as L
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "xing4.0-29b-a4b-serve.json")) as f:
+        file = json.load(f)
+    cfg, e = D.xing_config(file, jnp.bfloat16), file["engine"]
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert params["blocks"][1]["w1"].shape == (5, 64, 3584, 1024)
+    assert params["blocks"][1]["hc_mlp_phi"].shape == (5, 24, 14336)
+    from paddle_tpu.ops.pallas import paged_attention_latent as pl_
+    for module in (fa, pa, pl_):
+        monkeypatch.setattr(module, "available", lambda: True)
+    eng = PagedServingEngine(
+        cfg, params, num_blocks=e["num_blocks"], block_size=e["block_size"],
+        max_batch=e["max_batch"], token_budget=e["token_budget"],
+        max_len=e["max_len"], pallas=True, pallas_ffn=False)
+    assert eng._value_cache is None
+    assert eng._key_cache.shape == (6, 27648, 1, 16, 640)
+    build, gib = eng._build_step, {}
+
+    def compiled_not_run(tok_pad, B, *rest):
+        fn = build(tok_pad, B, *rest)
+
+        def tick(*args):
+            abstract = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip), args)
+            compiled = fn.lower(*abstract).compile()
+            text = compiled.as_text()
+            assert ("paged_attention_latent_mixed" in text) == (
+                tok_pad == 2048)
+            assert "paged_attention_latent_decode" in text
+            assert "hyper_post" in text and "hyper_coeff" in text
+            m = compiled.memory_analysis()
+            gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                            + m.temp_size_in_bytes
+                            - m.alias_size_in_bytes) / 2 ** 30
+            return (jnp.zeros((B + len(eng._moe_fields),), jnp.int32),
+                    args[1], args[2])
+        return tick
+
+    monkeypatch.setattr(eng, "_build_step", compiled_not_run)
+    eng.submit(list(range(1, 70)), max_new_tokens=4)
+    eng.step()                  # the prompt, one chunk
+    eng.step()                  # a decode row
+    assert set(gib) == {2048, 64}
+    assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
+    print("Xing depth-6 GiB by tok_pad:", gib)
+
+
 @pytest.mark.parametrize("launch", [
     "decode_window", "mixed_window", "decode_full", "mixed_full",
     "masked_full", "index", "index_decode", "write_index"])
