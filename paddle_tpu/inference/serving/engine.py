@@ -192,7 +192,8 @@ from ...core import flags
 from ...models import llama as L
 from ...observability import emit as _emit
 from ...observability import tracing as _tracing
-from ...ops.kernels.serving_attention import (paged_index_select,
+from ...ops.kernels.serving_attention import (index_select_form,
+                                              paged_index_select,
                                               paged_latent_attention,
                                               paged_layer_attention,
                                               sparse_walk_keys,
@@ -1537,11 +1538,17 @@ class PagedServingEngine:
             # read path: the page-write kernel beside the Pallas read, an
             # XLA row scatter on the stock path)
             # experts: the form `routed_ffn` computes the experts in
+            # index_select: the form of a sparse index's exact selection
+            # (`serving_attention.index_select_form`), "" without an index
             _emit("serving.step_build", tok_pad=tok_pad, batch=B,
                   ad_sig=list(ad_sig), spec=bool(spec_mode),
                   cache_write="pallas_pages" if self.pallas
                   else "scatter_rows",
                   experts=L.expert_form(self.cfg),
+                  index_select=index_select_form(
+                      self.max_blocks_per_seq * self.block_size, self.pallas)
+                  if any(s.sparse_index is not None for s in self.cfg.kinds)
+                  else "",
                   block_length=self.cfg.block_length,
                   latent=self.latent,
                   experts_held=list(self.cfg.experts_held))

@@ -966,6 +966,19 @@ def _by_row_blocks(fn, args, rows: int):
     return out.reshape(-1, *out.shape[2:])[:rows]
 
 
+def index_select_form(max_kv: int, use_pallas) -> str:
+    """The form `paged_index_select`'s exact selection takes over tables of
+    `max_kv` key positions, static by shape and read path: "launch" (the
+    Pallas read: `pallas.index_select.select_bits`, a block of rows'
+    scores fetched once and every counting pass in VMEM) wherever a row
+    block fits there, else "passes" (`sparse_index.select_topk`: the stock
+    path, and a table past a million positions). The engine names it in
+    `serving.step_build`."""
+    from ..pallas import index_select
+    return ("launch" if use_pallas and index_select.fits(max_kv)
+            else "passes")
+
+
 def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
                        seq_lens_this_time, cu_seqlens_q, block_tables,
                        topk: int, use_pallas=False):
@@ -995,17 +1008,25 @@ def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
     nothing lays a sequence's keys out by position and no layer of the
     pool is sliced or copied. False gathers every sequence's keys by
     position and scores each row against its own (the stock path: CPU
-    tests, the reference-side form). Scopes: `cache_write`, `index_scores`,
-    `index_select`. Returns (positions [tok, topk] int32 ascending, -1
-    behind a row's last and everywhere in a row that takes the dense walk;
-    the page of each selected key by its row's block table [tok, topk];
-    sparse [B] bool: the sequences whose rows were selected for; the
-    selection itself as `select_topk` made it, a bit a key position of the
+    tests, the reference-side form). Scopes: `cache_write`, `index_scores`
+    (the gather and the products, or the two launches), `index_select`:
+    the exact selection in the form `index_select_form` names (on the
+    Pallas read path the launch `index_select_bits`: a block of 8 rows'
+    scores fetched once, every counting pass in VMEM up to the keys the
+    block's rows see, the bits packed there; on the stock path
+    `select_topk`'s 32 passes and `cumsum`, then `pack_mask`), and on
+    both paths `selected_positions` over the packed bits
+    with each position's page. Returns (positions [tok, topk] int32
+    ascending, -1 behind a row's last and everywhere in a row that takes
+    the dense walk; the page of each selected key by its row's block table
+    [tok, topk]; sparse [B] bool: the sequences whose rows were selected
+    for; the selection itself as `select_topk` makes it, a bit a key of the
     table (`sparse_index.pack_mask`: [tok, blocks of 128 keys, 4] uint32,
     the words the positions are counted from), which the masked walk reads
     where the gather reads the positions; pool). `block_size` divides
     128."""
     from ..pallas import paged_attention_latent as PL
+    from ..pallas.index_select import select_bits
     from . import sparse_index
     L_, num_blocks, _, bs, ID = pool.shape
     B, max_blocks = block_tables.shape
@@ -1054,16 +1075,21 @@ def paged_index_select(qi, w, ki_tok, pool, layer, seq_lens_decoder,
                         one, first, token_num + jnp.arange(B))].set(
                         rows, mode="drop", unique_indices=True)
         with jax.named_scope("index_select"):
-            visible = ((jnp.arange(max_kv)[None, :] <= tok_pos[:, None])
-                       & (tok_valid & sparse[tok_b])[:, None])
+            # the keys a row sees: those up to its own, of a valid row of a
+            # sequence that selects
+            seen = jnp.where(tok_valid & sparse[tok_b], tok_pos + 1, 0)
+            if index_select_form(max_kv, use_pallas) == "launch":
+                bits = select_bits(scores, seen, topk)[0]
+            else:
+                bits = sparse_index.pack_mask(sparse_index.select_topk(
+                    scores, jnp.arange(max_kv)[None, :] < seen[:, None],
+                    topk))
             # a row's table in eights (the pages of a block of 128 keys)
             # rides along, so that each selected key comes with its page
             # and the read looks nothing up
             per = sparse_index.BLOCK // bs
             mine = jnp.pad(block_tables, ((0, 0), (0, -max_blocks % per))
                            )[tok_b].reshape(token_num, -1, per)
-            bits = sparse_index.pack_mask(
-                sparse_index.select_topk(scores, visible, topk))
             pos, pages = sparse_index.selected_positions(bits, topk,
                                                          carry=mine)
             sub = jnp.maximum(pos, 0) % sparse_index.BLOCK // bs

@@ -17,6 +17,18 @@ form in which the engine hands a selection on), becomes positions by
 popcounts over blocks of 128 keys and one exact product that hands each
 slot its block (`selected_positions`): compares, adds, selects and a
 matmul.
+
+WHO RUNS `select_topk` (since PR 55). The stock path of
+`serving_attention.paged_index_select` (`use_pallas` False: every CPU
+test, the reference-side form) and the model's own forward
+(`models.llama`). Each of its 32 passes reads all of [rows, keys] from HBM
+and its ties take a `cumsum` over the keys, so on the Pallas read path the
+same rule runs as one launch that holds a block of rows in VMEM
+(`ops/pallas/index_select.select_bits`: the k-th key, the ties' cut and the
+selection already in `pack_mask`'s bits), `select_topk`'s set bit for bit
+(`tests/test_dots3_paged.py`). `pack_mask` stays the stock path's and the
+format's definition; `selected_positions` and `index_scores` serve both
+paths.
 """
 from __future__ import annotations
 
@@ -58,7 +70,10 @@ def select_topk(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
     of a row that sees at most k. Returns the selected set as a mask
     [T, S]. The k-th largest order key is the largest u with at least k
     keys >= u, built from its top bit down; everything above it is taken,
-    and of the keys equal to it the first by position that still fit."""
+    and of the keys equal to it the first by position that still fit. The
+    rule of the selection wherever it runs, and the form the stock path and
+    the model's forward run; a tick on the Pallas read path runs
+    `pallas.index_select.select_bits`, which is tested against this."""
     u = jnp.where(visible, _order_key(scores), jnp.uint32(0))
 
     def bit(i, best):

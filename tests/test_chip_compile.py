@@ -1159,6 +1159,30 @@ def test_run_copy_launches_compile_at_keye_shapes(one_chip, launch):
     assert name in _compile(fn, one_chip, *shapes).as_text()
 
 
+@pytest.mark.parametrize("rows, keys", [
+    (16, 65536), (256, 65536), (2048, 65536),       # Keye: decode, turn, chunk
+    (32, 33280), (2048, 33280)])                    # dots3: decode, chunk
+def test_the_selection_launch_compiles_at_both_index_cells_shapes(
+        one_chip, rows, keys):
+    """`index_select.select_bits` (PR 55: the search for the k-th
+    order key and the ties' cut over a block of 8 rows' scores held in
+    VMEM) at every executable's [rows, max_len] of the two index cells, k
+    2,048: a chunk of a pass is 16 lane tiles at 65,536 keys and 13 at
+    33,280 (260 tiles), and what the launch holds (the scores' block twice,
+    the order keys once) stays under the 16 MiB a kernel has without asking
+    for more."""
+    from paddle_tpu.ops.pallas import index_select as ps
+
+    def fn(scores, seen):
+        return ps.select_bits(scores, seen, 2048, interpret=False)
+
+    assert ps._vmem_bytes(keys) == 3 * 8 * 4 * keys + (4 << 20) < 16 << 20
+    assert ps._chunk(keys) == 128 * (16 if keys == 65536 else 13)
+    text = _compile(fn, one_chip, ((rows, keys), jnp.float32),
+                    ((rows,), jnp.int32)).as_text()
+    assert "index_select_bits" in text
+
+
 def test_the_latent_walk_without_a_mask_is_the_kernel_it_was():
     """`latent_attention_packed` without a mask (Kimi's chunk walk, dots3's
     window walks and dense first chunk) launches with the parent's
@@ -1262,6 +1286,7 @@ def test_dots3_depth5_ticks_fit_the_chip(one_chip, monkeypatch):
             # (PR 45): no relayout of the stacked index pool, no gather of
             # every sequence's 33,280 index keys
             assert "paged_index_scores_decode" in text
+            assert "index_select_bits" in text     # (PR 55)
             assert "bf16[137216,2048]" not in text
             assert "bf16[32,33280,128]" not in text
             m = compiled.memory_analysis()
@@ -1432,6 +1457,7 @@ def test_keye_depth4_ticks_fit_the_chip(one_chip, monkeypatch):
                 assert (name in text) == (tok_pad > 16), name
             assert "paged_attention_decode_masked" in text
             assert "paged_index_scores_decode" in text
+            assert "index_select_bits" in text     # (PR 55)
             # no layout change of a pool, no copy of one
             assert "bf16[4,67584,4,16,128]{4,2,3,1,0" not in text
             m = compiled.memory_analysis()

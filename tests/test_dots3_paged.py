@@ -290,6 +290,110 @@ def test_the_selection_is_the_stable_sort_s_with_ties_to_the_lower_position():
         assert np.all(pos[t, len(mine):] == -1)
 
 
+def _order_keys(x):
+    """`sparse_index._order_key` by hand (numpy)."""
+    b = np.where(x == 0.0, np.float32(0.0), x).astype(np.float32).view(
+        np.int32)
+    return (b ^ ((b >> 31) & 0x7FFFFFFF)).view(np.uint32) ^ np.uint32(
+        0x80000000)
+
+
+@pytest.mark.parametrize("scores", ["ties", "zeros", "signed"])
+@pytest.mark.parametrize("T, S, k", [(1, 256, 4), (16, 4096, 64),
+                                     (31, 33280, 2048)])
+def test_the_selection_launch_finds_select_topk_s_set_bit_for_bit(T, S, k,
+                                                                  scores):
+    """`pallas.index_select.select_bits` (interpreter) against
+    `select_topk`: its bits are `pack_mask` of `select_topk`'s mask, word
+    for word; its `kth` is the k-th largest order key a row sees (0 where
+    it sees fewer than k); and the mask its two words a row stand for (the
+    keys above `kth`, and of those equal to it the ones at positions <=
+    `cut`) is that mask too. Scores from a set of nine with many exact
+    ties across the k-th key, so that the position passes decide ("ties");
+    mostly -0.0 and +0.0, one key ("zeros"); distinct negative and positive
+    floats, where no block of rows needs a position pass ("signed"). Rows
+    that see nothing, fewer than k, exactly k and every key; T of 1, 16 and
+    31 (a padded row block behind them); S a power of two and dots3's
+    33,280 (260 lane tiles: chunks of 13, and 4 of the 32 tiles of the
+    last tile of words)."""
+    from paddle_tpu.ops.pallas import index_select as PS
+    rng = np.random.default_rng(T + S)
+    if scores == "ties":
+        x = rng.choice([-2.0, -0.0, 0.0, 0.5, 0.5, 1.0, 3.0, 7.25, -1e30],
+                       (T, S))
+    elif scores == "zeros":
+        x = np.where(rng.random((T, S)) < 0.9, rng.choice([0.0, -0.0], (T, S)),
+                     rng.normal(size=(T, S)))
+    else:
+        x = rng.normal(size=(T, S)) * 1e3
+    x = x.astype(np.float32)
+    seen = rng.integers(k + 1, S + 1, T)
+    seen[[0, T // 4, T // 2, T - 1]] = (S, k, k - 1, 0)[:4] if T > 1 else S
+    visible = np.arange(S)[None, :] < seen[:, None]
+    want = SI.select_topk(jnp.asarray(x), jnp.asarray(visible), k)
+    bits, kth, cut = PS.select_bits(jnp.asarray(x), jnp.asarray(seen), k,
+                                    interpret=True)
+    assert bits.dtype == kth.dtype == jnp.uint32
+    assert bits.shape == (T, S // 128, 4) and kth.shape == cut.shape == (T,)
+    assert np.array_equal(np.asarray(bits), np.asarray(SI.pack_mask(want)))
+    want, kth, cut = np.asarray(want), np.asarray(kth), np.asarray(cut)
+    u = np.where(visible, _order_keys(x), 0)
+    by_hand = np.where(seen >= k, np.sort(u, axis=1)[:, S - k], 0)
+    assert np.array_equal(kth, by_hand)
+    pos = np.arange(S)[None, :]
+    assert np.array_equal(want, visible & (
+        (u > kth[:, None]) | ((u == kth[:, None]) & (pos <= cut[:, None]))))
+    assert np.array_equal(want.sum(1), np.minimum(seen, k))
+    # the cut is a position only where a row's ties do not all fit
+    over = (u == by_hand[:, None]).sum(1) * (seen >= k) > k - (
+        u > by_hand[:, None]).sum(1)
+    assert (scores == "signed") <= (not over.any())
+    assert (scores == "ties") <= bool(over.any())
+    assert np.all(cut[~over] >= S - 1) and np.all(cut[over] < seen[over])
+
+
+@pytest.mark.parametrize("use_pallas", [True, "decode"])
+def test_the_index_select_of_the_kernels_path_hands_on_the_stock_path_s_sets(
+        use_pallas):
+    """`paged_index_select` end to end on a small table (two layers, the
+    second one's pages; tables out of order; sequences that select, one
+    that holds exactly `topk` keys and one without a row): through the
+    index launches and the selection launch (interpreter) the selected
+    sets, as bits and as positions with their pages, are the stock path's
+    (`select_topk`), which the form's name says too."""
+    rng = np.random.default_rng(11)
+    bs, ID, IH, nb, mb, topk = 8, 16, 4, 64, 16, 8
+    ends = np.array([40, 64, 121, topk, 0])
+    this = (np.array([1, 1, 1, 1, 0]) if use_pallas == "decode"
+            else np.array([9, 1, 5, 4, 0]))
+    past = ends - this
+    tok = 8 if use_pallas == "decode" else 24
+    pool = jnp.asarray(rng.normal(size=(2, nb, 1, bs, ID)), jnp.float32)
+    tables = np.full((5, mb), -1, np.int32)
+    free = rng.permutation(nb)
+    for b, n in enumerate(-(-ends // bs)):
+        tables[b, :n], free = free[:n], free[n:]
+    # scores in eighths: exact ties across a row's k-th key
+    qi = jnp.asarray(rng.integers(-2, 3, (tok, IH, ID)), jnp.float32)
+    w = jnp.asarray(rng.integers(1, 3, (tok, IH)) / 8, jnp.float32)
+    ki = jnp.asarray(rng.integers(-2, 3, (tok, ID)), jnp.float32)
+    pool = jnp.round(pool)
+    cu = np.concatenate([[0], np.cumsum(this)]).astype(np.int32)
+    args = (qi, w, ki, pool, jnp.int32(1), jnp.asarray(past),
+            jnp.asarray(this), jnp.asarray(cu), jnp.asarray(tables), topk)
+    assert SA.index_select_form(mb * bs, use_pallas) == "launch"
+    assert SA.index_select_form(mb * bs, False) == "passes"
+    stock = SA.paged_index_select(*args, use_pallas=False)
+    mine = SA.paged_index_select(*args, use_pallas=use_pallas)
+    for name, a, b in zip(("positions", "pages", "sparse", "bits", "pool"),
+                          stock, mine):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    pos = np.asarray(mine[0])
+    assert np.array_equal(np.asarray(mine[2]), ends * (this > 0) > topk)
+    assert ((pos >= 0).sum(1)[:cu[3]] == topk).all() and (pos[cu[3]:] < 0
+                                                            ).all()
+
+
 @pytest.mark.parametrize("decode", [True, False])
 def test_the_layer_ops_select_the_reference_s_sets_and_read_them(decode):
     """The cell's direct check of a full layer's ops at the fixture's
@@ -347,18 +451,29 @@ def test_lower_precision_fails_the_direct_check(what, monkeypatch):
     elif what == "pages in 8 bits":
         corrupt = eight_bits("rows")
     else:
-        exact = SI.select_topk
+        from paddle_tpu.ops.pallas import index_select as PS
+        passes, launch = SI.select_topk, PS.select_bits
 
-        def nearly(scores, visible, k):
-            mask = exact(scores, visible, k)
+        def nearly(mask, scores, visible):
             lost = jnp.argmin(jnp.where(mask, scores, jnp.inf), axis=1)
             got = jnp.argmax(jnp.where(visible & ~mask, scores, -jnp.inf),
                              axis=1)
             rows = jnp.arange(mask.shape[0])
-            full = jnp.sum(visible, axis=1) > k
+            full = jnp.sum(visible, axis=1) > jnp.sum(mask, axis=1)
             return (mask.at[rows, lost].set(~full & mask[rows, lost])
                     .at[rows, got].set(full | mask[rows, got]))
-        monkeypatch.setattr(SI, "select_topk", nearly)
+
+        def nearly_bits(scores, seen, k, **kw):
+            bits, kth, cut = launch(scores, seen, k, **kw)
+            S = scores.shape[1]
+            return SI.pack_mask(nearly(
+                SI.unpack_mask(bits)[:, :S] > 0, scores,
+                jnp.arange(S)[None] < seen[:, None])), kth, cut
+        # both forms of the selection (the Pallas read takes the launch)
+        monkeypatch.setattr(SI, "select_topk", lambda scores, visible, k:
+                            nearly(passes(scores, visible, k), scores,
+                                   visible))
+        monkeypatch.setattr(PS, "select_bits", nearly_bits)
     # a tick with a chunk: thirty rows that select
     case = D.op_case(TINY, 7, jnp.bfloat16, "full_attention", False)
     res = D.op_outputs(TINY, case, False, corrupt)
