@@ -152,7 +152,15 @@ def require_trainable(cfg: L.LlamaConfig, dp: int = 1, pp: int = 1,
             ("a router bias (router_bias)", cfg.router_bias),
             ("QK-norm (qk_norm)", cfg.qk_norm),
             ("block diffusion (block_length)", cfg.block_length),
-            ("hyper-connections (hyper_lanes)", cfg.hyper_lanes)):
+            ("hyper-connections (hyper_lanes)", cfg.hyper_lanes),
+            ("state-space layers (LayerSpec.attn = 'ssm': the chunked "
+             "scan has no backward pass here)",
+             any(s.attn == "ssm" for s in cfg.layers)),
+            ("a layer without a rope, a layer's own softmax scale, "
+             "embed_scale, residual_scale, logit_divisor or a tied head",
+             any(s.rope is None or s.softmax_scale for s in cfg.layers)
+             or cfg.embed_scale != 1 or cfg.residual_scale != 1
+             or cfg.logit_divisor != 1 or cfg.tie_embeddings)):
         if has:
             raise NotImplementedError(f"{what} does not take {name}")
     if cfg.layer_plan and pp > 1:

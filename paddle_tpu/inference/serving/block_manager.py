@@ -30,7 +30,16 @@ of per-slot max_len reservations:
   no query to come can see them. The table keeps its logical width, the
   released entries read -1. Such pages hold the keys of the window layers
   alone, so the prefix cache is off: a hit on the full pool's pages would
-  find no window keys beside them.
+  find no window keys beside them;
+- for a model with recurrent state (state-space layers), a **second kind
+  of cache**: `state_slots` slots, one a running sequence, taken where the
+  sequence's pages are mapped (`allocate_sequence` calls `take_slot`: no
+  free slot is no admission, as no free page is) and given back with them
+  (`free_sequence`: finish, cancel, preemption). A slot holds a sum over
+  the whole sequence, which no page hit can restore, so the prefix cache
+  is off here too and a preempted sequence is recomputed from its ids from
+  a zero state. The engine owns the state arrays; a slot is an index into
+  them.
 
 Pure host-side bookkeeping: no jax imports, no device state. The engine
 owns the actual [num_blocks, KV, block_size, hd] cache arrays; block ids
@@ -57,7 +66,8 @@ class BlockManager:
     def __init__(self, num_blocks: int, block_size: int,
                  page_bytes: int = 0, hit_multiple: int = 1,
                  window_blocks: int = 0, window: int = 0,
-                 window_page_bytes: int = 0):
+                 window_page_bytes: int = 0, state_slots: int = 0,
+                 state_slot_bytes: int = 0):
         if num_blocks < 1 or block_size < 1:
             raise ValueError(f"need num_blocks>=1 and block_size>=1, got "
                              f"{num_blocks}/{block_size}")
@@ -89,6 +99,13 @@ class BlockManager:
         self._wfree: List[int] = list(range(self.window_blocks))[::-1]
         self._wtables: Dict[int, List[int]] = {}
         self._wreleased: Dict[int, int] = {}
+        # the state slots (0: the model has no recurrent state and nothing
+        # below differs from what it was): the free ones and each
+        # sequence's
+        self.state_slots = int(state_slots)
+        self.state_slot_bytes = int(state_slot_bytes)
+        self._sfree: List[int] = list(range(self.state_slots))[::-1]
+        self._slots: Dict[int, int] = {}
         self._free: List[int] = list(range(num_blocks))[::-1]  # pop() = lowest
         self._refs: Dict[int, int] = {}
         # content-addressed full blocks: chain hash -> block id, the inverse
@@ -148,21 +165,42 @@ class BlockManager:
         plus any registered extra paged residency (adapter slot packs)."""
         extra = self.extra_bytes()[1] if self.extra_bytes else 0
         return (self.num_blocks * self.page_bytes
-                + self.window_blocks * self.window_page_bytes + extra)
+                + self.window_blocks * self.window_page_bytes
+                + self.state_slots * self.state_slot_bytes + extra)
 
     def bytes_in_use(self) -> int:
         """Device bytes behind allocated pages, dtype-aware, plus any
         registered extra paged residency (adapter slot packs)."""
         extra = self.extra_bytes()[0] if self.extra_bytes else 0
         return (self.num_allocated() * self.page_bytes
-                + self.window_allocated() * self.window_page_bytes + extra)
+                + self.window_allocated() * self.window_page_bytes
+                + self.slots_live() * self.state_slot_bytes + extra)
 
     def blocks_needed(self, num_tokens: int) -> int:
         return -(-int(num_tokens) // self.block_size)
 
-    def can_allocate(self, n_blocks: int, n_window: int = 0) -> bool:
+    def can_allocate(self, n_blocks: int, n_window: int = 0,
+                     n_slots: int = 0) -> bool:
         return (self.num_free() >= n_blocks
-                and len(self._wfree) >= n_window)
+                and len(self._wfree) >= n_window
+                and len(self._sfree) >= n_slots)
+
+    # -- state slots ------------------------------------------------------
+    def slots_live(self) -> int:
+        return self.state_slots - len(self._sfree)
+
+    def take_slot(self, seq_id: int) -> int:
+        """Give `seq_id` a free state slot (what it holds is stale: the
+        sequence starts from zeros by its `past == 0`, not by a clear)."""
+        if not self._sfree:
+            raise NoFreeBlocksError(
+                f"no free state slot for sequence {seq_id}: all "
+                f"{self.state_slots} hold a running sequence")
+        self._slots[seq_id] = self._sfree.pop()
+        return self._slots[seq_id]
+
+    def slot_of(self, seq_id: int) -> int:
+        return self._slots[seq_id]
 
     def growth(self, seq_id: int, num_tokens: int) -> Tuple[int, int]:
         """(pages of the pool, pages of the window pool) that
@@ -240,18 +278,25 @@ class BlockManager:
             # a list is read as it stands (the scheduler's is `submit`'s,
             # Python ints; numpy's integers hash and compare as theirs)
             tokens = [int(t) for t in tokens]
-        if self.window_blocks:
-            # no prefix cache beside a window pool; the window table fills
-            # a chunk at a time (`ensure_capacity`)
+        if self.window_blocks or self.state_slots:
+            # no prefix cache beside a window pool (the window table fills
+            # a chunk at a time, `ensure_capacity`) nor beside recurrent
+            # state (the sequence takes its slot here)
             need = self.blocks_needed(len(tokens))
-            if not self.can_allocate(need):
+            slot = int(bool(self.state_slots))
+            if not self.can_allocate(need, n_slots=slot):
                 raise NoFreeBlocksError(
                     f"cannot map sequence {seq_id}: {need} blocks "
-                    f"({self.num_free()} free)")
+                    f"({self.num_free()} free)"
+                    + (f", a state slot ({len(self._sfree)} free)"
+                       if slot else ""))
             self._tables[seq_id] = [self._alloc_block() for _ in range(need)]
             self._hashed[seq_id] = (0, 0)
-            self._wtables[seq_id] = []
-            self._wreleased[seq_id] = 0
+            if self.window_blocks:
+                self._wtables[seq_id] = []
+                self._wreleased[seq_id] = 0
+            if slot:
+                self.take_slot(seq_id)
             return 0
         bs = self.block_size
         table: List[int] = []
@@ -403,7 +448,7 @@ class BlockManager:
         `num_computed` has to be known."""
         bs = self.block_size
         table = self._tables.get(seq_id)
-        if table is None or self.window_blocks:
+        if table is None or self.window_blocks or self.state_slots:
             return
         bi, prev_h = self._hashed[seq_id]
         full = min(num_computed, len(tokens)) // bs
@@ -424,6 +469,8 @@ class BlockManager:
         self._wreleased.pop(seq_id, None)
         self._wfree.extend(p for p in self._wtables.pop(seq_id, ())
                            if p >= 0)
+        if seq_id in self._slots:
+            self._sfree.append(self._slots.pop(seq_id))
         if not table:
             return
         if self._pending_copies:

@@ -171,6 +171,37 @@ refusals stand and by name: int8 pages, weight quantisation, LoRA adapters,
 a draft model, the fused FFN (``__init__``, ``submit``, ``_resolve_ffn``)
 and ``extract_pages`` / ``ingest_pages`` (``_refuse_page_handoff``).
 
+A layer may keep NO keys at all: a state-space layer (``LayerSpec.attn =
+"ssm"``: Mamba-2, granite-4.0-h's 36 of 40 layers) carries a recurrent state
+a sequence, not a row a token. It lives in a STATE POOL beside the page pools
+(``_state``: ``[L_ssm, slots + 1, H, P, N]`` in ``llama.SSM_STATE_DTYPE``,
+float32, and ``[L_ssm, slots + 1, (d_conv - 1) conv_dim]`` of the convolution's
+carried rows; the last slot is the void one that idle batch entries write),
+donated and carried through the tick with them. A running sequence owns one
+of ``max_batch`` slots (``BlockManager(state_slots=...)``: taken at
+admission, given back at finish, cancel and preemption); ``_launch`` sends each batch entry's slot,
+and the layer loop's body of that kind is ``llama.ssm_mixer`` (scopes ``ssm``
+> ``ssm_in``, ``ssm_conv``, ``ssm_step``, ``ssm_scan``, ``ssm_gate``,
+``ssm_out``; beside the kernel the one-row update is the launch of
+``ops/pallas/ssm_step.py``): a segment starts from its slot's state, or from zeros where it
+has nothing behind it, whatever the slot holds, so admission and preemption
+cost no device operation, and a preempted sequence is recomputed from its
+ids. The page pools hold the attention layers alone. A page hit cannot
+restore a state, so the prefix cache is off (``engine_stats["prefix_cache"]``
+says so) and copy-on-write never runs. Launch-ahead stays on: the next tick
+is determined by counts, and the state is on the device in tick order. The
+step span carries ``ssm_step_rows`` (one-row segments x state-space layers),
+``ssm_scan_rows`` and ``ssm_segments`` (the longer segments' rows and count,
+x layers) and ``state_slots_live``; ``stats`` sums them, ``engine_stats``
+has ``state_slots``, ``state_bytes_total`` and ``state_bytes_in_use``. Such a
+config may also state a layer of heads' softmax scale, have no rope, scale
+the embedded row, every sub-block's output and the logits, and tie its head
+(``llama.embedded``, ``llama.residual``, ``llama.head_logits``). Refused with
+state-space layers, where a written plan's refusals stand and by name: int8
+pages, weight quantisation, LoRA adapters, a draft model (a rejected draft
+would need the state rolled back), the fused FFN and ``extract_pages`` /
+``ingest_pages`` (the payload carries no state).
+
 SLO metrics (TTFT/TPOT histograms, queue-depth and KV-block-utilization
 gauges, admit/preempt/shed counters + flight-recorder events) flow
 through ``observability.emit`` — ``observability.summary()["serving"]``
@@ -288,6 +319,7 @@ class _Tick:
     pool_pages: Tuple[int, int] = (0, 0)
     sampled_rows: int = 0           # rows with a temperature: 0 skips the sort
     tables: Any = None              # under a sparse index: the full pool's
+    slots_live: int = 0             # state slots that held a sequence
 
 
 def _in_window_pool(spec: "L.LayerSpec") -> bool:
@@ -308,7 +340,8 @@ def _pool_plan(cfg: "L.LlamaConfig"):
     one window and one index geometry; a plan that asks for two of a kind
     in one pool is refused."""
     window = [s for s in cfg.layers if _in_window_pool(s)]
-    full = [s for s in cfg.layers if not _in_window_pool(s)]
+    full = [s for s in cfg.layers
+            if not _in_window_pool(s) and s.attn != "ssm"]
 
     def one(values, what):
         values = set(values)
@@ -328,7 +361,7 @@ def _pool_plan(cfg: "L.LlamaConfig"):
         raise NotImplementedError(
             f"index layers of two geometries {sorted(index)}: their keys "
             "share the full layers' pages, one width a position")
-    return ((cfg.num_layers - len(window), len(window)),
+    return ((len(full), len(window)),
             (width(full), width(window)),
             one([s.latent.window if s.latent else cfg.sliding_window
                  for s in window], "windows"),
@@ -420,6 +453,8 @@ class PagedServingEngine:
         indexed = any(s.index is not None for s in cfg.layers)
         # and hyper-connections (`cfg.hyper_lanes`), under a plan or not
         hyper = bool(cfg.hyper_lanes)
+        # and state-space layers (always under a plan)
+        ssm = {s.ssm for s in cfg.layers} - {None}
         if plan or indexed or hyper:
             asked = {"a draft model": draft is not None,
                      "pallas_ffn": bool(pallas_ffn),
@@ -429,9 +464,9 @@ class PagedServingEngine:
                          if quant_kv is None else quant_kv),
                      "adapter_slots (LoRA)": adapter_slots is not None}
             if any(asked.values()):
-                what = ("hyper-connections" if hyper else "a layer plan"
-                        if plan else "a sparse index over the heads' own "
-                        "keys and values")
+                what = ("state-space layers" if ssm else "hyper-connections"
+                        if hyper else "a layer plan" if plan else
+                        "a sparse index over the heads' own keys and values")
                 raise NotImplementedError(
                     f"a config with {what} is served in fp weights and "
                     "fp pages, one token a row: "
@@ -523,9 +558,41 @@ class PagedServingEngine:
         # kinds, the one with a window in the window pool at its own
         # width; the full kind's pages carry its index keys as a second
         # row a position (`_pool_plan`)
+        # the attention read, decided here and for good: None = the kernel
+        # where it runs and takes this geometry (PA.selected), the stock
+        # path elsewhere; True = force (interpret mode off-TPU — how CPU CI
+        # drives it; a bad geometry fails here); False = the stock
+        # reference
+        paged = [s for s in cfg.layers if s.attn != "ssm"]
+        geometry = (max(s.heads for s in paged), cfg.num_kv_heads,
+                    cfg.head_dim, self.block_size)
+        if pallas and not PA.supported(*geometry):
+            raise ValueError(
+                f"pallas=True forced but geometry H={cfg.num_heads} "
+                f"KV={cfg.num_kv_heads} hd={cfg.head_dim} "
+                f"block_size={self.block_size} is not supported() by the "
+                f"paged-attention kernel")
+        self.pallas = bool(PA.selected(*geometry) if pallas is None
+                           else pallas)
         self.latent = any(s.attn == "latent" for s in cfg.layers)
         (self._pool_layers, self._row_widths, self.window,
          self._index) = _pool_plan(cfg)
+        # Beside the kernel a head's row of a page is in WHOLE LANES where
+        # that at most doubles it (a head of 64 in 128, as a latent row is
+        # padded: `paged_attention_latent.padded_width`): the device tiles
+        # a row of 64 into 128 lanes anyway or, left to lay the pool out
+        # itself, puts the pages innermost and re-lays both pools out on
+        # the way into and out of every tick; and only whole lanes take the
+        # walks that copy whole pages (`paged_attention.whole_pages`; the
+        # BlockSpec walk took 72 % of granite-4.0-h-micro's tick at 64:
+        # PERF.md section 6, PR 56). The lanes behind a head hold zeros:
+        # `paged_layer_attention(head_dim=...)` pads q, k and v and cuts the
+        # output back, the scores and the values are what they were
+        self._head_width, lanes = cfg.head_dim, PL.padded_width(cfg.head_dim)
+        if (self.pallas and not self.latent
+                and cfg.head_dim < lanes <= 2 * cfg.head_dim):
+            self._head_width = lanes
+            self._row_widths = (lanes, lanes)
         # layers whose pages live in the pool and in the window pool (a
         # config without window layers: all of them, and none)
         n_window = self._pool_layers[1]
@@ -543,6 +610,23 @@ class PagedServingEngine:
         hd = self._row_widths[0]
         self.kv_page_bytes = (self._pool_layers[0] * full_bytes
                               + n_window * window_bytes)
+        # recurrent state: one slot a running sequence in a pool stacked
+        # over the state-space layers (the module's docstring)
+        if len(ssm) > 1:
+            raise NotImplementedError(
+                f"state-space layers of two geometries {sorted(map(str, ssm))}"
+                ": their states share one pool, and no model served has "
+                "them")
+        self._ssm = next(iter(ssm), None)
+        self._ssm_layers = sum(s.ssm is not None for s in cfg.layers)
+        self.state_slots = self.max_batch if self._ssm else 0
+        self.state_slot_bytes = 0
+        if self._ssm:
+            sm = self._ssm
+            self.state_slot_bytes = self._ssm_layers * (
+                sm.inner * sm.d_state * np.dtype(L.SSM_STATE_DTYPE).itemsize
+                + (sm.d_conv - 1) * sm.conv_dim
+                * np.dtype(cfg.dtype).itemsize)
         if self.quant_kv:
             self.kv_page_bytes += 2 * cfg.num_layers * kvh * 4
         if n_window:
@@ -566,7 +650,9 @@ class PagedServingEngine:
             page_bytes=self._pool_layers[0] * full_bytes
             if n_window else self.kv_page_bytes,
             hit_multiple=max(Bd, 1), window_blocks=self.window_blocks,
-            window=self.window, window_page_bytes=n_window * window_bytes)
+            window=self.window, window_page_bytes=n_window * window_bytes,
+            state_slots=self.state_slots,
+            state_slot_bytes=self.state_slot_bytes)
         self.scheduler = Scheduler(self.blocks, self.token_budget,
                                    self.max_batch,
                                    prefill_chunk=prefill_chunk,
@@ -603,6 +689,12 @@ class PagedServingEngine:
         if cfg.hyper_lanes:
             # rows x sub-blocks whose lanes were mixed, summed over ticks
             self.stats["hyper_rows"] = 0
+        if self._ssm:
+            # summed over ticks and state-space layers: the one-row
+            # segments, the longer segments' rows and their count; and the
+            # slots that held a sequence when a tick was launched
+            self.stats.update(ssm_step_rows=0, ssm_scan_rows=0,
+                              ssm_segments=0, state_slots_live=0)
         if self.latent:
             # keys and (row, key) pairs inside the causal mask, summed over
             # ticks and layers (`_plan_keys`), and pages allocated when a
@@ -668,21 +760,6 @@ class PagedServingEngine:
         register_distress_section("adapters", self.adapters.snapshot)
         if self.spec is not None:
             register_distress_section("spec", self.spec.snapshot)
-        # the attention read, decided here and for good: None = the kernel
-        # where it runs and takes this geometry (PA.selected), the stock
-        # path elsewhere; True = force (interpret mode off-TPU — how CPU CI
-        # drives it; a bad geometry fails here); False = the stock
-        # reference
-        geometry = (max(s.heads for s in cfg.layers), cfg.num_kv_heads,
-                    cfg.head_dim, self.block_size)
-        if pallas and not PA.supported(*geometry):
-            raise ValueError(
-                f"pallas=True forced but geometry H={cfg.num_heads} "
-                f"KV={cfg.num_kv_heads} hd={cfg.head_dim} "
-                f"block_size={self.block_size} is not supported() by the "
-                f"paged-attention kernel")
-        self.pallas = bool(PA.selected(*geometry) if pallas is None
-                           else pallas)
         if indexed and self.pallas and not PA.whole_pages(cfg.head_dim):
             raise NotImplementedError(
                 f"a sparse index over heads of {cfg.head_dim}: the masked "
@@ -691,11 +768,11 @@ class PagedServingEngine:
         # whether a tick's read is one of the whole-page walks (their
         # counters are reckoned only then) or the BlockSpec walk
         self._whole_pages = (self.pallas and not self.latent
-                             and PA.whole_pages(cfg.head_dim))
+                             and PA.whole_pages(self._head_width))
         # the (query heads, window) of each attention launch a tick makes
         self._launches = tuple(dict.fromkeys(
             (s.heads, self.window if _in_window_pool(s) else 0)
-            for s in cfg.layers))
+            for s in paged))
         # fused-FFN routing mirrors the attention tri-state: None =
         # FLAGS_pallas_ffn per tick; True = force (interpret off-TPU);
         # False = off. Forced mode validates params + geometry eagerly.
@@ -743,6 +820,10 @@ class PagedServingEngine:
             self._value_cache = (self._value_cache,
                                  None if self.latent
                                  else jnp.zeros(wshape, self.cache_dtype))
+        # the state pools of the state-space layers, donated and carried
+        # like the page pools: (recurrent state, convolution rows)
+        self._state = self._ssm and L.ssm_state_pools(
+            self._ssm, self._ssm_layers, self.state_slots, cfg.dtype)
         if self.quant_kv:
             # static calibrated absmax per (layer, kv head) -> per-head
             # quant multipliers [L, KV] for the append path and GENUINELY
@@ -774,7 +855,9 @@ class PagedServingEngine:
             return jnp.stack([jnp.concatenate([cos, cos], -1)[None],
                               jnp.concatenate([sin, sin], -1)[None]])
         widths = {s.rope: cfg.rope_width(s) for s in cfg.kinds[::-1]}
-        self._ropes = tuple(dict.fromkeys(s.rope for s in cfg.kinds))
+        # (a layer without a rope has no table)
+        self._ropes = tuple(dict.fromkeys(
+            s.rope for s in cfg.kinds if s.rope is not None))
         self._rope_emb = tuple(
             rope_emb(*L.rope_table(jnp.arange(self.max_len), widths[r], r))
             for r in self._ropes)
@@ -870,9 +953,9 @@ class PagedServingEngine:
                                         or self._index is not None
                                         or self.cfg.hyper_lanes):
                 raise NotImplementedError(
-                    "LoRA adapters with a layer plan, a sparse index or "
-                    "hyper-connections were never judged against a "
-                    "reference; submit without one")
+                    "LoRA adapters with a layer plan, a sparse index, "
+                    "hyper-connections or state-space layers were never "
+                    "judged against a reference; submit without one")
             if total > self.max_len:
                 raise ValueError(
                     f"prompt {len(tokens)} + new {max_new_tokens} "
@@ -992,6 +1075,12 @@ class PagedServingEngine:
         return out
 
     def _refuse_page_handoff(self, what: str):
+        if self._ssm:
+            raise NotImplementedError(
+                f"{what} with state-space layers: a sequence's recurrent "
+                "state lives in a slot of the state pool, which the "
+                "hand-off's payload (k, v) does not carry, and pages "
+                "adopted without it would continue from a zero state")
         if self.cfg.hyper_lanes:
             raise NotImplementedError(
                 f"{what} with hyper-connections (LlamaConfig.hyper_lanes): "
@@ -1182,16 +1271,21 @@ class PagedServingEngine:
                 rows = x if cu is None else x[block_rows(cu)]
                 h = L.rms_norm(L.hyper_collapse(rows, cfg),
                                params["final_norm"], cfg.rms_eps)
+                if cfg.tie_embeddings or cfg.logit_divisor != 1:
+                    return L.head_logits(params, h, cfg)
                 return Q.matmul_param(h, params, "lm_head"
                                       ).astype(jnp.float32)
 
         @functools.partial(jax.jit, donate_argnums=(1, 2),
-                           donate_argnames=("index_cache",))
+                           donate_argnames=("index_cache", "state"))
         def step_fn(params, key_cache, value_cache, kv_scales, tokens,
                     block_tables, cu_seqlens_q, seq_lens_decoder,
                     seq_lens_this_time, rope_emb, temps, top_ps, keys,
                     greedy, ad_args, quota=None, masked=None, prev=None,
-                    feed=None, index_cache=None):
+                    feed=None, index_cache=None, state=None, slots=None):
+            # `state`: the state-space layers' two pools (`_state`), given
+            # up and handed back behind the page arrays; `slots` [B] each
+            # batch entry's slot of them (an idle entry: the void one)
             # `index_cache`: the third page array of a config with a sparse
             # index over heads' own keys and values (`_index_cache`), given
             # up and handed back behind the two pools as they are
@@ -1224,18 +1318,19 @@ class PagedServingEngine:
             # `masked` [B, Bd] come with a block-diffusion tick alone
             # (`_unmask_rows` says what they are).
             with jax.named_scope("embed"):
-                x = L.hyper_spread(jnp.take(params["embed"], tokens,
-                                            axis=0).astype(cfg.dtype), cfg)
+                x = L.hyper_spread(L.embedded(params, tokens, cfg), cfg)
             # rows are packed from 0: what lies behind the last chunk is
             # padding, which no expert may see
             valid = jnp.arange(tok_pad) < cu_seqlens_q[B]
             with jax.named_scope("layers"):
-                x, kcs, vcs, ics, loads = self._layer_loop(
+                x, kcs, vcs, ics, state, loads = self._layer_loop(
                     params, x, key_cache, value_cache, index_cache,
                     kv_scales, ad_args, block_tables, cu_seqlens_q,
                     seq_lens_decoder, seq_lens_this_time, rope_emb, valid,
-                    use_pallas, ffn_mode)
+                    use_pallas, ffn_mode, state, slots, decode)
             pools = (kcs, vcs) if index_cache is None else (kcs, vcs, ics)
+            if state is not None:
+                pools += (state,)
             # last-token hidden state per slot, or its whole block's
             logits = head(params, x, cu_seqlens_q)         # [B (* Bd), V]
             with jax.named_scope("sample"):
@@ -1297,7 +1392,8 @@ class PagedServingEngine:
 
     def _layer_loop(self, params, x, key_cache, value_cache, index_cache,
                     kv_scales, ad_args, block_tables, cu, past, this,
-                    rope_emb, valid, use_pallas, ffn_mode):
+                    rope_emb, valid, use_pallas, ffn_mode, state=None,
+                    slots=None, one_row=False):
         """The tick's layers, of every config (`llama.scan_plan` over
         `cfg.kinds`; a uniform config is one kind, one `lax.scan` over its
         whole stack): one body a kind, which finds its pages in its
@@ -1322,10 +1418,15 @@ class PagedServingEngine:
         (`LayerSpec.index`) writes and scores its index keys in
         `index_cache` (`index_q`, `index_k`, `paged_index_select`'s own)
         and hands its selection to `paged_layer_attention`, which states
-        the rule of which read a row then takes.
-        Returns (x, key_cache, value_cache, index_cache, (experts hit,
-        largest load[, pairs on held experts, launches past their places])
-        or ())."""
+        the rule of which read a row then takes. A state-space layer
+        (`LayerSpec.attn = "ssm"`) has no pages: its body is
+        `llama.ssm_mixer` over the two pools of `state`, found by the
+        layer's place among the state-space layers and the batch entries'
+        `slots`; `one_row` is the decode tick's promise that every segment
+        is one row.
+        Returns (x, key_cache, value_cache, index_cache, state, (experts
+        hit, largest load[, pairs on held experts, launches past their
+        places]) or ())."""
         cfg = self.cfg
         kinds, kind_of = cfg.kinds, cfg.kind_of_layer
         two = isinstance(key_cache, tuple)
@@ -1342,8 +1443,11 @@ class PagedServingEngine:
                            else {})
             layers = [i for i, kk in enumerate(kind_of) if kk == k]
             # the layer's place among the layers of its pool
-            same = [i for i, s in enumerate(cfg.layers) if not two
-                    or _in_window_pool(s) == _in_window_pool(spec)]
+            # (a state-space layer's: among the state-space layers)
+            same = [i for i, s in enumerate(cfg.layers)
+                    if (s.attn == "ssm") == (spec.attn == "ssm") and (
+                        not two
+                        or _in_window_pool(s) == _in_window_pool(spec))]
             stacks.append({
                 "lp": {n: v for n, v in leaves.items()
                        if not (sparse and n in expert_names)},
@@ -1413,7 +1517,7 @@ class PagedServingEngine:
 
         def body(kind, carry, leaves):
             spec = kinds[kind]
-            x, pk, pv, pi, *counts = carry
+            x, pk, pv, pi, ps, *counts = carry
             lp = leaves["lp"]
 
             def lora(h, t, y):
@@ -1430,7 +1534,16 @@ class PagedServingEngine:
                     y = y + jnp.einsum("tso,ts->to", w, sel).astype(y.dtype)
                 return y
 
-            if spec.attn == "latent":
+            if spec.attn == "ssm":
+                h, out = L.residual(x, lp, cfg, "attn")
+                h = L.rms_norm(h, lp["attn_norm"], cfg.rms_eps)
+                y, *ps = L.ssm_mixer(h, lp, spec.ssm, cfg.rms_eps, *ps,
+                                     leaves["page_layer"], slots, past, this,
+                                     cu, one_row, use_pallas)
+                with jax.named_scope("ssm"), jax.named_scope("ssm_out"):
+                    x = out(y)
+                ps = tuple(ps)
+            elif spec.attn == "latent":
                 pool = int(two and _in_window_pool(spec))
                 x, kc, ic = latent_attention(
                     spec, x, lp, pk[pool], pv[pool], tables[pool],
@@ -1438,7 +1551,8 @@ class PagedServingEngine:
                 pk, pv = put(pk, pool, kc), put(pv, pool, ic)
             else:
                 pool = int(two and spec.attn == "window")
-                rot = int(cfg.head_dim * spec.rope.partial)
+                rot = int(cfg.head_dim * (spec.rope.partial if spec.rope
+                                          else 1.0))
                 h, out = L.residual(x, lp, cfg, "attn")
                 with jax.named_scope("qkv"):
                     h = L.rms_norm(h, lp["attn_norm"], cfg.rms_eps)
@@ -1462,7 +1576,10 @@ class PagedServingEngine:
                 o, _, kc, vc = paged_layer_attention(
                     qkv, pk[pool], pv[pool], leaves["page_layer"], past,
                     this, cu, tables[pool],
-                    rope_emb=rope_emb[self._ropes.index(spec.rope)],
+                    rope_emb=spec.rope and rope_emb[
+                        self._ropes.index(spec.rope)],
+                    softmax_scale=spec.softmax_scale,
+                    head_dim=cfg.head_dim,
                     quant_scales=leaves["kv"], use_neox_style=True,
                     use_pallas=use_pallas, block_length=cfg.block_length,
                     window=cfg.sliding_window if spec.attn == "window"
@@ -1500,16 +1617,17 @@ class PagedServingEngine:
                         gate = (jax.nn.silu(Q.matmul_param(h, lp, "w1"))
                                 * Q.matmul_param(h, lp, "w3"))
                         x = out(Q.matmul_param(gate, lp, "w2"))
-            return (x, pk, pv, pi, *counts)
+            return (x, pk, pv, pi, ps, *counts)
 
         # the expert counters ride the carry: one a field the tick sends
         # behind its tokens but `moe_pairs`, which the lengths give
         zero = jnp.zeros((), jnp.int32)
-        x, pk, pv, pi, *counts = L.scan_plan(
+        x, pk, pv, pi, ps, *counts = L.scan_plan(
             cfg, body,
-            (x, pools_k, pools_v, index_cache)
-            + (zero,) * len(self._moe_fields[1:]), stacks)
-        return (x, pk if two else pk[0], pv if two else pv[0], pi,
+            (x, pools_k, pools_v, index_cache, state)
+            + (zero,) * len(self._moe_fields[1:]), stacks,
+            by_index=bool(self._ssm))
+        return (x, pk if two else pk[0], pv if two else pv[0], pi, ps,
                 tuple(counts))
 
     def _held_counts(self, load, rows: int):
@@ -1835,8 +1953,12 @@ class PagedServingEngine:
             # masked; zero on a commit forward and on a prefill chunk
             quota = np.zeros((B,), np.int32)
             masked = np.zeros((B, Bd), bool)
+            # each entry's slot of the state pools; an idle entry: the void
+            slots = np.full((B,), self.state_slots, np.int32)
             pos = 0
             for i, (seq, n) in enumerate(batch.items):
+                if self._ssm:
+                    slots[i] = self.blocks.slot_of(seq.rid)
                 start = seq.planned()
                 chunk = seq.tokens[start:start + n]
                 if in_block[i]:
@@ -1933,12 +2055,17 @@ class PagedServingEngine:
                                         else (None, None)),
                      self._last_out, feed,
                      **({} if self._index_cache is None
-                        else {"index_cache": self._index_cache}))
+                        else {"index_cache": self._index_cache}),
+                     **({"state": self._state, "slots": slots}
+                        if self._ssm else {}))
             if spec_mode:
                 tick.out, tick.all_arg = out[:2]
             else:
                 tick.out = out[0]
             self._last_out = tick.out
+            if self._ssm:
+                *out, self._state = out
+                tick.slots_live = self.blocks.slots_live()
             if self._index_cache is not None:
                 *out, self._index_cache = out
             self._key_cache, self._value_cache = out[-2:]
@@ -2047,13 +2174,28 @@ class PagedServingEngine:
                 fields["hyper_rows"] = (2 * self.cfg.num_layers
                                         * (batch.total_tokens + spec_extra))
                 self.stats["hyper_rows"] += fields["hyper_rows"]
+            if self._ssm:
+                # the state-space layers' work, from the host's own lengths
+                long = this_lens > 1
+                fields.update(
+                    ssm_step_rows=self._ssm_layers * int(
+                        np.count_nonzero(this_lens == 1)),
+                    ssm_scan_rows=self._ssm_layers * int(
+                        this_lens[long].sum()),
+                    ssm_segments=self._ssm_layers * int(
+                        np.count_nonzero(long)),
+                    state_slots_live=cur.slots_live)
+                for name in ("ssm_step_rows", "ssm_scan_rows",
+                             "ssm_segments", "state_slots_live"):
+                    self.stats[name] += fields[name]
             if self._whole_pages:
                 # how well the launch's walk fits the traffic, from the
                 # host's own lengths: pages that hold a live key against
                 # pages fetched (whole key blocks), and on a mixed tick the
                 # work items with their live and packed query rows
                 cfg = self.cfg
-                pool = (cfg.head_dim, np.dtype(self.cache_dtype).itemsize,
+                pool = (self._head_width,
+                        np.dtype(self.cache_dtype).itemsize,
                         self.max_blocks_per_seq)
                 # one launch a kind of attention, summed
                 walked: Dict[str, int] = {}
@@ -2273,7 +2415,7 @@ class PagedServingEngine:
             return out
         return {**out, "attn_keys_full": n_full * keys,
                 "attn_keys_window": n_window * wkeys,
-                "attn_keys_causal": cfg.num_layers * keys,
+                "attn_keys_causal": (n_full + n_window) * keys,
                 "attn_pairs_full": n_full * pairs,
                 "attn_pairs_window": n_window * wpairs}
 
@@ -2512,4 +2654,12 @@ class PagedServingEngine:
         if self.window_blocks:
             out["prefix_cache"] = ("off: the window pool keeps no page "
                                    "behind a window for a prefix hit to map")
+        if self._ssm:
+            out.update(
+                state_slots=self.state_slots,
+                state_bytes_total=self.state_slots * self.state_slot_bytes,
+                state_bytes_in_use=(self.blocks.slots_live()
+                                    * self.state_slot_bytes),
+                prefix_cache="off: a page hit cannot restore a sequence's "
+                             "recurrent state")
         return out

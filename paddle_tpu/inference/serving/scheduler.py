@@ -18,7 +18,12 @@ request mix into one fixed-shape compiled program):
   generated tokens re-prefill when capacity returns, numerically exact).
   With a window pool beside the pool (a model with sliding-window layers)
   admission, growth and preemption look at both: a sequence runs only
-  with its pages in each;
+  with its pages in each. With recurrent state (a model with state-space
+  layers: `BlockManager(state_slots=...)`) a sequence also runs only with
+  a state slot: `allocate_sequence` takes it or admits nobody, every way
+  out of the running set (`_finish`, `_preempt`) gives it back through
+  `free_sequence`, and a preempted sequence re-prefills from `num_computed`
+  0, which is also what makes the device start it from a zero state;
 - **deadlines & cancellation**: per-request absolute deadlines checked at
   every schedule point; expired or cancelled requests free their pages
   immediately and finish with reason ``"deadline"`` / ``"cancelled"``;

@@ -84,6 +84,24 @@ through a doubly stochastic mix of them (`residual`, the one seam every
 `hyper_post`). The embedded row is copied to the lanes (`hyper_spread`)
 and the head reads their sum (`hyper_collapse`); with `hyper_lanes` 0 all
 of it is the identity and a block is traced as it always was.
+
+A fourth kind of mixer keeps no keys at all: a STATE-SPACE layer
+(`LayerSpec.attn = "ssm"` with an `SsmSpec`: Mamba-2's heads, head width,
+state width, groups, convolution width and block length; granite-4.0-h's
+36 of 40 layers). Its parameters are `w_in` (z | xBC) and `w_dt`, the depthwise
+convolution `conv_w` / `conv_b`, `dt_bias`, `A_log`, `D` (float32 whatever
+the weights' dtype: they make decays), the gated norm `ssm_norm` and
+`w_out`; `ssm_mixer` is the one body, over a packed stream whose segments
+carry their recurrent state in a pool of slots (`ops/kernels/ssm.py`), for
+the serving tick and, on whole sequences from a zero state, for `block`.
+Beside it a layer of heads may state its own softmax scale
+(`LayerSpec.softmax_scale`) and have no rope at all (`rope=None`: no table
+is made, nothing is rotated), and a config three scalars and a tied head
+(`embed_scale` on the embedded row, `residual_scale` on every sub-block's
+output through `residual`, `logit_divisor` under the logits,
+`tie_embeddings`: `head_logits` multiplies with the embedding's transpose
+and there is no `lm_head`); at their defaults every config traces as it
+did.
 """
 from __future__ import annotations
 
@@ -170,15 +188,52 @@ class LatentSpec:
             self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
 
 
+# a recurrent state in a serving pool: a sum carried over the whole sequence
+SSM_STATE_DTYPE = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class SsmSpec:
+    """The widths of one kind of state-space layer (Mamba-2): `heads` heads
+    of `head_dim` (the inner width is their product), a state of `d_state`
+    values a head value, `groups` groups of heads that share B and C, a
+    causal depthwise convolution of `d_conv` taps over xBC (inner width + 2
+    groups x d_state values), and the block length `chunk` of the chunked
+    scan."""
+    heads: int
+    head_dim: int
+    d_state: int
+    groups: int = 1
+    d_conv: int = 4
+    chunk: int = 256
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.inner + 2 * self.groups * self.d_state
+
+    @property
+    def in_width(self) -> int:
+        """Columns of `w_in`: z | xBC (in_proj's dt columns are `w_dt`)."""
+        return self.inner + self.conv_dim
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """What one layer of a layer plan is: `attn` "full" (causal),
     "window" (causal over the last `LlamaConfig.sliding_window` keys, the
-    query's own among them) or "latent" (causal multi-head latent
+    query's own among them), "latent" (causal multi-head latent
     attention at the widths of `latent`, which also says whether the layer
     has a window or a sparse index; a latent spec without one takes the
     config's `q_lora_rank` and its sibling fields, `__post_init__` writes
-    them here), its query heads, its rope, and `ffn` "dense" (SwiGLU of
+    them here) or "ssm" (a state-space mixer at the widths of `ssm`: no
+    heads of attention, no rope, no keys), its query heads, its rope (None:
+    the layer rotates nothing, and no table is made for it), the factor on
+    a layer of heads' scores `softmax_scale` (0: head_dim ** -0.5), and
+    `ffn` "dense" (SwiGLU of
     `dense_intermediate_size`) or "sparse" (the routed experts of
     `intermediate_size`, with the shared expert where the config has
     one). `index`: the sparse index of a layer of heads' own keys and
@@ -186,10 +241,12 @@ class LayerSpec:
     either). Layers with equal specs are one kind."""
     attn: str = "full"
     heads: int = 0
-    rope: RopeSpec = RopeSpec()
+    rope: Optional[RopeSpec] = RopeSpec()
     ffn: str = "dense"
     latent: Optional[LatentSpec] = None
     index: Optional[IndexSpec] = None
+    ssm: Optional[SsmSpec] = None
+    softmax_scale: float = 0.0
 
     @property
     def sparse_index(self) -> Optional[IndexSpec]:
@@ -294,6 +351,16 @@ class LlamaConfig:
     hyper_sinkhorn_iters: int = 20
     hyper_eps: float = 1e-6
     hyper_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # three scalars and a tied head (Granite's `embedding_multiplier`,
+    # `residual_multiplier`, `logits_scaling`, `tie_word_embeddings`): the
+    # embedded row times `embed_scale`, every sub-block's output times
+    # `residual_scale` before it joins the stream (`residual`), the logits
+    # over `logit_divisor`, and the head the embedding's transpose (no
+    # `lm_head` among the parameters). At 1, 1, 1, False nothing is traced
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         here = (self.hidden_size, self.num_heads)
@@ -315,10 +382,16 @@ class LlamaConfig:
             object.__setattr__(self, "layer_plan", tuple(
                 self._with_widths(s) for s in self.layer_plan))
             for spec in self.layer_plan:
-                if (spec.attn not in ("full", "window", "latent")
+                if (spec.attn not in ("full", "window", "latent", "ssm")
                         or spec.ffn not in ("dense", "sparse")
-                        or spec.heads % self.num_kv_heads):
+                        or spec.heads % self.num_kv_heads
+                        or (spec.attn == "ssm") != (spec.ssm is not None)):
                     raise ValueError(f"layer_plan: bad layer {spec}")
+                if spec.ssm is not None and (
+                        spec.ssm.heads % spec.ssm.groups
+                        or spec.ssm.d_conv < 2 or spec.index is not None):
+                    raise ValueError(f"layer_plan: bad state-space layer "
+                                     f"{spec}")
                 if spec.attn != "latent" and spec.latent is not None:
                     raise ValueError(f"layer_plan: {spec.attn} layer with "
                                      "latent widths")
@@ -347,6 +420,10 @@ class LlamaConfig:
                     "a sparse index stands over a latent layer or, over "
                     "heads' own keys and values, over a causal "
                     f"full-attention layer; no model served has {spec}")
+        if self.hyper_lanes and any(s.attn == "ssm" for s in self.layer_plan):
+            raise NotImplementedError(
+                "state-space layers under hyper-connections: no model "
+                "served has both")
         if self.hyper_lanes and (self.hyper_lanes < 2 or self.block_length
                                  or self.hyper_sinkhorn_iters < 1):
             raise NotImplementedError(
@@ -454,7 +531,12 @@ class LlamaConfig:
         heads, ffn, ls = spec.heads, spec.ffn, spec.latent
         d, hd = self.hidden_size, self.head_dim
         norms = 2 * d
-        if ls is not None:
+        if spec.ssm is not None:
+            sm = spec.ssm
+            attn = d * (sm.in_width + sm.heads) + sm.inner * d
+            # the convolution and its bias, dt_bias, A_log, D, the gated norm
+            norms += (sm.d_conv + 1) * sm.conv_dim + 3 * sm.heads + sm.inner
+        elif ls is not None:
             r, c = ls.q_lora_rank, ls.kv_lora_rank
             nope, rope, v = (ls.qk_nope_head_dim, ls.qk_rope_head_dim,
                              ls.v_head_dim)
@@ -493,7 +575,7 @@ class LlamaConfig:
     def num_params(self) -> int:
         d, v = self.hidden_size, self.vocab_size
         return (v * d + sum(self._layer_params(s)[0] for s in self.layers)
-                + d + d * v)
+                + d + (0 if self.tie_embeddings else d * v))
 
     def num_active_params(self) -> int:
         """Matmul parameters one token is multiplied with: a token passes
@@ -521,8 +603,17 @@ def require_uniform(cfg: LlamaConfig, what: str) -> None:
     if cfg.layer_plan:
         raise NotImplementedError(
             f"{what} runs one uniform stack of layers and does not take a "
-            "layer plan (LlamaConfig.layer_plan); `llama.forward`, "
-            "`PagedServingEngine` and `distributed.hybrid` do")
+            "layer plan (LlamaConfig.layer_plan"
+            + (", here with state-space layers"
+               if any(s.attn == "ssm" for s in cfg.layer_plan) else "")
+            + "); `llama.forward`, `PagedServingEngine` and "
+            "`distributed.hybrid` (state-space layers aside) do")
+    if (cfg.embed_scale != 1 or cfg.residual_scale != 1
+            or cfg.logit_divisor != 1 or cfg.tie_embeddings):
+        raise NotImplementedError(
+            f"{what} computes no embed_scale, residual_scale, "
+            "logit_divisor or tied head; `llama.forward` and "
+            "`PagedServingEngine` do")
     if cfg.experts_held:
         raise NotImplementedError(
             f"{what} holds every routed expert and does not take a chip's "
@@ -553,21 +644,56 @@ def _normal(key: jax.Array, shape, dtype, scale: float = 0.02):
 
 def _init_blocks(cfg: LlamaConfig, key: jax.Array, L: int, nh: int,
                  ffn_kind: str, ls: Optional[LatentSpec] = None,
-                 index: Optional[IndexSpec] = None) -> Dict[str, jax.Array]:
+                 index: Optional[IndexSpec] = None,
+                 sm: Optional[SsmSpec] = None) -> Dict[str, jax.Array]:
     """One stack of `L` layers with `nh` query heads, latent attention at
     the widths `ls` (None: heads' own keys and values, under the sparse
     index `index` where the layer has one) and an FFN of
     `ffn_kind` ("dense" | "sparse"); the keys are split as they always
     were, so a uniform config draws the weights it drew. A config that
     holds a share of its experts (`experts_held`) draws the whole router
-    and the held experts' matrices alone."""
+    and the held experts' matrices alone. `sm`: a state-space mixer at
+    those widths in place of attention."""
     d, f = cfg.hidden_size, cfg.intermediate_size
     hd, nkv = cfg.head_dim, cfg.num_kv_heads
     pt = cfg.param_dtype
     keys = jax.random.split(key, 10)
     normal = functools.partial(_normal, dtype=pt)
 
-    if ls is not None:
+    if sm is not None:
+        # Drawn so that the state MATTERS (Mamba-2's own initialisation):
+        # A in U(1, 16) and delta's bias the inverse softplus of
+        # exp(U(log 0.001, log 0.1)), so a head forgets over tens to
+        # thousands of tokens; in_proj's dt columns (`w_dt`, a matrix of
+        # its own: z | xBC is 66 whole lanes of 128 wide at Granite's
+        # widths, with dt's 64 behind them 66.5, and the chip's compiler
+        # then re-lays the whole stack out, 1.25 GB a tick) at 1 / 32 of
+        # the weights' 0.02, or a projection of std 0.02 sqrt(d) would
+        # swamp that bias and every head would forget within two tokens;
+        # D = 1; the
+        # convolution's taps U(-1/2, 1/2). dt_bias, A_log and D are float32
+        # whatever the weights' dtype: they make decays
+        ks = jax.random.split(keys[0], 5)
+        uniform = lambda k, shape, lo, hi: jax.random.uniform(
+            k, shape, jnp.float32, lo, hi)
+        dt = jnp.exp(uniform(ks[2], (L, sm.heads), math.log(1e-3),
+                             math.log(1e-1)))
+        blocks = {
+            "w_in": normal(ks[0], (L, d, sm.in_width)),
+            "w_dt": normal(ks[1], (L, d, sm.heads), scale=0.02 / 32),
+            "conv_w": uniform(keys[1], (L, sm.conv_dim, sm.d_conv),
+                              -0.5, 0.5).astype(pt),
+            "conv_b": normal(ks[4], (L, sm.conv_dim)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(uniform(ks[3], (L, sm.heads), 1.0, 16.0)),
+            "D": jnp.ones((L, sm.heads), jnp.float32),
+            "ssm_norm": jnp.ones((L, sm.inner), pt),
+            "w_out": normal(keys[3], (L, sm.inner, d)),
+            "attn_norm": jnp.ones((L, d), pt),
+            "mlp_norm": jnp.ones((L, d), pt),
+        }
+        ix = None
+    elif ls is not None:
         r, c = ls.q_lora_rank, ls.kv_lora_rank
         nope, rope, vd = (ls.qk_nope_head_dim, ls.qk_rope_head_dim,
                           ls.v_head_dim)
@@ -677,18 +803,24 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         blocks = tuple(
             _init_blocks(cfg, jax.random.fold_in(key, 1 + k),
                          kind_of.count(k), spec.heads, spec.ffn, spec.latent,
-                         spec.index)
+                         spec.index, spec.ssm)
             for k, spec in enumerate(cfg.kinds))
     else:
         blocks = _init_blocks(cfg, key, cfg.num_layers, cfg.num_heads,
                               "sparse" if cfg.num_experts else "dense",
                               index=cfg.index)
-    return {
-        "embed": normal(keys[8], (v, d)),
+    params = {
+        # (under `embed_scale` the row enters the stream at the weights'
+        # 0.02 as every other config's does; drawn at 0.02 a tied head
+        # would find the input token's own embedding, `embed_scale`-fold in
+        # the stream, five sigma over every other logit, and echo it)
+        "embed": normal(keys[8], (v, d), scale=0.02 / cfg.embed_scale),
         "blocks": blocks,
         "final_norm": jnp.ones((d,), pt),
-        "lm_head": normal(keys[9], (d, v)),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = normal(keys[9], (d, v))
+    return params
 
 
 def kind_stacks(blocks) -> Tuple[Dict[str, jax.Array], ...]:
@@ -1300,14 +1432,19 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     ix = spec.index if spec else cfg.index
     h, out = residual(x, lp, cfg, "attn")
     h = rms_norm(h, lp["attn_norm"], cfg.rms_eps)
-    if spec and spec.attn == "latent":
+    if spec and spec.attn == "ssm":
+        x = out(ssm_sequences(h, lp, cfg, spec.ssm))
+    elif spec and spec.attn == "latent":
         x = out(latent_self_attention(h, lp, cfg, nh, cos, sin, spec.latent))
     else:
         q, k = qk_normed(h @ lp["wq"].astype(h.dtype),
                          h @ lp["wk"].astype(h.dtype), lp, cfg)
         v = (h @ lp["wv"].astype(h.dtype)).reshape(B, T, nkv, hd)
-        q = apply_rope(q.reshape(B, T, nh, hd), cos, sin)
-        k = apply_rope(k.reshape(B, T, nkv, hd), cos, sin)
+        # (a layer with no rope rotates nothing)
+        rope = (lambda t: t) if cos is None else (
+            lambda t: apply_rope(t, cos, sin))
+        q = rope(q.reshape(B, T, nh, hd))
+        k = rope(k.reshape(B, T, nkv, hd))
         window = cfg.sliding_window if spec and spec.attn == "window" else 0
         select = None
         if ix is not None:
@@ -1315,6 +1452,9 @@ def block(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
             select = index_select(
                 h, None, lp, cfg, ix, *index_rope,
                 jnp.broadcast_to(pos[None, :] <= pos[:, None], (B, T, T)))
+        scale = spec.softmax_scale if spec else 0.0
+        if scale:       # the kernels and the einsum divide by sqrt(hd)
+            q = (q.astype(jnp.float32) * (scale * hd ** 0.5)).astype(q.dtype)
         o = attention(q, k, v, impl=attn_impl, block_length=cfg.block_length,
                       window=window, select=select)
         if cfg.attn_gate:
@@ -1427,8 +1567,11 @@ def residual(x: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
     `hyper_coeff`, `hyper_pre`, here) and out(y) = `hyper_post` (scope
     `hyper` > `hyper_post`, inside whatever scope the caller adds y in).
     Every `x = x + ...` of `block` and of the serving tick's layer loop
-    goes through here."""
+    goes through here; `residual_scale` multiplies y where it is not 1
+    (never under hyper-connections: no model served has both)."""
     if not cfg.hyper_lanes:
+        if cfg.residual_scale != 1:
+            return x, lambda y: x + cfg.residual_scale * y
         return x, lambda y: x + y
     with jax.named_scope("hyper"):
         with jax.named_scope("hyper_coeff"):
@@ -1590,6 +1733,96 @@ def latent_self_attention(h: jax.Array, lp: Dict[str, jax.Array],
     return o.reshape(B, T, -1) @ lp["wo"].astype(o.dtype)
 
 
+def ssm_recurrence(xbc: jax.Array, dt: jax.Array, lp: Dict[str, jax.Array],
+                   sm: SsmSpec, state_pool, conv_pool, layer, slots, past,
+                   this, cu, one_row: bool = False, kernel=False):
+    """What of a state-space mixer is no matrix product with its weights,
+    on a packed stream's projected rows xbc [T, conv_dim] and dt [T, H]
+    (`ops/kernels/ssm.py` says what a stream, a slot and the two pools
+    are): the convolution with its carried rows (scope `ssm_conv`, with
+    the split into u, B, C and delta = softplus(dt + dt_bias)), the
+    one-row segments' state update (`ssm_step`) and the longer segments'
+    chunked scan (`ssm_scan`; not traced where the caller promises
+    `one_row`: every segment of the tick is one row; `kernel`: the one-row
+    update as the launch of `ops/pallas/ssm_step.py`). Returns (y [T, H, P]
+    float32, the convolution's output [T, conv_dim], state_pool,
+    conv_pool)."""
+    from ..ops.kernels import ssm as S
+    T = xbc.shape[0]
+    H, P, N, G = sm.heads, sm.head_dim, sm.d_state, sm.groups
+    with jax.named_scope("ssm_conv"):
+        xbc, conv_pool = S.ssm_conv(xbc, conv_pool, layer, slots, past, this,
+                                    cu, lp["conv_w"], lp["conv_b"])
+        u = xbc[:, :sm.inner].reshape(T, H, P)
+        Bm = xbc[:, sm.inner:sm.inner + G * N].reshape(T, G, N)
+        Cm = xbc[:, sm.inner + G * N:].reshape(T, G, N)
+        delta = jax.nn.softplus(dt.astype(jnp.float32)
+                                + lp["dt_bias"].astype(jnp.float32))
+        args = (u, Bm, Cm, delta, jnp.exp(lp["A_log"].astype(jnp.float32)),
+                lp["D"])
+    with jax.named_scope("ssm_step"):
+        y, state_pool = S.ssm_step(*args, state_pool, layer, slots, past,
+                                   this, cu, kernel)
+    if not one_row:
+        with jax.named_scope("ssm_scan"):
+            y_long, state_pool = S.ssm_scan(
+                *args, state_pool, layer, slots, past, this, cu, sm.chunk)
+            y = y + y_long
+    return y, xbc, state_pool, conv_pool
+
+
+def ssm_mixer(h: jax.Array, lp: Dict[str, jax.Array], sm: SsmSpec,
+              eps: float, state_pool, conv_pool, layer, slots, past, this, cu,
+              one_row: bool = False, kernel=False):
+    """The state-space mixer of one layer on a packed stream's normed rows
+    h [T, d] (`layer` is the layer's place among the state-space layers):
+    (its output [T, d] before the residual, state_pool, conv_pool). Scopes
+    `ssm` > `ssm_in` (w_in: z | xBC; w_dt), `ssm_conv`, `ssm_step`,
+    `ssm_scan` (`ssm_recurrence`), `ssm_gate` (times silu(z), then the norm
+    over a group's values), `ssm_out` (w_out)."""
+    T = h.shape[0]
+    with jax.named_scope("ssm"):
+        with jax.named_scope("ssm_in"):
+            zx = h @ lp["w_in"].astype(h.dtype)
+            z, xbc = zx[:, :sm.inner], zx[:, sm.inner:]
+            dt = h @ lp["w_dt"].astype(h.dtype)
+        y, _, state_pool, conv_pool = ssm_recurrence(
+            xbc, dt, lp, sm, state_pool, conv_pool, layer, slots, past, this,
+            cu, one_row, kernel)
+        with jax.named_scope("ssm_gate"):
+            g = (y.reshape(T, sm.inner) * jax.nn.silu(z.astype(jnp.float32))
+                 ).reshape(T, sm.groups, -1)
+            g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+            o = (g.reshape(T, sm.inner)
+                 * lp["ssm_norm"].astype(jnp.float32)).astype(h.dtype)
+        with jax.named_scope("ssm_out"):
+            return o @ lp["w_out"].astype(o.dtype), state_pool, conv_pool
+
+
+def ssm_state_pools(sm: SsmSpec, layers: int, slots: int, conv_dtype):
+    """Zeroed (state_pool, conv_pool) for `layers` state-space layers and
+    `slots` sequences, the void slot behind them."""
+    return (jnp.zeros((layers, slots + 1, sm.heads, sm.head_dim, sm.d_state),
+                      SSM_STATE_DTYPE),
+            jnp.zeros((layers, slots + 1, (sm.d_conv - 1) * sm.conv_dim),
+                      conv_dtype))
+
+
+def ssm_sequences(h: jax.Array, lp: Dict[str, jax.Array], cfg: LlamaConfig,
+                  sm: SsmSpec) -> jax.Array:
+    """`ssm_mixer` on whole sequences h [B, T, d], each from a zero state:
+    the sequences are the segments of one packed stream, and the pools are
+    made and dropped here."""
+    B, T, d = h.shape
+    counts = jnp.full((B,), T, jnp.int32)
+    out, _, _ = ssm_mixer(
+        h.reshape(B * T, d), lp, sm, cfg.rms_eps,
+        *ssm_state_pools(sm, 1, B, h.dtype), jnp.int32(0),
+        jnp.arange(B, dtype=jnp.int32), jnp.zeros((B,), jnp.int32), counts,
+        jnp.arange(B + 1, dtype=jnp.int32) * T)
+    return out.reshape(B, T, d)
+
+
 def plan_segments(cfg: LlamaConfig):
     """The plan as the loops that run it: [(repeats, ((kind, count),
     ...)), ...]. Consecutive layers of one kind are a run; the longest
@@ -1618,7 +1851,8 @@ def plan_segments(cfg: LlamaConfig):
     return [part for part in parts if part[1]]
 
 
-def scan_plan(cfg: LlamaConfig, body: Callable, carry, stacks):
+def scan_plan(cfg: LlamaConfig, body: Callable, carry, stacks,
+              by_index: bool = False):
     """Run a layer plan: `body(kind, carry, leaves) -> carry` once a layer,
     in the plan's order, with `leaves` the layer's slice of `stacks[kind]`
     (a pytree whose leaves are stacked over that kind's layers; what a
@@ -1628,15 +1862,26 @@ def scan_plan(cfg: LlamaConfig, body: Callable, carry, stacks):
     `lax.scan`, and a period of runs that repeats is a scan over the
     periods with the runs' scans inside, so a kind's body is traced once a
     run of the period, not once a layer. A stack is sliced only where a
-    run does not cover it whole."""
+    run does not cover it whole. `by_index`: no stack is sliced at all;
+    the scans run over the layers' places and a layer's leaves are read
+    out of the whole stack where the body runs (a slice of a stack is a
+    copy: of 27 of granite-4.0-h's 36 state-space layers, 4.1 GB that the
+    chip has not got; the serving tick of a config with such layers asks
+    for this, nothing that is differentiated does)."""
     done = [0] * len(cfg.kinds)
 
     def part(stack, start, count):
+        if by_index:
+            return start + jnp.arange(count, dtype=jnp.int32)
         return jax.tree.map(
             lambda a: a if (start, count) == (0, a.shape[0])
             else a[start:start + count], stack)
 
     def run_of(kind):
+        if by_index:
+            return lambda c, i: (body(kind, c, jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+                stacks[kind])), None)
         return lambda c, leaves: (body(kind, c, leaves), None)
 
     for repeats, runs in plan_segments(cfg):
@@ -1657,8 +1902,10 @@ def scan_plan(cfg: LlamaConfig, body: Callable, carry, stacks):
         def period(c, xs_p):
             at = dict.fromkeys(per, 0)
             for kind, count in runs:
-                c, _ = lax.scan(run_of(kind), c,
-                                part(xs_p[kind], at[kind], count))
+                c, _ = lax.scan(
+                    run_of(kind), c,
+                    xs_p[kind][at[kind]:at[kind] + count] if by_index
+                    else part(xs_p[kind], at[kind], count))
                 at[kind] += count
             return c, None
 
@@ -1671,13 +1918,14 @@ def scan_plan(cfg: LlamaConfig, body: Callable, carry, stacks):
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
             attn_impl: str = "auto", ffn_impl: str = "stock") -> jax.Array:
     """tokens [B, T] int32 → logits [B, T, vocab] (f32)."""
-    x = hyper_spread(jnp.take(params["embed"], tokens,
-                              axis=0).astype(cfg.dtype), cfg)
+    x = hyper_spread(embedded(params, tokens, cfg), cfg)
     T = tokens.shape[1]
     if cfg.layer_plan:
         kinds = cfg.kinds
         ropes = {spec.rope: rope_table(jnp.arange(T), cfg.rope_width(spec),
-                                       spec.rope) for spec in kinds}
+                                       spec.rope) for spec in kinds
+                 if spec.rope is not None}
+        ropes[None] = (None, None)
 
         iropes = {spec: rope_table(jnp.arange(T), cfg.index_rope_width(spec),
                                    spec.rope)
@@ -1700,7 +1948,29 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
 
         x, _ = lax.scan(body, x, params["blocks"])
     x = rms_norm(hyper_collapse(x, cfg), params["final_norm"], cfg.rms_eps)
-    return (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
+    return head_logits(params, x, cfg)
+
+
+def embedded(params: Dict[str, Any], tokens: jax.Array,
+             cfg: LlamaConfig) -> jax.Array:
+    """The tokens' rows of the embedding in the compute dtype, times
+    `embed_scale` where the config has one."""
+    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.dtype)
+    return x if cfg.embed_scale == 1 else x * cfg.embed_scale
+
+
+def head_logits(params: Dict[str, Any], h: jax.Array,
+                cfg: LlamaConfig) -> jax.Array:
+    """Float32 logits of normed rows h [..., d]: through `lm_head`, or with
+    `tie_embeddings` through the embedding's transpose; over
+    `logit_divisor` where the config has one."""
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("...d,vd->...v", h,
+                            params["embed"].astype(h.dtype))
+    else:
+        logits = h @ params["lm_head"].astype(h.dtype)
+    logits = logits.astype(jnp.float32)
+    return logits if cfg.logit_divisor == 1 else logits / cfg.logit_divisor
 
 
 def loss_fn(params: Dict[str, Any], tokens: jax.Array, targets: jax.Array,

@@ -538,7 +538,8 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
                           use_neox_style=False, quant_max_bound=127.0,
                           quant_min_bound=-127.0, use_pallas=False,
                           block_length=0, window=0, rotary_dim=0,
-                          kind=None, select=None):
+                          kind=None, select=None, softmax_scale=0.0,
+                          head_dim=0):
     """One layer of `block_multihead_attention_` on the stacked page pool
     [L, num_blocks, KV, block_size, hd]: split and rotate `qkv`, write the
     new tokens' rows into `layer`'s pages where they lie, then attend over
@@ -559,7 +560,13 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
     the leading `rotary_dim` values of each head are rotated (rotate-half
     inside them). `kind` names the layer's kind in a layer plan: the read
     then runs under scope `paged_attention_<kind>` inside
-    `paged_attention`.
+    `paged_attention`. `softmax_scale`: the factor on the scores (0:
+    hd ** -0.5), on either read path. `head_dim` > 0 and narrower than the
+    pool's rows: the heads of `qkv` are that wide and lie in the leading
+    lanes of a pool row (a head of 64 in 128 lanes, zeros behind it, so
+    that a page is whole lanes: the engine's pool beside the kernel); q, k
+    and v are padded here and the output is cut back, the scores (at
+    head_dim ** -0.5 unless told) and the values are what they were.
 
     WHICH READ A ROW TAKES UNDER A SPARSE INDEX (`select` = (positions
     [tok, k], their pages [tok, k], sparse [B], the selection's bits [tok,
@@ -596,6 +603,22 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
     judged under a selection and raise. Returns (fmha_out, qkv_out,
     key_pool, value_pool)."""
     from ..pallas import paged_attention as PA
+    if head_dim and head_dim < key_pool.shape[-1]:
+        tok, wide = qkv.shape[0], key_pool.shape[-1]
+        padded = jnp.pad(qkv.reshape(tok, -1, head_dim),
+                         ((0, 0), (0, 0), (0, wide - head_dim)))
+        if qkv_bias is not None:
+            qkv_bias = jnp.pad(qkv_bias.reshape(-1, head_dim),
+                               ((0, 0), (0, wide - head_dim))).reshape(-1)
+        out, qkv_out, key_pool, value_pool = paged_layer_attention(
+            padded.reshape(tok, -1), key_pool, value_pool, layer,
+            seq_lens_decoder, seq_lens_this_time, cu_seqlens_q, block_tables,
+            rope_emb, quant_scales, qkv_bias, use_neox_style,
+            quant_max_bound, quant_min_bound, use_pallas, block_length,
+            window, (rotary_dim or head_dim) if rope_emb is not None else 0,
+            kind, select, softmax_scale or float(head_dim) ** -0.5)
+        return (out.reshape(tok, -1, wide)[..., :head_dim].reshape(tok, -1),
+                qkv_out, key_pool, value_pool)
     _, num_blocks, KV, bs, hd = key_pool.shape
     B, max_blocks = block_tables.shape
     token_num = qkv.shape[0]
@@ -707,7 +730,7 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
             # gather ever exists, and no slice of the layer either: the
             # freshly written pool goes in whole, with the layer index; int8
             # pages ride with their scale planes.
-            sm_scale = float(1.0 / np.sqrt(hd))
+            sm_scale = float(softmax_scale or 1.0 / np.sqrt(hd))
 
             def walk_rows(this_w, mask=None):
                 """The decode walk over the sequences whose `this_w` is not
@@ -810,7 +833,8 @@ def paged_layer_attention(qkv, key_pool, value_pool, layer, seq_lens_decoder,
         k_tok_rows = rows_k[tok_b]                               # [tok, max_kv, KV, hd]
         v_tok_rows = rows_v[tok_b]
         s = jnp.einsum("tkgd,tskd->tkgs", q_g.astype(jnp.float32),
-                       k_tok_rows.astype(jnp.float32)) / np.sqrt(hd)
+                       k_tok_rows.astype(jnp.float32))
+        s = s * softmax_scale if softmax_scale else s / np.sqrt(hd)
         if kv_quant:
             # per-page dequant: gather each row's page scales like the pages
             # themselves, expand to slots, apply on the SCORES — the scale is

@@ -318,7 +318,7 @@ def test_decode_walk_is_refused_off_whole_lanes(one_chip):
 
 @pytest.mark.parametrize("hidden, whole_pages", [
     (64, False),      # llama-test as it stands: 4 heads of 16
-    (256, False),     # 4 heads of 64
+    (256, True),      # 4 heads of 64: their rows lie in 128 lanes (PR 56)
     (512, True),      # 4 heads of 128: the decode tick takes the decode walk
 ])
 def test_default_engine_ticks_compile(one_chip, monkeypatch, hidden,
@@ -1489,3 +1489,87 @@ def test_keye_depth4_ticks_fit_the_chip(one_chip, monkeypatch):
                 + m.temp_size_in_bytes - m.alias_size_in_bytes) / 2 ** 30
     print("keye page copy GiB:", copy_gib)
     assert copy_gib < 15.75 - 1.0       # beside the weights
+
+
+def test_granite_depth40_ticks_fit_the_chip(one_chip, monkeypatch):
+    """The cell `serve_ssm_chat_decode64` as the engine builds it on a TPU
+    (`available` steered true), from the configuration file itself: both
+    executables (a tick with a prefill chunk, 512 rows: the state-space
+    layers' convolution, one-row update AND chunked scan; a decode tick, 64
+    rows: no scan) compile for the described v5e at the published widths
+    and all 40 layers, the 4 attention layers' 64-wide heads in 128 lanes through the
+    whole-page walks, with the float32 state pool of 65 slots donated and
+    carried, and the compiler counts each over 25 % and under the chip's
+    15.75 GiB with room for the float32 check."""
+    import json
+    import os
+    import re
+
+    from benchmark.drivers import closed_loop_serve_ssm as D
+    from paddle_tpu.inference.serving import PagedServingEngine
+    from paddle_tpu.models import llama as L
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "granite-4.0-h-micro-serve.json")) as f:
+        file = json.load(f)
+    cfg, e = D.granite_config(file, jnp.bfloat16), file["engine"]
+    params = jax.eval_shape(lambda k: L.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    assert params["blocks"][0]["w_in"].shape == (36, 2048, 8448)
+    assert params["blocks"][1]["wq"].shape == (4, 2048, 2048)
+    assert "lm_head" not in params and cfg.num_params() == 3_191_396_096
+    for module in (fa, pa):
+        monkeypatch.setattr(module, "available", lambda: True)
+    # the pools as shapes: 4.97 GB of zeros are not made here
+    pools = L.ssm_state_pools
+    monkeypatch.setattr(L, "ssm_state_pools",
+                        lambda *a: jax.eval_shape(lambda: pools(*a)))
+    eng = PagedServingEngine(
+        cfg, params, num_blocks=e["num_blocks"], block_size=e["block_size"],
+        max_batch=e["max_batch"], token_budget=e["token_budget"],
+        max_len=e["max_len"], pallas=True, pallas_ffn=False)
+    # a head of 64 in 128 lanes: the whole-page walks, no pool re-laid out
+    assert eng._key_cache.shape == (4, 7680, 8, 16, 128)
+    assert [p.shape for p in eng._state] == [(36, 65, 64, 64, 128),
+                                             (36, 65, 3 * 4352)]
+    assert eng._state[0].dtype == jnp.float32 and eng._rope_emb == ()
+    assert eng.state_slot_bytes * 65 == 4_968_437_760
+    build, gib = eng._build_step, {}
+
+    def compiled_not_run(tok_pad, B, *rest):
+        fn = build(tok_pad, B, *rest)
+
+        def tick(*args, **kw):
+            abstract, abstract_kw = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip),
+                (args, kw))
+            compiled = fn.lower(*abstract, **abstract_kw).compile()
+            text = compiled.as_text()
+            assert ("ssm_scan" in text) == (tok_pad == 512)
+            assert "ssm_step" in text and "ssm_conv" in text
+            # the one-row update is the launch of `ops/pallas/ssm_step.py`,
+            # in place on the donated pool
+            assert "ssm_state_step" in text
+            assert "paged_cache_write" in text
+            assert ("paged_attention_mixed" in text) == (tok_pad == 512)
+            assert ("paged_attention_decode" in text) == (tok_pad == 64)
+            # no copy of a page pool: the device's own layout of
+            # [.., 16, 128] is the kernels'
+            assert not re.search(
+                r"= bf16\[4,7680,8,16,128\]\S* copy\(", text)
+            m = compiled.memory_analysis()
+            gib[tok_pad] = (m.argument_size_in_bytes + m.output_size_in_bytes
+                            + m.temp_size_in_bytes
+                            - m.alias_size_in_bytes) / 2 ** 30
+            return (jnp.zeros((B,), jnp.int32), args[1], args[2],
+                    kw["state"])
+        return tick
+
+    monkeypatch.setattr(eng, "_build_step", compiled_not_run)
+    eng.submit(list(range(1, 70)), max_new_tokens=4)
+    eng.step()                  # the prompt, one chunk
+    eng.step()                  # a decode row
+    assert set(gib) == {512, 64}
+    assert all(0.25 * 15.75 < g < 15.75 for g in gib.values()), gib
+    print("Granite depth-40 GiB by tok_pad:", gib)

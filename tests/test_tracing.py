@@ -718,7 +718,7 @@ class TestStableDeviceNames:
     @pytest.mark.parametrize("module, count", [
         ("flash_attention", 3), ("fused_ffn", 6), ("fused_sample", 1),
         ("paged_attention", 6), ("paged_attention_latent", 6),
-        ("index_select", 1)])
+        ("index_select", 1), ("ssm_step", 1)])
     def test_every_pallas_call_has_a_name(self, module, count):
         import ast
         import os
@@ -744,7 +744,7 @@ class TestStableDeviceNames:
                    for n in names)
 
     def test_pallas_call_sites_are_all_in_ops_pallas(self):
-        """The 23 named sites above are all there are in the package."""
+        """The 24 named sites above are all there are in the package."""
         import os
         import re
 
@@ -765,7 +765,8 @@ class TestStableDeviceNames:
                          "ops/pallas/index_select.py": 1,
                          "ops/pallas/fused_sample.py": 1,
                          "ops/pallas/paged_attention.py": 6,
-                         "ops/pallas/paged_attention_latent.py": 6}
+                         "ops/pallas/paged_attention_latent.py": 6,
+                         "ops/pallas/ssm_step.py": 1}
 
 
 # ---------------------------------------------------------------------------
